@@ -15,28 +15,30 @@
 #   make lint-gate        the committed pre-merge gate: lint --changed
 #                         (SARIF) + the tier-1 test command
 #                         (deploy/ci/lint-gate.sh)
+#   make chip-smoke       simulate -> train -> serve on one TPU v5e
+#                         (chip_smoke.py; fails without a chip)
 #   make native           build the C++ featurizer (native/Makefile)
 #   make tsan             build the thread-sanitized featurizer selftest
 #                         — the native-side twin of the TH rule pack
 #   make bench-multichip  the mesh-shape scaling sweep on the 8-device
 #                         virtual CPU mesh, quick tier (locally
 #                         reproducible in a few minutes; refreshes
-#                         MULTICHIP_r06.json — the real curve rides
-#                         benchmarks/tpu_queue.sh)
+#                         MULTICHIP_r06.json — plumbing only; the real
+#                         curve is not measured on the chip)
 #   make serve-bench-replicas
 #                         the serving-plane replica sweep (routing front,
 #                         admission, concurrency up to 1024) — refreshes
-#                         benchmarks/serve_bench.json; the hardware
-#                         scaling curve rides benchmarks/tpu_queue.sh
+#                         benchmarks/serve_bench.json; not measured on
+#                         the chip
 #   make obs-bench        the observability overhead gate (serve + train
 #                         hot paths, obs off/on A/B, asserted <=3%
 #                         budget) — refreshes benchmarks/obs_bench.json;
-#                         the on-chip number rides benchmarks/tpu_queue.sh
+#                         not measured on the chip
 #   make tenk-bench       the 10k-endpoint sparse-first vertical (F=10240
 #                         featurize → ring → feed bytes → train → serve →
 #                         peak RSS, dense vs padded-COO) — refreshes
-#                         benchmarks/tenk_bench.json; the on-chip run
-#                         rides benchmarks/tpu_queue.sh
+#                         benchmarks/tenk_bench.json; not measured on
+#                         the chip
 #   make chaos-bench      the kill-under-load chaos storm gate (SIGKILL
 #                         worker replicas + scheduled thread-replica
 #                         ejections under live HTTP load, plus the
@@ -45,46 +47,41 @@
 #                         429/503, auto-rejoin, remesh bit-identical to
 #                         restart-resume, zero leaked threads/processes/
 #                         fds/device buffers) — refreshes
-#                         benchmarks/chaos_bench.json; the on-chip
-#                         storms ride benchmarks/tpu_queue.sh
-#                         chaos_storm + elastic_remesh
+#                         benchmarks/chaos_bench.json; not measured on
+#                         the chip
 #   make drift-bench      the model-quality observability gate (topology
 #                         shift detection latency, ransomware-mid-drift,
 #                         clean-corpus zero verdicts, <=3% monitor
 #                         overhead) — refreshes benchmarks/
-#                         drift_bench.json; the on-chip overhead number
-#                         rides benchmarks/tpu_queue.sh drift_overhead
+#                         drift_bench.json; not measured on the chip
 #   make whatif-bench     the what-if capacity-surface gate (cached
 #                         interpolated reads >=50x the direct
 #                         synthesize->predict path at concurrency 16,
 #                         parity envelope, batched build fold, zero
 #                         post-warmup compiles) — refreshes benchmarks/
-#                         whatif_bench.json; the on-chip numbers ride
-#                         benchmarks/tpu_queue.sh whatif_surface
+#                         whatif_bench.json; not measured on the chip
 #   make quant-bench      the quantized-serving gate (int8 weight tree
 #                         >=3.5x smaller than f32, serving drift inside
 #                         the pinned parity envelope, executable count
 #                         flat across off/int8/bf16 and frozen
 #                         post-warmup) — refreshes benchmarks/
-#                         quant_bench.json; the on-chip bandwidth win
-#                         rides benchmarks/tpu_queue.sh quant_serve
+#                         quant_bench.json; a bandwidth win is not
+#                         measured on the chip
 #   make fleet-bench      the multi-tenant serving gate (100 apps, one
 #                         executable plane: zero post-warmup compiles,
 #                         bit-exact LRU spill/restore, byte-checked
 #                         tenant isolation, AOT cold start beating
 #                         compile-from-scratch) — refreshes benchmarks/
-#                         fleet_bench.json; the on-chip cold-start and
-#                         restore numbers ride benchmarks/tpu_queue.sh
-#                         fleet_serve
+#                         fleet_bench.json; cold start and restore are
+#                         not measured on the chip
 #   make wire-bench       the span-firehose ingestion gate (push wire vs
 #                         tailer-poll spans/sec at F=10240 sparse, >=10x
 #                         asserted; overload storm with the drop/
 #                         backpressure accounting identity; wire-vs-
 #                         tailer training bit-parity + zero post-warmup
 #                         compiles) — refreshes benchmarks/
-#                         wire_bench.json; host-CPU-bankable, the
-#                         tpu_queue.sh wire_ingest step re-banks it on
-#                         the pod host alongside the device steps
+#                         wire_bench.json; the wire tier runs on the
+#                         host CPU, so this is its real measurement
 
 PYTHON ?= python
 
@@ -104,6 +101,9 @@ lint-sarif:
 lint-gate:
 	bash deploy/ci/lint-gate.sh
 
+chip-smoke:
+	$(PYTHON) chip_smoke.py
+
 native:
 	$(MAKE) -C native
 
@@ -111,7 +111,7 @@ tsan:
 	$(MAKE) -C native tsan
 
 bench-multichip:
-	$(PYTHON) bench.py --mesh --quick --out MULTICHIP_r06.json
+	$(PYTHON) bench.py --mesh --virtual --quick --out MULTICHIP_r06.json
 
 serve-bench-replicas:
 	$(PYTHON) benchmarks/serve_bench.py --out benchmarks/serve_bench.json
@@ -140,7 +140,7 @@ fleet-bench:
 wire-bench:
 	$(PYTHON) benchmarks/wire_bench.py --out benchmarks/wire_bench.json
 
-.PHONY: lint lint-changed lint-fix lint-sarif lint-gate native tsan \
+.PHONY: lint lint-changed lint-fix lint-sarif lint-gate chip-smoke native tsan \
 	bench-multichip serve-bench-replicas obs-bench tenk-bench \
 	chaos-bench drift-bench whatif-bench quant-bench fleet-bench \
 	wire-bench

@@ -30,8 +30,8 @@ Gates (asserted, and recorded in the committed
 
 Honest-CPU note: every replica shares one host core here, so
 throughput/latency numbers are plumbing proofs; worker reboot time is
-dominated by the child's jax import (~5-15 s cold).  The on-chip storm
-rides benchmarks/tpu_queue.sh (``chaos_storm`` step).
+dominated by the child's jax import (~5-15 s cold).  Not measured on the
+chip.
 """
 
 from __future__ import annotations
@@ -605,6 +605,13 @@ def main(argv=None) -> int:
 
     import jax
 
+    if "process" in wanted and jax.default_backend() != "cpu":
+        # a chip belongs to one process: this parent holds the host's
+        # chips by now, and worker processes that need one cannot boot
+        ap.error(f"the process arm spawns workers that need the "
+                 f"{jax.default_backend()} this process already holds; run "
+                 "it with JAX_PLATFORMS=cpu, or drop it: --arms "
+                 "thread,elastic")
     _warm_multiprocessing()
     quick = bool(args.quick)
     # recovery on CPU is dominated by the worker reboot's jax import

@@ -26,8 +26,8 @@ into one fused-engine executable set:
   loading the sidecar (``compile_fallbacks`` must stay 0).  Honest-CPU
   footnote: CPU compiles of these graphs take fractions of a second
   while TPU compiles take orders of magnitude longer, so the speedup
-  measured here UNDERSTATES the on-chip win (tpu_queue.sh fleet_serve
-  measures it where it matters).
+  measured here says nothing about the chip (not measured on the
+  chip).
 
 Run ``python benchmarks/fleet_bench.py --out benchmarks/fleet_bench.json``
 (the committed artifact; ``make fleet-bench``).  ``--quick`` is the
@@ -276,10 +276,8 @@ def measure_aot(make, traffic, quick: bool) -> dict:
                  and out["speedup"] >= gate)
     out["footnote"] = (
         "honest-CPU: XLA:CPU compiles these graphs in fractions of a "
-        "second, so the speedup measured here UNDERSTATES the win — "
-        "TPU compiles of the same ladder take orders of magnitude "
-        "longer while deserialization cost barely moves (tpu_queue.sh "
-        "fleet_serve measures the on-chip number)")
+        "second; the compile-vs-deserialize gap on the TPU is not "
+        "measured on the chip")
     return out
 
 

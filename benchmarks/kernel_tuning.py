@@ -19,7 +19,7 @@ to pick the production configuration of ops/pallas_gru.py:
   ONE recurrence, × LOOP_ORDER × STASH_GATES at production bf16 on TPU —
   plus the VMEM block-plan fit table at the fatter row counts.
 
-On a TPU the full on-chip sweep runs (rides benchmarks/tpu_queue.sh).  On
+On a TPU the full on-chip sweep runs (not measured on the chip yet).  On
 the CPU backend a reduced, honestly-labeled variant runs instead: the
 coalescing G sweep on the lax.scan recurrence (the production CPU path —
 real compute, the committed evidence for the coalesced row-fattening win)
@@ -254,16 +254,14 @@ BIDIR_DECISION = {
     "decision": "unfused (two gru_recurrence calls per layer) is the "
                 "production default; ops/gru.py BIDIR_FUSED=0 executes "
                 "the revert PERF.md committed to",
-    "decision_basis": "banked on-chip honest-sync headlines: round-3 "
-                      "unfused 122.0 steps/s vs round-4 fused 117.2 "
-                      "steps/s at production bf16 "
-                      "(benchmarks/bench_snapshot_r3.json, "
-                      "benchmarks/last_good_tpu.json); direction fusion "
+    "decision_basis": "two July 2026 builder runs on older kernels and "
+                      "another toolchain, not reproduced: unfused 122.0 "
+                      "steps/s vs fused 117.2 steps/s at production "
+                      "bf16; direction fusion "
                       "never demonstrated a win, and the round-11 "
                       "window coalescing attacks the same per-call "
                       "overhead with G x the row occupancy instead",
-    "reopen_with": "DEEPREST_GRU_BIDIR_FUSED=1 + this script on-chip "
-                   "(benchmarks/tpu_queue.sh)",
+    "reopen_with": "DEEPREST_GRU_BIDIR_FUSED=1 + this script on-chip",
 }
 
 

@@ -25,8 +25,8 @@ parity, and executable counts are properties of the graph):
   timing rides along as a collapse guard only.  Honest-CPU footnote:
   on the CPU backend per-leaf dispatch overhead dominates a memcpy of
   megabyte trees, so the wall-clock win here is a FRACTION of the
-  byte win; the byte ratio is what the TPU's host->HBM path realizes
-  (benchmarks/tpu_queue.sh quant_serve measures it on-chip).
+  byte win; the byte ratio is what a host->HBM path moves (not measured
+  on the chip).
 
 Throughput rides along un-gated except for collapse (int8 must stay
 within 2x of f32): on CPU the dequant multiply ADDS work per dispatch
@@ -193,8 +193,8 @@ def measure_coldstart(preds: dict, reps: int, quick: bool) -> dict:
     out["footnote"] = (
         "CPU backend: per-leaf dispatch overhead dominates megabyte "
         "memcpys, so wall-clock tracks the 3.9x byte win only loosely "
-        "here; the byte ratio is what the TPU host->HBM path realizes "
-        "(tpu_queue.sh quant_serve)")
+        "here; the byte ratio is what a host->HBM path moves (not "
+        "measured on the chip)")
     return out
 
 
@@ -220,9 +220,8 @@ def measure_throughput(preds: dict, feature_dim: int,
     out["ok"] = ratio >= THROUGHPUT_COLLAPSE
     out["footnote"] = (
         "honest-CPU: the dequant multiply ADDS work per dispatch on "
-        "CPU — the serving speedup is a weight-bandwidth property of "
-        "accelerators and is measured on-chip by tpu_queue.sh "
-        "quant_serve, never claimed from this number")
+        "CPU — a serving speedup on an accelerator is not measured on "
+        "the chip and is never claimed from this number")
     return out
 
 

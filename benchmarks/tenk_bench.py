@@ -12,8 +12,7 @@ Honest-measurement notes, in the repo's established style:
 
 - BYTES and RSS are deterministic on this 1-core CPU container even
   where timing is contended; the byte table is the headline, the CPU
-  steps/s and rps are plumbing proofs (the on-chip numbers ride
-  ``tpu_queue.sh tenk_vertical``).
+  steps/s and rps are plumbing proofs (not measured on the chip).
 - The month-scale RSS is measured on the SPARSE retained corpus
   (43 200 rows = 30 days of minutes actually allocated and touched); the
   dense ring's bytes at that scale (~3.4 GiB) are reported
@@ -230,7 +229,7 @@ def measure_train(rows: int = 200, capacity: int = F_10K,
                   k: int = NNZ_CAP, steps_cap: int | None = None) -> dict:
     """Fine-tune steps/s at F=10240, sparse vs dense staged feed, loss
     parity asserted.  Honest CPU: 1 core, contended — the number proves
-    the plumbing; tpu_queue.sh banks the chip."""
+    the plumbing; not measured on the chip."""
     import jax
 
     jax.config.update("jax_platforms", "cpu")

@@ -1105,7 +1105,11 @@ def cmd_serve(args) -> int:
             _time.sleep(args.deadline)
             server.stop()
         else:
-            server.serve_forever()
+            try:
+                server.serve_forever()
+            except KeyboardInterrupt:    # SIGINT is the operator's stop
+                pass
+            server.stop()
     finally:
         if autoscaler is not None:
             autoscaler.stop()
@@ -1783,9 +1787,14 @@ def build_parser() -> argparse.ArgumentParser:
                         "today's single-engine path")
     p.add_argument("--replica-mode", choices=("thread", "process"),
                    default="thread",
-                   help="replica isolation: in-process threads (default) "
-                        "or worker subprocesses that each rebuild the "
-                        "full stack from --ckpt-dir/--artifact")
+                   help="replica isolation: in-process threads (default; "
+                        "one process drives every chip of the host) or "
+                        "worker subprocesses that each rebuild the full "
+                        "stack from --ckpt-dir/--artifact.  A chip "
+                        "belongs to one process: on an accelerator this "
+                        "process has taken the chips by the time it "
+                        "starts workers, so 'process' is refused there; "
+                        "it is for the CPU tier")
     p.add_argument("--admission-depth", type=int, default=0, metavar="N",
                    help="max concurrently admitted requests across the "
                         "plane; beyond it (plus a same-size bounded wait "
@@ -2025,6 +2034,9 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv: list[str] | None = None) -> int:
     args = build_parser().parse_args(argv)
+    from deeprest_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     return args.fn(args)
 
 

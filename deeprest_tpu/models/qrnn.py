@@ -48,6 +48,8 @@ axis of ONE recurrence call.  Two hooks support that here:
 
 from __future__ import annotations
 
+from typing import Any
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -120,6 +122,10 @@ class QuantileGRU(nn.Module):
     """
 
     config: ModelConfig
+    # The (data, expert, model) mesh the caller shards params and batch
+    # over, if any: the pallas recurrence needs it (ops/gru.py wraps the
+    # kernel in shard_map); every other op is partitioned by GSPMD.
+    mesh: Any = None
 
     @nn.compact
     def __call__(self, x: jax.Array, *, deterministic: bool = True,
@@ -201,9 +207,11 @@ class QuantileGRU(nn.Module):
                 if layer == 0:
                     bwd = masked(bwd)
                 out = bidirectional_gru(cast(fwd), cast(bwd), out,
-                                        backend=cfg.rnn_backend)
+                                        backend=cfg.rnn_backend,
+                                        mesh=self.mesh)
             else:
-                out = gru(cast(fwd), out, backend=cfg.rnn_backend)
+                out = gru(cast(fwd), out, backend=cfg.rnn_backend,
+                          mesh=self.mesh)
             # layer 0 broadcasts [B,T,F] across experts; the output (and all
             # deeper layers) carry the expert axis: [E,B,T,D].
         # The post-RNN path stays in the model's compute dtype (bf16 for

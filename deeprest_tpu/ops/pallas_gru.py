@@ -39,12 +39,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 import os as _os
 
-# jax renamed pltpu.TPUCompilerParams → pltpu.CompilerParams; the fields
-# used here (dimension_semantics) exist under both names.  Resolve once so
-# the kernels trace on either side of the rename.
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 # Experts per kernel program: amortizes grid overhead while keeping
 # VMEM residency (W_hh alone is E_BLK * H * 3H * 4B).  Env-overridable
 # (DEEPREST_GRU_E_BLK) so on-chip sweeps can A/B without code edits.
@@ -64,12 +58,13 @@ E_BLK = int(_os.environ.get("DEEPREST_GRU_E_BLK", "8"))
 T_BLK = max(1, int(_os.environ.get("DEEPREST_GRU_T_BLK", "6")))
 # f32 sublane granularity — batch is padded up to this.
 _SUBLANE = 8
-# Scoped-VMEM budget for one kernel program's blocks (the hardware limit
-# is 16 MiB; headroom covers in-kernel temporaries the block math below
-# cannot see).  Blocks indexed by the sequential time grid are double-
-# buffered by the pallas pipeline and count twice.
+# Scoped-VMEM budget for one kernel program: the compiler's 16 MiB limit
+# less 1 MiB for Mosaic's own fixed scratch.  The per-expert byte models
+# below count everything else: blocks indexed by the sequential time grid
+# twice (the pallas pipeline double-buffers them), resident blocks once
+# (_resident), scratch, and the in-kernel temporaries (_temp_bytes).
 _VMEM_BUDGET = int(_os.environ.get("DEEPREST_GRU_VMEM_BUDGET",
-                                   str(14 << 20)))
+                                   str(15 << 20)))
 # Stash the pre-activation hidden-side gates (h·W_hh + b_hh) from the
 # training forward so the backward skips its recompute dot — per
 # expert-step that removes one [B,H]x[H,3H] MXU dot (~1/3 of the
@@ -100,21 +95,29 @@ def _checked_loop_order() -> str:
 _checked_loop_order()   # fail fast on a bad env var at import too
 
 
-def _choose_blocks(e: int, t: int, per_expert_bytes) -> tuple[int, int]:
-    """Pick (e_blk, t_blk) whose block footprint fits the scoped-VMEM
-    budget.
-
-    The f32 backward kernel at the default E_BLK=8/T_BLK=6 needs ~18 MB
-    of double-buffered blocks — over the chip's 16 MiB scoped-VMEM limit
-    (observed on v5e as a hard compile OOM) — while the bf16 production
-    path fits.  The expert axis is the sublane of the 2-D f32 bias
-    blocks, so pallas requires e_blk % 8 == 0 (or e_blk == e); the time
-    axis is grid-leading and unconstrained, so VMEM pressure is relieved
-    by shrinking t_blk.  ``per_expert_bytes`` maps t_blk → bytes per
-    expert.  Correctness is unaffected (experts independent; the kernels
-    carry hidden state across time blocks in scratch)."""
+def _legal_blocks(e: int, t: int) -> tuple[list[int], list[int]]:
+    """Legal expert blocks (ascending) and time blocks (descending)."""
     legal_e = [c for c in range(_SUBLANE, e + 1, _SUBLANE)
                if e % c == 0 and c <= E_BLK] or [e]
+    t_candidates = [c for c in range(min(T_BLK, t), 0, -1) if t % c == 0]
+    return legal_e, t_candidates
+
+
+def _choose_blocks(e: int, t: int, per_expert_bytes,
+                   shape: str = "") -> tuple[int, int]:
+    """Pick (e_blk, t_blk) whose footprint fits the scoped-VMEM budget,
+    or raise when none does.
+
+    The f32 backward kernel at the default E_BLK=8/T_BLK=6 needs ~21 MB
+    — over the chip's 16 MiB scoped-VMEM limit, a hard compile error —
+    while the bf16 production path fits.  The expert axis is the sublane
+    of the 2-D f32 bias blocks, so pallas requires e_blk % 8 == 0 (or
+    e_blk == e); the time axis is grid-leading and unconstrained, so VMEM
+    pressure is relieved by shrinking t_blk.  ``per_expert_bytes`` maps
+    t_blk → bytes per expert; ``shape`` names the call in the error.
+    Correctness is unaffected (experts independent; the kernels carry
+    hidden state across time blocks in scratch)."""
+    legal_e, t_candidates = _legal_blocks(e, t)
     if E_BLK % _SUBLANE and E_BLK < e:
         import warnings
 
@@ -122,20 +125,27 @@ def _choose_blocks(e: int, t: int, per_expert_bytes) -> tuple[int, int]:
             f"DEEPREST_GRU_E_BLK={E_BLK} is not a multiple of {_SUBLANE} "
             f"(the sublane of the 2-D f32 bias blocks) — pallas cannot "
             f"tile it; using e_blk={legal_e[-1]} instead", stacklevel=3)
-    t_candidates = [c for c in range(min(T_BLK, t), 0, -1) if t % c == 0]
     # Prefer the widest expert block; shrink time first, then experts.
     for e_blk in reversed(legal_e):
         for t_blk in t_candidates:
             if e_blk * per_expert_bytes(t_blk) <= _VMEM_BUDGET:
                 return e_blk, t_blk
-    import warnings
+    e_min, t_min = legal_e[0], t_candidates[-1]
+    raise ValueError(
+        f"GRU kernel {shape or f'E={e} T={t}'} does not fit scoped VMEM: "
+        f"the smallest plan (e_blk={e_min}, t_blk={t_min}) needs "
+        f"{e_min * per_expert_bytes(t_min)} bytes against a budget of "
+        f"{_VMEM_BUDGET}; use fewer rows per call, or bfloat16")
 
-    warnings.warn(
-        f"GRU kernel block footprint exceeds the scoped-VMEM budget even "
-        f"at ({legal_e[0]}, 1) — compile may OOM; raise "
-        f"DEEPREST_GRU_VMEM_BUDGET only if the chip allows it",
-        stacklevel=3)
-    return legal_e[0], t_candidates[-1]
+
+def _resident(block_shape):
+    """BlockSpec of a block indexed by the expert grid axis only (W_hh,
+    b_hh, h0 in; dW/db/dh0 out).  It changes once per expert block, so it
+    is single-buffered: the pipeline's default second buffer would cost
+    VMEM (2.4 MB in the bf16 backward at e_blk=8) and hide no DMA."""
+    zeros = (0,) * (len(block_shape) - 1)
+    return pl.BlockSpec(block_shape, lambda i, j: (i, *zeros),
+                        pipeline_mode=pl.Buffered(1))
 
 
 def _gates(xproj, gates_h):
@@ -229,6 +239,18 @@ def _out_dtype_for(proj_dtype):
     return jnp.bfloat16 if proj_dtype == jnp.bfloat16 else jnp.float32
 
 
+def _temp_bytes(b, g3, h, proj_dtype):
+    """In-kernel temporaries per expert that Mosaic keeps in VMEM beside
+    the blocks: the f32 gate pre-activations ``[B, 3H]`` of every expert
+    of the program are in flight at once, and the bf16-dot path adds the
+    ``[B, H]`` casts of the carry.  Fitted to what the v5e compiler
+    reports (jax 0.9.0, libtpu 0.0.34) at B = 32..256, t_blk = 1..6; with
+    the 1 MiB kept out of ``_VMEM_BUDGET`` it bounds every reading from
+    above.  tests/test_chip_compile.py holds it to the compiler."""
+    casts = b * h * 4 if _dot_dtype_for(proj_dtype) == jnp.bfloat16 else 0
+    return b * g3 * 4 + casts
+
+
 def _fwd_per_expert_bytes(b, g3, h, proj_dtype, stash, n_h_out,
                           w_itemsize, h0_itemsize):
     """Forward-kernel VMEM bytes per expert as a function of t_blk — the
@@ -242,6 +264,7 @@ def _fwd_per_expert_bytes(b, g3, h, proj_dtype, stash, n_h_out,
              + (t_blk * b * g3 * io if stash else 0))
         + h * g3 * w_itemsize + g3 * 4                   # W_hh, b_hh resident
         + b * h * h0_itemsize + b * h * 4                # h0 block + scratch
+        + _temp_bytes(b, g3, h, proj_dtype)
     )
 
 
@@ -254,7 +277,10 @@ def _fwd_call(proj, w_hh, b_hh, h0, interpret, emit_prev=False):
     n_h_out = 2 if emit_prev else 1
     per_expert = _fwd_per_expert_bytes(b, g3, h, proj.dtype, stash, n_h_out,
                                        w_hh.dtype.itemsize, h0.dtype.itemsize)
-    e_blk, t_blk = _choose_blocks(e, t, per_expert)
+    e_blk, t_blk = _choose_blocks(
+        e, t, per_expert,
+        f"forward{' (training)' if emit_prev else ''} "
+        f"E={e} T={t} B={b} H={h} {proj.dtype}")
     eb = e // e_blk
     grid = (eb, t // t_blk)
     h_spec = pl.BlockSpec((e_blk, t_blk, b, h), lambda i, j: (i, j, 0, 0))
@@ -276,14 +302,14 @@ def _fwd_call(proj, w_hh, b_hh, h0, interpret, emit_prev=False):
         grid=grid,
         in_specs=[
             pl.BlockSpec((e_blk, t_blk, b, g3), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((e_blk, h, g3), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((e_blk, g3), lambda i, j: (i, 0)),
-            pl.BlockSpec((e_blk, b, h), lambda i, j: (i, 0, 0)),
+            _resident((e_blk, h, g3)),
+            _resident((e_blk, g3)),
+            _resident((e_blk, b, h)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((e_blk, b, h), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
@@ -319,7 +345,14 @@ def _bwd_kernel(proj_ref, hprev_ref, *refs, dot_dtype, stash_gates,
     ws = [w_ref[i].astype(dot_dtype) for i in range(n_e)]
     bs = [b_ref[i].astype(jnp.float32) for i in range(n_e)]
     dhs = [dh_scr[i] for i in range(n_e)]
-    dbs = [db_scr[i] for i in range(n_e)]
+    hh = dh_scr.shape[-1]
+    # Bias-gradient accumulators, one [1, H] row per gate: Mosaic refuses a
+    # 1-D concat of the three [H] sums ("Input offsets outside of the first
+    # tile"), so they stay apart and land in their 128-aligned lane slices
+    # of db_scr at the end of the program, like dproj/dg below.
+    dbs = [[db_scr[i:i + 1, k * hh:(k + 1) * hh] for k in range(3)]
+           for i in range(n_e)]
+
     def step(i, tt):
         h_prev = hprev_ref[i, tt].astype(jnp.float32)
         if gates_in_ref is not None:
@@ -348,7 +381,6 @@ def _bwd_kernel(proj_ref, hprev_ref, *refs, dot_dtype, stash_gates,
         # directly in their 128-aligned lane slices of the output block
         # and the dgates stash (dot dtype — the SAME quantization the
         # old per-step dW dot applied).
-        hh = da_r.shape[-1]
         dproj_ref[i, tt, :, 0:hh] = da_r.astype(dproj_ref.dtype)
         dproj_ref[i, tt, :, hh:2 * hh] = da_z.astype(dproj_ref.dtype)
         dproj_ref[i, tt, :, 2 * hh:3 * hh] = dtanh.astype(dproj_ref.dtype)
@@ -362,9 +394,8 @@ def _bwd_kernel(proj_ref, hprev_ref, *refs, dot_dtype, stash_gates,
             dg_scr[i, tt], ws[i], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        dbs[i] = dbs[i] + jnp.concatenate(
-            [jnp.sum(da_r, axis=0), jnp.sum(da_z, axis=0),
-             jnp.sum(dhn, axis=0)])
+        for k, dgate in enumerate((da_r, da_z, dhn)):
+            dbs[i][k] = dbs[i][k] + jnp.sum(dgate, axis=0, keepdims=True)
 
     if loop_order == "time_inner":
         for i in range(n_e):               # experts OUTER: W_hh stays hot
@@ -387,7 +418,8 @@ def _bwd_kernel(proj_ref, hprev_ref, *refs, dot_dtype, stash_gates,
             preferred_element_type=jnp.float32,
         )
         dh_scr[i] = dhs[i]
-        db_scr[i] = dbs[i]
+        for k in range(3):
+            db_scr[i:i + 1, k * hh:(k + 1) * hh] = dbs[i][k]
 
     @pl.when(t == t_total - 1)  # last grid step == time 0: flush accumulators
     def _flush():
@@ -415,6 +447,7 @@ def _bwd_per_expert_bytes(b, g3, h, proj_dtype, stash, hp_io, do_io,
         + h * g3 * 4 + g3 * 4 + b * h * 4
         + b * h * 4 + h * g3 * 4 + g3 * 4
         + t_blk * b * g3 * dot_io
+        + _temp_bytes(b, g3, h, proj_dtype)
     )
 
 
@@ -426,7 +459,8 @@ def _bwd_call(proj, h_prev_all, gates_all, w_hh, b_hh, dout, interpret):
     per_expert = _bwd_per_expert_bytes(
         b, g3, h, proj.dtype, stash, h_prev_all.dtype.itemsize,
         dout.dtype.itemsize, w_hh.dtype.itemsize)
-    e_blk, t_blk = _choose_blocks(e, t, per_expert)
+    e_blk, t_blk = _choose_blocks(
+        e, t, per_expert, f"backward E={e} T={t} B={b} H={h} {proj.dtype}")
     eb = e // e_blk
     nb = t // t_blk
     grid = (eb, nb)
@@ -440,8 +474,8 @@ def _bwd_call(proj, h_prev_all, gates_all, w_hh, b_hh, dout, interpret):
         in_specs.append(pl.BlockSpec((e_blk, t_blk, b, g3), rev))
         operands.append(gates_all)
     in_specs += [
-        pl.BlockSpec((e_blk, h, g3), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((e_blk, g3), lambda i, j: (i, 0)),
+        _resident((e_blk, h, g3)),
+        _resident((e_blk, g3)),
         pl.BlockSpec((e_blk, t_blk, b, h), rev),
     ]
     operands += [w_hh, b_hh, dout]
@@ -452,9 +486,9 @@ def _bwd_call(proj, h_prev_all, gates_all, w_hh, b_hh, dout, interpret):
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((e_blk, t_blk, b, g3), rev),
-            pl.BlockSpec((e_blk, h, g3), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((e_blk, g3), lambda i, j: (i, 0)),
-            pl.BlockSpec((e_blk, b, h), lambda i, j: (i, 0, 0)),
+            _resident((e_blk, h, g3)),
+            _resident((e_blk, g3)),
+            _resident((e_blk, b, h)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((e, t, b, g3), proj.dtype),
@@ -468,7 +502,7 @@ def _bwd_call(proj, h_prev_all, gates_all, w_hh, b_hh, dout, interpret):
             pltpu.VMEM((e_blk, g3), jnp.float32),
             pltpu.VMEM((e_blk, t_blk, b, g3), _dot_dtype_for(proj.dtype)),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
@@ -602,12 +636,13 @@ def block_plan(e: int, t: int, b: int, h: int, dtype=jnp.float32,
             do_io=out_io, w_itemsize=w_itemsize)
         plans.append(("bwd", bwd_pe))
     worst = None
-    import warnings
-
     for _name, per_expert in plans:
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore")   # probe, not a compile site
+        try:
             e_blk, t_blk = _choose_blocks(e, t_pad, per_expert)
+        except ValueError:
+            # nothing fits: report the smallest legal plan, fits=False
+            legal_e, t_candidates = _legal_blocks(e, t_pad)
+            e_blk, t_blk = legal_e[0], t_candidates[-1]
         block_bytes = e_blk * per_expert(t_blk)
         entry = {
             "e_blk": e_blk, "t_blk": t_blk,

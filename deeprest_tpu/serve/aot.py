@@ -13,7 +13,9 @@ admission — deserializes every artifact whose manifest fingerprint
 matches the live engine and installs it into the engine's AOT dispatch
 table, so the first request compiles nothing; rungs with no loadable
 artifact fall back to the normal lazy jit compile and are counted
-loudly (the pool's compile-fallback counter).
+loudly (the pool's compile-fallback counter).  An artifact that matches
+the fingerprint and still does not load is an error: a plane that was told
+it cold-starts from artifacts must not turn into one that compiles.
 
 Three contracts keep this honest:
 
@@ -115,11 +117,23 @@ def _example_args(predictor, rung: int, sparse: bool):
     return (eng._params,) + (x,) + tail
 
 
-def _programs(eng):
-    out = [("dense", eng._jit)]
-    if eng._jit_sparse is not None:
-        out.append(("sparse", eng._jit_sparse))
-    return out
+_PROGRAM_OF = {"dense": "_program", "sparse": "_program_sparse"}
+
+
+def _kinds(eng) -> list[str]:
+    """The fused programs the engine serves: dense, and sparse when on."""
+    return ["dense", "sparse"] if eng.sparse_enabled else ["dense"]
+
+
+def _fresh_jit(program):
+    """``program`` under a jit of its own.  JAX keeps lowerings and
+    executables in memory per traced function, so the engine's own jit
+    would hand ``export_aot`` the executable its warm-up loaded from the
+    persistent cache; a new wrapper traces the same Python to the same HLO
+    and compiles it anew."""
+    import jax
+
+    return jax.jit(lambda *args: program(*args))
 
 
 def export_aot(predictor, checkpoint_dir: str,
@@ -133,6 +147,7 @@ def export_aot(predictor, checkpoint_dir: str,
     perturbs the zero-post-warmup-compiles ledger.
     """
     import jax
+    from jax.experimental.compilation_cache import compilation_cache
     from jax.experimental.serialize_executable import serialize
 
     eng = predictor.fused
@@ -140,29 +155,22 @@ def export_aot(predictor, checkpoint_dir: str,
     out_dir = aot_dir(checkpoint_dir)
     os.makedirs(out_dir, exist_ok=True)
     entries = []
+    programs = {kind: _fresh_jit(getattr(eng, _PROGRAM_OF[kind]))
+                for kind in _kinds(eng)}
     # Compile OUTSIDE the persistent compilation cache: a cache-hit
     # executable serializes as a thin reference to jit-compiled symbols
-    # ("Symbols not found" at deserialize time) instead of embedding its
-    # object code, and the artifact must be self-contained on any host.
-    # Disabling the flag is NOT enough: the cache keeps an in-memory
-    # layer, and a prior compile of the same program (the predictor's
-    # own warmup, with the cache live) leaves a cache-backed executable
-    # there that .compile() returns even with the flag off — reset it
-    # so the export compile is genuinely fresh.
+    # ("Function ... not found" when the loaded artifact first runs)
+    # instead of embedding its object code, and the artifact must be
+    # self-contained on any host.  Two layers have to be passed: the
+    # cache memoizes whether it is in use, so the flag is switched with a
+    # reset on either side; and each program is traced under a jit of its
+    # own (_fresh_jit).
     cache_was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except (ImportError, AttributeError):
-        # private API moved: exports still compile fresh whenever no
-        # prior cache-backed executable exists; load_aot's fallback
-        # path names any artifact that fails to deserialize
-        pass
+    compilation_cache.reset_cache()
     try:
         for rung in (tuple(rungs) if rungs is not None else eng.rungs):
-            for kind, jitted in _programs(eng):
+            for kind, jitted in programs.items():
                 args = _example_args(predictor, int(rung), kind == "sparse")
                 compiled = jitted.lower(*args).compile()
                 payload, _, _ = serialize(compiled)
@@ -173,6 +181,7 @@ def export_aot(predictor, checkpoint_dir: str,
                                 "file": fname, "bytes": len(payload)})
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
     manifest = {"fingerprint": fp, "entries": entries}
     with open(os.path.join(out_dir, MANIFEST_NAME), "w",
               encoding="utf-8") as f:
@@ -183,12 +192,17 @@ def export_aot(predictor, checkpoint_dir: str,
 def load_aot(predictor, checkpoint_dir: str) -> dict:
     """Load-or-compile at pool admission: deserialize every artifact
     whose fingerprint matches the live engine into the engine's AOT
-    dispatch table.  Never raises on artifact problems — a missing/
-    stale/corrupt artifact means that rung compiles lazily through the
-    normal jit path, and the result names every such fallback:
+    dispatch table.  A missing or stale artifact means that rung compiles
+    lazily through the normal jit path, and the result names every such
+    fallback:
 
     ``{"loaded": n, "fallback_rungs": [(kind, rung), ...],
        "reason": None | str, "bytes": total_payload_bytes}``
+
+    An artifact the manifest lists under a matching fingerprint that then
+    fails to deserialize raises ``RuntimeError`` naming the file.  Each
+    executable is bound to the engine's own device (``execution_devices``),
+    not to every device JAX sees: a replica owns one.
     """
     from jax.experimental.serialize_executable import deserialize_and_load
     import jax.tree_util as jtu
@@ -198,7 +212,7 @@ def load_aot(predictor, checkpoint_dir: str) -> dict:
     if eng is None:
         result["reason"] = "fused engine disabled"
         return result
-    want = [(kind, int(r)) for r in eng.rungs for kind, _ in _programs(eng)]
+    want = [(kind, int(r)) for r in eng.rungs for kind in _kinds(eng)]
     man_path = os.path.join(aot_dir(checkpoint_dir), MANIFEST_NAME)
     if not os.path.exists(man_path):
         result["reason"] = "no artifacts"
@@ -221,28 +235,31 @@ def load_aot(predictor, checkpoint_dir: str) -> dict:
         return result
     by_key = {(e["kind"], int(e["rung"])): e
               for e in manifest.get("entries", ())}
-    errors = []
+    device = next(iter(eng._carry0.devices()))
     for kind, rung in want:
         entry = by_key.get((kind, rung))
-        if entry is None:
+        path = (os.path.join(aot_dir(checkpoint_dir), entry["file"])
+                if entry is not None else None)
+        if path is None or not os.path.exists(path):
             result["fallback_rungs"].append((kind, rung))
             continue
+        with open(path, "rb") as f:
+            payload = f.read()
+        args = _example_args(predictor, rung, kind == "sparse")
+        _, in_tree = jtu.tree_flatten((args, {}))
+        # the program returns (out, carry): a 2-tuple of arrays
+        _, out_tree = jtu.tree_flatten((0.0, 0.0))
         try:
-            with open(os.path.join(aot_dir(checkpoint_dir),
-                                   entry["file"]), "rb") as f:
-                payload = f.read()
-            args = _example_args(predictor, rung, kind == "sparse")
-            _, in_tree = jtu.tree_flatten((args, {}))
-            # the program returns (out, carry): a 2-tuple of arrays
-            _, out_tree = jtu.tree_flatten((0.0, 0.0))
-            loaded = deserialize_and_load(payload, in_tree, out_tree)
-            eng._aot[(kind, rung)] = loaded
-            result["loaded"] += 1
-            result["bytes"] += len(payload)
-        except Exception as e:   # noqa: BLE001 — any artifact failure
-            # must degrade to a compile, never kill an admission
-            result["fallback_rungs"].append((kind, rung))
-            errors.append(f"{kind}_r{rung}: {type(e).__name__}: {e}")
-    if errors:
-        result["reason"] = "; ".join(errors[:4])
+            loaded = deserialize_and_load(payload, in_tree, out_tree,
+                                          execution_devices=[device])
+        except Exception as e:
+            raise RuntimeError(
+                f"AOT artifact {path} matches the engine fingerprint but "
+                f"does not load: {type(e).__name__}: {e}") from e
+        eng._aot[(kind, rung)] = loaded
+        result["loaded"] += 1
+        result["bytes"] += len(payload)
+    if result["fallback_rungs"]:
+        result["reason"] = "artifacts missing for " + ", ".join(
+            f"{kind}_r{rung}" for kind, rung in result["fallback_rungs"][:4])
     return result

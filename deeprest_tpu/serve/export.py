@@ -30,7 +30,7 @@ import numpy as np
 from jax import export as jexport
 
 from deeprest_tpu.data.windows import MinMaxStats
-from deeprest_tpu.models.qrnn import resolve_params
+from deeprest_tpu.models.qrnn import QuantileGRU, resolve_params
 from deeprest_tpu.serve.batcher import BatchedBackendMixin
 from deeprest_tpu.serve.fused import FusedInferenceMixin
 from deeprest_tpu.serve.predictor import Predictor
@@ -48,8 +48,16 @@ def export_predictor(pred: Predictor, directory: str) -> str:
     *normalized* windows ``[b, W, F] -> [b, W, E, Q]`` with ``b``
     symbolic, lowered for both cpu and tpu so one artifact serves on
     either; normalization/de-normalization are host-side (manifest).
+
+    The recurrence in the artifact is always the ``lax.scan``: the Mosaic
+    kernel is a TPU-only custom call that sizes its blocks from a static
+    row count, so it can be lowered neither for the cpu platform nor under
+    the symbolic ``b`` (on a TPU host ``rnn_backend="auto"`` would pick it
+    and the export would fail).  A CPU host has always exported the scan.
     """
     os.makedirs(directory, exist_ok=True)
+    portable = QuantileGRU(config=dataclasses.replace(
+        pred.model_config, rnn_backend="scan"))
     (b,) = jexport.symbolic_shape("b")
     spec = jax.ShapeDtypeStruct(
         (b, pred.window_size, pred.feature_dim), jnp.float32)
@@ -57,7 +65,7 @@ def export_predictor(pred: Predictor, directory: str) -> str:
     # time, so the artifact bakes the quantized-then-dequantized values —
     # the exported module reproduces the quantized numerics (and the
     # manifest carries the mode + its measured parity envelope below).
-    fn = jax.jit(lambda x: pred.model.apply(
+    fn = jax.jit(lambda x: portable.apply(
         # graftlint: disable=JX001 -- deliberate: the artifact's whole point is baking the trained params into the serialized module as constants; bit parity vs the in-process path is pinned by tests/test_export_serve.py
         {"params": resolve_params(pred.params)}, x, deterministic=True))
     exported = jexport.export(fn, platforms=_PLATFORMS)(spec)

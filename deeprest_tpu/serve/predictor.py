@@ -122,7 +122,8 @@ class Predictor(BatchedBackendMixin, FusedInferenceMixin):
                  sparse_feed: bool = False,
                  sparse_nnz_cap: int = 64,
                  quant: str = "off",
-                 quant_budget: dict | None = None):
+                 quant_budget: dict | None = None,
+                 mesh=None):
         # Quantized serving (round 22, ops/quantize.py): weight leaves
         # stored int8 (+f32 scales) or bf16, dequantized at use INSIDE
         # the jitted wrappers below via models.qrnn.resolve_params — the
@@ -135,7 +136,9 @@ class Predictor(BatchedBackendMixin, FusedInferenceMixin):
         if quant != "off":
             params = quant_ops.quantize_params(params, quant)
         self.params = params
-        self.model = QuantileGRU(config=model_config)
+        # ``mesh``: the serving mesh ``params`` are sharded over, if any
+        # (from_checkpoint's mesh_config) — the pallas recurrence needs it.
+        self.model = QuantileGRU(config=model_config, mesh=mesh)
         self.x_stats = x_stats
         self.y_stats = y_stats
         self.metric_names = list(metric_names)
@@ -504,6 +507,7 @@ class Predictor(BatchedBackendMixin, FusedInferenceMixin):
             sparse_nnz_cap=sparse_nnz_cap,
             quant=quant,
             quant_budget=quant_budget,
+            mesh=mesh,
         )
         if quant != "off" and quant_budget is None:
             import json

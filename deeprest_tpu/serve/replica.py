@@ -435,7 +435,12 @@ def _worker_main(spec: dict, conn) -> None:
     import os
     from concurrent.futures import ThreadPoolExecutor
 
-    os.environ.setdefault("JAX_PLATFORMS", spec.get("jax_platform", "cpu"))
+    # The worker's platform is the spec's, or else the parent's (the
+    # environment it inherits) — never a quiet CPU.  With a platform named,
+    # JAX fails the backend's start-up, and with it the boot, when it is
+    # not there: a worker that was to hold a chip does not serve without it.
+    if spec.get("jax_platform"):
+        os.environ["JAX_PLATFORMS"] = spec["jax_platform"]
     obs_on = bool(spec.get("obs"))
     if obs_on:
         from deeprest_tpu import obs

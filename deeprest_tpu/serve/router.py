@@ -461,9 +461,26 @@ class ReplicaRouter:
                       config: RouterConfig | None = None,
                       batching=None) -> "ReplicaRouter":
         """N worker-subprocess replicas from one spec (each child builds
-        and owns its full stack; see serve/replica.ProcessReplica)."""
+        and owns its full stack; see serve/replica.ProcessReplica).
+
+        A chip belongs to one process.  A parent whose JAX already holds
+        an accelerator keeps every local chip, and a worker that needs one
+        would fail or hang at start-up, so that arrangement is refused
+        here; it works when the parent stays on the CPU and the spec's
+        ``jax_platform`` gives the workers theirs."""
+        import jax
+
         from deeprest_tpu.serve.replica import ProcessReplica
 
+        held = jax.default_backend()
+        if held != "cpu" and spec.get("jax_platform", held) != "cpu":
+            raise RuntimeError(
+                f"process replicas need a {spec.get('jax_platform', held)} "
+                f"chip each, but this process already holds the host's "
+                f"{held} chips and a chip belongs to one process: use "
+                f"thread replicas (one process drives every chip), or "
+                f"start this process with JAX_PLATFORMS=cpu and name the "
+                f"workers' platform in the spec")
         if n < 1:
             raise ValueError(f"replica count {n} must be >= 1")
         config = config or RouterConfig()

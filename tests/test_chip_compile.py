@@ -1,0 +1,302 @@
+"""The main path's programs, compiled for a TPU v5e that is described and
+not attached (`on-chip-measurement` guide, section 2, third rehearsal).
+
+Nothing runs here: the chip's compiler (libtpu is installed) either accepts
+each program at its real width or raises what it would raise on the chip —
+a Mosaic lowering it does not implement, a kernel over the 16 MiB scoped
+VMEM limit, a kernel GSPMD cannot partition, a step that does not fit HBM.
+Interpret-mode tests can show none of these.  A pass is not a chip run;
+`chip_smoke.py` is.
+
+Only one process may hold libtpu, so the topology is described inside a
+fixture of THIS file, never at import: every xdist worker collects the same
+tests and only the worker that runs the file loads the library.  All of
+these tests live in this one file for the same reason.  Code that asks
+``jax.default_backend()`` still sees the CPU here, so the tests name the
+backend (``rnn_backend="pallas"``) and the accelerator's rung set
+themselves.
+"""
+
+import importlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from deeprest_tpu.config import Config, ModelConfig, TrainConfig
+from deeprest_tpu.data.windows import MinMaxStats
+from deeprest_tpu.models.qrnn import QuantileGRU, resolve_params
+from deeprest_tpu.ops import pallas_gru
+from deeprest_tpu.ops.densify import SparseBase
+from deeprest_tpu.ops.quantize import quantize_params
+from deeprest_tpu.parallel.mesh import AXES
+from deeprest_tpu.parallel.sharding import state_sharding
+from deeprest_tpu.serve.fused import FusedRolledEngine
+from deeprest_tpu.train.trainer import Trainer, TrainState
+
+# the module: `from deeprest_tpu.ops import gru` is the function of that name
+gru_ops = importlib.import_module("deeprest_tpu.ops.gru")
+
+E, F, H, W, B = 40, 512, 128, 60, 32      # the flagship geometry
+F_10K = 10240
+NNZ_CAP = 64
+HBM_BYTES = 16e9                          # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 — no libtpu, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip (the next one would warn
+    # and compile again), so the cache stays off around these tests.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return Mesh(np.asarray(topo.devices[:1]).reshape(1, 1, 1), AXES)
+
+
+def _on(mesh, tree, spec=P()):
+    """Shapes of ``tree``, placed on ``mesh`` (replicated by default)."""
+    sharding = NamedSharding(mesh, spec)
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _kernel_calls(compiled) -> int:
+    return len(re.findall(r'custom_call_target="tpu_custom_call"',
+                          compiled.as_text()))
+
+
+def _model_config(dtype="bfloat16", feature_dim=F) -> ModelConfig:
+    return ModelConfig(feature_dim=feature_dim, num_metrics=E, hidden_size=H,
+                       compute_dtype=dtype, rnn_backend="pallas")
+
+
+def _param_shapes(cfg: ModelConfig):
+    return jax.eval_shape(
+        lambda: QuantileGRU(config=cfg).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, W, cfg.feature_dim))
+        ))["params"]
+
+
+# ---------------------------------------------------------------------------
+# the recurrence kernel, forward and backward
+# ---------------------------------------------------------------------------
+
+
+def _gru_grad(mesh, dtype, rows, groups=None):
+    """Lowered ``jax.grad`` of a bidirectional pallas GRU at the flagship
+    shape, ``rows`` windows (``groups`` folds them as [G, B] first)."""
+    def shapes():
+        keys = jax.random.split(jax.random.PRNGKey(0))
+        return [gru_ops.init_gru_params(k, E, F, H, dtype) for k in keys]
+
+    fwd, bwd = (_on(mesh, p, P("expert")) for p in jax.eval_shape(shapes))
+    xshape = (rows, W, F) if groups is None else (groups, rows // groups,
+                                                  W, F)
+    x = _on(mesh, jax.ShapeDtypeStruct(xshape, dtype),
+            P("data") if groups is None else P(None, "data"))
+    run = (gru_ops.bidirectional_gru if groups is None
+           else gru_ops.bidirectional_gru_coalesced)
+    live = mesh if mesh.size > 1 else None
+
+    def loss(fwd, bwd, x):
+        out = run(fwd, bwd, x, backend="pallas", mesh=live)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(fwd, bwd, x)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_kernel_fwd_bwd_flagship(one_chip, dtype):
+    compiled = _gru_grad(one_chip, jnp.dtype(dtype), B).compile()
+    assert _kernel_calls(compiled) == 4        # 2 directions x (fwd, bwd)
+
+
+@pytest.mark.parametrize("stash,order", [(False, "expert_inner"),
+                                         (True, "time_inner"),
+                                         (False, "time_inner")])
+def test_kernel_env_variants_compile(one_chip, monkeypatch, stash, order):
+    """The non-default DEEPREST_GRU_STASH_GATES / _LOOP_ORDER settings (the
+    defaults are the case above) — kept only while the chip accepts them."""
+    monkeypatch.setattr(pallas_gru, "STASH_GATES", stash)
+    monkeypatch.setattr(pallas_gru, "LOOP_ORDER", order)
+    compiled = _gru_grad(one_chip, jnp.bfloat16, B).compile()
+    assert _kernel_calls(compiled) == 4
+
+
+def test_kernel_fused_bidirectional_compiles(one_chip, monkeypatch):
+    monkeypatch.setattr(gru_ops, "BIDIR_FUSED", True)
+    compiled = _gru_grad(one_chip, jnp.bfloat16, B).compile()
+    assert _kernel_calls(compiled) == 2        # both directions per call
+
+
+def test_kernel_coalesced_g4(one_chip):
+    """Window coalescing at G=4: 128 rows per recurrence dot, the widest
+    the bf16 training kernels fit (tests/test_coalesce.py block plan)."""
+    compiled = _gru_grad(one_chip, jnp.bfloat16, 4 * B, groups=4).compile()
+    assert _kernel_calls(compiled) == 4
+
+
+def test_kernel_f32_g4_has_no_plan_and_says_so(one_chip):
+    with pytest.raises(ValueError, match=r"backward E=40 T=60 B=128 H=128"):
+        _gru_grad(one_chip, jnp.float32, 4 * B, groups=4)
+
+
+@pytest.mark.parametrize("shape", [(4, 1, 1), (2, 2, 1)])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_kernel_under_mesh(topo, dtype, shape):
+    """shard_map over (data, expert): one kernel call per direction per
+    pass in each device's program, and no all-gather around it."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(shape), AXES)
+    compiled = _gru_grad(mesh, jnp.dtype(dtype), B).compile()
+    assert _kernel_calls(compiled) == 4
+    assert "all-gather" not in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# the fused serving program, every rung an accelerator builds
+# ---------------------------------------------------------------------------
+
+
+def _engine(cfg: ModelConfig, sparse: bool) -> FusedRolledEngine:
+    model = QuantileGRU(config=cfg)
+    stats_x = MinMaxStats(min=np.zeros((cfg.feature_dim,), np.float32),
+                          max=np.ones((cfg.feature_dim,), np.float32))
+    stats_y = MinMaxStats(min=np.zeros((E,), np.float32),
+                          max=np.ones((E,), np.float32))
+    return FusedRolledEngine(
+        lambda p, x: model.apply({"params": resolve_params(p)}, x,
+                                 deterministic=True),
+        stats_x, stats_y, W,
+        # what the engine picks by itself on an accelerator
+        page_windows=64, coalesce_pages=FusedRolledEngine.ACCEL_COALESCE_PAGES,
+        sparse_nnz_cap=NNZ_CAP if sparse else None,
+        feature_dim=cfg.feature_dim if sparse else None)
+
+
+def _fused_lowered(mesh, cfg, rung, sparse=False, quant="off"):
+    eng = _engine(cfg, sparse)
+    assert rung in eng.rungs
+    params = _param_shapes(cfg)
+    if quant != "off":
+        params = jax.eval_shape(lambda p: quantize_params(p, quant), params)
+    f32, i32 = jnp.float32, jnp.int32
+    sds = jax.ShapeDtypeStruct
+    if sparse:
+        x = (sds((rung, W, NNZ_CAP), i32), sds((rung, W, NNZ_CAP), f32))
+        program = eng._program_sparse
+    else:
+        x = (sds((rung, W, cfg.feature_dim), f32),)
+        program = eng._program
+    args = (params, *x, eng._x_mn, eng._x_rg, eng._y_mn, eng._y_rg,
+            eng._carry0, sds((rung,), i32), sds((rung,), jnp.bool_),
+            sds((), i32), sds((), jnp.bool_))
+    return jax.jit(program).lower(*_on(mesh, args))
+
+
+@pytest.mark.parametrize("rung,sparse,dtype,quant", [
+    (64, False, "bfloat16", "off"),
+    (64, True, "bfloat16", "off"),
+    (128, False, "bfloat16", "off"),
+    (192, False, "bfloat16", "off"),
+    (256, False, "bfloat16", "off"),
+    (256, True, "bfloat16", "off"),
+    (8, False, "bfloat16", "off"),
+    (128, False, "float32", "off"),
+    (256, False, "float32", "off"),
+    (128, False, "bfloat16", "int8"),
+    (256, False, "float32", "int8"),
+])
+def test_fused_serve_program(one_chip, rung, sparse, dtype, quant):
+    compiled = _fused_lowered(one_chip, _model_config(dtype), rung,
+                              sparse=sparse, quant=quant).compile()
+    assert _kernel_calls(compiled) == 2        # one per direction
+
+
+def test_fused_serve_program_10k_sparse(one_chip):
+    compiled = _fused_lowered(one_chip, _model_config(feature_dim=F_10K),
+                              256, sparse=True).compile()
+    assert _kernel_calls(compiled) == 2
+
+
+# ---------------------------------------------------------------------------
+# the whole train step
+# ---------------------------------------------------------------------------
+
+
+def _train_step_lowered(mesh, feature_dim, sparse, accum_mode=None):
+    accum = 1 if accum_mode is None else 4
+    cfg = Config(model=_model_config(feature_dim=feature_dim),
+                 train=TrainConfig(batch_size=B, window_size=W,
+                                   grad_accum_windows=accum,
+                                   grad_accum_mode=accum_mode or "exact"))
+    trainer = Trainer(cfg, feature_dim, [f"m{i}" for i in range(E)],
+                      mesh=mesh)
+
+    def state():
+        rng = jax.random.PRNGKey(0)
+        params = dict(trainer.model.init(
+            rng, jnp.zeros((1, W, feature_dim)))["params"])
+        return TrainState(step=jnp.zeros((), jnp.int32), params=params,
+                          opt_state=trainer.tx.init(params), rng=rng)
+
+    shapes = jax.eval_shape(state)
+    state_sds = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        shapes, state_sharding(mesh, shapes))
+    t_len = 4096
+    sds = jax.ShapeDtypeStruct
+    if sparse:
+        base = SparseBase(cols=sds((t_len, NNZ_CAP), jnp.int32),
+                          vals=sds((t_len, NNZ_CAP), jnp.float32),
+                          mn=sds((feature_dim,), jnp.float32),
+                          rg=sds((feature_dim,), jnp.float32),
+                          capacity=feature_dim)
+    else:
+        base = sds((t_len, feature_dim), jnp.bfloat16)
+    y_base = sds((t_len, E), jnp.float32)
+    if accum == 1:
+        args = (base, y_base, sds((B,), jnp.int32), sds((B,), jnp.float32))
+        return trainer._train_step_indexed.lower(state_sds, *_on(mesh, args))
+    plan = (2, accum, B)
+    args = (base, y_base, sds(plan, jnp.int32), sds(plan, jnp.float32),
+            sds((), jnp.int32))
+    return trainer._accum_superstep.lower(state_sds, *_on(mesh, args))
+
+
+@pytest.mark.parametrize("feature_dim,sparse,accum_mode", [
+    (F, False, None),
+    (F, False, "exact"),
+    (F, False, "flat"),
+    (F_10K, True, None),
+])
+def test_train_step_fits_the_chip(one_chip, feature_dim, sparse, accum_mode):
+    """Forward, backward and Adam in one program, state donated: the
+    kernel is in it and arguments + temporaries fit 16 GB of HBM.  With an
+    ``accum_mode`` it is the G=4 window-coalesced superstep instead."""
+    compiled = _train_step_lowered(one_chip, feature_dim, sparse,
+                                   accum_mode).compile()
+    assert _kernel_calls(compiled) == 4
+    mem = compiled.memory_analysis()
+    need = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert need < HBM_BYTES, mem

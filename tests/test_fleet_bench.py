@@ -99,6 +99,6 @@ def test_committed_record_keeps_the_claim():
     assert rec["aot"]["speedup"] >= 1.5
     assert rec["aot"]["bit_identical_vs_compiled"]
     assert rec["aot"]["pool_admission"]["compile_fallbacks"] == 0
-    # the on-chip cold-start claim rides tpu_queue.sh fleet_serve, not
+    # the cold-start gap is not measured on the chip, and not claimed from
     # this CPU artifact — the footnote must say so
     assert "CPU" in rec["aot"]["footnote"]

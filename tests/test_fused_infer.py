@@ -17,6 +17,8 @@ Numerics contract pinned here (acceptance criteria of the fused pipeline):
 import numpy as np
 import pytest
 
+from conftest import assert_fold_equal
+
 from deeprest_tpu.config import ModelConfig
 from deeprest_tpu.data.windows import MinMaxStats
 from deeprest_tpu.serve import ExportedPredictor, Predictor, export_predictor
@@ -137,8 +139,9 @@ def test_fused_disabled_falls_back_to_reference():
 
 def test_fold_matches_per_series(pred_delta):
     """Folding several series into shared pages must not change results:
-    non-delta bit-exact (row-independent model + single-rung pages), the
-    per-series carry reset within the documented delta tolerance."""
+    non-delta equal (row-independent model; conftest.assert_fold_equal for
+    why a few ulp and not bits once the row count differs), the per-series
+    carry reset within the documented delta tolerance."""
     rng = np.random.default_rng(3)
     xs = [rng.random((t, F)).astype(np.float32)
           for t in (3 * W, 2 * W + 5, W, 9 * W + 1)]
@@ -146,7 +149,7 @@ def test_fold_matches_per_series(pred_delta):
     folded = pred_delta.predict_series_many(xs)
     assert [o.shape for o in folded] == [s.shape for s in singles]
     for singl, fold in zip(singles, folded):
-        np.testing.assert_array_equal(fold[:, ~DELTA], singl[:, ~DELTA])
+        assert_fold_equal(fold[:, ~DELTA], singl[:, ~DELTA])
         np.testing.assert_allclose(fold[:, DELTA], singl[:, DELTA],
                                    rtol=DELTA_RTOL, atol=0)
 
@@ -232,7 +235,8 @@ def test_page_windows_override():
     x = rng.random((7 * W + 4, F)).astype(np.float32)   # 8 windows → 3 pages
     ref = reference(pred, x)
     got = pred.predict_series(x)
-    np.testing.assert_array_equal(got[:, ~DELTA], ref[:, ~DELTA])
+    # pages of 3 rows against the reference's own batch: assert_fold_equal
+    assert_fold_equal(got[:, ~DELTA], ref[:, ~DELTA])
     np.testing.assert_allclose(got[:, DELTA], ref[:, DELTA],
                                rtol=DELTA_RTOL, atol=0)
     assert pred.fused.stats()["pages"] == 3

@@ -238,6 +238,17 @@ def test_unsupported_hidden_falls_back_to_scan():
                                rtol=1e-6, atol=1e-6)
 
 
+def test_no_fitting_plan_raises_naming_the_shape(monkeypatch):
+    """A shape with no plan inside the scoped-VMEM budget is an error that
+    names it, not a warning followed by a compile the chip refuses."""
+    from deeprest_tpu.ops import pallas_gru
+
+    monkeypatch.setattr(pallas_gru, "_VMEM_BUDGET", 1)
+    with pytest.raises(ValueError, match=r"E=8 T=12 B=8 H=128.*float32"):
+        params, x, _ = _setup(t=12)
+        gru(params, x, backend="pallas_interpret")
+
+
 @pytest.mark.slow
 def test_vmem_budget_shrinks_time_block(monkeypatch):
     """When the block footprint would exceed the scoped-VMEM budget, the
@@ -257,8 +268,13 @@ def test_vmem_budget_shrinks_time_block(monkeypatch):
     ref_l = float(loss("scan", x))
     g_ref = jax.grad(lambda x: loss("scan", x))(x)
 
-    monkeypatch.setattr(pallas_gru, "_VMEM_BUDGET", 1)
-    e_blk, t_blk = pallas_gru._choose_blocks(8, 12, lambda t: t * 10_000)
+    # E=3 pads to 8 experts, B=5 to 8 rows: admit the backward kernel (the
+    # larger of the two) at t_blk=1 and nothing wider.
+    per_expert = pallas_gru._bwd_per_expert_bytes(
+        8, 3 * H, H, jnp.float32, stash=pallas_gru.STASH_GATES, hp_io=4,
+        do_io=4, w_itemsize=4)
+    monkeypatch.setattr(pallas_gru, "_VMEM_BUDGET", 8 * per_expert(1))
+    e_blk, t_blk = pallas_gru._choose_blocks(8, 12, per_expert)
     assert t_blk == 1 and e_blk == 8      # shrank time, kept sublane-legal E
 
     np.testing.assert_allclose(float(loss("pallas_interpret", x)), ref_l,

@@ -282,3 +282,38 @@ def test_training_identical_with_and_without_prefetch(bundle):
                                           np.random.default_rng(0))
         losses[depth] = loss
     assert losses[0] == losses[3]      # prefetch must not change training
+
+
+def test_pallas_kernel_under_shard_map_matches_scan():
+    """The kernel under a mesh runs per device inside ``shard_map`` over
+    (data, expert) — GSPMD cannot partition a Mosaic call.  Values and
+    every gradient must match the unsharded scan: weight gradients come out
+    summed over ``data``, ragged rows (5 pads to 6 to divide over data=2,
+    then each device pads its 3 to the sublane) are padded and sliced, and
+    the ``model`` axis sees replicated operands."""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding
+
+    from deeprest_tpu.ops.gru import bidirectional_gru, init_gru_params
+
+    e, t, f, h = 4, 7, 11, 128
+    kf, kb, kx = jax.random.split(jax.random.PRNGKey(0), 3)
+    fwd, bwd = init_gru_params(kf, e, f, h), init_gru_params(kb, e, f, h)
+
+    def loss(fwd, bwd, x, backend, mesh):
+        out = bidirectional_gru(fwd, bwd, x, backend=backend, mesh=mesh)
+        return jnp.sum(jnp.sin(out))
+
+    mesh = make_mesh(MeshConfig(data=2, expert=2, model=2))
+    on_experts = NamedSharding(mesh, P("expert"))
+    put = lambda p: type(p)(*[jax.device_put(a, on_experts) for a in p])
+    sharded = jax.jit(lambda fwd, bwd, x: jax.value_and_grad(
+        loss, argnums=(0, 1, 2))(fwd, bwd, x, "pallas_interpret", mesh))
+    x = jax.random.normal(kx, (5, t, f), jnp.float32)
+    ref, g_ref = jax.value_and_grad(loss, argnums=(0, 1, 2))(
+        fwd, bwd, x, "scan", None)
+    val, g = sharded(put(fwd), put(bwd), x)
+    np.testing.assert_allclose(float(val), float(ref), rtol=1e-5)
+    for a, c in zip(jax.tree.leaves(g), jax.tree.leaves(g_ref)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                   rtol=2e-4, atol=2e-4)

@@ -87,7 +87,7 @@ def test_committed_record_keeps_the_claim():
         assert rec["parity"]["modes"][mode]["within_envelope"]
     assert rec["compiles"]["flat_across_modes"]
     assert rec["compiles"]["zero_post_warmup"]
-    # the on-chip speedup claim rides tpu_queue.sh quant_serve, not this
+    # a speedup is not measured on the chip, and not claimed from this
     # CPU artifact — the footnotes must say so
     assert "CPU" in rec["throughput"]["footnote"]
     assert "CPU" in rec["coldstart"]["footnote"]

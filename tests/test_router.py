@@ -664,6 +664,21 @@ def test_serve_help_covers_replica_flags(capsys):
 # Worker-subprocess replicas
 
 
+def test_process_replicas_refused_when_the_parent_holds_the_chip(monkeypatch):
+    """A chip belongs to one process: a parent on an accelerator cannot
+    start workers that need it, and says so before it spawns any."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spec = {"factory": "router_test_support:build_tiny"}
+    with pytest.raises(RuntimeError, match="a chip belongs to one process"):
+        ReplicaRouter.build_process(spec, 2)
+    # workers that the spec sends to the CPU need no chip: not refused
+    # (the count check is the next one, so nothing is spawned here either)
+    with pytest.raises(ValueError, match="replica count"):
+        ReplicaRouter.build_process({**spec, "jax_platform": "cpu"}, 0)
+
+
 def test_process_replica_same_interface_and_results(traffic):
     """One worker subprocess behind the replica interface: byte-identical
     predictions, outstanding accounting, clean shutdown."""
