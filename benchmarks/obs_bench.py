@@ -76,8 +76,12 @@ def _build_predictor():
         window_size=W, ladder=(8,))
 
 
-def _ab_rates(run_once, trials: int, units: int):
-    """Interleaved off/on trials → (off_rate, on_rate) medians."""
+def _ab_rates(run_once, trials: int, units: int, best: bool = False):
+    """Interleaved off/on trials → (off_rate, on_rate) medians; with
+    ``best`` (``--quick``) each side's fastest trial instead: the tier-1
+    smoke shares its cores with five other test workers, whose load only
+    ever slows a trial, and a median of three ~60 ms trials swings past
+    the smoke's budget on that alone."""
     from deeprest_tpu import obs
 
     rates = {False: [], True: []}
@@ -88,7 +92,8 @@ def _ab_rates(run_once, trials: int, units: int):
             run_once()
             rates[enabled].append(units / (time.perf_counter() - t0))
     obs.configure(enabled=False)
-    return (statistics.median(rates[False]), statistics.median(rates[True]))
+    pick = max if best else statistics.median
+    return pick(rates[False]), pick(rates[True])
 
 
 def _overhead_pct(off_rate: float, on_rate: float) -> float:
@@ -114,7 +119,8 @@ def measure_serve(quick: bool) -> dict:
 
     run_once()                                       # warm the jit cache
     obs.RECORDER.clear()
-    off, on = _ab_rates(run_once, trials=3 if quick else 5, units=calls)
+    off, on = _ab_rates(run_once, trials=3 if quick else 5, units=calls,
+                        best=quick)
     return {"off_calls_per_sec": round(off, 2),
             "on_calls_per_sec": round(on, 2),
             "windows_per_call": 20,
@@ -153,7 +159,8 @@ def measure_train(quick: bool) -> dict:
             state_box["state"], bundle, data_rng)
 
     run_once()                                       # warm the jit cache
-    off, on = _ab_rates(run_once, trials=3 if quick else 5, units=steps)
+    off, on = _ab_rates(run_once, trials=3 if quick else 5, units=steps,
+                        best=quick)
     return {"off_steps_per_sec": round(off, 2),
             "on_steps_per_sec": round(on, 2),
             "steps_per_epoch": steps,

@@ -405,27 +405,28 @@ def cmd_train(args) -> int:
     trainer = Trainer(cfg, bundle.feature_dim, bundle.metric_names)
 
     # ML-plane profiling (SURVEY.md §5.1: the reference has nothing beyond
-    # epoch prints; jax.profiler is the TPU-native equivalent).  The first
-    # epoch is captured — it includes compile + steady-state steps, which
-    # is what one inspects in TensorBoard/XProf.
-    profiling = False
-    if args.profile_dir:
-        import jax
+    # epoch prints).  --profile-dir traces the SECOND epoch (the first
+    # steady one; the first compiles) through Trainer.profile_epoch, which
+    # reads the trace back: the device's time by the named scopes of the
+    # train step, its idle gaps by the epoch's host phases.  The table is
+    # printed and written as layers.json beside the trace.
+    def report_profile():
+        table, trainer.last_profile = trainer.last_profile, None
+        if table is None:
+            return
+        import os
 
-        jax.profiler.start_trace(args.profile_dir)
-        profiling = True
+        from deeprest_tpu.obs.profiler import format_table
 
-    def stop_profiling():
-        nonlocal profiling
-        if profiling:
-            import jax
-
-            jax.profiler.stop_trace()
-            profiling = False
-            print(f"profiler trace written to {args.profile_dir}", flush=True)
+        path = os.path.join(args.profile_dir, "layers.json")
+        with open(path, "w") as fh:
+            json.dump(table, fh, indent=1)
+        print(format_table(table), flush=True)
+        print(f"profiler trace and {path} written to {args.profile_dir}",
+              flush=True)
 
     def on_epoch(result, state):
-        stop_profiling()    # first epoch captured: compile + steady steps
+        report_profile()
         line = (f"epoch {result.epoch}: train {result.train_loss:.4f}"
                 + (f" test {result.test_loss:.4f}" if result.test_loss else ""))
         print(line, flush=True)
@@ -445,18 +446,14 @@ def cmd_train(args) -> int:
         if resume:
             print(f"resuming preempted run from {args.ckpt_dir} "
                   "(newest cursor snapshot)", flush=True)
-    try:
-        if resume:
-            state, history = trainer.resume_training(
-                bundle, baseline_preds=baselines, on_epoch=on_epoch)
-        else:
-            state, history = trainer.fit(bundle, baseline_preds=baselines,
-                                         on_epoch=on_epoch)
-    finally:
-        # fit() may raise (or run zero epochs) before on_epoch could stop
-        # the trace — flush it anyway: the failing run is exactly the one
-        # worth profiling.
-        stop_profiling()
+    if resume:
+        state, history = trainer.resume_training(
+            bundle, baseline_preds=baselines, on_epoch=on_epoch,
+            profile_dir=args.profile_dir)
+    else:
+        state, history = trainer.fit(bundle, baseline_preds=baselines,
+                                     on_epoch=on_epoch,
+                                     profile_dir=args.profile_dir)
     if history:
         print(format_report(history[-1].report))
     else:
@@ -1224,8 +1221,9 @@ def cmd_profile(args) -> int:
     """Open a jax.profiler capture window on a RUNNING serving plane
     (POST /v1/profile — obs/profiler.py): the server keeps answering
     traffic on its other handler threads while the window is open, so
-    the trace shows the plane under its live load.  Inspect the written
-    directory with TensorBoard/XProf."""
+    the trace shows the plane under its live load.  The answer holds the
+    trace's directory and ``layers``: device busy and idle, the kernels'
+    time by name, the idle gaps under the serving spans that cover them."""
     import urllib.error
     import urllib.request
 
@@ -1554,8 +1552,9 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--ckpt-dir", default=None)
     p.add_argument("--plots-dir", default=None)
     p.add_argument("--profile-dir", default=None,
-                   help="capture a jax.profiler trace of the first epoch "
-                        "(inspect with TensorBoard/XProf)")
+                   help="trace the second epoch with jax.profiler, print "
+                        "its device time by layer and idle gaps by host "
+                        "phase, write layers.json beside the trace")
     p.add_argument("--report-every", type=int, default=0,
                    help="print the full MAE table every N epochs (0 = end only)")
     p.add_argument("--no-baselines", action="store_true")

@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 
 from deeprest_tpu.config import ModelConfig
+from deeprest_tpu.ops import scopes
 from deeprest_tpu.ops.gru import GRUParams, bidirectional_gru, gru
 
 MASK_PARAM_NAMES = ("mask_w1", "mask_b1", "mask_w2", "mask_b2")
@@ -80,6 +81,7 @@ def resolve_params(params):
     return dequantize_params(params)
 
 
+@jax.named_scope(scopes.MASK)
 def feature_mask(params) -> jax.Array:
     """The learned soft feature mask ``[E, F]`` from the mask parameters.
 
@@ -96,6 +98,12 @@ def feature_mask(params) -> jax.Array:
     return jax.nn.softmax(logits, axis=-1)                          # [E, F]
 
 
+@jax.named_scope(scopes.MASK)
+def _fold(mask: jax.Array, w_ih: jax.Array) -> jax.Array:
+    """``(x ⊙ m) @ W ≡ x @ (m ⊙ W)``: the one place the fold multiplies."""
+    return mask[:, :, None] * w_ih
+
+
 def fold_feature_mask(params):
     """Fold the soft mask into the layer-0 input weights, tree-level.
 
@@ -110,7 +118,7 @@ def fold_feature_mask(params):
     out = dict(params)
     for name in MASKED_PARAM_NAMES:
         if name in out:
-            out[name] = mask[:, :, None] * out[name]
+            out[name] = _fold(mask, out[name])
     return out
 
 
@@ -190,7 +198,7 @@ class QuantileGRU(nn.Module):
         def masked(p: GRUParams) -> GRUParams:
             if mask is None:
                 return p
-            return p._replace(w_ih=mask[:, :, None] * p.w_ih)
+            return p._replace(w_ih=_fold(mask, p.w_ih))
 
         def cast(p: GRUParams) -> GRUParams:
             return jax.tree.map(lambda a: a.astype(compute_dtype), p)
@@ -221,17 +229,19 @@ class QuantileGRU(nn.Module):
         # HBM.  All reductions still ACCUMULATE in f32 (the cross-expert
         # sum explicitly, the head dots via preferred_element_type);
         # only storage between ops is narrow.  f32 models are unchanged.
-        rnn_out = nn.Dropout(rate=cfg.dropout_rate)(
-            out, deterministic=deterministic
-        )
+        with jax.named_scope(scopes.DROPOUT):
+            rnn_out = nn.Dropout(rate=cfg.dropout_rate)(
+                out, deterministic=deterministic
+            )
 
         # (c) cross-expert mixing + per-metric quantile heads
         # (reference: qrnn.py:46-55), via the O(E) sum-minus-own identity.
         if e > 1:
-            total = jnp.sum(rnn_out.astype(jnp.float32), axis=0,
-                            keepdims=True)                            # [1,B,T,D]
-            mix = ((total - rnn_out.astype(jnp.float32)) / (e - 1)
-                   ).astype(compute_dtype)                            # [E,B,T,D]
+            with jax.named_scope(scopes.MIXING):
+                total = jnp.sum(rnn_out.astype(jnp.float32), axis=0,
+                                keepdims=True)                        # [1,B,T,D]
+                mix = ((total - rnn_out.astype(jnp.float32)) / (e - 1)
+                       ).astype(compute_dtype)                        # [E,B,T,D]
         else:
             mix = rnn_out
 
@@ -246,13 +256,14 @@ class QuantileGRU(nn.Module):
         k_d = 1.0 / d_in ** 0.5
         head_w = self.param("head_w", uniform_pm(k_d), (e, d_in, q))
         head_b = self.param("head_b", uniform_pm(k_d), (e, q))
-        hw = head_w.astype(compute_dtype)
-        preds = (jnp.einsum("ebtd,edq->ebtq", mix, hw[:, :d],
-                            preferred_element_type=jnp.float32)
-                 + jnp.einsum("ebtd,edq->ebtq", rnn_out, hw[:, d:],
-                              preferred_element_type=jnp.float32))
-        preds = preds + head_b[:, None, None, :]
-        preds = jnp.transpose(preds, (1, 2, 0, 3))                    # [B,T,E,Q]
+        with jax.named_scope(scopes.HEADS):
+            hw = head_w.astype(compute_dtype)
+            preds = (jnp.einsum("ebtd,edq->ebtq", mix, hw[:, :d],
+                                preferred_element_type=jnp.float32)
+                     + jnp.einsum("ebtd,edq->ebtq", rnn_out, hw[:, d:],
+                                  preferred_element_type=jnp.float32))
+            preds = preds + head_b[:, None, None, :]
+            preds = jnp.transpose(preds, (1, 2, 0, 3))                # [B,T,E,Q]
         if group_shape is not None:
             preds = preds.reshape(*group_shape, *preds.shape[1:])     # [G,B,T,E,Q]
         return preds

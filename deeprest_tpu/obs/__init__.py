@@ -1,6 +1,6 @@
 """deeprest_tpu/obs — spans, metrics, and profiling for the whole plane.
 
-One package, four surfaces (ISSUE 9):
+One package, four surfaces (ISSUE 9), one helper (ISSUE 24):
 
 - :mod:`.spans` — ring-buffer span recorder with request-scoped trace ids
   propagated router → admission → replica → batcher → fused dispatch
@@ -10,9 +10,13 @@ One package, four surfaces (ISSUE 9):
   Prometheus text at ``GET /metrics`` on the serving plane; the trainer /
   stream side emits step time, superstep dispatch counts, compile-cache
   sizes, ETL stall/lag, and readback counts into the same registry.
-- :mod:`.profiler` — on-demand ``jax.profiler`` capture windows
-  (``POST /v1/profile`` + ``deeprest profile``) and the honest-sync
-  step-time breakdown (host feed vs dispatch vs device wait).
+- :mod:`.profiler` — ``jax.profiler`` windows that are read back
+  (``POST /v1/profile`` + ``deeprest profile``, ``train --profile-dir``):
+  the device's time by the named scopes of the compiled step, its idle
+  gaps by the program span that covers them.
+- :mod:`.phases` — ``PhaseClock``: the phases of a repeated unit of host
+  work (the trainer's epoch) as spans and as a gauge of the last unit's
+  seconds per phase.
 - :mod:`.export` — spans as Jaeger-style JSON + span-derived busy-seconds
   as Prometheus range JSON, both consumed by the STANDARD ingest pipeline
   (data/ingest.py), so the plane's own traffic becomes a DeepRest corpus
@@ -33,6 +37,7 @@ from deeprest_tpu.obs.spans import (
     NULL_SPAN, RECORDER, SpanRecord, SpanRecorder, current_context,
     set_context, span,
 )
+from deeprest_tpu.obs.phases import PhaseClock
 
 
 def configure(enabled: bool | None = None,
@@ -50,6 +55,7 @@ def configure(enabled: bool | None = None,
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "Stopwatch",
+    "PhaseClock",
     "REGISTRY", "PROMETHEUS_CONTENT_TYPE",
     "SpanRecord", "SpanRecorder", "RECORDER", "NULL_SPAN",
     "span", "current_context", "set_context", "configure",

@@ -1,30 +1,59 @@
-"""On-demand jax.profiler capture windows + the step-time breakdown.
+"""``jax.profiler`` windows that are read, not only opened.
 
-Two tools:
+A trace alone names the device's time ``fusion.17``; the numbers after
+``fusion.`` change whenever the graph does.  This module joins three
+things the program owns into one table of layers:
 
-- :func:`capture` — a bounded ``jax.profiler`` trace window, one at a
-  time (a second concurrent request gets :class:`ProfilerBusy`).  The
-  serving plane mounts it at ``POST /v1/profile`` and ``deeprest
-  profile`` drives it over the wire: the handler keeps serving traffic on
-  the other threads while the window is open, so the trace captures the
-  plane under its real load.  Inspect with TensorBoard/XProf.
-- :func:`measure_step_breakdown` — where does a train step's wall time
-  go?  Built on bench.py measure_main's trial ledger: the only timed
-  edges are host readbacks of the updated params, and the ledger asserts
-  every trial closed with one (chip_smoke.py times the same steps closed
-  by ``jax.block_until_ready`` and prints both).  The breakdown splits per-step cost into host
-  feed (fresh window tensors staged to device), dispatch (the Python/jax
-  call returning), and device wait (dispatch edge → updated-params
-  readback completing).
+- **Scopes.**  The program names its own work: ``jax.named_scope`` s
+  inside a jitted function, ``name=`` on a ``pallas_call``
+  (ops/scopes.py holds the train step's).  This libtpu writes no scope
+  into the trace, but the compiled executable's text carries
+  ``op_name="jit(..)/transpose(jvp(..))/in_proj/dot_general"`` on every
+  instruction: :func:`scope_table` turns that text and the names the
+  caller gives it into ``{instruction: (scope, pass)}``.  No layer's name
+  is written here.
+- **The device's operations**, with their self time, from the trace
+  (``jax.profiler.ProfileData``, nothing else): :func:`layer_table` sums
+  them by ``(scope, pass)``.  A fusion is counted under its own label,
+  which XLA takes from one of the operations it fused; what else it holds
+  (:func:`fused_scopes`) is listed beside the row, because XLA does fuse
+  Adam's update of a weight into the matmul that makes its gradient.
+- **The program's spans.**  An enabled span (obs/spans.py) enters a
+  ``TraceAnnotation`` ``<component>/<name>``, so it lies on the trace's
+  clock; each idle gap of the device goes to the innermost program span
+  that covers its middle.
+
+Entries: :func:`capture` (``POST /v1/profile``, ``deeprest profile``) opens
+a window on a running plane and returns the table without a scope map
+(kernels by their names in the trace, idle gaps by the serving spans);
+``Trainer.profile_epoch`` (``train --profile-dir``) traces one steady epoch
+with the scope map of the program it dispatches.
+
+The arithmetic of busy, window and self time is the same as
+``chipbench/trace_reduce.py``'s, written again: the program may not import
+the yardstick.  tests/test_obs_layers.py holds the two to one answer on a
+recorded trace.
 """
 
 from __future__ import annotations
 
+import bisect
+import collections
+import contextlib
+import glob
 import os
+import re
 import threading
 import time
 
-import numpy as np
+OTHER = "other"
+# every component the program records spans under starts with this
+PROGRAM_SPAN_PREFIXES = ("deeprest",)
+UNATTRIBUTED = "unattributed"
+
+_KERNEL_MARK = 'custom_call_target="tpu_custom_call"'
+_OPS_LINE = "XLA Ops"
+_MODULES_LINE = "XLA Modules"
 
 _capture_lock = threading.Lock()
 
@@ -33,10 +62,28 @@ class ProfilerBusy(RuntimeError):
     """A capture window is already open (one at a time, by design)."""
 
 
+@contextlib.contextmanager
+def trace_window(out_dir: str):
+    """A ``jax.profiler`` window into ``out_dir`` with the Python tracer
+    off (the program's own spans name the host's time; Python frames
+    would only slow it)."""
+    import jax
+
+    os.makedirs(out_dir, exist_ok=True)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    with jax.profiler.trace(out_dir, profiler_options=options):
+        yield
+
+
 def capture(out_dir: str, seconds: float,
             max_seconds: float = 120.0) -> dict:
-    """Open a ``jax.profiler`` trace window for ``seconds`` and block
-    until it closes.  Returns ``{"trace_dir", "seconds"}``.
+    """Open a trace window for ``seconds``, block until it closes, read
+    it.  Returns ``{"trace_dir", "seconds", "layers"}``, ``layers`` being
+    :func:`layer_table` without a scope map.  A trace that cannot be read
+    (none written, a file ``ProfileData`` refuses) still answers with its
+    directory, ``layers`` null and the reason as ``layers_error``: the
+    window was opened, and the operator can open the trace elsewhere.
 
     Bounded (``max_seconds``) because the handler thread blocks for the
     window; concurrent captures fail fast with :class:`ProfilerBusy`
@@ -49,92 +96,330 @@ def capture(out_dir: str, seconds: float,
     if not _capture_lock.acquire(blocking=False):
         raise ProfilerBusy("a profiler capture window is already open")
     try:
-        import jax
-
-        os.makedirs(out_dir, exist_ok=True)
-        jax.profiler.start_trace(out_dir)
-        try:
+        with trace_window(out_dir):
             time.sleep(seconds)
-        finally:
-            jax.profiler.stop_trace()
+        out = {"trace_dir": os.path.abspath(out_dir), "seconds": seconds}
+        try:
+            out["layers"] = layer_table(out_dir)
+        except (OSError, ValueError, RuntimeError) as exc:
+            out["layers"] = None
+            out["layers_error"] = f"{type(exc).__name__}: {exc}"
     finally:
         _capture_lock.release()
-    return {"trace_dir": os.path.abspath(out_dir), "seconds": seconds}
+    return out
 
 
-def measure_step_breakdown(trainer, x, y, w, steps: int = 10,
-                           warmup: int = 2) -> dict:
-    """Per-step wall-time breakdown of ``trainer._train_step`` on the
-    host-feed path (the upper-bound feed cost; the staged path's feed
-    term is a [B] index ship and measures ~0).
+# -- the compiled program's text → scopes ----------------------------------
 
-    Phases, each closed by the honest-sync readback discipline:
+_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT )?%?(?P<name>[\w.\-]+) = .*? (?P<opcode>[a-z][a-z0-9\-]*)\(")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?(?P<name>[\w.\-]+) \(.*\{\s*$")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_TO_APPLY = re.compile(r"\bto_apply=%?([\w.\-]+)")
+# never a device event of their own
+_NO_EVENT = frozenset({"parameter", "constant", "get-tuple-element", "tuple",
+                       "bitcast"})
 
-    - ``host_feed``: staging the numpy batch onto the device
-      (``jax.device_put`` + readiness of the staged buffers).
-    - ``dispatch``: the jitted step call returning to Python (async
-      dispatch cost — what the host pays per step even when the device
-      is the bottleneck).
-    - ``device_wait``: from the last dispatch returning to the
-      updated-params element readback completing (device execution not
-      hidden behind dispatch).
 
-    The trial ledger asserts every timed phase ended in a host readback —
-    the same guard bench.py's ``timed_trial`` carries.
-    """
-    import jax
-    import jax.numpy as jnp
+def _scope_of(op_name: str, names) -> tuple[str, str]:
+    """``jit(step)/transpose(jvp(Model))/in_proj/dot_general`` →
+    ``("in_proj", "bwd")``: the innermost of ``names`` among the path's
+    components (transform wrappers opened up, the primitive at the end
+    left out), ``bwd`` under a ``transpose(``; ``(OTHER, "-")`` when the
+    path holds none of them."""
+    parts = [p for p in re.split(r"[/()]", op_name) if p][:-1]
+    known = [p for p in parts if p in names]
+    if not known:
+        return OTHER, "-"
+    return known[-1], "bwd" if "transpose(" in op_name else "fwd"
 
-    ledger = {"started": 0, "synced": 0}
 
-    def sync_params(state) -> None:
-        v = float(jnp.ravel(jax.tree.leaves(state.params)[0])[0])
-        if not np.isfinite(v):
-            raise RuntimeError(f"non-finite params in breakdown trial ({v})")
-        ledger["synced"] += 1
+def _parse_hlo(hlo_text: str):
+    """{computation: [(instruction, opcode, op_name, called computation)]}
+    plus the set of computations that run inside another instruction (a
+    fusion's body, a reduce's combiner)."""
+    computations, inner, current = {}, set(), None
+    for line in hlo_text.splitlines():
+        if not line.startswith(" "):
+            m = _COMPUTATION.match(line)
+            current = computations.setdefault(m["name"], []) if m else None
+            continue
+        m = _INSTRUCTION.match(line)
+        if current is None or not m:
+            continue
+        op_name = _OP_NAME.search(line)
+        calls = _CALLS.search(line) if m["opcode"] == "fusion" else None
+        inner.update(_TO_APPLY.findall(line))
+        if calls:
+            inner.add(calls[1])
+        current.append((m["name"], m["opcode"],
+                        op_name[1] if op_name else "",
+                        calls[1] if calls else None))
+    return computations, inner
 
-    state = trainer.init_state(x)
-    for _ in range(max(1, warmup)):
-        state, loss = trainer._train_step(
-            state, jnp.asarray(x), jnp.asarray(y), jnp.asarray(w))
-    sync_params(state)
-    ledger["started"] += 1          # warmup closes with a readback too
 
-    # host_feed: stage fresh batches and force their readiness with an
-    # element readback of the staged buffer (same primitive discipline).
-    ledger["started"] += 1
-    t0 = time.perf_counter()
-    staged = []
-    for _ in range(steps):
-        xb = jax.device_put(x)
-        yb = jax.device_put(y)
-        wb = jax.device_put(w)
-        staged.append((xb, yb, wb))
-    probe = float(jnp.ravel(staged[-1][0])[0])
-    if not np.isfinite(probe):
-        raise RuntimeError("non-finite staged feed probe")
-    ledger["synced"] += 1
-    host_feed_s = time.perf_counter() - t0
+def module_name(hlo_text: str) -> str | None:
+    """The name the trace's ``XLA Modules`` line gives this program's
+    executions (less the run's fingerprint in brackets)."""
+    m = re.match(r"HloModule ([\w.\-]+)", hlo_text)
+    return m[1] if m else None
 
-    # dispatch + device wait over the pre-staged batches.
-    ledger["started"] += 1
-    t1 = time.perf_counter()
-    for xb, yb, wb in staged:
-        state, loss = trainer._train_step(state, xb, yb, wb)
-    t2 = time.perf_counter()        # all steps dispatched
-    sync_params(state)              # the trial's closing readback
-    t3 = time.perf_counter()
 
-    assert ledger["started"] == ledger["synced"] == 3, ledger
-    per = 1e3 / steps
+def scope_table(hlo_text: str, names) -> dict[str, tuple[str, str]]:
+    """``{instruction: (scope, pass)}`` for every instruction of an
+    optimized HLO module's text (``jit(f).lower(..).compile().as_text()``)
+    that can be an event on the device: a fusion under its own label,
+    instructions under none of the scope and kernel ``names`` under
+    ``(OTHER, "-")``."""
+    computations, inner = _parse_hlo(hlo_text)
+    names = frozenset(names)
+    return {name: _scope_of(op_name, names)
+            for comp, instructions in computations.items() if comp not in inner
+            for name, opcode, op_name, _ in instructions
+            if opcode not in _NO_EVENT}
+
+
+def fused_scopes(hlo_text: str, names) -> dict[str, tuple[str, ...]]:
+    """``{fusion instruction: the other ones of ``names`` inside it}``, for
+    the fusions that hold operations of more scopes than their label
+    says."""
+    computations, inner = _parse_hlo(hlo_text)
+    names = frozenset(names)
+
+    def inside(comp: str) -> set[str]:
+        found = set()
+        for _, opcode, op_name, calls in computations.get(comp, ()):
+            if opcode in _NO_EVENT:     # a shared constant carries the
+                continue                # label of whoever made it first
+            found.add(_scope_of(op_name, names)[0])
+            if calls:
+                found |= inside(calls)
+        return found
+
+    out = {}
+    for comp, instructions in computations.items():
+        if comp in inner:
+            continue
+        for name, _, op_name, calls in instructions:
+            if calls:
+                extra = inside(calls) - {_scope_of(op_name, names)[0], OTHER}
+                if extra:
+                    out[name] = tuple(sorted(extra))
+    return out
+
+
+# -- the trace → the table -------------------------------------------------
+
+
+def find_xplane(trace_dir: str) -> str:
+    paths = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    if not paths:
+        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
+    return paths[-1]
+
+
+def read_planes(path: str):
+    """The trace as plain data: ``[(plane, [(line, [(event, start ns,
+    duration ns)])])]`` of the device and host planes."""
+    from jax.profiler import ProfileData
+
+    return [(plane.name,
+             [(line.name, [(ev.name, ev.start_ns, ev.duration_ns)
+                           for ev in line.events])
+              for line in plane.lines])
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith(("/device:", "/host:"))]
+
+
+def _self_times(events):
+    """[(text, start, self ns)] of events [(text, start, duration)] on one
+    line, where an event (a ``while``, a ``conditional``) may hold later,
+    shorter events inside it."""
+    out, stack = [], []                 # stack of [text, start, end, self]
+    for text, start, dur in sorted(events, key=lambda e: (e[1], -e[2])):
+        while stack and start >= stack[-1][2]:
+            top = stack.pop()
+            out.append((top[0], top[1], top[3]))
+        if stack:
+            stack[-1][3] -= min(dur, stack[-1][2] - start)
+        stack.append([text, start, start + dur, dur])
+    out += [(text, start, own) for text, start, _, own in stack]
+    return out
+
+
+def _merged(intervals):
+    """Sorted (start, end) intervals with the overlapping ones joined."""
+    out = []
+    for lo, hi in sorted(intervals):
+        if out and lo <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], hi)
+        else:
+            out.append([lo, hi])
+    return out
+
+
+def _covering_spans(gaps, spans):
+    """For each ``(lo, hi)`` gap, in time order, the name of the innermost
+    span ``(name, lo, hi)`` that covers its middle, else
+    :data:`UNATTRIBUTED`.  One sweep: a busy plane's window holds
+    thousands of spans and many more gaps."""
+    pending = sorted(spans, key=lambda s: -s[1])    # earliest start last
+    active = []
+    for lo, hi in gaps:
+        mid = (lo + hi) / 2
+        while pending and pending[-1][1] <= mid:
+            active.append(pending.pop())
+        active = [s for s in active if s[2] >= mid]
+        best = min(active, key=lambda s: s[2] - s[1], default=None)
+        yield best[0] if best else UNATTRIBUTED
+
+
+def _row_key(name: str, text: str, scopes) -> tuple[str, str]:
+    """The row of the operation ``name`` (its event's ``text``): through
+    the map when it is in it; a kernel the map does not hold under the
+    name its ``pallas_call`` gave it (the instruction's, less XLA's
+    ``.N``); else ``(OTHER, "-")``."""
+    if scopes and name in scopes:
+        return tuple(scopes[name])
+    if _KERNEL_MARK in text:
+        base, _, number = name.rpartition(".")
+        return (base if number.isdigit() else name), "-"
+    return OTHER, "-"
+
+
+def layer_table_of(planes, scopes=None, fused=None, module=None,
+                   span_prefixes=PROGRAM_SPAN_PREFIXES, steps=None) -> dict:
+    """:func:`layer_table` on what :func:`read_planes` gives.  Seconds are
+    means over the chips of the trace; a trace with no device operation (a
+    CPU run) gives ``chips: 0``, no seconds and no gaps, and still one row
+    per ``(scope, pass)`` of ``scopes``."""
+    device, spans = [], []
+    for plane, lines in planes:
+        by_line = dict(lines)
+        if plane.startswith("/device:"):
+            if by_line.get(_OPS_LINE):
+                device.append((by_line[_OPS_LINE],
+                               by_line.get(_MODULES_LINE, ())))
+        else:
+            spans += [(n, s, s + d) for events in by_line.values()
+                      for n, s, d in events
+                      if n.startswith(tuple(span_prefixes))]
+    fused = fused or {}
+    rows = collections.Counter(
+        {tuple(key): 0 for key in (scopes or {}).values()})
+    rows.setdefault((OTHER, "-"), 0)        # a row, never dropped
+    held = collections.defaultdict(collections.Counter)
+    gaps = collections.defaultdict(list)
+    busy_ns = window_ns = kernel_ns = 0
+    for events, modules in device:
+        # instruction names repeat across programs: the map holds for the
+        # operations that ran inside its own module's executions only
+        runs = _merged((s, s + d) for n, s, d in modules
+                       if n.split("(")[0] == module)
+        starts = [lo for lo, _ in runs]
+        busy = _merged((s, s + d) for _, s, d in events)
+        busy_ns += sum(hi - lo for lo, hi in busy)
+        window_ns += busy[-1][1] - busy[0][0]
+        for text, start, own in _self_times(events):
+            name = text.split(" = ", 1)[0].lstrip("%")
+            i = bisect.bisect_right(starts, start) - 1
+            mapped = module is None or (i >= 0 and start < runs[i][1])
+            key = _row_key(name, text, scopes if mapped else None)
+            rows[key] += own
+            if _KERNEL_MARK in text:
+                kernel_ns += own
+            for other in fused.get(name, ()) if mapped else ():
+                held[key][other] += own
+        idle = [(lo, hi) for (_, lo), (hi, _) in zip(busy, busy[1:])]
+        for (lo, hi), name in zip(idle, _covering_spans(idle, spans)):
+            gaps[name].append(hi - lo)
+    n = len(device)
+    per = 1e9 * max(n, 1)
+
+    def row(key, ns):
+        out = {"scope": key[0], "pass": key[1], "seconds": ns / per,
+               "share_of_busy": ns / busy_ns if busy_ns else 0.0}
+        if steps:
+            out["ms_per_step"] = 1e3 * ns / per / steps
+        if held.get(key):
+            out["in_fusions_that_also_hold"] = {
+                other: v / per for other, v in held[key].most_common()}
+        return out
+
     return {
+        "chips": n,
+        "window_s": window_ns / per,
+        "busy_s": busy_ns / per,
+        "idle_pct": (100.0 * (1.0 - busy_ns / window_ns) if window_ns
+                     else None),
+        "kernel_s": kernel_ns / per,
         "steps": steps,
-        "host_feed_ms_per_step": round(host_feed_s * per, 4),
-        "dispatch_ms_per_step": round((t2 - t1) * per, 4),
-        "device_wait_ms_per_step": round((t3 - t2) * per, 4),
-        "total_ms_per_step": round((host_feed_s + (t3 - t1)) * per, 4),
-        "ledger": dict(ledger),
+        "rows": [row(k, v) for k, v in sorted(
+            rows.items(), key=lambda kv: (-kv[1], kv[0]))],
+        "idle_gaps": [
+            {"span": name, "seconds": sum(g) / per, "gaps": len(g),
+             "longest_s": max(g) / 1e9}
+            for name, g in sorted(gaps.items(), key=lambda kv: -sum(kv[1]))],
     }
 
 
-__all__ = ["capture", "measure_step_breakdown", "ProfilerBusy"]
+def layer_table(trace_dir: str, scopes=None, fused=None, module=None,
+                span_prefixes=PROGRAM_SPAN_PREFIXES, steps=None) -> dict:
+    """The newest trace under ``trace_dir`` as a table of layers.
+
+    - ``window_s`` from the first to the last device operation, ``busy_s``
+      the union of the operations' intervals, ``idle_pct`` the rest;
+    - ``rows``: device self time (an operation's duration less what the
+      operations nested in it cover) summed by ``(scope, pass)`` through
+      ``scopes`` (:func:`scope_table`; it holds for the operations inside
+      the executions of ``module``, :func:`module_name`, when that is
+      given), a kernel the map does not hold by its own name; per row
+      seconds, share of busy, ms per step when ``steps`` is given, and the
+      seconds spent in fusions that also hold another scope's operations
+      (``fused``: :func:`fused_scopes`).  :data:`OTHER` (pass ``-``) is
+      ONE row, never dropped: the operations under none of the map's
+      names and those the map does not hold, so the rows sum to
+      ``busy_s``;
+    - ``kernel_s``: the ``tpu_custom_call`` events' self time, the
+      cross-check of the kernel rows;
+    - ``idle_gaps``: the gaps between device operations, summed under the
+      innermost program span (a ``TraceAnnotation`` whose name starts
+      with one of ``span_prefixes``) that covers their middle, else
+      :data:`UNATTRIBUTED`, with the count and the longest.
+    """
+    path = find_xplane(trace_dir)
+    table = layer_table_of(read_planes(path), scopes, fused, module,
+                           span_prefixes, steps)
+    table["trace"] = path
+    return table
+
+
+def format_table(table: dict) -> str:
+    """The table as the lines ``train --profile-dir`` prints."""
+    head = (f"device: {table['chips']} chip(s), window "
+            f"{table['window_s']:.4f} s, busy {table['busy_s']:.4f} s"
+            + ("" if table["idle_pct"] is None
+               else f", idle {table['idle_pct']:.2f}%"))
+    lines = [head, f"{'scope':<16}{'pass':<5}{'seconds':>10}{'ms/step':>10}"
+                   f"{'of busy':>9}  in fusions that also hold"]
+    for r in table["rows"]:
+        also = ", ".join(f"{k} {v:.4f} s" for k, v in
+                         r.get("in_fusions_that_also_hold", {}).items())
+        ms = f"{r['ms_per_step']:10.4f}" if "ms_per_step" in r else " " * 10
+        lines.append(f"{r['scope']:<16}{r['pass']:<5}{r['seconds']:10.4f}{ms}"
+                     f"{100 * r['share_of_busy']:8.2f}%  {also}")
+    for g in table["idle_gaps"]:
+        lines.append(f"idle under {g['span']}: {g['seconds']:.6f} s in "
+                     f"{g['gaps']} gap(s), longest {g['longest_s']:.6f} s")
+    for name, value in table.get("phases", {}).items():
+        lines.append(f"host phase {name}: {value:.6f} s")
+    return "\n".join(lines)
+
+
+__all__ = ["OTHER", "UNATTRIBUTED",
+           "PROGRAM_SPAN_PREFIXES", "ProfilerBusy", "capture",
+           "trace_window", "scope_table", "fused_scopes", "module_name",
+           "layer_table", "layer_table_of", "read_planes", "find_xplane",
+           "format_table"]

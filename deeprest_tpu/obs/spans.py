@@ -19,7 +19,15 @@ Cost discipline:
 - **Enabled**: one clock pair + one ring append per span, under the
   recorder lock only at commit (the ring is the ONLY shared mutable
   state; the enabled flag is deliberately never read or written under a
-  lock — a torn read costs at most one dropped/extra span).
+  lock — a torn read costs at most one dropped/extra span).  An enabled
+  span also enters a ``jax.profiler.TraceAnnotation`` named
+  ``<component>/<name>``: ``start_s`` is wall-clock and the duration
+  monotonic, neither of which is the profiler's clock, and the
+  annotation is what puts the span on the same timeline as the device's
+  operations when a profiler window is open (obs/profiler.py attributes
+  the device's idle gaps to these).  With no window open it is a flag
+  check.  jax is imported by the first enabled span, never at module
+  scope and never by a disabled one.
 
 Cross-boundary propagation:
 
@@ -73,6 +81,20 @@ _ID_COUNTER = itertools.count(1)
 
 def _new_id() -> str:
     return _ID_BASE + f"{next(_ID_COUNTER) & 0xFFFFFF:06x}"
+
+
+_profiler = None      # jax.profiler, once an enabled span has needed it
+
+
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` (the class is looked up per
+    call, so a test can patch it)."""
+    global _profiler
+    if _profiler is None:
+        import jax.profiler
+
+        _profiler = jax.profiler
+    return _profiler.TraceAnnotation(name)
 
 
 @dataclasses.dataclass
@@ -133,7 +155,7 @@ class ActiveSpan:
 
     __slots__ = ("_recorder", "name", "component", "tags", "trace_id",
                  "span_id", "parent_id", "start_s", "duration_s",
-                 "_t0", "_token")
+                 "_t0", "_token", "_annotation")
 
     def __init__(self, recorder: "SpanRecorder", name: str, component: str,
                  tags: dict | None, parent: tuple[str, str] | None):
@@ -152,6 +174,7 @@ class ActiveSpan:
         self.duration_s = 0.0
         self._t0 = 0.0
         self._token = None
+        self._annotation = _annotation(f"{component}/{name}")
 
     def tag(self, **kv) -> "ActiveSpan":
         self.tags.update(kv)
@@ -162,6 +185,7 @@ class ActiveSpan:
         return (self.trace_id, self.span_id)
 
     def __enter__(self) -> "ActiveSpan":
+        self._annotation.__enter__()
         self.start_s = time.time()
         self._t0 = time.perf_counter()
         self._token = _CTX.set((self.trace_id, self.span_id))
@@ -169,6 +193,7 @@ class ActiveSpan:
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.duration_s = time.perf_counter() - self._t0
+        self._annotation.__exit__(exc_type, exc, tb)
         if self._token is not None:
             _CTX.reset(self._token)
             self._token = None

@@ -31,8 +31,11 @@ from __future__ import annotations
 
 import numpy as np
 
+from deeprest_tpu.ops import scopes
+
 try:  # host-only callers (benchmarks, lint) may lack an initialized backend
     import flax.struct
+    import jax
     import jax.numpy as jnp
 
     _HAVE_JAX = True
@@ -86,6 +89,7 @@ if _HAVE_JAX:
         return jnp.where(rg == 0.0, x,
                          (x - mn) / jnp.where(rg == 0.0, 1.0, rg))
 
+    @jax.named_scope(scopes.DENSIFY)
     def gather_densify_normalize(base: "SparseBase", idx):
         """Window gather + densify + normalize for a staged sparse base:
         ``idx [..., W]`` start-expanded row indices → normalized dense

@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deeprest_tpu.ops import scopes
+
 _BACKENDS = ("auto", "scan", "pallas", "pallas_interpret")
 
 # Fused bidirectional (both directions stacked on the expert axis of ONE
@@ -115,10 +117,9 @@ def _gru_scan(
     # time-major for the scan.  A rank-3 ``x [B,T,F]`` is shared across all
     # experts without materializing E copies (the per-expert feature mask is
     # folded into w_ih by the caller instead — see models/qrnn.py).
-    if x.ndim == 3:
-        proj = jnp.einsum("btf,efg->tebg", x, params.w_ih) + params.b_ih[:, None, :]
-    else:
-        proj = jnp.einsum("ebtf,efg->tebg", x, params.w_ih) + params.b_ih[:, None, :]
+    eq = "btf,efg->tebg" if x.ndim == 3 else "ebtf,efg->tebg"
+    with jax.named_scope(scopes.IN_PROJ):
+        proj = jnp.einsum(eq, x, params.w_ih) + params.b_ih[:, None, :]
 
     def step(h, xproj):
         # xproj: [E,B,3H]; h: [E,B,H]
@@ -131,8 +132,9 @@ def _gru_scan(
         h_new = (1.0 - z) * n + z * h
         return h_new, h_new
 
-    _, outs = jax.lax.scan(step, h0, proj, reverse=reverse, unroll=unroll)
-    return jnp.moveaxis(outs, 0, 2)  # [T,E,B,H] -> [E,B,T,H]
+    with jax.named_scope(scopes.RECURRENCE):
+        _, outs = jax.lax.scan(step, h0, proj, reverse=reverse, unroll=unroll)
+        return jnp.moveaxis(outs, 0, 2)  # [T,E,B,H] -> [E,B,T,H]
 
 
 def _kernel_io_dtype(dtype) -> jnp.dtype:
@@ -149,8 +151,9 @@ def _project(params: GRUParams, x: jax.Array) -> jax.Array:
     """Hoisted input projection ``x @ W_ih + b_ih`` → [E, T, B, 3H] in the
     kernel's I/O dtype."""
     eq = "btf,efg->etbg" if x.ndim == 3 else "ebtf,efg->etbg"
-    proj = jnp.einsum(eq, x, params.w_ih) + params.b_ih[:, None, None, :]
-    return proj.astype(_kernel_io_dtype(proj.dtype))
+    with jax.named_scope(scopes.IN_PROJ):
+        proj = jnp.einsum(eq, x, params.w_ih) + params.b_ih[:, None, None, :]
+        return proj.astype(_kernel_io_dtype(proj.dtype))
 
 
 def _recur_local(projs, w_hhs, b_hhs, h0s, interpret: bool):
@@ -242,13 +245,16 @@ def _gru_pallas(
     pallas recurrence of ops/pallas_gru.py. Output matches the scan path's
     layout/time-alignment; see that module for the kernel design."""
     proj = _project(params, x)
-    if reverse:
-        proj = jnp.flip(proj, axis=1)
-    (h_all,) = _recurrence((proj,), (params.w_hh,), (params.b_hh,), (h0,),
-                           interpret, mesh)
-    if reverse:
-        h_all = jnp.flip(h_all, axis=1)
-    return jnp.moveaxis(h_all, 1, 2).astype(x.dtype)  # [E,B,T,H]
+    # the kernels carry their own names inside this scope; what is left
+    # under `recurrence` is the layout work around them
+    with jax.named_scope(scopes.RECURRENCE):
+        if reverse:
+            proj = jnp.flip(proj, axis=1)
+        (h_all,) = _recurrence((proj,), (params.w_hh,), (params.b_hh,),
+                               (h0,), interpret, mesh)
+        if reverse:
+            h_all = jnp.flip(h_all, axis=1)
+        return jnp.moveaxis(h_all, 1, 2).astype(x.dtype)  # [E,B,T,H]
 
 
 def gru(
@@ -420,14 +426,15 @@ def _bidir_pallas(
     invocation pipelines over.
     """
     e, b, h = fwd.w_ih.shape[0], x.shape[-3], fwd.hidden_size
-    proj_f = _project(fwd, x)
-    proj_b = jnp.flip(_project(bwd, x), axis=1)
-    h0 = jnp.zeros((e, b, h), jnp.float32)
-    out_f, out_b = _recurrence(
-        (proj_f, proj_b), (fwd.w_hh, bwd.w_hh), (fwd.b_hh, bwd.b_hh),
-        (h0, h0), interpret, mesh)
-    out = jnp.concatenate([out_f, jnp.flip(out_b, axis=1)], axis=-1)
-    return jnp.moveaxis(out, 1, 2).astype(x.dtype)      # [E,B,T,2H]
+    proj_f, proj_b = _project(fwd, x), _project(bwd, x)
+    with jax.named_scope(scopes.RECURRENCE):
+        proj_b = jnp.flip(proj_b, axis=1)
+        h0 = jnp.zeros((e, b, h), jnp.float32)
+        out_f, out_b = _recurrence(
+            (proj_f, proj_b), (fwd.w_hh, bwd.w_hh), (fwd.b_hh, bwd.b_hh),
+            (h0, h0), interpret, mesh)
+        out = jnp.concatenate([out_f, jnp.flip(out_b, axis=1)], axis=-1)
+        return jnp.moveaxis(out, 1, 2).astype(x.dtype)      # [E,B,T,2H]
 
 
 def bidirectional_gru(

@@ -37,6 +37,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deeprest_tpu.ops import scopes
+
 import os as _os
 
 # Experts per kernel program: amortizes grid overhead while keeping
@@ -313,6 +315,7 @@ def _fwd_call(proj, w_hh, b_hh, h0, interpret, emit_prev=False):
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
+        name=scopes.GRU_KERNEL_FWD,
     )(proj, w_hh, b_hh, h0)
 
 
@@ -506,6 +509,7 @@ def _bwd_call(proj, h_prev_all, gates_all, w_hh, b_hh, dout, interpret):
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
+        name=scopes.GRU_KERNEL_BWD,
     )(*operands)
     return dproj, dw, db, dh0
 

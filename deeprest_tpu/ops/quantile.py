@@ -12,7 +12,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from deeprest_tpu.ops import scopes
 
+
+@jax.named_scope(scopes.LOSS)
 def pinball_loss(
     preds: jax.Array,
     targets: jax.Array,

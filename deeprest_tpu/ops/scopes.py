@@ -1,0 +1,28 @@
+"""The names of the work inside the compiled train step.
+
+Every ``jax.named_scope`` of ops/, models/qrnn.py and train/trainer.py,
+and the ``name=`` of the two ``pallas_call`` s of ops/pallas_gru.py, is one
+of these constants, so a call site cannot name a scope this list lacks.
+``Trainer.profile_epoch`` hands :data:`STEP_SCOPES` + :data:`KERNELS` to
+obs/profiler.py, which knows no layer's name of its own and puts whatever
+carries none of the names it is given under ``other``.
+tests/test_obs_layers.py holds every name to the compiled superstep.
+"""
+
+GATHER = "gather"           # starts → idx → base[idx] (train/trainer.py)
+DENSIFY = "densify"         # ops/densify.py
+MASK = "mask"               # the feature mask and its fold (models/qrnn.py)
+IN_PROJ = "in_proj"         # the hoisted input projection (ops/gru.py)
+RECURRENCE = "recurrence"   # the scan, or the layout work round the kernels
+DROPOUT = "dropout"         # the step's key (trainer) and the mask (model)
+MIXING = "mixing"           # cross-expert mixing (models/qrnn.py)
+HEADS = "heads"             # the quantile heads (models/qrnn.py)
+LOSS = "loss"               # ops/quantile.py
+OPTIMIZER = "optimizer"     # tx.update + apply_updates (train/trainer.py)
+STEP_SCOPES = (GATHER, DENSIFY, MASK, IN_PROJ, RECURRENCE, DROPOUT, MIXING,
+               HEADS, LOSS, OPTIMIZER)
+
+# Not gru_fwd/gru_bwd: those are the two DIRECTIONS' parameter leaves.
+GRU_KERNEL_FWD = "gru_kernel_fwd"   # the forward pass's kernel
+GRU_KERNEL_BWD = "gru_kernel_bwd"   # the backward pass's kernel
+KERNELS = (GRU_KERNEL_FWD, GRU_KERNEL_BWD)

@@ -19,7 +19,8 @@ Routes (all JSON):
     POST /v1/whatif/scaling   {"baseline_traffic", "hypothetical_traffic"}
     POST /v1/whatif/surface   {"base_traffic", "scales"|"factor"}     → peaks
     POST /v1/anomaly          {"traffic", "observed", "tolerance"?, "min_run"?}
-    POST /v1/profile          {"seconds"?, "out_dir"?} → jax.profiler window
+    POST /v1/profile          {"seconds"?, "out_dir"?} → jax.profiler window,
+                              read back as `layers` (obs/profiler.py)
 
 Built on the stdlib ThreadingHTTPServer: one small dependency-free binary
 surface.  Concurrent requests do NOT each pay a device dispatch: the
@@ -578,7 +579,9 @@ class PredictionService:
         """On-demand ``jax.profiler`` capture window (``POST
         /v1/profile``): the handler blocks for the window while the other
         handler threads keep serving — the trace captures the plane under
-        its live load.  One window at a time (409 when busy)."""
+        its live load, and the answer's ``layers`` reads it back: device
+        busy and idle, kernels by name, idle gaps by the serving span
+        that covers them.  One window at a time (409 when busy)."""
         import tempfile
 
         from deeprest_tpu.obs import profiler

@@ -29,6 +29,8 @@ from deeprest_tpu.config import Config
 from deeprest_tpu.models.qrnn import QuantileGRU, fold_feature_mask
 from deeprest_tpu.obs import metrics as obs_metrics
 from deeprest_tpu.obs import spans as obs_spans
+from deeprest_tpu.obs.phases import PhaseClock
+from deeprest_tpu.ops import scopes
 from deeprest_tpu.ops.densify import SparseBase, gather_densify_normalize
 from deeprest_tpu.ops.quantile import pinball_loss
 from deeprest_tpu.parallel.distributed import (
@@ -44,6 +46,14 @@ from deeprest_tpu.parallel.mesh import (
 from deeprest_tpu.parallel.sharding import shard_params, state_sharding
 from deeprest_tpu.train.data import DatasetBundle, eval_window_indices
 from deeprest_tpu.train.metrics import Throughput, mae_report
+
+
+# The host's phases of one train epoch, in the order they happen
+# (`log_readback` inside `dispatch`).  `dispatch`, `log_readback` and
+# `device_wait` are waits on the device; during `plan_build`, `plan_h2d`
+# and `loss_readback` the device has nothing queued.
+EPOCH_PHASES = ("plan_build", "plan_h2d", "dispatch", "log_readback",
+                "device_wait", "loss_readback")
 
 
 @flax.struct.dataclass
@@ -81,6 +91,13 @@ class Trainer:
         # readback per epoch/superstep) — the superstep-vs-per-step parity
         # tests and callers that want the full curve read this.
         self._last_epoch_losses: np.ndarray | None = None
+        # (jitted program, its arguments after the state) of the most
+        # recent train_epoch's dispatches: profile_epoch lowers exactly
+        # this to name the trace's operations.
+        self._dispatched: tuple | None = None
+        # The table of the epoch fit(profile_dir=...) ran through
+        # profile_epoch, for the caller to print or write.
+        self.last_profile: dict | None = None
         # Preemption-safe snapshot state (enable_snapshots / ROADMAP item
         # 7 dynamic half).  The epoch-plan cursor lives here between the
         # fit loop (which pins the epoch index + the shuffle rng's
@@ -148,8 +165,17 @@ class Trainer:
 
         self._pin_state = jax.jit(pin_state)
 
+        @jax.named_scope(scopes.OPTIMIZER)
+        def apply_gradients(state: TrainState, grads):
+            updates, opt_state = self.tx.update(grads, state.opt_state)
+            return optax.apply_updates(state.params, updates), opt_state
+
+        @jax.named_scope(scopes.DROPOUT)
+        def dropout_key(state: TrainState):
+            return jax.random.fold_in(state.rng, state.step)
+
         def train_step(state: TrainState, xb, yb, wb):
-            dropout_rng = jax.random.fold_in(state.rng, state.step)
+            dropout_rng = dropout_key(state)
 
             def loss_fn(params):
                 preds = self.model.apply(
@@ -159,8 +185,7 @@ class Trainer:
                 return pinball_loss(preds, yb, quantiles, sample_weight=wb)
 
             loss, grads = jax.value_and_grad(loss_fn)(state.params)
-            updates, opt_state = self.tx.update(grads, state.opt_state)
-            params = optax.apply_updates(state.params, updates)
+            params, opt_state = apply_gradients(state, grads)
             return (
                 pin_state(TrainState(step=state.step + 1, params=params,
                                      opt_state=opt_state, rng=state.rng)),
@@ -179,15 +204,20 @@ class Trainer:
                 return gather_densify_normalize(x_base, idx)
             return x_base[idx]
 
+        @jax.named_scope(scopes.GATHER)
+        def gather_windows(x_base, y_base, starts):
+            w = self.config.train.window_size
+            idx = starts[:, None] + jnp.arange(w)[None, :]    # [B, W]
+            return gather_x(x_base, idx), y_base[idx]
+
         def train_step_indexed(state: TrainState, x_base, y_base, starts, wb):
             # Device-resident feed: the normalized BASE series live in HBM
             # (stage_dataset) and each step gathers its windows by start
             # index — per-step host→device traffic is [B] int32 + weights
             # instead of the [B,W,F] window tensor (windows overlap W−1 of
             # W rows, so materialized shipping re-sends every row W times).
-            w = self.config.train.window_size
-            idx = starts[:, None] + jnp.arange(w)[None, :]    # [B, W]
-            return train_step(state, gather_x(x_base, idx), y_base[idx], wb)
+            return train_step(state, *gather_windows(x_base, y_base, starts),
+                              wb)
 
         def train_superstep(state: TrainState, x_base, y_base,
                             starts_plan, weights_plan, chunk):
@@ -269,18 +299,13 @@ class Trainer:
         accum_g = int(self.config.train.grad_accum_windows)
         accum_mode = self.config.train.grad_accum_mode
 
-        def _gather_windows(x_base, y_base, starts):
-            w = self.config.train.window_size
-            idx = starts[:, None] + jnp.arange(w)[None, :]    # [B, W]
-            return gather_x(x_base, idx), y_base[idx]
-
         def _accum_grads_exact(params, x_base, y_base, starts, wb, step_key):
             folded, fold_vjp = jax.vjp(fold_feature_mask, params)
             keys = jax.vmap(lambda g: jax.random.fold_in(step_key, g))(
                 jnp.arange(accum_g))
 
             def micro(s, wb_g, key):
-                xb, yb = _gather_windows(x_base, y_base, s)
+                xb, yb = gather_windows(x_base, y_base, s)
 
                 def loss_fn(pf):
                     preds = self.model.apply(
@@ -301,7 +326,7 @@ class Trainer:
 
         def _accum_grads_flat(params, x_base, y_base, starts, wb, step_key):
             g, b = starts.shape
-            xb, yb = _gather_windows(x_base, y_base, starts.reshape(-1))
+            xb, yb = gather_windows(x_base, y_base, starts.reshape(-1))
             x4 = xb.reshape(g, b, *xb.shape[1:])
             y4 = yb.reshape(g, b, *yb.shape[1:])
 
@@ -323,7 +348,7 @@ class Trainer:
         def _accum_grads_loop(params, x_base, y_base, starts, wb, step_key):
             losses, total = [], None
             for g in range(accum_g):
-                xb, yb = _gather_windows(x_base, y_base, starts[g])
+                xb, yb = gather_windows(x_base, y_base, starts[g])
 
                 def loss_fn(params, g=g, xb=xb, yb=yb):
                     preds = self.model.apply(
@@ -345,11 +370,9 @@ class Trainer:
         def train_accum_update(state: TrainState, x_base, y_base, starts, wb):
             """One optimizer update from G coalesced microbatches.
             starts/wb: [G, B]."""
-            step_key = jax.random.fold_in(state.rng, state.step)
             losses, grads = _accum_grads(state.params, x_base, y_base,
-                                         starts, wb, step_key)
-            updates, opt_state = self.tx.update(grads, state.opt_state)
-            params = optax.apply_updates(state.params, updates)
+                                         starts, wb, dropout_key(state))
+            params, opt_state = apply_gradients(state, grads)
             n_real = jnp.sum(jnp.any(wb > 0, axis=1).astype(jnp.int32))
             return (
                 pin_state(TrainState(step=state.step + n_real, params=params,
@@ -392,9 +415,7 @@ class Trainer:
             return preds, loss
 
         def eval_step_indexed(params, x_base, y_base, starts):
-            w = self.config.train.window_size
-            idx = starts[:, None] + jnp.arange(w)[None, :]    # [n, W]
-            return eval_step(params, gather_x(x_base, idx), y_base[idx])
+            return eval_step(params, *gather_windows(x_base, y_base, starts))
 
         self._train_step = jax.jit(train_step, donate_argnums=0)
         self._train_step_indexed = jax.jit(train_step_indexed, donate_argnums=0)
@@ -421,6 +442,15 @@ class Trainer:
             "deeprest_train_readbacks_total",
             "designed device->host readbacks by sink",
             labelnames=("sink",))
+        self._epoch_clock = PhaseClock(
+            "train.epoch", "deeprest-trainer", EPOCH_PHASES,
+            last_seconds=obs_metrics.REGISTRY.gauge(
+                "deeprest_train_last_epoch_phase_seconds",
+                "the last finished epoch's host seconds by phase (a phase "
+                "inside another is timed exclusively)",
+                labelnames=("phase",)),
+            units_total=obs_metrics.REGISTRY.counter(
+                "deeprest_train_epochs_total", "train epochs finished"))
         self._m_executables = obs_metrics.REGISTRY.gauge(
             "deeprest_train_jit_executables",
             "compiled executables across the trainer's jitted programs "
@@ -687,7 +717,7 @@ class Trainer:
 
     def _run_epochs_elastic(self, bundle, state, data_rng, start_epoch,
                             skip_steps, baseline_preds, on_epoch,
-                            num_epochs, on_step):
+                            num_epochs, on_step, profile_dir=None):
         """THE fault barrier (the only sanctioned swallow point for the
         device-loss family — graftlint EX004 keeps it that way): run the
         epochs; on device loss, remesh + restore in-process and
@@ -707,7 +737,7 @@ class Trainer:
                 return self._run_epochs(bundle, state, data_rng,
                                         start_epoch, skip_steps,
                                         baseline_preds, on_epoch,
-                                        num_epochs, on_step)
+                                        num_epochs, on_step, profile_dir)
             except Exception as exc:
                 if not is_device_loss(exc):
                     raise
@@ -931,7 +961,17 @@ class Trainer:
         (the resumed epoch's mean is not comparable to the uninterrupted
         one — state parity is, and is what tests/test_chaos.py pins).
         ``on_step(global_step)`` fires at every real-step (superstep:
-        chunk) boundary — the chaos tests' preemption injection point."""
+        chunk) boundary — the chaos tests' preemption injection point.
+
+        The epoch's host work is timed by phase (:data:`EPOCH_PHASES`,
+        obs/phases.py): one ``train.epoch`` span with a child per phase
+        when the recorder is on, the phase-seconds counters always."""
+        with self._epoch_clock.unit() as phase:
+            return self._train_epoch(state, bundle, epoch_rng, staged,
+                                     skip_steps, on_step, phase)
+
+    def _train_epoch(self, state, bundle, epoch_rng, staged, skip_steps,
+                     on_step, phase) -> tuple[TrainState, float]:
         accum = self.config.train.grad_accum_windows
         if staged is None and bundle.is_sparse:
             raise ValueError(
@@ -952,7 +992,7 @@ class Trainer:
             s = self._superstep_len(num_steps)
             if s > 1:
                 return self._train_epoch_superstep(state, bundle, epoch_rng,
-                                                   staged, s,
+                                                   staged, s, phase,
                                                    skip_steps=skip_steps,
                                                    on_step=on_step)
         self._epoch_num_steps = -(-bundle.num_train_windows
@@ -985,7 +1025,7 @@ class Trainer:
 
             batches = prefetch_to_device(self.mesh, host_batches(),
                                          depth=self.config.train.prefetch_depth)
-            run = self._train_step
+            program, fixed = self._train_step, ()
         else:
             x_base, y_base = staged
 
@@ -1005,33 +1045,42 @@ class Trainer:
 
             batches = prefetch_to_device(self.mesh, index_batches(),
                                          depth=self.config.train.prefetch_depth)
-            run = lambda st, starts, wb: self._train_step_indexed(
-                st, x_base, y_base, starts, wb)
+            program, fixed = self._train_step_indexed, (x_base, y_base)
 
-        for batch in batches:
-            state, loss = run(state, *batch)
-            # Fault barrier probe BEFORE any bookkeeping: a device lost
-            # during this dispatch means the step never happened — the
-            # cursor must not advance past it and no snapshot may
-            # include it (the barrier restores the newest durable one).
-            self._fault_check(1)
-            losses.append(loss)
-            self._global_step += 1
-            if not self._warmed:
-                # The first step ever pays jit trace+compile; keep it out of
-                # the throughput window so steps/sec reflects steady state.
-                jax.block_until_ready(loss)
-                self._warmed = True
-                self.throughput.start()
-                measuring = True
-            else:
-                steps += 1
-            if log_every and self._global_step % log_every == 0:
-                self._m_readbacks.inc(sink="log_boundary")
-                # graftlint: disable=JX003 -- designed sink: one scalar readback per log_every steps, the logging contract
-                print(f"step {self._global_step}: loss {float(loss):.6f}")
-            self._note_steps(state, bundle, 1, on_step)
-        jax.block_until_ready(state.params)
+        # This driver feeds as it dispatches (the prefetch generator
+        # builds and ships each batch), so the whole loop is `dispatch`
+        # and `plan_build`/`plan_h2d` stay 0.
+        with phase("dispatch"):
+            for batch in batches:
+                state, loss = program(state, *fixed, *batch)
+                # Fault barrier probe BEFORE any bookkeeping: a device lost
+                # during this dispatch means the step never happened — the
+                # cursor must not advance past it and no snapshot may
+                # include it (the barrier restores the newest durable one).
+                self._fault_check(1)
+                losses.append(loss)
+                self._global_step += 1
+                if not self._warmed:
+                    # The first step ever pays jit trace+compile; keep it
+                    # out of the throughput window so steps/sec reflects
+                    # steady state.
+                    jax.block_until_ready(loss)
+                    self._warmed = True
+                    self.throughput.start()
+                    measuring = True
+                else:
+                    steps += 1
+                if log_every and self._global_step % log_every == 0:
+                    self._m_readbacks.inc(sink="log_boundary")
+                    with phase("log_readback"):
+                        # graftlint: disable=JX003 -- designed sink: one scalar readback per log_every steps, the logging contract
+                        value = float(loss)
+                    print(f"step {self._global_step}: loss {value:.6f}")
+                self._note_steps(state, bundle, 1, on_step)
+        # what this epoch dispatched, for profile_epoch to lower again
+        self._dispatched = (program, (*fixed, *batch))
+        with phase("device_wait"):
+            jax.block_until_ready(state.params)
         if measuring:
             self.throughput.stop(steps)
         self._publish_epoch_metrics()
@@ -1040,13 +1089,14 @@ class Trainer:
         # per-step values reproduces the historical list-of-floats mean
         # bit-for-bit.
         self._m_readbacks.inc(sink="epoch_losses")
-        epoch_losses = np.asarray(jnp.stack(losses))
+        with phase("loss_readback"):
+            epoch_losses = np.asarray(jnp.stack(losses))
         self._last_epoch_losses = epoch_losses
         return state, float(np.mean(epoch_losses, dtype=np.float64))
 
     def _train_epoch_superstep(self, state: TrainState, bundle: DatasetBundle,
                                epoch_rng: np.random.Generator, staged,
-                               s: int, skip_steps: int = 0,
+                               s: int, phase, skip_steps: int = 0,
                                on_step=None) -> tuple[TrainState, float]:
         """Fused epoch driver: ceil(K/S) donated dispatches instead of K.
 
@@ -1067,8 +1117,9 @@ class Trainer:
         cfg = self.config.train
         log_every = cfg.log_every_steps
         x_base, y_base = staged
-        starts, weights, num_steps = self._epoch_plan(
-            bundle.num_train_windows, epoch_rng, s)
+        with phase("plan_build"):
+            starts, weights, num_steps = self._epoch_plan(
+                bundle.num_train_windows, epoch_rng, s)
         self._epoch_num_steps = num_steps
         self._epoch_steps_done = skip_steps
         if skip_steps >= num_steps:
@@ -1082,7 +1133,8 @@ class Trainer:
                 "chunk boundaries — the sidecar is inconsistent with "
                 "this config's steps_per_superstep/grad_accum_windows")
         skip_chunks = skip_steps // s
-        starts_d, weights_d = stage_plan(self.mesh, starts, weights)
+        with phase("plan_h2d"):
+            starts_d, weights_d = stage_plan(self.mesh, starts, weights)
         # The coalesced (grad-accum) superstep and the per-step superstep
         # share the whole driver: only the compiled scan differs.
         superstep = (self._accum_superstep if cfg.grad_accum_windows > 1
@@ -1092,36 +1144,48 @@ class Trainer:
             self.throughput.start()
         chunk_losses = []
         steps = 0
-        for c in range(skip_chunks, starts.shape[0]):
-            real = min(s, num_steps - c * s)
-            state, losses_c = superstep(state, x_base, y_base,
-                                        starts_d, weights_d, c)
-            # Mid-superstep (and mid-grad-accum-group) device loss: the
-            # whole chunk's dispatch is the unit that fails, so the probe
-            # sits before ANY of the chunk's bookkeeping — progress since
-            # the last durable snapshot is what the barrier rolls back.
-            self._fault_check(real)
-            chunk_losses.append(losses_c)
-            if not self._warmed:
-                # First-ever superstep pays the scan's trace+compile.
-                jax.block_until_ready(losses_c)
-                self._warmed = True
-                self.throughput.start()
-                measuring = True
-            else:
-                steps += real
-            prev = self._global_step
-            self._global_step += real
-            if log_every and prev // log_every != self._global_step // log_every:
-                self._m_readbacks.inc(sink="log_boundary")
-                # graftlint: disable=JX003 -- designed sink: one [S] readback per superstep, only when a log boundary passed
-                vals = np.asarray(losses_c)     # one readback, ≥1 boundary
-                for gs in range(prev + 1, self._global_step + 1):
-                    if gs % log_every == 0:
-                        print(f"step {gs}: loss {vals[gs - prev - 1]:.6f}")
-            self._note_steps(state, bundle, real, on_step)
+        with phase("dispatch"):
+            for c in range(skip_chunks, starts.shape[0]):
+                real = min(s, num_steps - c * s)
+                state, losses_c = superstep(state, x_base, y_base,
+                                            starts_d, weights_d, c)
+                # Mid-superstep (and mid-grad-accum-group) device loss: the
+                # whole chunk's dispatch is the unit that fails, so the
+                # probe sits before ANY of the chunk's bookkeeping —
+                # progress since the last durable snapshot is what the
+                # barrier rolls back.
+                self._fault_check(real)
+                chunk_losses.append(losses_c)
+                if not self._warmed:
+                    # First-ever superstep pays the scan's trace+compile.
+                    jax.block_until_ready(losses_c)
+                    self._warmed = True
+                    self.throughput.start()
+                    measuring = True
+                else:
+                    steps += real
+                prev = self._global_step
+                self._global_step += real
+                if log_every and (prev // log_every
+                                  != self._global_step // log_every):
+                    self._m_readbacks.inc(sink="log_boundary")
+                    # The readback blocks until its chunk is done, so it
+                    # is a wait, and the next chunk is not dispatched
+                    # while it waits.
+                    with phase("log_readback"):
+                        # graftlint: disable=JX003 -- designed sink: one [S] readback per superstep, only when a log boundary passed
+                        vals = np.asarray(losses_c)   # one readback, ≥1 boundary
+                    for gs in range(prev + 1, self._global_step + 1):
+                        if gs % log_every == 0:
+                            print(f"step {gs}: "
+                                  f"loss {vals[gs - prev - 1]:.6f}")
+                self._note_steps(state, bundle, real, on_step)
         self._m_dispatches.inc(starts.shape[0] - skip_chunks)
-        jax.block_until_ready(state.params)
+        # what this epoch dispatched, for profile_epoch to lower again
+        self._dispatched = (superstep,
+                            (x_base, y_base, starts_d, weights_d, 0))
+        with phase("device_wait"):
+            jax.block_until_ready(state.params)
         if measuring:
             self.throughput.stop(steps)
         self._publish_epoch_metrics()
@@ -1129,10 +1193,58 @@ class Trainer:
         # concatenated chunks to the executed real-step count recovers
         # exactly the (remaining) per-step loss curve.
         self._m_readbacks.inc(sink="epoch_losses")
-        epoch_losses = np.asarray(
-            jnp.concatenate(chunk_losses))[:num_steps - skip_steps]
+        with phase("loss_readback"):
+            epoch_losses = np.asarray(
+                jnp.concatenate(chunk_losses))[:num_steps - skip_steps]
         self._last_epoch_losses = epoch_losses
         return state, float(np.mean(epoch_losses, dtype=np.float64))
+
+    def _dispatched_program_text(self, state: TrainState) -> str:
+        """The optimized HLO of the program the last epoch dispatched (the
+        epoch drivers record it and the arguments beside ``state``):
+        lowered on those very arguments, so it is the executable that ran,
+        and with the persistent cache on it costs a trace and a cache
+        read.  Called by :meth:`profile_epoch` only; the normal train path
+        never lowers twice."""
+        program, args = self._dispatched
+        return program.lower(state, *args).compile().as_text()
+
+    def profile_epoch(self, state: TrainState, bundle: DatasetBundle,
+                      epoch_rng: np.random.Generator, staged,
+                      trace_dir: str, skip_steps: int = 0,
+                      on_step=None) -> tuple[TrainState, dict]:
+        """:meth:`train_epoch` under a ``jax.profiler`` window (Python
+        tracer off) with the span recorder on for its duration, then the
+        trace read into the table of obs/profiler.py: the device's time by
+        the scopes of the program this epoch dispatched, its idle gaps by
+        the epoch's phases, the phases' host seconds and the epoch's mean
+        loss (``train_loss``).  Trace a steady epoch (the program
+        compiled, the loss concatenation too): the second, not the
+        first."""
+        from deeprest_tpu.obs import profiler
+
+        was = obs_spans.RECORDER.enabled
+        obs_spans.RECORDER.enabled = True
+        try:
+            with profiler.trace_window(trace_dir):
+                state, loss = self.train_epoch(state, bundle, epoch_rng,
+                                               staged=staged,
+                                               skip_steps=skip_steps,
+                                               on_step=on_step)
+        finally:
+            obs_spans.RECORDER.enabled = was
+        text = self._dispatched_program_text(state)
+        names = scopes.STEP_SCOPES + scopes.KERNELS
+        table = profiler.layer_table(
+            trace_dir, scopes=profiler.scope_table(text, names),
+            fused=profiler.fused_scopes(text, names),
+            module=profiler.module_name(text),
+            steps=len(self._last_epoch_losses))
+        table["phases"] = {
+            name: self._epoch_clock.last_seconds.value(phase=name)
+            for name in EPOCH_PHASES}
+        table["train_loss"] = loss
+        return state, table
 
     # ------------------------------------------------------------------
 
@@ -1230,14 +1342,19 @@ class Trainer:
         on_epoch: Callable[[EpochResult, TrainState], None] | None = None,
         num_epochs: int | None = None,
         on_step=None,
+        profile_dir: str | None = None,
     ) -> tuple[TrainState, list[EpochResult]]:
+        """``profile_dir``: run the second epoch of this call (the only
+        one, if there is one) through :meth:`profile_epoch`, tracing into
+        that directory; the table is kept as ``last_profile``."""
         if state is None:
             state = self.init_state(self.sample_input(bundle))
         data_rng = np.random.default_rng(self.config.train.seed)
         run = (self._run_epochs_elastic if self.config.train.elastic
                else self._run_epochs)
         return run(bundle, state, data_rng, 0, 0,
-                   baseline_preds, on_epoch, num_epochs, on_step)
+                   baseline_preds, on_epoch, num_epochs, on_step,
+                   profile_dir)
 
     def resume_training(
         self,
@@ -1247,6 +1364,7 @@ class Trainer:
         on_epoch: Callable[[EpochResult, TrainState], None] | None = None,
         num_epochs: int | None = None,
         on_step=None,
+        profile_dir: str | None = None,
     ) -> tuple[TrainState, list[EpochResult]]:
         """Restart a preempted :meth:`fit` from its newest cursor
         snapshot and run to completion, bit-identical to the
@@ -1289,7 +1407,8 @@ class Trainer:
                else self._run_epochs)
         return run(bundle, state, data_rng,
                    int(cursor["epoch"]), int(cursor["steps_done"]),
-                   baseline_preds, on_epoch, num_epochs, on_step)
+                   baseline_preds, on_epoch, num_epochs, on_step,
+                   profile_dir)
 
     def _run_epochs(
         self,
@@ -1302,6 +1421,7 @@ class Trainer:
         on_epoch: Callable[[EpochResult, TrainState], None] | None,
         num_epochs: int | None,
         on_step=None,
+        profile_dir: str | None = None,
     ) -> tuple[TrainState, list[EpochResult]]:
         cfg = self.config.train
         if cfg.snapshot_every_steps and cfg.checkpoint_dir \
@@ -1311,12 +1431,20 @@ class Trainer:
         history: list[EpochResult] = []
         total = num_epochs if num_epochs is not None else cfg.num_epochs
         staged = self.stage_dataset(bundle) if total > start_epoch else None
+        # the second epoch is the first steady one (the first compiles)
+        profiled = min(start_epoch + 1, total - 1) if profile_dir else None
         for epoch in range(start_epoch, total):
             self._begin_epoch_cursor(epoch, data_rng)
-            state, train_loss = self.train_epoch(
-                state, bundle, data_rng, staged=staged,
-                skip_steps=(skip_steps if epoch == start_epoch else 0),
-                on_step=on_step)
+            skip = skip_steps if epoch == start_epoch else 0
+            if epoch == profiled:
+                state, self.last_profile = self.profile_epoch(
+                    state, bundle, data_rng, staged, profile_dir,
+                    skip_steps=skip, on_step=on_step)
+                train_loss = self.last_profile["train_loss"]
+            else:
+                state, train_loss = self.train_epoch(
+                    state, bundle, data_rng, staged=staged,
+                    skip_steps=skip, on_step=on_step)
             test_loss, report = self.evaluate(state, bundle, baseline_preds,
                                               staged=staged)
             result = EpochResult(epoch=epoch, train_loss=train_loss,

@@ -186,7 +186,8 @@ def test_whatif_command_and_sweep(pipeline, capsys, tmp_path):
 
 @pytest.mark.slow
 def test_train_profile_capture(pipeline, tmp_path):
-    """--profile-dir captures a jax.profiler trace of the first epoch
+    """--profile-dir captures a jax.profiler trace of one epoch (the
+    second; the only one here) and reads it back into layers.json
     (SURVEY.md §5.1: the ML-plane profiling the reference lacks)."""
     import glob
 
@@ -198,6 +199,7 @@ def test_train_profile_capture(pipeline, tmp_path):
                        recursive=True)
     assert planes, f"no xplane artifact under {profile_dir}"
     assert os.path.getsize(planes[0]) > 0
+    assert os.path.exists(os.path.join(profile_dir, "layers.json"))
 
 
 @pytest.mark.slow

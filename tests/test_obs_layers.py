@@ -1,0 +1,438 @@
+"""The layer table of deeprest_tpu/obs/profiler.py (ISSUE 24): the named
+scopes inside the compiled train step, the program's spans on the
+profiler's clock, the trainer's epoch phases as spans and counters, and the
+trace reduction held to the yardstick's on a recorded v5e trace.
+
+Everything here runs on the CPU: it checks names, counts, arithmetic and
+plumbing.  No number of it is a device number.
+"""
+
+import json
+import os
+import subprocess
+import sys
+import time
+
+import numpy as np
+import pytest
+
+from conftest import make_series_buckets
+
+from deeprest_tpu import obs
+from deeprest_tpu.config import Config, FeaturizeConfig, ModelConfig, TrainConfig
+from deeprest_tpu.data.featurize import featurize_buckets
+from deeprest_tpu.obs import profiler
+from deeprest_tpu.obs.metrics import REGISTRY
+from deeprest_tpu.ops import scopes
+from deeprest_tpu.train import Trainer, prepare_dataset
+from deeprest_tpu.train.trainer import EPOCH_PHASES
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+RECORDED = os.path.join(REPO, "chipbench", "tests", "data",
+                        "recorded_v5e.xplane.pb")
+BATCH, LOG_EVERY, SUPERSTEP = 8, 4, 3
+NAMES = frozenset(scopes.STEP_SCOPES + scopes.KERNELS)
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    """A sparse-feed trainer, its corpus staged: the path the benchmark's
+    cell takes (gather + densify inside the superstep)."""
+    cfg = Config(
+        model=ModelConfig(hidden_size=8, dropout_rate=0.1),
+        train=TrainConfig(batch_size=BATCH, window_size=10, seed=0,
+                          log_every_steps=LOG_EVERY,
+                          steps_per_superstep=SUPERSTEP,
+                          device_data="always", sparse_feed=True,
+                          sparse_nnz_cap=48))
+    data = featurize_buckets(make_series_buckets(200, seed=5),
+                             FeaturizeConfig(round_to=8))
+    bundle = prepare_dataset(data, cfg.train)
+    trainer = Trainer(cfg, bundle.feature_dim, bundle.metric_names)
+    state = trainer.init_state(trainer.sample_input(bundle))
+    staged = trainer.stage_dataset(bundle)
+    assert staged is not None
+    return {"trainer": trainer, "bundle": bundle, "state": state,
+            "staged": staged, "rng": np.random.default_rng(0)}
+
+
+def _epoch(tiny):
+    tiny["state"], loss = tiny["trainer"].train_epoch(
+        tiny["state"], tiny["bundle"], tiny["rng"], staged=tiny["staged"])
+    return loss
+
+
+# -- (a) names inside the compiled step -------------------------------------
+
+
+def test_scope_map_of_the_compiled_superstep_holds_every_scope(tiny):
+    trainer = tiny["trainer"]
+    _epoch(tiny)
+    # the text is of what the epoch dispatched: the driver's own choice of
+    # program, on its own arguments
+    program, args = trainer._dispatched
+    assert program is trainer._superstep and args[:2] == tiny["staged"]
+    text = trainer._dispatched_program_text(tiny["state"])
+    assert profiler.module_name(text) == "jit_train_superstep"
+    table = profiler.scope_table(text, NAMES)
+    found = set(table.values())
+    for scope in scopes.STEP_SCOPES:
+        assert (scope, "fwd") in found, scope
+    for scope in ("mask", "in_proj", "recurrence", "mixing", "heads", "loss"):
+        assert (scope, "bwd") in found, scope
+    # Of the instructions JAX named (the compiler's own layout copies
+    # carry no op_name and no scope can claim them), under a tenth fall
+    # to `other`: the superstep loop's bookkeeping around the step.
+    computations, inner = profiler._parse_hlo(text)
+    named = [profiler._scope_of(op_name, NAMES)[0]
+             for comp, instructions in computations.items()
+             if comp not in inner
+             for _, opcode, op_name, _ in instructions
+             if op_name and opcode not in profiler._NO_EVENT]
+    assert named.count(profiler.OTHER) < 0.10 * len(named), (
+        named.count(profiler.OTHER), len(named), len(table))
+    # a fusion that holds another scope's work says so
+    fused = profiler.fused_scopes(text, NAMES)
+    assert all(name in table and profiler.OTHER not in held
+               for name, held in fused.items())
+
+
+@pytest.mark.parametrize("op_name, expected", [
+    ("jit(step)/transpose(jvp(QuantileGRU))/in_proj/btf,efg->etbg/dot_general",
+     ("in_proj", "bwd")),
+    ("jit(step)/while/body/jvp(loss)/mul", ("loss", "fwd")),
+    ("jit(step)/transpose(jvp(loss))/mul", ("loss", "bwd")),
+    ("jit(step)/gather/densify/scatter-add", ("densify", "fwd")),
+    ("jit(step)/jvp(QuantileGRU)/recurrence/gru_kernel_fwd/pallas_call",
+     ("gru_kernel_fwd", "fwd")),
+    # the primitive at the end is not a scope, though it shares the name
+    ("jit(step)/jvp(Embed)/gather", ("other", "-")),
+    # under no name it was given there is ONE `other`, whatever the pass
+    ("jit(step)/transpose(jvp(Embed))/mul", ("other", "-")),
+    ("", ("other", "-")),
+])
+def test_scope_of_an_op_name(op_name, expected):
+    assert profiler._scope_of(op_name, NAMES) == expected
+
+
+def test_the_profiler_knows_no_name_it_is_not_given():
+    op_name = "jit(step)/transpose(jvp(Model))/in_proj/dot_general"
+    assert profiler._scope_of(op_name, {"Model"}) == ("Model", "bwd")
+    assert profiler._scope_of(op_name, ()) == ("other", "-")
+
+
+# -- (b) two reducers, one answer -------------------------------------------
+
+
+def test_layer_table_agrees_with_the_yardstick_on_the_recorded_trace():
+    sys.path.insert(0, REPO)
+    from chipbench import trace_reduce
+
+    theirs = trace_reduce.reduce_file(RECORDED)
+    ours = profiler.layer_table_of(profiler.read_planes(RECORDED), steps=4)
+    for key in ("busy_s", "window_s", "kernel_s"):
+        assert ours[key] == pytest.approx(theirs[key], rel=1e-12), key
+    assert ours["chips"] == theirs["chips"] == 1
+    assert sum(r["seconds"] for r in ours["rows"]) == pytest.approx(
+        ours["busy_s"], rel=1e-9)
+    # no map: one `other` row and the kernels under the names the trace
+    # has for them (recorded before the pallas_calls had a name=, so the
+    # flax module's), which is the yardstick's kernel time again; no
+    # program span was on the trace
+    rows = {r["scope"]: r for r in ours["rows"]}
+    assert set(rows) > {profiler.OTHER} and all(
+        "QuantileGRU" in k for k in set(rows) - {profiler.OTHER}), set(rows)
+    assert sum(r["seconds"] for k, r in rows.items()
+               if k != profiler.OTHER) == pytest.approx(theirs["kernel_s"])
+    assert rows[profiler.OTHER]["ms_per_step"] == pytest.approx(
+        1e3 * (ours["busy_s"] - ours["kernel_s"]) / 4)
+    assert [g["span"] for g in ours["idle_gaps"]] == [profiler.UNATTRIBUTED]
+    assert ours["idle_gaps"][0]["seconds"] == pytest.approx(
+        ours["window_s"] - ours["busy_s"], rel=1e-9)
+
+
+# -- (c) spans on the profiler's clock --------------------------------------
+
+
+def test_enabled_span_enters_a_trace_annotation(monkeypatch):
+    import jax.profiler
+
+    seen = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    rec = obs.SpanRecorder(capacity=4, enabled=True)
+    with rec.span("batch.dispatch", component="deeprest-batcher"):
+        assert seen == [("enter", "deeprest-batcher/batch.dispatch")]
+    assert seen[-1] == ("exit", "deeprest-batcher/batch.dispatch")
+    rec.enabled = False
+    with rec.span("batch.dispatch", component="deeprest-batcher"):
+        pass
+    assert len(seen) == 2
+
+
+def test_disabled_span_does_not_import_jax():
+    code = ("import sys\n"
+            "from deeprest_tpu import obs\n"
+            "from deeprest_tpu.obs import profiler, phases\n"
+            "with obs.span('x', component='deeprest-test'):\n"
+            "    pass\n"
+            "assert 'jax' not in sys.modules, 'jax imported'\n"
+            "obs.configure(enabled=True)\n"
+            "with obs.span('x', component='deeprest-test'):\n"
+            "    pass\n"
+            "assert 'jax' in sys.modules\n")
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
+# -- (d) the epoch's phases as counters -------------------------------------
+
+
+def _phase_values(metric_name):
+    metric = REGISTRY.get(metric_name)
+    return {p: metric.value(phase=p) for p in EPOCH_PHASES}
+
+
+def _totals():
+    readbacks = REGISTRY.get("deeprest_train_readbacks_total").series()
+    return {
+        "epochs": REGISTRY.get("deeprest_train_epochs_total").value(),
+        "dispatches": REGISTRY.get(
+            "deeprest_train_superstep_dispatches_total").value(),
+        "readbacks": sum(readbacks.values()),
+    }
+
+
+def test_two_epochs_fill_the_phase_counters(tiny):
+    trainer, bundle = tiny["trainer"], tiny["bundle"]
+    num_steps = -(-bundle.num_train_windows // BATCH)
+    chunks = -(-num_steps // SUPERSTEP)
+    assert trainer._superstep_len(num_steps) == SUPERSTEP and chunks > 2
+    _epoch(tiny)        # compiles; its seconds must not stay in the gauge
+    start_step = trainer._global_step
+    before = _totals()
+    first = _phase_values("deeprest_train_last_epoch_phase_seconds")
+    t0 = time.perf_counter()
+    _epoch(tiny)
+    wall = time.perf_counter() - t0
+    after = _totals()
+    last = _phase_values("deeprest_train_last_epoch_phase_seconds")
+
+    assert set(last) == set(EPOCH_PHASES)
+    assert all(v > 0 for v in last.values()), last
+    assert last != first
+    assert sum(last.values()) <= wall
+    assert after["epochs"] - before["epochs"] == 1
+    assert after["dispatches"] - before["dispatches"] == chunks
+    # one readback per chunk in which a multiple of LOG_EVERY falls, and
+    # the epoch's loss readback
+    bounds = [min(start_step + (c + 1) * SUPERSTEP, start_step + num_steps)
+              for c in range(chunks)]
+    crossings = sum(lo // LOG_EVERY != hi // LOG_EVERY
+                    for lo, hi in zip([start_step] + bounds, bounds))
+    assert after["readbacks"] - before["readbacks"] == crossings + 1
+
+
+def test_epoch_spans_are_children_of_one_epoch_span(tiny):
+    prev = obs.RECORDER.enabled
+    obs.RECORDER.clear()
+    obs.RECORDER.enabled = True
+    try:
+        _epoch(tiny)
+    finally:
+        obs.RECORDER.enabled = prev
+    spans = [s for s in obs.RECORDER.drain()
+             if s.component == "deeprest-trainer"]
+    epoch = [s for s in spans if s.name == "train.epoch"]
+    assert len(epoch) == 1
+    phases = [s for s in spans if s.name != "train.epoch"]
+    assert {s.name for s in phases} == set(EPOCH_PHASES)
+    assert all(s.parent_id == epoch[0].span_id for s in phases)
+
+
+def test_nested_phase_is_timed_exclusively_and_a_failed_unit_publishes_nothing():
+    reg = obs.MetricsRegistry()
+    clock = obs.PhaseClock(
+        "unit", "deeprest-test", ("outer", "inner", "unused"),
+        last_seconds=reg.gauge("s_last", labelnames=("phase",)),
+        units_total=reg.counter("units"))
+    t0 = time.perf_counter()
+    with clock.unit() as phase:
+        with phase("outer"):
+            time.sleep(0.01)
+            with phase("inner"):
+                time.sleep(0.02)
+    wall = time.perf_counter() - t0
+    outer = clock.last_seconds.value(phase="outer")
+    inner = clock.last_seconds.value(phase="inner")
+    assert inner >= 0.02 and 0.01 <= outer < inner
+    assert outer + inner <= wall
+    assert clock.last_seconds.series()[("unused",)] == 0.0
+    with pytest.raises(ValueError):
+        with clock.unit() as phase:
+            with phase("nope"):
+                pass
+    assert clock.units_total.value() == 1
+
+
+# -- (e) idle gaps under the innermost program span -------------------------
+
+
+def test_gaps_go_to_the_innermost_covering_program_span():
+    ms = 1_000_000
+    ops = [("%fusion.1 = f32[] fusion()", 0, 10 * ms),
+           ("%gru_kernel_fwd.2 = f32[] custom-call(), "
+            'custom_call_target="tpu_custom_call"', 12 * ms, 8 * ms),
+           ("%fusion.1 = f32[] fusion()", 30 * ms, 10 * ms),
+           ("%fusion.9 = f32[] fusion()", 45 * ms, 5 * ms),
+           ("%fusion.9 = f32[] fusion()", 60 * ms, 1 * ms)]
+    modules = [("jit_train_superstep(123)", 0, 50 * ms),
+               ("jit_concatenate(9)", 60 * ms, 1 * ms)]
+    host = [("deeprest-trainer/train.epoch", 0, 52 * ms),
+            ("deeprest-trainer/dispatch", 0, 25 * ms),
+            ("deeprest-trainer/log_readback", 20 * ms, 3 * ms),
+            ("bench.train_epoch", 0, 100 * ms),
+            ("not-ours", 40 * ms, 5 * ms)]
+    planes = [("/device:TPU:0", [("XLA Modules", modules), ("XLA Ops", ops),
+                                 ("Async XLA Ops", [("x", 0, 90 * ms)])]),
+              ("/host:CPU", [("main/1", host)])]
+    scope_map = {"fusion.1": ("optimizer", "fwd"), "fusion.9": ("loss", "bwd"),
+              "gru_kernel_fwd.2": ("gru_kernel_fwd", "fwd")}
+    table = profiler.layer_table_of(
+        planes, scopes=scope_map, fused={"fusion.1": ("mask",)},
+        module="jit_train_superstep", steps=2)
+    gaps = {g["span"]: g for g in table["idle_gaps"]}
+    # 10-12 under dispatch, 20-30 (middle 25) on dispatch's edge, 40-45
+    # under the epoch alone (a foreign span is not ours), 50-60 under none
+    assert gaps["deeprest-trainer/dispatch"]["gaps"] == 2
+    assert gaps["deeprest-trainer/dispatch"]["seconds"] == pytest.approx(0.012)
+    assert gaps["deeprest-trainer/train.epoch"]["seconds"] == pytest.approx(0.005)
+    assert gaps[profiler.UNATTRIBUTED]["longest_s"] == pytest.approx(0.010)
+    assert "deeprest-trainer/log_readback" not in gaps
+    rows = {(r["scope"], r["pass"]): r for r in table["rows"]}
+    assert rows["optimizer", "fwd"]["seconds"] == pytest.approx(0.020)
+    assert rows["optimizer", "fwd"]["in_fusions_that_also_hold"] == {
+        "mask": pytest.approx(0.020)}
+    assert rows["optimizer", "fwd"]["ms_per_step"] == pytest.approx(10.0)
+    assert rows["gru_kernel_fwd", "fwd"]["seconds"] == pytest.approx(0.008)
+    assert rows["loss", "bwd"]["seconds"] == pytest.approx(0.005)
+    # the same instruction name in another program is not the map's
+    assert rows["other", "-"]["seconds"] == pytest.approx(0.001)
+    assert table["kernel_s"] == pytest.approx(0.008)
+    assert table["busy_s"] == pytest.approx(0.034)
+    assert table["window_s"] == pytest.approx(0.061)
+    assert sum(r["seconds"] for r in table["rows"]) == pytest.approx(
+        table["busy_s"])
+    # a log readback that does cover a gap takes it from dispatch
+    host[2] = ("deeprest-trainer/log_readback", 10 * ms, 3 * ms)
+    again = profiler.layer_table_of(planes, scopes=scope_map)
+    gaps = {g["span"]: g["seconds"] for g in again["idle_gaps"]}
+    assert gaps["deeprest-trainer/log_readback"] == pytest.approx(0.002)
+    # without a module every operation goes through the map; without a map
+    # a kernel still goes by the name its pallas_call gave it
+    assert {(r["scope"], r["pass"]): r["seconds"] for r in again["rows"]}[
+        "loss", "bwd"] == pytest.approx(0.006)
+    bare = profiler.layer_table_of(planes)
+    assert {r["scope"]: r["seconds"] for r in bare["rows"]} == {
+        "other": pytest.approx(0.026), "gru_kernel_fwd": pytest.approx(0.008)}
+
+
+def test_a_trace_with_no_device_gives_rows_and_no_seconds():
+    table = profiler.layer_table_of(
+        [("/host:CPU", [("main/1", [("deeprest-trainer/train.epoch", 0, 5)])])],
+        scopes={"fusion.1": ("optimizer", "fwd")})
+    assert table["chips"] == 0 and table["idle_pct"] is None
+    assert table["busy_s"] == table["window_s"] == table["kernel_s"] == 0
+    assert {(r["scope"], r["seconds"]) for r in table["rows"]} == {
+        ("optimizer", 0.0), ("other", 0.0)}
+    assert table["idle_gaps"] == []
+
+
+# -- (f) the operator's entries ---------------------------------------------
+
+
+def test_profile_epoch_reads_the_trace_it_opens(tiny, tmp_path):
+    trainer = tiny["trainer"]
+    epochs = REGISTRY.get("deeprest_train_epochs_total").value()
+    tiny["state"], table = trainer.profile_epoch(
+        tiny["state"], tiny["bundle"], tiny["rng"], tiny["staged"],
+        str(tmp_path))
+    assert obs.RECORDER.enabled is False
+    assert REGISTRY.get("deeprest_train_epochs_total").value() == epochs + 1
+    assert os.path.exists(table["trace"])
+    assert table["steps"] == len(trainer._last_epoch_losses) > 0
+    found = {r["scope"] for r in table["rows"]}
+    assert found >= set(scopes.STEP_SCOPES) | {profiler.OTHER}
+    assert [r["pass"] for r in table["rows"]
+            if r["scope"] == profiler.OTHER] == ["-"]      # one row
+    assert set(table["phases"]) == set(EPOCH_PHASES)
+    assert table["chips"] == 0         # the CPU has no device plane
+    # the spans did reach the trace: the host plane holds the epoch's
+    names = {n for _, lines in profiler.read_planes(table["trace"])
+             for _, events in lines for n, _, _ in events}
+    assert {"deeprest-trainer/train.epoch",
+            "deeprest-trainer/loss_readback"} <= names
+    assert "optimizer" in profiler.format_table(table)
+
+
+def test_profile_epoch_lowers_what_the_per_step_driver_dispatched(tmp_path):
+    """The host-feed driver (no staged corpus, no superstep) records its
+    own program too: the scope map is never built from another branch's
+    executable."""
+    cfg = Config(model=ModelConfig(hidden_size=8, dropout_rate=0.1),
+                 train=TrainConfig(batch_size=BATCH, window_size=10, seed=0,
+                                   log_every_steps=0, device_data="off"))
+    data = featurize_buckets(make_series_buckets(60, seed=5),
+                             FeaturizeConfig(round_to=8))
+    bundle = prepare_dataset(data, cfg.train)
+    trainer = Trainer(cfg, bundle.feature_dim, bundle.metric_names)
+    state = trainer.init_state(trainer.sample_input(bundle))
+    rng = np.random.default_rng(0)
+    state, _ = trainer.train_epoch(state, bundle, rng)
+    program, args = trainer._dispatched
+    assert program is trainer._train_step and len(args) == 3
+    state, table = trainer.profile_epoch(state, bundle, rng, None,
+                                         str(tmp_path))
+    assert profiler.module_name(
+        trainer._dispatched_program_text(state)) == "jit_train_step"
+    found = {r["scope"] for r in table["rows"]}
+    assert found >= set(scopes.STEP_SCOPES) - {"gather", "densify"}
+    assert table["phases"]["plan_build"] == 0.0 < table["phases"]["dispatch"]
+
+
+def test_train_profile_dir_writes_layers_json(tmp_path, capsys):
+    from deeprest_tpu.cli import main
+
+    raw, feats = str(tmp_path / "raw.jsonl"), str(tmp_path / "input.npz")
+    out = str(tmp_path / "prof")
+    assert main(["simulate", "--scenario=normal", "--ticks=90",
+                 f"--out={raw}"]) == 0
+    assert main(["featurize", f"--raw={raw}", f"--out={feats}",
+                 "--round-to=8"]) == 0
+    assert main(["train", f"--features={feats}", "--epochs=2",
+                 "--batch-size=16", "--window=20", "--hidden-size=8",
+                 "--no-baselines", "--device-data=always",
+                 f"--profile-dir={out}"]) == 0
+    printed = capsys.readouterr().out
+    assert "host phase loss_readback" in printed
+    # the second epoch was the one traced: its table comes before its line
+    assert printed.index("epoch 0:") < printed.index("host phase") \
+        < printed.index("epoch 1:")
+    with open(os.path.join(out, "layers.json")) as fh:
+        table = json.load(fh)
+    rows = {r["scope"] for r in table["rows"]}
+    assert rows >= set(scopes.STEP_SCOPES) - {"densify"}    # a dense corpus
+    assert set(table["phases"]) == set(EPOCH_PHASES)
+    assert os.path.exists(table["trace"])
