@@ -44,6 +44,17 @@ axis of ONE recurrence call.  Two hooks support that here:
   float association than the per-microbatch loop it must match
   bit-for-bit); staging it through an explicit ``jax.vjp`` keeps the
   mask backward per-group and unbatched, exactly like the loop.
+
+Live-column compaction (PR 25): a staged sparse corpus whose live call
+paths are few (``ops/densify.py``, the compact form) hands ``__call__``
+windows of those ``U_pad`` columns only, with the table that names them
+(``live_cols``).  Layer 0 then takes the same columns of the soft mask and
+of ``w_ih`` (:func:`take_columns`), folds and projects ``[B, T, U_pad] x
+[E, U_pad, 3H]``: the sum over F without its exact-zero terms.  The mask's
+softmax still runs over all F, every parameter keeps its shape, and the
+take's transpose hands the optimizer a dense gradient that is zero at the
+columns left out.  Without ``live_cols`` (every dense feed, serving) the
+call is what it was.
 """
 
 from __future__ import annotations
@@ -104,6 +115,15 @@ def _fold(mask: jax.Array, w_ih: jax.Array) -> jax.Array:
     return mask[:, :, None] * w_ih
 
 
+def take_columns(a: jax.Array, live_cols: jax.Array) -> jax.Array:
+    """``a[:, live_cols]``: the live columns of the ``[E, F]`` mask or of a
+    ``[E, F, 3H]`` input weight.  The table is sorted, without repeats and
+    in range (``ops/densify.compact_table``), and the gather says so, so
+    its transpose is a scatter that need not serialize or accumulate."""
+    return a.at[:, live_cols].get(unique_indices=True, indices_are_sorted=True,
+                                  mode="promise_in_bounds")
+
+
 def fold_feature_mask(params):
     """Fold the soft mask into the layer-0 input weights, tree-level.
 
@@ -137,11 +157,18 @@ class QuantileGRU(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array, *, deterministic: bool = True,
-                 mask_folded: bool = False) -> jax.Array:
+                 mask_folded: bool = False,
+                 live_cols: jax.Array | None = None) -> jax.Array:
+        """``live_cols`` (``[U_pad]`` int32, sorted dense column indices):
+        ``x`` is ``[..., U_pad]`` and holds those columns only; every
+        column left out is zero in the dense input (module docstring)."""
         cfg = self.config
         e, f, h, q = cfg.num_metrics, cfg.feature_dim, cfg.hidden_size, len(cfg.quantiles)
-        if x.shape[-1] != f:
+        if live_cols is None and x.shape[-1] != f:
             raise ValueError(f"input feature dim {x.shape[-1]} != config.feature_dim {f}")
+        if live_cols is not None and x.shape[-1] != live_cols.shape[0]:
+            raise ValueError(f"input feature dim {x.shape[-1]} != the "
+                             f"{live_cols.shape[0]} live columns")
         compute_dtype = jnp.dtype(cfg.compute_dtype)
 
         # Group axis (coalescing plumbing): [G, B, T, F] folds its groups
@@ -194,11 +221,16 @@ class QuantileGRU(nn.Module):
             )
 
         # Fold the mask into the input weights: (x ⊙ m) @ W == x @ (m ⊙ W).
-        # Identity when the caller pre-folded (fold_feature_mask).
+        # Identity when the caller pre-folded (fold_feature_mask).  On a
+        # compact input only the live columns of either are folded.
+        if live_cols is not None and mask is not None:
+            mask = take_columns(mask, live_cols)                      # [E, U]
+
         def masked(p: GRUParams) -> GRUParams:
-            if mask is None:
-                return p
-            return p._replace(w_ih=_fold(mask, p.w_ih))
+            w_ih = (p.w_ih if live_cols is None
+                    else take_columns(p.w_ih, live_cols))
+            return p._replace(
+                w_ih=w_ih if mask is None else _fold(mask, w_ih))
 
         def cast(p: GRUParams) -> GRUParams:
             return jax.tree.map(lambda a: a.astype(compute_dtype), p)

@@ -25,6 +25,25 @@ Numerics contract (pinned by tests/test_sparse.py):
   ARGUMENTS, never baked constants — a constant range lets XLA
   strength-reduce the divide into a multiply-by-reciprocal, which breaks
   bit parity with the host path (the serve/fused.py lesson).
+
+The compact form (live-column compaction, PR 25).  A hashed path space is
+sized for the call paths that MAY come; a staged corpus holds the paths
+that DID.  A column is *live* when its normalized value can be nonzero in
+some staged row (:func:`live_columns`); every other column is exactly 0.0
+in every window, and a product with it adds nothing to the layer-0
+projection.  When the live set, padded to a power of two
+(:func:`compact_table`), is at most a quarter of F, the trainer stages the
+base with that table (``SparseBase.live``): ``cols`` are then RANKS in the
+table, the statistics are taken at the table, and
+:func:`gather_densify_normalize` builds ``[..., W, U_pad]`` windows whose
+column j is dense column ``live[j]`` (by :func:`densify_compare`, which
+at such widths is cheaper than the scatter and gives the same bits); the
+model contracts over those columns only (``QuantileGRU.__call__`` with
+``live_cols``).  The dense ``[..., W, F]`` window is never built.
+Dropping exact zeros from a sum loses nothing, but the sum's order
+changes, so the compact form agrees with the dense form to float
+tolerance, not bit for bit.  With ``live=None`` (a wide live set, or F
+sharded over the mesh's ``model`` axis) everything above holds unchanged.
 """
 
 from __future__ import annotations
@@ -44,6 +63,8 @@ except Exception:  # pragma: no cover - jax is a hard dep of the repo
 
 
 DEFAULT_NNZ_CAP = 64
+MIN_COMPACT_WIDTH = 128          # one lane tile: no narrower table
+COMPARE_MAX_WIDTH = 4096         # densify_compare beats the scatter up to here
 
 
 if _HAVE_JAX:
@@ -59,13 +80,24 @@ if _HAVE_JAX:
         ``mn``/``rg`` runtime arguments.  ``capacity`` is the static
         dense width — a Python int excluded from the pytree so jit
         treats it as a compile-time constant.
+
+        ``live`` is None (windows are ``capacity`` wide) or the sorted
+        ``[U_pad]`` table of :func:`compact_table`: ``cols`` then hold
+        ranks in it, ``mn``/``rg`` the statistics at it, and windows are
+        ``U_pad`` wide (module docstring, the compact form).
         """
 
         cols: object                 # [T, K] int32 device array
         vals: object                 # [T, K] float32 device array
         mn: object                   # broadcastable x_stats.min
         rg: object                   # broadcastable x_stats.range
+        live: object = None          # [U_pad] int32 dense columns, or None
         capacity: int = flax.struct.field(pytree_node=False, default=0)
+
+        @property
+        def width(self) -> int:
+            """Columns of a gathered window: U_pad, or the dense F."""
+            return self.capacity if self.live is None else self.live.shape[0]
 
     def densify_coo(cols, vals, capacity: int):
         """``(cols[..., K], vals[..., K])`` padded-COO → ``[..., capacity]``.
@@ -83,6 +115,18 @@ if _HAVE_JAX:
         out = out.at[idx].add(flat_v.reshape(-1))
         return out.reshape(*cols.shape[:-1], capacity)
 
+    def densify_compare(cols, vals, width: int):
+        """:func:`densify_coo` without a scatter: every output column
+        compares itself with the row's K entries and sums the values that
+        hit.  The same bits (at most one real entry hits, the rest add
+        exact zeros), K x ``width`` compares a row where the scatter takes
+        one serial update an entry: 0.09 / 0.16 / 0.36 / 0.82 ms at a
+        width of 256 / 1024 / 2048 / 4096 against the scatter's 1.0-1.2 ms
+        at any width (1,920 rows of K=64 on a TPU v5e; PERF.md, PR 25),
+        so it is taken up to :data:`COMPARE_MAX_WIDTH` columns."""
+        hit = cols[..., :, None] == jnp.arange(width, dtype=cols.dtype)
+        return jnp.sum(jnp.where(hit, vals[..., :, None], 0.0), axis=-2)
+
     def normalize_minmax(x, mn, rg):
         """The exact device mirror of ``MinMaxStats.apply`` (degenerate
         ranges pass through raw)."""
@@ -92,9 +136,12 @@ if _HAVE_JAX:
     @jax.named_scope(scopes.DENSIFY)
     def gather_densify_normalize(base: "SparseBase", idx):
         """Window gather + densify + normalize for a staged sparse base:
-        ``idx [..., W]`` start-expanded row indices → normalized dense
-        ``[..., W, capacity]`` windows, all inside the caller's jit."""
-        x = densify_coo(base.cols[idx], base.vals[idx], base.capacity)
+        ``idx [..., W]`` start-expanded row indices → normalized
+        ``[..., W, base.width]`` windows (dense, or the live columns of
+        the compact form), all inside the caller's jit."""
+        compare = base.live is not None and base.width <= COMPARE_MAX_WIDTH
+        x = (densify_compare if compare else densify_coo)(
+            base.cols[idx], base.vals[idx], base.width)
         return normalize_minmax(x, base.mn, base.rg)
 
 
@@ -194,12 +241,73 @@ def sparse_minmax(cols: np.ndarray, vals: np.ndarray, nnz: np.ndarray,
                        max=mx[None, :].astype(np.float32))
 
 
+def live_columns(cols: np.ndarray, vals: np.ndarray, mn: np.ndarray,
+                 rg: np.ndarray, capacity: int) -> np.ndarray:
+    """The sorted dense columns whose NORMALIZED value can be nonzero in
+    some row of the padded-COO ``cols``/``vals``.
+
+    A column is live when it carries a nonzero value somewhere, or when
+    its statistics turn the raw 0 into a nonzero constant
+    (``normalize_minmax(0, mn, rg) != 0``: ``rg != 0 and mn != 0``, which
+    statistics carried over from another span can give a column this
+    corpus never saw).  ``mn``/``rg`` are one scalar or ``[capacity]``.
+    """
+    cols = np.asarray(cols)
+    if cols.size and (cols.min() < 0 or cols.max() >= capacity):
+        raise ValueError(f"padded-COO columns outside [0, {capacity})")
+    seen = np.zeros((capacity,), bool)
+    seen[cols[np.asarray(vals) != 0]] = True
+    shifted = np.broadcast_to(
+        (np.asarray(rg).reshape(-1) != 0) & (np.asarray(mn).reshape(-1) != 0),
+        (capacity,))
+    return np.flatnonzero(seen | shifted).astype(np.int32)
+
+
+def compact_table(live: np.ndarray, capacity: int) -> np.ndarray | None:
+    """The ``[U_pad]`` table of the compact form, or None where the dense
+    form is kept: ``U_pad`` is the next power of two at or above
+    ``max(len(live), MIN_COMPACT_WIDTH)`` (so a live set that grows from
+    one staging to the next meets a handful of shapes, not one each), and
+    the form is compact when ``U_pad <= capacity // 4``: what the compact
+    form saves grows with F and what its takes, their layout copies and
+    its matmuls cost grows with the table, and on a TPU v5e at F = 10,240
+    a table of 2,048 trains 8% faster than the dense form and one of
+    4,096 14% slower (PERF.md, PR 25).  The pad slots are the lowest dead
+    columns, so the sorted table names ``U_pad`` distinct columns and
+    every pad slot's input is exactly 0."""
+    u_pad = max(MIN_COMPACT_WIDTH, 1 << max(len(live) - 1, 0).bit_length())
+    if u_pad > capacity // 4:
+        return None
+    dead = np.setdiff1d(np.arange(capacity, dtype=np.int32), live,
+                        assume_unique=True)
+    return np.sort(np.concatenate([live, dead[:u_pad - len(live)]])
+                   ).astype(np.int32)
+
+
+def compact_rows(cols: np.ndarray, vals: np.ndarray,
+                 table: np.ndarray) -> np.ndarray:
+    """``cols`` as ranks in ``table``.  Entries whose value is 0 (the
+    ``(0, 0.0)`` padding, or an explicit zero count) may name a column
+    outside the table; they add 0.0 wherever they land, so they go to
+    rank 0."""
+    ranks = np.minimum(np.searchsorted(table, cols), len(table) - 1)
+    real = np.asarray(vals) != 0
+    if not np.array_equal(table[ranks][real], np.asarray(cols)[real]):
+        raise ValueError("a nonzero traffic column is not in the live table")
+    return np.where(real, ranks, 0).astype(np.int32)
+
+
 __all__ = [
+    "COMPARE_MAX_WIDTH",
     "DEFAULT_NNZ_CAP",
+    "MIN_COMPACT_WIDTH",
+    "compact_rows",
+    "compact_table",
+    "live_columns",
     "densify_rows",
     "sparsify_rows",
     "sparse_minmax",
 ]
 if _HAVE_JAX:
-    __all__ += ["SparseBase", "densify_coo", "normalize_minmax",
-                "gather_densify_normalize"]
+    __all__ += ["SparseBase", "densify_coo", "densify_compare",
+                "normalize_minmax", "gather_densify_normalize"]

@@ -184,7 +184,8 @@ def feed_global_coo(mesh: Mesh, cols: np.ndarray, vals: np.ndarray,
 
 
 def stage_sparse_base(mesh: Mesh, cols: np.ndarray, vals: np.ndarray,
-                      mn: np.ndarray, rg: np.ndarray, capacity: int):
+                      mn: np.ndarray, rg: np.ndarray, capacity: int,
+                      live: np.ndarray | None = None):
     """Replicated device residency for a padded-COO BASE series plus its
     normalization stats — the sparse twin of the trainer's staged dense
     base (every process holds the same rows; per-step feeds are then just
@@ -192,15 +193,22 @@ def stage_sparse_base(mesh: Mesh, cols: np.ndarray, vals: np.ndarray,
     static ``capacity`` the consuming jit treats as a compile-time
     constant.  Stats ride as device arrays (runtime ARGUMENTS — baked
     constants would let XLA strength-reduce the normalize divide and
-    break bit parity; the serve/fused.py lesson)."""
-    from deeprest_tpu.ops.densify import SparseBase
+    break bit parity; the serve/fused.py lesson).  With ``live`` (the
+    table of ``ops.densify.compact_table``) the base is staged in the
+    compact form: ``cols`` as ranks in the table, the statistics at it."""
+    from deeprest_tpu.ops.densify import SparseBase, compact_rows
 
+    cols = np.asarray(cols, np.int32)
+    mn, rg = np.asarray(mn, np.float32), np.asarray(rg, np.float32)
+    if live is not None:
+        cols = compact_rows(cols, vals, live)
+        mn, rg = (a if a.size == 1 else a[live] for a in (mn, rg))
+        live = feed_replicated(mesh, np.asarray(live, np.int32))
     return SparseBase(
-        cols=feed_replicated(mesh, np.asarray(cols, np.int32)),
+        cols=feed_replicated(mesh, cols),
         vals=feed_replicated(mesh, np.asarray(vals, np.float32)),
-        mn=feed_replicated(mesh, np.asarray(mn, np.float32)),
-        rg=feed_replicated(mesh, np.asarray(rg, np.float32)),
-        capacity=int(capacity))
+        mn=feed_replicated(mesh, mn), rg=feed_replicated(mesh, rg),
+        live=live, capacity=int(capacity))
 
 
 def prefetch_to_device(mesh: Mesh, batches, depth: int = 2):

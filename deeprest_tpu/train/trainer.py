@@ -31,7 +31,9 @@ from deeprest_tpu.obs import metrics as obs_metrics
 from deeprest_tpu.obs import spans as obs_spans
 from deeprest_tpu.obs.phases import PhaseClock
 from deeprest_tpu.ops import scopes
-from deeprest_tpu.ops.densify import SparseBase, gather_densify_normalize
+from deeprest_tpu.ops.densify import (
+    SparseBase, compact_table, gather_densify_normalize, live_columns,
+)
 from deeprest_tpu.ops.quantile import pinball_loss
 from deeprest_tpu.parallel.distributed import (
     feed_replicated, gather_to_host, prefetch_to_device, stage_plan,
@@ -174,13 +176,13 @@ class Trainer:
         def dropout_key(state: TrainState):
             return jax.random.fold_in(state.rng, state.step)
 
-        def train_step(state: TrainState, xb, yb, wb):
+        def train_step(state: TrainState, xb, yb, wb, live_cols=None):
             dropout_rng = dropout_key(state)
 
             def loss_fn(params):
                 preds = self.model.apply(
                     {"params": params}, xb, deterministic=False,
-                    rngs={"dropout": dropout_rng},
+                    rngs={"dropout": dropout_rng}, live_cols=live_cols,
                 )
                 return pinball_loss(preds, yb, quantiles, sample_weight=wb)
 
@@ -199,10 +201,15 @@ class Trainer:
             # rows, densifies via one scatter-add, and normalizes ON
             # DEVICE — all inside the caller's existing jit, so the
             # sparse feed adds no executables beyond the per-form
-            # signature (ops/densify.py for the numerics contract).
+            # signature (ops/densify.py for the numerics contract).  A
+            # base staged in the compact form gives windows of its live
+            # columns only, for the model to take with live_cols_of().
             if isinstance(x_base, SparseBase):
                 return gather_densify_normalize(x_base, idx)
             return x_base[idx]
+
+        def live_cols_of(x_base):
+            return x_base.live if isinstance(x_base, SparseBase) else None
 
         @jax.named_scope(scopes.GATHER)
         def gather_windows(x_base, y_base, starts):
@@ -217,7 +224,7 @@ class Trainer:
             # instead of the [B,W,F] window tensor (windows overlap W−1 of
             # W rows, so materialized shipping re-sends every row W times).
             return train_step(state, *gather_windows(x_base, y_base, starts),
-                              wb)
+                              wb, live_cols_of(x_base))
 
         def train_superstep(state: TrainState, x_base, y_base,
                             starts_plan, weights_plan, chunk):
@@ -310,7 +317,8 @@ class Trainer:
                 def loss_fn(pf):
                     preds = self.model.apply(
                         {"params": pf}, xb, deterministic=False,
-                        rngs={"dropout": key}, mask_folded=True)
+                        rngs={"dropout": key}, mask_folded=True,
+                        live_cols=live_cols_of(x_base))
                     return pinball_loss(preds, yb, quantiles,
                                         sample_weight=wb_g, allow_empty=True)
 
@@ -333,7 +341,8 @@ class Trainer:
             def loss_fn(params):
                 preds = self.model.apply(
                     {"params": params}, x4, deterministic=False,
-                    rngs={"dropout": step_key})              # [G,B,T,E,Q]
+                    rngs={"dropout": step_key},
+                    live_cols=live_cols_of(x_base))          # [G,B,T,E,Q]
                 losses = jax.vmap(
                     lambda p, y, w: pinball_loss(p, y, quantiles,
                                                  sample_weight=w,
@@ -353,7 +362,8 @@ class Trainer:
                 def loss_fn(params, g=g, xb=xb, yb=yb):
                     preds = self.model.apply(
                         {"params": params}, xb, deterministic=False,
-                        rngs={"dropout": jax.random.fold_in(step_key, g)})
+                        rngs={"dropout": jax.random.fold_in(step_key, g)},
+                        live_cols=live_cols_of(x_base))
                     return pinball_loss(preds, yb, quantiles,
                                         sample_weight=wb[g], allow_empty=True)
 
@@ -409,13 +419,15 @@ class Trainer:
             state, losses = jax.lax.scan(body, state, (starts_c, weights_c))
             return state, losses.reshape(-1)                 # [S] f32
 
-        def eval_step(params, xb, yb):
-            preds = self.model.apply({"params": params}, xb, deterministic=True)
+        def eval_step(params, xb, yb, live_cols=None):
+            preds = self.model.apply({"params": params}, xb,
+                                     deterministic=True, live_cols=live_cols)
             loss = pinball_loss(preds, yb, quantiles)
             return preds, loss
 
         def eval_step_indexed(params, x_base, y_base, starts):
-            return eval_step(params, *gather_windows(x_base, y_base, starts))
+            return eval_step(params, *gather_windows(x_base, y_base, starts),
+                             live_cols_of(x_base))
 
         self._train_step = jax.jit(train_step, donate_argnums=0)
         self._train_step_indexed = jax.jit(train_step_indexed, donate_argnums=0)
@@ -451,6 +463,11 @@ class Trainer:
                 labelnames=("phase",)),
             units_total=obs_metrics.REGISTRY.counter(
                 "deeprest_train_epochs_total", "train epochs finished"))
+        self._m_projection_columns = obs_metrics.REGISTRY.gauge(
+            "deeprest_train_projection_columns",
+            "columns of the staged sparse corpus: live (can be nonzero), "
+            "contracted (the layer-0 projection sums over), total (F)",
+            labelnames=("kind",))
         self._m_executables = obs_metrics.REGISTRY.gauge(
             "deeprest_train_jit_executables",
             "compiled executables across the trainer's jitted programs "
@@ -926,7 +943,13 @@ class Trainer:
         dense path is pinned by tests/test_sparse.py).  Unlike the dense
         "auto" rule this stages on the CPU backend too: the sparse feed
         IS the staged feed — there is no host-windowed fallback to
-        prefer."""
+        prefer.
+
+        The form is chosen here, from the corpus and the mesh alone: the
+        compact form (ops/densify.py: windows and the layer-0 contraction
+        over the live call paths only) when the padded live set is at
+        most a quarter of F and the mesh's ``model`` axis, which shards
+        F, is 1; the dense form otherwise."""
         cfg = self.config.train
         if bundle.y_base is None:
             raise ValueError("sparse bundle lacks y_base; the targets "
@@ -942,11 +965,18 @@ class Trainer:
         x_stats = bundle.x_stats
         mn = np.asarray(x_stats.min, np.float32).reshape(-1)
         rg = np.asarray(x_stats.range, np.float32).reshape(-1)
-        base = stage_sparse_base(
-            self.mesh,
-            np.ascontiguousarray(bundle.x_cols, dtype=np.int32),
-            np.ascontiguousarray(bundle.x_vals, dtype=np.float32),
-            mn, rg, int(bundle.sparse_capacity or bundle.feature_dim))
+        cols = np.ascontiguousarray(bundle.x_cols, dtype=np.int32)
+        vals = np.ascontiguousarray(bundle.x_vals, dtype=np.float32)
+        capacity = int(bundle.sparse_capacity or bundle.feature_dim)
+        live = live_columns(cols, vals, mn, rg, capacity)
+        table = (compact_table(live, capacity)
+                 if self.mesh.shape["model"] == 1 else None)
+        contracted = capacity if table is None else len(table)
+        for kind, n in (("live", len(live)), ("contracted", contracted),
+                        ("total", capacity)):
+            self._m_projection_columns.set(n, kind=kind)
+        base = stage_sparse_base(self.mesh, cols, vals, mn, rg, capacity,
+                                 live=table)
         return base, feed_replicated(self.mesh, np.asarray(bundle.y_base))
 
     def train_epoch(self, state: TrainState, bundle: DatasetBundle,

@@ -34,10 +34,7 @@ BATCH, LOG_EVERY, SUPERSTEP = 8, 4, 3
 NAMES = frozenset(scopes.STEP_SCOPES + scopes.KERNELS)
 
 
-@pytest.fixture(scope="module")
-def tiny():
-    """A sparse-feed trainer, its corpus staged: the path the benchmark's
-    cell takes (gather + densify inside the superstep)."""
+def _staged_trainer(featurize: FeaturizeConfig):
     cfg = Config(
         model=ModelConfig(hidden_size=8, dropout_rate=0.1),
         train=TrainConfig(batch_size=BATCH, window_size=10, seed=0,
@@ -45,8 +42,7 @@ def tiny():
                           steps_per_superstep=SUPERSTEP,
                           device_data="always", sparse_feed=True,
                           sparse_nnz_cap=48))
-    data = featurize_buckets(make_series_buckets(200, seed=5),
-                             FeaturizeConfig(round_to=8))
+    data = featurize_buckets(make_series_buckets(200, seed=5), featurize)
     bundle = prepare_dataset(data, cfg.train)
     trainer = Trainer(cfg, bundle.feature_dim, bundle.metric_names)
     state = trainer.init_state(trainer.sample_input(bundle))
@@ -54,6 +50,24 @@ def tiny():
     assert staged is not None
     return {"trainer": trainer, "bundle": bundle, "state": state,
             "staged": staged, "rng": np.random.default_rng(0)}
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    """A sparse-feed trainer, its corpus staged: the path the benchmark's
+    cell takes (gather + densify inside the superstep).  Every call path
+    of the corpus is a column, so the feed keeps the dense form."""
+    return _staged_trainer(FeaturizeConfig(round_to=8))
+
+
+@pytest.fixture(scope="module")
+def tiny_compact():
+    """The same corpus hashed into 512 columns: the live paths are few of
+    F and the feed takes the compact form (ops/densify.py), as the
+    benchmark's cell does."""
+    out = _staged_trainer(FeaturizeConfig(hash_features=True, capacity=512))
+    assert out["staged"][0].live is not None
+    return out
 
 
 def _epoch(tiny):
@@ -285,6 +299,44 @@ def test_nested_phase_is_timed_exclusively_and_a_failed_unit_publishes_nothing()
             with phase("nope"):
                 pass
     assert clock.units_total.value() == 1
+
+
+def test_the_compact_superstep_carries_every_scope(tiny_compact):
+    """The compact form (ISSUE 25) keeps every name, `densify`, `mask` and
+    `in_proj` among them, so `profile_epoch`'s table keeps its rows.  One
+    lowering of the superstep on the staged arguments; nothing runs."""
+    from deeprest_tpu.parallel.distributed import stage_plan
+
+    trainer, bundle = tiny_compact["trainer"], tiny_compact["bundle"]
+    starts, weights, _ = trainer._epoch_plan(
+        bundle.num_train_windows, np.random.default_rng(0), SUPERSTEP)
+    text = trainer._superstep.lower(
+        tiny_compact["state"], *tiny_compact["staged"],
+        *stage_plan(trainer.mesh, starts, weights), 0).compile().as_text()
+    found = set(profiler.scope_table(text, NAMES).values())
+    for scope in scopes.STEP_SCOPES:
+        assert (scope, "fwd") in found, scope
+    for scope in ("mask", "in_proj", "recurrence", "mixing", "heads", "loss"):
+        assert (scope, "bwd") in found, scope
+
+
+def test_staging_sets_the_projection_columns_gauge(tiny, tiny_compact):
+    """`deeprest_train_projection_columns` (-> `proj_columns_pct.train`):
+    set once per staged sparse corpus, on either side of the rule."""
+    gauge = REGISTRY.get("deeprest_train_projection_columns")
+
+    def read():
+        return [gauge.value(kind=k) for k in ("live", "contracted", "total")]
+
+    dense, compact = tiny["staged"][0], tiny_compact["staged"][0]
+    tiny["trainer"].stage_dataset(tiny["bundle"])
+    live, contracted, total = read()
+    assert dense.live is None
+    assert 0 < live <= contracted == total == dense.capacity
+    tiny_compact["trainer"].stage_dataset(tiny_compact["bundle"])
+    live, contracted, total = read()
+    assert 0 < live <= contracted == compact.width == 128
+    assert total == compact.capacity == 512
 
 
 # -- (e) idle gaps under the innermost program span -------------------------
