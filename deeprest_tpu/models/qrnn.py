@@ -51,15 +51,19 @@ windows of those ``U_pad`` columns only, with the table that names them
 (``live_cols``).  Layer 0 then takes the same columns of the soft mask and
 of ``w_ih`` (:func:`take_columns`), folds and projects ``[B, T, U_pad] x
 [E, U_pad, 3H]``: the sum over F without its exact-zero terms.  The mask's
-softmax still runs over all F, every parameter keeps its shape, and the
-take's transpose hands the optimizer a dense gradient that is zero at the
-columns left out.  Without ``live_cols`` (every dense feed, serving) the
-call is what it was.
+softmax still runs over all F and every parameter keeps its shape.  A
+caller that differentiates with respect to ``w_ih`` gets the take's
+transpose: a dense gradient that is zero at the columns left out (the
+per-step programs, the accumulation supersteps).  One that hands in the
+taken rows itself (``live_w_ih``; the compact superstep, PR 27) gets their
+gradient as ``[E, U_pad, 3H]`` and no dense one, and runs Adam on those
+rows alone (``train/trainer.py``).  Without ``live_cols`` (every dense
+feed, serving) the call is what it was.
 """
 
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, Mapping
 
 import flax.linen as nn
 import jax
@@ -124,6 +128,16 @@ def take_columns(a: jax.Array, live_cols: jax.Array) -> jax.Array:
                                   mode="promise_in_bounds")
 
 
+def put_columns(a: jax.Array, live_cols: jax.Array,
+                rows: jax.Array) -> jax.Array:
+    """``a`` with ``rows`` at ``a[:, live_cols]``: what :func:`take_columns`
+    took, put back under the same promises, so that on a donated or
+    loop-carried ``a`` the scatter writes in place."""
+    return a.at[:, live_cols].set(rows, unique_indices=True,
+                                  indices_are_sorted=True,
+                                  mode="promise_in_bounds")
+
+
 def fold_feature_mask(params):
     """Fold the soft mask into the layer-0 input weights, tree-level.
 
@@ -158,10 +172,16 @@ class QuantileGRU(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array, *, deterministic: bool = True,
                  mask_folded: bool = False,
-                 live_cols: jax.Array | None = None) -> jax.Array:
+                 live_cols: jax.Array | None = None,
+                 live_w_ih: Mapping[str, jax.Array] | None = None
+                 ) -> jax.Array:
         """``live_cols`` (``[U_pad]`` int32, sorted dense column indices):
         ``x`` is ``[..., U_pad]`` and holds those columns only; every
-        column left out is zero in the dense input (module docstring)."""
+        column left out is zero in the dense input (module docstring).
+        ``live_w_ih`` (with ``live_cols``): ``take_columns(w_ih,
+        live_cols)`` of every ``MASKED_PARAM_NAMES`` leaf, by the leaf's
+        name, from a caller that differentiates with respect to the taken
+        rows; the leaves in the params tree are then not read."""
         cfg = self.config
         e, f, h, q = cfg.num_metrics, cfg.feature_dim, cfg.hidden_size, len(cfg.quantiles)
         if live_cols is None and x.shape[-1] != f:
@@ -169,6 +189,9 @@ class QuantileGRU(nn.Module):
         if live_cols is not None and x.shape[-1] != live_cols.shape[0]:
             raise ValueError(f"input feature dim {x.shape[-1]} != the "
                              f"{live_cols.shape[0]} live columns")
+        if live_w_ih is not None and live_cols is None:
+            raise ValueError("live_w_ih holds the rows live_cols names; "
+                             "live_cols is missing")
         compute_dtype = jnp.dtype(cfg.compute_dtype)
 
         # Group axis (coalescing plumbing): [G, B, T, F] folds its groups
@@ -226,9 +249,13 @@ class QuantileGRU(nn.Module):
         if live_cols is not None and mask is not None:
             mask = take_columns(mask, live_cols)                      # [E, U]
 
-        def masked(p: GRUParams) -> GRUParams:
-            w_ih = (p.w_ih if live_cols is None
-                    else take_columns(p.w_ih, live_cols))
+        def masked(p: GRUParams, name: str) -> GRUParams:
+            if live_cols is None:
+                w_ih = p.w_ih
+            elif live_w_ih is None:
+                w_ih = take_columns(p.w_ih, live_cols)
+            else:
+                w_ih = live_w_ih[name]
             return p._replace(
                 w_ih=w_ih if mask is None else _fold(mask, w_ih))
 
@@ -241,11 +268,11 @@ class QuantileGRU(nn.Module):
             in_dim = f if layer == 0 else cfg.rnn_out_dim
             fwd = gru_params(f"gru_fwd{sfx}", in_dim)
             if layer == 0:
-                fwd = masked(fwd)
+                fwd = masked(fwd, MASKED_PARAM_NAMES[0])
             if cfg.bidirectional:
                 bwd = gru_params(f"gru_bwd{sfx}", in_dim)
                 if layer == 0:
-                    bwd = masked(bwd)
+                    bwd = masked(bwd, MASKED_PARAM_NAMES[1])
                 out = bidirectional_gru(cast(fwd), cast(bwd), out,
                                         backend=cfg.rnn_backend,
                                         mesh=self.mesh)

@@ -17,6 +17,7 @@ one batched jit call instead of batch-1 Python loops.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Mapping
 
 import flax.struct
@@ -26,7 +27,10 @@ import numpy as np
 import optax
 
 from deeprest_tpu.config import Config
-from deeprest_tpu.models.qrnn import QuantileGRU, fold_feature_mask
+from deeprest_tpu.models.qrnn import (
+    MASKED_PARAM_NAMES, QuantileGRU, fold_feature_mask, put_columns,
+    take_columns,
+)
 from deeprest_tpu.obs import metrics as obs_metrics
 from deeprest_tpu.obs import spans as obs_spans
 from deeprest_tpu.obs.phases import PhaseClock
@@ -64,6 +68,87 @@ class TrainState:
     params: Any
     opt_state: Any
     rng: jax.Array
+
+
+def _names_param(path) -> bool:
+    """A leaf of a params mapping or of a mirror of it (the last key of
+    its path is a mapping's), not bookkeeping such as Adam's ``count``."""
+    return isinstance(path[-1], jax.tree_util.DictKey)
+
+
+def _is_w_ih(path) -> bool:
+    return _names_param(path) and path[-1].key in MASKED_PARAM_NAMES
+
+
+def live_cols_of(x_base):
+    """The table of a base staged in the compact form, else None."""
+    return x_base.live if isinstance(x_base, SparseBase) else None
+
+
+def w_ih_leaves(tree) -> list:
+    """The ``MASKED_PARAM_NAMES`` leaves of a params tree, or of the
+    optimizer state's mirrors of it (Adam's ``mu`` and ``nu``)."""
+    return [a for path, a in jax.tree_util.tree_leaves_with_path(tree)
+            if _is_w_ih(path)]
+
+
+def take_w_ih(tree, live_cols):
+    """``tree`` with :func:`take_columns` of each such leaf in its place:
+    ``[E, U_pad, 3H]`` for ``[E, F, 3H]``, every other leaf as it is."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, a: take_columns(a, live_cols) if _is_w_ih(path) else a,
+        tree)
+
+
+def put_w_ih(tree, rows, live_cols):
+    """The inverse: ``rows`` (a tree as :func:`take_w_ih` gives it), with
+    each such leaf written back into ``tree``'s full one."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, a, new: (put_columns(a, live_cols, new)
+                              if _is_w_ih(path) else new), tree, rows)
+
+
+def only_w_ih(tree, which: bool):
+    """``tree`` without its w_ih leaves (``which`` False) or without every
+    other leaf of params and of its mirrors (True): the leaves left out
+    are None, which a pytree does not count; what is no mirror of a
+    parameter (Adam's ``count``) stays in both."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, a: a if (not _names_param(path)
+                              or _is_w_ih(path) == which) else None, tree)
+
+
+def merge_leaves(a, b):
+    """Two trees of one structure whose None leaves complement each other
+    (:func:`only_w_ih`), as one; a leaf both hold is taken from ``a``."""
+    return jax.tree.map(lambda x, y: y if x is None else x, a, b,
+                        is_leaf=lambda x: x is None)
+
+
+def untake_w_ih(rows, like, live_cols):
+    """:func:`take_w_ih`'s transpose, as differentiating through the take
+    gives it: each such leaf of ``rows`` scattered into zeros of the shape
+    of ``like``'s (a gradient over all F rows, zero off the table)."""
+    def untake(path, g, full):
+        if not _is_w_ih(path):
+            return g
+        return jax.linear_transpose(
+            lambda a: take_columns(a, live_cols),
+            jax.ShapeDtypeStruct(full.shape, full.dtype))(g)[0]
+    return jax.tree_util.tree_map_with_path(untake, rows, like)
+
+
+def moments_off_table_are_zero(opt_state, live_cols) -> jax.Array:
+    """Whether every moment of the w_ih leaves is exactly zero at every
+    row ``live_cols`` does not name.  Adam's step on a row whose gradient
+    and moments are zero is zero, so while this holds and the gradient
+    lives on the table, Adam on the table's rows IS Adam (NaN counts as
+    nonzero)."""
+    off = functools.reduce(jnp.logical_or, [
+        jnp.any(a != 0, axis=(0, 2)) for a in w_ih_leaves(opt_state)])  # [F]
+    return ~jnp.any(off.at[live_cols].set(
+        False, unique_indices=True, indices_are_sorted=True,
+        mode="promise_in_bounds"))
 
 
 @dataclasses.dataclass
@@ -168,26 +253,78 @@ class Trainer:
         self._pin_state = jax.jit(pin_state)
 
         @jax.named_scope(scopes.OPTIMIZER)
-        def apply_gradients(state: TrainState, grads):
-            updates, opt_state = self.tx.update(grads, state.opt_state)
-            return optax.apply_updates(state.params, updates), opt_state
+        def apply_gradients(state: TrainState, grads, live_cols=None,
+                            rows_ok=None):
+            # With `rows_ok` (the compact superstep; a scalar of the
+            # dispatch) `grads` holds the w_ih leaves' gradient at the
+            # table's rows only.  Where it is true the same tx.update
+            # runs on those rows of the two leaves and of their moments,
+            # with the shared count, and each is written back in place;
+            # where it is not, the rows are scattered into a gradient
+            # over all F rows (what differentiating through the take
+            # gives) and the update runs over them all.  Only the two
+            # leaves pass through the conditional: every other leaf's
+            # update stays where the standalone step has it, fused as
+            # there, so its rounding is the standalone step's.
+            def on_all(state, grads):
+                updates, opt_state = self.tx.update(grads, state.opt_state)
+                return optax.apply_updates(state.params, updates), opt_state
+
+            def on_rows(state, grads):
+                updates, opt_rows = self.tx.update(
+                    grads, take_w_ih(state.opt_state, live_cols))
+                params_rows = optax.apply_updates(
+                    take_w_ih(state.params, live_cols), updates)
+                return (put_w_ih(state.params, params_rows, live_cols),
+                        put_w_ih(state.opt_state, opt_rows, live_cols))
+
+            def on_all_from_rows(state, grads):
+                return on_all(state,
+                              untake_w_ih(grads, state.params, live_cols))
+
+            if rows_ok is None:
+                return on_all(state, grads)
+
+            def part(w_ih: bool):
+                return (state.replace(
+                    params=only_w_ih(state.params, w_ih),
+                    opt_state=only_w_ih(state.opt_state, w_ih)),
+                    only_w_ih(grads, w_ih))
+
+            return merge_leaves(
+                on_all(*part(False)),
+                jax.lax.cond(rows_ok, on_rows, on_all_from_rows,
+                             *part(True)))
 
         @jax.named_scope(scopes.DROPOUT)
         def dropout_key(state: TrainState):
             return jax.random.fold_in(state.rng, state.step)
 
-        def train_step(state: TrainState, xb, yb, wb, live_cols=None):
+        def train_step(state: TrainState, xb, yb, wb, live_cols=None,
+                       rows_ok=None):
+            # With `rows_ok` (and live_cols): differentiate with respect
+            # to the table's rows of the w_ih leaves, which the model is
+            # handed in the leaves' place, so their gradient exists as
+            # [E, U_pad, 3H] only; the full leaves ride along unread
+            # (flax holds a supplied leaf to its init shape).
             dropout_rng = dropout_key(state)
+            w_ih = ({k: v for k, v in state.params.items()
+                     if k in MASKED_PARAM_NAMES} if rows_ok is not None
+                    else {})
 
             def loss_fn(params):
                 preds = self.model.apply(
-                    {"params": params}, xb, deterministic=False,
+                    {"params": {**params, **w_ih}}, xb, deterministic=False,
                     rngs={"dropout": dropout_rng}, live_cols=live_cols,
+                    live_w_ih={k: params[k] for k in w_ih} or None,
                 )
                 return pinball_loss(preds, yb, quantiles, sample_weight=wb)
 
-            loss, grads = jax.value_and_grad(loss_fn)(state.params)
-            params, opt_state = apply_gradients(state, grads)
+            loss, grads = jax.value_and_grad(loss_fn)(
+                state.params if rows_ok is None
+                else take_w_ih(state.params, live_cols))
+            params, opt_state = apply_gradients(state, grads, live_cols,
+                                                rows_ok)
             return (
                 pin_state(TrainState(step=state.step + 1, params=params,
                                      opt_state=opt_state, rng=state.rng)),
@@ -208,23 +345,21 @@ class Trainer:
                 return gather_densify_normalize(x_base, idx)
             return x_base[idx]
 
-        def live_cols_of(x_base):
-            return x_base.live if isinstance(x_base, SparseBase) else None
-
         @jax.named_scope(scopes.GATHER)
         def gather_windows(x_base, y_base, starts):
             w = self.config.train.window_size
             idx = starts[:, None] + jnp.arange(w)[None, :]    # [B, W]
             return gather_x(x_base, idx), y_base[idx]
 
-        def train_step_indexed(state: TrainState, x_base, y_base, starts, wb):
+        def train_step_indexed(state: TrainState, x_base, y_base, starts, wb,
+                               rows_ok=None):
             # Device-resident feed: the normalized BASE series live in HBM
             # (stage_dataset) and each step gathers its windows by start
             # index — per-step host→device traffic is [B] int32 + weights
             # instead of the [B,W,F] window tensor (windows overlap W−1 of
             # W rows, so materialized shipping re-sends every row W times).
             return train_step(state, *gather_windows(x_base, y_base, starts),
-                              wb, live_cols_of(x_base))
+                              wb, live_cols_of(x_base), rows_ok)
 
         def train_superstep(state: TrainState, x_base, y_base,
                             starts_plan, weights_plan, chunk):
@@ -247,12 +382,32 @@ class Trainer:
             weights_c = jax.lax.dynamic_index_in_dim(
                 weights_plan, chunk, 0, keepdims=False)      # [S, B]
 
+            # A compact base's gradient lives on its table's rows of the
+            # w_ih leaves, and the step takes it there and nowhere else.
+            # Where their moments are zero off the table (a state from
+            # init_state, or one trained on this table only), Adam leaves
+            # every other row as it is, and the step updates the table's
+            # rows alone; it keeps that true, so one look a dispatch is
+            # enough.  Moments off the table (another corpus's, a
+            # dense-form run's) still move their rows: for such a state
+            # the step scatters the gradient and updates all F rows, as
+            # every other feed does.  The choice sits round the optimizer
+            # inside the step, on the dispatch's scalar: a cond round two
+            # scans gives each loop its own copy of the six big leaves
+            # (3.8 GB more), one round two whole steps doubles the
+            # program (5 s more to load it from the cache).
+            live_cols = live_cols_of(x_base)
+            rows_ok = (
+                moments_off_table_are_zero(state.opt_state, live_cols)
+                if live_cols is not None and w_ih_leaves(state.params)
+                else None)
+
             def body(st, step_plan):
                 starts, wb = step_plan
 
                 def run(s):
                     s2, loss = train_step_indexed(s, x_base, y_base,
-                                                  starts, wb)
+                                                  starts, wb, rows_ok)
                     # f32 losses regardless of compute dtype so the skip
                     # branch's zero matches the run branch's aval.
                     return s2, loss.astype(jnp.float32)
@@ -432,6 +587,7 @@ class Trainer:
         self._train_step = jax.jit(train_step, donate_argnums=0)
         self._train_step_indexed = jax.jit(train_step_indexed, donate_argnums=0)
         self._superstep = jax.jit(train_superstep, donate_argnums=0)
+        self._moments_off_table_are_zero = jax.jit(moments_off_table_are_zero)
         self._accum_superstep = jax.jit(train_accum_superstep, donate_argnums=0)
         self._eval_step = jax.jit(eval_step)
         self._eval_step_indexed = jax.jit(eval_step_indexed)
@@ -468,6 +624,11 @@ class Trainer:
             "columns of the staged sparse corpus: live (can be nonzero), "
             "contracted (the layer-0 projection sums over), total (F)",
             labelnames=("kind",))
+        self._m_optimizer_rows = obs_metrics.REGISTRY.gauge(
+            "deeprest_train_optimizer_rows",
+            "rows of each layer-0 input weight that the last epoch's Adam "
+            "steps on a staged sparse corpus wrote (updated), of F (total)",
+            labelnames=("kind",))
         self._m_executables = obs_metrics.REGISTRY.gauge(
             "deeprest_train_jit_executables",
             "compiled executables across the trainer's jitted programs "
@@ -501,8 +662,9 @@ class Trainer:
         sizes = []
         for fn in (self._train_step, self._train_step_indexed,
                    self._superstep, self._accum_superstep,
-                   self._eval_step, self._eval_step_indexed,
-                   self._predict_step, self._pin_state):
+                   self._moments_off_table_are_zero, self._eval_step,
+                   self._eval_step_indexed, self._predict_step,
+                   self._pin_state):
             probe = getattr(fn, "_cache_size", None)
             if callable(probe):
                 sizes.append(int(probe()))
@@ -512,6 +674,23 @@ class Trainer:
         cache = self._jit_cache_size()
         if cache is not None:
             self._m_executables.set(cache)
+
+    def _publish_optimizer_rows(self, x_base, rowwise=None) -> None:
+        """The epoch's ``deeprest_train_optimizer_rows``, for a staged
+        sparse corpus: ``rowwise`` is what the compact superstep's rule
+        read on the state the epoch began with (a device scalar, read
+        here, after the epoch; the superstep keeps it as it finds it), or
+        None where every step ran over all F rows."""
+        if not isinstance(x_base, SparseBase):
+            return
+        updated = x_base.capacity
+        if rowwise is not None:
+            self._m_readbacks.inc(sink="optimizer_rows")
+            # graftlint: disable=JX003 -- designed sink: one scalar an epoch, dispatched before its first chunk and read after its last
+            if bool(rowwise):
+                updated = x_base.width
+        self._m_optimizer_rows.set(updated, kind="updated")
+        self._m_optimizer_rows.set(x_base.capacity, kind="total")
 
     # -- preemption-safe snapshots (ROADMAP item 7, dynamic half) ------
 
@@ -1114,6 +1293,8 @@ class Trainer:
         if measuring:
             self.throughput.stop(steps)
         self._publish_epoch_metrics()
+        if staged is not None:
+            self._publish_optimizer_rows(staged[0])
         # One stacked host readback for the epoch mean instead of a
         # device round-trip per element; f64 accumulation over the f32
         # per-step values reproduces the historical list-of-floats mean
@@ -1169,6 +1350,14 @@ class Trainer:
         # share the whole driver: only the compiled scan differs.
         superstep = (self._accum_superstep if cfg.grad_accum_windows > 1
                      else self._superstep)
+        # The rule the compact superstep applies in each dispatch, on the
+        # state this epoch begins with: dispatched here, read after the
+        # epoch for the gauge, waited on nowhere in between.
+        rowwise = None
+        if (superstep is self._superstep and live_cols_of(x_base) is not None
+                and w_ih_leaves(state.params)):
+            rowwise = self._moments_off_table_are_zero(state.opt_state,
+                                                       x_base.live)
         measuring = self._warmed
         if measuring:
             self.throughput.start()
@@ -1226,6 +1415,7 @@ class Trainer:
         with phase("loss_readback"):
             epoch_losses = np.asarray(
                 jnp.concatenate(chunk_losses))[:num_steps - skip_steps]
+            self._publish_optimizer_rows(x_base, rowwise)
         self._last_epoch_losses = epoch_losses
         return state, float(np.mean(epoch_losses, dtype=np.float64))
 

@@ -244,7 +244,10 @@ def test_fused_serve_program_10k_sparse(one_chip):
 # ---------------------------------------------------------------------------
 
 
-def _train_step_lowered(mesh, feature_dim, sparse, accum_mode=None):
+def _train_step_lowered(mesh, feature_dim, sparse, accum_mode=None,
+                        superstep=False):
+    """``superstep``: the G=1 superstep (a 3 x 50 plan, as the 10k cell's
+    126-step epoch gives) instead of the per-step program."""
     accum = 1 if accum_mode is None else 4
     cfg = Config(model=_model_config(feature_dim=feature_dim),
                  train=TrainConfig(batch_size=B, window_size=W,
@@ -280,13 +283,14 @@ def _train_step_lowered(mesh, feature_dim, sparse, accum_mode=None):
     else:
         base = sds((t_len, feature_dim), jnp.bfloat16)
     y_base = sds((t_len, E), jnp.float32)
-    if accum == 1:
+    if accum == 1 and not superstep:
         args = (base, y_base, sds((B,), jnp.int32), sds((B,), jnp.float32))
         return trainer._train_step_indexed.lower(state_sds, *_on(mesh, args))
-    plan = (2, accum, B)
+    plan = (3, 50, B) if superstep else (2, accum, B)
     args = (base, y_base, sds(plan, jnp.int32), sds(plan, jnp.float32),
             sds((), jnp.int32))
-    return trainer._accum_superstep.lower(state_sds, *_on(mesh, args))
+    program = trainer._superstep if superstep else trainer._accum_superstep
+    return program.lower(state_sds, *_on(mesh, args))
 
 
 @pytest.mark.parametrize("feature_dim,sparse,accum_mode", [
@@ -315,3 +319,34 @@ def test_train_step_fits_the_chip(one_chip, feature_dim, sparse, accum_mode):
     need = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert need < HBM_BYTES, mem
+
+
+@pytest.mark.slow       # by hand, as the compact per-step case above
+def test_compact_superstep_updates_the_leaves_in_place(one_chip):
+    """The compact 10k superstep (ISSUE 27): the step takes the w_ih
+    gradient at the table's rows, and one conditional round Adam of the two
+    w_ih leaves chooses between the table's rows and all F.  The six
+    ``[E, F, 3H]`` leaves are copied into the loop's layout and back once a
+    dispatch (12 copies, as before) and nowhere else, and the temporaries
+    hold one set of them (3.9 GB, as before the change; a cond round two
+    scans took 7.7): the write-back is in place.  Arguments and
+    temporaries stay under the 8.92 GB that ``init_state`` peaks at, so
+    ``hbm_peak_gb`` does not rise."""
+    compiled = _train_step_lowered(one_chip, F_10K, "compact",
+                                   superstep=True).compile()
+    text = compiled.as_text()
+    leaf = re.escape(f"f32[{E},{F_10K},{3 * H}]")
+    copies = len(re.findall(rf"= {leaf}\{{[^}}]*\}} copy\(", text))
+    mem = compiled.memory_analysis()
+    need = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"compact 10k superstep for a described v5e: temporaries "
+          f"{mem.temp_size_in_bytes / 1e9:.3f} GB, arguments "
+          f"{mem.argument_size_in_bytes / 1e9:.3f} GB, needs "
+          f"{need / 1e9:.3f} GB; whole-leaf copy operations {copies}; "
+          f"conditionals {len(re.findall(r' conditional[(]', text))}")
+    assert _kernel_calls(compiled) == 4
+    assert len(re.findall(r" while[(]", text)) == 1
+    assert copies == 12
+    assert mem.temp_size_in_bytes < 4.0e9, mem
+    assert need < 8.92e9, mem
