@@ -82,12 +82,10 @@ MEASURE_TIMEOUT_S = 2400     # flagship f32 CPU steps (--cpu) are ~7s each
 # Step counts are sized so the end-of-trial host readback (see
 # measure_main) is amortized to <2% of the trial.
 #
-# grad_accum_G: the window-coalescing factor for the schema-v6
-# coalesced_steps_per_sec measurement (G plan steps fused into one
-# update, G·B recurrence rows — TrainConfig.grad_accum_windows).  4 is
-# the widest the flagship bf16 TRAINING kernel's VMEM block plan fits
-# (ops/pallas_gru.block_plan: G=8 overflows scoped VMEM even at the
-# minimum block); a --cpu run uses 2 to bound its ~7 s/step trials.
+# grad_accum_G: the accumulation factor for the schema-v6
+# coalesced_steps_per_sec measurement (G plan steps an update —
+# TrainConfig.grad_accum_windows); a --cpu run uses 2 to bound its
+# ~7 s/step trials.
 FULL = {"warmup": 5, "steps": 100, "trials": 3, "dtype": "bfloat16",
         "superstep_S": 8, "grad_accum_G": 4}
 LIGHT = {"warmup": 1, "steps": 3, "trials": 1, "dtype": "float32",
@@ -248,11 +246,10 @@ def measure_main(light: bool, tenk: bool = False) -> None:
     elapsed, state = timed_trial(run_superstep, state)
     superstep_sps = ss_chunks * S / elapsed
 
-    # Window-coalesced superstep (schema v6): G consecutive plan steps
-    # fuse into ONE optimizer update whose recurrence sees G·B rows per
-    # matmul (TrainConfig.grad_accum_windows, PERF.md round 11) — the
-    # direct attack on the flagship's ~12% MXU row occupancy.  A second
-    # Trainer is needed because G is a plan-shape static.
+    # Accumulation superstep (schema v6): G consecutive plan steps run
+    # forward and backward each and feed ONE optimizer update
+    # (TrainConfig.grad_accum_windows).  A second Trainer is needed
+    # because G is a plan-shape static.
     accum_g = sizes["grad_accum_G"]
     import dataclasses as _dc
 
@@ -300,7 +297,7 @@ def measure_main(light: bool, tenk: bool = False) -> None:
         "superstep_S": S,
         "coalesced_steps_per_sec": coalesced_sps,
         "grad_accum_G": accum_g,
-        "recurrence_rows": accum_g * B,
+        "recurrence_rows": B,
         "host_feed_steps_per_sec": host_sps,
         "platform": dev.platform,
         "device_kind": dev.device_kind,
@@ -449,10 +446,8 @@ def _mfu_block(measured: dict, features: int) -> dict:
             float(measured["superstep_steps_per_sec"]), 3)
         block["superstep_S"] = measured.get("superstep_S")
     if measured.get("coalesced_steps_per_sec") is not None:
-        # Window-coalesced superstep (schema v6, NEW keys): G plan steps
-        # per optimizer update, recurrence matmuls at G·B rows
-        # (TrainConfig.grad_accum_windows; benchmarks/kernel_tuning.py
-        # --coalesce has the recurrence-isolated G sweep).  Rate is in
+        # Accumulation superstep (schema v6 keys): G plan steps per
+        # optimizer update (TrainConfig.grad_accum_windows).  Rate is in
         # MICROBATCH steps/s — directly comparable to
         # superstep_steps_per_sec at the same shape.
         block["coalesced_steps_per_sec"] = round(
@@ -604,8 +599,8 @@ def main() -> None:
         # mesh sweep's timed trials carry the same asserted
         # updated-params-readback ledger.
         # v6: coalesced_steps_per_sec (+ grad_accum_G, recurrence_rows) is
-        # the window-coalesced superstep — G plan steps fused into one
-        # optimizer update with G·B recurrence rows per matmul — and every
+        # the accumulation superstep — G plan steps an optimizer update,
+        # B recurrence rows per matmul since PR 28 — and every
         # timed trial is now ASSERTED to end in an updated-params readback
         # (the honest-sync ledger in measure_main), so the round-2
         # dispatch-rate bug class cannot regress silently.  NEW keys only;

@@ -312,8 +312,8 @@ def checks(opts: dict) -> int:
           preds_max_abs_diff=float(jnp.max(jnp.abs(
               k_preds.astype(jnp.float32) - s_preds.astype(jnp.float32)))))
 
-    # -- row-folded (coalesced) vs per-group recurrence --------------------
-    from deeprest_tpu.ops.gru import gru, gru_coalesced, init_gru_params
+    # -- four batches' rows in one recurrence vs one call a batch ----------
+    from deeprest_tpu.ops.gru import gru, init_gru_params
 
     fold = {}
     for dtype in ("float32", "bfloat16"):
@@ -321,13 +321,12 @@ def checks(opts: dict) -> int:
                             jnp.dtype(dtype))
         x4 = jnp.asarray(rng.random((4, b, w, f), np.float32), dtype)
         run = jax.jit(lambda p, x: gru(p, x, backend=backend))
-        full = jax.jit(lambda p, x: gru_coalesced(p, x, backend=backend))(
-            p, x4).astype(jnp.float32)
+        full = run(p, x4.reshape(4 * b, w, f)).astype(jnp.float32)
         fold[dtype] = max(
-            float(jnp.max(jnp.abs(full[:, g]
+            float(jnp.max(jnp.abs(full[:, g * b:(g + 1) * b]
                                   - run(p, x4[g]).astype(jnp.float32))))
             for g in range(4))
-    check("coalesced_vs_per_group",
+    check("row_fold_vs_per_group",
           all(np.isfinite(v) for v in fold.values()),
           max_abs_diff=fold, rows=4 * b,
           note="0.0 means the row fold is bit-identical on this backend")

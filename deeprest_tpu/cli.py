@@ -387,7 +387,6 @@ def cmd_train(args) -> int:
                           device_data=args.device_data,
                           steps_per_superstep=args.steps_per_superstep,
                           grad_accum_windows=args.grad_accum_windows,
-                          grad_accum_mode=args.grad_accum_mode,
                           sparse_feed=args.sparse_feed,
                           sparse_nnz_cap=args.sparse_nnz_cap,
                           snapshot_every_steps=args.snapshot_every_steps,
@@ -576,7 +575,6 @@ def cmd_stream(args) -> int:
                           log_every_steps=0,
                           steps_per_superstep=args.steps_per_superstep,
                           grad_accum_windows=args.grad_accum_windows,
-                          grad_accum_mode=args.grad_accum_mode,
                           sparse_feed=args.sparse_feed,
                           sparse_nnz_cap=args.sparse_nnz_cap,
                           snapshot_every_steps=args.snapshot_every_steps,
@@ -1522,21 +1520,12 @@ def build_parser() -> argparse.ArgumentParser:
                         "'epoch' = whole epoch per dispatch; 'auto' sizes "
                         "from the logging cadence)")
     p.add_argument("--grad-accum-windows", type=int, default=1, metavar="G",
-                   help="window-coalesced gradient accumulation on the "
-                        "staged superstep path: fold G consecutive "
-                        "microbatches into one fused forward/backward "
-                        "(G*batch-size recurrence rows per matmul) with "
-                        "one optimizer update per G on summed grads; "
-                        "requires the device-resident feed "
-                        "(--device-data always on CPU); 1 = per-step "
-                        "updates (default)")
-    p.add_argument("--grad-accum-mode", default="exact",
-                   choices=("exact", "flat", "loop"),
-                   help="how the G microbatches fuse: 'exact' (default) "
-                        "is bit-identical to the unfused accumulation "
-                        "loop; 'flat' folds rows straight through the "
-                        "kernel (max MXU row occupancy, ~1e-7 grad "
-                        "reassociation); 'loop' is the unfused reference")
+                   help="gradient accumulation on the staged superstep "
+                        "path: G consecutive microbatches each run "
+                        "forward and backward, one optimizer update per "
+                        "G on their summed grads; requires the "
+                        "device-resident feed (--device-data always on "
+                        "CPU); 1 = per-step updates (default)")
     p.add_argument("--snapshot-every-steps", type=int, default=0,
                    metavar="N",
                    help="preemption-safe training: atomically checkpoint "
@@ -1616,21 +1605,12 @@ def build_parser() -> argparse.ArgumentParser:
                    help="fused steps per compiled dispatch for the staged "
                         "fine-tune epochs (1 = per-step loop)")
     p.add_argument("--grad-accum-windows", type=int, default=1, metavar="G",
-                   help="window-coalesced gradient accumulation on the "
-                        "staged superstep path: fold G consecutive "
-                        "microbatches into one fused forward/backward "
-                        "(G*batch-size recurrence rows per matmul) with "
-                        "one optimizer update per G on summed grads; "
-                        "requires the device-resident feed "
-                        "(--device-data always on CPU); 1 = per-step "
-                        "updates (default)")
-    p.add_argument("--grad-accum-mode", default="exact",
-                   choices=("exact", "flat", "loop"),
-                   help="how the G microbatches fuse: 'exact' (default) "
-                        "is bit-identical to the unfused accumulation "
-                        "loop; 'flat' folds rows straight through the "
-                        "kernel (max MXU row occupancy, ~1e-7 grad "
-                        "reassociation); 'loop' is the unfused reference")
+                   help="gradient accumulation on the staged superstep "
+                        "path: G consecutive microbatches each run "
+                        "forward and backward, one optimizer update per "
+                        "G on their summed grads; requires the "
+                        "device-resident feed (--device-data always on "
+                        "CPU); 1 = per-step updates (default)")
     p.add_argument("--snapshot-every-steps", type=int, default=0,
                    metavar="N",
                    help="preemption-safe fine-tuning: checkpoint the full "

@@ -115,34 +115,17 @@ class TrainConfig:
     # Ignored when the dataset is not staged (host-feed fallback keeps the
     # per-step loop).
     steps_per_superstep: int | str = "auto"
-    # Window-coalesced gradient accumulation on the staged superstep path:
-    # G consecutive plan steps (microbatches) fold into ONE fused
-    # forward/backward whose recurrence sees G·B rows per matmul — G× the
-    # MXU row occupancy of the latency-bound [32,128]×[128,384] per-step
-    # dot (PERF.md round 11) — and the optimizer update applies once per G
-    # with summed grads.  Groups share the weights, so the fold is
-    # algebraically free (unlike the rejected expert fold).  1 = the
-    # historical per-step update (default; the G>1 paths are new code,
-    # never silently entered).  Requires the staged (device-resident)
-    # feed; per-microbatch losses keep their meaning and the step counter
-    # still counts real microbatches.
+    # Gradient accumulation on the staged superstep path: G consecutive
+    # plan steps (microbatches) each run the shared step's forward and
+    # backward, their gradients are summed in microbatch order and the
+    # optimizer updates once per G (dropout key fold_in(step_key, g)).
+    # 1 = one update a step (default).  Requires the staged
+    # (device-resident) feed; per-microbatch losses keep their meaning and
+    # the step counter still counts real microbatches.  No benchmark cell
+    # accumulates: the recurrence kernels it once meant to fatten are 11%
+    # of the step in both cells and bound by their HBM traffic, not by MXU
+    # row occupancy (PERF.md section 5).
     grad_accum_windows: int = 1
-    # How the G microbatches are fused (ignored at G=1):
-    #   "exact" (default) — per-microbatch grads via jax.vmap with the
-    #     mask fold staged through an explicit jax.vjp prologue, summed in
-    #     microbatch order: bit-identical losses AND params to the
-    #     unfused accumulation loop (pinned by tests/test_coalesce.py).
-    #     XLA flattens the shared-weight dots to G·B rows.
-    #   "flat" — the G batches reshape into one [G·B] row batch through
-    #     the model's group axis: maximum kernel-level row occupancy (the
-    #     pallas recurrence sees G·B rows directly), per-microbatch
-    #     losses still bit-exact, but weight-grad contractions
-    #     re-associate across groups (~1e-7 relative on f32 — measured,
-    #     documented in PERF.md; same class as the fused-inference delta
-    #     tolerance).
-    #   "loop" — G sequential unfused passes, summed grads: the pinned
-    #     reference the other two are measured against.
-    grad_accum_mode: str = "exact"
     # Sparse-first traffic feed (the 10k-endpoint tier, ROADMAP item 4):
     # traffic rows travel host→device as padded-COO ``(cols[K], vals[K])``
     # pairs — >99% of a 10k-wide count vector is zeros — and densify to
@@ -213,10 +196,6 @@ class TrainConfig:
         if not isinstance(g, int) or isinstance(g, bool) or g < 1:
             raise ValueError(
                 f"TrainConfig.grad_accum_windows={g!r}: must be an int >= 1")
-        if self.grad_accum_mode not in ("exact", "flat", "loop"):
-            raise ValueError(
-                f"TrainConfig.grad_accum_mode={self.grad_accum_mode!r}: "
-                f"must be 'exact', 'flat', or 'loop'")
         if not isinstance(self.sparse_nnz_cap, int) \
                 or isinstance(self.sparse_nnz_cap, bool) \
                 or self.sparse_nnz_cap < 1:

@@ -26,25 +26,6 @@ Deviation (documented): for ``num_metrics == 1`` the reference's mean over
 the empty "others" set is undefined (it would crash); here the mix input
 falls back to the expert's own output.
 
-Coalescing plumbing (round 11): the window-coalesced trainer and the fused
-serving engine both fold G independent window batches into the batch (row)
-axis of ONE recurrence call.  Two hooks support that here:
-
-- **Group axis**: ``__call__`` accepts ``[G, B, T, F]`` and flattens the
-  group axis into the rows (``[G·B, T, F]``) around the shared pipeline —
-  every op is row-independent, so each group's slice of the output is
-  bit-identical to a standalone ``[B, T, F]`` call.
-- **External mask fold**: :func:`feature_mask` / :func:`fold_feature_mask`
-  lift the soft-mask computation and its fold into the layer-0 input
-  weights out of the module (single source — ``__call__`` calls the same
-  functions), and ``mask_folded=True`` tells ``__call__`` the caller
-  already folded.  The coalesced trainer's exact-gradient mode needs
-  this: the mask subgraph is params-only, so under ``jax.vmap`` its
-  backward would otherwise run ONCE on a pre-summed cotangent (different
-  float association than the per-microbatch loop it must match
-  bit-for-bit); staging it through an explicit ``jax.vjp`` keeps the
-  mask backward per-group and unbatched, exactly like the loop.
-
 Live-column compaction (PR 25): a staged sparse corpus whose live call
 paths are few (``ops/densify.py``, the compact form) hands ``__call__``
 windows of those ``U_pad`` columns only, with the table that names them
@@ -100,9 +81,6 @@ def resolve_params(params):
 def feature_mask(params) -> jax.Array:
     """The learned soft feature mask ``[E, F]`` from the mask parameters.
 
-    Single source of the mask math: ``QuantileGRU.__call__`` routes through
-    this same function, so an externally computed mask (the coalesced
-    trainer's ``jax.vjp`` prologue) is bit-identical to the in-module one.
     Mirrors the reference encoder: Linear(1→H) on a constant 1.0 input is
     just (weight + bias), then ReLU → Linear(H→F) → softmax
     (reference: resource-estimation/qrnn.py:20-26,33-36).
@@ -138,24 +116,6 @@ def put_columns(a: jax.Array, live_cols: jax.Array,
                                   mode="promise_in_bounds")
 
 
-def fold_feature_mask(params):
-    """Fold the soft mask into the layer-0 input weights, tree-level.
-
-    Returns a new params mapping where every ``MASKED_PARAM_NAMES`` leaf is
-    replaced by ``mask[:, :, None] * w_ih`` — exactly the fold
-    ``__call__`` applies internally (``(x ⊙ m) @ W ≡ x @ (m ⊙ W)``).
-    Apply the result with ``mask_folded=True``.  The coalesced trainer
-    stages this through ``jax.vjp`` so the mask/fold backward runs
-    per-microbatch and unbatched (see module docstring).
-    """
-    mask = feature_mask(params)
-    out = dict(params)
-    for name in MASKED_PARAM_NAMES:
-        if name in out:
-            out[name] = _fold(mask, out[name])
-    return out
-
-
 class QuantileGRU(nn.Module):
     """Multi-task quantile GRU.
 
@@ -171,7 +131,6 @@ class QuantileGRU(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array, *, deterministic: bool = True,
-                 mask_folded: bool = False,
                  live_cols: jax.Array | None = None,
                  live_w_ih: Mapping[str, jax.Array] | None = None
                  ) -> jax.Array:
@@ -184,6 +143,8 @@ class QuantileGRU(nn.Module):
         rows; the leaves in the params tree are then not read."""
         cfg = self.config
         e, f, h, q = cfg.num_metrics, cfg.feature_dim, cfg.hidden_size, len(cfg.quantiles)
+        if x.ndim != 3:
+            raise ValueError(f"expected [B, T, F] windows, got shape {x.shape}")
         if live_cols is None and x.shape[-1] != f:
             raise ValueError(f"input feature dim {x.shape[-1]} != config.feature_dim {f}")
         if live_cols is not None and x.shape[-1] != live_cols.shape[0]:
@@ -194,17 +155,6 @@ class QuantileGRU(nn.Module):
                              "live_cols is missing")
         compute_dtype = jnp.dtype(cfg.compute_dtype)
 
-        # Group axis (coalescing plumbing): [G, B, T, F] folds its groups
-        # into the row axis for the whole pipeline — one fat recurrence
-        # call instead of G thin ones — and unfolds on the way out.  Every
-        # op maps rows independently, so each group's output slice is
-        # bit-identical to a standalone [B, T, F] call (pinned by
-        # tests/test_coalesce.py).
-        group_shape = None
-        if x.ndim == 4:
-            group_shape = x.shape[:2]
-            x = x.reshape(group_shape[0] * group_shape[1], *x.shape[2:])
-
         def uniform_pm(scale):
             def _init(key, shape, dtype=jnp.float32):
                 return jax.random.uniform(key, shape, dtype, minval=-scale, maxval=scale)
@@ -213,12 +163,7 @@ class QuantileGRU(nn.Module):
         # (a) learned soft feature mask — Linear(1→H) → ReLU → Linear(H→F)
         # → softmax, driven by a constant 1.0 (reference: qrnn.py:20-26,33-36).
         # Linear(1→H) on a constant input is just (weight + bias): one [E,H]
-        # pre-activation per expert.  The math lives in the module-level
-        # feature_mask() so external callers (the coalesced trainer's vjp
-        # prologue) compute bit-identical values; with mask_folded=True the
-        # caller already folded it into the layer-0 weights and the mask
-        # subgraph is skipped entirely (its params then receive zero grads
-        # from this apply — the prologue vjp supplies them).
+        # pre-activation per expert.
         k_in = 1.0  # fan_in of the constant input
         mask_params = {
             "mask_w1": self.param("mask_w1", uniform_pm(1.0 / k_in ** 0.5), (e, h)),
@@ -228,7 +173,7 @@ class QuantileGRU(nn.Module):
         mask_params["mask_w2"] = self.param("mask_w2", uniform_pm(k_h), (e, h, f))
         mask_params["mask_b2"] = self.param("mask_b2", uniform_pm(k_h), (e, f))
 
-        mask = None if mask_folded else feature_mask(mask_params)     # [E, F]
+        mask = feature_mask(mask_params)                              # [E, F]
 
         # (b) (stacked) bidirectional GRU over the window (reference:
         # qrnn.py:24,39-43; layer l>0 consumes layer l-1's output, matching
@@ -244,9 +189,8 @@ class QuantileGRU(nn.Module):
             )
 
         # Fold the mask into the input weights: (x ⊙ m) @ W == x @ (m ⊙ W).
-        # Identity when the caller pre-folded (fold_feature_mask).  On a
-        # compact input only the live columns of either are folded.
-        if live_cols is not None and mask is not None:
+        # On a compact input only the live columns of either are folded.
+        if live_cols is not None:
             mask = take_columns(mask, live_cols)                      # [E, U]
 
         def masked(p: GRUParams, name: str) -> GRUParams:
@@ -256,8 +200,7 @@ class QuantileGRU(nn.Module):
                 w_ih = take_columns(p.w_ih, live_cols)
             else:
                 w_ih = live_w_ih[name]
-            return p._replace(
-                w_ih=w_ih if mask is None else _fold(mask, w_ih))
+            return p._replace(w_ih=_fold(mask, w_ih))
 
         def cast(p: GRUParams) -> GRUParams:
             return jax.tree.map(lambda a: a.astype(compute_dtype), p)
@@ -323,8 +266,6 @@ class QuantileGRU(nn.Module):
                                   preferred_element_type=jnp.float32))
             preds = preds + head_b[:, None, None, :]
             preds = jnp.transpose(preds, (1, 2, 0, 3))                # [B,T,E,Q]
-        if group_shape is not None:
-            preds = preds.reshape(*group_shape, *preds.shape[1:])     # [G,B,T,E,Q]
         return preds
 
     # ------------------------------------------------------------------

@@ -1,23 +1,17 @@
 """TPU compute primitives: scan-based GRU, quantile (pinball) loss."""
 
 from deeprest_tpu.ops.gru import (
-    GroupSpec,
     GRUParams,
     bidirectional_gru,
-    bidirectional_gru_coalesced,
     gru,
-    gru_coalesced,
     init_gru_params,
 )
 from deeprest_tpu.ops.quantile import pinball_loss
 
 __all__ = [
-    "GroupSpec",
     "GRUParams",
     "gru",
-    "gru_coalesced",
     "bidirectional_gru",
-    "bidirectional_gru_coalesced",
     "init_gru_params",
     "pinball_loss",
 ]

@@ -20,7 +20,6 @@ comparable against the public torch API.
 
 from __future__ import annotations
 
-import os as _os
 from typing import NamedTuple
 
 import jax
@@ -31,15 +30,14 @@ from deeprest_tpu.ops import scopes
 
 _BACKENDS = ("auto", "scan", "pallas", "pallas_interpret")
 
-# Fused bidirectional (both directions stacked on the expert axis of ONE
-# gru_recurrence call) never demonstrated a win at the production bf16
-# dtypes: the round-4 fused on-chip headline was 117.2 steps/s vs the
-# round-3 unfused 122.0 (PERF.md "Measured so far"), and PERF.md committed
-# to reverting if unfused won.  Round 11 executes that revert: the default
-# pallas bidirectional path is two single-direction gru_recurrence calls.
-# The fused path stays behind this knob so benchmarks/kernel_tuning.py can
-# keep A/B-ing it on-chip without a code edit.
-BIDIR_FUSED = _os.environ.get("DEEPREST_GRU_BIDIR_FUSED", "0") == "1"
+# Fused bidirectional: both directions stacked on the expert axis of ONE
+# gru_recurrence call.  Off, as it has been since round 11: two
+# single-direction calls a layer.  On the chip the fused form read 13.0%
+# and 7.2% more steps/s in the benchmark's two cells (PERF.md section 6,
+# PR 28: its kernels are slower, what follows them is faster), so it is
+# kept, as a constant no run sets, for the perf_opt PR that takes the gain
+# (ROADMAP speed queue) and deletes the form that loses.
+_BIDIR_FUSED = False
 
 
 def _resolve_backend(backend: str) -> str:
@@ -71,9 +69,8 @@ class GRUParams(NamedTuple):
 def resolve_weights(params: GRUParams) -> GRUParams:
     """Weights-adapter hook (round 22): dequantize-at-use for quantized
     serving weights, identity otherwise.  Called once at the top of the
-    ``gru``/``bidirectional_gru`` entry points — the coalesced variants
-    delegate to them, so EVERY recurrence path (scan, pallas, coalesced,
-    bidirectional) shares the one sanctioned dequant site
+    ``gru``/``bidirectional_gru`` entry points, so EVERY recurrence path
+    (scan, pallas, bidirectional) shares the one sanctioned dequant site
     (ops/quantize.dequantize); the widen+scale runs inside the calling
     executable and XLA fuses it into the first projection dot."""
     from deeprest_tpu.ops.quantize import QuantTensor, dequantize
@@ -165,8 +162,8 @@ def _recur_local(projs, w_hhs, b_hhs, h0s, interpret: bool):
     ``gru_recurrence`` call, stacked along the expert axis (the fused
     bidirectional form — the kernel only ever scans its grid forward).
     Shape hygiene for the kernel's tiling happens here, per device: rows
-    pad to the sublane, experts to ``E_BLK``, time to ``T_BLK``.  The time
-    pad sits at the END of scan order, beyond every real output: sliced
+    pad to the sublane, experts and time to the kernel's widest blocks.  The
+    time pad sits at the END of scan order, beyond every real output: sliced
     off afterwards, zero incoming gradient in the VJP.  Returns one
     ``[E, T, B, H]`` array per direction."""
     from deeprest_tpu.ops import pallas_gru
@@ -174,7 +171,7 @@ def _recur_local(projs, w_hhs, b_hhs, h0s, interpret: bool):
     e, t, b, _ = projs[0].shape
     io_dtype = projs[0].dtype
     b_pad = pallas_gru.pad_batch(b, io_dtype) - b
-    e_pad = -e % pallas_gru.E_BLK
+    e_pad = pallas_gru.pad_experts(e) - e
     t_pad = pallas_gru.pad_time(t) - t
 
     def stack(arrays, pads):
@@ -315,99 +312,6 @@ def gru(
     return _gru_scan(params, x, h0, reverse=reverse, unroll=unroll)
 
 
-# ---------------------------------------------------------------------------
-# window-coalesced batching (round 11)
-# ---------------------------------------------------------------------------
-
-
-class GroupSpec(NamedTuple):
-    """Segment descriptor for a row-coalesced batch: ``groups`` independent
-    window batches of ``rows`` windows each, stacked along the recurrence's
-    B (row) axis as ``[G·B, ...]`` in group-major order.
-
-    Groups share the SAME weights — which is exactly why the fold is
-    algebraically free: unlike the rejected expert fold (PERF.md round 5:
-    each expert contracts its OWN ``W_hh``, so stacking experts into rows
-    needs a block-diagonal embedding that multiplies FLOPs), window batches
-    all contract one shared ``W_hh``, so G thin ``[B,H]×[H,3H]`` dots
-    become one ``[G·B,H]×[H,3H]`` dot with G× the MXU row occupancy.
-    Unlike serve/fused.py's carry-offset/segment-reset vectors there is no
-    cross-row state to reset — every window batch starts from ``h0`` and
-    rows never interact — so the descriptor is pure split bookkeeping.
-    """
-
-    groups: int
-    rows: int
-
-    @property
-    def coalesced_rows(self) -> int:
-        return self.groups * self.rows
-
-
-def coalesce_windows(x: jax.Array) -> tuple[jax.Array, GroupSpec]:
-    """``[G, B, T, F] → ([G·B, T, F], GroupSpec)`` — fold group batches
-    into the row axis (group-major, zero-copy reshape)."""
-    if x.ndim != 4:
-        raise ValueError(f"expected [G, B, T, F] window groups, got shape "
-                         f"{x.shape}")
-    g, b = x.shape[:2]
-    return x.reshape(g * b, *x.shape[2:]), GroupSpec(groups=g, rows=b)
-
-
-def split_coalesced(h: jax.Array, spec: GroupSpec) -> jax.Array:
-    """``[E, G·B, T, D] → [E, G, B, T, D]`` — unfold a coalesced GRU
-    output back to per-group batches."""
-    if h.shape[1] != spec.coalesced_rows:
-        raise ValueError(
-            f"coalesced output has {h.shape[1]} rows; spec says "
-            f"{spec.groups}x{spec.rows}={spec.coalesced_rows}")
-    return h.reshape(h.shape[0], spec.groups, spec.rows, *h.shape[2:])
-
-
-def gru_coalesced(
-    params: GRUParams,
-    x: jax.Array,
-    h0: jax.Array | None = None,
-    reverse: bool = False,
-    unroll: int = 4,
-    backend: str = "auto",
-    mesh=None,
-) -> jax.Array:
-    """Single-direction GRU over G coalesced window batches.
-
-    ``x``: ``[G, B, T, F]`` independent window batches → ``[E, G, B, T, H]``
-    hidden states.  All G batches ride ONE ``gru`` call (one recurrence
-    kernel invocation on the pallas backends) with ``G·B`` rows in every
-    per-step matmul; each group's output slice is bit-identical to a
-    standalone ``gru`` call on that group (rows are independent — pinned by
-    tests/test_coalesce.py).  ``h0``, when given, is per group:
-    ``[E, G, B, H]``.
-    """
-    flat, spec = coalesce_windows(x)
-    if h0 is not None:
-        h0 = h0.reshape(h0.shape[0], spec.coalesced_rows, h0.shape[-1])
-    out = gru(params, flat, h0=h0, reverse=reverse, unroll=unroll,
-              backend=backend, mesh=mesh)
-    return split_coalesced(out, spec)
-
-
-def bidirectional_gru_coalesced(
-    fwd: GRUParams,
-    bwd: GRUParams,
-    x: jax.Array,
-    unroll: int = 4,
-    backend: str = "auto",
-    mesh=None,
-) -> jax.Array:
-    """Bidirectional variant of :func:`gru_coalesced`:
-    ``[G, B, T, F] → [E, G, B, T, 2H]`` with both directions' recurrences
-    each running once over the coalesced ``G·B`` rows."""
-    flat, spec = coalesce_windows(x)
-    out = bidirectional_gru(fwd, bwd, flat, unroll=unroll, backend=backend,
-                            mesh=mesh)
-    return split_coalesced(out, spec)
-
-
 def _bidir_pallas(
     fwd: GRUParams,
     bwd: GRUParams,
@@ -452,15 +356,15 @@ def bidirectional_gru(
     """
     fwd, bwd = resolve_weights(fwd), resolve_weights(bwd)
     resolved = _resolve_backend(backend)
-    if resolved != "scan" and BIDIR_FUSED:
+    if resolved != "scan" and _BIDIR_FUSED:
         from deeprest_tpu.ops import pallas_gru
 
         if pallas_gru.supported(x.shape[-2], fwd.hidden_size):
             return _bidir_pallas(fwd, bwd, x,
                                  interpret=resolved == "pallas_interpret",
                                  mesh=mesh)
-    # Default (round-11 revert, PERF.md): two single-direction calls — on
-    # the pallas backends each direction is its own kernel invocation.
+    # Two single-direction calls — on the pallas backends each direction
+    # is its own kernel invocation.
     out_f = gru(fwd, x, reverse=False, unroll=unroll, backend=backend,
                 mesh=mesh)
     out_b = gru(bwd, x, reverse=True, unroll=unroll, backend=backend,

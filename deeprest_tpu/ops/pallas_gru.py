@@ -14,9 +14,10 @@ This kernel runs the whole time loop *inside one pallas invocation*:
 - ``W_hh`` is indexed only by the expert block, so it stays resident in
   VMEM for all T steps of that block;
 - the backward pass is a second pallas kernel walking the grid in reverse
-  time order, recomputing gate activations from (proj, h_prev) — no
-  activation stash beyond the forward outputs — and accumulating weight
-  gradients in VMEM scratch, flushed to HBM on the final step.
+  time order, recomputing gate activations from (proj, h_prev and the
+  hidden-side gate pre-activations the training forward stashed) and
+  accumulating weight gradients in VMEM scratch, flushed to HBM on the
+  final step.
 
 Only the recurrence is hand-written: input/output projections, the feature
 mask, mixing, and heads remain plain XLA einsums (models/qrnn.py), which
@@ -39,25 +40,20 @@ from jax.experimental.pallas import tpu as pltpu
 
 from deeprest_tpu.ops import scopes
 
-import os as _os
-
-# Experts per kernel program: amortizes grid overhead while keeping
-# VMEM residency (W_hh alone is E_BLK * H * 3H * 4B).  Env-overridable
-# (DEEPREST_GRU_E_BLK) so on-chip sweeps can A/B without code edits.
-E_BLK = int(_os.environ.get("DEEPREST_GRU_E_BLK", "8"))
-# Time steps per kernel program.  Each program advances the recurrence
-# T_BLK steps with the hidden state in VMEM scratch: fewer grid programs
-# and fewer (larger) DMA blocks.  Inside a program the loop runs
-# time-OUTER, expert-INNER so each step issues E_BLK *independent*
+# Experts per kernel program at most: amortizes grid overhead while
+# keeping VMEM residency (W_hh alone is _E_BLK * H * 3H * 4B).  A ceiling:
+# _choose_blocks derives the plan of a call from its shape.
+_E_BLK = 8
+# Time steps per kernel program at most.  Each program advances the
+# recurrence that many steps with the hidden state in VMEM scratch: fewer
+# grid programs and fewer (larger) DMA blocks.  Inside a program the loop
+# runs time-OUTER, expert-INNER so each step issues e_blk *independent*
 # matmuls that pipeline through the MXU (expert-outer would serialize
-# each expert's whole T_BLK chain).  Measured on v5e at the flagship
-# shape (benchmarks/kernel_tuning.py): ~25% faster than T_BLK=1.
-# Callers pad T up to a multiple (pad_time); padded tail steps compute
-# garbage that is sliced off, which is safe because the tail is beyond
-# every real output in scan order.  Env-overridable (DEEPREST_GRU_T_BLK;
-# clamped to ≥1 — 0 would divide-by-zero pad_time and empty the chooser's
-# candidate list).
-T_BLK = max(1, int(_os.environ.get("DEEPREST_GRU_T_BLK", "6")))
+# each expert's whole chain: PERF.md section 6, PR 28).  Callers pad T up
+# to a multiple (pad_time); padded tail steps compute garbage that is
+# sliced off, which is safe because the tail is beyond every real output
+# in scan order.
+_T_BLK = 6
 # f32 sublane granularity — batch is padded up to this.
 _SUBLANE = 8
 # Scoped-VMEM budget for one kernel program: the compiler's 16 MiB limit
@@ -65,43 +61,14 @@ _SUBLANE = 8
 # below count everything else: blocks indexed by the sequential time grid
 # twice (the pallas pipeline double-buffers them), resident blocks once
 # (_resident), scratch, and the in-kernel temporaries (_temp_bytes).
-_VMEM_BUDGET = int(_os.environ.get("DEEPREST_GRU_VMEM_BUDGET",
-                                   str(15 << 20)))
-# Stash the pre-activation hidden-side gates (h·W_hh + b_hh) from the
-# training forward so the backward skips its recompute dot — per
-# expert-step that removes one [B,H]x[H,3H] MXU dot (~1/3 of the
-# backward's dispatches) for one extra [E,T,B,3H] stream in the kernel's
-# I/O dtype each way (~0.3 ms HBM vs ~0.8 ms MXU at the flagship shape).
-# Env-tunable for the on-chip A/B (benchmarks/kernel_tuning.py).
-STASH_GATES = _os.environ.get("DEEPREST_GRU_STASH_GATES", "1") != "0"
-# In-program loop order.  "expert_inner" (default) walks time outer /
-# experts inner: each step issues E_BLK independent dots that pipeline
-# through the MXU.  "time_inner" walks expert outer / time inner: all of
-# one expert's steps run consecutively so the SAME W_hh tiles feed
-# consecutive dots — scheduler-friendlier for weight reuse, but the
-# sequential h dependency stalls between steps.  Which wins is a
-# hardware-scheduling question; benchmarks/kernel_tuning.py settles it.
-LOOP_ORDER = _os.environ.get("DEEPREST_GRU_LOOP_ORDER", "expert_inner")
-def _checked_loop_order() -> str:
-    """Validate LOOP_ORDER at every trace, not just env-var load — the
-    tuning sweep (and tests) assign the module global directly, and a typo
-    falling through an ``== "time_inner"`` check would silently mislabel
-    an on-chip A/B."""
-    if LOOP_ORDER not in ("expert_inner", "time_inner"):
-        raise ValueError(
-            f"DEEPREST_GRU_LOOP_ORDER={LOOP_ORDER!r}: must be "
-            f"'expert_inner' or 'time_inner'")
-    return LOOP_ORDER
-
-
-_checked_loop_order()   # fail fast on a bad env var at import too
+_VMEM_BUDGET = 15 << 20
 
 
 def _legal_blocks(e: int, t: int) -> tuple[list[int], list[int]]:
     """Legal expert blocks (ascending) and time blocks (descending)."""
     legal_e = [c for c in range(_SUBLANE, e + 1, _SUBLANE)
-               if e % c == 0 and c <= E_BLK] or [e]
-    t_candidates = [c for c in range(min(T_BLK, t), 0, -1) if t % c == 0]
+               if e % c == 0 and c <= _E_BLK] or [e]
+    t_candidates = [c for c in range(min(_T_BLK, t), 0, -1) if t % c == 0]
     return legal_e, t_candidates
 
 
@@ -110,7 +77,7 @@ def _choose_blocks(e: int, t: int, per_expert_bytes,
     """Pick (e_blk, t_blk) whose footprint fits the scoped-VMEM budget,
     or raise when none does.
 
-    The f32 backward kernel at the default E_BLK=8/T_BLK=6 needs ~21 MB
+    The f32 backward kernel at e_blk=8/t_blk=6 needs ~21 MB
     — over the chip's 16 MiB scoped-VMEM limit, a hard compile error —
     while the bf16 production path fits.  The expert axis is the sublane
     of the 2-D f32 bias blocks, so pallas requires e_blk % 8 == 0 (or
@@ -120,13 +87,6 @@ def _choose_blocks(e: int, t: int, per_expert_bytes,
     Correctness is unaffected (experts independent; the kernels carry
     hidden state across time blocks in scratch)."""
     legal_e, t_candidates = _legal_blocks(e, t)
-    if E_BLK % _SUBLANE and E_BLK < e:
-        import warnings
-
-        warnings.warn(
-            f"DEEPREST_GRU_E_BLK={E_BLK} is not a multiple of {_SUBLANE} "
-            f"(the sublane of the 2-D f32 bias blocks) — pallas cannot "
-            f"tile it; using e_blk={legal_e[-1]} instead", stacklevel=3)
     # Prefer the widest expert block; shrink time first, then experts.
     for e_blk in reversed(legal_e):
         for t_blk in t_candidates:
@@ -165,19 +125,20 @@ def _gates(xproj, gates_h):
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(proj_ref, w_ref, b_ref, h0_ref, *refs, dot_dtype, emit_prev,
-                stash_gates, loop_order):
+def _fwd_kernel(proj_ref, w_ref, b_ref, h0_ref, *refs, dot_dtype, emit_prev):
     # Training (emit_prev=True) also streams out the PRE-update hidden
     # state per step: the VJP consumes h_prev directly instead of
     # re-materializing it outside the kernel as concat(h0, h_all[:-1]) —
-    # one full [E,T,B,H] HBM round-trip saved per step.  With stash_gates
-    # the pre-activation hidden gates stream out too, so the backward
-    # skips its recompute dot entirely.
-    refs = list(refs)
-    out_ref = refs.pop(0)
-    prev_ref = refs.pop(0) if emit_prev else None
-    gates_ref = refs.pop(0) if (emit_prev and stash_gates) else None
-    (h_scr,) = refs
+    # one full [E,T,B,H] HBM round-trip saved per step.  The
+    # pre-activation hidden gates (h·W_hh + b_hh) stream out too, so the
+    # backward skips its recompute dot: one [B,H]x[H,3H] MXU dot less per
+    # expert-step for one extra [E,T,B,3H] stream in the kernel's I/O
+    # dtype each way (recomputing read slower on the chip in both cells,
+    # PERF.md section 6, PR 28).
+    if emit_prev:
+        out_ref, prev_ref, gates_ref, h_scr = refs
+    else:
+        (out_ref, h_scr), prev_ref, gates_ref = refs, None, None
     t = pl.program_id(1)
 
     @pl.when(t == 0)
@@ -205,14 +166,9 @@ def _fwd_kernel(proj_ref, w_ref, b_ref, h0_ref, *refs, dot_dtype, emit_prev,
         hs[i] = (1.0 - z) * n + z * hs[i]
         out_ref[i, tt] = hs[i].astype(out_ref.dtype)
 
-    if loop_order == "time_inner":
-        for i in range(n_e):          # experts OUTER: W_hh stays hot
-            for tt in range(t_blk):   # time INNER: sequential chain
-                step(i, tt)
-    else:
-        for tt in range(t_blk):       # time OUTER
-            for i in range(n_e):      # experts INNER: independent matmuls
-                step(i, tt)
+    for tt in range(t_blk):           # time OUTER
+        for i in range(n_e):          # experts INNER: independent matmuls
+            step(i, tt)
     for i in range(n_e):
         h_scr[i] = hs[i]
 
@@ -253,17 +209,18 @@ def _temp_bytes(b, g3, h, proj_dtype):
     return b * g3 * 4 + casts
 
 
-def _fwd_per_expert_bytes(b, g3, h, proj_dtype, stash, n_h_out,
+def _fwd_per_expert_bytes(b, g3, h, proj_dtype, emit_prev,
                           w_itemsize, h0_itemsize):
     """Forward-kernel VMEM bytes per expert as a function of t_blk — the
     single source for _choose_blocks AND the public block_plan probe."""
     io = jnp.dtype(proj_dtype).itemsize
     oo = jnp.dtype(_out_dtype_for(proj_dtype)).itemsize
+    n_h_out = 2 if emit_prev else 1
     return lambda t_blk: (
         # proj in + h out (+ prev out and gates out when training),
         # double-buffered
         2 * (t_blk * b * g3 * io + n_h_out * t_blk * b * h * oo
-             + (t_blk * b * g3 * io if stash else 0))
+             + (t_blk * b * g3 * io if emit_prev else 0))
         + h * g3 * w_itemsize + g3 * 4                   # W_hh, b_hh resident
         + b * h * h0_itemsize + b * h * 4                # h0 block + scratch
         + _temp_bytes(b, g3, h, proj_dtype)
@@ -273,11 +230,9 @@ def _fwd_per_expert_bytes(b, g3, h, proj_dtype, stash, n_h_out,
 def _fwd_call(proj, w_hh, b_hh, h0, interpret, emit_prev=False):
     e, t, b, g3 = proj.shape
     h = g3 // 3
-    assert t % T_BLK == 0, (t, T_BLK)   # callers pad_time first
+    assert t % _T_BLK == 0, (t, _T_BLK)   # callers pad_time first
     out_dtype = _out_dtype_for(proj.dtype)
-    stash = emit_prev and STASH_GATES
-    n_h_out = 2 if emit_prev else 1
-    per_expert = _fwd_per_expert_bytes(b, g3, h, proj.dtype, stash, n_h_out,
+    per_expert = _fwd_per_expert_bytes(b, g3, h, proj.dtype, emit_prev,
                                        w_hh.dtype.itemsize, h0.dtype.itemsize)
     e_blk, t_blk = _choose_blocks(
         e, t, per_expert,
@@ -287,20 +242,15 @@ def _fwd_call(proj, w_hh, b_hh, h0, interpret, emit_prev=False):
     grid = (eb, t // t_blk)
     h_spec = pl.BlockSpec((e_blk, t_blk, b, h), lambda i, j: (i, j, 0, 0))
     h_shape = jax.ShapeDtypeStruct((e, t, b, h), out_dtype)
-    out_specs, out_shape = [h_spec], [h_shape]
+    out_specs, out_shape = h_spec, h_shape
     if emit_prev:
-        out_specs.append(h_spec)
-        out_shape.append(h_shape)
-    if stash:
-        out_specs.append(
-            pl.BlockSpec((e_blk, t_blk, b, g3), lambda i, j: (i, j, 0, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((e, t, b, g3), proj.dtype))
-    if not emit_prev:
-        out_specs, out_shape = out_specs[0], out_shape[0]
+        out_specs = [h_spec, h_spec, pl.BlockSpec(
+            (e_blk, t_blk, b, g3), lambda i, j: (i, j, 0, 0))]
+        out_shape = [h_shape, h_shape,
+                     jax.ShapeDtypeStruct((e, t, b, g3), proj.dtype)]
     return pl.pallas_call(
         functools.partial(_fwd_kernel, dot_dtype=_dot_dtype_for(proj.dtype),
-                          emit_prev=emit_prev, stash_gates=stash,
-                          loop_order=_checked_loop_order()),
+                          emit_prev=emit_prev),
         grid=grid,
         in_specs=[
             pl.BlockSpec((e_blk, t_blk, b, g3), lambda i, j: (i, j, 0, 0)),
@@ -324,17 +274,12 @@ def _fwd_call(proj, w_hh, b_hh, h0, interpret, emit_prev=False):
 # ---------------------------------------------------------------------------
 
 
-def _bwd_kernel(proj_ref, hprev_ref, *refs, dot_dtype, stash_gates,
-                loop_order):
-    if stash_gates:
-        (gates_in_ref, w_ref, b_ref, dout_ref,
-         dproj_ref, dw_ref, db_ref, dh0_ref,
-         dh_scr, dw_scr, db_scr, dg_scr) = refs
-    else:
-        gates_in_ref = None
-        (w_ref, b_ref, dout_ref,
-         dproj_ref, dw_ref, db_ref, dh0_ref,
-         dh_scr, dw_scr, db_scr, dg_scr) = refs
+def _bwd_kernel(proj_ref, hprev_ref, gates_in_ref, w_ref, b_ref, dout_ref,
+                dproj_ref, dw_ref, db_ref, dh0_ref,
+                dh_scr, dw_scr, db_scr, dg_scr, *, dot_dtype):
+    # b_ref (b_hh) is not read: the stashed gates hold it already.  It
+    # stays an operand because taking it away changes the compiled program
+    # and the byte model (ROADMAP, speed queue).
     t = pl.program_id(1)
     t_total = pl.num_programs(1)
 
@@ -346,7 +291,6 @@ def _bwd_kernel(proj_ref, hprev_ref, *refs, dot_dtype, stash_gates,
 
     n_e, t_blk = proj_ref.shape[0], proj_ref.shape[1]
     ws = [w_ref[i].astype(dot_dtype) for i in range(n_e)]
-    bs = [b_ref[i].astype(jnp.float32) for i in range(n_e)]
     dhs = [dh_scr[i] for i in range(n_e)]
     hh = dh_scr.shape[-1]
     # Bias-gradient accumulators, one [1, H] row per gate: Mosaic refuses a
@@ -358,17 +302,9 @@ def _bwd_kernel(proj_ref, hprev_ref, *refs, dot_dtype, stash_gates,
 
     def step(i, tt):
         h_prev = hprev_ref[i, tt].astype(jnp.float32)
-        if gates_in_ref is not None:
-            # Forward stashed the pre-activation hidden gates — no
-            # recompute dot (1/3 of this kernel's per-step MXU work).
-            gates_h = gates_in_ref[i, tt].astype(jnp.float32)
-        else:
-            gates_h = (
-                jax.lax.dot_general(h_prev.astype(dot_dtype), ws[i],
-                                    (((1,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-                + bs[i]
-            )
+        # Forward stashed the pre-activation hidden gates — no recompute
+        # dot (it would be 1/3 of this kernel's per-step MXU work).
+        gates_h = gates_in_ref[i, tt].astype(jnp.float32)
         xproj = proj_ref[i, tt].astype(jnp.float32)
         r, z, n, hn = _gates(xproj, gates_h)
 
@@ -400,14 +336,9 @@ def _bwd_kernel(proj_ref, hprev_ref, *refs, dot_dtype, stash_gates,
         for k, dgate in enumerate((da_r, da_z, dhn)):
             dbs[i][k] = dbs[i][k] + jnp.sum(dgate, axis=0, keepdims=True)
 
-    if loop_order == "time_inner":
-        for i in range(n_e):               # experts OUTER: W_hh stays hot
-            for tt in reversed(range(t_blk)):
-                step(i, tt)
-    else:
-        for tt in reversed(range(t_blk)):  # time OUTER, back-to-front
-            for i in range(n_e):           # experts INNER
-                step(i, tt)
+    for tt in reversed(range(t_blk)):      # time OUTER, back-to-front
+        for i in range(n_e):               # experts INNER
+            step(i, tt)
     for i in range(n_e):
         # dW_hh += h_prevᵀ @ dgates, contracted over the WHOLE time block
         # (K = t_blk·B instead of B): one MXU dot per block instead of one
@@ -431,19 +362,18 @@ def _bwd_kernel(proj_ref, hprev_ref, *refs, dot_dtype, stash_gates,
         dh0_ref[...] = dh_scr[...]
 
 
-def _bwd_per_expert_bytes(b, g3, h, proj_dtype, stash, hp_io, do_io,
-                          w_itemsize):
+def _bwd_per_expert_bytes(b, g3, h, proj_dtype, hp_io, do_io, w_itemsize):
     """Backward-kernel VMEM bytes per expert as a function of t_blk — the
     single source for _choose_blocks AND the public block_plan probe."""
     io = jnp.dtype(proj_dtype).itemsize
     dot_io = jnp.dtype(_dot_dtype_for(proj_dtype)).itemsize
     return lambda t_blk: (
-        # time-grid blocks, double-buffered: proj, h_prev, dout (and the
-        # stashed gates when present) in; dproj out (h_prev/dout ride the
-        # model's out dtype — _vjp_bwd)
+        # time-grid blocks, double-buffered: proj, h_prev, dout and the
+        # stashed gates in; dproj out (h_prev/dout ride the model's out
+        # dtype — _vjp_bwd)
         2 * (t_blk * b * g3 * io + t_blk * b * h * (hp_io + do_io)
              + t_blk * b * g3 * io
-             + (t_blk * b * g3 * io if stash else 0))
+             + t_blk * b * g3 * io)
         # resident: W_hh + b_hh in, dW/db/dh0 out, dh/dW/db scratch,
         # dgates stash (dot dtype) for the block-batched dW dot
         + h * g3 * w_itemsize + g3 * 4
@@ -457,10 +387,9 @@ def _bwd_per_expert_bytes(b, g3, h, proj_dtype, stash, hp_io, do_io,
 def _bwd_call(proj, h_prev_all, gates_all, w_hh, b_hh, dout, interpret):
     e, t, b, g3 = proj.shape
     h = g3 // 3
-    assert t % T_BLK == 0, (t, T_BLK)   # callers pad_time first
-    stash = gates_all is not None
+    assert t % _T_BLK == 0, (t, _T_BLK)   # callers pad_time first
     per_expert = _bwd_per_expert_bytes(
-        b, g3, h, proj.dtype, stash, h_prev_all.dtype.itemsize,
+        b, g3, h, proj.dtype, h_prev_all.dtype.itemsize,
         dout.dtype.itemsize, w_hh.dtype.itemsize)
     e_blk, t_blk = _choose_blocks(
         e, t, per_expert, f"backward E={e} T={t} B={b} H={h} {proj.dtype}")
@@ -468,25 +397,17 @@ def _bwd_call(proj, h_prev_all, gates_all, w_hh, b_hh, dout, interpret):
     nb = t // t_blk
     grid = (eb, nb)
     rev = lambda i, j: (i, nb - 1 - j, 0, 0)  # walk time blocks back-to-front
-    in_specs = [
-        pl.BlockSpec((e_blk, t_blk, b, g3), rev),
-        pl.BlockSpec((e_blk, t_blk, b, h), rev),
-    ]
-    operands = [proj, h_prev_all]
-    if stash:
-        in_specs.append(pl.BlockSpec((e_blk, t_blk, b, g3), rev))
-        operands.append(gates_all)
-    in_specs += [
-        _resident((e_blk, h, g3)),
-        _resident((e_blk, g3)),
-        pl.BlockSpec((e_blk, t_blk, b, h), rev),
-    ]
-    operands += [w_hh, b_hh, dout]
     dproj, dw, db, dh0 = pl.pallas_call(
-        functools.partial(_bwd_kernel, dot_dtype=_dot_dtype_for(proj.dtype),
-                          stash_gates=stash, loop_order=_checked_loop_order()),
+        functools.partial(_bwd_kernel, dot_dtype=_dot_dtype_for(proj.dtype)),
         grid=grid,
-        in_specs=in_specs,
+        in_specs=[
+            pl.BlockSpec((e_blk, t_blk, b, g3), rev),
+            pl.BlockSpec((e_blk, t_blk, b, h), rev),
+            pl.BlockSpec((e_blk, t_blk, b, g3), rev),
+            _resident((e_blk, h, g3)),
+            _resident((e_blk, g3)),
+            pl.BlockSpec((e_blk, t_blk, b, h), rev),
+        ],
         out_specs=[
             pl.BlockSpec((e_blk, t_blk, b, g3), rev),
             _resident((e_blk, h, g3)),
@@ -510,7 +431,7 @@ def _bwd_call(proj, h_prev_all, gates_all, w_hh, b_hh, dout, interpret):
         ),
         interpret=interpret,
         name=scopes.GRU_KERNEL_BWD,
-    )(*operands)
+    )(proj, h_prev_all, gates_all, w_hh, b_hh, dout)
     return dproj, dw, db, dh0
 
 
@@ -547,14 +468,10 @@ def _vjp_fwd(proj, w_hh, b_hh, h0, interpret):
     # backward consumes it without the concat(h0, h_all[:-1]) round-trip,
     # and h_all itself is NOT a residual (the recompute needs only
     # h_prev).  h0 rides along for its dtype/shape (tiny next to the
-    # [E,T,B,H] stash this replaces).  With STASH_GATES the pre-activation
-    # hidden gates ride as a third output so the backward skips its
-    # recompute dot.
-    outs = _fwd_call(proj, w_hh, b_hh, h0, interpret, emit_prev=True)
-    if STASH_GATES:
-        h_all, h_prev_all, gates_all = outs
-    else:
-        (h_all, h_prev_all), gates_all = outs, None
+    # [E,T,B,H] stash this replaces).  The pre-activation hidden gates
+    # ride as a third output so the backward skips its recompute dot.
+    h_all, h_prev_all, gates_all = _fwd_call(proj, w_hh, b_hh, h0, interpret,
+                                             emit_prev=True)
     return h_all, (proj, w_hh, b_hh, h0, h_prev_all, gates_all)
 
 
@@ -588,13 +505,19 @@ def pad_batch(b: int, dtype=None) -> int:
 
 
 def pad_time(t: int) -> int:
-    """Round the time axis up to the kernel's T_BLK granularity.
+    """Round the time axis up to the kernel's time-block granularity.
 
-    ``gru_recurrence`` requires ``T % T_BLK == 0``; callers pad ``proj``
+    ``gru_recurrence`` requires ``T % _T_BLK == 0``; callers pad ``proj``
     with zeros at the END of scan order to this length and slice the
     output back to ``t`` (the tail contributes zero gradient — see
     ops/gru.py's pallas path)."""
-    return int(np.ceil(t / T_BLK) * T_BLK)
+    return int(np.ceil(t / _T_BLK) * _T_BLK)
+
+
+def pad_experts(e: int) -> int:
+    """Round the expert axis up to the widest expert block, so that a
+    legal block (a multiple of the sublane that divides E) always exists."""
+    return int(np.ceil(e / _E_BLK) * _E_BLK)
 
 
 def supported(t: int, h: int) -> bool:
@@ -606,12 +529,12 @@ def block_plan(e: int, t: int, b: int, h: int, dtype=jnp.float32,
                training: bool = True) -> dict:
     """Predict the (e_blk, t_blk) blocking and scoped-VMEM fit at a shape.
 
-    The round-11 window coalescing fattens the kernels' B (row) axis by
-    G× — the VMEM footprint model that sizes blocks (_choose_blocks) was
-    built at B=32 and is re-validated here at the fatter row counts:
-    callers (tests/test_coalesce.py, benchmarks/kernel_tuning.py
-    ``--coalesce``) probe the EXACT per-expert byte model the kernel calls
-    use (shared _fwd/_bwd_per_expert_bytes) without compiling anything.
+    The serving page fold fattens the kernels' B (row) axis — the VMEM
+    footprint model that sizes blocks (_choose_blocks) was built at B=32
+    and is re-validated here at the fatter row counts: callers
+    (tests/test_coalesce.py, tests/test_trainticket_config.py) probe the
+    EXACT per-expert byte model the kernel calls use (shared
+    _fwd/_bwd_per_expert_bytes) without compiling anything.
 
     ``dtype`` is the kernel I/O (proj) dtype — bf16 for bf16 models, f32
     otherwise (ops/gru.py ``_kernel_io_dtype``); ``b`` is the PRE-padding
@@ -631,13 +554,13 @@ def block_plan(e: int, t: int, b: int, h: int, dtype=jnp.float32,
     out_io = jnp.dtype(_out_dtype_for(io_dtype)).itemsize
     plans = []
     fwd_pe = _fwd_per_expert_bytes(
-        b_pad, g3, h, io_dtype, stash=training and STASH_GATES,
-        n_h_out=2 if training else 1, w_itemsize=w_itemsize, h0_itemsize=4)
+        b_pad, g3, h, io_dtype, emit_prev=training, w_itemsize=w_itemsize,
+        h0_itemsize=4)
     plans.append(("fwd", fwd_pe))
     if training:
         bwd_pe = _bwd_per_expert_bytes(
-            b_pad, g3, h, io_dtype, stash=STASH_GATES, hp_io=out_io,
-            do_io=out_io, w_itemsize=w_itemsize)
+            b_pad, g3, h, io_dtype, hp_io=out_io, do_io=out_io,
+            w_itemsize=w_itemsize)
         plans.append(("bwd", bwd_pe))
     worst = None
     for _name, per_expert in plans:

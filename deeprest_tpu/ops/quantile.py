@@ -37,7 +37,7 @@ def pinball_loss(
         all-zero-weight batch yields loss 0 (and exactly-zero gradients)
         instead of 0/0 NaN.  Real batches have ``sum(weight) >= 1``, where
         ``max(sum, 1)`` returns the identical float — bit-equal to the
-        unguarded loss (the window-coalesced trainer relies on this:
+        unguarded loss (gradient accumulation relies on this:
         zero-weight pad microbatches inside a partially-real group must
         contribute nothing without a per-microbatch cond branch).
 

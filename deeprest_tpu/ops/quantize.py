@@ -21,7 +21,7 @@ discipline:
   consumer and the fused engine's executables stay one-per-rung.
 - ``resolve hooks`` — ``ops.gru.resolve_weights`` and
   ``models.qrnn.resolve_params`` both route here, so the scan and
-  pallas recurrence paths (and the coalesced/bidirectional variants)
+  pallas recurrence paths (and the bidirectional variant)
   share this one dequant site.
 - parity as a product contract — ``parity_envelope`` measures the
   per-(metric, quantile) max deviation vs the f32 reference on a

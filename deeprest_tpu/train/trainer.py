@@ -28,8 +28,7 @@ import optax
 
 from deeprest_tpu.config import Config
 from deeprest_tpu.models.qrnn import (
-    MASKED_PARAM_NAMES, QuantileGRU, fold_feature_mask, put_columns,
-    take_columns,
+    MASKED_PARAM_NAMES, QuantileGRU, put_columns, take_columns,
 )
 from deeprest_tpu.obs import metrics as obs_metrics
 from deeprest_tpu.obs import spans as obs_spans
@@ -419,97 +418,22 @@ class Trainer:
 
             return jax.lax.scan(body, state, (starts_c, weights_c))
 
-        # -- window-coalesced gradient accumulation (round 11) ---------
+        # -- gradient accumulation -------------------------------------
         #
-        # G consecutive plan steps (microbatches) fold into ONE fused
-        # forward/backward — the recurrence's per-step dot sees G·B rows
-        # instead of B — and the optimizer update applies once per G with
-        # grads summed in microbatch order.  Three modes (TrainConfig.
-        # grad_accum_mode); "exact" is the default and is bit-identical
-        # to the unfused "loop" reference:
-        #
-        #   exact: per-microbatch value_and_grad under jax.vmap.  Two
-        #     subtleties make this BIT-equal to the loop: (1) the soft
-        #     feature mask is params-only, so under vmap its backward
-        #     would run once on a pre-summed cotangent (different float
-        #     association than per-microbatch backwards) — the mask fold
-        #     therefore stages through an explicit jax.vjp prologue
-        #     outside the vmap, and each microbatch's fold cotangent is
-        #     pushed through that unbatched vjp separately, in microbatch
-        #     order; (2) dropout draws per-microbatch fold_in(key, g)
-        #     streams, which jax.random reproduces bit-for-bit under
-        #     vmap.  XLA still flattens the shared-weight matmuls to G·B
-        #     rows (the RHS carries no group axis), so the fat-dot win
-        #     survives the exactness.
-        #   flat: the G batches reshape to one [G·B] row batch through
-        #     the model's group axis — the kernel-level row fold (the
-        #     pallas recurrence sees G·B rows directly).  Microbatch
-        #     LOSSES stay bit-exact (rows are independent); weight-grad
-        #     contractions re-associate across groups (~1e-7 relative on
-        #     f32, measured — PERF.md round 11), because one fma-chain
-        #     over G·B rows cannot reproduce "sum of per-group chains".
-        #   loop: G sequential unfused passes — the pinned reference.
-        #
-        # Zero-weight pad microbatches contribute exactly-zero grads
-        # (pinball_loss allow_empty guards the 0/0) so partially-padded
-        # trailing groups need no per-microbatch cond; a fully-padded
-        # group takes the update-level cond skip.  The step counter keeps
-        # counting REAL microbatches, and the per-update dropout key is
+        # G consecutive plan steps (microbatches) each run the shared
+        # step's forward and backward; the optimizer update applies once
+        # per G with grads summed in microbatch order.  Zero-weight pad
+        # microbatches contribute exactly-zero grads (pinball_loss
+        # allow_empty guards the 0/0) so partially-padded trailing groups
+        # need no per-microbatch cond; a fully-padded group takes the
+        # update-level cond skip.  The step counter keeps counting REAL
+        # microbatches, and the per-update dropout key is
         # fold_in(rng, step)-then-fold_in(·, g) — a stream of its own
-        # (grad accumulation is a different training algorithm; it is
-        # pinned against its OWN loop reference, not against G=1).
+        # (grad accumulation is a different training algorithm, not
+        # pinned against G=1).
         accum_g = int(self.config.train.grad_accum_windows)
-        accum_mode = self.config.train.grad_accum_mode
 
-        def _accum_grads_exact(params, x_base, y_base, starts, wb, step_key):
-            folded, fold_vjp = jax.vjp(fold_feature_mask, params)
-            keys = jax.vmap(lambda g: jax.random.fold_in(step_key, g))(
-                jnp.arange(accum_g))
-
-            def micro(s, wb_g, key):
-                xb, yb = gather_windows(x_base, y_base, s)
-
-                def loss_fn(pf):
-                    preds = self.model.apply(
-                        {"params": pf}, xb, deterministic=False,
-                        rngs={"dropout": key}, mask_folded=True,
-                        live_cols=live_cols_of(x_base))
-                    return pinball_loss(preds, yb, quantiles,
-                                        sample_weight=wb_g, allow_empty=True)
-
-                return jax.value_and_grad(loss_fn)(folded)
-
-            losses, gfolded = jax.vmap(micro)(starts, wb, keys)
-            total = None
-            for g in range(accum_g):
-                gg, = fold_vjp(jax.tree.map(lambda a, g=g: a[g], gfolded))
-                total = gg if total is None else jax.tree.map(
-                    jnp.add, total, gg)
-            return losses.astype(jnp.float32), total
-
-        def _accum_grads_flat(params, x_base, y_base, starts, wb, step_key):
-            g, b = starts.shape
-            xb, yb = gather_windows(x_base, y_base, starts.reshape(-1))
-            x4 = xb.reshape(g, b, *xb.shape[1:])
-            y4 = yb.reshape(g, b, *yb.shape[1:])
-
-            def loss_fn(params):
-                preds = self.model.apply(
-                    {"params": params}, x4, deterministic=False,
-                    rngs={"dropout": step_key},
-                    live_cols=live_cols_of(x_base))          # [G,B,T,E,Q]
-                losses = jax.vmap(
-                    lambda p, y, w: pinball_loss(p, y, quantiles,
-                                                 sample_weight=w,
-                                                 allow_empty=True)
-                )(preds, y4, wb)
-                return jnp.sum(losses), losses
-
-            (_, losses), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params)
-            return losses.astype(jnp.float32), grads
-
-        def _accum_grads_loop(params, x_base, y_base, starts, wb, step_key):
+        def _accum_grads(params, x_base, y_base, starts, wb, step_key):
             losses, total = [], None
             for g in range(accum_g):
                 xb, yb = gather_windows(x_base, y_base, starts[g])
@@ -528,12 +452,8 @@ class Trainer:
                                                               total, gg)
             return jnp.stack(losses).astype(jnp.float32), total
 
-        _accum_grads = {"exact": _accum_grads_exact,
-                        "flat": _accum_grads_flat,
-                        "loop": _accum_grads_loop}[accum_mode]
-
         def train_accum_update(state: TrainState, x_base, y_base, starts, wb):
-            """One optimizer update from G coalesced microbatches.
+            """One optimizer update from G microbatches.
             starts/wb: [G, B]."""
             losses, grads = _accum_grads(state.params, x_base, y_base,
                                          starts, wb, dropout_key(state))
@@ -1191,7 +1111,7 @@ class Trainer:
         if staged is None and accum > 1:
             raise ValueError(
                 f"grad_accum_windows={accum} requires the staged "
-                "(device-resident) feed — the coalesced update consumes "
+                "(device-resident) feed — the accumulated update consumes "
                 "its microbatches from the on-device plan; stage the "
                 "dataset (device_data='always' forces it on the CPU "
                 "backend) or set grad_accum_windows=1")
@@ -1346,7 +1266,7 @@ class Trainer:
         skip_chunks = skip_steps // s
         with phase("plan_h2d"):
             starts_d, weights_d = stage_plan(self.mesh, starts, weights)
-        # The coalesced (grad-accum) superstep and the per-step superstep
+        # The accumulation superstep and the per-step superstep
         # share the whole driver: only the compiled scan differs.
         superstep = (self._accum_superstep if cfg.grad_accum_windows > 1
                      else self._superstep)

@@ -29,7 +29,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from deeprest_tpu.config import Config, ModelConfig, TrainConfig
 from deeprest_tpu.data.windows import MinMaxStats
 from deeprest_tpu.models.qrnn import QuantileGRU, resolve_params
-from deeprest_tpu.ops import pallas_gru
 from deeprest_tpu.ops.densify import SparseBase
 from deeprest_tpu.ops.quantize import quantize_params
 from deeprest_tpu.parallel.mesh import AXES
@@ -103,24 +102,20 @@ def _param_shapes(cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 
 
-def _gru_grad(mesh, dtype, rows, groups=None):
+def _gru_grad(mesh, dtype, rows):
     """Lowered ``jax.grad`` of a bidirectional pallas GRU at the flagship
-    shape, ``rows`` windows (``groups`` folds them as [G, B] first)."""
+    shape, ``rows`` windows."""
     def shapes():
         keys = jax.random.split(jax.random.PRNGKey(0))
         return [gru_ops.init_gru_params(k, E, F, H, dtype) for k in keys]
 
     fwd, bwd = (_on(mesh, p, P("expert")) for p in jax.eval_shape(shapes))
-    xshape = (rows, W, F) if groups is None else (groups, rows // groups,
-                                                  W, F)
-    x = _on(mesh, jax.ShapeDtypeStruct(xshape, dtype),
-            P("data") if groups is None else P(None, "data"))
-    run = (gru_ops.bidirectional_gru if groups is None
-           else gru_ops.bidirectional_gru_coalesced)
+    x = _on(mesh, jax.ShapeDtypeStruct((rows, W, F), dtype), P("data"))
     live = mesh if mesh.size > 1 else None
 
     def loss(fwd, bwd, x):
-        out = run(fwd, bwd, x, backend="pallas", mesh=live)
+        out = gru_ops.bidirectional_gru(fwd, bwd, x, backend="pallas",
+                                        mesh=live)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
     return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(fwd, bwd, x)
@@ -132,34 +127,23 @@ def test_kernel_fwd_bwd_flagship(one_chip, dtype):
     assert _kernel_calls(compiled) == 4        # 2 directions x (fwd, bwd)
 
 
-@pytest.mark.parametrize("stash,order", [(False, "expert_inner"),
-                                         (True, "time_inner"),
-                                         (False, "time_inner")])
-def test_kernel_env_variants_compile(one_chip, monkeypatch, stash, order):
-    """The non-default DEEPREST_GRU_STASH_GATES / _LOOP_ORDER settings (the
-    defaults are the case above) — kept only while the chip accepts them."""
-    monkeypatch.setattr(pallas_gru, "STASH_GATES", stash)
-    monkeypatch.setattr(pallas_gru, "LOOP_ORDER", order)
-    compiled = _gru_grad(one_chip, jnp.bfloat16, B).compile()
-    assert _kernel_calls(compiled) == 4
-
-
 def test_kernel_fused_bidirectional_compiles(one_chip, monkeypatch):
-    monkeypatch.setattr(gru_ops, "BIDIR_FUSED", True)
+    monkeypatch.setattr(gru_ops, "_BIDIR_FUSED", True)
     compiled = _gru_grad(one_chip, jnp.bfloat16, B).compile()
     assert _kernel_calls(compiled) == 2        # both directions per call
 
 
-def test_kernel_coalesced_g4(one_chip):
-    """Window coalescing at G=4: 128 rows per recurrence dot, the widest
-    the bf16 training kernels fit (tests/test_coalesce.py block plan)."""
-    compiled = _gru_grad(one_chip, jnp.bfloat16, 4 * B, groups=4).compile()
+def test_kernel_fat_rows_g4(one_chip):
+    """Four batches' rows in one call: 128 rows per recurrence dot, the
+    widest the bf16 training kernels fit (tests/test_coalesce.py block
+    plan)."""
+    compiled = _gru_grad(one_chip, jnp.bfloat16, 4 * B).compile()
     assert _kernel_calls(compiled) == 4
 
 
 def test_kernel_f32_g4_has_no_plan_and_says_so(one_chip):
     with pytest.raises(ValueError, match=r"backward E=40 T=60 B=128 H=128"):
-        _gru_grad(one_chip, jnp.float32, 4 * B, groups=4)
+        _gru_grad(one_chip, jnp.float32, 4 * B)
 
 
 @pytest.mark.parametrize("shape", [(4, 1, 1), (2, 2, 1)])
@@ -244,15 +228,13 @@ def test_fused_serve_program_10k_sparse(one_chip):
 # ---------------------------------------------------------------------------
 
 
-def _train_step_lowered(mesh, feature_dim, sparse, accum_mode=None,
+def _train_step_lowered(mesh, feature_dim, sparse, accum=1,
                         superstep=False):
     """``superstep``: the G=1 superstep (a 3 x 50 plan, as the 10k cell's
     126-step epoch gives) instead of the per-step program."""
-    accum = 1 if accum_mode is None else 4
     cfg = Config(model=_model_config(feature_dim=feature_dim),
                  train=TrainConfig(batch_size=B, window_size=W,
-                                   grad_accum_windows=accum,
-                                   grad_accum_mode=accum_mode or "exact"))
+                                   grad_accum_windows=accum))
     trainer = Trainer(cfg, feature_dim, [f"m{i}" for i in range(E)],
                       mesh=mesh)
 
@@ -293,23 +275,23 @@ def _train_step_lowered(mesh, feature_dim, sparse, accum_mode=None,
     return program.lower(state_sds, *_on(mesh, args))
 
 
-@pytest.mark.parametrize("feature_dim,sparse,accum_mode", [
-    (F, False, None),
-    (F, False, "exact"),
-    (F, False, "flat"),
-    (F_10K, True, None),
+@pytest.mark.parametrize("feature_dim,sparse,accum", [
+    (F, False, 1),
+    (F, False, 4),
+    (F_10K, True, 1),
     # by hand (`-m slow`): one more whole-step compile is CPU time the
     # timing gates of tier-1's bench tests do not have to spare
-    pytest.param(F_10K, "compact", None, marks=pytest.mark.slow),
+    pytest.param(F_10K, "compact", 1, marks=pytest.mark.slow),
 ])
-def test_train_step_fits_the_chip(one_chip, feature_dim, sparse, accum_mode):
+def test_train_step_fits_the_chip(one_chip, feature_dim, sparse, accum):
     """Forward, backward and Adam in one program, state donated: the
-    kernel is in it and arguments + temporaries fit 16 GB of HBM.  With an
-    ``accum_mode`` it is the G=4 window-coalesced superstep instead.  The
-    compact form never builds a window or a folded weight F wide."""
+    kernel is in it and arguments + temporaries fit 16 GB of HBM.  With
+    ``accum`` 4 it is the accumulation superstep instead: four passes an
+    update.  The compact form never builds a window or a folded weight F
+    wide."""
     compiled = _train_step_lowered(one_chip, feature_dim, sparse,
-                                   accum_mode).compile()
-    assert _kernel_calls(compiled) == 4
+                                   accum).compile()
+    assert _kernel_calls(compiled) == 4 * accum
     if sparse == "compact":
         text = compiled.as_text()
         assert f"[{B},{W},{U_LIVE}]" in text

@@ -16,7 +16,7 @@ SMOKE = os.path.join(REPO, "chip_smoke.py")
 
 PHASES = (
     "device", "compile", "kernel_in_train_step", "block_until_ready",
-    "kernel_vs_scan", "coalesced_vs_per_group", "wide_train_step",
+    "kernel_vs_scan", "row_fold_vs_per_group", "wide_train_step",
     "wide_fused_predict", "memory", "checks", "compile_cache", "simulate",
     "featurize", "train", "train_superstep_g4", "serve", "serve_int8",
     "export_aot", "serve_aot_load", "total",

@@ -1,14 +1,13 @@
-"""Round-11 window-coalescing tests: the bit-parity matrix for the
-coalesced recurrence (ops-level row fold, model group axis, the
-grad-accum superstep vs its unfused loop reference), the VMEM block-plan
-re-validation at fat row counts, serve-side page coalescing vs the pinned
-host reference, and the no-recompile probes.
+"""Row folds and gradient accumulation: the recurrence over G·B rows
+against G calls of B rows (the serving page fold relies on rows being
+independent), the accumulation superstep against one update on the summed
+per-microbatch gradients, the VMEM block-plan re-validation at fat row
+counts, serve-side page coalescing vs the pinned host reference, and the
+no-recompile probes.
 
 The parity bar: a row fold is the same arithmetic on independent rows, so
 it is held to conftest's `assert_fold_equal` — FOLD_ULPS f32 ulp of the
-reference's largest magnitude, see there for why not bit equality — and a documented,
-measured tolerance where float reassociation is in the algorithm itself
-(the "flat" mode's cross-group weight-grad contractions — PERF.md).
+reference's largest magnitude, see there for why not bit equality.
 """
 
 import dataclasses
@@ -85,9 +84,7 @@ def test_config_rejects_bad_accum():
         TrainConfig(grad_accum_windows=0)
     with pytest.raises(ValueError, match="grad_accum_windows"):
         TrainConfig(grad_accum_windows=True)
-    with pytest.raises(ValueError, match="grad_accum_mode"):
-        TrainConfig(grad_accum_mode="fast")
-    TrainConfig(grad_accum_windows=4, grad_accum_mode="flat")
+    TrainConfig(grad_accum_windows=4)
     with pytest.raises(ValueError, match="coalesce_pages"):
         InferConfig(coalesce_pages=0)
     InferConfig(coalesce_pages=4)
@@ -113,67 +110,27 @@ def test_accum_requires_staged_feed(bundle):
 
 
 @pytest.mark.parametrize("backend", ["scan", "pallas_interpret"])
-def test_gru_coalesced_equal_per_group(backend):
-    """G folded window batches through ONE recurrence == G standalone
-    calls on both backends (rows are independent; assert_fold_equal)."""
-    from deeprest_tpu.ops.gru import (
-        bidirectional_gru, bidirectional_gru_coalesced, gru, gru_coalesced,
-        init_gru_params,
-    )
+def test_gru_rows_fold_equal_per_group(backend):
+    """G window batches folded into the rows of ONE recurrence == G
+    standalone calls on both backends (rows are independent;
+    assert_fold_equal)."""
+    from deeprest_tpu.ops.gru import bidirectional_gru, gru, init_gru_params
 
     rng = np.random.default_rng(0)
     e, f, h, g, b, t = 2, 8, 128, 3, 8, 7
     fwd = init_gru_params(jax.random.PRNGKey(1), e, f, h)
     bwd = init_gru_params(jax.random.PRNGKey(2), e, f, h)
     x = jnp.asarray(rng.standard_normal((g, b, t, f)), jnp.float32)
+    flat = x.reshape(g * b, t, f)
 
-    out = gru_coalesced(fwd, x, backend=backend)
-    assert out.shape == (e, g, b, t, h)
-    outb = bidirectional_gru_coalesced(fwd, bwd, x, backend=backend)
+    out = gru(fwd, flat, backend=backend)
+    assert out.shape == (e, g * b, t, h)
+    outb = bidirectional_gru(fwd, bwd, flat, backend=backend)
     for gi in range(g):
-        assert_fold_equal(out[:, gi], gru(fwd, x[gi], backend=backend))
+        rows = slice(gi * b, (gi + 1) * b)
+        assert_fold_equal(out[:, rows], gru(fwd, x[gi], backend=backend))
         assert_fold_equal(
-            outb[:, gi], bidirectional_gru(fwd, bwd, x[gi], backend=backend))
-
-
-def test_group_spec_round_trip():
-    from deeprest_tpu.ops.gru import GroupSpec, coalesce_windows, split_coalesced
-
-    x = jnp.arange(2 * 3 * 4 * 5, dtype=jnp.float32).reshape(2, 3, 4, 5)
-    flat, spec = coalesce_windows(x)
-    assert flat.shape == (6, 4, 5)
-    assert spec == GroupSpec(groups=2, rows=3) and spec.coalesced_rows == 6
-    h = jnp.zeros((7, 6, 4, 8))
-    assert split_coalesced(h, spec).shape == (7, 2, 3, 4, 8)
-    with pytest.raises(ValueError, match="rows"):
-        split_coalesced(jnp.zeros((7, 5, 4, 8)), spec)
-    with pytest.raises(ValueError, match="window groups"):
-        coalesce_windows(jnp.zeros((6, 4, 5)))
-
-
-def test_model_group_axis_and_mask_fold_equal():
-    """The model's [G,B,T,F] group axis == per-group 3-D applies
-    (assert_fold_equal), and an externally folded mask (fold_feature_mask
-    + mask_folded=True) == the internal fold, bit-for-bit (the exact-mode
-    trainer's two structural prerequisites)."""
-    from deeprest_tpu.models.qrnn import QuantileGRU, fold_feature_mask
-
-    cfg = ModelConfig(feature_dim=16, num_metrics=3, hidden_size=8)
-    model = QuantileGRU(config=cfg)
-    rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.random((4, 6, 12, 16), np.float32))
-    params = dict(model.init(jax.random.PRNGKey(0), x[0])["params"])
-
-    p4 = model.apply({"params": params}, x)
-    assert p4.shape == (4, 6, 12, 3, 3)
-    for g in range(4):
-        assert_fold_equal(p4[g], model.apply({"params": params}, x[g]))
-
-    jit_folded = jax.jit(lambda p, xb: model.apply(
-        {"params": fold_feature_mask(p)}, xb, mask_folded=True))
-    jit_normal = jax.jit(lambda p, xb: model.apply({"params": p}, xb))
-    np.testing.assert_array_equal(np.asarray(jit_folded(params, x[0])),
-                                  np.asarray(jit_normal(params, x[0])))
+            outb[:, rows], bidirectional_gru(fwd, bwd, x[gi], backend=backend))
 
 
 # ---------------------------------------------------------------------------
@@ -206,20 +163,20 @@ def test_block_plan_fat_rows_flagship():
 
 
 def test_block_plan_matches_kernel_execution():
-    """A coalesced fat-row batch runs through the REAL (interpret-mode)
-    kernel at a shape whose block plan fits — fwd and VJP."""
+    """A fat-row batch runs through the REAL (interpret-mode) kernel at
+    a shape whose block plan fits — fwd and VJP."""
     from deeprest_tpu.ops import pallas_gru
-    from deeprest_tpu.ops.gru import gru_coalesced, init_gru_params
+    from deeprest_tpu.ops.gru import gru, init_gru_params
 
     e, f, h, g, b, t = 2, 8, 128, 4, 8, 7
     plan = pallas_gru.block_plan(e, t, g * b, h, training=True)
     assert plan["fits"]
     params = init_gru_params(jax.random.PRNGKey(0), e, f, h)
-    x = jnp.asarray(np.random.default_rng(0).standard_normal((g, b, t, f)),
+    x = jnp.asarray(np.random.default_rng(0).standard_normal((g * b, t, f)),
                     jnp.float32)
 
     def loss(p):
-        return jnp.sum(gru_coalesced(p, x, backend="pallas_interpret") ** 2)
+        return jnp.sum(gru(p, x, backend="pallas_interpret") ** 2)
 
     grads = jax.grad(loss)(params)
     assert all(np.isfinite(np.asarray(l)).all()
@@ -232,50 +189,51 @@ def test_block_plan_matches_kernel_execution():
 
 
 @pytest.mark.parametrize("g", [2, 4])
-def test_accum_exact_equal_to_loop(bundle, g):
-    """The fused 'exact' coalesced update == the unfused accumulation
-    loop (assert_fold_equal): per-microbatch losses, params, optimizer
-    state, and step counter, across epochs with ragged chunks — WITH
-    dropout on (the per-microbatch fold_in streams reproduce under vmap,
-    or the losses would differ in the first digits, not the last)."""
-    t_loop = trainer_with(bundle, grad_accum_windows=g,
-                          grad_accum_mode="loop", steps_per_superstep=4)
-    s_loop, l_loop = run_epochs(t_loop, bundle, epochs=2)
-    t_exact = trainer_with(bundle, grad_accum_windows=g,
-                           grad_accum_mode="exact", steps_per_superstep=4)
-    s_exact, l_exact = run_epochs(t_exact, bundle, epochs=2)
-    for a, b in zip(l_exact, l_loop):
-        assert_fold_equal(a, b)
-    assert_states_fold_equal(s_exact, s_loop)
-    # K=4 microbatches/epoch: the counter still counts REAL microbatches
-    assert int(s_exact.step) == 2 * 4
+def test_accum_update_is_one_update_on_the_summed_gradients(bundle, g):
+    """One accumulated update == the optimizer applied once to the sum, in
+    microbatch order, of G per-microbatch gradients taken at the same
+    parameters, dropout on with key fold_in(fold_in(rng, step), g); a
+    zero-weight pad microbatch adds nothing and is not counted."""
+    from deeprest_tpu.ops.quantile import pinball_loss
+    from deeprest_tpu.parallel.distributed import stage_plan
 
+    t = trainer_with(bundle, grad_accum_windows=g, steps_per_superstep=g)
+    x_base, y_base = staged = t.stage_dataset(bundle)
+    state = t.init_state(bundle.x_train, seed=3)
+    b, w = SMALL.train.batch_size, SMALL.train.window_size
+    starts = np.random.default_rng(1).integers(
+        0, bundle.num_train_windows, (1, g, b)).astype(np.int32)
+    weights = np.ones((1, g, b), np.float32)
+    weights[0, -1] = 0.0                         # the last microbatch is pad
+    params0 = jax.tree.map(np.asarray, state.params)
+    opt0, rng0, step0 = state.opt_state, state.rng, int(state.step)
+    key = jax.random.fold_in(rng0, step0)
 
-def test_accum_flat_losses_exact_params_tolerance(bundle):
-    """'flat' mode (kernel-level row fold): per-microbatch losses of the
-    FIRST update are bit-exact vs the loop (forward is row-independent),
-    and params stay within the documented ~1e-7-relative reassociation
-    envelope — the cross-group fma-chains in the weight-grad contractions
-    cannot reproduce the loop's per-group-sum association (PERF.md round
-    11).  Dropout 0: flat draws one fat mask, a different (equally valid)
-    stream than the loop's per-microbatch draws."""
-    model = dataclasses.replace(SMALL.model, dropout_rate=0.0)
+    def micro(params, i):
+        idx = starts[0, i][:, None] + np.arange(w)[None, :]
+        preds = t.model.apply(
+            {"params": params}, x_base[idx], deterministic=False,
+            rngs={"dropout": jax.random.fold_in(key, i)})
+        return pinball_loss(preds, y_base[idx], SMALL.model.quantiles,
+                            sample_weight=jnp.asarray(weights[0, i]),
+                            allow_empty=True)
 
-    def tr(mode):
-        cfg = Config(model=model,
-                     train=dataclasses.replace(
-                         SMALL.train, grad_accum_windows=2,
-                         grad_accum_mode=mode, steps_per_superstep=4))
-        return Trainer(cfg, bundle.feature_dim, bundle.metric_names)
+    losses, total = [], None
+    for i in range(g):
+        loss, grads = jax.jit(jax.value_and_grad(micro), static_argnums=1)(
+            params0, i)
+        losses.append(float(loss))
+        total = grads if total is None else jax.tree.map(jnp.add, total, grads)
+    updates, _ = t.tx.update(total, jax.tree.map(jnp.asarray, opt0))
+    want = jax.tree.map(lambda p, u: p + u, params0, updates)
 
-    s_loop, l_loop = run_epochs(tr("loop"), bundle, epochs=1)
-    s_flat, l_flat = run_epochs(tr("flat"), bundle, epochs=1)
-    np.testing.assert_array_equal(l_flat[0][:2], l_loop[0][:2])
-    for x, y in zip(jax.tree.leaves(s_flat.params),
-                    jax.tree.leaves(s_loop.params)):
-        np.testing.assert_allclose(np.asarray(x), np.asarray(y),
-                                   rtol=5e-6, atol=1e-7)
-    assert int(s_flat.step) == int(s_loop.step)
+    got, got_losses = t._accum_superstep(
+        state, *staged, *stage_plan(t.mesh, starts, weights), 0)
+    assert_fold_equal(got_losses, np.asarray(losses, np.float32))
+    assert losses[-1] == 0.0
+    for a, r in zip(jax.tree.leaves(got.params), jax.tree.leaves(want)):
+        assert_fold_equal(a, r)
+    assert int(got.step) == step0 + g - 1        # REAL microbatches only
 
 
 def test_accum_g1_config_uses_historical_superstep(bundle):
@@ -305,8 +263,8 @@ def test_accum_one_executable_across_epochs(bundle):
         state, _ = t.train_epoch(state, bundle, rng, staged=staged)
     assert probe() == 1
     # G is a plan-shape static: a DIFFERENT G is its own trainer/executable
-    # (test_accum_exact_equal_to_loop exercises G=2 and G=4; each
-    # holds the invariant independently).
+    # (test_accum_update_is_one_update_on_the_summed_gradients exercises
+    # G=2 and G=4; each holds the invariant independently).
 
 
 def test_accum_smoke_fit(bundle):
@@ -326,22 +284,21 @@ def test_accum_smoke_fit(bundle):
 
 
 # ---------------------------------------------------------------------------
-# bidirectional: revert default + fused path stays covered behind the knob
+# bidirectional: two calls by default, the fused path stays covered
 # ---------------------------------------------------------------------------
 
 
-def test_bidir_default_unfused_and_fused_knob_parity(monkeypatch):
-    """Round 11 reverts fused bidirectional (PERF.md: on-chip unfused
-    122.0 beat fused 117.2): the DEFAULT pallas path is two calls.  The
-    fused kernel stays behind BIDIR_FUSED for on-chip A/B and must keep
-    matching the scan spec."""
+def test_bidir_default_unfused_and_fused_parity(monkeypatch):
+    """The DEFAULT pallas path is two calls a layer.  The fused form stays
+    behind the constant _BIDIR_FUSED (PERF.md section 6, PR 28) and must
+    keep matching the scan spec."""
     import importlib
 
     # deeprest_tpu.ops re-exports the gru FUNCTION, shadowing the module
     # on attribute access — importlib reaches the module unambiguously.
     gru_mod = importlib.import_module("deeprest_tpu.ops.gru")
 
-    assert gru_mod.BIDIR_FUSED is False   # the revert, default off
+    assert gru_mod._BIDIR_FUSED is False
 
     rng = np.random.default_rng(3)
     fwd = gru_mod.init_gru_params(jax.random.PRNGKey(1), 3, 8, 128)
@@ -351,7 +308,7 @@ def test_bidir_default_unfused_and_fused_knob_parity(monkeypatch):
 
     unfused = np.asarray(gru_mod.bidirectional_gru(
         fwd, bwd, x, backend="pallas_interpret"))
-    monkeypatch.setattr(gru_mod, "BIDIR_FUSED", True)
+    monkeypatch.setattr(gru_mod, "_BIDIR_FUSED", True)
     fused = np.asarray(gru_mod.bidirectional_gru(
         fwd, bwd, x, backend="pallas_interpret"))
     np.testing.assert_allclose(unfused, ref, rtol=1e-5, atol=1e-5)

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import assert_fold_equal
-
 from deeprest_tpu.config import Config, MeshConfig, ModelConfig, TrainConfig
 from deeprest_tpu.data.windows import MinMaxStats
 from deeprest_tpu.obs.metrics import REGISTRY
@@ -198,17 +196,14 @@ def test_a_dense_base_never_sees_a_table():
 # -- parity of the model and of the step --------------------------------------
 
 
-@pytest.mark.parametrize("mask_folded", [False, True])
 @pytest.mark.parametrize("dtype, rtol, atol", [("float32", 2e-5, 1e-6),
                                                ("bfloat16", 2e-2, 2e-3)])
-def test_compact_call_agrees_with_the_dense_call(mask_folded, dtype, rtol,
-                                                 atol):
+def test_compact_call_agrees_with_the_dense_call(dtype, rtol, atol):
     """``live_cols`` (ISSUE 25): the layer-0 projection over the live
     columns only, pad slots among them, against the dense call on the
     input those columns come from: predictions, loss and every gradient
-    leaf, which keeps its dense shape and is zero at the columns left out.
-    With ``mask_folded`` the take is applied to the folded leaf."""
-    from deeprest_tpu.models.qrnn import QuantileGRU, fold_feature_mask
+    leaf, which keeps its dense shape and is zero at the columns left out."""
+    from deeprest_tpu.models.qrnn import QuantileGRU
     from deeprest_tpu.ops.quantile import pinball_loss
 
     cfg = ModelConfig(feature_dim=64, num_metrics=3, hidden_size=4,
@@ -223,12 +218,9 @@ def test_compact_call_agrees_with_the_dense_call(mask_folded, dtype, rtol,
     x[..., live] = rng.random((2, 5, 9))
     y = jnp.asarray(rng.random((2, 5, 3), np.float32))
     params = variables["params"]
-    if mask_folded:
-        params = fold_feature_mask(params)
 
     def loss_fn(params, xb, live_cols):
-        preds = model.apply({"params": params}, xb, mask_folded=mask_folded,
-                            live_cols=live_cols)
+        preds = model.apply({"params": params}, xb, live_cols=live_cols)
         return pinball_loss(preds, y, cfg.quantiles), preds
 
     (want, want_preds), want_g = jax.value_and_grad(
@@ -249,7 +241,7 @@ def test_compact_call_agrees_with_the_dense_call(mask_folded, dtype, rtol,
             assert np.asarray(g)[:, live].any(), name
     with pytest.raises(ValueError, match="live columns"):
         model.apply({"params": params}, jnp.asarray(x),
-                    mask_folded=mask_folded, live_cols=jnp.asarray(table))
+                    live_cols=jnp.asarray(table))
 
 
 def _three_steps(trainer, base, y):
@@ -295,32 +287,29 @@ def test_adam_after_three_steps_agrees_leaf_by_leaf_dead_rows_included():
         assert np.asarray(got.opt_state[0].mu[name])[:, hot].any()
 
 
-@pytest.mark.parametrize("mode", ["exact", "flat"])
-def test_accumulation_modes_agree_with_the_loop_on_a_compact_base(mode):
+def test_accumulation_on_a_compact_base_agrees_with_its_dense_form():
     cols, vals, y, _ = _corpus(60)
     bundle = _bundle(cols, vals, y)
+    trainer = _trainer(grad_accum_windows=2, steps_per_superstep=4)
+    compact = trainer.stage_dataset(bundle)
+    assert compact[0].width == 128
+    mn, rg = np.zeros((1,), np.float32), np.array([vals.max()], np.float32)
+    dense = (stage_sparse_base(trainer.mesh, cols, vals, mn, rg, F),
+             compact[1])
 
-    def run(accum_mode):
-        # flat draws one fat dropout mask, another stream than the loop's
-        trainer = _trainer(grad_accum_windows=2, grad_accum_mode=accum_mode,
-                           steps_per_superstep=4,
-                           dropout=0.0 if mode == "flat" else 0.1)
-        staged = trainer.stage_dataset(bundle)
-        assert staged[0].width == 128
+    def run(staged):
         state = trainer.init_state(trainer.sample_input(bundle), seed=1)
         state, _ = trainer.train_epoch(state, bundle,
                                        np.random.default_rng(5), staged=staged)
         return state, trainer._last_epoch_losses
 
-    want, want_losses = run("loop")
-    got, got_losses = run(mode)
-    if mode == "exact":
-        np.testing.assert_array_equal(got_losses, want_losses)
-        for x, z in zip(jax.tree.leaves(got.params),
-                        jax.tree.leaves(want.params)):
-            assert_fold_equal(x, z)
-    else:       # the row fold re-associates the weight gradients (PERF.md)
-        np.testing.assert_allclose(got_losses, want_losses, rtol=1e-5)
+    want, want_losses = run(dense)
+    got, got_losses = run(compact)
+    np.testing.assert_allclose(got_losses, want_losses, rtol=RTOL)
+    for (path, x), z in zip(jax.tree.leaves_with_path(got.params),
+                            jax.tree.leaves(want.params)):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(z), rtol=RTOL,
+                                   atol=ATOL, err_msg=str(path))
 
 
 def test_evaluate_on_a_compact_base_agrees_with_the_dense_form():

@@ -57,7 +57,6 @@ def test_bidirectional_matches_torch():
     np.testing.assert_allclose(out, tout, rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.slow
 def test_expert_axis_is_independent():
     """Each expert's output must equal running it alone (no cross-talk)."""
     key = jax.random.PRNGKey(0)
@@ -72,7 +71,6 @@ def test_expert_axis_is_independent():
                                    rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.slow
 def test_gru_jit_and_grad():
     params = init_gru_params(jax.random.PRNGKey(0), 2, 4, 8)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 3, 10, 4))

@@ -16,7 +16,7 @@ from deeprest_tpu.ops.gru import (
     init_gru_params,
 )
 
-E, B, T, F, H = 3, 5, 7, 11, 128  # E not a multiple of E_BLK, B not of 8
+E, B, T, F, H = 3, 5, 7, 11, 128  # E and B not multiples of 8 (the blocks)
 
 
 def _setup(seed=0, e=E, b=B, t=T, f=F, h=H):
@@ -28,7 +28,6 @@ def _setup(seed=0, e=E, b=B, t=T, f=F, h=H):
 
 
 @pytest.mark.parametrize("reverse", [False, True])
-@pytest.mark.slow
 def test_forward_matches_scan(reverse):
     params, x, _ = _setup()
     ref = gru(params, x, reverse=reverse, backend="scan")
@@ -38,9 +37,8 @@ def test_forward_matches_scan(reverse):
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.slow
 def test_forward_aligned_shapes():
-    # E multiple of E_BLK and B multiple of 8: the no-padding fast path.
+    # E and B multiples of 8: the no-padding fast path.
     params, x, _ = _setup(e=8, b=16)
     ref = gru(params, x, backend="scan")
     out = gru(params, x, backend="pallas_interpret")
@@ -49,10 +47,10 @@ def test_forward_aligned_shapes():
 
 
 @pytest.mark.parametrize("t", [1, 2, 6, 12])
-@pytest.mark.slow
 def test_time_blocking_boundaries(t):
-    # T below / equal to / a multiple of T_BLK: padding and the in-program
-    # time loop must agree with scan in both directions, values and grads.
+    # T below / equal to / a multiple of the time block (6): padding and the
+    # in-program time loop must agree with scan in both directions, values
+    # and grads.
     params, x, _ = _setup(t=t)
 
     def loss(backend, x):
@@ -69,7 +67,6 @@ def test_time_blocking_boundaries(t):
                                rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.slow
 def test_gradients_match_scan():
     params, x, _ = _setup()
 
@@ -86,22 +83,20 @@ def test_gradients_match_scan():
         )
 
 
-@pytest.mark.slow
 def test_fused_bidirectional_distinct_params_odd_shapes(monkeypatch):
     """The fused-bidirectional path (both directions stacked on the expert
     axis, one kernel invocation) must be exact against the scan backend
     with DISTINCT fwd/bwd weights at shapes that hit every padding branch
-    (odd E, B below the sublane, T off the T_BLK grid).  Since the round-11
-    revert (ops/gru.BIDIR_FUSED=0: unfused won on-chip) the fused kernel
-    is opt-in — force it here so the path stays covered for the on-chip
-    A/B it remains available for."""
+    (odd E, B below the sublane, T off the time-block grid).  The path is
+    off (ops/gru._BIDIR_FUSED) and kept for the PR that takes its gain
+    (PERF.md section 6, PR 28) — force it here so it stays covered."""
     import importlib
 
     # deeprest_tpu.ops re-exports the gru FUNCTION, shadowing the module
     # on attribute access — importlib reaches the module unambiguously.
     gru_mod = importlib.import_module("deeprest_tpu.ops.gru")
 
-    monkeypatch.setattr(gru_mod, "BIDIR_FUSED", True)
+    monkeypatch.setattr(gru_mod, "_BIDIR_FUSED", True)
     e, b, t, f, h = 5, 3, 13, 7, 128
     kf, kb, kx = jax.random.split(jax.random.PRNGKey(7), 3)
     fwd = init_gru_params(kf, e, f, h)
@@ -124,7 +119,6 @@ def test_fused_bidirectional_distinct_params_odd_shapes(monkeypatch):
                                    rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.slow
 def test_bf16_proj_io_matches_bf16_scan():
     """With bf16 params/inputs the kernel keeps bf16 proj I/O (the einsum
     already quantized the values — storing f32 would just double the
@@ -158,21 +152,11 @@ def test_bf16_proj_io_matches_bf16_scan():
         assert np.max(np.abs(a - b_)) < 0.15 * (1e-3 + np.max(np.abs(a)))
 
 
-@pytest.mark.parametrize("stash", [True, False])
-@pytest.mark.parametrize("order", ["expert_inner", "time_inner"])
 @pytest.mark.parametrize("dtype", ["f32", "bf16"])
-@pytest.mark.slow
-def test_kernel_knob_configs_match_scan(monkeypatch, stash, order, dtype):
-    """Every STASH_GATES × LOOP_ORDER config must agree with the scan
-    backend in values and grads, in BOTH dtypes (the bf16 non-stash path
-    is the recompute-dot branch; f32 stash is a lossless round-trip) —
-    whichever config loses the on-chip tuning A/B
-    (benchmarks/kernel_tuning.py) must not rot into broken code, because
-    the knobs exist precisely so the default can flip."""
-    from deeprest_tpu.ops import pallas_gru
-
-    monkeypatch.setattr(pallas_gru, "STASH_GATES", stash)
-    monkeypatch.setattr(pallas_gru, "LOOP_ORDER", order)
+def test_bidirectional_values_and_input_grads_match_scan(dtype):
+    """The kernels agree with the scan backend in values and grads in
+    BOTH dtypes, over a window off the time-block grid (f32 gate stash is
+    a lossless round-trip; bf16 rounds it to the kernel's I/O dtype)."""
     params, x, _ = _setup(t=9)
     if dtype == "bf16":
         params = jax.tree.map(lambda a: a.astype(jnp.bfloat16), params)
@@ -195,7 +179,6 @@ def test_kernel_knob_configs_match_scan(monkeypatch, stash, order, dtype):
             1e-3 + np.max(np.abs(g_ref)))
 
 
-@pytest.mark.slow
 def test_gradient_wrt_input_matches_scan():
     params, x, _ = _setup()
 
@@ -208,7 +191,6 @@ def test_gradient_wrt_input_matches_scan():
                                rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.slow
 def test_model_parity_across_backends():
     """The full QuantileGRU forward agrees between backends."""
     import dataclasses
@@ -249,7 +231,6 @@ def test_no_fitting_plan_raises_naming_the_shape(monkeypatch):
         gru(params, x, backend="pallas_interpret")
 
 
-@pytest.mark.slow
 def test_vmem_budget_shrinks_time_block(monkeypatch):
     """When the block footprint would exceed the scoped-VMEM budget, the
     chooser shrinks the TIME block (the expert block is sublane-pinned to
@@ -271,8 +252,7 @@ def test_vmem_budget_shrinks_time_block(monkeypatch):
     # E=3 pads to 8 experts, B=5 to 8 rows: admit the backward kernel (the
     # larger of the two) at t_blk=1 and nothing wider.
     per_expert = pallas_gru._bwd_per_expert_bytes(
-        8, 3 * H, H, jnp.float32, stash=pallas_gru.STASH_GATES, hp_io=4,
-        do_io=4, w_itemsize=4)
+        8, 3 * H, H, jnp.float32, hp_io=4, do_io=4, w_itemsize=4)
     monkeypatch.setattr(pallas_gru, "_VMEM_BUDGET", 8 * per_expert(1))
     e_blk, t_blk = pallas_gru._choose_blocks(8, 12, per_expert)
     assert t_blk == 1 and e_blk == 8      # shrank time, kept sublane-legal E

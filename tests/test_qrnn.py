@@ -45,7 +45,6 @@ def reference_forward(params, x, cfg):
     return np.stack(preds, axis=2)  # [B,T,E,Q]
 
 
-@pytest.mark.slow
 def test_forward_matches_explicit_loop():
     model, variables, x = init_model()
     got = np.asarray(model.apply(variables, x))
@@ -61,7 +60,6 @@ def test_output_shape_and_dtype():
     assert out.dtype == jnp.float32
 
 
-@pytest.mark.slow
 def test_single_metric_mix_fallback():
     cfg = ModelConfig(feature_dim=4, num_metrics=1, hidden_size=3)
     model, variables, x = init_model(cfg)
@@ -71,7 +69,6 @@ def test_single_metric_mix_fallback():
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.slow
 def test_dropout_train_vs_eval():
     model, variables, x = init_model()
     eval_a = model.apply(variables, x, deterministic=True)
@@ -125,9 +122,12 @@ def test_feature_dim_mismatch_raises():
         assert False, "expected ValueError"
     except ValueError as e:
         assert "feature_dim" in str(e)
+    # a [G, B, T, F] stack is no calling convention (PR 28): it would
+    # otherwise reach the recurrence as per-expert input
+    with pytest.raises(ValueError, match=r"\[B, T, F\]"):
+        model.apply(variables, jnp.zeros((2, 2, 5, CFG.feature_dim)))
 
 
-@pytest.mark.slow
 def test_stacked_layers():
     cfg = ModelConfig(feature_dim=6, num_metrics=2, hidden_size=4, num_layers=2)
     model, variables, x = init_model(cfg)
