@@ -16,6 +16,14 @@ one batched scan with the expert axis `E` as a leading array dimension, which
 Gate math matches torch's GRU (gate order r, z, n; two separate biases;
 ``n = tanh(x_n + b_in + r * (h @ W_hn + b_hn))``) so numerics are directly
 comparable against the public torch API.
+
+On a TPU (backend 'auto') the scan is the fused kernel of
+ops/pallas_gru.py: one kernel call a direction, on projections and hidden
+states in the kernels' ``[E, T, B, ·]`` order.  This module does the layout
+work round the calls, and there is one form of it: a reverse direction is
+flipped in time going in and coming out, and a bidirectional layer joins its
+two directions on the last axis BEFORE the one transpose to ``[E, B, T,
+2H]`` (:func:`_bidir_pallas`).
 """
 
 from __future__ import annotations
@@ -29,15 +37,6 @@ import numpy as np
 from deeprest_tpu.ops import scopes
 
 _BACKENDS = ("auto", "scan", "pallas", "pallas_interpret")
-
-# Fused bidirectional: both directions stacked on the expert axis of ONE
-# gru_recurrence call.  Off, as it has been since round 11: two
-# single-direction calls a layer.  On the chip the fused form read 13.0%
-# and 7.2% more steps/s in the benchmark's two cells (PERF.md section 6,
-# PR 28: its kernels are slower, what follows them is faster), so it is
-# kept, as a constant no run sets, for the perf_opt PR that takes the gain
-# (ROADMAP speed queue) and deletes the form that loses.
-_BIDIR_FUSED = False
 
 
 def _resolve_backend(backend: str) -> str:
@@ -153,47 +152,35 @@ def _project(params: GRUParams, x: jax.Array) -> jax.Array:
         return proj.astype(_kernel_io_dtype(proj.dtype))
 
 
-def _recur_local(projs, w_hhs, b_hhs, h0s, interpret: bool):
+def _recur_local(proj, w_hh, b_hh, h0, interpret: bool):
     """The kernel call on the arrays one device holds.
 
-    ``projs``/``w_hhs``/``b_hhs``/``h0s`` are tuples with one entry per
-    direction, each already in scan order (``[E, T, B, 3H]``, ``[E, H,
-    3H]``, ``[E, 3H]``, ``[E, B, H]``).  Two directions ride ONE
-    ``gru_recurrence`` call, stacked along the expert axis (the fused
-    bidirectional form — the kernel only ever scans its grid forward).
-    Shape hygiene for the kernel's tiling happens here, per device: rows
-    pad to the sublane, experts and time to the kernel's widest blocks.  The
-    time pad sits at the END of scan order, beyond every real output: sliced
-    off afterwards, zero incoming gradient in the VJP.  Returns one
-    ``[E, T, B, H]`` array per direction."""
+    One direction, already in scan order: ``proj [E, T, B, 3H]``, ``w_hh
+    [E, H, 3H]``, ``b_hh [E, 3H]``, ``h0 [E, B, H]`` (the kernel only ever
+    scans its grid forward).  Shape hygiene for the kernel's tiling happens
+    here, per device: rows pad to the sublane, experts and time to the
+    kernel's widest blocks.  The time pad sits at the END of scan order,
+    beyond every real output: sliced off afterwards, zero incoming gradient
+    in the VJP.  Returns ``[E, T, B, H]``."""
     from deeprest_tpu.ops import pallas_gru
 
-    e, t, b, _ = projs[0].shape
-    io_dtype = projs[0].dtype
+    e, t, b, _ = proj.shape
+    io_dtype = proj.dtype
     b_pad = pallas_gru.pad_batch(b, io_dtype) - b
     e_pad = pallas_gru.pad_experts(e) - e
     t_pad = pallas_gru.pad_time(t) - t
-
-    def stack(arrays, pads):
-        return jnp.concatenate([jnp.pad(a, pads) for a in arrays], axis=0)
-
-    proj = stack(projs, ((0, e_pad), (0, t_pad), (0, b_pad), (0, 0)))
+    proj = jnp.pad(proj, ((0, e_pad), (0, t_pad), (0, b_pad), (0, 0)))
     # W_hh ships in the dot dtype: for bf16 models an f32 copy would
     # double its HBM/VMEM footprint only to be downcast inside every grid
     # program.  b_hh stays f32 (it is ADDED to the f32 accumulator).
-    w_hh = stack([w.astype(io_dtype) for w in w_hhs],
-                 ((0, e_pad), (0, 0), (0, 0)))
-    b_hh = stack([v.astype(jnp.float32) for v in b_hhs],
-                 ((0, e_pad), (0, 0)))
-    h0 = stack([v.astype(jnp.float32) for v in h0s],
-               ((0, e_pad), (0, b_pad), (0, 0)))
+    w_hh = jnp.pad(w_hh.astype(io_dtype), ((0, e_pad), (0, 0), (0, 0)))
+    b_hh = jnp.pad(b_hh.astype(jnp.float32), ((0, e_pad), (0, 0)))
+    h0 = jnp.pad(h0.astype(jnp.float32), ((0, e_pad), (0, b_pad), (0, 0)))
     h_all = pallas_gru.gru_recurrence(proj, w_hh, b_hh, h0, interpret)
-    half = e + e_pad
-    return tuple(h_all[d * half:d * half + e, :t, :b]
-                 for d in range(len(projs)))
+    return h_all[:e, :t, :b]
 
 
-def _recurrence(projs, w_hhs, b_hhs, h0s, interpret: bool, mesh):
+def _recurrence(proj, w_hh, b_hh, h0, interpret: bool, mesh):
     """:func:`_recur_local`, under ``shard_map`` when ``mesh`` has more
     than one device.
 
@@ -204,11 +191,11 @@ def _recurrence(projs, w_hhs, b_hhs, h0s, interpret: bool, mesh):
     operand is replicated over it.  ``shard_map``'s transpose sums the
     weight cotangents over ``data``."""
     if mesh is None or mesh.size == 1:
-        return _recur_local(projs, w_hhs, b_hhs, h0s, interpret)
+        return _recur_local(proj, w_hh, b_hh, h0, interpret)
     from jax.sharding import PartitionSpec as P
 
     n_data, n_expert = mesh.shape["data"], mesh.shape["expert"]
-    e, _, b, _ = projs[0].shape
+    e, _, b, _ = proj.shape
     if e % n_expert:
         raise ValueError(f"{e} experts do not divide over the mesh's "
                          f"expert axis of {n_expert}")
@@ -216,18 +203,40 @@ def _recurrence(projs, w_hhs, b_hhs, h0s, interpret: bool, mesh):
     # to it; every row is independent, so the pad rows are sliced off.
     b_pad = -b % n_data
     if b_pad:
-        projs = tuple(jnp.pad(a, ((0, 0), (0, 0), (0, b_pad), (0, 0)))
-                      for a in projs)
-        h0s = tuple(jnp.pad(a, ((0, 0), (0, b_pad), (0, 0))) for a in h0s)
-    n = len(projs)
+        proj = jnp.pad(proj, ((0, 0), (0, 0), (0, b_pad), (0, 0)))
+        h0 = jnp.pad(h0, ((0, 0), (0, b_pad), (0, 0)))
     rows = P("expert", None, "data", None)
-    outs = jax.shard_map(
+    out = jax.shard_map(
         lambda *a: _recur_local(*a, interpret), mesh=mesh,
-        in_specs=((rows,) * n, (P("expert", None, None),) * n,
-                  (P("expert", None),) * n, (P("expert", "data", None),) * n),
-        out_specs=(rows,) * n, check_vma=False,
-    )(tuple(projs), tuple(w_hhs), tuple(b_hhs), tuple(h0s))
-    return tuple(o[:, :, :b] for o in outs) if b_pad else outs
+        in_specs=(rows, P("expert", None, None), P("expert", None),
+                  P("expert", "data", None)),
+        out_specs=rows, check_vma=False,
+    )(proj, w_hh, b_hh, h0)
+    return out[:, :, :b] if b_pad else out
+
+
+def _hidden_scan_order(
+    params: GRUParams,
+    x: jax.Array,
+    h0: jax.Array,
+    reverse: bool,
+    interpret: bool,
+    mesh,
+) -> jax.Array:
+    """One direction through the kernels: hoisted input projection (one
+    MXU einsum), then the pallas recurrence of ops/pallas_gru.py (see that
+    module for the kernel design).  Returns the hidden states in the
+    kernels' order ``[E, T, B, H]``, time-aligned with ``x``: a reverse
+    direction's projection is flipped going in and its states coming out."""
+    proj = _project(params, x)
+    # the kernels carry their own names inside this scope; what is left
+    # under `recurrence` is the layout work around them
+    with jax.named_scope(scopes.RECURRENCE):
+        if reverse:
+            proj = jnp.flip(proj, axis=1)
+        h_all = _recurrence(proj, params.w_hh, params.b_hh, h0, interpret,
+                            mesh)
+        return jnp.flip(h_all, axis=1) if reverse else h_all
 
 
 def _gru_pallas(
@@ -238,19 +247,10 @@ def _gru_pallas(
     interpret: bool,
     mesh=None,
 ) -> jax.Array:
-    """Fused-kernel path: hoisted input projection (one MXU einsum), then the
-    pallas recurrence of ops/pallas_gru.py. Output matches the scan path's
-    layout/time-alignment; see that module for the kernel design."""
-    proj = _project(params, x)
-    # the kernels carry their own names inside this scope; what is left
-    # under `recurrence` is the layout work around them
+    """Fused-kernel path of one direction.  Output matches the scan
+    path's layout and time-alignment."""
+    h_all = _hidden_scan_order(params, x, h0, reverse, interpret, mesh)
     with jax.named_scope(scopes.RECURRENCE):
-        if reverse:
-            proj = jnp.flip(proj, axis=1)
-        (h_all,) = _recurrence((proj,), (params.w_hh,), (params.b_hh,),
-                               (h0,), interpret, mesh)
-        if reverse:
-            h_all = jnp.flip(h_all, axis=1)
         return jnp.moveaxis(h_all, 1, 2).astype(x.dtype)  # [E,B,T,H]
 
 
@@ -319,25 +319,23 @@ def _bidir_pallas(
     interpret: bool,
     mesh=None,
 ) -> jax.Array:
-    """Fused bidirectional kernel path: BOTH directions ride one
-    ``gru_recurrence`` invocation, stacked along the expert axis with the
-    backward direction's projections pre-flipped in time.
+    """Fused-kernel path of both directions: one kernel call a direction,
+    exactly those of two :func:`gru` calls, and the two hidden states
+    joined on the last axis while still in the kernels' ``[E, T, B, H]``
+    order, BEFORE the one transpose to ``[E, B, T, 2H]``.
 
-    The recurrence kernel is direction-agnostic — it only ever scans its
-    grid forward — so direction fusion is pure plumbing (see
-    :func:`_recur_local`).  This halves the pallas invocations per layer
-    (2→1 forward, 2→1 in the VJP) and doubles the expert-block count each
-    invocation pipelines over.
-    """
+    The values are those of transposing each direction and joining
+    afterwards, bit for bit; the order matters to the compiler.  Handed two
+    transposed halves it drew the dropout mask again in every fusion that
+    reads the joined array (six a train step, for two here), and on the
+    chip the operations outside the kernels took 7.54 ms a step for 5.86
+    at E=40 and 48.1 for 40.1 at E=200 (PERF.md section 6, PR 29)."""
     e, b, h = fwd.w_ih.shape[0], x.shape[-3], fwd.hidden_size
-    proj_f, proj_b = _project(fwd, x), _project(bwd, x)
+    h0 = jnp.zeros((e, b, h), jnp.float32)
+    out_f = _hidden_scan_order(fwd, x, h0, False, interpret, mesh)
+    out_b = _hidden_scan_order(bwd, x, h0, True, interpret, mesh)
     with jax.named_scope(scopes.RECURRENCE):
-        proj_b = jnp.flip(proj_b, axis=1)
-        h0 = jnp.zeros((e, b, h), jnp.float32)
-        out_f, out_b = _recurrence(
-            (proj_f, proj_b), (fwd.w_hh, bwd.w_hh), (fwd.b_hh, bwd.b_hh),
-            (h0, h0), interpret, mesh)
-        out = jnp.concatenate([out_f, jnp.flip(out_b, axis=1)], axis=-1)
+        out = jnp.concatenate([out_f, out_b], axis=-1)
         return jnp.moveaxis(out, 1, 2).astype(x.dtype)      # [E,B,T,2H]
 
 
@@ -356,15 +354,15 @@ def bidirectional_gru(
     """
     fwd, bwd = resolve_weights(fwd), resolve_weights(bwd)
     resolved = _resolve_backend(backend)
-    if resolved != "scan" and _BIDIR_FUSED:
+    if resolved != "scan":
         from deeprest_tpu.ops import pallas_gru
 
         if pallas_gru.supported(x.shape[-2], fwd.hidden_size):
             return _bidir_pallas(fwd, bwd, x,
                                  interpret=resolved == "pallas_interpret",
                                  mesh=mesh)
-    # Two single-direction calls — on the pallas backends each direction
-    # is its own kernel invocation.
+    # The scan backend, and an H the kernels do not take (``gru`` warns
+    # where pallas was asked for by name): two single-direction calls.
     out_f = gru(fwd, x, reverse=False, unroll=unroll, backend=backend,
                 mesh=mesh)
     out_b = gru(bwd, x, reverse=True, unroll=unroll, backend=backend,

@@ -102,12 +102,13 @@ def _param_shapes(cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 
 
-def _gru_grad(mesh, dtype, rows):
+def _gru_grad(mesh, dtype, rows, experts=E):
     """Lowered ``jax.grad`` of a bidirectional pallas GRU at the flagship
     shape, ``rows`` windows."""
     def shapes():
         keys = jax.random.split(jax.random.PRNGKey(0))
-        return [gru_ops.init_gru_params(k, E, F, H, dtype) for k in keys]
+        return [gru_ops.init_gru_params(k, experts, F, H, dtype)
+                for k in keys]
 
     fwd, bwd = (_on(mesh, p, P("expert")) for p in jax.eval_shape(shapes))
     x = _on(mesh, jax.ShapeDtypeStruct((rows, W, F), dtype), P("data"))
@@ -127,10 +128,16 @@ def test_kernel_fwd_bwd_flagship(one_chip, dtype):
     assert _kernel_calls(compiled) == 4        # 2 directions x (fwd, bwd)
 
 
-def test_kernel_fused_bidirectional_compiles(one_chip, monkeypatch):
-    monkeypatch.setattr(gru_ops, "_BIDIR_FUSED", True)
-    compiled = _gru_grad(one_chip, jnp.bfloat16, B).compile()
-    assert _kernel_calls(compiled) == 2        # both directions per call
+@pytest.mark.parametrize("experts", [E, 200])     # both benchmark cells
+def test_bidirectional_joins_before_the_one_transpose(one_chip, experts):
+    """One kernel call a direction and pass, and the two directions joined
+    in the kernels' ``[E,T,B,H]`` order: the compiler is handed no
+    direction transposed alone, and makes none (ops/gru._bidir_pallas)."""
+    compiled = _gru_grad(one_chip, jnp.bfloat16, B, experts).compile()
+    assert _kernel_calls(compiled) == 4
+    text = compiled.as_text()
+    assert f"[{experts},{W},{B},{H}]" in text          # a kernel's output
+    assert f"[{experts},{B},{W},{H}]" not in text
 
 
 def test_kernel_fat_rows_g4(one_chip):
@@ -292,8 +299,11 @@ def test_train_step_fits_the_chip(one_chip, feature_dim, sparse, accum):
     compiled = _train_step_lowered(one_chip, feature_dim, sparse,
                                    accum).compile()
     assert _kernel_calls(compiled) == 4 * accum
+    text = compiled.as_text()
+    # `dropout`, `mixing` and `heads` get the two directions as ONE array
+    assert f"[{E},{B},{W},{2 * H}]" in text
+    assert f"[{E},{B},{W},{H}]" not in text
     if sparse == "compact":
-        text = compiled.as_text()
         assert f"[{B},{W},{U_LIVE}]" in text
         assert f"[{B},{W},{F_10K}]" not in text
         assert f"bf16[{E},{F_10K},{3 * H}]" not in text

@@ -284,40 +284,6 @@ def test_accum_smoke_fit(bundle):
 
 
 # ---------------------------------------------------------------------------
-# bidirectional: two calls by default, the fused path stays covered
-# ---------------------------------------------------------------------------
-
-
-def test_bidir_default_unfused_and_fused_parity(monkeypatch):
-    """The DEFAULT pallas path is two calls a layer.  The fused form stays
-    behind the constant _BIDIR_FUSED (PERF.md section 6, PR 28) and must
-    keep matching the scan spec."""
-    import importlib
-
-    # deeprest_tpu.ops re-exports the gru FUNCTION, shadowing the module
-    # on attribute access — importlib reaches the module unambiguously.
-    gru_mod = importlib.import_module("deeprest_tpu.ops.gru")
-
-    assert gru_mod._BIDIR_FUSED is False
-
-    rng = np.random.default_rng(3)
-    fwd = gru_mod.init_gru_params(jax.random.PRNGKey(1), 3, 8, 128)
-    bwd = gru_mod.init_gru_params(jax.random.PRNGKey(2), 3, 8, 128)
-    x = jnp.asarray(rng.standard_normal((4, 9, 8)), jnp.float32)
-    ref = np.asarray(gru_mod.bidirectional_gru(fwd, bwd, x, backend="scan"))
-
-    unfused = np.asarray(gru_mod.bidirectional_gru(
-        fwd, bwd, x, backend="pallas_interpret"))
-    monkeypatch.setattr(gru_mod, "_BIDIR_FUSED", True)
-    fused = np.asarray(gru_mod.bidirectional_gru(
-        fwd, bwd, x, backend="pallas_interpret"))
-    np.testing.assert_allclose(unfused, ref, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(fused, ref, rtol=1e-5, atol=1e-5)
-    # direction fusion is pure plumbing: both kernel routes agree exactly
-    np.testing.assert_array_equal(unfused, fused)
-
-
-# ---------------------------------------------------------------------------
 # serve-side page coalescing
 # ---------------------------------------------------------------------------
 
