@@ -38,9 +38,10 @@ class PhaseClock:
         self.units_total = units_total
 
     @contextlib.contextmanager
-    def unit(self):
-        """One unit of work.  Yields ``phase(name)``, the context manager
-        for its phases.  A unit that raises publishes nothing."""
+    def unit(self, tags: dict | None = None):
+        """One unit of work, its span carrying ``tags``.  Yields
+        ``phase(name)``, the context manager for its phases.  A unit that
+        raises publishes nothing."""
         seconds = dict.fromkeys(self.phases, 0.0)
         nested = [0.0]      # seconds of the phases inside the open one
 
@@ -59,7 +60,7 @@ class PhaseClock:
                 seconds[name] += elapsed - nested[0]
                 nested[0] = outer + elapsed
 
-        with RECORDER.span(self.name, self.component):
+        with RECORDER.span(self.name, self.component, tags):
             parent = current_context()
             yield phase
         for name, value in seconds.items():

@@ -18,6 +18,15 @@ things the program owns into one table of layers:
   which XLA takes from one of the operations it fused; what else it holds
   (:func:`fused_scopes`) is listed beside the row, because XLA does fuse
   Adam's update of a weight into the matmul that makes its gradient.
+- **Collectives.**  The partitioner puts the all-reduces of a mesh in and
+  gives them no scope of their own (they carry the ``op_name`` of whatever
+  they reduce), so they are found by instruction kind: one row
+  :data:`COLLECTIVE`.  Like every row it holds self time, and the device
+  runs one operation at a time, so it is the part of the collectives'
+  time in which the chip ran nothing else (what an asynchronous pair
+  hides between its ``-start`` and its ``-done`` lies in the rows of what
+  ran then).  :func:`collective_bytes` reads what they move from the
+  program's text.
 - **The program's spans.**  An enabled span (obs/spans.py) enters a
   ``TraceAnnotation`` ``<component>/<name>``, so it lies on the trace's
   clock; each idle gap of the device goes to the innermost program span
@@ -47,6 +56,11 @@ import threading
 import time
 
 OTHER = "other"
+COLLECTIVE = "collective"
+# the instruction kinds that move data between chips; an asynchronous one
+# is a `-start` and a `-done` instruction of the kind
+COLLECTIVE_KINDS = ("all-reduce", "reduce-scatter", "all-gather",
+                    "collective-permute", "all-to-all")
 # every component the program records spans under starts with this
 PROGRAM_SPAN_PREFIXES = ("deeprest",)
 UNATTRIBUTED = "unattributed"
@@ -120,6 +134,20 @@ _TO_APPLY = re.compile(r"\bto_apply=%?([\w.\-]+)")
 # never a device event of their own
 _NO_EVENT = frozenset({"parameter", "constant", "get-tuple-element", "tuple",
                        "bitcast"})
+_COLLECTIVE = re.compile(
+    rf"^({'|'.join(COLLECTIVE_KINDS)})(-start|-done)?(?:\.\d+)?$")
+_CALLED = re.compile(
+    r"\b(?:body|condition|to_apply|calls|true_computation|false_computation)"
+    r"=%?([\w.\-]+)|\bbranch_computations=\{([^}]*)\}")
+_ARRAY = re.compile(r"\b(?:pred|[a-z]+?(\d+)\w*)\[([\d,]*)\]")
+
+
+def collective_kind(name: str) -> tuple[str, str] | None:
+    """``all-reduce-start.3`` (an opcode, or an instruction's name as the
+    trace gives it) -> ``("all-reduce", "-start")``; None for what is no
+    collective."""
+    m = _COLLECTIVE.match(name.lstrip("%"))
+    return (m[1], m[2] or "") if m else None
 
 
 def _scope_of(op_name: str, names) -> tuple[str, str]:
@@ -135,27 +163,39 @@ def _scope_of(op_name: str, names) -> tuple[str, str]:
     return known[-1], "bwd" if "transpose(" in op_name else "fwd"
 
 
-def _parse_hlo(hlo_text: str):
-    """{computation: [(instruction, opcode, op_name, called computation)]}
-    plus the set of computations that run inside another instruction (a
-    fusion's body, a reduce's combiner)."""
-    computations, inner, current = {}, set(), None
+def _instruction_lines(hlo_text: str):
+    """({computation: [(the match of its instruction, the line)]}, the
+    ENTRY computation's name) of an HLO module's text."""
+    computations, entry, current = {}, None, None
     for line in hlo_text.splitlines():
         if not line.startswith(" "):
             m = _COMPUTATION.match(line)
             current = computations.setdefault(m["name"], []) if m else None
+            if m and line.startswith("ENTRY"):
+                entry = m["name"]
             continue
         m = _INSTRUCTION.match(line)
-        if current is None or not m:
-            continue
-        op_name = _OP_NAME.search(line)
-        calls = _CALLS.search(line) if m["opcode"] == "fusion" else None
-        inner.update(_TO_APPLY.findall(line))
-        if calls:
-            inner.add(calls[1])
-        current.append((m["name"], m["opcode"],
-                        op_name[1] if op_name else "",
-                        calls[1] if calls else None))
+        if current is not None and m:
+            current.append((m, line))
+    return computations, entry
+
+
+def _parse_hlo(hlo_text: str):
+    """{computation: [(instruction, opcode, op_name, called computation)]}
+    plus the set of computations that run inside another instruction (a
+    fusion's body, a reduce's combiner)."""
+    computations, inner = {}, set()
+    for comp, lines in _instruction_lines(hlo_text)[0].items():
+        current = computations[comp] = []
+        for m, line in lines:
+            op_name = _OP_NAME.search(line)
+            calls = _CALLS.search(line) if m["opcode"] == "fusion" else None
+            inner.update(_TO_APPLY.findall(line))
+            if calls:
+                inner.add(calls[1])
+            current.append((m["name"], m["opcode"],
+                            op_name[1] if op_name else "",
+                            calls[1] if calls else None))
     return computations, inner
 
 
@@ -169,12 +209,14 @@ def module_name(hlo_text: str) -> str | None:
 def scope_table(hlo_text: str, names) -> dict[str, tuple[str, str]]:
     """``{instruction: (scope, pass)}`` for every instruction of an
     optimized HLO module's text (``jit(f).lower(..).compile().as_text()``)
-    that can be an event on the device: a fusion under its own label,
-    instructions under none of the scope and kernel ``names`` under
-    ``(OTHER, "-")``."""
+    that can be an event on the device: a fusion under its own label, a
+    collective (by its kind, whatever it reduces) under ``(COLLECTIVE,
+    "-")``, instructions under none of the scope and kernel ``names``
+    under ``(OTHER, "-")``."""
     computations, inner = _parse_hlo(hlo_text)
     names = frozenset(names)
-    return {name: _scope_of(op_name, names)
+    return {name: ((COLLECTIVE, "-") if collective_kind(opcode)
+                   else _scope_of(op_name, names))
             for comp, instructions in computations.items() if comp not in inner
             for name, opcode, op_name, _ in instructions
             if opcode not in _NO_EVENT}
@@ -207,6 +249,52 @@ def fused_scopes(hlo_text: str, names) -> dict[str, tuple[str, ...]]:
                 if extra:
                     out[name] = tuple(sorted(extra))
     return out
+
+
+def _array_bytes(type_text: str) -> int:
+    """Bytes of every array a result type names: ``(f32[40,3]{0,1},
+    bf16[40,512,3]{..})`` -> 480 + 122,880."""
+    total = 0
+    for bits, dims in _ARRAY.findall(type_text):
+        n = 1
+        for d in dims.split(","):
+            n *= int(d) if d else 1
+        total += n * max(int(bits or 8) // 8, 1)
+    return total
+
+
+def collective_bytes(hlo_text: str) -> dict[str, int]:
+    """``{kind: bytes}`` that one pass through an optimized HLO module
+    gets from its collectives: the result of every instruction of a
+    :data:`COLLECTIVE_KINDS` kind (of an asynchronous pair the ``-done``,
+    whose result is the collective's; its ``-start`` not) that the entry
+    computation reaches, the body of a loop taken once (for a superstep:
+    one train step) and of a conditional's branches the one that moves
+    most.  Kinds the program does not use are left out; a program for one
+    device gives ``{}``."""
+    bodies, entry = _instruction_lines(hlo_text)
+
+    def cost(comp: str) -> collections.Counter:
+        total = collections.Counter()
+        for m, line in bodies.get(comp, ()):
+            opcode = m["opcode"]
+            kind = collective_kind(opcode)
+            if kind and kind[1] != "-start":
+                head = line.split(" = ", 1)[1]
+                total[kind[0]] += _array_bytes(
+                    head[:head.index(f" {opcode}(")])
+            if opcode == "fusion":
+                continue
+            costs = [cost(c.lstrip("%"))
+                     for one, many in _CALLED.findall(line)
+                     for c in ([one] if one else re.split(r",\s*", many))]
+            if opcode == "conditional" and costs:
+                costs = [max(costs, key=lambda c: sum(c.values()))]
+            for c in costs:
+                total.update(c)
+        return total
+
+    return dict(cost(entry)) if entry else {}
 
 
 # -- the trace → the table -------------------------------------------------
@@ -278,11 +366,14 @@ def _covering_spans(gaps, spans):
 
 def _row_key(name: str, text: str, scopes) -> tuple[str, str]:
     """The row of the operation ``name`` (its event's ``text``): through
-    the map when it is in it; a kernel the map does not hold under the
-    name its ``pallas_call`` gave it (the instruction's, less XLA's
-    ``.N``); else ``(OTHER, "-")``."""
+    the map when it is in it; a collective the map does not hold by its
+    kind; a kernel the map does not hold under the name its
+    ``pallas_call`` gave it (the instruction's, less XLA's ``.N``); else
+    ``(OTHER, "-")``."""
     if scopes and name in scopes:
         return tuple(scopes[name])
+    if collective_kind(name):
+        return COLLECTIVE, "-"
     if _KERNEL_MARK in text:
         base, _, number = name.rpartition(".")
         return (base if number.isdigit() else name), "-"
@@ -378,7 +469,9 @@ def layer_table(trace_dir: str, scopes=None, fused=None, module=None,
       given), a kernel the map does not hold by its own name; per row
       seconds, share of busy, ms per step when ``steps`` is given, and the
       seconds spent in fusions that also hold another scope's operations
-      (``fused``: :func:`fused_scopes`).  :data:`OTHER` (pass ``-``) is
+      (``fused``: :func:`fused_scopes`).  :data:`COLLECTIVE` (pass ``-``)
+      holds the collectives, found by kind: the part of their time in
+      which the chip ran nothing else.  :data:`OTHER` (pass ``-``) is
       ONE row, never dropped: the operations under none of the map's
       names and those the map does not hold, so the rows sum to
       ``busy_s``;
@@ -418,7 +511,8 @@ def format_table(table: dict) -> str:
     return "\n".join(lines)
 
 
-__all__ = ["OTHER", "UNATTRIBUTED",
+__all__ = ["OTHER", "UNATTRIBUTED", "COLLECTIVE", "COLLECTIVE_KINDS",
+           "collective_kind", "collective_bytes",
            "PROGRAM_SPAN_PREFIXES", "ProfilerBusy", "capture",
            "trace_window", "scope_table", "fused_scopes", "module_name",
            "layer_table", "layer_table_of", "read_planes", "find_xplane",

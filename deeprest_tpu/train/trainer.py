@@ -46,7 +46,7 @@ from deeprest_tpu.parallel.elastic import (
     FaultInjector, RemeshExhaustedError, enumerate_healthy, is_device_loss,
 )
 from deeprest_tpu.parallel.mesh import (
-    NoValidMeshError, make_mesh, mesh_config_of, shrink_mesh_config,
+    AXES, NoValidMeshError, make_mesh, mesh_config_of, shrink_mesh_config,
 )
 from deeprest_tpu.parallel.sharding import shard_params, state_sharding
 from deeprest_tpu.train.data import DatasetBundle, eval_window_indices
@@ -228,6 +228,10 @@ class Trainer:
         """
         self.model = QuantileGRU(config=self.model_config, mesh=self.mesh)
         quantiles = self.model_config.quantiles
+        # the epoch span's tag, and whether this mesh's superstep has had
+        # its collectives read into deeprest_train_collective_bytes
+        self._mesh_tag = "x".join(str(self.mesh.shape[a]) for a in AXES)
+        self._collectives_published = False
 
         def pin_state(state: TrainState) -> TrainState:
             """Constrain every leaf to its CANONICAL named sharding, all
@@ -549,6 +553,11 @@ class Trainer:
             "rows of each layer-0 input weight that the last epoch's Adam "
             "steps on a staged sparse corpus wrote (updated), of F (total)",
             labelnames=("kind",))
+        self._m_collective_bytes = obs_metrics.REGISTRY.gauge(
+            "deeprest_train_collective_bytes",
+            "bytes a train step of the compiled superstep hands to its "
+            "collectives, by kind (set under a mesh of more than one device)",
+            labelnames=("op",))
         self._m_executables = obs_metrics.REGISTRY.gauge(
             "deeprest_train_jit_executables",
             "compiled executables across the trainer's jitted programs "
@@ -594,6 +603,21 @@ class Trainer:
         cache = self._jit_cache_size()
         if cache is not None:
             self._m_executables.set(cache)
+
+    def _publish_collective_bytes(self, state) -> None:
+        """``deeprest_train_collective_bytes``, once for each mesh of more
+        than one device: what a step of the superstep this epoch
+        dispatched hands to each kind of collective, read from the
+        compiled program's own text (the partitioner decides what is
+        reduced and in which type, so no sum over the gradient tree would
+        say it).  No second compile (see :meth:`_dispatched_program_text`):
+        the text of the executable the dispatch made, parsed once."""
+        from deeprest_tpu.obs import profiler
+
+        for op, n in profiler.collective_bytes(
+                self._dispatched_program_text(state)).items():
+            self._m_collective_bytes.set(n, op=op)
+        self._collectives_published = True
 
     def _publish_optimizer_rows(self, x_base, rowwise=None) -> None:
         """The epoch's ``deeprest_train_optimizer_rows``, for a staged
@@ -1093,9 +1117,10 @@ class Trainer:
         chunk) boundary — the chaos tests' preemption injection point.
 
         The epoch's host work is timed by phase (:data:`EPOCH_PHASES`,
-        obs/phases.py): one ``train.epoch`` span with a child per phase
-        when the recorder is on, the phase-seconds counters always."""
-        with self._epoch_clock.unit() as phase:
+        obs/phases.py): one ``train.epoch`` span, tagged with the mesh
+        ``DxExM``, with a child per phase when the recorder is on, the
+        phase-seconds counters always."""
+        with self._epoch_clock.unit({"mesh": self._mesh_tag}) as phase:
             return self._train_epoch(state, bundle, epoch_rng, staged,
                                      skip_steps, on_step, phase)
 
@@ -1323,6 +1348,8 @@ class Trainer:
         # what this epoch dispatched, for profile_epoch to lower again
         self._dispatched = (superstep,
                             (x_base, y_base, starts_d, weights_d, 0))
+        if self.mesh.size > 1 and not self._collectives_published:
+            self._publish_collective_bytes(state)
         with phase("device_wait"):
             jax.block_until_ready(state.params)
         if measuring:
@@ -1341,11 +1368,13 @@ class Trainer:
 
     def _dispatched_program_text(self, state: TrainState) -> str:
         """The optimized HLO of the program the last epoch dispatched (the
-        epoch drivers record it and the arguments beside ``state``):
-        lowered on those very arguments, so it is the executable that ran,
-        and with the persistent cache on it costs a trace and a cache
-        read.  Called by :meth:`profile_epoch` only; the normal train path
-        never lowers twice."""
+        epoch drivers record it and the arguments beside ``state``).
+        ``lower`` on those very arguments finds the trace and the lowering
+        the dispatch left in the jit's caches, and ``compile`` the
+        executable that ran: nothing is compiled again
+        (tests/test_mesh_dp4.py counts the backend's compiles).  Called by
+        :meth:`profile_epoch`, and once for each mesh of more than one
+        device by :meth:`_publish_collective_bytes`."""
         program, args = self._dispatched
         return program.lower(state, *args).compile().as_text()
 

@@ -236,11 +236,13 @@ def test_fused_serve_program_10k_sparse(one_chip):
 
 
 def _train_step_lowered(mesh, feature_dim, sparse, accum=1,
-                        superstep=False):
+                        superstep=False, batch=B):
     """``superstep``: the G=1 superstep (a 3 x 50 plan, as the 10k cell's
-    126-step epoch gives) instead of the per-step program."""
+    126-step epoch gives; under a mesh of several chips the 1 x 32 plan of
+    `tenk-train-dp4`'s 32-step epoch, split over ``data``) instead of the
+    per-step program."""
     cfg = Config(model=_model_config(feature_dim=feature_dim),
-                 train=TrainConfig(batch_size=B, window_size=W,
+                 train=TrainConfig(batch_size=batch, window_size=W,
                                    grad_accum_windows=accum))
     trainer = Trainer(cfg, feature_dim, [f"m{i}" for i in range(E)],
                       mesh=mesh)
@@ -275,11 +277,13 @@ def _train_step_lowered(mesh, feature_dim, sparse, accum=1,
     if accum == 1 and not superstep:
         args = (base, y_base, sds((B,), jnp.int32), sds((B,), jnp.float32))
         return trainer._train_step_indexed.lower(state_sds, *_on(mesh, args))
-    plan = (3, 50, B) if superstep else (2, accum, B)
-    args = (base, y_base, sds(plan, jnp.int32), sds(plan, jnp.float32),
-            sds((), jnp.int32))
+    plan = ((2, accum, batch) if not superstep
+            else (3, 50, batch) if mesh.size == 1 else (1, 32, batch))
+    plans = _on(mesh, (sds(plan, jnp.int32), sds(plan, jnp.float32)),
+                P(None, None, "data"))
     program = trainer._superstep if superstep else trainer._accum_superstep
-    return program.lower(state_sds, *_on(mesh, args))
+    return program.lower(state_sds, *_on(mesh, (base, y_base)), *plans,
+                         *_on(mesh, (sds((), jnp.int32),)))
 
 
 @pytest.mark.parametrize("feature_dim,sparse,accum", [
@@ -342,3 +346,32 @@ def test_compact_superstep_updates_the_leaves_in_place(one_chip):
     assert copies == 12
     assert mem.temp_size_in_bytes < 4.0e9, mem
     assert need < 8.92e9, mem
+
+
+@pytest.mark.slow       # by hand, as the one-chip compact superstep above
+def test_compact_superstep_under_data4_reduces_the_compact_gradients(topo):
+    """`tenk-train-dp4`'s superstep (ISSUE 31: global batch 128 over a mesh
+    data=4, a 1 x 32 plan) for a described v5e:2x2.  The kernels stay
+    whole under ``shard_map``; a chip needs what one chip needs (the state
+    is replicated, the windows are 32 a chip); and what the partitioner
+    reduces each step is the w_ih gradients at the table's rows, w_hh and
+    the heads as the bfloat16 matmuls make them, 23.8 MB, NOT the 210 MB of
+    the mask weights' float32 gradient, which every chip derives from the
+    reduced w_ih gradient (PERF.md section 6, PR 31)."""
+    from deeprest_tpu.obs import profiler
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(4, 1, 1), AXES)
+    compiled = _train_step_lowered(mesh, F_10K, "compact", superstep=True,
+                                   batch=4 * B).compile()
+    text = compiled.as_text()
+    assert _kernel_calls(compiled) == 4
+    assert "all-gather" not in text
+    moved = profiler.collective_bytes(text)
+    print(f"compact 10k superstep under data=4 for a described v5e:2x2: "
+          f"collectives a step {moved}")
+    assert set(moved) == {"all-reduce"}
+    assert 20e6 < moved["all-reduce"] < 30e6, moved
+    mem = compiled.memory_analysis()
+    need = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert mem.temp_size_in_bytes < 4.0e9 and need < 8.92e9, mem

@@ -1,0 +1,444 @@
+"""`endpoints-10k-dp4` (ISSUE 31): the estimator trained data-parallel over a
+mesh ``data=4``, held on the CPU's virtual devices to the plain one-device
+reference on the GLOBAL batch and to the same program at ``data=1``; what
+the program records of its collectives; the benchmark's three readers of
+them on a recorded slice of a four-chip trace; and the cell's files.
+
+On the chips the benchmark's cell `tenk-train-dp4` makes the comparison at
+the configuration's own widths in bfloat16 (chipbench/limits/); here it is
+float32 at toy widths.  No number of this file is a device number.
+"""
+
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench import trace_reduce
+from chipbench.generators import corpus
+from chipbench.readers import collectives
+from chipbench.reference import qrnn_ref as ref
+from chipbench.runners import train_mesh as runner
+from deeprest_tpu import obs
+from deeprest_tpu.config import (
+    Config, FeaturizeConfig, MeshConfig, ModelConfig, TrainConfig,
+)
+from deeprest_tpu.data.featurize import CallPathSpace, FeaturizedData
+from deeprest_tpu.obs import profiler
+from deeprest_tpu.obs.metrics import REGISTRY
+from deeprest_tpu.parallel.distributed import stage_plan
+from deeprest_tpu.train import Trainer, prepare_dataset
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SLICE = os.path.join(REPO, "chipbench", "tests", "data",
+                     "recorded_v5e_dp4_step.json")
+RESOURCES = ["cpu", "memory", "write-iops", "write-tp", "usage"]
+QUANTILES = (0.05, 0.5, 0.95)
+SEED = 3_000_000_031           # as large as the driver's
+
+# one component x 5 resources over 512 hashed call paths of which 16 are
+# live (so the feed takes the compact form), a global batch of 16
+E, F, H, W, B = 5, 512, 16, 8, 16
+
+# Program and reference both compute in float32 here, the reference at
+# `highest`; what is left is the order of the sums.  Beside the one-device
+# comparison's causes (the batched einsum over experts against a map over
+# one at a time, the hoisted projection, XLA's fusions) the mesh adds one:
+# each chip sums its quarter of the batch and the all-reduce adds the four
+# partial sums.  Read at this size: 1.9e-7, 8.4e-7 and 8.8e-8 under
+# `data=4` (9.4e-8, 8.4e-7, 9.2e-8 under `data=1`; the two against each
+# other 9.4e-8, 1.6e-7, 9.2e-8).  The limits leave about ten times that for
+# another BLAS or thread count and no more: the last chip's rows left out
+# of the mean read 4.2e-2, 1.3e-2 and 3.6e-2, the reference with bfloat16
+# operands 5.5e-5, 5.1e-4 and 1.9e-4 (27, 51 and 97 times the limits).
+TOLERANCE = {"loss_rel_gap": 2e-6, "grad_norm_gap": 1e-5,
+             "delta_norm_gap": 2e-6}
+
+
+def _corpus():
+    model = {"feature_dim": F, "num_metrics": E}
+    raw = corpus.generate({"buckets": 400, "hot_paths": 16, "nnz_lo": 2,
+                           "nnz_hi": 6, "day": 100, "resources": RESOURCES},
+                          SEED, model)
+    space = CallPathSpace(config=FeaturizeConfig(
+        hash_features=True, capacity=F)).freeze()
+    data = FeaturizedData(
+        traffic=raw["traffic"], resources=raw["resources"],
+        invocations={"general": np.ones(len(raw["traffic"]), np.float32)},
+        space=space)
+    return raw, data
+
+
+def _three_steps(data_axis: int, raw, data, leave_out_a_chip=False):
+    """The runner's phases 2 and 3 at the small size under ``data``:
+    (the numbers `correct` compares, the trainer, its state, the bundle,
+    what was staged)."""
+    tcfg = TrainConfig(batch_size=B, window_size=W, train_split=0.4,
+                       seed=SEED % (2 ** 31 - 1), sparse_feed=True,
+                       sparse_nnz_cap=8, log_every_steps=0)
+    config = Config(
+        model=ModelConfig(feature_dim=F, num_metrics=E, hidden_size=H,
+                          quantiles=QUANTILES, dropout_rate=0.5,
+                          compute_dtype="float32"),
+        train=tcfg, mesh=MeshConfig(data=data_axis))
+    bundle = prepare_dataset(data, tcfg)
+    starts = runner.check_starts(raw, tcfg, SEED, bundle)
+    trainer = Trainer(config, bundle.feature_dim, bundle.metric_names)
+    assert trainer.mesh.shape["data"] == data_axis
+    state = trainer.init_state(trainer.sample_input(bundle))
+    key = jax.random.PRNGKey(tcfg.seed)
+    seeded = ref.init_params(key, E, F, H, len(QUANTILES))
+    state = state.replace(params={
+        k: jax.device_put(seeded[k], state.params[k].sharding)
+        for k in state.params})
+    staged = trainer.stage_dataset(bundle)
+    assert staged[0].live is not None                # the compact form
+    num_steps = -(-bundle.num_train_windows // B)
+    plans = runner.check_plans(trainer, starts, num_steps)
+    if leave_out_a_chip:                             # the last chip's rows
+        for _, weights in plans:
+            weights[..., -B // data_axis:] = 0.0
+    plans = [stage_plan(trainer.mesh, *plan) for plan in plans]
+    state, losses0 = trainer._superstep(state, *staged, *plans[0], 0)
+    grad_norm = {k: float(jnp.sqrt(jnp.sum(jnp.square(v))))
+                 / (1 - ref.ADAM["b1"])
+                 for k, v in state.opt_state[0].mu.items()}
+    state, losses1 = trainer._superstep(state, *staged, *plans[1], 0)
+    assert int(state.step) == runner.STEPS_CHECKED
+    start = ref.init_params(key, E, F, H, len(QUANTILES))
+    delta = {k: float(v) for k, v in ref.leaf_norms(
+        {k: state.params[k] - start[k] for k in start}).items()}
+    program = {"losses": [float(losses0[0]), float(losses1[0]),
+                          float(losses1[1])],
+               "grad_norm": grad_norm, "delta_norm": delta}
+    return program, trainer, state, bundle, staged, (tcfg, starts, key)
+
+
+@pytest.fixture(scope="module")
+def runs():
+    raw, data = _corpus()
+    out = {d: _three_steps(d, raw, data) for d in (4, 1)}
+    tcfg, starts, key = out[4][5]
+    out["reference"] = ref.train_three_steps(
+        ref.init_params(key, E, F, H, len(QUANTILES)),
+        runner.check_batches(raw, tcfg, starts), tcfg.seed, QUANTILES, 0.5,
+        "f32")
+    out["corpus"] = raw, data
+    return out
+
+
+# -- (a) data=4 against the plain reference on the global batch -------------
+
+
+@pytest.mark.parametrize("number", sorted(TOLERANCE))
+def test_data_parallel_superstep_against_the_reference(runs, number):
+    gaps = runner.compare(runs[4][0], runs["reference"])
+    assert gaps[number] <= TOLERANCE[number], (gaps, runs[4][0])
+
+
+# -- (b) data=4 against data=1 ----------------------------------------------
+
+
+@pytest.mark.parametrize("number", sorted(TOLERANCE))
+def test_data_parallel_superstep_against_one_device(runs, number):
+    """The same three steps whatever the mesh: the dropout mask is the
+    same function of the key and the logical ``[E, B, T, 2H]`` shape, and
+    every chip's rows are in the mean."""
+    gaps = runner.compare(runs[4][0], runs[1][0])
+    assert gaps[number] <= TOLERANCE[number], gaps
+
+
+def test_a_chips_rows_left_out_of_the_mean_is_seen(runs):
+    raw, data = runs["corpus"]
+    short = _three_steps(4, raw, data, leave_out_a_chip=True)[0]
+    gaps = runner.compare(short, runs["reference"])
+    assert gaps["loss_rel_gap"] > 1000 * TOLERANCE["loss_rel_gap"], gaps
+
+
+def test_state_is_the_same_on_every_chip(runs):
+    state = runs[4][2]
+    for leaf in jax.tree.leaves(state.params):
+        shards = [np.asarray(s.data) for s in leaf.addressable_shards]
+        assert len(shards) == 4
+        assert all(np.array_equal(shards[0], s) for s in shards[1:])
+
+
+# -- (c) what the program records under a mesh ------------------------------
+
+
+def _gradient_bytes(hlo_text: str) -> dict:
+    """Bytes by kind of the collectives in ``hlo_text``, counted here from
+    the instructions' own result types."""
+    sizes = {"f32": 4, "u32": 4, "s32": 4, "pred": 1, "bf16": 2}
+    out = {}
+    for line in hlo_text.splitlines():
+        m = profiler._INSTRUCTION.match(line)
+        kind = m and profiler.collective_kind(m["opcode"])
+        if not kind or kind[1] == "-start":
+            continue
+        result = line.split(" = ", 1)[1].split(f" {m['opcode']}(")[0]
+        n = 0
+        for dtype, dims in re.findall(
+                r"\b(f32|u32|s32|pred|bf16)\[([\d,]*)\]", result):
+            n += sizes[dtype] * int(np.prod(
+                [int(d) for d in dims.split(",") if d] or [1]))
+        out[kind[0]] = out.get(kind[0], 0) + n
+    return out
+
+
+def test_collective_bytes_gauge_and_the_one_device_gauges(runs):
+    _, trainer, state, bundle, staged, _ = runs[4]
+    gauge = REGISTRY.get("deeprest_train_collective_bytes")
+    gauge._series.clear()
+    was = obs.RECORDER.enabled
+    obs.RECORDER.enabled = True
+    obs.RECORDER.clear()
+    try:
+        state, _ = trainer.train_epoch(state, bundle,
+                                       np.random.default_rng(0),
+                                       staged=staged)
+    finally:
+        obs.RECORDER.enabled = was
+    runs[4] = (runs[4][0], trainer, state, bundle, staged, runs[4][5])
+    text = trainer._dispatched_program_text(state)
+    read = {k[0]: v for k, v in gauge.series().items()}
+    assert read == _gradient_bytes(text) and read["all-reduce"] > 0
+    # what is reduced is the gradient: the w_ih leaves at the table's rows
+    # (not F), and NOT the mask weights' [E, H, F], which every chip
+    # derives from the reduced w_ih gradient
+    width = staged[0].width
+    grads = 4 * (2 * E * width * 3 * H + 2 * E * H * 3 * H + 4 * E * 3 * H
+                 + E * 4 * H * len(QUANTILES) + E * len(QUANTILES))
+    assert grads <= read["all-reduce"] < grads + 4 * E * H * F
+    # the compact feed and the row-wise Adam hold under the `data` axis
+    cols = REGISTRY.get("deeprest_train_projection_columns")
+    rows = REGISTRY.get("deeprest_train_optimizer_rows")
+    assert cols.value(kind="contracted") == width <= F / 4
+    assert cols.value(kind="total") == F
+    assert rows.value(kind="updated") == width and rows.value(kind="total") == F
+    # the epoch's span says which mesh it ran on
+    spans = [s for s in obs.RECORDER.snapshot() if s.name == "train.epoch"]
+    assert spans and spans[-1].tags["mesh"] == "4x1x1"
+    # one device: no collective, and the gauge is not touched
+    one = runs[1]
+    gauge._series.clear()
+    one[1].train_epoch(one[2], one[3], np.random.default_rng(0),
+                       staged=one[4])
+    assert gauge.series() == {}
+    assert profiler.collective_bytes(
+        one[1]._dispatched_program_text(one[2])) == {}
+
+
+def test_reading_the_collectives_compiles_nothing(runs):
+    """The gauge is read from the executable the dispatch made: lowered on
+    the dispatch's own arguments, the program is found in the jit's cache,
+    compiled (so a mesh costs a training run no second compile)."""
+    import jax._src.monitoring as monitoring
+
+    _, trainer, state, bundle, staged, rest = runs[4]
+    state, _ = trainer.train_epoch(state, bundle, np.random.default_rng(1),
+                                   staged=staged)
+    runs[4] = (runs[4][0], trainer, state, bundle, staged, rest)
+    compiles = []
+
+    def listen(event, _secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    monitoring.register_event_duration_secs_listener(listen)
+    try:
+        jax.jit(lambda x: x + 1)(np.float32(len(runs)))   # the listener hears
+        heard = len(compiles)
+        trainer._collectives_published = False
+        trainer._publish_collective_bytes(state)
+    finally:
+        monitoring.unregister_event_duration_listener(listen)
+    assert heard and len(compiles) == heard
+    assert trainer._collectives_published
+
+
+def test_collective_row_of_the_scope_table(runs):
+    _, trainer, state, *_ = runs[4]
+    text = trainer._dispatched_program_text(state)
+    table = profiler.scope_table(text, ())
+    found = [k for k, v in table.items() if v == (profiler.COLLECTIVE, "-")]
+    assert found and all(profiler.collective_kind(k) for k in found)
+
+
+@pytest.mark.parametrize("text, expected", [
+    ("  %ar = (f32[40,3]{0,1:T(4,128)S(1)}, bf16[40,512,3]{1,2,0}) "
+     "all-reduce(%a, %b), channel_id=7", {"all-reduce": 480 + 122880}),
+    ("  %ag = (f32[4,8]{1,0}, f32[16,8]{1,0}) all-gather-start(%a), "
+     "dimensions={0}", {}),
+    ("  %d = f32[16,8]{1,0} all-gather-done(%ag)", {"all-gather": 512}),
+    ("  %p = pred[7]{0} collective-permute(%a)", {"collective-permute": 7}),
+    ("  %f = f32[8]{0} fusion(%a), kind=kLoop, calls=%fused", {}),
+])
+def test_collective_bytes_of_an_instruction(text, expected):
+    hlo = "HloModule m\n\nENTRY %main (a: f32[8]) -> f32[8] {\n" + text + "\n}"
+    assert profiler.collective_bytes(hlo) == expected
+
+
+def test_collective_bytes_takes_a_loop_once_and_a_conditionals_larger_branch():
+    hlo = """HloModule m
+
+%body (p: f32[8]) -> f32[8] {
+  %r = f32[8]{0} all-reduce(%p), to_apply=%add
+}
+
+%small (p: f32[8]) -> f32[8] {
+  %r = f32[2]{0} all-reduce(%p), to_apply=%add
+}
+
+%large (p: f32[8]) -> f32[8] {
+  %r = f32[4]{0} reduce-scatter(%p), to_apply=%add
+}
+
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %w = f32[8]{0} while(%a), condition=%cond, body=%body
+  %c = f32[8]{0} conditional(%i, %a, %a), branch_computations={%small, %large}
+}
+"""
+    assert profiler.collective_bytes(hlo) == {"all-reduce": 32,
+                                              "reduce-scatter": 16}
+
+
+# -- (d) the three readers on a recorded slice of a four-chip trace ---------
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    with open(SLICE) as fh:
+        return json.load(fh)
+
+
+def test_readers_on_the_recorded_step(recorded):
+    planes = [(p, [(line, [tuple(e) for e in events])
+                   for line, events in lines])
+              for p, lines in recorded["planes"]]
+    found = collectives.reduce_planes(planes)
+    assert found["chips"] == 4
+    evidence = {"collectives": found, "steps": recorded["steps"]}
+    ms = collectives.ms_per_step(evidence)
+    exposed = collectives.exposed_ms_per_step(evidence)
+    assert ms == pytest.approx(recorded["expected"]["collective_ms"])
+    assert exposed == pytest.approx(
+        recorded["expected"]["collective_exposed_ms"])
+    assert 0 < exposed <= ms
+    # the program's own table reads the same trace: its row `collective`
+    # (self time on the chip's one operation line) is the exposed part
+    ours = profiler.layer_table_of(planes, steps=recorded["steps"])
+    row = {(r["scope"], r["pass"]): r for r in ours["rows"]}
+    assert row[(profiler.COLLECTIVE, "-")]["seconds"] == pytest.approx(
+        found["exposed_s"])
+    assert sum(r["seconds"] for r in ours["rows"]) == pytest.approx(
+        ours["busy_s"])
+    # and the yardstick's busy time is the same union as ever
+    assert trace_reduce.reduce_planes(planes)["busy_s"] == pytest.approx(
+        ours["busy_s"])
+
+
+def _line(*events):
+    return [("/device:TPU:0", [("XLA Ops", list(events))])]
+
+
+@pytest.mark.parametrize("events, whole, alone", [
+    # an asynchronous pair: from the start's start to the done's end, and
+    # the fusion between them hides its part
+    ([("%all-reduce-start.1 = f32[8] all-reduce-start(%a)", 100, 10),
+      ("%fusion.2 = f32[8] fusion(%b)", 110, 200),
+      ("%all-reduce-done.1 = f32[8] all-reduce-done(%s)", 310, 90)],
+     300, 100),
+    # a synchronous one with nothing beside it: all of it exposed
+    ([("%fusion.1 = f32[8] fusion(%b)", 0, 100),
+      ("%all-reduce.5 = f32[8] all-reduce(%a)", 100, 50)], 50, 50),
+    # inside a loop's event: the `while` holds it and is no work itself
+    ([("%while.1 = (f32[8]) while(%t)", 0, 1000),
+      ("%all-reduce.5 = f32[8] all-reduce(%a)", 100, 50),
+      ("%fusion.3 = f32[8] fusion(%b)", 150, 850)], 50, 50),
+    # an overlapped pair, wholly hidden
+    ([("%all-gather-start.2 = f32[8] all-gather-start(%a)", 0, 5),
+      ("%fusion.1 = f32[8] fusion(%b)", 0, 400),
+      ("%all-gather-done.2 = f32[8] all-gather-done(%s)", 390, 10)],
+     400, 0),
+    ([("%fusion.1 = f32[8] fusion(%b)", 0, 100)], 0, 0),
+])
+def test_collective_time_and_its_exposed_part(events, whole, alone):
+    found = collectives.reduce_planes(_line(*events))
+    assert found["collective_s"] == pytest.approx(whole / 1e9)
+    assert found["exposed_s"] == pytest.approx(alone / 1e9)
+    # the program's row: the collectives' self time.  Where the line is
+    # serial, as a chip's is, that is the exposed part (in the fourth case
+    # the fusion is drawn over the pair's own events, which no chip does)
+    rows = {r["scope"]: r["seconds"]
+            for r in profiler.layer_table_of(_line(*events))["rows"]}
+    if whole != 400:
+        assert rows.get(profiler.COLLECTIVE, 0.0) == pytest.approx(
+            alone / 1e9)
+
+
+def test_readers_find_nothing_where_there_is_nothing():
+    assert collectives.ms_per_step({"steps": 4}) is None
+    assert collectives.exposed_ms_per_step({"steps": 4, "collectives": {
+        "collective_s": 0.0, "exposed_s": 0.0}}) is None
+
+
+# -- (e) the cell's files ---------------------------------------------------
+
+
+def _load(*path):
+    with open(os.path.join(REPO, *path)) as fh:
+        return json.load(fh)
+
+
+def test_the_cells_files_exist_and_say_what_the_issue_says():
+    bench = _load("BENCHMARK.json")
+    cell = {c["name"]: c for c in bench["workloads"]}["tenk-train-dp4"]
+    assert cell["chips"] == 4 and cell["config"] == "endpoints-10k-dp4"
+    entry = {c["name"]: c for c in bench["configs"]}[cell["config"]]
+    cfg = _load(entry["file"])
+    one = _load("chipbench", "configs", "endpoints-10k.json")
+    assert cfg["model"] == one["model"]              # no width cut
+    assert cfg["train"] == {**one["train"], "batch_size": 128}
+    assert cfg["mesh"] == {"data": 4, "expert": 1, "model": 1}
+    assert cfg["reduced"] == entry["reduced"] == ["chips", "corpus_days",
+                                                  "mesh"]
+    # two deployments of one brief line: the driver takes a configuration
+    # with another's source AND reduced keys as no new configuration
+    first = {c["name"]: c for c in bench["configs"]}["endpoints-10k"]
+    assert entry["source"] != first["source"] and len(entry["source"]) <= 200
+    assert set(entry["reduced"]) != set(first["reduced"])
+    assert cfg["all_reduce_bytes_per_step"] > 0
+    mix = _load("chipbench", "traffic", cell["traffic"] + ".json")
+    assert mix["runner"] == "train_mesh"
+    assert mix["params"] == _load("chipbench", "traffic",
+                                  "week-sparse.json")["params"]
+    limits = _load("chipbench", "limits", cell["name"] + ".json")
+    assert set(limits["limits"]) == {"loss_rel_gap", "grad_norm_gap",
+                                     "delta_norm_gap"}
+    for m in bench["per_layer"]:
+        spec = _load("chipbench", "layer_metrics", m["name"] + ".json")
+        module, func = spec["reader"].split(":")
+        assert os.path.exists(os.path.join(
+            REPO, "chipbench", "readers", module + ".py"))
+        if m["layer"] == "mesh":
+            assert m["workloads"] == [cell["name"]]
+            assert spec["runners"] == ["train_mesh"]
+            assert callable(getattr(collectives, func))
+    for name in ("train_steps_per_s", "hbm_peak_gb"):
+        metric = {m["name"]: m for m in bench["end_to_end"]}[name]
+        assert cell["name"] in metric["workloads"]
+    # the accepted per-layer metrics: the two gauges of the compact feed
+    # name the cell; the seven that apply by runner name keep no list (the
+    # runner is read as the `train` run it is)
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    for name in ("proj_columns_pct.train", "adam_rows_pct.train"):
+        assert by_name[name]["workloads"][-1] == cell["name"]
+    unlisted = [m["name"] for m in bench["per_layer"] if "workloads" not in m]
+    assert len(unlisted) == 7
+    assert all(_load("chipbench", "layer_metrics", name + ".json")["runners"]
+               == ["train"] for name in unlisted)
