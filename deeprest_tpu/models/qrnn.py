@@ -36,7 +36,8 @@ softmax still runs over all F and every parameter keeps its shape.  A
 caller that differentiates with respect to ``w_ih`` gets the take's
 transpose: a dense gradient that is zero at the columns left out (the
 per-step programs, the accumulation supersteps).  One that hands in the
-taken rows itself (``live_w_ih``; the compact superstep, PR 27) gets their
+taken rows itself (``live_w_ih``; the compact superstep, which takes them
+once a dispatch and carries them through its scan, PR 32) gets their
 gradient as ``[E, U_pad, 3H]`` and no dense one, and runs Adam on those
 rows alone (``train/trainer.py``).  Without ``live_cols`` (every dense
 feed, serving) the call is what it was.
@@ -97,23 +98,70 @@ def _fold(mask: jax.Array, w_ih: jax.Array) -> jax.Array:
     return mask[:, :, None] * w_ih
 
 
-def take_columns(a: jax.Array, live_cols: jax.Array) -> jax.Array:
+def _over_experts(fn, mesh):
+    """``fn`` (leaves ``[E, ...]`` first, the table last) on each device's
+    own experts where ``mesh`` shards them: the rows ``e*F + live_cols[u]``
+    of a shard's experts lie in that shard's part of the ``[E*F, 3H]`` view,
+    which the partitioner cannot see (left to it, it gathers every shard's
+    rows everywhere and reduces)."""
+    if mesh is None or mesh.shape["expert"] == 1:
+        return fn
+    from jax.sharding import PartitionSpec as P
+
+    def sharded(*args):
+        return jax.shard_map(
+            fn, mesh=mesh, in_specs=(*[P("expert")] * (len(args) - 1), P()),
+            out_specs=P("expert"))(*args)
+    return sharded
+
+
+def _flat_rows(a: jax.Array, live_cols: jax.Array):
+    """A ``[E, F, C]`` leaf as its ``[E*F, C]`` rows (a bitcast of the
+    leaf as it lies in memory) and the rows ``e*F + live_cols[u]``, in
+    ``e``-major order: sorted, unique and in bounds as the table is."""
+    e, f = a.shape[:2]
+    rows = jnp.arange(e, dtype=live_cols.dtype)[:, None] * f + live_cols
+    return a.reshape(e * f, *a.shape[2:]), rows.reshape(-1)
+
+
+def take_columns(a: jax.Array, live_cols: jax.Array, mesh=None) -> jax.Array:
     """``a[:, live_cols]``: the live columns of the ``[E, F]`` mask or of a
     ``[E, F, 3H]`` input weight.  The table is sorted, without repeats and
     in range (``ops/densify.compact_table``), and the gather says so, so
-    its transpose is a scatter that need not serialize or accumulate."""
-    return a.at[:, live_cols].get(unique_indices=True, indices_are_sorted=True,
-                                  mode="promise_in_bounds")
+    its transpose is a scatter that need not serialize or accumulate.  A
+    weight is indexed by row ``e*F + live_cols[u]`` of its ``[E*F, 3H]``
+    view: a gather over dimension 1 of the leaf itself makes the TPU
+    compiler lay the whole leaf out with that dimension outermost, a copy
+    of the leaf each way (PERF.md section 6, PR 32); a gather of rows asks
+    for no layout.  ``mesh``: the one the weight is sharded over, if any."""
+    if a.ndim == 2:
+        return a.at[:, live_cols].get(unique_indices=True,
+                                      indices_are_sorted=True,
+                                      mode="promise_in_bounds")
+
+    def take(a, live_cols):
+        flat, rows = _flat_rows(a, live_cols)
+        return flat.at[rows].get(
+            unique_indices=True, indices_are_sorted=True,
+            mode="promise_in_bounds").reshape(a.shape[0], -1, *a.shape[2:])
+
+    return _over_experts(take, mesh)(a, live_cols)
 
 
-def put_columns(a: jax.Array, live_cols: jax.Array,
-                rows: jax.Array) -> jax.Array:
-    """``a`` with ``rows`` at ``a[:, live_cols]``: what :func:`take_columns`
-    took, put back under the same promises, so that on a donated or
-    loop-carried ``a`` the scatter writes in place."""
-    return a.at[:, live_cols].set(rows, unique_indices=True,
-                                  indices_are_sorted=True,
-                                  mode="promise_in_bounds")
+def put_columns(a: jax.Array, live_cols: jax.Array, rows: jax.Array,
+                mesh=None) -> jax.Array:
+    """The ``[E, F, 3H]`` weight ``a`` with ``rows`` at ``a[:, live_cols]``:
+    what :func:`take_columns` took, put back under the same promises and
+    over the same ``[E*F, 3H]`` view, so that on a donated or loop-carried
+    ``a`` the scatter writes in place and no copy of ``a`` is made."""
+    def put(a, rows, live_cols):
+        flat, at = _flat_rows(a, live_cols)
+        return flat.at[at].set(
+            rows.reshape(-1, *a.shape[2:]), unique_indices=True,
+            indices_are_sorted=True, mode="promise_in_bounds"
+        ).reshape(a.shape)
+
+    return _over_experts(put, mesh)(a, rows, live_cols)
 
 
 class QuantileGRU(nn.Module):
@@ -197,7 +245,7 @@ class QuantileGRU(nn.Module):
             if live_cols is None:
                 w_ih = p.w_ih
             elif live_w_ih is None:
-                w_ih = take_columns(p.w_ih, live_cols)
+                w_ih = take_columns(p.w_ih, live_cols, self.mesh)
             else:
                 w_ih = live_w_ih[name]
             return p._replace(w_ih=_fold(mask, w_ih))

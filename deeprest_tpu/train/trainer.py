@@ -91,50 +91,30 @@ def w_ih_leaves(tree) -> list:
             if _is_w_ih(path)]
 
 
-def take_w_ih(tree, live_cols):
+def take_w_ih(tree, live_cols, mesh=None):
     """``tree`` with :func:`take_columns` of each such leaf in its place:
     ``[E, U_pad, 3H]`` for ``[E, F, 3H]``, every other leaf as it is."""
     return jax.tree_util.tree_map_with_path(
-        lambda path, a: take_columns(a, live_cols) if _is_w_ih(path) else a,
-        tree)
+        lambda path, a: (take_columns(a, live_cols, mesh) if _is_w_ih(path)
+                         else a), tree)
 
 
-def put_w_ih(tree, rows, live_cols):
+def put_w_ih(rows, tree, live_cols, mesh=None):
     """The inverse: ``rows`` (a tree as :func:`take_w_ih` gives it), with
-    each such leaf written back into ``tree``'s full one."""
+    each such leaf written back into ``tree``'s whole one; ``tree`` need
+    hold no other leaf (:func:`only_w_ih`)."""
     return jax.tree_util.tree_map_with_path(
-        lambda path, a, new: (put_columns(a, live_cols, new)
-                              if _is_w_ih(path) else new), tree, rows)
+        lambda path, new, a: (put_columns(a, live_cols, new, mesh)
+                              if _is_w_ih(path) else new), rows, tree)
 
 
-def only_w_ih(tree, which: bool):
-    """``tree`` without its w_ih leaves (``which`` False) or without every
-    other leaf of params and of its mirrors (True): the leaves left out
-    are None, which a pytree does not count; what is no mirror of a
-    parameter (Adam's ``count``) stays in both."""
+def only_w_ih(tree):
+    """``tree`` with only its w_ih leaves: every other leaf of params, or
+    of a mirror of it, is None, which a pytree does not count; what is no
+    mirror of a parameter (Adam's ``count``) stays."""
     return jax.tree_util.tree_map_with_path(
-        lambda path, a: a if (not _names_param(path)
-                              or _is_w_ih(path) == which) else None, tree)
-
-
-def merge_leaves(a, b):
-    """Two trees of one structure whose None leaves complement each other
-    (:func:`only_w_ih`), as one; a leaf both hold is taken from ``a``."""
-    return jax.tree.map(lambda x, y: y if x is None else x, a, b,
-                        is_leaf=lambda x: x is None)
-
-
-def untake_w_ih(rows, like, live_cols):
-    """:func:`take_w_ih`'s transpose, as differentiating through the take
-    gives it: each such leaf of ``rows`` scattered into zeros of the shape
-    of ``like``'s (a gradient over all F rows, zero off the table)."""
-    def untake(path, g, full):
-        if not _is_w_ih(path):
-            return g
-        return jax.linear_transpose(
-            lambda a: take_columns(a, live_cols),
-            jax.ShapeDtypeStruct(full.shape, full.dtype))(g)[0]
-    return jax.tree_util.tree_map_with_path(untake, rows, like)
+        lambda path, a: a if (not _names_param(path) or _is_w_ih(path))
+        else None, tree)
 
 
 def moments_off_table_are_zero(opt_state, live_cols) -> jax.Array:
@@ -256,64 +236,26 @@ class Trainer:
         self._pin_state = jax.jit(pin_state)
 
         @jax.named_scope(scopes.OPTIMIZER)
-        def apply_gradients(state: TrainState, grads, live_cols=None,
-                            rows_ok=None):
-            # With `rows_ok` (the compact superstep; a scalar of the
-            # dispatch) `grads` holds the w_ih leaves' gradient at the
-            # table's rows only.  Where it is true the same tx.update
-            # runs on those rows of the two leaves and of their moments,
-            # with the shared count, and each is written back in place;
-            # where it is not, the rows are scattered into a gradient
-            # over all F rows (what differentiating through the take
-            # gives) and the update runs over them all.  Only the two
-            # leaves pass through the conditional: every other leaf's
-            # update stays where the standalone step has it, fused as
-            # there, so its rounding is the standalone step's.
-            def on_all(state, grads):
-                updates, opt_state = self.tx.update(grads, state.opt_state)
-                return optax.apply_updates(state.params, updates), opt_state
-
-            def on_rows(state, grads):
-                updates, opt_rows = self.tx.update(
-                    grads, take_w_ih(state.opt_state, live_cols))
-                params_rows = optax.apply_updates(
-                    take_w_ih(state.params, live_cols), updates)
-                return (put_w_ih(state.params, params_rows, live_cols),
-                        put_w_ih(state.opt_state, opt_rows, live_cols))
-
-            def on_all_from_rows(state, grads):
-                return on_all(state,
-                              untake_w_ih(grads, state.params, live_cols))
-
-            if rows_ok is None:
-                return on_all(state, grads)
-
-            def part(w_ih: bool):
-                return (state.replace(
-                    params=only_w_ih(state.params, w_ih),
-                    opt_state=only_w_ih(state.opt_state, w_ih)),
-                    only_w_ih(grads, w_ih))
-
-            return merge_leaves(
-                on_all(*part(False)),
-                jax.lax.cond(rows_ok, on_rows, on_all_from_rows,
-                             *part(True)))
+        def apply_gradients(state: TrainState, grads):
+            updates, opt_state = self.tx.update(grads, state.opt_state)
+            return optax.apply_updates(state.params, updates), opt_state
 
         @jax.named_scope(scopes.DROPOUT)
         def dropout_key(state: TrainState):
             return jax.random.fold_in(state.rng, state.step)
 
         def train_step(state: TrainState, xb, yb, wb, live_cols=None,
-                       rows_ok=None):
-            # With `rows_ok` (and live_cols): differentiate with respect
-            # to the table's rows of the w_ih leaves, which the model is
-            # handed in the leaves' place, so their gradient exists as
-            # [E, U_pad, 3H] only; the full leaves ride along unread
-            # (flax holds a supplied leaf to its init shape).
+                       full_w_ih=None):
+            # With `full_w_ih` (the compact superstep, with live_cols): the
+            # state holds the table's rows [E, U_pad, 3H] of the w_ih
+            # leaves, and of their moments, in the leaves' place.  The
+            # model is handed them as `live_w_ih`, the step differentiates
+            # with respect to them and runs the one tx.update on them with
+            # the shared count: no [E, F, 3H] array is read or made.  The
+            # whole leaves of `full_w_ih` ride along unread (flax holds a
+            # supplied leaf to its init shape).
             dropout_rng = dropout_key(state)
-            w_ih = ({k: v for k, v in state.params.items()
-                     if k in MASKED_PARAM_NAMES} if rows_ok is not None
-                    else {})
+            w_ih = full_w_ih or {}
 
             def loss_fn(params):
                 preds = self.model.apply(
@@ -323,11 +265,8 @@ class Trainer:
                 )
                 return pinball_loss(preds, yb, quantiles, sample_weight=wb)
 
-            loss, grads = jax.value_and_grad(loss_fn)(
-                state.params if rows_ok is None
-                else take_w_ih(state.params, live_cols))
-            params, opt_state = apply_gradients(state, grads, live_cols,
-                                                rows_ok)
+            loss, grads = jax.value_and_grad(loss_fn)(state.params)
+            params, opt_state = apply_gradients(state, grads)
             return (
                 pin_state(TrainState(step=state.step + 1, params=params,
                                      opt_state=opt_state, rng=state.rng)),
@@ -355,14 +294,14 @@ class Trainer:
             return gather_x(x_base, idx), y_base[idx]
 
         def train_step_indexed(state: TrainState, x_base, y_base, starts, wb,
-                               rows_ok=None):
+                               full_w_ih=None):
             # Device-resident feed: the normalized BASE series live in HBM
             # (stage_dataset) and each step gathers its windows by start
             # index — per-step host→device traffic is [B] int32 + weights
             # instead of the [B,W,F] window tensor (windows overlap W−1 of
             # W rows, so materialized shipping re-sends every row W times).
             return train_step(state, *gather_windows(x_base, y_base, starts),
-                              wb, live_cols_of(x_base), rows_ok)
+                              wb, live_cols_of(x_base), full_w_ih)
 
         def train_superstep(state: TrainState, x_base, y_base,
                             starts_plan, weights_plan, chunk):
@@ -386,31 +325,23 @@ class Trainer:
                 weights_plan, chunk, 0, keepdims=False)      # [S, B]
 
             # A compact base's gradient lives on its table's rows of the
-            # w_ih leaves, and the step takes it there and nowhere else.
-            # Where their moments are zero off the table (a state from
-            # init_state, or one trained on this table only), Adam leaves
-            # every other row as it is, and the step updates the table's
-            # rows alone; it keeps that true, so one look a dispatch is
-            # enough.  Moments off the table (another corpus's, a
-            # dense-form run's) still move their rows: for such a state
-            # the step scatters the gradient and updates all F rows, as
-            # every other feed does.  The choice sits round the optimizer
-            # inside the step, on the dispatch's scalar: a cond round two
-            # scans gives each loop its own copy of the six big leaves
-            # (3.8 GB more), one round two whole steps doubles the
-            # program (5 s more to load it from the cache).
+            # w_ih leaves, and Adam is elementwise.  So the table's rows of
+            # the two leaves and of their moments are taken once, here;
+            # they ride the scan in the leaves' place, each step
+            # differentiating with respect to them and updating them; and
+            # they are put back once, into the donated leaves, after it.
+            # Nothing [E, F, 3H] is named inside the scan.
             live_cols = live_cols_of(x_base)
-            rows_ok = (
-                moments_off_table_are_zero(state.opt_state, live_cols)
-                if live_cols is not None and w_ih_leaves(state.params)
-                else None)
+            full_w_ih = ({k: v for k, v in state.params.items()
+                          if k in MASKED_PARAM_NAMES}
+                         if live_cols is not None else {})
 
             def body(st, step_plan):
                 starts, wb = step_plan
 
                 def run(s):
                     s2, loss = train_step_indexed(s, x_base, y_base,
-                                                  starts, wb, rows_ok)
+                                                  starts, wb, full_w_ih)
                     # f32 losses regardless of compute dtype so the skip
                     # branch's zero matches the run branch's aval.
                     return s2, loss.astype(jnp.float32)
@@ -420,7 +351,43 @@ class Trainer:
 
                 return jax.lax.cond(jnp.any(wb > 0), run, skip, st)
 
-            return jax.lax.scan(body, state, (starts_c, weights_c))
+            rows, losses = jax.lax.scan(
+                body,
+                take_w_ih(state, live_cols, self.mesh) if full_w_ih else state,
+                (starts_c, weights_c))
+            if not full_w_ih:
+                return rows, losses
+
+            # Off the table the gradient is exactly zero, so what a row
+            # there does in a dispatch depends on its moments, the count
+            # and the number of real steps alone.  Where the moments are
+            # zero there (a state from init_state, or one trained on this
+            # table only) it does nothing, and keeps that true.  Moments
+            # off the table (another corpus's, a dense-form run's) still
+            # move their rows: that many zero-gradient updates of the six
+            # whole leaves, with the counts the scan used, before the
+            # carried rows go on top.  The rule is the loop's trip count
+            # and not a cond round it (a conditional's identity branch
+            # copies the six leaves each dispatch), nor a second step in
+            # the program (5 s more to load it from the cache).
+            rows_ok = moments_off_table_are_zero(state.opt_state, live_cols)
+            whole = only_w_ih(state.params), only_w_ih(state.opt_state)
+
+            @jax.named_scope(scopes.OPTIMIZER)
+            def off_table_step(_, leaves):
+                params, opt_state = leaves
+                updates, opt_state = self.tx.update(
+                    jax.tree.map(jnp.zeros_like, params), opt_state)
+                return optax.apply_updates(params, updates), opt_state
+
+            params, opt_state = jax.lax.fori_loop(
+                0, jnp.where(rows_ok, 0, rows.step - state.step),
+                off_table_step, whole)
+            return pin_state(rows.replace(
+                params=put_w_ih(rows.params, params, live_cols, self.mesh),
+                opt_state=put_w_ih(rows.opt_state, opt_state, live_cols,
+                                   self.mesh),
+            )), losses
 
         # -- gradient accumulation -------------------------------------
         #

@@ -1,18 +1,36 @@
-"""Adam over the rows the corpus can touch (ISSUE 27).
+"""Adam over the rows the corpus can touch (ISSUEs 27 and 32).
 
-On a compact base the superstep differentiates with respect to the table's
-rows of the two w_ih leaves and runs Adam on those rows of the leaves and of
-their moments; every other row has a zero gradient and, in a state whose
-moments are zero off the table, a zero step.  The program checks that itself
-once a dispatch and scatters the gradient and updates all F rows otherwise.
-Held here, on the CPU at toy widths in float32: the row-wise pass against
-the per-step dense pass on the same base, bit for bit; the dense branch for
-a state with moments off the table; the surface the benchmark drives; the
-traced step's shapes; the untouched feeds' StableHLO; and a ``data`` mesh.
+On a compact base the superstep takes the table's rows of the two w_ih
+leaves and of their moments once a dispatch; they ride the scan in the
+leaves' place, each step differentiating with respect to them and running
+Adam on them; after the scan they are put back.  Every other row has a zero
+gradient, so in a state whose moments are zero off the table it does not
+move; in any other state a loop after the scan gives the six whole leaves
+that many zero-gradient updates before the carried rows go on top (its trip
+count is 0 where the rule holds).  Held here, on the CPU at toy widths in
+float32: the row-wise pass against the per-step dense pass on the same base;
+a state with moments off the table, with trailing padded steps too; the
+surface the benchmark drives; the traced program's shapes; the take and the
+put over ``(E*F)`` rows against plain indexing; the untouched feeds'
+StableHLO; and a ``data`` mesh.
+
+Bit for bit needs care.  The two passes are the same arithmetic, but
+XLA:CPU fuses a step's Adam with what feeds it, and which product of
+``a*b + c*d`` the backend then folds into an FMA depends on the fusion: a
+row gathered inside the step (the per-step pass) and a row carried by the
+loop (the superstep) round ``mu`` and ``nu`` differently in the last bit.
+With no FMA to contract into (``--xla_cpu_max_isa=SSE4_2``, a flag of the
+process, so a subprocess: ``python tests/test_sparse_adam.py --exact``) the
+passes are equal in every bit of every leaf; in-process they are held to
+one unit in the last place of each leaf's largest magnitude.
 """
 
 import hashlib
 import inspect
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,8 +39,11 @@ import pytest
 from test_live_columns import B, E, F, H, W, _bundle, _corpus, _trainer
 
 from deeprest_tpu.config import MeshConfig
-from deeprest_tpu.models.qrnn import MASKED_PARAM_NAMES
+from deeprest_tpu.models.qrnn import (
+    MASKED_PARAM_NAMES, put_columns, take_columns,
+)
 from deeprest_tpu.obs import metrics
+from deeprest_tpu.obs.profiler import collective_bytes
 from deeprest_tpu.parallel.distributed import stage_plan, stage_sparse_base
 from deeprest_tpu.parallel.mesh import make_mesh
 
@@ -69,11 +90,24 @@ def _leaves(state):
             jax.tree_util.tree_leaves_with_path(state)}
 
 
-def _assert_states_equal(got, want):
+def _assert_states_equal(got, want, ulps: int = 0):
+    """Every leaf equal; with ``ulps``, a float leaf to within that many
+    units in the last place of its largest magnitude (the module
+    docstring: what an FMA contracted otherwise moves)."""
     got, want = _leaves(got), _leaves(want)
     assert list(got) == list(want)
-    for name in want:
-        np.testing.assert_array_equal(got[name], want[name], err_msg=name)
+    for name, z in want.items():
+        if ulps and z.dtype == np.float32:
+            np.testing.assert_allclose(
+                got[name], z, rtol=0.0, err_msg=name,
+                atol=ulps * float(np.spacing(np.abs(z).max())))
+        else:
+            np.testing.assert_array_equal(got[name], z, err_msg=name)
+
+
+# Whether this process's XLA:CPU has an FMA to contract into.
+EXACT = "--xla_cpu_max_isa=SSE4_2" in os.environ.get("XLA_FLAGS", "")
+ULPS = 0 if EXACT else 1
 
 
 def _gauge():
@@ -93,17 +127,19 @@ def _setup(hot: int = 100, mesh=None):
 # -- (a), (f): the row-wise pass is the dense pass ---------------------------
 
 
-@pytest.mark.parametrize("mesh_config", [None, MeshConfig(data=2)],
-                         ids=["one-device", "data2"])
+@pytest.mark.parametrize(
+    "mesh_config", [None, MeshConfig(data=2), MeshConfig(data=2, expert=2)],
+    ids=["one-device", "data2", "data2-expert2"])
 def test_rowwise_superstep_is_the_per_step_dense_pass(mesh_config):
     """Seven steps over two dispatches, the last one padded, against the
     same steps through ``_train_step_indexed``, which differentiates with
     respect to the whole leaves and updates all F rows: every leaf of
-    ``params``, ``mu``, ``nu``, ``count``, ``step`` and ``rng`` bit for
-    bit, dead rows included.  (Under a mesh GSPMD places a superstep's
-    reductions and a step's differently: there the dense superstep and
-    the per-step loop differ in the last bit at the parent commit too, so
-    that case holds a tolerance, and exactness off the table.)"""
+    ``params``, ``mu``, ``nu``, ``count``, ``step`` and ``rng``, dead rows
+    included; the losses bit for bit.  (Under a mesh GSPMD places a
+    superstep's reductions and a step's differently: there the dense
+    superstep and the per-step loop differ in the last bit at the parent
+    commit too, so that case holds a tolerance, and exactness off the
+    table.)"""
     mesh = None if mesh_config is None else make_mesh(mesh_config)
     trainer, bundle, staged = _setup(mesh=mesh)
     table = np.asarray(staged[0].live)
@@ -121,9 +157,16 @@ def test_rowwise_superstep_is_the_per_step_dense_pass(mesh_config):
     assert bool(trainer._moments_off_table_are_zero(got.opt_state,
                                                     staged[0].live))
     assert int(got.step) == int(want.step) == 7 and got_losses[7] == 0.0
+    if mesh is not None:
+        # the take and the put stay on each shard's own experts: what the
+        # superstep hands its collectives is gradients, never a leaf
+        moved = collective_bytes(trainer._superstep.lower(
+            got, *staged, *plan[2], 0).compile().as_text())
+        assert set(moved) <= {"all-reduce"}, moved
+        assert moved["all-reduce"] < 4 * E * F * 3 * H, moved
     if mesh is None:
         np.testing.assert_array_equal(got_losses[:7], want_losses)
-        _assert_states_equal(got, want)
+        _assert_states_equal(got, want, ULPS)
     got, want = _leaves(got), _leaves(want)
     np.testing.assert_allclose(got_losses[:7], want_losses, rtol=1e-6)
     for name, z in want.items():
@@ -140,10 +183,13 @@ def test_rowwise_superstep_is_the_per_step_dense_pass(mesh_config):
                     err_msg=name)
 
 
-# -- (b): moments off the table take the pass over all F rows -----------------
+# -- (b): moments off the table still move their rows -------------------------
 
 
-def test_moments_off_the_table_take_the_dense_branch_bit_for_bit():
+def _two_corpora():
+    """A trainer, corpus A staged, corpus B staged (a table that leaves
+    out rows of A's), and a maker of states three steps into corpus A:
+    their moments lie on A's table, so off B's."""
     trainer, bundle_a, staged_a = _setup(hot=100)
     cols, vals, y, _ = _corpus(60)
     bundle_b = _bundle(cols, vals, y)
@@ -161,15 +207,22 @@ def test_moments_off_the_table_take_the_dense_branch_bit_for_bit():
                                                     staged_a[0].live))
     assert not bool(trainer._moments_off_table_are_zero(state.opt_state,
                                                         staged_b[0].live))
+    return (trainer, (bundle_a, staged_a), (bundle_b, staged_b),
+            after_corpus_a, np.setdiff1d(table_a, table_b))
+
+
+def test_moments_off_the_table_move_their_rows_as_the_dense_pass_does():
+    trainer, (bundle_a, staged_a), (bundle_b, staged_b), after_corpus_a, \
+        only_a = _two_corpora()
     plan = _plan(trainer, bundle_b, 5, seed=6)
     got, got_losses = _through_superstep(trainer, after_corpus_a(), staged_b,
                                          plan)
-    want, want_losses = _through_per_step(trainer, state, staged_b, plan)
+    want, want_losses = _through_per_step(trainer, after_corpus_a(),
+                                          staged_b, plan)
     np.testing.assert_array_equal(got_losses[:5], want_losses)
-    _assert_states_equal(got, want)
+    _assert_states_equal(got, want, ULPS)
     # rows of corpus A's table that B's does not name still stepped: their
     # moments decay and Adam moves them with no gradient
-    only_a = np.setdiff1d(table_a, table_b)
     before = _leaves(after_corpus_a())
     name = f".params['{MASKED_PARAM_NAMES[0]}']"
     assert (_leaves(got)[name][:, only_a] != before[name][:, only_a]).any()
@@ -182,6 +235,35 @@ def test_moments_off_the_table_take_the_dense_branch_bit_for_bit():
     trainer.train_epoch(after_corpus_a(), bundle_a, np.random.default_rng(0),
                         staged=staged_a)
     assert _gauge() == {"updated": 128, "total": F}
+
+
+def test_a_padded_dispatch_moves_rows_off_the_table_by_its_real_steps_only():
+    """One dispatch of two real steps and two padded ones on a state with
+    moments off the table: the loop after the scan runs twice, with the
+    counts the two real steps used, so every leaf (dead rows included) is
+    what two steps of ``_train_step_indexed`` leave, and not what four
+    zero-gradient updates would."""
+    trainer, _, (bundle_b, staged_b), after_corpus_a, only_a = _two_corpora()
+    plan = _plan(trainer, bundle_b, 2, seed=7)
+    assert plan[1].shape == (1, S, B) and (plan[1].sum(axis=2) > 0).tolist() \
+        == [[True, True, False, False]]
+    got, got_losses = _through_superstep(trainer, after_corpus_a(), staged_b,
+                                         plan)
+    want, want_losses = _through_per_step(trainer, after_corpus_a(),
+                                          staged_b, plan)
+    count = ".opt_state[0].count"
+    assert int(got.step) == 3 + 2 and _leaves(got)[count] == 3 + 2
+    np.testing.assert_array_equal(got_losses, [*want_losses, 0.0, 0.0])
+    _assert_states_equal(got, want, ULPS)
+    # a row only corpus A named moved, and two padded steps more would
+    # have moved it further
+    name = f".params['{MASKED_PARAM_NAMES[0]}']"
+    before = _leaves(after_corpus_a())[name][:, only_a]
+    moved = _leaves(got)[name][:, only_a]
+    further = _leaves(_through_per_step(
+        trainer, after_corpus_a(), staged_b,
+        _plan(trainer, bundle_b, 4, seed=7))[0])[name][:, only_a]
+    assert (moved != before).any() and (moved != further).any()
 
 
 # -- (c): what the benchmark drives -------------------------------------------
@@ -222,7 +304,7 @@ def test_the_benchmarks_surface_is_kept_and_seeded_weights_run_rowwise():
         assert not np.delete(np.asarray(mu[name]), live, axis=1).any()
 
 
-# -- (d): the traced step's shapes --------------------------------------------
+# -- (d): the traced program's shapes ------------------------------------------
 
 
 def _eqns(jaxpr):
@@ -232,49 +314,94 @@ def _eqns(jaxpr):
             yield from _eqns(sub)
 
 
-def _leaf_makers(eqns, leaf):
-    """primitive -> count, over the equations with a float result of a
-    leaf's shape."""
-    made = {}
-    for eqn in eqns:
-        if any(getattr(v.aval, "shape", None) == leaf
-               and v.aval.dtype == jnp.float32 for v in eqn.outvars):
-            made[eqn.primitive.name] = made.get(eqn.primitive.name, 0) + 1
-    return made
+def _floats_of_shape(variables, shapes) -> bool:
+    return any(getattr(v.aval, "shape", None) in shapes
+               and v.aval.dtype == jnp.float32 for v in variables)
 
 
-def test_the_rowwise_branch_holds_no_gradient_or_zero_fill_of_a_leafs_shape():
-    """One trace of the compact superstep.  The step makes the w_ih
-    gradient at the table's rows; the only equations with an ``[E, F, 3H]``
-    float result sit in the conditional round the optimizer: on its
-    row-wise side the three write-backs a leaf and nothing else, on the
-    other the zeros the rows are scattered into and Adam over all rows."""
+def test_the_rows_ride_the_scan_and_nothing_of_a_leafs_shape_is_inside_it():
+    """One trace of the compact superstep.  Before the scan six gathers of
+    ``(E*F)`` rows (two leaves, their ``mu`` and ``nu``); inside its body
+    no equation with an ``[E, F, 3H]`` (or ``[E*F, 3H]``) float operand or
+    result, and no such operand of the scan itself: the step names the
+    carried ``[E, U_pad, 3H]`` rows only; after it the loop over the six
+    whole leaves for a state with moments off the table, then the six
+    scatters that put the rows back, and nothing else that makes a leaf."""
     trainer, bundle, staged = _setup()
     state = trainer.init_state(trainer.sample_input(bundle), seed=1)
     traced = jax.make_jaxpr(trainer._superstep)(
         state, *staged, *_plan(trainer, bundle, 2)[2], 0)
-    leaf = (E, F, 3 * H)
-    wrappers = {"pjit", "jit", "sharding_constraint", "closed_call", "cond",
-                "scan", "while"}
-    conds = [e for e in _eqns(traced.jaxpr) if e.primitive.name == "cond"]
-    # the innermost conditional that holds the write-backs
-    optimizer = min(
-        (e for e in conds if any(
-            "scatter" in _leaf_makers(_eqns(b.jaxpr), leaf)
-            for b in e.params["branches"])),
-        key=lambda e: sum(1 for b in e.params["branches"]
-                          for _ in _eqns(b.jaxpr)))
-    on_all, on_rows = (_leaf_makers(_eqns(b.jaxpr), leaf)
-                       for b in optimizer.params["branches"])  # false, true
-    assert set(on_rows) - wrappers == {"scatter"}, on_rows
-    assert on_rows["scatter"] == 3 * len(MASKED_PARAM_NAMES)
-    assert on_all.get("broadcast_in_dim", 0) >= 2, on_all
-    assert on_all.get("scatter-add", 0) == 2 and on_all.get("mul", 0) > 6
-    inside = {id(e) for b in optimizer.params["branches"]
-              for e in _eqns(b.jaxpr)}
-    outside = _leaf_makers(
-        (e for e in _eqns(traced.jaxpr) if id(e) not in inside), leaf)
-    assert not set(outside) - wrappers, outside
+    (program,) = traced.jaxpr.eqns
+    eqns = program.params["jaxpr"].jaxpr.eqns
+    names = [e.primitive.name for e in eqns]
+    assert names.count("scan") == 1 and names.count("while") == 1
+    scan_at, loop_at = names.index("scan"), names.index("while")
+    assert scan_at < loop_at
+    width = staged[0].width
+    leaf, flat = (E, F, 3 * H), (E * F, 3 * H)
+    rows, flat_rows = (E, width, 3 * H), (E * width, 3 * H)
+    n = 3 * len(MASKED_PARAM_NAMES)
+
+    scan = eqns[scan_at]
+    inside = list(_eqns(scan.params["jaxpr"].jaxpr))
+    assert len(inside) > 200
+    assert not _floats_of_shape(scan.invars, {leaf, flat})
+    assert not [e for e in inside
+                if _floats_of_shape([*e.invars, *e.outvars], {leaf, flat})]
+    assert sum(_floats_of_shape(scan.outvars[i:i + 1], {rows})
+               for i in range(len(scan.outvars))) == n
+
+    before = eqns[:scan_at]
+    takes = [e for e in before if e.primitive.name == "gather"]
+    assert len(takes) == n and all(
+        _floats_of_shape(e.invars[:1], {flat})
+        and _floats_of_shape(e.outvars, {flat_rows}) for e in takes)
+    assert not [e for e in before if "scatter" in e.primitive.name]
+
+    # the all-rows case: Adam's arithmetic on whole leaves, in the loop only
+    loop = eqns[loop_at]
+    body = [e for e in _eqns(loop.params["body_jaxpr"].jaxpr)
+            if _floats_of_shape(e.outvars, {leaf})]
+    assert sum(e.primitive.name == "mul" for e in body) > n
+    puts = [e for e in eqns[loop_at + 1:] if e.primitive.name == "scatter"]
+    assert len(puts) == n and all(
+        _floats_of_shape(e.invars[:1], {flat})
+        and _floats_of_shape(e.invars[2:3], {flat_rows}) for e in puts)
+    layout_only = {"reshape", "sharding_constraint", "scatter", "gather",
+                   "scan", "while"}
+    makers = {e.primitive.name for i, e in enumerate(eqns) if i != loop_at
+              and _floats_of_shape(e.outvars, {leaf, flat})}
+    assert makers <= layout_only, makers
+
+
+# -- (g): the take and the put over (E*F) rows ---------------------------------
+
+
+@pytest.mark.parametrize("f", [512, 2048, 10240])
+def test_take_and_put_over_flat_rows_are_plain_indexing(f):
+    """``take_columns`` / ``put_columns`` of a 3-D leaf index rows
+    ``e*F + live[u]`` of its ``[E*F, C]`` view: the values of
+    ``a[:, live]`` and ``a.at[:, live].set(rows)``, and the take's
+    transpose a gradient that is zero off the table."""
+    rng = np.random.default_rng(f)
+    e, c, u = 3, 24, f // 8
+    a = rng.standard_normal((e, f, c)).astype(np.float32)
+    live = np.sort(rng.choice(f, u, replace=False)).astype(np.int32)
+    new = rng.standard_normal((e, u, c)).astype(np.float32)
+    np.testing.assert_array_equal(
+        jax.jit(take_columns)(a, live), a[:, live])
+    want = a.copy()
+    want[:, live] = new
+    np.testing.assert_array_equal(
+        jax.jit(put_columns)(a, live, new), want)
+    grad = jax.grad(lambda w: jnp.sum(take_columns(w, live) * new))(
+        jnp.asarray(a))
+    want = np.zeros_like(a)
+    want[:, live] = new
+    np.testing.assert_array_equal(grad, want)
+    # the 2-D mask keeps its plain form
+    np.testing.assert_array_equal(
+        take_columns(jnp.asarray(a[:, :, 0]), live), a[:, live, 0])
 
 
 # -- (e): the other feeds' supersteps are the parent's ------------------------
@@ -309,14 +436,14 @@ def _sparse_dense_form():
     return trainer, bundle, staged
 
 
-# sha1 of the lowered superstep's StableHLO at a parent commit, from these
-# very builders run against a checkout of it: the two feeds without a table
-# at a159714 (ISSUE 27), the compact base (``_setup``: a table, the row-wise
-# superstep) at 29db32f (ISSUE 28)
+# sha1 of the lowered superstep's StableHLO, from these very builders: the
+# two feeds without a table run against a checkout of a159714 (ISSUE 27's
+# parent) and unmoved since; the compact base (``_setup``: a table, the
+# rows riding the scan) as ISSUE 32 left it
 PARENT_SHA1 = {
     "dense-feed": "0d7001e28a87175cec6d84c804c7eab4ef1e62b7",
     "sparse-dense-form": "088b19fac794d650f0639c88651a4697758deea4",
-    "sparse-compact": "2564abe58bf57ef47346861c28cefdb5b22bc957",
+    "sparse-compact": "9a642e4696a85223a2f31d8bedfebe1d8b25fc0d",
 }
 BUILDERS = dict(zip(PARENT_SHA1,
                     (_dense_feed, _sparse_dense_form, _setup)))
@@ -330,9 +457,9 @@ def superstep_sha1(build) -> str:
     return hashlib.sha1(text.encode()).hexdigest()
 
 
-def test_the_compact_base_lowers_to_the_parents_superstep():
-    """ISSUE 28 took options away and moved no instruction: the row-wise
-    superstep of a base with a table is the one 29db32f lowered."""
+def test_the_compact_base_lowers_to_the_pinned_superstep():
+    """The compact superstep's program, pinned: a refactor that moves no
+    instruction leaves it, and one that does says so here."""
     assert superstep_sha1(_setup) == PARENT_SHA1["sparse-compact"]
 
 
@@ -353,6 +480,47 @@ def test_feeds_without_a_table_lower_to_the_parents_superstep(
                         else {"updated": F, "total": F})
 
 
-if __name__ == "__main__":       # the digests, from a checkout on sys.path
+# -- (h): without an FMA to contract into, every bit ---------------------------
+
+EXACT_CASES = {
+    "rowwise": lambda: test_rowwise_superstep_is_the_per_step_dense_pass(None),
+    "off-table":
+        test_moments_off_the_table_move_their_rows_as_the_dense_pass_does,
+    "padded-off-table":
+        test_a_padded_dispatch_moves_rows_off_the_table_by_its_real_steps_only,
+}
+
+
+@pytest.fixture(scope="module")
+def exact_run():
+    """This file as a script in a process whose XLA:CPU may use no FMA
+    (the module docstring): the cases above with ``ULPS`` 0."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_cpu_max_isa=SSE4_2",
+           "PYTHONPATH": os.pathsep.join(
+               [root, os.path.join(root, "tests")])}
+    return subprocess.run([sys.executable, __file__, "--exact"], env=env,
+                          capture_output=True, text=True, timeout=600)
+
+
+@pytest.mark.parametrize("case", list(EXACT_CASES))
+def test_without_fma_contraction_the_passes_are_equal_in_every_bit(
+        exact_run, case):
+    assert f"exact {case}: equal" in exact_run.stdout, (
+        exact_run.stdout[-2000:] + exact_run.stderr[-4000:])
+
+
+if __name__ == "__main__":
+    if "--exact" in sys.argv:
+        assert EXACT and ULPS == 0
+        for _name, _case in EXACT_CASES.items():
+            try:
+                _case()
+                print(f"exact {_name}: equal", flush=True)
+            except AssertionError as err:
+                print(f"exact {_name}: NOT equal: {err}", flush=True)
+        sys.exit(0)
+    # the digests, from a checkout on PYTHONPATH
     for _name, _build in BUILDERS.items():
         print(_name, superstep_sha1(_build))
