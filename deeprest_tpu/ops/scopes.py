@@ -19,8 +19,10 @@ MIXING = "mixing"           # cross-expert mixing (models/qrnn.py)
 HEADS = "heads"             # the quantile heads (models/qrnn.py)
 LOSS = "loss"               # ops/quantile.py
 OPTIMIZER = "optimizer"     # tx.update + apply_updates (train/trainer.py)
+OFF_TABLE = "off_table"     # the compact superstep's zero-gradient Adam pass
+                            # over the whole w_ih leaves (train/trainer.py)
 STEP_SCOPES = (GATHER, DENSIFY, MASK, IN_PROJ, RECURRENCE, DROPOUT, MIXING,
-               HEADS, LOSS, OPTIMIZER)
+               HEADS, LOSS, OPTIMIZER, OFF_TABLE)
 
 # Not gru_fwd/gru_bwd: those are the two DIRECTIONS' parameter leaves.
 GRU_KERNEL_FWD = "gru_kernel_fwd"   # the forward pass's kernel

@@ -117,17 +117,32 @@ def only_w_ih(tree):
         else None, tree)
 
 
-def moments_off_table_are_zero(opt_state, live_cols) -> jax.Array:
-    """Whether every moment of the w_ih leaves is exactly zero at every
-    row ``live_cols`` does not name.  Adam's step on a row whose gradient
-    and moments are zero is zero, so while this holds and the gradient
-    lives on the table, Adam on the table's rows IS Adam (NaN counts as
-    nonzero)."""
+def _moments_off_table(opt_state, live_cols) -> jax.Array:
+    """[F] booleans: the rows of the w_ih leaves that ``live_cols`` does not
+    name and at which a moment of either leaf is not exactly zero (NaN
+    counts as nonzero)."""
     off = functools.reduce(jnp.logical_or, [
         jnp.any(a != 0, axis=(0, 2)) for a in w_ih_leaves(opt_state)])  # [F]
-    return ~jnp.any(off.at[live_cols].set(
+    return off.at[live_cols].set(
         False, unique_indices=True, indices_are_sorted=True,
-        mode="promise_in_bounds"))
+        mode="promise_in_bounds")
+
+
+def moments_off_table_are_zero(opt_state, live_cols) -> jax.Array:
+    """Whether no row of the w_ih leaves that ``live_cols`` does not name
+    carries a moment (:func:`stale_rows` is 0).  Adam's step on a row whose
+    gradient and moments are zero is zero, so while this holds and the
+    gradient lives on the table, Adam on the table's rows IS Adam."""
+    return ~jnp.any(_moments_off_table(opt_state, live_cols))
+
+
+def stale_rows(opt_state, live_cols) -> jax.Array:
+    """How many of the F rows of a w_ih leaf are stale: not named by
+    ``live_cols`` and yet carrying a nonzero moment, in ``mu`` or ``nu`` of
+    either leaf (a row counts once).  They are what another corpus's table
+    left behind, and while there is one the compact superstep runs Adam
+    over all F rows."""
+    return jnp.sum(_moments_off_table(opt_state, live_cols), dtype=jnp.int32)
 
 
 @dataclasses.dataclass
@@ -161,6 +176,10 @@ class Trainer:
         # recent train_epoch's dispatches: profile_epoch lowers exactly
         # this to name the trace's operations.
         self._dispatched: tuple | None = None
+        # Whether stage_dataset has run, and its last call's compact table
+        # (host copy; None for a feed without one): the stage span's tags.
+        self._staged_before = False
+        self._staged_table: np.ndarray | None = None
         # The table of the epoch fit(profile_dir=...) ran through
         # profile_epoch, for the caller to print or write.
         self.last_profile: dict | None = None
@@ -373,7 +392,7 @@ class Trainer:
             rows_ok = moments_off_table_are_zero(state.opt_state, live_cols)
             whole = only_w_ih(state.params), only_w_ih(state.opt_state)
 
-            @jax.named_scope(scopes.OPTIMIZER)
+            @jax.named_scope(scopes.OFF_TABLE)
             def off_table_step(_, leaves):
                 params, opt_state = leaves
                 updates, opt_state = self.tx.update(
@@ -478,7 +497,7 @@ class Trainer:
         self._train_step = jax.jit(train_step, donate_argnums=0)
         self._train_step_indexed = jax.jit(train_step_indexed, donate_argnums=0)
         self._superstep = jax.jit(train_superstep, donate_argnums=0)
-        self._moments_off_table_are_zero = jax.jit(moments_off_table_are_zero)
+        self._stale_rows = jax.jit(stale_rows)
         self._accum_superstep = jax.jit(train_accum_superstep, donate_argnums=0)
         self._eval_step = jax.jit(eval_step)
         self._eval_step_indexed = jax.jit(eval_step_indexed)
@@ -518,8 +537,13 @@ class Trainer:
         self._m_optimizer_rows = obs_metrics.REGISTRY.gauge(
             "deeprest_train_optimizer_rows",
             "rows of each layer-0 input weight that the last epoch's Adam "
-            "steps on a staged sparse corpus wrote (updated), of F (total)",
+            "steps on a staged sparse corpus wrote (updated), of F (total); "
+            "stale: rows off the staged table that carried a nonzero moment "
+            "when the epoch began (counted on a compact base only)",
             labelnames=("kind",))
+        self._m_stage_seconds = obs_metrics.REGISTRY.gauge(
+            "deeprest_train_last_stage_seconds",
+            "host seconds of the last stage_dataset call")
         self._m_collective_bytes = obs_metrics.REGISTRY.gauge(
             "deeprest_train_collective_bytes",
             "bytes a train step of the compiled superstep hands to its "
@@ -558,7 +582,7 @@ class Trainer:
         sizes = []
         for fn in (self._train_step, self._train_step_indexed,
                    self._superstep, self._accum_superstep,
-                   self._moments_off_table_are_zero, self._eval_step,
+                   self._stale_rows, self._eval_step,
                    self._eval_step_indexed, self._predict_step,
                    self._pin_state):
             probe = getattr(fn, "_cache_size", None)
@@ -586,20 +610,25 @@ class Trainer:
             self._m_collective_bytes.set(n, op=op)
         self._collectives_published = True
 
-    def _publish_optimizer_rows(self, x_base, rowwise=None) -> None:
+    def _publish_optimizer_rows(self, x_base, stale=None) -> None:
         """The epoch's ``deeprest_train_optimizer_rows``, for a staged
-        sparse corpus: ``rowwise`` is what the compact superstep's rule
-        read on the state the epoch began with (a device scalar, read
-        here, after the epoch; the superstep keeps it as it finds it), or
-        None where every step ran over all F rows."""
+        sparse corpus.  ``stale`` is :func:`stale_rows` of the state the
+        epoch began with on the base's table (a device scalar, read here,
+        after the epoch; the superstep keeps it as it finds it): 0 is the
+        compact superstep's rule holding, so ``updated`` is the table's
+        width then and F otherwise.  None where no table was consulted
+        (the per-step and accumulation paths, a base in its dense form):
+        every step ran over all F rows and ``stale`` is left as it was."""
         if not isinstance(x_base, SparseBase):
             return
         updated = x_base.capacity
-        if rowwise is not None:
+        if stale is not None:
             self._m_readbacks.inc(sink="optimizer_rows")
             # graftlint: disable=JX003 -- designed sink: one scalar an epoch, dispatched before its first chunk and read after its last
-            if bool(rowwise):
+            stale = int(stale)
+            if not stale:
                 updated = x_base.width
+            self._m_optimizer_rows.set(stale, kind="stale")
         self._m_optimizer_rows.set(updated, kind="updated")
         self._m_optimizer_rows.set(x_base.capacity, kind="total")
 
@@ -981,7 +1010,35 @@ class Trainer:
         For bf16 models ``x_base`` stages in bf16 — the model casts inputs
         there anyway, and it halves both HBM residency and the one-time
         transfer (885 MB for a month at F=10240).
+
+        A trainer stages again whenever its corpus moves (every refresh of
+        train/stream.py, a resumed run on another week): each call is one
+        span ``deeprest-trainer/train.stage``, tagged ``restage`` (this
+        trainer has staged before), ``width`` (the compact table's, else
+        the columns staged, 0 for nothing) and, from one table to the
+        next, the rows that ``left`` and ``entered`` it — the rows that
+        left are the ones the carried moments make stale
+        (:func:`stale_rows`); its host seconds are the gauge
+        ``deeprest_train_last_stage_seconds``.
         """
+        clock = obs_metrics.Stopwatch()
+        with obs_spans.RECORDER.span("train.stage",
+                                     "deeprest-trainer") as span:
+            before, self._staged_table = self._staged_table, None
+            staged = self._stage(bundle)
+            table = self._staged_table
+            tags = {"restage": self._staged_before,
+                    "width": (len(table) if table is not None else
+                              0 if staged is None else bundle.feature_dim)}
+            if before is not None and table is not None:
+                tags["left"] = int(np.setdiff1d(before, table).size)
+                tags["entered"] = int(np.setdiff1d(table, before).size)
+            span.tag(**tags)
+        self._staged_before = True
+        self._m_stage_seconds.set(clock.elapsed())
+        return staged
+
+    def _stage(self, bundle: DatasetBundle):
         cfg = self.config.train
         if cfg.device_data not in ("auto", "always", "off"):
             raise ValueError(
@@ -1061,6 +1118,7 @@ class Trainer:
         live = live_columns(cols, vals, mn, rg, capacity)
         table = (compact_table(live, capacity)
                  if self.mesh.shape["model"] == 1 else None)
+        self._staged_table = table
         contracted = capacity if table is None else len(table)
         for kind, n in (("live", len(live)), ("contracted", contracted),
                         ("total", capacity)):
@@ -1262,14 +1320,13 @@ class Trainer:
         # share the whole driver: only the compiled scan differs.
         superstep = (self._accum_superstep if cfg.grad_accum_windows > 1
                      else self._superstep)
-        # The rule the compact superstep applies in each dispatch, on the
-        # state this epoch begins with: dispatched here, read after the
-        # epoch for the gauge, waited on nowhere in between.
-        rowwise = None
+        # What the compact superstep's rule reads in each dispatch, as a
+        # count, on the state this epoch begins with: dispatched here, read
+        # after the epoch for the gauge, waited on nowhere in between.
+        stale = None
         if (superstep is self._superstep and live_cols_of(x_base) is not None
                 and w_ih_leaves(state.params)):
-            rowwise = self._moments_off_table_are_zero(state.opt_state,
-                                                       x_base.live)
+            stale = self._stale_rows(state.opt_state, x_base.live)
         measuring = self._warmed
         if measuring:
             self.throughput.start()
@@ -1329,7 +1386,7 @@ class Trainer:
         with phase("loss_readback"):
             epoch_losses = np.asarray(
                 jnp.concatenate(chunk_losses))[:num_steps - skip_steps]
-            self._publish_optimizer_rows(x_base, rowwise)
+            self._publish_optimizer_rows(x_base, stale)
         self._last_epoch_losses = epoch_losses
         return state, float(np.mean(epoch_losses, dtype=np.float64))
 

@@ -437,7 +437,7 @@ def test_the_cells_files_exist_and_say_what_the_issue_says():
     # runner is read as the `train` run it is)
     by_name = {m["name"]: m for m in bench["per_layer"]}
     for name in ("proj_columns_pct.train", "adam_rows_pct.train"):
-        assert by_name[name]["workloads"][-1] == cell["name"]
+        assert cell["name"] in by_name[name]["workloads"]  # later cells follow
     unlisted = [m["name"] for m in bench["per_layer"] if "workloads" not in m]
     assert len(unlisted) == 7
     assert all(_load("chipbench", "layer_metrics", name + ".json")["runners"]

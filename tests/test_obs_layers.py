@@ -32,6 +32,8 @@ RECORDED = os.path.join(REPO, "chipbench", "tests", "data",
                         "recorded_v5e.xplane.pb")
 BATCH, LOG_EVERY, SUPERSTEP = 8, 4, 3
 NAMES = frozenset(scopes.STEP_SCOPES + scopes.KERNELS)
+# a superstep on a base without a compact table has no off-table pass
+NO_TABLE = tuple(s for s in scopes.STEP_SCOPES if s != scopes.OFF_TABLE)
 
 
 def _staged_trainer(featurize: FeaturizeConfig):
@@ -90,8 +92,9 @@ def test_scope_map_of_the_compiled_superstep_holds_every_scope(tiny):
     assert profiler.module_name(text) == "jit_train_superstep"
     table = profiler.scope_table(text, NAMES)
     found = set(table.values())
-    for scope in scopes.STEP_SCOPES:
+    for scope in NO_TABLE:
         assert (scope, "fwd") in found, scope
+    assert (scopes.OFF_TABLE, "fwd") not in found
     for scope in ("mask", "in_proj", "recurrence", "mixing", "heads", "loss"):
         assert (scope, "bwd") in found, scope
     # Of the instructions JAX named (the compiler's own layout copies
@@ -310,12 +313,18 @@ def test_the_compact_superstep_carries_every_scope(tiny_compact):
     trainer, bundle = tiny_compact["trainer"], tiny_compact["bundle"]
     starts, weights, _ = trainer._epoch_plan(
         bundle.num_train_windows, np.random.default_rng(0), SUPERSTEP)
-    text = trainer._superstep.lower(
+    lowered = trainer._superstep.lower(
         tiny_compact["state"], *tiny_compact["staged"],
-        *stage_plan(trainer.mesh, starts, weights), 0).compile().as_text()
+        *stage_plan(trainer.mesh, starts, weights), 0)
+    text = lowered.compile().as_text()
     found = set(profiler.scope_table(text, NAMES).values())
-    for scope in scopes.STEP_SCOPES:
+    for scope in NO_TABLE:
         assert (scope, "fwd") in found, scope
+    # The pass over the whole leaves has its own name (ISSUE 33).  Held in
+    # the lowered text: a name is metadata and no part of the compile
+    # cache's key, so an executable cached by an older checkout comes back
+    # under the names it was compiled with.
+    assert f"/{scopes.OFF_TABLE}/" in lowered.as_text(debug_info=True)
     for scope in ("mask", "in_proj", "recurrence", "mixing", "heads", "loss"):
         assert (scope, "bwd") in found, scope
 
@@ -426,7 +435,7 @@ def test_profile_epoch_reads_the_trace_it_opens(tiny, tmp_path):
     assert os.path.exists(table["trace"])
     assert table["steps"] == len(trainer._last_epoch_losses) > 0
     found = {r["scope"] for r in table["rows"]}
-    assert found >= set(scopes.STEP_SCOPES) | {profiler.OTHER}
+    assert found >= set(NO_TABLE) | {profiler.OTHER}
     assert [r["pass"] for r in table["rows"]
             if r["scope"] == profiler.OTHER] == ["-"]      # one row
     assert set(table["phases"]) == set(EPOCH_PHASES)
@@ -460,7 +469,7 @@ def test_profile_epoch_lowers_what_the_per_step_driver_dispatched(tmp_path):
     assert profiler.module_name(
         trainer._dispatched_program_text(state)) == "jit_train_step"
     found = {r["scope"] for r in table["rows"]}
-    assert found >= set(scopes.STEP_SCOPES) - {"gather", "densify"}
+    assert found >= set(NO_TABLE) - {"gather", "densify"}
     assert table["phases"]["plan_build"] == 0.0 < table["phases"]["dispatch"]
 
 
@@ -485,6 +494,6 @@ def test_train_profile_dir_writes_layers_json(tmp_path, capsys):
     with open(os.path.join(out, "layers.json")) as fh:
         table = json.load(fh)
     rows = {r["scope"] for r in table["rows"]}
-    assert rows >= set(scopes.STEP_SCOPES) - {"densify"}    # a dense corpus
+    assert rows >= set(NO_TABLE) - {"densify"}    # a dense corpus
     assert set(table["phases"]) == set(EPOCH_PHASES)
     assert os.path.exists(table["trace"])

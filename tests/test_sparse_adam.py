@@ -149,13 +149,11 @@ def test_rowwise_superstep_is_the_per_step_dense_pass(mesh_config):
         return trainer.init_state(trainer.sample_input(bundle), seed=1)
 
     init = _leaves(fresh())
-    assert bool(trainer._moments_off_table_are_zero(fresh().opt_state,
-                                                    staged[0].live))
+    assert int(trainer._stale_rows(fresh().opt_state, staged[0].live)) == 0
     plan = _plan(trainer, bundle, 7)
     got, got_losses = _through_superstep(trainer, fresh(), staged, plan)
     want, want_losses = _through_per_step(trainer, fresh(), staged, plan)
-    assert bool(trainer._moments_off_table_are_zero(got.opt_state,
-                                                    staged[0].live))
+    assert int(trainer._stale_rows(got.opt_state, staged[0].live)) == 0
     assert int(got.step) == int(want.step) == 7 and got_losses[7] == 0.0
     if mesh is not None:
         # the take and the put stay on each shard's own experts: what the
@@ -203,10 +201,8 @@ def _two_corpora():
                                   _plan(trainer, bundle_a, 3))[0]
 
     state = after_corpus_a()
-    assert bool(trainer._moments_off_table_are_zero(state.opt_state,
-                                                    staged_a[0].live))
-    assert not bool(trainer._moments_off_table_are_zero(state.opt_state,
-                                                        staged_b[0].live))
+    assert int(trainer._stale_rows(state.opt_state, staged_a[0].live)) == 0
+    assert int(trainer._stale_rows(state.opt_state, staged_b[0].live)) > 0
     return (trainer, (bundle_a, staged_a), (bundle_b, staged_b),
             after_corpus_a, np.setdiff1d(table_a, table_b))
 
