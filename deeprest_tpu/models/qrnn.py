@@ -164,6 +164,25 @@ def put_columns(a: jax.Array, live_cols: jax.Array, rows: jax.Array,
     return _over_experts(put, mesh)(a, rows, live_cols)
 
 
+def put_rows(a: jax.Array, cols: jax.Array, rows: jax.Array) -> jax.Array:
+    """``a`` with ``rows[:, i]`` at ``a[:, cols[i]]``, one ``[E, 1, 3H]``
+    slab at a time in a loop: for a few rows of a donated or loop-carried
+    ``a``.  On a TPU v5e :func:`put_columns`' scatter costs a pass over the
+    whole leaf however few rows it writes (1.95 ms for the 629 MB of
+    ``[40, 10240, 384]``, for 1,280 rows as for 10,240), a
+    ``dynamic_update_slice`` the slab it writes (under 5 us; both:
+    PERF.md section 6, PR 34), so below some 400 rows this is the cheaper
+    put.  It promises nothing about ``cols`` (a later row wins) and, the
+    slab being whole along the experts, needs no ``shard_map`` where they
+    are sharded."""
+    def put(i, a):
+        return jax.lax.dynamic_update_slice_in_dim(
+            a, jax.lax.dynamic_slice_in_dim(rows, i, 1, axis=1), cols[i],
+            axis=1)
+
+    return jax.lax.fori_loop(0, cols.shape[0], put, a)
+
+
 class QuantileGRU(nn.Module):
     """Multi-task quantile GRU.
 

@@ -20,7 +20,8 @@ HEADS = "heads"             # the quantile heads (models/qrnn.py)
 LOSS = "loss"               # ops/quantile.py
 OPTIMIZER = "optimizer"     # tx.update + apply_updates (train/trainer.py)
 OFF_TABLE = "off_table"     # the compact superstep's zero-gradient Adam pass
-                            # over the whole w_ih leaves (train/trainer.py)
+                            # over the stale rows of the w_ih leaves, by
+                            # chunks or, past a bound, whole (train/trainer.py)
 STEP_SCOPES = (GATHER, DENSIFY, MASK, IN_PROJ, RECURRENCE, DROPOUT, MIXING,
                HEADS, LOSS, OPTIMIZER, OFF_TABLE)
 

@@ -28,7 +28,7 @@ import optax
 
 from deeprest_tpu.config import Config
 from deeprest_tpu.models.qrnn import (
-    MASKED_PARAM_NAMES, QuantileGRU, put_columns, take_columns,
+    MASKED_PARAM_NAMES, QuantileGRU, put_columns, put_rows, take_columns,
 )
 from deeprest_tpu.obs import metrics as obs_metrics
 from deeprest_tpu.obs import spans as obs_spans
@@ -140,9 +140,77 @@ def stale_rows(opt_state, live_cols) -> jax.Array:
     """How many of the F rows of a w_ih leaf are stale: not named by
     ``live_cols`` and yet carrying a nonzero moment, in ``mu`` or ``nu`` of
     either leaf (a row counts once).  They are what another corpus's table
-    left behind, and while there is one the compact superstep runs Adam
-    over all F rows."""
+    left behind, and they are the rows the compact superstep's off-table
+    pass steps (:func:`stale_chunks`)."""
     return jnp.sum(_moments_off_table(opt_state, live_cols), dtype=jnp.int32)
+
+
+# Rows of a leaf that one trip of the off-table pass takes, steps and puts
+# back.  What a trip costs is its rows' (1.93 ms for 64, 3.95 for 128, at
+# two steps; my chip runs, PR 34), so a chunk is sized by what its pad rows
+# waste (at most 63 rows, 2.6 ms of a 290 ms dispatch) and by the six
+# ``[40, 64, 384]`` arrays of a trip staying in VMEM, where a step on them
+# is two fusions of 7 us.  A multiple of the float32 tile's 8 rows.
+_CHUNK = 64
+
+
+def _chunks(rows):
+    """How many chunks hold ``rows`` rows (an int or a traced count)."""
+    return -(-rows // _CHUNK)
+
+
+def off_table_bound(capacity: int, width: int, steps: int) -> int:
+    """The most stale rows that the off-table pass visits row by row; a
+    state with more gets the pass over all ``capacity`` rows.  From the
+    shapes alone (F, the table's width, the steps of a dispatch):
+
+    a stale row costs a dispatch its take and put, 27 steps' worth of
+    streaming it through the all-rows pass, and then its steps at a fifth
+    of that pass's cost a row (its chunk is in VMEM); the all-rows pass
+    costs every row its steps and nothing else.  So row by row wins while
+    ``count * (27 + steps / 5) < capacity * steps``.  Read on a TPU v5e at
+    E = 40, H = 128, F = 10,240 (my chip runs, PR 34): 30.2 us a row to
+    take and put, 0.23 us a row and step, against 11.45 ms a step over all
+    rows (1.12 us a row and step).  A dispatch of 50 steps with 8,591
+    stale rows took 643.9 ms row by row and 860.2 over all rows (290.2 with
+    64 stale rows: the crossover, 13,800, is past F); one of 2 real steps
+    290.4 and 52.0 (30.9 with 64: the crossover is 750).  Never more than
+    leaves a chunk of dead rows without a moment to pad the last trip with
+    (:func:`stale_chunks`): a state in which every row carries a moment
+    takes the all-rows pass whatever the steps.
+    """
+    crossover = 5 * capacity * steps // (steps + 5 * 27)
+    return max(0, min(crossover, capacity - width - _CHUNK))
+
+
+def rows_visited(capacity: int, width: int, steps: int, stale: int) -> int:
+    """The rows of a w_ih leaf that a dispatch of the compact superstep
+    writes for a state with ``stale`` stale rows: the table's, and the
+    stale rows to the chunk, or all of them past :func:`off_table_bound`."""
+    if stale > off_table_bound(capacity, width, steps):
+        return capacity
+    return width + _chunks(stale) * _CHUNK
+
+
+def stale_chunks(off: jax.Array, live_cols: jax.Array, bound: int):
+    """``(rows, count)``: the ordered list of the stale rows ``off`` marks
+    (:func:`_moments_off_table`), as many chunks of it as ``bound`` rows
+    fill, and how many ``off`` marks in all.  The
+    list is filled up to a whole chunk with the lowest dead rows that carry
+    no moment, whose zero-gradient step is exactly nothing, merged in
+    order: every chunk of it that holds a stale row is sorted, without
+    repeats and in bounds, which is what :func:`take_columns` is promised,
+    and names no row of the table.  Entries past that are ``F`` and for no
+    one to read.  While ``count`` is at most
+    :func:`off_table_bound`, the dead rows suffice."""
+    f = off.shape[0]
+    count = jnp.sum(off, dtype=jnp.int32)
+    dead = (~off).at[live_cols].set(
+        False, unique_indices=True, indices_are_sorted=True,
+        mode="promise_in_bounds")
+    pad = dead & (jnp.cumsum(dead, dtype=jnp.int32) <= -count % _CHUNK)
+    rows = jnp.sort(jnp.where(off | pad, jnp.arange(f, dtype=jnp.int32), f))
+    return rows[:_chunks(max(bound, 1)) * _CHUNK], count
 
 
 @dataclasses.dataclass
@@ -379,18 +447,33 @@ class Trainer:
 
             # Off the table the gradient is exactly zero, so what a row
             # there does in a dispatch depends on its moments, the count
-            # and the number of real steps alone.  Where the moments are
-            # zero there (a state from init_state, or one trained on this
-            # table only) it does nothing, and keeps that true.  Moments
-            # off the table (another corpus's, a dense-form run's) still
-            # move their rows: that many zero-gradient updates of the six
-            # whole leaves, with the counts the scan used, before the
-            # carried rows go on top.  The rule is the loop's trip count
-            # and not a cond round it (a conditional's identity branch
-            # copies the six leaves each dispatch), nor a second step in
-            # the program (5 s more to load it from the cache).
+            # and the number of real steps alone.  Where its moments are
+            # zero (every such row of a state from init_state, or of one
+            # trained on this table only) it does nothing, and keeps that
+            # true.  A stale row (moments another corpus's table left, a
+            # dense-form run's) still moves: that many zero-gradient
+            # updates, with the counts the scan used, before the carried
+            # rows go on top.  So the pass visits the stale rows, a chunk
+            # a trip: taken from the six whole leaves as the table's rows
+            # were (stale_chunks keeps take_columns' promises), stepped,
+            # put back a row at a time (put_rows: a scatter would pass
+            # over the whole leaf every trip).  A state in which they are
+            # most of F (past off_table_bound) gets the updates on the
+            # whole leaves instead, which costs a row less.  Either rule is
+            # a loop's trip count and not a cond round it (a conditional's
+            # identity branch copies the six leaves each dispatch), nor a
+            # second step in the program (5 s more to load it from the
+            # cache).
             rows_ok = moments_off_table_are_zero(state.opt_state, live_cols)
             whole = only_w_ih(state.params), only_w_ih(state.opt_state)
+            real_steps = rows.step - state.step
+            bound = off_table_bound(x_base.capacity, live_cols.shape[0],
+                                    starts_c.shape[0])
+            stale, count = stale_chunks(
+                _moments_off_table(state.opt_state, live_cols), live_cols,
+                bound)
+            count = jnp.where(rows_ok, 0, count)
+            by_row = count <= bound
 
             @jax.named_scope(scopes.OFF_TABLE)
             def off_table_step(_, leaves):
@@ -399,9 +482,23 @@ class Trainer:
                     jax.tree.map(jnp.zeros_like, params), opt_state)
                 return optax.apply_updates(params, updates), opt_state
 
+            @jax.named_scope(scopes.OFF_TABLE)
+            def off_table_chunk(i, leaves):
+                at = jax.lax.dynamic_slice_in_dim(stale, i * _CHUNK, _CHUNK)
+                chunk = jax.lax.fori_loop(
+                    0, real_steps, off_table_step,
+                    take_w_ih(leaves, at, self.mesh))
+                # every chunk steps from the counts the dispatch began with
+                return jax.tree_util.tree_map_with_path(
+                    lambda path, a, new: (put_rows(a, at, new)
+                                          if _is_w_ih(path) else a),
+                    leaves, chunk)
+
+            whole = jax.lax.fori_loop(
+                0, jnp.where(by_row, _chunks(count), 0),
+                off_table_chunk, whole)
             params, opt_state = jax.lax.fori_loop(
-                0, jnp.where(rows_ok, 0, rows.step - state.step),
-                off_table_step, whole)
+                0, jnp.where(by_row, 0, real_steps), off_table_step, whole)
             return pin_state(rows.replace(
                 params=put_w_ih(rows.params, params, live_cols, self.mesh),
                 opt_state=put_w_ih(rows.opt_state, opt_state, live_cols,
@@ -536,10 +633,11 @@ class Trainer:
             labelnames=("kind",))
         self._m_optimizer_rows = obs_metrics.REGISTRY.gauge(
             "deeprest_train_optimizer_rows",
-            "rows of each layer-0 input weight that the last epoch's Adam "
-            "steps on a staged sparse corpus wrote (updated), of F (total); "
-            "stale: rows off the staged table that carried a nonzero moment "
-            "when the epoch began (counted on a compact base only)",
+            "rows of each layer-0 input weight over which the last epoch's "
+            "steps on a staged sparse corpus were Adam (updated), and which "
+            "its dispatches wrote (visited), of F (total); stale: rows off "
+            "the staged table that carried a nonzero moment when the epoch "
+            "began (counted on a compact base only)",
             labelnames=("kind",))
         self._m_stage_seconds = obs_metrics.REGISTRY.gauge(
             "deeprest_train_last_stage_seconds",
@@ -610,26 +708,32 @@ class Trainer:
             self._m_collective_bytes.set(n, op=op)
         self._collectives_published = True
 
-    def _publish_optimizer_rows(self, x_base, stale=None) -> None:
+    def _publish_optimizer_rows(self, x_base, stale=None, steps=0) -> None:
         """The epoch's ``deeprest_train_optimizer_rows``, for a staged
         sparse corpus.  ``stale`` is :func:`stale_rows` of the state the
         epoch began with on the base's table (a device scalar, read here,
-        after the epoch; the superstep keeps it as it finds it): 0 is the
-        compact superstep's rule holding, so ``updated`` is the table's
-        width then and F otherwise.  None where no table was consulted
-        (the per-step and accumulation paths, a base in its dense form):
-        every step ran over all F rows and ``stale`` is left as it was."""
+        after the epoch; the superstep keeps it as it finds it), ``steps``
+        the steps of a dispatch.  With no stale row the step is Adam on
+        the table's rows: ``updated`` and ``visited`` are its width.  With
+        one, it is Adam over all F (``updated``), of which the dispatches
+        wrote :func:`rows_visited` (``visited``).  ``stale`` None where no
+        table was consulted (the per-step and accumulation paths, a base
+        in its dense form): every step ran over and wrote all F rows, and
+        ``stale`` is left as it was."""
         if not isinstance(x_base, SparseBase):
             return
-        updated = x_base.capacity
+        updated = visited = x_base.capacity
         if stale is not None:
             self._m_readbacks.inc(sink="optimizer_rows")
             # graftlint: disable=JX003 -- designed sink: one scalar an epoch, dispatched before its first chunk and read after its last
             stale = int(stale)
+            visited = rows_visited(x_base.capacity, x_base.width, steps,
+                                   stale)
             if not stale:
                 updated = x_base.width
             self._m_optimizer_rows.set(stale, kind="stale")
         self._m_optimizer_rows.set(updated, kind="updated")
+        self._m_optimizer_rows.set(visited, kind="visited")
         self._m_optimizer_rows.set(x_base.capacity, kind="total")
 
     # -- preemption-safe snapshots (ROADMAP item 7, dynamic half) ------
@@ -1386,7 +1490,7 @@ class Trainer:
         with phase("loss_readback"):
             epoch_losses = np.asarray(
                 jnp.concatenate(chunk_losses))[:num_steps - skip_steps]
-            self._publish_optimizer_rows(x_base, stale)
+            self._publish_optimizer_rows(x_base, stale, s)
         self._last_epoch_losses = epoch_losses
         return state, float(np.mean(epoch_losses, dtype=np.float64))
 

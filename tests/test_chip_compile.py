@@ -332,9 +332,13 @@ def test_compact_superstep_updates_the_leaves_in_place(one_chip):
     index rows of the ``[E*F, 3H]`` view, so the compiler is asked for no
     other layout of a leaf and copies none (the parent: a gather and a
     scatter over dimension 1 of each, twelve whole-leaf copies a dispatch,
-    3.88 GB of temporaries, 8.34 GB needed, 18.9 MB of code).  The second
-    ``while`` is the all-rows case, whose trip count is 0 where the moments
-    are zero off the table."""
+    3.88 GB of temporaries, 8.34 GB needed, 18.9 MB of code).  The other
+    nine ``while`` s are the off-table pass (ISSUE 34: 18.8 MB of code for
+    15.9): the loop over chunks of stale rows, inside it the steps' loop
+    and six loops that put a chunk back a row at a time, and the all-rows
+    loop past the bound; each reads and writes the carried leaves in
+    place, so their trip counts, 0 where the moments are zero off the
+    table, are all they cost."""
     compiled = _train_step_lowered(one_chip, F_10K, "compact",
                                    superstep=True).compile()
     text = compiled.as_text()
@@ -346,11 +350,11 @@ def test_compact_superstep_updates_the_leaves_in_place(one_chip):
           f"{mem.generated_code_size_in_bytes / 1e6:.1f} MB; whole-leaf "
           f"copy operations {_whole_leaf_copies(text)}")
     assert _kernel_calls(compiled) == 4
-    assert len(re.findall(r" while[(]", text)) == 2
+    assert len(re.findall(r" while[(]", text)) == 10
     assert _whole_leaf_copies(text) == 0
     assert mem.temp_size_in_bytes < 1.0e9, mem
     assert _need(mem) < 5.5e9, mem
-    assert mem.generated_code_size_in_bytes <= 18.9e6, mem
+    assert mem.generated_code_size_in_bytes <= 20e6, mem
 
 
 @pytest.mark.slow       # by hand, as the one-chip compact superstep above

@@ -5,11 +5,15 @@ leaves and of their moments once a dispatch; they ride the scan in the
 leaves' place, each step differentiating with respect to them and running
 Adam on them; after the scan they are put back.  Every other row has a zero
 gradient, so in a state whose moments are zero off the table it does not
-move; in any other state a loop after the scan gives the six whole leaves
-that many zero-gradient updates before the carried rows go on top (its trip
-count is 0 where the rule holds).  Held here, on the CPU at toy widths in
-float32: the row-wise pass against the per-step dense pass on the same base;
-a state with moments off the table, with trailing padded steps too; the
+move; in any other state a loop after the scan visits the stale rows (those
+off the table that carry a moment), a chunk a trip: taken from the six whole
+leaves as the table's rows were, given that many zero-gradient updates, put
+back, before the carried rows go on top (ISSUE 34); past a bound computed
+from the shapes the updates run on the six whole leaves instead.  Both
+loops' trip counts are 0 where the rule holds.  Held here, on the CPU at toy
+widths in float32: the row-wise pass against the per-step dense pass on the
+same base; a state with moments off the table, with trailing padded steps
+too, and by its number of stale rows round the chunk and the bound; the
 surface the benchmark drives; the traced program's shapes; the take and the
 put over ``(E*F)`` rows against plain indexing; the untouched feeds'
 StableHLO; and a ``data`` mesh.
@@ -40,19 +44,21 @@ from test_live_columns import B, E, F, H, W, _bundle, _corpus, _trainer
 
 from deeprest_tpu.config import MeshConfig
 from deeprest_tpu.models.qrnn import (
-    MASKED_PARAM_NAMES, put_columns, take_columns,
+    MASKED_PARAM_NAMES, put_columns, put_rows, take_columns,
 )
 from deeprest_tpu.obs import metrics
 from deeprest_tpu.obs.profiler import collective_bytes
 from deeprest_tpu.parallel.distributed import stage_plan, stage_sparse_base
 from deeprest_tpu.parallel.mesh import make_mesh
+from deeprest_tpu.train import trainer as trainer_module
 
 S = 4                                   # steps a dispatch
 
 
 def _plan(trainer, bundle, steps: int, seed: int = 5):
     """A staged ``[C, S, B]`` plan of ``steps`` real steps, the last chunk
-    padded with zero-weight steps."""
+    padded with zero-weight steps (S: the trainer's steps a dispatch)."""
+    S = trainer.config.train.steps_per_superstep
     chunks = -(-steps // S)
     rng = np.random.default_rng(seed)
     starts = rng.integers(0, bundle.num_train_windows,
@@ -110,15 +116,15 @@ EXACT = "--xla_cpu_max_isa=SSE4_2" in os.environ.get("XLA_FLAGS", "")
 ULPS = 0 if EXACT else 1
 
 
-def _gauge():
+def _gauge(kinds=("updated", "total")):
     g = metrics.REGISTRY.get("deeprest_train_optimizer_rows")
-    return {k: g.value(kind=k) for k in ("updated", "total")}
+    return {k: g.value(kind=k) for k in kinds}
 
 
-def _setup(hot: int = 100, mesh=None):
+def _setup(hot: int = 100, mesh=None, steps: int = S):
     cols, vals, y, hot_cols = _corpus(hot)
     bundle = _bundle(cols, vals, y)
-    trainer = _trainer(mesh=mesh, steps_per_superstep=S)
+    trainer = _trainer(mesh=mesh, steps_per_superstep=steps)
     staged = trainer.stage_dataset(bundle)
     assert staged[0].width == 128 and len(hot_cols) <= 128
     return trainer, bundle, staged
@@ -262,6 +268,136 @@ def test_a_padded_dispatch_moves_rows_off_the_table_by_its_real_steps_only():
     assert (moved != before).any() and (moved != further).any()
 
 
+# -- (b'): by the number of stale rows, round the chunk and the bound ----------
+
+S_LONG = 8          # steps a dispatch at which the bound clears a chunk
+CHUNK = trainer_module._CHUNK
+BOUND = trainer_module.off_table_bound(F, 128, S_LONG)
+
+
+def _stale_rows_for(case, table) -> np.ndarray:
+    """The rows to make stale: ``case`` of them spread over the dead rows,
+    or for ``"neighbours"`` the lowest dead rows next to a row of the table,
+    three short of a chunk (the pad slots are then the lowest dead rows left,
+    which lie next to the table's own pad slots)."""
+    dead = np.setdiff1d(np.arange(F), table)
+    if case == "neighbours":
+        return np.intersect1d(
+            dead, np.concatenate([table - 1, table + 1]))[:CHUNK - 3]
+    return np.sort(np.random.default_rng(case).choice(dead, case,
+                                                      replace=False))
+
+
+def _with_moments_at(state, rows, seed: int = 11):
+    """``state`` with Adam moments of a real size at ``rows`` of both w_ih
+    leaves (``mu`` of either sign, ``nu`` positive), as a table that named
+    them would have left, placed as the leaves were."""
+    rng = np.random.default_rng(seed)
+    adam = state.opt_state[0]
+    mu, nu = dict(adam.mu), dict(adam.nu)
+    for name in MASKED_PARAM_NAMES:
+        for tree, draw in ((mu, lambda n: rng.standard_normal(n) * 1e-3),
+                           (nu, lambda n: rng.random(n) * 1e-6 + 1e-9)):
+            a = np.array(tree[name])
+            a[:, rows] = draw((E, len(rows), 3 * H)).astype(np.float32)
+            tree[name] = jax.device_put(a, tree[name].sharding)
+    return state.replace(opt_state=(adam._replace(mu=mu, nu=nu),
+                                    *state.opt_state[1:]))
+
+
+@pytest.mark.parametrize("case", [
+    1, CHUNK - 1, CHUNK, CHUNK + 1, BOUND, BOUND + 1, "neighbours"])
+def test_the_off_table_pass_by_its_number_of_stale_rows(case):
+    """Ten steps over two dispatches (the second: two real steps, six
+    padded) on a state with ``case`` stale rows, against the same steps one
+    dispatch each through the pass over all F rows: every leaf, so the
+    stale rows as Adam steps them, the table's rows, and the dead rows that
+    pad a chunk (which neighbour the table's and must not move).  A chunk
+    less one row, a chunk, a chunk and a row (two trips), the bound (the
+    last count row by row) and one past it (the all-rows loop)."""
+    assert CHUNK + 1 < BOUND < F - 128 - CHUNK
+    trainer, bundle, staged = _setup(steps=S_LONG)
+    table = np.asarray(staged[0].live)
+    rows = _stale_rows_for(case, table)
+    count = len(rows)
+    assert count == (CHUNK - 3 if case == "neighbours" else case)
+
+    def stale_state():
+        return _with_moments_at(
+            trainer.init_state(trainer.sample_input(bundle), seed=1), rows)
+
+    assert int(trainer._stale_rows(stale_state().opt_state,
+                                   staged[0].live)) == count
+    plan = _plan(trainer, bundle, S_LONG + 2, seed=8)
+    got, got_losses = _through_superstep(trainer, stale_state(), staged, plan)
+    want, want_losses = _through_per_step(trainer, stale_state(), staged,
+                                          plan)
+    np.testing.assert_array_equal(got_losses[:S_LONG + 2], want_losses)
+    # ten steps contract one unit further apart than the seven above
+    _assert_states_equal(got, want, 2 * ULPS)
+    # the stale rows moved, every one; a dead row without a moment did not
+    before, after = _leaves(stale_state()), _leaves(got)
+    untouched = np.setdiff1d(np.arange(F), np.concatenate([table, rows]))
+    for name in MASKED_PARAM_NAMES:
+        key = f".params['{name}']"
+        assert (after[key][:, rows] != before[key][:, rows]).any(axis=(0, 2)
+                                                                 ).all()
+        np.testing.assert_array_equal(after[key][:, untouched],
+                                      before[key][:, untouched])
+    # the gauge, from a whole epoch: `updated` and `stale` as ever, and the
+    # rows the dispatches wrote
+    trainer.train_epoch(stale_state(), bundle, np.random.default_rng(0),
+                        staged=staged)
+    visited = (F if count > BOUND else 128 + -(-count // CHUNK) * CHUNK)
+    assert _gauge(("stale", "updated", "visited", "total")) == {
+        "stale": count, "updated": F, "visited": visited, "total": F}
+    assert visited == trainer_module.rows_visited(F, 128, S_LONG, count)
+
+
+@pytest.mark.parametrize(
+    "mesh_config", [MeshConfig(data=2), MeshConfig(data=2, expert=2)],
+    ids=["data2", "data2-expert2"])
+def test_the_off_table_pass_under_a_mesh(mesh_config):
+    """A chunk and a row of stale rows under a mesh: with an ``expert``
+    axis the take indexes each shard's own ``(E/2 * F)`` rows and the put
+    writes each shard's own slabs.  Against the per-step pass to the
+    tolerance a mesh asks for (see the row-wise case above), the stale
+    rows having moved; and what the superstep hands its collectives is
+    still gradients, never a leaf or a chunk of one."""
+    trainer, bundle, staged = _setup(mesh=make_mesh(mesh_config),
+                                     steps=S_LONG)
+    table = np.asarray(staged[0].live)
+    rows = _stale_rows_for(CHUNK + 1, table)
+
+    def stale_state():
+        return _with_moments_at(
+            trainer.init_state(trainer.sample_input(bundle), seed=1), rows)
+
+    plan = _plan(trainer, bundle, S_LONG + 2, seed=8)
+    got, got_losses = _through_superstep(trainer, stale_state(), staged, plan)
+    want, want_losses = _through_per_step(trainer, stale_state(), staged,
+                                          plan)
+    moved = collective_bytes(trainer._superstep.lower(
+        got, *staged, *plan[2], 0).compile().as_text())
+    assert set(moved) <= {"all-reduce"}, moved
+    assert moved["all-reduce"] < 4 * E * F * 3 * H, moved
+    np.testing.assert_allclose(got_losses[:S_LONG + 2], want_losses,
+                               rtol=1e-6)
+    before, got, want = (_leaves(s) for s in (stale_state(), got, want))
+    for name, z in want.items():
+        np.testing.assert_allclose(got[name], z, rtol=2e-4, atol=1e-7,
+                                   err_msg=name)
+        if any(k in name for k in MASKED_PARAM_NAMES):
+            # off the table no gradient enters: the same steps in both
+            # passes (to what an FMA contracts), whatever the mesh did to
+            # the table's rows
+            dead = np.setdiff1d(np.arange(F), table)
+            np.testing.assert_allclose(
+                got[name][:, dead], z[:, dead], rtol=0.0, err_msg=name,
+                atol=2 * float(np.spacing(np.abs(z).max())))
+            assert (got[name][:, rows] != before[name][:, rows]).any()
+
+
 # -- (c): what the benchmark drives -------------------------------------------
 
 
@@ -315,25 +451,31 @@ def _floats_of_shape(variables, shapes) -> bool:
                and v.aval.dtype == jnp.float32 for v in variables)
 
 
-def test_the_rows_ride_the_scan_and_nothing_of_a_leafs_shape_is_inside_it():
-    """One trace of the compact superstep.  Before the scan six gathers of
-    ``(E*F)`` rows (two leaves, their ``mu`` and ``nu``); inside its body
-    no equation with an ``[E, F, 3H]`` (or ``[E*F, 3H]``) float operand or
-    result, and no such operand of the scan itself: the step names the
-    carried ``[E, U_pad, 3H]`` rows only; after it the loop over the six
-    whole leaves for a state with moments off the table, then the six
-    scatters that put the rows back, and nothing else that makes a leaf."""
+def _compact_program():
+    """One trace of the compact superstep: its equations, and the widths."""
     trainer, bundle, staged = _setup()
     state = trainer.init_state(trainer.sample_input(bundle), seed=1)
     traced = jax.make_jaxpr(trainer._superstep)(
         state, *staged, *_plan(trainer, bundle, 2)[2], 0)
     (program,) = traced.jaxpr.eqns
-    eqns = program.params["jaxpr"].jaxpr.eqns
+    return program.params["jaxpr"].jaxpr.eqns, staged[0].width
+
+
+def test_the_rows_ride_the_scan_and_nothing_of_a_leafs_shape_is_inside_it():
+    """One trace of the compact superstep.  Before the scan six gathers of
+    ``(E*F)`` rows (two leaves, their ``mu`` and ``nu``); inside its body
+    no equation with an ``[E, F, 3H]`` (or ``[E*F, 3H]``) float operand or
+    result, and no such operand of the scan itself: the step names the
+    carried ``[E, U_pad, 3H]`` rows only; after it the two loops over the
+    six whole leaves for a state with stale rows (a chunk of them a trip;
+    all rows past the bound), then the six scatters that put the rows
+    back, and nothing else that makes a leaf."""
+    eqns, width = _compact_program()
     names = [e.primitive.name for e in eqns]
-    assert names.count("scan") == 1 and names.count("while") == 1
-    scan_at, loop_at = names.index("scan"), names.index("while")
-    assert scan_at < loop_at
-    width = staged[0].width
+    assert names.count("scan") == 1 and names.count("while") == 2
+    scan_at, chunks_at = names.index("scan"), names.index("while")
+    loop_at = names.index("while", chunks_at + 1)
+    assert scan_at < chunks_at
     leaf, flat = (E, F, 3 * H), (E * F, 3 * H)
     rows, flat_rows = (E, width, 3 * H), (E * width, 3 * H)
     n = 3 * len(MASKED_PARAM_NAMES)
@@ -370,6 +512,50 @@ def test_the_rows_ride_the_scan_and_nothing_of_a_leafs_shape_is_inside_it():
     assert makers <= layout_only, makers
 
 
+def test_the_chunk_loop_names_a_leaf_only_to_take_and_put_its_rows():
+    """The first loop after the scan (ISSUE 34): a trip takes one chunk's
+    rows of the six carried leaves through their ``[E*F, 3H]`` views (six
+    gathers), steps them in a loop of its own whose equations are all
+    ``[E, CHUNK, 3H]`` or smaller, and puts them back a row at a time (six
+    loops of ``dynamic_update_slice``, ``scan`` s here: ``put_rows``).  Nothing else in the
+    body has a leaf's shape as operand or result: Adam's arithmetic never
+    sees a whole leaf here, and no scatter passes over one."""
+    eqns, _ = _compact_program()
+    chunk = trainer_module._CHUNK
+    leaf, flat = (E, F, 3 * H), (E * F, 3 * H)
+    rows, flat_rows = (E, chunk, 3 * H), (E * chunk, 3 * H)
+    n = 3 * len(MASKED_PARAM_NAMES)
+    loop = next(e for e in eqns if e.primitive.name == "while")
+    body = loop.params["body_jaxpr"].jaxpr
+    assert sum(_floats_of_shape([v], {leaf}) for v in body.outvars) == n
+    names = [e.primitive.name for e in body.eqns]
+    assert names.count("gather") == names.count("scan") == n
+    assert names.count("while") == 1
+    assert "scatter" not in {e.primitive.name for e in _eqns(body)}
+    takes, steps = names.index("gather"), names.index("while")
+    assert takes < steps
+    assert all(_floats_of_shape(e.invars[:1], {flat})
+               and _floats_of_shape(e.outvars, {flat_rows})
+               for e in body.eqns if e.primitive.name == "gather")
+    inner = body.eqns[steps]
+    puts = [e for e in body.eqns if e.primitive.name == "scan"]
+    assert not _floats_of_shape([*inner.invars, *inner.outvars],
+                                {leaf, flat})
+    stepped = [e for e in _eqns(inner.params["body_jaxpr"].jaxpr)
+               if _floats_of_shape(e.outvars, {rows})]
+    assert sum(e.primitive.name == "mul" for e in stepped) > n
+    for put in puts:
+        assert sum(_floats_of_shape([v], {leaf}) for v in put.outvars) == 1
+        assert {e.primitive.name for e in
+                _eqns(put.params["jaxpr"].jaxpr)
+                if _floats_of_shape([*e.invars, *e.outvars], {leaf})
+                } == {"dynamic_update_slice"}
+    touching = {e.primitive.name for e in _eqns(body)
+                if _floats_of_shape([*e.invars, *e.outvars], {leaf, flat})}
+    assert touching == {"reshape", "gather", "scan",
+                        "dynamic_update_slice"}, touching
+
+
 # -- (g): the take and the put over (E*F) rows ---------------------------------
 
 
@@ -398,6 +584,25 @@ def test_take_and_put_over_flat_rows_are_plain_indexing(f):
     # the 2-D mask keeps its plain form
     np.testing.assert_array_equal(
         take_columns(jnp.asarray(a[:, :, 0]), live), a[:, live, 0])
+
+
+def test_put_rows_is_plain_indexing_and_promises_nothing():
+    """``put_rows`` writes ``a[:, cols[i]] = rows[:, i]`` a row at a time:
+    the values of ``put_columns`` on a sorted table, and on one out of
+    order or with a repeat still plain assignment in order."""
+    rng = np.random.default_rng(3)
+    e, f, c = 3, 64, 24
+    a = rng.standard_normal((e, f, c)).astype(np.float32)
+    for cols in ([2, 5, 17, 40], [40, 2, 17, 5], [7, 9, 7, 63]):
+        cols = np.asarray(cols, np.int32)
+        new = rng.standard_normal((e, len(cols), c)).astype(np.float32)
+        want = a.copy()
+        for i, col in enumerate(cols):
+            want[:, col] = new[:, i]
+        np.testing.assert_array_equal(jax.jit(put_rows)(a, cols, new), want)
+    np.testing.assert_array_equal(
+        jax.jit(put_rows)(a, cols[:2], new[:, :2]),
+        jax.jit(put_columns)(a, cols[:2], new[:, :2]))
 
 
 # -- (e): the other feeds' supersteps are the parent's ------------------------
@@ -435,11 +640,12 @@ def _sparse_dense_form():
 # sha1 of the lowered superstep's StableHLO, from these very builders: the
 # two feeds without a table run against a checkout of a159714 (ISSUE 27's
 # parent) and unmoved since; the compact base (``_setup``: a table, the
-# rows riding the scan) as ISSUE 32 left it
+# rows riding the scan, the off-table pass by chunks of stale rows) as
+# ISSUE 34 left it
 PARENT_SHA1 = {
     "dense-feed": "0d7001e28a87175cec6d84c804c7eab4ef1e62b7",
     "sparse-dense-form": "088b19fac794d650f0639c88651a4697758deea4",
-    "sparse-compact": "9a642e4696a85223a2f31d8bedfebe1d8b25fc0d",
+    "sparse-compact": "f4785d4d6f2df5cab0a343b0fb10b9df5b9f2f08",
 }
 BUILDERS = dict(zip(PARENT_SHA1,
                     (_dense_feed, _sparse_dense_form, _setup)))
@@ -484,6 +690,10 @@ EXACT_CASES = {
         test_moments_off_the_table_move_their_rows_as_the_dense_pass_does,
     "padded-off-table":
         test_a_padded_dispatch_moves_rows_off_the_table_by_its_real_steps_only,
+    "two-chunks":
+        lambda: test_the_off_table_pass_by_its_number_of_stale_rows(CHUNK + 1),
+    "past-the-bound":
+        lambda: test_the_off_table_pass_by_its_number_of_stale_rows(BOUND + 1),
 }
 
 

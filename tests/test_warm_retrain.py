@@ -1,9 +1,9 @@
 """`endpoints-10k-warm` (ISSUE 33): a retrain from the last one's Adam state
 on a corpus whose live call paths moved, held on the CPU at toy widths to
 the plain reference ACROSS the restage, with the control that shows the
-check bite, the restage's executable count, the `stale` kind of the
-optimizer-rows gauge, the stage span, the pair generator and the
-`off_table` scope.
+check bite, the restage's executable count, the `stale` and `visited`
+kinds of the optimizer-rows gauge, the stage span, the pair generator and
+the `off_table` scope.
 
 On the chip the benchmark's cell `tenk-retrain-drift` makes comparison (i)
 at the configuration's own widths in bfloat16 (chipbench/limits/); here it
@@ -128,6 +128,7 @@ def _crossing(carried: int, skip_the_pass: bool = False):
             "reference": reference, "compiled": compiled,
             "executables": executables, "stale_before": fresh,
             "carrying": int(moment.sum()), "gauge": _gauge(),
+            "visited": _gauge(("visited",))["visited"],
             "tags": restage[1], "stage_seconds": restage[2],
             "retired": np.setdiff1d(_hot(pair["prior"]),
                                     _hot(pair["current"])).size}
@@ -226,6 +227,18 @@ def test_stale_rows_are_the_retired_rows_that_carry_a_moment(case):
     else:                                      # Adam over all F rows
         assert out["carrying"] > 0
         assert out["gauge"]["updated"] == out["gauge"]["total"] == F
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_visited_is_the_table_and_the_stale_rows_to_the_chunk(case):
+    """What the epoch's dispatches wrote of each w_ih leaf (ISSUE 34): the
+    table's rows, and one chunk for the few retired rows that carry a
+    moment, where ``updated`` still says F: the step IS Adam over all F
+    rows, the other rows being fixed points of it."""
+    out = _crossing(CASES[case])
+    chunk = trainer_module._CHUNK
+    assert 0 <= out["carrying"] <= chunk < F - 128
+    assert out["visited"] == 128 + (chunk if out["carrying"] else 0)
 
 
 # -- (v) the pair generator ---------------------------------------------------
