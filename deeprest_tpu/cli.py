@@ -424,8 +424,20 @@ def cmd_train(args) -> int:
         print(f"profiler trace and {path} written to {args.profile_dir}",
               flush=True)
 
+    # What came before the first steady step, once, when the first epoch
+    # of this process is done (obs/setup.py): seconds of init_state by
+    # phase, of staging, of each first dispatch, the compilations by
+    # program and phase, device memory, the superstep executable's bytes.
+    setup_reported = False
+
     def on_epoch(result, state):
+        nonlocal setup_reported
         report_profile()
+        if not setup_reported:
+            from deeprest_tpu.obs.setup import format_setup, setup_table
+
+            setup_reported = True
+            print(format_setup(setup_table()), flush=True)
         line = (f"epoch {result.epoch}: train {result.train_loss:.4f}"
                 + (f" test {result.test_loss:.4f}" if result.test_loss else ""))
         print(line, flush=True)

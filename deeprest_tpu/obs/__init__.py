@@ -1,6 +1,6 @@
 """deeprest_tpu/obs — spans, metrics, and profiling for the whole plane.
 
-One package, four surfaces (ISSUE 9), one helper (ISSUE 24):
+One package, four surfaces (ISSUE 9), two helpers (ISSUEs 24, 35):
 
 - :mod:`.spans` — ring-buffer span recorder with request-scoped trace ids
   propagated router → admission → replica → batcher → fused dispatch
@@ -8,8 +8,8 @@ One package, four surfaces (ISSUE 9), one helper (ISSUE 24):
   near-zero cost when disabled.
 - :mod:`.metrics` — counters/gauges/histograms registry rendered as
   Prometheus text at ``GET /metrics`` on the serving plane; the trainer /
-  stream side emits step time, superstep dispatch counts, compile-cache
-  sizes, ETL stall/lag, and readback counts into the same registry.
+  stream side emits step time, superstep dispatch counts, what set-up
+  cost, ETL stall/lag, and readback counts into the same registry.
 - :mod:`.profiler` — ``jax.profiler`` windows that are read back
   (``POST /v1/profile`` + ``deeprest profile``, ``train --profile-dir``):
   the device's time by the named scopes of the compiled step, its idle
@@ -17,6 +17,10 @@ One package, four surfaces (ISSUE 9), one helper (ISSUE 24):
 - :mod:`.phases` — ``PhaseClock``: the phases of a repeated unit of host
   work (the trainer's epoch) as spans and as a gauge of the last unit's
   seconds per phase.
+- :mod:`.setup` — what comes before the first steady step: the set-up
+  phase open on a thread, the process's one ``jax.monitoring`` listener
+  (every compilation by jitted program and by that phase, as counters and
+  as spans), and the set-up gauges as a table and as a line.
 - :mod:`.export` — spans as Jaeger-style JSON + span-derived busy-seconds
   as Prometheus range JSON, both consumed by the STANDARD ingest pipeline
   (data/ingest.py), so the plane's own traffic becomes a DeepRest corpus

@@ -11,7 +11,8 @@ each phase is one ``with`` line that
 - adds its seconds to the unit's tally, which is published when the unit
   finishes: a gauge holding the LAST finished unit's seconds per phase (a
   mean over the process would fold the first unit's one-off compiles in)
-  and a count of units.
+  and a count of units (none for a unit that runs once a process, as
+  ``Trainer.init_state`` does).
 
 A phase entered inside another (a log readback inside the dispatch loop)
 is timed exclusively: its seconds are taken off the enclosing phase's, so
@@ -30,7 +31,7 @@ from deeprest_tpu.obs.spans import RECORDER, current_context
 
 class PhaseClock:
     def __init__(self, name: str, component: str, phases: tuple[str, ...],
-                 last_seconds: Gauge, units_total: Counter):
+                 last_seconds: Gauge, units_total: Counter | None = None):
         self.name = name
         self.component = component
         self.phases = tuple(phases)
@@ -65,7 +66,8 @@ class PhaseClock:
             yield phase
         for name, value in seconds.items():
             self.last_seconds.set(value, phase=name)
-        self.units_total.inc()
+        if self.units_total is not None:
+            self.units_total.inc()
 
 
 __all__ = ["PhaseClock"]

@@ -139,7 +139,13 @@ _COLLECTIVE = re.compile(
 _CALLED = re.compile(
     r"\b(?:body|condition|to_apply|calls|true_computation|false_computation)"
     r"=%?([\w.\-]+)|\bbranch_computations=\{([^}]*)\}")
-_ARRAY = re.compile(r"\b(?:pred|[a-z]+?(\d+)\w*)\[([\d,]*)\]")
+_ARRAY = re.compile(
+    r"\b(?:pred|[a-z]+?(\d+)\w*)\[([\d,]*)\](?:\{([^}]*)\})?")
+_SPACE = re.compile(r"S\((\d+)\)")
+_OPERAND = re.compile(r"%?([\w.\-]+)\s*(?:,|$)")
+# the memory spaces a TPU layout names; an array with no `S(n)` is in HBM
+HBM = "hbm"
+_SPACES = {"1": "vmem"}
 
 
 def collective_kind(name: str) -> tuple[str, str] | None:
@@ -251,16 +257,29 @@ def fused_scopes(hlo_text: str, names) -> dict[str, tuple[str, ...]]:
     return out
 
 
-def _array_bytes(type_text: str) -> int:
-    """Bytes of every array a result type names: ``(f32[40,3]{0,1},
-    bf16[40,512,3]{..})`` -> 480 + 122,880."""
-    total = 0
-    for bits, dims in _ARRAY.findall(type_text):
+def _arrays(type_text: str):
+    """``(bytes, memory space)`` of every array a result type names:
+    ``(f32[40,3]{0,1}, bf16[40,512,3]{2,1,0:T(8,128)(2,1)S(1)})`` ->
+    ``(480, "hbm"), (122880, "vmem")``.  The space is what the layout
+    says: ``S(1)`` is VMEM, no ``S(n)`` HBM, another ``S(n)`` itself."""
+    for bits, dims, layout in _ARRAY.findall(type_text):
         n = 1
         for d in dims.split(","):
             n *= int(d) if d else 1
-        total += n * max(int(bits or 8) // 8, 1)
-    return total
+        space = _SPACE.search(layout)
+        yield (n * max(int(bits or 8) // 8, 1),
+               HBM if not space else _SPACES.get(space[1], space[0]))
+
+
+def _array_bytes(type_text: str) -> int:
+    """Bytes of every array a result type names."""
+    return sum(n for n, _ in _arrays(type_text))
+
+
+def _result_type(m, line: str) -> str:
+    """The result type of an instruction: between `` = `` and its opcode."""
+    head = line.split(" = ", 1)[1]
+    return head[:head.index(f" {m['opcode']}(")]
 
 
 def collective_bytes(hlo_text: str) -> dict[str, int]:
@@ -280,9 +299,7 @@ def collective_bytes(hlo_text: str) -> dict[str, int]:
             opcode = m["opcode"]
             kind = collective_kind(opcode)
             if kind and kind[1] != "-start":
-                head = line.split(" = ", 1)[1]
-                total[kind[0]] += _array_bytes(
-                    head[:head.index(f" {opcode}(")])
+                total[kind[0]] += _array_bytes(_result_type(m, line))
             if opcode == "fusion":
                 continue
             costs = [cost(c.lstrip("%"))
@@ -295,6 +312,39 @@ def collective_bytes(hlo_text: str) -> dict[str, int]:
         return total
 
     return dict(cost(entry)) if entry else {}
+
+
+def kernel_operand_spaces(hlo_text: str, names) -> dict[str, dict[str, int]]:
+    """``{kernel: {memory space: bytes}}`` of the arrays that every
+    ``tpu_custom_call`` of an optimized HLO module hands its kernel, the
+    call's operands and its results alike (the kernel's body takes both as
+    its operands), for the calls whose name (the instruction's, less XLA's
+    ``.N``: what its ``pallas_call`` was given) is one of ``names``, summed
+    over a kernel's calls.  The text names an operand and not its type, so
+    each is looked up where its own computation defines it; the space is
+    the one its layout there carries (:func:`_arrays`), which the
+    compiler's memory-space assignment chose: the same kernel on the same
+    shapes finds an array in VMEM in one program and in HBM in the next.
+    A kernel the text does not hold is left out."""
+    names = frozenset(names)
+    out: dict[str, collections.Counter] = {}
+    for lines in _instruction_lines(hlo_text)[0].values():
+        types = None
+        for m, line in lines:
+            base, _, number = m["name"].rpartition(".")
+            kernel = base if number.isdigit() else m["name"]
+            if _KERNEL_MARK not in line or kernel not in names:
+                continue
+            if types is None:
+                types = {d["name"]: _result_type(d, text)
+                         for d, text in lines}
+            head = line[line.index(" custom-call(") + len(" custom-call("):]
+            found = out.setdefault(kernel, collections.Counter())
+            for handed in [m["name"],
+                           *_OPERAND.findall(head[:head.index(")")])]:
+                for n, space in _arrays(types[handed]):
+                    found[space] += n
+    return {kernel: dict(found) for kernel, found in out.items()}
 
 
 # -- the trace → the table -------------------------------------------------
@@ -512,7 +562,7 @@ def format_table(table: dict) -> str:
 
 
 __all__ = ["OTHER", "UNATTRIBUTED", "COLLECTIVE", "COLLECTIVE_KINDS",
-           "collective_kind", "collective_bytes",
+           "collective_kind", "collective_bytes", "kernel_operand_spaces",
            "PROGRAM_SPAN_PREFIXES", "ProfilerBusy", "capture",
            "trace_window", "scope_table", "fused_scopes", "module_name",
            "layer_table", "layer_table_of", "read_planes", "find_xplane",

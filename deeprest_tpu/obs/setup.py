@@ -1,0 +1,266 @@
+"""What happens before the first steady step, recorded by the program.
+
+A retrain's set-up (state, staging, the first dispatches, every program
+XLA compiles or loads from its cache on the way) costs more than its
+epochs once the step is fast, and from outside it can only be timed whole.
+This module holds the three pieces that are not one trainer's:
+
+- :func:`phase` — the set-up phase open on this thread (``init_state``,
+  ``stage``, ``first_dispatch``, ``epoch``; :data:`OTHER` outside all of
+  them).  The trainer opens them; the innermost one names where a
+  compilation happened.
+- :func:`install` — ONE listener a process on ``jax.monitoring``.  Every
+  backend compilation (served from the persistent cache or not) is added
+  to ``deeprest_compilations_total{program,phase,cache}`` and its seconds
+  to ``deeprest_compile_seconds_total{program,phase}``: ``program`` is the
+  jitted function's name where it is one of the names :func:`install` was
+  given and :data:`OTHER` for everything else (the primitives an un-jitted
+  ``model.init`` runs one by one, a caller's own programs), so the label
+  has a dozen values; ``cache`` is ``hit`` or ``miss`` by the cache's own
+  event inside that compilation, ``uncached`` where it sent neither (the
+  cache is off, or the entry was under its thresholds and not written).
+  With the span recorder on, a compilation under an open span is also a
+  span ``deeprest-jax/compile`` tagged ``program`` and ``cache``, a child
+  of that span, entered when the compilation begins: it lies on the
+  profiler's clock like every other (outside every span it is counted and
+  no span: it would be a trace of its own in the plane's exports).  A compilation in phase ``epoch``
+  after the first epoch is a recompile, with its name.
+- :func:`setup_table` / :func:`format_setup` — the set-up gauges of the
+  process registry as one table (``Trainer.profile_epoch``'s ``setup``,
+  ``layers.json``) and as the one line ``deeprest_tpu train`` prints when
+  its first epoch is done.  The names are this module's constants; the
+  trainer sets them.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import contextvars
+import re
+import threading
+
+from deeprest_tpu.obs.metrics import REGISTRY
+from deeprest_tpu.obs.spans import RECORDER, current_context
+
+OTHER = "other"
+UNCACHED = "uncached"
+
+COMPILATIONS = "deeprest_compilations_total"
+COMPILE_SECONDS = "deeprest_compile_seconds_total"
+INIT_STATE_SECONDS = "deeprest_train_init_state_seconds"
+STAGE_SECONDS = "deeprest_train_last_stage_seconds"
+FIRST_DISPATCH_SECONDS = "deeprest_train_first_dispatch_seconds"
+DEVICE_BYTES = "deeprest_train_device_bytes"
+PROGRAM_BYTES = "deeprest_train_program_bytes"
+KERNEL_OPERAND_BYTES = "deeprest_train_kernel_operand_bytes"
+
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
+
+_WRAPPED = re.compile(r"\w+\((.*)\)")
+
+_PHASE: contextvars.ContextVar[str] = contextvars.ContextVar(
+    "deeprest_obs_setup_phase", default=OTHER)
+
+
+@contextlib.contextmanager
+def phase(name: str):
+    """``name`` is the set-up phase of this thread until the block ends (a
+    phase inside another wins while it is open)."""
+    token = _PHASE.set(name)
+    try:
+        yield
+    finally:
+        _PHASE.reset(token)
+
+
+def current_phase() -> str:
+    return _PHASE.get()
+
+
+def _compilations():
+    return REGISTRY.counter(
+        COMPILATIONS,
+        "XLA backend compilations by jitted program (other: not one of the "
+        "trainer's), by the set-up phase open on the compiling thread, and "
+        "by what the persistent cache did (hit, miss, uncached)",
+        labelnames=("program", "phase", "cache"))
+
+
+def _compile_seconds():
+    return REGISTRY.counter(
+        COMPILE_SECONDS,
+        "seconds of those compilations (a cache hit's are its load)",
+        labelnames=("program", "phase"))
+
+
+class _Listener:
+    """``jax.monitoring`` hands a compilation's start as a scalar event,
+    the cache's verdict as a plain event inside it and its seconds as a
+    duration event at its end, all on the compiling thread."""
+
+    def __init__(self):
+        self.programs: set[str] = set()
+        self._open = threading.local()
+
+    def _program(self, fun_name) -> str:
+        """``jit(train_superstep)`` -> ``train_superstep`` if it is one of
+        ours."""
+        m = _WRAPPED.fullmatch(fun_name or "")
+        name = m[1] if m else fun_name
+        return name if name in self.programs else OTHER
+
+    def began(self, event: str, _value, fun_name=None, **_kw) -> None:
+        if event != _BACKEND_COMPILE:
+            return
+        self._open.cache = UNCACHED
+        # a span only under an open one: a compilation outside every span
+        # would be a trace of its own in the plane's exports
+        self._open.span = None
+        if current_context() is not None:
+            self._open.span = RECORDER.span(
+                "compile", "deeprest-jax",
+                {"program": self._program(fun_name)})
+            self._open.span.__enter__()
+
+    def cache(self, event: str, **_kw) -> None:
+        verdict = _CACHE_EVENTS.get(event)
+        if verdict:
+            self._open.cache = verdict
+
+    def ended(self, event: str, seconds: float, fun_name=None,
+              **_kw) -> None:
+        if event != _BACKEND_COMPILE:
+            return
+        cache = getattr(self._open, "cache", UNCACHED)
+        span = getattr(self._open, "span", None)
+        self._open.cache, self._open.span = UNCACHED, None
+        if span is not None:
+            span.tag(cache=cache).__exit__(None, None, None)
+        program, where = self._program(fun_name), current_phase()
+        # looked up by name each time: a registry reset between two
+        # compilations (tests) must not leave the counts in dropped objects
+        _compilations().inc(program=program, phase=where, cache=cache)
+        _compile_seconds().inc(max(float(seconds), 0.0), program=program,
+                               phase=where)
+
+
+_listener: _Listener | None = None
+_install_lock = threading.Lock()
+
+
+def install(programs=()) -> None:
+    """Listen to this process's compilations (once, however often it is
+    called) and count those of the jitted functions named ``programs``
+    under their own names from now on."""
+    global _listener
+    with _install_lock:
+        if _listener is None:
+            import jax.monitoring as monitoring
+
+            _listener = _Listener()
+            monitoring.register_scalar_listener(_listener.began)
+            monitoring.register_event_listener(_listener.cache)
+            monitoring.register_event_duration_secs_listener(_listener.ended)
+        _listener.programs.update(programs)
+
+
+# -- the set-up gauges as a table and as a line ------------------------------
+
+
+def _series(name: str) -> list[tuple[dict, float]]:
+    metric = REGISTRY.get(name)
+    if metric is None:
+        return []
+    return [(dict(zip(metric.labelnames, key)), value)
+            for key, value in metric.series().items()]
+
+
+def _by(name: str, *labels: str) -> dict:
+    """The metric's series as nested dicts keyed by ``labels`` in turn."""
+    out: dict = {}
+    for found, value in _series(name):
+        at = out
+        for label in labels[:-1]:
+            at = at.setdefault(found[label], {})
+        at[found[labels[-1]]] = value
+    return out
+
+
+def setup_table() -> dict:
+    """The set-up gauges as they stand: seconds of ``init_state`` by phase,
+    of the last ``stage_dataset``, of each first dispatch; the compilations
+    by program and phase (count, seconds, misses); device memory at the
+    three moments; the superstep executable's bytes and where its kernels'
+    operands live.  What was never set is left out."""
+    seconds = {(s["program"], s["phase"]): v
+               for s, v in _series(COMPILE_SECONDS)}
+    compilations: dict = {}
+    for s, n in _series(COMPILATIONS):
+        row = compilations.setdefault(
+            (s["program"], s["phase"]),
+            {"program": s["program"], "phase": s["phase"], "count": 0,
+             "misses": 0,
+             "seconds": seconds.get((s["program"], s["phase"]), 0.0)})
+        row["count"] += int(n)
+        if s["cache"] != "hit":
+            row["misses"] += int(n)
+    stage = _series(STAGE_SECONDS)
+    table = {
+        "init_state_seconds": _by(INIT_STATE_SECONDS, "phase"),
+        "stage_seconds": stage[0][1] if stage else None,
+        "first_dispatch_seconds": _by(FIRST_DISPATCH_SECONDS, "program"),
+        "compilations": sorted(compilations.values(),
+                               key=lambda r: -r["seconds"]),
+        "device_bytes": _by(DEVICE_BYTES, "at", "kind"),
+        "program_bytes": _by(PROGRAM_BYTES, "kind"),
+        "kernel_operand_bytes": _by(KERNEL_OPERAND_BYTES, "kernel", "space"),
+    }
+    return {k: v for k, v in table.items() if v not in (None, {}, [])}
+
+
+def format_setup(table: dict) -> str:
+    """:func:`setup_table` as one line."""
+    def seconds(found: dict) -> str:
+        return ", ".join(f"{k} {v:.3f}" for k, v in found.items())
+
+    def size(n: float) -> str:
+        return f"{n / 1e9:.3f} GB" if n >= 1e8 else f"{n / 1e6:.1f} MB"
+
+    parts = []
+    if "init_state_seconds" in table:
+        found = table["init_state_seconds"]
+        parts.append(f"init_state {sum(found.values()):.3f} s "
+                     f"({seconds(found)})")
+    if "stage_seconds" in table:
+        parts.append(f"stage {table['stage_seconds']:.3f} s")
+    if "first_dispatch_seconds" in table:
+        parts.append("first dispatch "
+                     + seconds(table["first_dispatch_seconds"]) + " s")
+    rows = table.get("compilations", ())
+    if rows:
+        parts.append(
+            f"{sum(r['count'] for r in rows)} compilations in "
+            f"{sum(r['seconds'] for r in rows):.3f} s, "
+            f"{sum(r['misses'] for r in rows)} not from the cache ("
+            + ", ".join(f"{r['program']} in {r['phase']} {r['count']} in "
+                        f"{r['seconds']:.3f} s"
+                        + (f", {r['misses']} missed" if r["misses"] else "")
+                        for r in rows) + ")")
+    for at, found in table.get("device_bytes", {}).items():
+        parts.append(f"device memory at {at} {size(found.get('in_use', 0))} "
+                     f"in use, peak {size(found.get('peak', 0))}")
+    if "program_bytes" in table:
+        parts.append("superstep " + ", ".join(
+            f"{k} {size(v)}" for k, v in table["program_bytes"].items()))
+    for kernel, found in table.get("kernel_operand_bytes", {}).items():
+        parts.append(f"{kernel} operands " + ", ".join(
+            f"{space} {size(v)}" for space, v in found.items()))
+    return "set-up: " + "; ".join(parts)
+
+
+__all__ = ["OTHER", "UNCACHED", "phase", "current_phase", "install",
+           "setup_table", "format_setup", "COMPILATIONS", "COMPILE_SECONDS",
+           "INIT_STATE_SECONDS", "STAGE_SECONDS", "FIRST_DISPATCH_SECONDS",
+           "DEVICE_BYTES", "PROGRAM_BYTES", "KERNEL_OPERAND_BYTES"]

@@ -16,6 +16,7 @@ one batched jit call instead of batch-1 Python loops.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Callable, Mapping
@@ -31,6 +32,7 @@ from deeprest_tpu.models.qrnn import (
     MASKED_PARAM_NAMES, QuantileGRU, put_columns, put_rows, take_columns,
 )
 from deeprest_tpu.obs import metrics as obs_metrics
+from deeprest_tpu.obs import setup as obs_setup
 from deeprest_tpu.obs import spans as obs_spans
 from deeprest_tpu.obs.phases import PhaseClock
 from deeprest_tpu.ops import scopes
@@ -59,6 +61,10 @@ from deeprest_tpu.train.metrics import Throughput, mae_report
 # and `loss_readback` the device has nothing queued.
 EPOCH_PHASES = ("plan_build", "plan_h2d", "dispatch", "log_readback",
                 "device_wait", "loss_readback")
+# The steps of `Trainer.init_state`, in order.  The host's seconds of each
+# (trace, compile or cache load, enqueue); `pin` ends with the one wait on
+# the state, so what the device still owed the earlier three is in it.
+INIT_PHASES = ("model_init", "shard", "opt_init", "pin")
 
 
 @flax.struct.dataclass
@@ -248,6 +254,9 @@ class Trainer:
         # (host copy; None for a feed without one): the stage span's tags.
         self._staged_before = False
         self._staged_table: np.ndarray | None = None
+        # Whether a train_epoch has finished (device memory is read once,
+        # after the first).
+        self._epoch_finished = False
         # The table of the epoch fit(profile_dir=...) ran through
         # profile_epoch, for the caller to print or write.
         self.last_profile: dict | None = None
@@ -295,10 +304,12 @@ class Trainer:
         """
         self.model = QuantileGRU(config=self.model_config, mesh=self.mesh)
         quantiles = self.model_config.quantiles
-        # the epoch span's tag, and whether this mesh's superstep has had
-        # its collectives read into deeprest_train_collective_bytes
+        # the epoch span's tag; whether this mesh's superstep has been
+        # read into the gauges of _publish_program; the programs built
+        # here that have been dispatched once (_first_dispatch)
         self._mesh_tag = "x".join(str(self.mesh.shape[a]) for a in AXES)
-        self._collectives_published = False
+        self._program_published = False
+        self._dispatched_once: set[str] = set()
 
         def pin_state(state: TrainState) -> TrainState:
             """Constrain every leaf to its CANONICAL named sharding, all
@@ -603,13 +614,24 @@ class Trainer:
                 {"params": params}, xb, deterministic=True
             )
         )
+        # Their compilations are counted under their own names from here
+        # on (obs/setup.py; a lambda's name is nobody's).
+        obs_setup.install({fn.__name__ for fn in self._jitted()}
+                          - {"<lambda>"})
+
+    def _jitted(self) -> tuple:
+        """The trainer's jitted programs."""
+        return (self._train_step, self._train_step_indexed, self._superstep,
+                self._accum_superstep, self._stale_rows, self._eval_step,
+                self._eval_step_indexed, self._predict_step, self._pin_state)
 
     def _build_metrics(self) -> None:
         # Training-plane obs metrics (process-wide registry singletons —
         # step time itself rides in via Throughput.stop): superstep
-        # dispatch counts, the designed host-readback counter, and the
-        # compile-event gauge fed from the jit cache probes.  One
-        # increment per epoch/superstep/log-boundary — never per step.
+        # dispatch counts, the designed host-readback counter, and what
+        # set-up cost (obs/setup.py names those; every compilation of the
+        # process is counted there).  One touch per epoch/superstep/
+        # log-boundary or once a trainer — never per step.
         self._m_dispatches = obs_metrics.REGISTRY.counter(
             "deeprest_train_superstep_dispatches_total",
             "fused lax.scan superstep dispatches")
@@ -640,17 +662,44 @@ class Trainer:
             "began (counted on a compact base only)",
             labelnames=("kind",))
         self._m_stage_seconds = obs_metrics.REGISTRY.gauge(
-            "deeprest_train_last_stage_seconds",
+            obs_setup.STAGE_SECONDS,
             "host seconds of the last stage_dataset call")
+        self._init_clock = PhaseClock(
+            "train.init_state", "deeprest-trainer", INIT_PHASES,
+            last_seconds=obs_metrics.REGISTRY.gauge(
+                obs_setup.INIT_STATE_SECONDS,
+                "the last init_state's host seconds by phase; pin ends "
+                "with the wait on the state",
+                labelnames=("phase",)))
+        self._m_first_dispatch = obs_metrics.REGISTRY.gauge(
+            obs_setup.FIRST_DISPATCH_SECONDS,
+            "host seconds of the first call of a jitted trainer program "
+            "(trace, lower, compile or cache load, enqueue; the "
+            "superstep's and the per-step programs' with the wait for "
+            "that first dispatch)",
+            labelnames=("program",))
+        self._m_device_bytes = obs_metrics.REGISTRY.gauge(
+            obs_setup.DEVICE_BYTES,
+            "device memory of the mesh's fullest device (in_use, peak) as "
+            "init_state returned, as stage_dataset returned, and after the "
+            "first train_epoch (set where the backend reports it)",
+            labelnames=("at", "kind"))
+        self._m_program_bytes = obs_metrics.REGISTRY.gauge(
+            obs_setup.PROGRAM_BYTES,
+            "the dispatched superstep executable's memory_analysis: "
+            "arguments, outputs, aliased, temporaries, code",
+            labelnames=("kind",))
+        self._m_kernel_operand_bytes = obs_metrics.REGISTRY.gauge(
+            obs_setup.KERNEL_OPERAND_BYTES,
+            "bytes the dispatched superstep hands each pallas kernel a "
+            "step (operands and results), by the memory space the "
+            "compiler assigned them (hbm, vmem)",
+            labelnames=("kernel", "space"))
         self._m_collective_bytes = obs_metrics.REGISTRY.gauge(
             "deeprest_train_collective_bytes",
             "bytes a train step of the compiled superstep hands to its "
             "collectives, by kind (set under a mesh of more than one device)",
             labelnames=("op",))
-        self._m_executables = obs_metrics.REGISTRY.gauge(
-            "deeprest_train_jit_executables",
-            "compiled executables across the trainer's jitted programs "
-            "(compile events = increases)")
         self._m_snapshots = obs_metrics.REGISTRY.counter(
             "deeprest_train_snapshots_total",
             "preemption-safe cursor snapshots written")
@@ -675,38 +724,87 @@ class Trainer:
     def _jit_cache_size(self) -> int | None:
         """Total compiled-executable count across the trainer's jitted
         programs (None when the running jax version has no cache probe) —
-        the compile-event source for the obs gauge and the no-recompile
-        probes' shared hook."""
+        the no-recompile probes' shared hook."""
         sizes = []
-        for fn in (self._train_step, self._train_step_indexed,
-                   self._superstep, self._accum_superstep,
-                   self._stale_rows, self._eval_step,
-                   self._eval_step_indexed, self._predict_step,
-                   self._pin_state):
+        for fn in self._jitted():
             probe = getattr(fn, "_cache_size", None)
             if callable(probe):
                 sizes.append(int(probe()))
         return sum(sizes) if sizes else None
 
-    def _publish_epoch_metrics(self) -> None:
-        cache = self._jit_cache_size()
-        if cache is not None:
-            self._m_executables.set(cache)
+    @contextlib.contextmanager
+    def _first_dispatch(self, program):
+        """Round the first call of the jitted ``program`` since the
+        programs were built: a span ``train.first_dispatch`` tagged with
+        its name, the set-up phase ``first_dispatch`` (so what it compiles
+        or loads is counted there), its seconds in
+        ``deeprest_train_first_dispatch_seconds{program}``.  Nothing round
+        a later call."""
+        name = program.__name__
+        if name in self._dispatched_once:
+            yield
+            return
+        clock = obs_metrics.Stopwatch()
+        with obs_spans.RECORDER.span("train.first_dispatch",
+                                     "deeprest-trainer", {"program": name}), \
+                obs_setup.phase("first_dispatch"):
+            yield
+        self._m_first_dispatch.set(clock.elapsed(), program=name)
+        self._dispatched_once.add(name)
 
-    def _publish_collective_bytes(self, state) -> None:
-        """``deeprest_train_collective_bytes``, once for each mesh of more
-        than one device: what a step of the superstep this epoch
-        dispatched hands to each kind of collective, read from the
-        compiled program's own text (the partitioner decides what is
-        reduced and in which type, so no sum over the gradient tree would
-        say it).  No second compile (see :meth:`_dispatched_program_text`):
-        the text of the executable the dispatch made, parsed once."""
+    def _publish_device_bytes(self, at: str) -> None:
+        """``deeprest_train_device_bytes{at}``: what the fullest of this
+        process's devices of the mesh holds now and its peak so far.  A
+        backend that reports no ``memory_stats`` (the CPU) sets nothing."""
+        stats = [s for s in (d.memory_stats() for d in self.mesh.local_devices)
+                 if s]
+        if not stats:
+            return
+        fullest = max(stats, key=lambda s: s.get("peak_bytes_in_use", 0))
+        self._m_device_bytes.set(fullest.get("bytes_in_use", 0),
+                                 at=at, kind="in_use")
+        self._m_device_bytes.set(fullest.get("peak_bytes_in_use", 0),
+                                 at=at, kind="peak")
+
+    def _publish_program(self, state) -> None:
+        """What the compiler made of the superstep this epoch dispatched,
+        once for each build of the programs, from the executable the
+        dispatch left in the jit's cache (:meth:`_dispatched_executable`:
+        no second compile): its ``memory_analysis`` into
+        ``deeprest_train_program_bytes{kind}``; from its text, parsed
+        once, where each pallas kernel's operands and results live
+        (``deeprest_train_kernel_operand_bytes{kernel,space}``: the
+        memory-space assignment is the compiler's, and moves a kernel's
+        time with nothing in the kernel changed) and, under a mesh of
+        more than one device, what a step hands to each kind of collective
+        (``deeprest_train_collective_bytes{op}``: the partitioner decides
+        what is reduced and in which type, so no sum over the gradient
+        tree would say it)."""
         from deeprest_tpu.obs import profiler
 
-        for op, n in profiler.collective_bytes(
-                self._dispatched_program_text(state)).items():
+        self._program_published = True
+        if not hasattr(self._dispatched[0], "lower"):
+            # a caller's stand-in round the jitted program (the
+            # benchmark's rehearsals wrap `_superstep` in a plain
+            # function): there is no executable of it to read
+            return
+        compiled = self._dispatched_executable(state)
+        mem = compiled.memory_analysis()
+        if mem is not None:
+            for kind, n in (("arguments", mem.argument_size_in_bytes),
+                            ("outputs", mem.output_size_in_bytes),
+                            ("aliased", mem.alias_size_in_bytes),
+                            ("temporaries", mem.temp_size_in_bytes),
+                            ("code", mem.generated_code_size_in_bytes)):
+                self._m_program_bytes.set(n, kind=kind)
+        text = compiled.as_text()
+        for kernel, spaces in profiler.kernel_operand_spaces(
+                text, scopes.KERNELS).items():
+            for space, n in spaces.items():
+                self._m_kernel_operand_bytes.set(n, kernel=kernel,
+                                                 space=space)
+        for op, n in profiler.collective_bytes(text).items():
             self._m_collective_bytes.set(n, op=op)
-        self._collectives_published = True
 
     def _publish_optimizer_rows(self, x_base, stale=None, steps=0) -> None:
         """The epoch's ``deeprest_train_optimizer_rows``, for a staged
@@ -1012,21 +1110,40 @@ class Trainer:
                         np.float32)
 
     def init_state(self, sample_x: np.ndarray, seed: int | None = None) -> TrainState:
-        """Initialize (and shard) params + optimizer state."""
+        """Initialize (and shard) params + optimizer state.
+
+        One span ``deeprest-trainer/train.init_state`` with a child for
+        each of :data:`INIT_PHASES`, their seconds (timed as an epoch's
+        phases are) in ``deeprest_train_init_state_seconds{phase}``; the
+        set-up phase ``init_state`` for what it compiles; closed by one
+        wait on the state, which the first step would wait for anyway;
+        device memory as it returns in
+        ``deeprest_train_device_bytes{at="init_state"}``."""
         seed = self.config.train.seed if seed is None else seed
-        rng = jax.random.PRNGKey(seed)
-        init_rng, train_rng = jax.random.split(rng)
-        variables = self.model.init(init_rng, jnp.asarray(sample_x[:1]))
-        params = shard_params(self.mesh, dict(variables["params"]))
-        opt_state = jax.jit(self.tx.init)(params)
-        # Pinned through the same jitted constraint the train step applies
-        # to its output, so the first step's input signature equals every
-        # later step's — one executable, bit-stable numerics (see
-        # pin_state in __init__).
-        return self._pin_state(TrainState(
-            step=jnp.zeros((), jnp.int32), params=params,
-            opt_state=opt_state, rng=train_rng,
-        ))
+        with obs_setup.phase("init_state"), \
+                self._init_clock.unit() as phase:
+            with phase("model_init"):
+                rng = jax.random.PRNGKey(seed)
+                init_rng, train_rng = jax.random.split(rng)
+                variables = self.model.init(init_rng,
+                                            jnp.asarray(sample_x[:1]))
+            with phase("shard"):
+                params = shard_params(self.mesh, dict(variables["params"]))
+            with phase("opt_init"):
+                opt_state = jax.jit(self.tx.init)(params)
+            # Pinned through the same jitted constraint the train step
+            # applies to its output, so the first step's input signature
+            # equals every later step's — one executable, bit-stable
+            # numerics (see pin_state in __init__).
+            with phase("pin"):
+                with self._first_dispatch(self._pin_state):
+                    state = self._pin_state(TrainState(
+                        step=jnp.zeros((), jnp.int32), params=params,
+                        opt_state=opt_state, rng=train_rng,
+                    ))
+                jax.block_until_ready(state)
+        self._publish_device_bytes("init_state")
+        return state
 
     # ------------------------------------------------------------------
 
@@ -1123,11 +1240,14 @@ class Trainer:
         next, the rows that ``left`` and ``entered`` it — the rows that
         left are the ones the carried moments make stale
         (:func:`stale_rows`); its host seconds are the gauge
-        ``deeprest_train_last_stage_seconds``.
+        ``deeprest_train_last_stage_seconds``, and device memory as it
+        returns ``deeprest_train_device_bytes{at="stage"}``; what it
+        compiles is counted in the set-up phase ``stage``.
         """
         clock = obs_metrics.Stopwatch()
         with obs_spans.RECORDER.span("train.stage",
-                                     "deeprest-trainer") as span:
+                                     "deeprest-trainer") as span, \
+                obs_setup.phase("stage"):
             before, self._staged_table = self._staged_table, None
             staged = self._stage(bundle)
             table = self._staged_table
@@ -1140,6 +1260,7 @@ class Trainer:
             span.tag(**tags)
         self._staged_before = True
         self._m_stage_seconds.set(clock.elapsed())
+        self._publish_device_bytes("stage")
         return staged
 
     def _stage(self, bundle: DatasetBundle):
@@ -1248,10 +1369,19 @@ class Trainer:
         The epoch's host work is timed by phase (:data:`EPOCH_PHASES`,
         obs/phases.py): one ``train.epoch`` span, tagged with the mesh
         ``DxExM``, with a child per phase when the recorder is on, the
-        phase-seconds counters always."""
-        with self._epoch_clock.unit({"mesh": self._mesh_tag}) as phase:
-            return self._train_epoch(state, bundle, epoch_rng, staged,
-                                     skip_steps, on_step, phase)
+        phase-seconds counters always.  What compiles in it is counted in
+        the set-up phase ``epoch`` (after the first epoch: a recompile);
+        device memory after the first one to finish is
+        ``deeprest_train_device_bytes{at="first_epoch"}``."""
+        with obs_setup.phase("epoch"), \
+                self._epoch_clock.unit({"mesh": self._mesh_tag}) as phase:
+            out = self._train_epoch(state, bundle, epoch_rng, staged,
+                                    skip_steps, on_step, phase)
+        if not self._epoch_finished:
+            # its state resident, its temporaries freed
+            self._publish_device_bytes("first_epoch")
+            self._epoch_finished = True
+        return out
 
     def _train_epoch(self, state, bundle, epoch_rng, staged, skip_steps,
                      on_step, phase) -> tuple[TrainState, float]:
@@ -1333,7 +1463,9 @@ class Trainer:
         # This driver feeds as it dispatches (the prefetch generator
         # builds and ships each batch), so the whole loop is `dispatch`
         # and `plan_build`/`plan_h2d` stay 0.
-        with phase("dispatch"):
+        with phase("dispatch"), contextlib.ExitStack() as first:
+            if not self._warmed:
+                first.enter_context(self._first_dispatch(program))
             for batch in batches:
                 state, loss = program(state, *fixed, *batch)
                 # Fault barrier probe BEFORE any bookkeeping: a device lost
@@ -1348,6 +1480,7 @@ class Trainer:
                     # out of the throughput window so steps/sec reflects
                     # steady state.
                     jax.block_until_ready(loss)
+                    first.close()
                     self._warmed = True
                     self.throughput.start()
                     measuring = True
@@ -1366,7 +1499,6 @@ class Trainer:
             jax.block_until_ready(state.params)
         if measuring:
             self.throughput.stop(steps)
-        self._publish_epoch_metrics()
         if staged is not None:
             self._publish_optimizer_rows(staged[0])
         # One stacked host readback for the epoch mean instead of a
@@ -1430,13 +1562,16 @@ class Trainer:
         stale = None
         if (superstep is self._superstep and live_cols_of(x_base) is not None
                 and w_ih_leaves(state.params)):
-            stale = self._stale_rows(state.opt_state, x_base.live)
+            with self._first_dispatch(self._stale_rows):
+                stale = self._stale_rows(state.opt_state, x_base.live)
         measuring = self._warmed
         if measuring:
             self.throughput.start()
         chunk_losses = []
         steps = 0
-        with phase("dispatch"):
+        with phase("dispatch"), contextlib.ExitStack() as first:
+            if not self._warmed:
+                first.enter_context(self._first_dispatch(superstep))
             for c in range(skip_chunks, starts.shape[0]):
                 real = min(s, num_steps - c * s)
                 state, losses_c = superstep(state, x_base, y_base,
@@ -1451,6 +1586,7 @@ class Trainer:
                 if not self._warmed:
                     # First-ever superstep pays the scan's trace+compile.
                     jax.block_until_ready(losses_c)
+                    first.close()
                     self._warmed = True
                     self.throughput.start()
                     measuring = True
@@ -1476,13 +1612,14 @@ class Trainer:
         # what this epoch dispatched, for profile_epoch to lower again
         self._dispatched = (superstep,
                             (x_base, y_base, starts_d, weights_d, 0))
-        if self.mesh.size > 1 and not self._collectives_published:
-            self._publish_collective_bytes(state)
+        if not self._program_published:
+            # the host reads the executable while the device runs the
+            # epoch's last chunk
+            self._publish_program(state)
         with phase("device_wait"):
             jax.block_until_ready(state.params)
         if measuring:
             self.throughput.stop(steps)
-        self._publish_epoch_metrics()
         # Padding only ever trails the real steps, so clipping the
         # concatenated chunks to the executed real-step count recovers
         # exactly the (remaining) per-step loss curve.
@@ -1494,17 +1631,21 @@ class Trainer:
         self._last_epoch_losses = epoch_losses
         return state, float(np.mean(epoch_losses, dtype=np.float64))
 
-    def _dispatched_program_text(self, state: TrainState) -> str:
-        """The optimized HLO of the program the last epoch dispatched (the
+    def _dispatched_executable(self, state: TrainState):
+        """The executable of the program the last epoch dispatched (the
         epoch drivers record it and the arguments beside ``state``).
         ``lower`` on those very arguments finds the trace and the lowering
         the dispatch left in the jit's caches, and ``compile`` the
         executable that ran: nothing is compiled again
         (tests/test_mesh_dp4.py counts the backend's compiles).  Called by
-        :meth:`profile_epoch`, and once for each mesh of more than one
-        device by :meth:`_publish_collective_bytes`."""
+        :meth:`profile_epoch`, and once for each build of the programs by
+        :meth:`_publish_program`."""
         program, args = self._dispatched
-        return program.lower(state, *args).compile().as_text()
+        return program.lower(state, *args).compile()
+
+    def _dispatched_program_text(self, state: TrainState) -> str:
+        """Its optimized HLO."""
+        return self._dispatched_executable(state).as_text()
 
     def profile_epoch(self, state: TrainState, bundle: DatasetBundle,
                       epoch_rng: np.random.Generator, staged,
@@ -1514,8 +1655,9 @@ class Trainer:
         tracer off) with the span recorder on for its duration, then the
         trace read into the table of obs/profiler.py: the device's time by
         the scopes of the program this epoch dispatched, its idle gaps by
-        the epoch's phases, the phases' host seconds and the epoch's mean
-        loss (``train_loss``).  Trace a steady epoch (the program
+        the epoch's phases, the phases' host seconds, the epoch's mean
+        loss (``train_loss``) and what set-up cost this process
+        (``setup``: obs/setup.py).  Trace a steady epoch (the program
         compiled, the loss concatenation too): the second, not the
         first."""
         from deeprest_tpu.obs import profiler
@@ -1540,6 +1682,7 @@ class Trainer:
         table["phases"] = {
             name: self._epoch_clock.last_seconds.value(phase=name)
             for name in EPOCH_PHASES}
+        table["setup"] = obs_setup.setup_table()
         table["train_loss"] = loss
         return state, table
 

@@ -325,7 +325,15 @@ def test_train_step_fits_the_chip(one_chip, feature_dim, sparse, accum):
     assert _need(mem) < HBM_BYTES, mem
 
 
-def test_compact_superstep_updates_the_leaves_in_place(one_chip):
+@pytest.fixture(scope="module")
+def compact_superstep(one_chip):
+    """The compact 10k superstep (a 3 x 50 plan), compiled once for the
+    two tests that read it."""
+    return _train_step_lowered(one_chip, F_10K, "compact",
+                               superstep=True).compile()
+
+
+def test_compact_superstep_updates_the_leaves_in_place(compact_superstep):
     """The compact 10k superstep (ISSUE 32), the guard of its mechanism in
     tier-1 (8-10 s): the table's rows of the two w_ih leaves and of their
     moments ride the scan, and the take before it and the put after it
@@ -339,8 +347,7 @@ def test_compact_superstep_updates_the_leaves_in_place(one_chip):
     loop past the bound; each reads and writes the carried leaves in
     place, so their trip counts, 0 where the moments are zero off the
     table, are all they cost."""
-    compiled = _train_step_lowered(one_chip, F_10K, "compact",
-                                   superstep=True).compile()
+    compiled = compact_superstep
     text = compiled.as_text()
     mem = compiled.memory_analysis()
     print(f"compact 10k superstep for a described v5e: temporaries "
@@ -355,6 +362,52 @@ def test_compact_superstep_updates_the_leaves_in_place(one_chip):
     assert mem.temp_size_in_bytes < 1.0e9, mem
     assert _need(mem) < 5.5e9, mem
     assert mem.generated_code_size_in_bytes <= 20e6, mem
+
+
+def test_compact_superstep_names_where_its_kernels_operands_live(
+        compact_superstep):
+    """What ``Trainer._publish_program`` reads from the executable it
+    dispatched (ISSUE 35), on the 10k compact superstep compiled for the
+    described v5e: ``kernel_operand_spaces`` finds both kernels and every
+    array their two calls each are handed (the text names an operand, its
+    own computation holds its type), the bytes are the shapes', part of
+    them is in VMEM by the compiler's memory-space assignment and the
+    rest in HBM, and ``memory_analysis`` gives the five kinds of
+    ``deeprest_train_program_bytes``."""
+    from deeprest_tpu.obs import profiler
+    from deeprest_tpu.ops import scopes
+
+    found = profiler.kernel_operand_spaces(compact_superstep.as_text(),
+                                           scopes.KERNELS)
+    bf16, f32 = 2, 4
+    xp = E * W * B * 3 * H * bf16          # the projected input; the gates
+    h = E * W * B * H * bf16               # a hidden-state sequence
+    w_hh, bias, h0 = E * H * 3 * H, E * 3 * H * f32, E * B * H * f32
+    handed = {
+        # operands: xp, w_hh, the bias, h0; results: h twice, the gates
+        "gru_kernel_fwd": (xp + w_hh * bf16 + bias + h0) + (2 * h + xp),
+        # operands: xp, h, the gates, w_hh, the bias, h's cotangent;
+        # results: xp's cotangent, w_hh's, the bias's, h0's
+        "gru_kernel_bwd": ((2 * xp + 2 * h + w_hh * bf16 + bias)
+                           + (xp + w_hh * f32 + bias + h0)),
+    }
+    print(f"compact 10k superstep for a described v5e: the kernels' "
+          f"operands and results by memory space {found}")
+    assert set(found) == set(scopes.KERNELS) == set(handed)
+    for kernel, spaces in found.items():
+        assert set(spaces) == {"hbm", "vmem"}, (kernel, spaces)
+        assert sum(spaces.values()) == 2 * handed[kernel], (kernel, spaces)
+    assert profiler.kernel_operand_spaces(
+        compact_superstep.as_text(), ("gru_kernel_fwd",)).keys() \
+        == {"gru_kernel_fwd"}
+    mem = compact_superstep.memory_analysis()
+    kinds = {"arguments": mem.argument_size_in_bytes,
+             "outputs": mem.output_size_in_bytes,
+             "aliased": mem.alias_size_in_bytes,
+             "temporaries": mem.temp_size_in_bytes,
+             "code": mem.generated_code_size_in_bytes}
+    assert all(v > 0 for v in kinds.values()), kinds
+    assert kinds["aliased"] <= kinds["outputs"] <= kinds["arguments"]
 
 
 @pytest.mark.slow       # by hand, as the one-chip compact superstep above

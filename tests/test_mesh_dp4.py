@@ -253,12 +253,17 @@ def test_reading_the_collectives_compiles_nothing(runs):
     try:
         jax.jit(lambda x: x + 1)(np.float32(len(runs)))   # the listener hears
         heard = len(compiles)
-        trainer._collectives_published = False
-        trainer._publish_collective_bytes(state)
+        trainer._program_published = False
+        trainer._publish_program(state)
     finally:
         monitoring.unregister_event_duration_listener(listen)
     assert heard and len(compiles) == heard
-    assert trainer._collectives_published
+    assert trainer._program_published
+    # the same read gave the executable's bytes by kind
+    found = REGISTRY.get("deeprest_train_program_bytes").series()
+    assert {k[0] for k in found} == {"arguments", "outputs", "aliased",
+                                     "temporaries", "code"}
+    assert found[("arguments",)] > 0
 
 
 def test_collective_row_of_the_scope_table(runs):

@@ -22,10 +22,11 @@ from deeprest_tpu import obs
 from deeprest_tpu.config import Config, FeaturizeConfig, ModelConfig, TrainConfig
 from deeprest_tpu.data.featurize import featurize_buckets
 from deeprest_tpu.obs import profiler
+from deeprest_tpu.obs import setup as obs_setup
 from deeprest_tpu.obs.metrics import REGISTRY
 from deeprest_tpu.ops import scopes
 from deeprest_tpu.train import Trainer, prepare_dataset
-from deeprest_tpu.train.trainer import EPOCH_PHASES
+from deeprest_tpu.train.trainer import EPOCH_PHASES, INIT_PHASES
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 RECORDED = os.path.join(REPO, "chipbench", "tests", "data",
@@ -262,19 +263,25 @@ def test_two_epochs_fill_the_phase_counters(tiny):
     assert after["readbacks"] - before["readbacks"] == crossings + 1
 
 
-def test_epoch_spans_are_children_of_one_epoch_span(tiny):
+def _recorded(run):
+    """``run()`` with the recorder on; the spans it left."""
     prev = obs.RECORDER.enabled
     obs.RECORDER.clear()
     obs.RECORDER.enabled = True
     try:
-        _epoch(tiny)
+        run()
     finally:
         obs.RECORDER.enabled = prev
-    spans = [s for s in obs.RECORDER.drain()
+    return obs.RECORDER.drain()
+
+
+def test_epoch_spans_are_children_of_one_epoch_span(tiny):
+    spans = [s for s in _recorded(lambda: _epoch(tiny))
              if s.component == "deeprest-trainer"]
     epoch = [s for s in spans if s.name == "train.epoch"]
     assert len(epoch) == 1
-    phases = [s for s in spans if s.name != "train.epoch"]
+    phases = [s for s in spans
+              if s.name not in ("train.epoch", "train.first_dispatch")]
     assert {s.name for s in phases} == set(EPOCH_PHASES)
     assert all(s.parent_id == epoch[0].span_id for s in phases)
 
@@ -439,6 +446,8 @@ def test_profile_epoch_reads_the_trace_it_opens(tiny, tmp_path):
     assert [r["pass"] for r in table["rows"]
             if r["scope"] == profiler.OTHER] == ["-"]      # one row
     assert set(table["phases"]) == set(EPOCH_PHASES)
+    assert set(table["setup"]["init_state_seconds"]) == set(INIT_PHASES)
+    assert table["setup"]["program_bytes"]["arguments"] > 0
     assert table["chips"] == 0         # the CPU has no device plane
     # the spans did reach the trace: the host plane holds the epoch's
     names = {n for _, lines in profiler.read_planes(table["trace"])
@@ -488,6 +497,9 @@ def test_train_profile_dir_writes_layers_json(tmp_path, capsys):
                  f"--profile-dir={out}"]) == 0
     printed = capsys.readouterr().out
     assert "host phase loss_readback" in printed
+    # one line on what came before the first steady step, after epoch 0
+    assert printed.count("set-up: init_state ") == 1
+    assert printed.index("set-up:") < printed.index("epoch 0:")
     # the second epoch was the one traced: its table comes before its line
     assert printed.index("epoch 0:") < printed.index("host phase") \
         < printed.index("epoch 1:")
@@ -496,4 +508,168 @@ def test_train_profile_dir_writes_layers_json(tmp_path, capsys):
     rows = {r["scope"] for r in table["rows"]}
     assert rows >= set(NO_TABLE) - {"densify"}    # a dense corpus
     assert set(table["phases"]) == set(EPOCH_PHASES)
+    assert {"init_state_seconds", "stage_seconds", "first_dispatch_seconds",
+            "compilations", "program_bytes"} <= set(table["setup"])
     assert os.path.exists(table["trace"])
+
+
+# -- (e) set-up: init_state, the first dispatches, every compilation --------
+
+
+def test_init_state_sets_its_four_phases(tiny):
+    trainer, bundle = tiny["trainer"], tiny["bundle"]
+    gauge = REGISTRY.get(obs_setup.INIT_STATE_SECONDS)
+    t0 = time.perf_counter()
+    spans = _recorded(
+        lambda: trainer.init_state(trainer.sample_input(bundle)))
+    wall = time.perf_counter() - t0
+    seconds = {p: gauge.value(phase=p) for p in INIT_PHASES}
+    assert set(k[0] for k in gauge.series()) == set(INIT_PHASES)
+    assert all(v > 0 for v in seconds.values()), seconds
+    assert sum(seconds.values()) <= wall
+    ours = [s for s in spans if s.component == "deeprest-trainer"]
+    whole = [s for s in ours if s.name == "train.init_state"]
+    assert len(whole) == 1
+    phases = [s for s in ours if s.name in INIT_PHASES]
+    assert [s.name for s in phases] == list(INIT_PHASES)
+    assert all(s.parent_id == whole[0].span_id for s in phases)
+    # the CPU reports no device memory: nothing is set, nothing raises
+    assert REGISTRY.get(obs_setup.DEVICE_BYTES).series() == {}
+
+
+def _compilations(**labels):
+    found = REGISTRY.get(obs_setup.COMPILATIONS)
+    return sum(v for k, v in (found.series() if found else {}).items()
+               if all(dict(zip(found.labelnames, k))[name] == value
+                      for name, value in labels.items()))
+
+
+def test_the_compile_listener_counts_under_the_open_phase(tiny):
+    """One listener a process (the first Trainer installed it): a fresh
+    jitted function's compilation goes to the phase open on this thread,
+    to ``other`` outside one, under its own name once the listener has
+    been given it; a second call compiles nothing and adds nothing."""
+    import jax
+
+    def fresh_for_the_listener(x):
+        return x * 3 + 1
+
+    def named_for_the_listener(x):
+        return x * 5 + 1
+
+    args = np.arange(7, dtype=np.float32)
+    before = _compilations()
+    seconds = sum(REGISTRY.get(obs_setup.COMPILE_SECONDS).series().values())
+
+    def under_a_span():
+        with obs.RECORDER.span("test.unit", "deeprest-test"):
+            jax.jit(fresh_for_the_listener)(args)
+
+    with obs_setup.phase("stage"):
+        assert obs_setup.current_phase() == "stage"
+        spans = _recorded(under_a_span)
+    assert obs_setup.current_phase() == obs_setup.OTHER
+    assert _compilations() == before + 1
+    assert _compilations(program="other", phase="stage") >= 1
+    assert sum(REGISTRY.get(obs_setup.COMPILE_SECONDS).series().values()) \
+        > seconds
+    compiled = [s for s in spans if (s.component, s.name)
+                == ("deeprest-jax", "compile")]
+    assert len(compiled) == 1 and compiled[0].tags["program"] == "other"
+    assert compiled[0].tags["cache"] in ("hit", "miss", "uncached")
+    unit = [s for s in spans if s.name == "test.unit"]
+    assert compiled[0].parent_id == unit[0].span_id
+
+    with obs_setup.phase("stage"):
+        jax.jit(fresh_for_the_listener)(args)       # compiled already
+    assert _compilations() == before + 1
+
+    obs_setup.install(["named_for_the_listener"])   # no second listener
+    outside = _compilations(program="named_for_the_listener", phase="other")
+    # outside every span: counted, and no trace of its own
+    assert not _recorded(lambda: jax.jit(named_for_the_listener)(args))
+    assert _compilations() == before + 2
+    assert _compilations(program="named_for_the_listener",
+                         phase="other") == outside + 1
+
+
+def test_first_dispatch_is_set_once_for_a_trainer():
+    """A fresh trainer's first epoch: one ``train.first_dispatch`` span
+    for each program the main path dispatches, its compilation counted in
+    that phase under the program's name; the second epoch sets nothing
+    again."""
+    fresh = _staged_trainer(FeaturizeConfig(hash_features=True, capacity=512))
+    gauge = REGISTRY.get(obs_setup.FIRST_DISPATCH_SECONDS)
+    was = {p: _compilations(program=p, phase="first_dispatch")
+           for p in ("train_superstep", "stale_rows")}
+    spans = _recorded(lambda: _epoch(fresh))
+    first = [s.tags["program"] for s in spans
+             if s.name == "train.first_dispatch"]
+    assert sorted(first) == ["stale_rows", "train_superstep"]
+    assert "pin_state" in {k[0] for k in gauge.series()}    # init_state's
+    assert all(_compilations(program=p, phase="first_dispatch") == n + 1
+               for p, n in was.items())
+    seconds = dict(gauge.series())
+    assert seconds[("train_superstep",)] > 0
+    epoch_compiles = _compilations(phase="epoch")
+    spans = _recorded(lambda: _epoch(fresh))
+    assert not [s for s in spans if s.name == "train.first_dispatch"]
+    assert dict(gauge.series()) == seconds
+    # a compilation in phase `epoch` after the first epoch is a recompile
+    assert _compilations(phase="epoch") == epoch_compiles
+    assert REGISTRY.get("deeprest_train_jit_executables") is None
+
+
+def test_setup_table_and_its_line(tiny):
+    table = obs_setup.setup_table()
+    assert list(table["init_state_seconds"]) == list(INIT_PHASES)
+    rows = table["compilations"]
+    assert rows == sorted(rows, key=lambda r: -r["seconds"])
+    assert all(r["misses"] <= r["count"] for r in rows)
+    assert sum(r["count"] for r in rows) == _compilations()
+    json.dumps(table)                       # layers.json carries it
+    line = obs_setup.format_setup(table)
+    assert line.startswith("set-up: init_state ") and "\n" not in line
+    assert "compilations in" in line
+
+
+_KERNEL_BYTES = {("gru_kernel_fwd", "vmem"): 1.0, ("gru_kernel_fwd", "hbm"): 2.0,
+                 ("gru_kernel_bwd", "hbm"): 5.0}
+
+
+@pytest.mark.parametrize("reader, metric, labels, series, expected", [
+    ("init_state_s", obs_setup.INIT_STATE_SECONDS, ("phase",),
+     {("model_init",): 3.0, ("pin",): 0.5}, 3.5),
+    ("compile_s", obs_setup.COMPILE_SECONDS, ("program", "phase"),
+     {("other", "other"): 9.0, ("other", "init_state"): 2.0,
+      ("train_superstep", "other"): 5.0}, 7.0),
+    ("compilations", obs_setup.COMPILATIONS, ("program", "phase", "cache"),
+     {("other", "other", "hit"): 4, ("other", "init_state", "hit"): 40,
+      ("train_superstep", "other", "miss"): 1}, 41),
+    ("init_state_peak_gb", obs_setup.DEVICE_BYTES, ("at", "kind"),
+     {("init_state", "peak"): 8.9e9, ("init_state", "in_use"): 7.2e9,
+      ("first_epoch", "in_use"): 4.5e9}, 8.9),
+    ("steady_hbm_gb", obs_setup.DEVICE_BYTES, ("at", "kind"),
+     {("init_state", "peak"): 8.9e9, ("first_epoch", "in_use"): 4.5e9,
+      ("first_epoch", "peak"): 8.9e9}, 4.5),
+    ("gru_kernel_vmem_pct", obs_setup.KERNEL_OPERAND_BYTES,
+     ("kernel", "space"), _KERNEL_BYTES, 12.5),
+])
+def test_the_setup_readers(monkeypatch, reader, metric, labels, series,
+                           expected):
+    """chipbench/readers/setup.py: nothing (not an error) from a program
+    without the gauge or with the gauge never set, the value with it
+    set."""
+    from chipbench.readers import setup as readers
+    from deeprest_tpu.obs import metrics
+
+    registry = metrics.MetricsRegistry()
+    monkeypatch.setattr(metrics, "REGISTRY", registry)
+    read = getattr(readers, reader)
+    assert read({}) is None
+    made = (registry.counter if metric.endswith("_total")
+            else registry.gauge)(metric, labelnames=labels)
+    assert read({}) is None
+    for key, value in series.items():
+        made.inc(value, **dict(zip(labels, key)))
+    assert read({}) == pytest.approx(expected)
