@@ -183,6 +183,34 @@ def put_rows(a: jax.Array, cols: jax.Array, rows: jax.Array) -> jax.Array:
     return jax.lax.fori_loop(0, cols.shape[0], put, a)
 
 
+class KeptMaskDropout(nn.Module):
+    """``flax.linen.Dropout``'s arithmetic on ``flax.linen.Dropout``'s
+    mask (``bernoulli(1 - rate)`` from ``make_rng("dropout")``, so under
+    the name ``Dropout_0`` the key's path, and the mask, are those of the
+    first Dropout of the parent), with the mask behind an
+    ``optimization_barrier``: a ``pred`` array, a byte an element, that the
+    forward select and its transpose both READ.  Left to itself XLA fuses
+    the threefry rounds into every consumer of the mask and draws it again
+    in each: twice a step at best, once in the forward pass and once in
+    the ``heads`` cotangent's fusion (PERF.md section 6, PR 36).  Only a
+    barrier on every use holds it to one draw: a ``custom_vjp`` that kept
+    a barriered mask for the backward pass alone left the forward's uses
+    fused with the draw, six in all."""
+
+    rate: float
+
+    @nn.compact
+    def __call__(self, x: jax.Array, *, deterministic: bool) -> jax.Array:
+        if self.rate == 0.0 or deterministic:
+            return x
+        if self.rate == 1.0:            # no 0 / 0 in the gradient
+            return jnp.zeros_like(x)
+        keep_prob = 1.0 - self.rate
+        keep = jax.lax.optimization_barrier(jax.random.bernoulli(
+            self.make_rng("dropout"), keep_prob, x.shape))
+        return jax.lax.select(keep, x / keep_prob, jnp.zeros_like(x))
+
+
 class QuantileGRU(nn.Module):
     """Multi-task quantile GRU.
 
@@ -294,14 +322,16 @@ class QuantileGRU(nn.Module):
         # The post-RNN path stays in the model's compute dtype (bf16 for
         # the flagship): rnn_out/mix are the largest activations outside
         # the recurrence (~78 MB each at flagship scale in f32), and
-        # dropout + mixing + both head einsums each stream them through
-        # HBM.  All reductions still ACCUMULATE in f32 (the cross-expert
-        # sum explicitly, the head dots via preferred_element_type);
-        # only storage between ops is narrow.  f32 models are unchanged.
+        # mixing + both head einsums each stream them through HBM (the
+        # dropped array itself is rebuilt from the kept mask wherever it
+        # is read).  All reductions still ACCUMULATE in f32 (the
+        # cross-expert sum explicitly, the head dots via
+        # preferred_element_type); only storage between ops is narrow.
+        # f32 models are unchanged.
         with jax.named_scope(scopes.DROPOUT):
-            rnn_out = nn.Dropout(rate=cfg.dropout_rate)(
-                out, deterministic=deterministic
-            )
+            rnn_out = KeptMaskDropout(
+                rate=cfg.dropout_rate, name="Dropout_0")(
+                    out, deterministic=deterministic)
 
         # (c) cross-expert mixing + per-metric quantile heads
         # (reference: qrnn.py:46-55), via the O(E) sum-minus-own identity.

@@ -347,6 +347,42 @@ def kernel_operand_spaces(hlo_text: str, names) -> dict[str, dict[str, int]]:
     return {kernel: dict(found) for kernel, found in out.items()}
 
 
+def threefry_draws(hlo_text: str, scope: str) -> list[str]:
+    """The places of an optimized HLO module's text that generate random
+    bits under ``scope``, by name: every fusion instruction whose fused
+    computation holds a round of the threefry block cipher
+    (``jax.random``'s default generator, lowered to plain adds, rotations
+    and xors) on an array (identical fusions share one computation, so the
+    calls are what is counted), and a computation that holds such a round
+    unfused.  A round is found by an ``xor`` whose ``op_name`` has
+    ``scope`` among its path's components and whose result has more than
+    one element: nothing else a ``bernoulli`` lowers to xors, and deriving
+    a key (``fold_in``, ``split``) runs the rounds on scalars, which are
+    not counted.  XLA fuses a draw into each consumer it can rather than
+    keep its bits, so a mask that the program draws once and uses twice is
+    two such places unless the program holds the compiler to one
+    (models/qrnn.py); a loop's body is counted once however often it runs,
+    and XLA:CPU rolls the rounds into a loop of their own, so there the
+    count says little."""
+    def round_of(m, line) -> bool:
+        op_name = _OP_NAME.search(line)
+        if (m["opcode"] != "xor" or not op_name
+                or scope not in re.split(r"[/()]", op_name[1])):
+            return False
+        dims = _ARRAY.search(_result_type(m, line))[2]
+        return any(int(d) > 1 for d in dims.split(",") if d)
+
+    computations = _instruction_lines(hlo_text)[0]
+    holders = {comp for comp, lines in computations.items()
+               if any(round_of(m, line) for m, line in lines)}
+    fusions = [(m["name"], _CALLS.search(line))
+               for lines in computations.values() for m, line in lines
+               if m["opcode"] == "fusion"]
+    fused = {calls[1] for _, calls in fusions if calls}
+    return ([name for name, calls in fusions if calls and calls[1] in holders]
+            + sorted(holders - fused))
+
+
 # -- the trace → the table -------------------------------------------------
 
 
@@ -563,6 +599,7 @@ def format_table(table: dict) -> str:
 
 __all__ = ["OTHER", "UNATTRIBUTED", "COLLECTIVE", "COLLECTIVE_KINDS",
            "collective_kind", "collective_bytes", "kernel_operand_spaces",
+           "threefry_draws",
            "PROGRAM_SPAN_PREFIXES", "ProfilerBusy", "capture",
            "trace_window", "scope_table", "fused_scopes", "module_name",
            "layer_table", "layer_table_of", "read_planes", "find_xplane",

@@ -327,9 +327,10 @@ def _bidir_pallas(
     The values are those of transposing each direction and joining
     afterwards, bit for bit; the order matters to the compiler.  Handed two
     transposed halves it drew the dropout mask again in every fusion that
-    reads the joined array (six a train step, for two here), and on the
-    chip the operations outside the kernels took 7.54 ms a step for 5.86
-    at E=40 and 48.1 for 40.1 at E=200 (PERF.md section 6, PR 29)."""
+    reads the joined array (six a train step, for two here; the model has
+    held the draw itself to one since ISSUE 36), and on the chip the
+    operations outside the kernels took 7.54 ms a step for 5.86 at E=40
+    and 48.1 for 40.1 at E=200 (PERF.md section 6, PR 29)."""
     e, b, h = fwd.w_ih.shape[0], x.shape[-3], fwd.hidden_size
     h0 = jnp.zeros((e, b, h), jnp.float32)
     out_f = _hidden_scan_order(fwd, x, h0, False, interpret, mesh)

@@ -700,6 +700,12 @@ class Trainer:
             "bytes a train step of the compiled superstep hands to its "
             "collectives, by kind (set under a mesh of more than one device)",
             labelnames=("op",))
+        self._m_dropout_draws = obs_metrics.REGISTRY.gauge(
+            "deeprest_train_dropout_draws",
+            "places of the compiled superstep (fusions) that generate the "
+            "dropout scope's random bits: 1 a forward pass where the mask "
+            "is drawn once and kept for the backward pass (set where the "
+            "step draws one)")
         self._m_snapshots = obs_metrics.REGISTRY.counter(
             "deeprest_train_snapshots_total",
             "preemption-safe cursor snapshots written")
@@ -779,7 +785,10 @@ class Trainer:
         more than one device, what a step hands to each kind of collective
         (``deeprest_train_collective_bytes{op}``: the partitioner decides
         what is reduced and in which type, so no sum over the gradient
-        tree would say it)."""
+        tree would say it) and in how many places a step generates
+        the dropout mask's random bits (``deeprest_train_dropout_draws``:
+        the program draws the mask once a forward pass, and the compiler
+        draws it again wherever it fuses the draw into a consumer)."""
         from deeprest_tpu.obs import profiler
 
         self._program_published = True
@@ -805,6 +814,9 @@ class Trainer:
                                                  space=space)
         for op, n in profiler.collective_bytes(text).items():
             self._m_collective_bytes.set(n, op=op)
+        draws = profiler.threefry_draws(text, scopes.DROPOUT)
+        if draws:
+            self._m_dropout_draws.set(len(draws))
 
     def _publish_optimizer_rows(self, x_base, stale=None, steps=0) -> None:
         """The epoch's ``deeprest_train_optimizer_rows``, for a staged

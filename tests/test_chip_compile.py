@@ -85,9 +85,10 @@ def _kernel_calls(compiled) -> int:
                           compiled.as_text()))
 
 
-def _model_config(dtype="bfloat16", feature_dim=F) -> ModelConfig:
-    return ModelConfig(feature_dim=feature_dim, num_metrics=E, hidden_size=H,
-                       compute_dtype=dtype, rnn_backend="pallas")
+def _model_config(dtype="bfloat16", feature_dim=F, experts=E) -> ModelConfig:
+    return ModelConfig(feature_dim=feature_dim, num_metrics=experts,
+                       hidden_size=H, compute_dtype=dtype,
+                       rnn_backend="pallas")
 
 
 def _param_shapes(cfg: ModelConfig):
@@ -236,15 +237,16 @@ def test_fused_serve_program_10k_sparse(one_chip):
 
 
 def _train_step_lowered(mesh, feature_dim, sparse, accum=1,
-                        superstep=False, batch=B):
+                        superstep=False, batch=B, experts=E):
     """``superstep``: the G=1 superstep (a 3 x 50 plan, as the 10k cell's
     126-step epoch gives; under a mesh of several chips the 1 x 32 plan of
     `tenk-train-dp4`'s 32-step epoch, split over ``data``) instead of the
     per-step program."""
-    cfg = Config(model=_model_config(feature_dim=feature_dim),
+    cfg = Config(model=_model_config(feature_dim=feature_dim,
+                                     experts=experts),
                  train=TrainConfig(batch_size=batch, window_size=W,
                                    grad_accum_windows=accum))
-    trainer = Trainer(cfg, feature_dim, [f"m{i}" for i in range(E)],
+    trainer = Trainer(cfg, feature_dim, [f"m{i}" for i in range(experts)],
                       mesh=mesh)
 
     def state():
@@ -273,7 +275,7 @@ def _train_step_lowered(mesh, feature_dim, sparse, accum=1,
                           capacity=feature_dim)
     else:
         base = sds((t_len, feature_dim), jnp.bfloat16)
-    y_base = sds((t_len, E), jnp.float32)
+    y_base = sds((t_len, experts), jnp.float32)
     if accum == 1 and not superstep:
         args = (base, y_base, sds((B,), jnp.int32), sds((B,), jnp.float32))
         return trainer._train_step_indexed.lower(state_sds, *_on(mesh, args))
@@ -296,6 +298,31 @@ def _need(mem) -> int:
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
 
 
+def _assert_masks_drawn_once(text: str, passes: int = 1, experts: int = E,
+                             rows: int = B):
+    """The dropout mask of each forward pass (ISSUE 36) is drawn once in
+    the compiled step, by a fusion that returns masks and nothing else:
+    ``pred`` arrays, a byte an element, of the joined output's ``E*B*W*2H``
+    elements, which the forward select and the ``heads`` cotangent's read
+    (the parent: the threefry in both their fusions, and no mask in
+    memory).  The accumulation superstep's ``passes`` masks, one a
+    microbatch from its own key, may come out of one fusion as siblings.
+    ``rows``: the windows one chip sees."""
+    from deeprest_tpu.obs import profiler
+    from deeprest_tpu.ops import scopes
+
+    drawn = profiler.threefry_draws(text, scopes.DROPOUT)
+    assert 1 <= len(drawn) <= passes, drawn
+    masks = [f"pred[{experts},{a},{b},{2 * H}]"
+             for a, b in ((rows, W), (W, rows))]
+    made = []
+    for name in drawn:
+        results = re.search(rf"^\s*%?{re.escape(name)} = (.*?) fusion\(",
+                            text, re.M)[1]
+        made += re.findall(r"\w+\[[\d,]*\]", results)
+    assert len(made) == passes and all(m in masks for m in made), made
+
+
 @pytest.mark.parametrize("feature_dim,sparse,accum", [
     (F, False, 1),
     (F, False, 4),
@@ -314,6 +341,7 @@ def test_train_step_fits_the_chip(one_chip, feature_dim, sparse, accum):
                                    accum).compile()
     assert _kernel_calls(compiled) == 4 * accum
     text = compiled.as_text()
+    _assert_masks_drawn_once(text, passes=accum)
     # `dropout`, `mixing` and `heads` get the two directions as ONE array
     assert f"[{E},{B},{W},{2 * H}]" in text
     assert f"[{E},{B},{W},{H}]" not in text
@@ -331,6 +359,29 @@ def compact_superstep(one_chip):
     two tests that read it."""
     return _train_step_lowered(one_chip, F_10K, "compact",
                                superstep=True).compile()
+
+
+def test_compact_superstep_draws_the_dropout_mask_once(compact_superstep):
+    """E=40: the 10k cells' program (ISSUE 36).  The temporaries' bounds
+    of the next test hold with the 19.7 MB mask in them."""
+    _assert_masks_drawn_once(compact_superstep.as_text())
+
+
+def test_dense_superstep_e200_draws_the_dropout_mask_once(one_chip):
+    """E=200: `tt-train-dense`'s program (the dense feed, F=2,048, a
+    3 x 50 plan), in which the mask is 98.3 MB; the superstep still needs
+    less than `init_state` leaves at its peak (9.317 GB: the ledger's
+    `hbm_peak_gb`) beside the staged corpus, so the peak stays
+    `init_state`'s."""
+    compiled = _train_step_lowered(one_chip, 2048, False, superstep=True,
+                                   experts=200).compile()
+    assert _kernel_calls(compiled) == 4
+    _assert_masks_drawn_once(compiled.as_text(), experts=200)
+    mem = compiled.memory_analysis()
+    print(f"dense E=200 superstep for a described v5e: temporaries "
+          f"{mem.temp_size_in_bytes / 1e9:.3f} GB, needs "
+          f"{_need(mem) / 1e9:.3f} GB")
+    assert _need(mem) < 9.25e9, mem
 
 
 def test_compact_superstep_updates_the_leaves_in_place(compact_superstep):
@@ -429,6 +480,7 @@ def test_compact_superstep_under_data4_reduces_the_compact_gradients(topo):
     text = compiled.as_text()
     assert _kernel_calls(compiled) == 4
     assert "all-gather" not in text
+    _assert_masks_drawn_once(text)          # each chip's own 32 windows
     moved = profiler.collective_bytes(text)
     print(f"compact 10k superstep under data=4 for a described v5e:2x2: "
           f"collectives a step {moved}")

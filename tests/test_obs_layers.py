@@ -638,34 +638,41 @@ _KERNEL_BYTES = {("gru_kernel_fwd", "vmem"): 1.0, ("gru_kernel_fwd", "hbm"): 2.0
 
 
 @pytest.mark.parametrize("reader, metric, labels, series, expected", [
-    ("init_state_s", obs_setup.INIT_STATE_SECONDS, ("phase",),
+    ("setup:init_state_s", obs_setup.INIT_STATE_SECONDS, ("phase",),
      {("model_init",): 3.0, ("pin",): 0.5}, 3.5),
-    ("compile_s", obs_setup.COMPILE_SECONDS, ("program", "phase"),
+    ("setup:compile_s", obs_setup.COMPILE_SECONDS, ("program", "phase"),
      {("other", "other"): 9.0, ("other", "init_state"): 2.0,
       ("train_superstep", "other"): 5.0}, 7.0),
-    ("compilations", obs_setup.COMPILATIONS, ("program", "phase", "cache"),
+    ("setup:compilations", obs_setup.COMPILATIONS,
+     ("program", "phase", "cache"),
      {("other", "other", "hit"): 4, ("other", "init_state", "hit"): 40,
       ("train_superstep", "other", "miss"): 1}, 41),
-    ("init_state_peak_gb", obs_setup.DEVICE_BYTES, ("at", "kind"),
+    ("setup:init_state_peak_gb", obs_setup.DEVICE_BYTES, ("at", "kind"),
      {("init_state", "peak"): 8.9e9, ("init_state", "in_use"): 7.2e9,
       ("first_epoch", "in_use"): 4.5e9}, 8.9),
-    ("steady_hbm_gb", obs_setup.DEVICE_BYTES, ("at", "kind"),
+    ("setup:steady_hbm_gb", obs_setup.DEVICE_BYTES, ("at", "kind"),
      {("init_state", "peak"): 8.9e9, ("first_epoch", "in_use"): 4.5e9,
       ("first_epoch", "peak"): 8.9e9}, 4.5),
-    ("gru_kernel_vmem_pct", obs_setup.KERNEL_OPERAND_BYTES,
+    ("setup:gru_kernel_vmem_pct", obs_setup.KERNEL_OPERAND_BYTES,
      ("kernel", "space"), _KERNEL_BYTES, 12.5),
+    # ISSUE 36: how often the compiled step draws the dropout mask
+    ("dropout_draws:draws_per_step", "deeprest_train_dropout_draws", (),
+     {(): 1.0}, 1.0),
 ])
 def test_the_setup_readers(monkeypatch, reader, metric, labels, series,
                            expected):
-    """chipbench/readers/setup.py: nothing (not an error) from a program
-    without the gauge or with the gauge never set, the value with it
-    set."""
-    from chipbench.readers import setup as readers
+    """chipbench/readers/setup.py and dropout_draws.py: nothing (not an
+    error) from a program without the gauge or with the gauge never set,
+    the value with it set."""
+    import importlib
+
     from deeprest_tpu.obs import metrics
 
     registry = metrics.MetricsRegistry()
     monkeypatch.setattr(metrics, "REGISTRY", registry)
-    read = getattr(readers, reader)
+    module, func = reader.split(":")
+    read = getattr(importlib.import_module(f"chipbench.readers.{module}"),
+                   func)
     assert read({}) is None
     made = (registry.counter if metric.endswith("_total")
             else registry.gauge)(metric, labelnames=labels)
