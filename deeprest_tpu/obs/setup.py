@@ -26,7 +26,8 @@ This module holds the three pieces that are not one trainer's:
   no span: it would be a trace of its own in the plane's exports).  A compilation in phase ``epoch``
   after the first epoch is a recompile, with its name.
 - :func:`setup_table` / :func:`format_setup` — the set-up gauges of the
-  process registry as one table (``Trainer.profile_epoch``'s ``setup``,
+  process registry (the form a staged sparse corpus took among them) as
+  one table (``Trainer.profile_epoch``'s ``setup``,
   ``layers.json``) and as the one line ``deeprest_tpu train`` prints when
   its first epoch is done.  The names are this module's constants; the
   trainer sets them.
@@ -49,6 +50,7 @@ COMPILATIONS = "deeprest_compilations_total"
 COMPILE_SECONDS = "deeprest_compile_seconds_total"
 INIT_STATE_SECONDS = "deeprest_train_init_state_seconds"
 STAGE_SECONDS = "deeprest_train_last_stage_seconds"
+PROJECTION_COLUMNS = "deeprest_train_projection_columns"
 FIRST_DISPATCH_SECONDS = "deeprest_train_first_dispatch_seconds"
 DEVICE_BYTES = "deeprest_train_device_bytes"
 PROGRAM_BYTES = "deeprest_train_program_bytes"
@@ -190,7 +192,10 @@ def _by(name: str, *labels: str) -> dict:
 
 def setup_table() -> dict:
     """The set-up gauges as they stand: seconds of ``init_state`` by phase,
-    of the last ``stage_dataset``, of each first dispatch; the compilations
+    of the last ``stage_dataset`` and, for a sparse corpus, the form its
+    rule chose (``sparse_feed``: ``form``, and the columns ``live``,
+    ``padded``, ``bound``, ``contracted``, ``total``), of each first
+    dispatch; the compilations
     by program and phase (count, seconds, misses); device memory at the
     three moments; the superstep executable's bytes and where its kernels'
     operands live.  What was never set is left out."""
@@ -207,9 +212,15 @@ def setup_table() -> dict:
         if s["cache"] != "hit":
             row["misses"] += int(n)
     stage = _series(STAGE_SECONDS)
+    columns = {k: int(v) for k, v in _by(PROJECTION_COLUMNS, "kind").items()}
+    feed = None
+    if columns.get("total"):            # a sparse corpus was staged
+        compact = columns["contracted"] < columns["total"]
+        feed = {"form": "compact" if compact else "dense", **columns}
     table = {
         "init_state_seconds": _by(INIT_STATE_SECONDS, "phase"),
         "stage_seconds": stage[0][1] if stage else None,
+        "sparse_feed": feed,
         "first_dispatch_seconds": _by(FIRST_DISPATCH_SECONDS, "program"),
         "compilations": sorted(compilations.values(),
                                key=lambda r: -r["seconds"]),
@@ -235,6 +246,13 @@ def format_setup(table: dict) -> str:
                      f"({seconds(found)})")
     if "stage_seconds" in table:
         parts.append(f"stage {table['stage_seconds']:.3f} s")
+    if "sparse_feed" in table:
+        feed = table["sparse_feed"]
+        parts.append(
+            f"sparse feed {feed['form']} ({feed['live']} live call paths "
+            f"of {feed['total']}, padded to {feed['padded']}, bound "
+            f"{feed['bound'] or 'the model axis'}, {feed['contracted']} "
+            "contracted)")
     if "first_dispatch_seconds" in table:
         parts.append("first dispatch "
                      + seconds(table["first_dispatch_seconds"]) + " s")
@@ -262,5 +280,6 @@ def format_setup(table: dict) -> str:
 
 __all__ = ["OTHER", "UNCACHED", "phase", "current_phase", "install",
            "setup_table", "format_setup", "COMPILATIONS", "COMPILE_SECONDS",
-           "INIT_STATE_SECONDS", "STAGE_SECONDS", "FIRST_DISPATCH_SECONDS",
-           "DEVICE_BYTES", "PROGRAM_BYTES", "KERNEL_OPERAND_BYTES"]
+           "INIT_STATE_SECONDS", "STAGE_SECONDS", "PROJECTION_COLUMNS",
+           "FIRST_DISPATCH_SECONDS", "DEVICE_BYTES", "PROGRAM_BYTES",
+           "KERNEL_OPERAND_BYTES"]

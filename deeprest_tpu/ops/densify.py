@@ -120,10 +120,13 @@ if _HAVE_JAX:
         compares itself with the row's K entries and sums the values that
         hit.  The same bits (at most one real entry hits, the rest add
         exact zeros), K x ``width`` compares a row where the scatter takes
-        one serial update an entry: 0.09 / 0.16 / 0.36 / 0.82 ms at a
-        width of 256 / 1024 / 2048 / 4096 against the scatter's 1.0-1.2 ms
-        at any width (1,920 rows of K=64 on a TPU v5e; PERF.md, PR 25),
-        so it is taken up to :data:`COMPARE_MAX_WIDTH` columns."""
+        one serial update an entry.  PR 25's readings, the only ones there
+        are (1,920 rows of K=64 on a TPU v5e): 0.09 / 0.16 / 0.36 / 0.82 ms
+        at a width of 256 / 1024 / 2048 / 4096 against the scatter's
+        1.0-1.2 ms at any width, so it is taken up to
+        :data:`COMPARE_MAX_WIDTH` columns; the scatter at 10,240 columns
+        runs in the cell ``tenk-train-live4k`` since PR 38 (PERF.md
+        section 5 has its ``densify`` row)."""
         hit = cols[..., :, None] == jnp.arange(width, dtype=cols.dtype)
         return jnp.sum(jnp.where(hit, vals[..., :, None], 0.0), axis=-2)
 
@@ -263,20 +266,36 @@ def live_columns(cols: np.ndarray, vals: np.ndarray, mn: np.ndarray,
     return np.flatnonzero(seen | shifted).astype(np.int32)
 
 
+def compact_rule(n_live: int, capacity: int) -> tuple[int, int]:
+    """What the rule of the compact form weighs: ``(padded, bound)``.
+    ``padded`` is the next power of two at or above ``max(n_live,
+    MIN_COMPACT_WIDTH)`` (so a live set that grows from one staging to the
+    next meets a handful of shapes, not one each); ``bound`` is the widest
+    table the rule admits, ``capacity // 4``.  The form is compact when
+    ``padded <= bound``.
+
+    Why a quarter: what the compact form saves grows with F, and what its
+    takes, its puts and its matmuls cost grows with the table.  The
+    constant is a reading of PR 25 on a TPU v5e at F = 10,240 (a table of
+    2,048 trained 8% faster than the dense form, one of 4,096 14% slower)
+    and has not moved since, though the compact side alone got cheaper
+    three times (PRs 27, 32 and 34: Adam over the table's rows, the rows
+    carried through the superstep's scan, the off-table pass by stale
+    rows).  PR 38 read both sides again with nothing changed here: PERF.md
+    section 6; the cell ``tenk-train-live4k`` runs the first padded width
+    over the bound."""
+    padded = max(MIN_COMPACT_WIDTH, 1 << max(n_live - 1, 0).bit_length())
+    return padded, capacity // 4
+
+
 def compact_table(live: np.ndarray, capacity: int) -> np.ndarray | None:
     """The ``[U_pad]`` table of the compact form, or None where the dense
-    form is kept: ``U_pad`` is the next power of two at or above
-    ``max(len(live), MIN_COMPACT_WIDTH)`` (so a live set that grows from
-    one staging to the next meets a handful of shapes, not one each), and
-    the form is compact when ``U_pad <= capacity // 4``: what the compact
-    form saves grows with F and what its takes, their layout copies and
-    its matmuls cost grows with the table, and on a TPU v5e at F = 10,240
-    a table of 2,048 trains 8% faster than the dense form and one of
-    4,096 14% slower (PERF.md, PR 25).  The pad slots are the lowest dead
-    columns, so the sorted table names ``U_pad`` distinct columns and
-    every pad slot's input is exactly 0."""
-    u_pad = max(MIN_COMPACT_WIDTH, 1 << max(len(live) - 1, 0).bit_length())
-    if u_pad > capacity // 4:
+    form is kept (:func:`compact_rule`: the padded live set is over the
+    bound).  The pad slots are the lowest dead columns, so the sorted
+    table names ``U_pad`` distinct columns and every pad slot's input is
+    exactly 0."""
+    u_pad, bound = compact_rule(len(live), capacity)
+    if u_pad > bound:
         return None
     dead = np.setdiff1d(np.arange(capacity, dtype=np.int32), live,
                         assume_unique=True)
@@ -302,6 +321,7 @@ __all__ = [
     "DEFAULT_NNZ_CAP",
     "MIN_COMPACT_WIDTH",
     "compact_rows",
+    "compact_rule",
     "compact_table",
     "live_columns",
     "densify_rows",

@@ -37,7 +37,8 @@ from deeprest_tpu.obs import spans as obs_spans
 from deeprest_tpu.obs.phases import PhaseClock
 from deeprest_tpu.ops import scopes
 from deeprest_tpu.ops.densify import (
-    SparseBase, compact_table, gather_densify_normalize, live_columns,
+    SparseBase, compact_rule, compact_table, gather_densify_normalize,
+    live_columns,
 )
 from deeprest_tpu.ops.quantile import pinball_loss
 from deeprest_tpu.parallel.distributed import (
@@ -250,10 +251,13 @@ class Trainer:
         # recent train_epoch's dispatches: profile_epoch lowers exactly
         # this to name the trace's operations.
         self._dispatched: tuple | None = None
-        # Whether stage_dataset has run, and its last call's compact table
-        # (host copy; None for a feed without one): the stage span's tags.
+        # Whether stage_dataset has run, its last call's compact table
+        # (host copy; None for a feed without one) and what the rule of the
+        # compact form decided there (empty for a feed that is not sparse):
+        # the stage span's tags.
         self._staged_before = False
         self._staged_table: np.ndarray | None = None
+        self._staged_form: dict = {}
         # Whether a train_epoch has finished (device memory is read once,
         # after the first).
         self._epoch_finished = False
@@ -649,9 +653,12 @@ class Trainer:
             units_total=obs_metrics.REGISTRY.counter(
                 "deeprest_train_epochs_total", "train epochs finished"))
         self._m_projection_columns = obs_metrics.REGISTRY.gauge(
-            "deeprest_train_projection_columns",
+            obs_setup.PROJECTION_COLUMNS,
             "columns of the staged sparse corpus: live (can be nonzero), "
-            "contracted (the layer-0 projection sums over), total (F)",
+            "padded (the power of two the rule of the compact form "
+            "weighed), bound (the widest table it admits; 0 where the "
+            "mesh's model axis shards F), contracted (the layer-0 "
+            "projection sums over), total (F)",
             labelnames=("kind",))
         self._m_optimizer_rows = obs_metrics.REGISTRY.gauge(
             "deeprest_train_optimizer_rows",
@@ -1248,7 +1255,11 @@ class Trainer:
         train/stream.py, a resumed run on another week): each call is one
         span ``deeprest-trainer/train.stage``, tagged ``restage`` (this
         trainer has staged before), ``width`` (the compact table's, else
-        the columns staged, 0 for nothing) and, from one table to the
+        the columns staged, 0 for nothing), for a sparse corpus what the
+        rule of the compact form decided (``form`` ``compact`` or
+        ``dense``, the ``live`` set's size, the ``padded`` width it
+        weighed and the ``bound`` it held it to, ``model_axis`` where the
+        mesh's ``model`` axis decided) and, from one table to the
         next, the rows that ``left`` and ``entered`` it — the rows that
         left are the ones the carried moments make stale
         (:func:`stale_rows`); its host seconds are the gauge
@@ -1261,11 +1272,13 @@ class Trainer:
                                      "deeprest-trainer") as span, \
                 obs_setup.phase("stage"):
             before, self._staged_table = self._staged_table, None
+            self._staged_form = {}
             staged = self._stage(bundle)
             table = self._staged_table
             tags = {"restage": self._staged_before,
                     "width": (len(table) if table is not None else
-                              0 if staged is None else bundle.feature_dim)}
+                              0 if staged is None else bundle.feature_dim),
+                    **self._staged_form}
             if before is not None and table is not None:
                 tags["left"] = int(np.setdiff1d(before, table).size)
                 tags["entered"] = int(np.setdiff1d(table, before).size)
@@ -1353,12 +1366,18 @@ class Trainer:
         vals = np.ascontiguousarray(bundle.x_vals, dtype=np.float32)
         capacity = int(bundle.sparse_capacity or bundle.feature_dim)
         live = live_columns(cols, vals, mn, rg, capacity)
-        table = (compact_table(live, capacity)
-                 if self.mesh.shape["model"] == 1 else None)
+        padded, bound = compact_rule(len(live), capacity)
+        sharded = self.mesh.shape["model"] != 1
+        table = None if sharded else compact_table(live, capacity)
         self._staged_table = table
+        self._staged_form = {
+            "form": "dense" if table is None else "compact",
+            "live": len(live), "padded": padded,
+            "bound": "model_axis" if sharded else bound}
         contracted = capacity if table is None else len(table)
-        for kind, n in (("live", len(live)), ("contracted", contracted),
-                        ("total", capacity)):
+        for kind, n in (("live", len(live)), ("padded", padded),
+                        ("bound", 0 if sharded else bound),
+                        ("contracted", contracted), ("total", capacity)):
             self._m_projection_columns.set(n, kind=kind)
         base = stage_sparse_base(self.mesh, cols, vals, mn, rg, capacity,
                                  live=table)

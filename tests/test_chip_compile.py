@@ -384,6 +384,31 @@ def test_dense_superstep_e200_draws_the_dropout_mask_once(one_chip):
     assert _need(mem) < 9.25e9, mem
 
 
+def test_dense_form_10k_superstep_is_the_live4k_cells_program(one_chip):
+    """The sparse base in its DENSE form at the 10k width (ISSUE 38: a live
+    set over the rule's bound, `tenk-train-live4k`'s program, a 3 x 50
+    plan): the scatter builds `[32,60,10240]` windows, the projection
+    contracts over all F columns of the bf16 folded weights, both w_ih
+    gradients are whole leaves; with the 4.46 GB of state it needs less than
+    `init_state` leaves at its peak (8.924 GB: the 10k cells'
+    `hbm_peak_gb`), so the peak stays `init_state`'s."""
+    compiled = _train_step_lowered(one_chip, F_10K, True,
+                                   superstep=True).compile()
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    print(f"dense-form 10k superstep for a described v5e: temporaries "
+          f"{mem.temp_size_in_bytes / 1e9:.3f} GB, arguments "
+          f"{mem.argument_size_in_bytes / 1e9:.3f} GB, needs "
+          f"{_need(mem) / 1e9:.3f} GB, code "
+          f"{mem.generated_code_size_in_bytes / 1e6:.1f} MB; whole-leaf "
+          f"copy operations {_whole_leaf_copies(text)}")
+    assert _kernel_calls(compiled) == 4
+    _assert_masks_drawn_once(text)
+    assert f"[{B},{W},{F_10K}]" in text                  # the dense windows
+    assert f"bf16[{E},{F_10K},{3 * H}]" in text          # the folded weights
+    assert _need(mem) < 8.9e9, mem
+
+
 def test_compact_superstep_updates_the_leaves_in_place(compact_superstep):
     """The compact 10k superstep (ISSUE 32), the guard of its mechanism in
     tier-1 (8-10 s): the table's rows of the two w_ih leaves and of their
