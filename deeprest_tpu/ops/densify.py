@@ -32,7 +32,8 @@ that DID.  A column is *live* when its normalized value can be nonzero in
 some staged row (:func:`live_columns`); every other column is exactly 0.0
 in every window, and a product with it adds nothing to the layer-0
 projection.  When the live set, padded to a power of two
-(:func:`compact_table`), is at most a quarter of F, the trainer stages the
+(:func:`compact_table`), is at most half of F (:func:`compact_rule` and
+the readings it stands on), the trainer stages the
 base with that table (``SparseBase.live``): ``cols`` are then RANKS in the
 table, the statistics are taken at the table, and
 :func:`gather_densify_normalize` builds ``[..., W, U_pad]`` windows whose
@@ -124,9 +125,14 @@ if _HAVE_JAX:
         are (1,920 rows of K=64 on a TPU v5e): 0.09 / 0.16 / 0.36 / 0.82 ms
         at a width of 256 / 1024 / 2048 / 4096 against the scatter's
         1.0-1.2 ms at any width, so it is taken up to
-        :data:`COMPARE_MAX_WIDTH` columns; the scatter at 10,240 columns
-        runs in the cell ``tenk-train-live4k`` since PR 38 (PERF.md
-        section 5 has its ``densify`` row)."""
+        :data:`COMPARE_MAX_WIDTH` columns, which since PR 39 is the widest
+        table :func:`compact_rule` admits at F = 10,240: the cell
+        ``tenk-train-live4k`` runs it there (0.81 ms in its windows, PR
+        38's reading, against the scatter's 1.35 into 10,240 columns).
+        The scatter is left to the dense form (a live set over the bound,
+        F sharded over the mesh's ``model`` axis), to a table wider than
+        4,096 at a larger F, and to serving; no benchmark cell runs it in
+        training since PR 39 (PERF.md section 7)."""
         hit = cols[..., :, None] == jnp.arange(width, dtype=cols.dtype)
         return jnp.sum(jnp.where(hit, vals[..., :, None], 0.0), axis=-2)
 
@@ -271,21 +277,28 @@ def compact_rule(n_live: int, capacity: int) -> tuple[int, int]:
     ``padded`` is the next power of two at or above ``max(n_live,
     MIN_COMPACT_WIDTH)`` (so a live set that grows from one staging to the
     next meets a handful of shapes, not one each); ``bound`` is the widest
-    table the rule admits, ``capacity // 4``.  The form is compact when
+    table the rule admits, ``capacity // 2``.  The form is compact when
     ``padded <= bound``.
 
-    Why a quarter: what the compact form saves grows with F, and what its
-    takes, its puts and its matmuls cost grows with the table.  The
-    constant is a reading of PR 25 on a TPU v5e at F = 10,240 (a table of
-    2,048 trained 8% faster than the dense form, one of 4,096 14% slower)
-    and has not moved since, though the compact side alone got cheaper
-    three times (PRs 27, 32 and 34: Adam over the table's rows, the rows
+    Why a half, from readings at F = 10,240, E = 40, H = 128 on a TPU v5e
+    (steps/s of 32 windows; PERF.md section 6 has them by scope).  PR 25
+    read a table of 2,048 8% faster than the dense form and one of 4,096
+    14% slower, and put the bound at a quarter; PRs 27, 32 and 34 then
+    shortened the compact side alone (Adam over the table's rows, the rows
     carried through the superstep's scan, the off-table pass by stale
-    rows).  PR 38 read both sides again with nothing changed here: PERF.md
-    section 6; the cell ``tenk-train-live4k`` runs the first padded width
-    over the bound."""
+    rows).  PR 38 read both sides again: dense 31.74, a table of 4,096
+    62.66, one of 2,048 95.67.  PR 39 read 62.65-62.70 at 4,096 over four
+    seeds and two processes, and a table of 8,192 at 35.52 against the
+    dense form's 31.73: a step costs 14.9 / 26.4 / 30.9 ms (4,096 / 8,192 /
+    dense) and a DISPATCH 42 / 69 / 23 ms on top (the takes and puts of the
+    table's rows cost by its width; the dense form copies its six leaves
+    into the dot's layout and back).  So at 50 steps a dispatch both tables
+    win, but at 2 (what ``stream``'s refreshes run) 4,096 still wins, 27.73
+    against 23.60, and 8,192 loses, 16.37: the bound admits 4,096 and not
+    8,192, and a power-of-two table is at most half of F at any F.  The
+    cell ``tenk-train-live4k`` runs the widest table it admits there."""
     padded = max(MIN_COMPACT_WIDTH, 1 << max(n_live - 1, 0).bit_length())
-    return padded, capacity // 4
+    return padded, capacity // 2
 
 
 def compact_table(live: np.ndarray, capacity: int) -> np.ndarray | None:

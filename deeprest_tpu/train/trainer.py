@@ -1345,8 +1345,9 @@ class Trainer:
         The form is chosen here, from the corpus and the mesh alone: the
         compact form (ops/densify.py: windows and the layer-0 contraction
         over the live call paths only) when the padded live set is at
-        most a quarter of F and the mesh's ``model`` axis, which shards
-        F, is 1; the dense form otherwise."""
+        most half of F (``compact_rule``, whose docstring names the chip
+        readings behind the bound) and the mesh's ``model`` axis, which
+        shards F, is 1; the dense form otherwise."""
         cfg = self.config.train
         if bundle.y_base is None:
             raise ValueError("sparse bundle lacks y_base; the targets "

@@ -237,11 +237,11 @@ def test_fused_serve_program_10k_sparse(one_chip):
 
 
 def _train_step_lowered(mesh, feature_dim, sparse, accum=1,
-                        superstep=False, batch=B, experts=E):
+                        superstep=False, batch=B, experts=E, table=U_LIVE):
     """``superstep``: the G=1 superstep (a 3 x 50 plan, as the 10k cell's
     126-step epoch gives; under a mesh of several chips the 1 x 32 plan of
     `tenk-train-dp4`'s 32-step epoch, split over ``data``) instead of the
-    per-step program."""
+    per-step program.  ``table``: the width of the compact form's table."""
     cfg = Config(model=_model_config(feature_dim=feature_dim,
                                      experts=experts),
                  train=TrainConfig(batch_size=batch, window_size=W,
@@ -264,8 +264,8 @@ def _train_step_lowered(mesh, feature_dim, sparse, accum=1,
     sds = jax.ShapeDtypeStruct
     if sparse:
         # "compact": the form the trainer stages when few of the call
-        # paths are live (ops/densify.py), here a table of U_LIVE columns
-        width = U_LIVE if sparse == "compact" else feature_dim
+        # paths are live (ops/densify.py), here a table of `table` columns
+        width = table if sparse == "compact" else feature_dim
         base = SparseBase(cols=sds((t_len, NNZ_CAP), jnp.int32),
                           vals=sds((t_len, NNZ_CAP), jnp.float32),
                           mn=sds((width,), jnp.float32),
@@ -384,9 +384,11 @@ def test_dense_superstep_e200_draws_the_dropout_mask_once(one_chip):
     assert _need(mem) < 9.25e9, mem
 
 
-def test_dense_form_10k_superstep_is_the_live4k_cells_program(one_chip):
-    """The sparse base in its DENSE form at the 10k width (ISSUE 38: a live
-    set over the rule's bound, `tenk-train-live4k`'s program, a 3 x 50
+def test_dense_form_10k_superstep_is_the_all_live_program(one_chip):
+    """The sparse base in its DENSE form at the 10k width (ISSUE 38; since
+    ISSUE 39 moved the rule's bound to F // 2 the program of a live set
+    over 4,096 paths, the all-live corpus among them, and of a mesh whose
+    `model` axis shards F; no benchmark cell runs it any more; a 3 x 50
     plan): the scatter builds `[32,60,10240]` windows, the projection
     contracts over all F columns of the bf16 folded weights, both w_ih
     gradients are whole leaves; with the 4.46 GB of state it needs less than
@@ -407,6 +409,40 @@ def test_dense_form_10k_superstep_is_the_live4k_cells_program(one_chip):
     assert f"[{B},{W},{F_10K}]" in text                  # the dense windows
     assert f"bf16[{E},{F_10K},{3 * H}]" in text          # the folded weights
     assert _need(mem) < 8.9e9, mem
+
+
+def test_compact_superstep_at_the_widest_table_is_the_live4k_cells_program(
+        one_chip):
+    """`tenk-train-live4k`'s program since ISSUE 39: the compact superstep
+    at the widest table the rule admits at F = 10,240, 4,096 columns (a
+    3 x 50 plan).  Windows and folded weights are the table's width and
+    never F wide, the six `[40,4096,384]` arrays of rows ride the scan as
+    the 256-wide ones do (ten `while`s, no whole-leaf copy), and with the
+    4.46 GB of state it needs 6.39 GB (temporaries 1.930): 2.5 GB under
+    what `init_state` leaves at its peak (8.924 GB, the cell's
+    `hbm_peak_gb`), so the peak stays `init_state`'s.  (A table of 8,192,
+    which the rule does not admit, compiles to 3.554 GB of temporaries and
+    8.015 GB needed: ISSUE 39's reading, not kept as a case.)"""
+    table = 4096
+    compiled = _train_step_lowered(one_chip, F_10K, "compact",
+                                   superstep=True, table=table).compile()
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    print(f"compact 10k superstep, a table of {table}, for a described "
+          f"v5e: temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB, needs "
+          f"{_need(mem) / 1e9:.3f} GB, code "
+          f"{mem.generated_code_size_in_bytes / 1e6:.1f} MB; whole-leaf "
+          f"copy operations {_whole_leaf_copies(text)}")
+    assert _kernel_calls(compiled) == 4
+    _assert_masks_drawn_once(text)
+    assert f"[{B},{W},{table}]" in text                  # the table's windows
+    assert f"[{B},{W},{F_10K}]" not in text
+    assert f"bf16[{E},{F_10K},{3 * H}]" not in text
+    assert len(re.findall(r" while[(]", text)) == 10
+    assert _whole_leaf_copies(text) == 0
+    assert mem.temp_size_in_bytes == pytest.approx(1.930e9, rel=0.03), mem
+    assert _need(mem) < 6.5e9, mem
+    assert mem.generated_code_size_in_bytes <= 20e6, mem
 
 
 def test_compact_superstep_updates_the_leaves_in_place(compact_superstep):

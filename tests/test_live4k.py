@@ -1,18 +1,22 @@
-"""`endpoints-10k-live4k` (ISSUE 38): the sparse feed on both sides of the
-rule of its compact form, held to the plain reference on the CPU at a small
-size, and the rule itself held to what it was.
+"""`endpoints-10k-live4k` (ISSUEs 38 and 39): the sparse feed on both sides
+of the rule of its compact form, held to the plain reference on the CPU at a
+small size, and the rule itself held to where ISSUE 39 moved it.
 
 The deployment's guarantee: every nonzero call-path count reaches the
 layer-0 sum and every step is plain Adam on every row of every leaf, so the
 result is the dense model's to float tolerance whichever form of the feed
 computes it.  Here the same seeded corpus is staged with a live set that pads
-to exactly `F // 4` (the compact form) and with one that pads over it (the
-dense form: the scatter `densify_coo` into F columns, the F-wide
-projection, whole-leaf Adam), and three steps of the window's own compiled
-superstep are compared with `chipbench/reference/qrnn_ref.py`.  On the chip
-the benchmark's cell `tenk-train-live4k` makes the dense side's comparison
-at the configuration's widths in bfloat16 (chipbench/limits/); here it is
-float32 at toy widths.  No number of this file is a device number.
+to exactly the rule's bound, `F // 2` (the compact form at its widest
+table), and with one path more, which pads over it (the dense form: the
+scatter `densify_coo` into F columns, the F-wide projection, whole-leaf
+Adam), and three steps of the window's own compiled superstep are compared
+with `chipbench/reference/qrnn_ref.py`.  On the chip the benchmark's cell
+`tenk-train-live4k` makes the COMPACT side's comparison since ISSUE 39 (a
+table of 4,096 of 10,240, under the bound of 5,120) at the configuration's
+widths in bfloat16 (chipbench/limits/); the dense form of a sparse base is in
+no cell, so these cases and `test_the_model_axis_decides_before_the_rule`
+are its guard.  Here it is float32 at toy widths.  No number of this file is
+a device number.
 """
 
 import json
@@ -45,19 +49,21 @@ RESOURCES = ["cpu", "memory", "write-iops", "write-tp", "usage"]
 QUANTILES = (0.05, 0.5, 0.95)
 SEED = 3_000_000_038           # as large as the driver's
 
-# 2 components x 5 resources over 512 hashed call paths, float32.  128 live
-# paths pad to 128 = F // 4, the widest table the rule admits; 200 pad to
-# 256, over it.
+# 2 components x 5 resources over 512 hashed call paths, float32.  256 live
+# paths pad to 256 = F // 2, the widest table the rule admits; 257 pad to
+# 512, over it.
 E, F, H, W, B, K = 10, 512, 8, 6, 4, 16
 DIMS = (E, F, H, len(QUANTILES))
-SIDES = {"compact": 128, "dense": 200}
+BOUND = F // 2
+SIDES = {"compact": 256, "dense": 257}
+PADDED = {"compact": 256, "dense": 512}
 KINDS = ("live", "padded", "bound", "contracted", "total")
 
 # Program and reference both compute in float32 here, the reference at
 # `highest`: what is left is the order of the sums (the compact form leaves
 # exact zeros out of the layer-0 sum, the dense form sums over all F).  Read
-# at this size: 1.3e-7 / 1.6e-7 (loss), 3.3e-7 / 3.4e-7 (first gradient),
-# 6.6e-8 / 6.2e-8 (the leaves' change), compact / dense.  The limits leave
+# at this size: 1.2e-7 / 1.2e-7 (loss), 5.2e-7 / 8.7e-7 (first gradient),
+# 8.2e-8 / 8.8e-8 (the leaves' change), compact / dense.  The limits leave
 # ten times that and no more, test_trainticket_config.py's rule: the
 # reference with bfloat16 operands reads 1e-4 and more at such a size.
 TOLERANCE = {"loss_rel_gap": 2e-6, "grad_norm_gap": 5e-6,
@@ -158,17 +164,17 @@ def test_the_corpus_was_staged_in_the_form_its_side_names(side):
     assert isinstance(base, SparseBase) and base.capacity == F
     assert side["live"] == SIDES[side["form"]]      # every hot path was hit
     if side["form"] == "compact":
-        assert base.width == F // 4 == len(np.asarray(base.live))
+        assert base.width == BOUND == len(np.asarray(base.live))
     else:
         assert base.live is None and base.width == F
 
 
 def test_the_stage_span_says_what_the_rule_weighed(side):
     tags = side["tags"]
-    padded = {"compact": 128, "dense": 256}[side["form"]]
+    padded = PADDED[side["form"]]
     assert {k: tags[k] for k in ("form", "live", "padded", "bound")} == {
         "form": side["form"], "live": side["live"], "padded": padded,
-        "bound": F // 4}
+        "bound": BOUND}
     assert tags["width"] == (padded if side["form"] == "compact" else F)
     assert tags["restage"] is False
 
@@ -176,16 +182,16 @@ def test_the_stage_span_says_what_the_rule_weighed(side):
 def test_the_gauge_has_five_kinds(side):
     compact = side["form"] == "compact"
     assert side["gauge"] == {
-        "live": side["live"], "padded": 128 if compact else 256,
-        "bound": F // 4, "contracted": 128 if compact else F, "total": F}
+        "live": side["live"], "padded": PADDED[side["form"]],
+        "bound": BOUND, "contracted": BOUND if compact else F, "total": F}
 
 
 def test_the_set_up_line_carries_the_form(side):
     line, live = side["line"], side["live"]
     want = {"compact": f"sparse feed compact ({live} live call paths of 512, "
-                       "padded to 128, bound 128, 128 contracted)",
+                       "padded to 256, bound 256, 256 contracted)",
             "dense": f"sparse feed dense ({live} live call paths of 512, "
-                     "padded to 256, bound 128, 512 contracted)"}
+                     "padded to 512, bound 256, 512 contracted)"}
     assert line.startswith("set-up: ") and "\n" not in line
     assert want[side["form"]] in line
     assert line.index("stage ") < line.index("sparse feed ")
@@ -234,13 +240,96 @@ def test_no_gauge_no_sparse_feed_in_the_table(monkeypatch):
     assert "sparse_feed" not in obs_setup.setup_table()
 
 
-# -- the rule is what it was ----------------------------------------------
+# -- either side of the bound through `stage_dataset`, and across a restage --
 
 
-def _parents_table(live, capacity):
-    """`compact_table` as the parent commit had it, line for line."""
+@pytest.mark.parametrize("hot, form, padded", [(256, "compact", 256),
+                                               (257, "dense", 512)])
+def test_a_live_set_at_the_bound_and_one_path_over_it(hot, form, padded):
+    """ISSUE 39's edge on another corpus than the `side` fixture's: the
+    widest live set the rule admits and one path more, and the four places
+    that say which form the staging took."""
+    from test_live_columns import _bundle, _corpus, _trainer
+
+    staged, tags = _staged_with_tags(_trainer(),
+                                     _bundle(*_corpus(hot)[:3]))
+    contracted = padded if form == "compact" else F
+    assert (staged[0].live is None) == (form == "dense")
+    assert staged[0].width == contracted
+    assert {k: tags[k] for k in ("form", "live", "padded", "bound",
+                                 "width")} == {
+        "form": form, "live": hot, "padded": padded, "bound": BOUND,
+        "width": contracted}
+    assert _gauge() == {"live": hot, "padded": padded, "bound": BOUND,
+                        "contracted": contracted, "total": F}
+    assert (f"sparse feed {form} ({hot} live call paths of {F}, padded to "
+            f"{padded}, bound {BOUND}, {contracted} contracted)"
+            ) in obs_setup.format_setup(obs_setup.setup_table())
+
+
+def test_a_restage_to_the_widest_table_and_back_reuses_both_executables():
+    """What `stream` meets when a tenant's live set grows past the old bound
+    and shrinks again, on ONE trainer and state: a table of 128, one of 256
+    (the widest the rule admits at this F: `tenk-train-live4k`'s 4,096 at
+    F = 10,240 after a week at 256), then the first corpus again.  Each
+    width compiles the superstep once and the way back compiles nothing;
+    the rows that left the table keep their moments and are stepped by the
+    off-table pass; a row no corpus ever lit stays where `init_state` put
+    it, bit for bit."""
+    from test_live_columns import W as w_lc
+    from test_live_columns import _bundle, _corpus, _trainer
+
+    narrow, wide = _corpus(100), _corpus(200)
+    bundles = {"narrow": _bundle(*narrow[:3]), "wide": _bundle(*wide[:3])}
+    trainer = _trainer(steps_per_superstep=8)
+    state = trainer.init_state(np.zeros((1, w_lc, F), np.float32), seed=0)
+    start = np.asarray(state.params["gru_fwd_w_ih"])
+    rows = REGISTRY.get("deeprest_train_optimizer_rows")
+    seen = []
+    for name in ("narrow", "wide", "narrow"):
+        staged, tags = _staged_with_tags(trainer, bundles[name])
+        before = np.asarray(state.params["gru_fwd_w_ih"])
+        state, loss = trainer.train_epoch(state, bundles[name],
+                                          np.random.default_rng(0),
+                                          staged=staged)
+        assert np.isfinite(loss)
+        moved = np.flatnonzero(
+            (np.asarray(state.params["gru_fwd_w_ih"]) != before
+             ).any(axis=(0, 2)))
+        seen.append({
+            "form": tags["form"], "width": tags["width"],
+            "restage": tags["restage"],
+            "executables": trainer._superstep._cache_size(),
+            "stale": int(rows.value(kind="stale")),
+            "table": np.asarray(staged[0].live), "moved": moved})
+    assert [(e["form"], e["width"], e["restage"], e["executables"])
+            for e in seen] == [("compact", 128, False, 1),
+                               ("compact", 256, True, 2),
+                               ("compact", 128, True, 2)]
+    first, second, third = seen
+    assert first["stale"] == 0 and set(first["moved"]) <= set(first["table"])
+    # the narrow week's rows that the wide table does not hold
+    left = np.setdiff1d(narrow[3], second["table"])
+    assert second["stale"] == len(left) > 0
+    assert set(left) <= set(second["moved"])
+    # back on the narrow table: the wide week's rows are stale and stepped
+    left = np.setdiff1d(np.union1d(narrow[3], wide[3]), third["table"])
+    assert third["stale"] == len(left) > 0
+    assert set(left) <= set(third["moved"])
+    never = np.setdiff1d(np.arange(F), np.union1d(first["table"],
+                                                  second["table"]))
+    assert len(never) and np.array_equal(
+        np.asarray(state.params["gru_fwd_w_ih"])[:, never], start[:, never])
+
+
+# -- the rule is where ISSUE 39 put it --------------------------------------
+
+
+def _table_by_the_rule(live, capacity):
+    """`compact_table` written out again, with the bound as a number and
+    not through `compact_rule`: a half of F since ISSUE 39."""
     u_pad = max(MIN_COMPACT_WIDTH, 1 << max(len(live) - 1, 0).bit_length())
-    if u_pad > capacity // 4:
+    if u_pad > capacity // 2:
         return None
     dead = np.setdiff1d(np.arange(capacity, dtype=np.int32), live,
                         assume_unique=True)
@@ -252,33 +341,41 @@ def _parents_table(live, capacity):
     (512, range(0, 513)),
     (2048, range(0, 2049, 7)),
     (10240, (1, 127, 128, 129, 256, 257, 2047, 2048, 2049, 2560, 2561,
-             4095, 4096, 4097, 8192, 10239, 10240)),
-    (4 * MIN_COMPACT_WIDTH - 1, (1, 64, 128)),
+             4095, 4096, 4097, 5120, 5121, 8192, 10239, 10240)),
+    (4 * MIN_COMPACT_WIDTH - 1, (1, 64, 128, 129)),
+    (2 * MIN_COMPACT_WIDTH - 1, (1, 64, 128)),
 ])
 def test_compact_table_is_the_parents_for_every_live_set(capacity, sizes):
     rng = np.random.default_rng(capacity)
     order = rng.permutation(capacity).astype(np.int32)
     for n in sizes:
         live = np.sort(order[:n])
-        want, got = _parents_table(live, capacity), compact_table(live,
-                                                                  capacity)
+        want = _table_by_the_rule(live, capacity)
+        got = compact_table(live, capacity)
         padded, bound = compact_rule(n, capacity)
-        assert bound == capacity // 4 and padded >= max(n, MIN_COMPACT_WIDTH)
+        assert bound == capacity // 2 and padded >= max(n, MIN_COMPACT_WIDTH)
         assert padded & (padded - 1) == 0
         if want is None:
             assert got is None and padded > bound, (capacity, n)
         else:
             assert got.dtype == np.int32 and np.array_equal(got, want)
             assert len(got) == padded <= bound
+            assert 2 * len(got) <= capacity     # never a table as wide as F
 
 
 def test_the_bound_at_the_10k_width_is_where_the_cell_says():
-    """`tenk-train-live4k`: 4,096 live paths of 10,240 pad to 4,096, over the
-    bound of 2,560; 2,048 is the widest live set that stays compact."""
-    assert compact_rule(4096, 10240) == (4096, 2560)
-    assert compact_rule(2048, 10240) == (2048, 2560)
-    assert compact_rule(2049, 10240) == (4096, 2560)
-    assert compact_rule(256, 10240) == (256, 2560)
+    """`tenk-train-live4k`: 4,096 live paths of 10,240 pad to 4,096, under
+    the bound of 5,120, and that is the widest table the rule admits: one
+    path more pads to 8,192, the first width that is not compact."""
+    assert compact_rule(4096, 10240) == (4096, 5120)
+    assert compact_rule(2049, 10240) == (4096, 5120)
+    assert compact_rule(4097, 10240) == (8192, 5120)
+    assert compact_rule(256, 10240) == (256, 5120)
+    order = np.random.default_rng(39).permutation(10240).astype(np.int32)
+    assert len(compact_table(np.sort(order[:4096]), 10240)) == 4096
+    assert compact_table(np.sort(order[:4097]), 10240) is None
+    assert compact_table(np.sort(order[:8192]), 10240) is None
+    assert compact_table(np.arange(10240, dtype=np.int32), 10240) is None
 
 
 # -- the configuration and the cell, as files ------------------------------
@@ -311,7 +408,9 @@ def test_the_mix_is_week_sparse_but_for_the_live_set():
     model = _load("chipbench", "configs", "endpoints-10k-live4k.json")["model"]
     padded, bound = compact_rule(mine["params"]["hot_paths"],
                                  model["feature_dim"])
-    assert padded > bound               # the rule sends it to the dense form
+    assert padded <= bound              # the rule sends it to the compact form
+    assert compact_rule(mine["params"]["hot_paths"] + 1,
+                        model["feature_dim"])[0] > bound    # its widest table
     assert mine["params"]["nnz_hi"] - 1 <= 64
 
 

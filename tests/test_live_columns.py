@@ -1,7 +1,7 @@
 """Live-column compaction of the layer-0 projection (ISSUE 25).
 
 A staged sparse corpus whose live call paths, padded to a power of two, are
-at most a quarter of F is staged in the compact form (ops/densify.py): windows of
+at most half of F is staged in the compact form (ops/densify.py): windows of
 the live columns only, and a model that contracts over them.  Leaving exact
 zeros out of a sum changes its order, not its value, so the compact form is
 held to the dense form at float32 tolerance: predictions, loss, every
@@ -93,10 +93,10 @@ def test_table_is_sorted_padded_with_dead_columns_and_a_power_of_two():
     assert len(table) == MIN_COMPACT_WIDTH
     assert np.all(np.diff(table) > 0) and set(live) <= set(table)
     assert table.min() >= 0 and table.max() < F and table.dtype == np.int32
-    assert len(compact_table(np.arange(129, dtype=np.int32), 2 * F)) == 256
-    # a table over a quarter of F is no table: the dense form stays
-    assert compact_table(np.arange(129, dtype=np.int32), F) is None
-    assert compact_table(live, 4 * MIN_COMPACT_WIDTH - 1) is None
+    assert len(compact_table(np.arange(129, dtype=np.int32), F)) == 256
+    # a table over half of F is no table: the dense form stays
+    assert compact_table(np.arange(257, dtype=np.int32), F) is None
+    assert compact_table(live, 2 * MIN_COMPACT_WIDTH - 1) is None
 
 
 def test_a_never_seen_column_that_the_statistics_shift_is_live():
@@ -146,19 +146,19 @@ def test_compact_windows_are_the_dense_windows_at_the_table(monkeypatch):
 
 
 def test_the_rule_picks_the_form_from_the_corpus_and_the_mesh():
-    narrow, wide = _corpus(100), _corpus(129)
+    narrow, wide = _corpus(100), _corpus(257)
     trainer = _trainer()
     staged = trainer.stage_dataset(_bundle(*narrow[:3]))
     assert isinstance(staged, tuple) and len(staged) == 2
-    # a table of 128 is exactly a quarter of F: compact
+    # a table of 128 is a quarter of F: compact
     assert isinstance(staged[0], SparseBase) and staged[0].width == 128
     assert np.all(np.diff(np.asarray(staged[0].live)) > 0)
     assert _gauge() == {"live": 100, "contracted": 128, "total": F}
-    # a table of 256 is over a quarter of F: the dense form, untouched
+    # a live set that pads to 512 is over half of F: the dense form, untouched
     base = trainer.stage_dataset(_bundle(*wide[:3]))[0]
     assert base.live is None and base.width == F
     assert np.array_equal(np.asarray(base.cols), wide[0])
-    assert _gauge() == {"live": 129, "contracted": F, "total": F}
+    assert _gauge() == {"live": 257, "contracted": F, "total": F}
     # F sharded over the mesh's model axis: the dense form
     sharded = _trainer(mesh=make_mesh(MeshConfig(data=1, model=2)))
     assert sharded.stage_dataset(_bundle(*narrow[:3]))[0].live is None
