@@ -50,6 +50,8 @@ COMPILATIONS = "deeprest_compilations_total"
 COMPILE_SECONDS = "deeprest_compile_seconds_total"
 INIT_STATE_SECONDS = "deeprest_train_init_state_seconds"
 STAGE_SECONDS = "deeprest_train_last_stage_seconds"
+STAGINGS = "deeprest_train_stagings_total"
+OPTIMIZER_ROWS = "deeprest_train_optimizer_rows"
 PROJECTION_COLUMNS = "deeprest_train_projection_columns"
 FIRST_DISPATCH_SECONDS = "deeprest_train_first_dispatch_seconds"
 DEVICE_BYTES = "deeprest_train_device_bytes"
@@ -194,8 +196,11 @@ def setup_table() -> dict:
     """The set-up gauges as they stand: seconds of ``init_state`` by phase,
     of the last ``stage_dataset`` and, for a sparse corpus, the form its
     rule chose (``sparse_feed``: ``form``, and the columns ``live``,
-    ``padded``, ``bound``, ``contracted``, ``total``), of each first
-    dispatch; the compilations
+    ``padded``, ``bound``, ``contracted``, ``total``), which staging of the
+    process that was (``nth``), of each first dispatch; what the last
+    epoch's off-table pass found and did (``off_table``: the ``stale``
+    rows, the ``bound`` up to which they are visited row by row, the
+    ``trips`` of a dispatch); the compilations
     by program and phase (count, seconds, misses); device memory at the
     three moments; the superstep executable's bytes and where its kernels'
     operands live.  What was never set is left out."""
@@ -212,6 +217,8 @@ def setup_table() -> dict:
         if s["cache"] != "hit":
             row["misses"] += int(n)
     stage = _series(STAGE_SECONDS)
+    stagings = _series(STAGINGS)
+    rows = _by(OPTIMIZER_ROWS, "kind")
     columns = {k: int(v) for k, v in _by(PROJECTION_COLUMNS, "kind").items()}
     feed = None
     if columns.get("total"):            # a sparse corpus was staged
@@ -221,6 +228,9 @@ def setup_table() -> dict:
         "init_state_seconds": _by(INIT_STATE_SECONDS, "phase"),
         "stage_seconds": stage[0][1] if stage else None,
         "sparse_feed": feed,
+        "nth": int(stagings[0][1]) if stagings else None,
+        "off_table": {k: int(rows[k]) for k in ("stale", "bound", "trips")
+                      if k in rows},
         "first_dispatch_seconds": _by(FIRST_DISPATCH_SECONDS, "program"),
         "compilations": sorted(compilations.values(),
                                key=lambda r: -r["seconds"]),
@@ -245,7 +255,8 @@ def format_setup(table: dict) -> str:
         parts.append(f"init_state {sum(found.values()):.3f} s "
                      f"({seconds(found)})")
     if "stage_seconds" in table:
-        parts.append(f"stage {table['stage_seconds']:.3f} s")
+        parts.append(f"stage {table['stage_seconds']:.3f} s"
+                     + (f" (nth {table['nth']})" if "nth" in table else ""))
     if "sparse_feed" in table:
         feed = table["sparse_feed"]
         parts.append(
@@ -253,6 +264,9 @@ def format_setup(table: dict) -> str:
             f"of {feed['total']}, padded to {feed['padded']}, bound "
             f"{feed['bound'] or 'the model axis'}, {feed['contracted']} "
             "contracted)")
+    if "off_table" in table:
+        parts.append("off the table " + ", ".join(
+            f"{k} {v}" for k, v in table["off_table"].items()))
     if "first_dispatch_seconds" in table:
         parts.append("first dispatch "
                      + seconds(table["first_dispatch_seconds"]) + " s")
@@ -280,6 +294,7 @@ def format_setup(table: dict) -> str:
 
 __all__ = ["OTHER", "UNCACHED", "phase", "current_phase", "install",
            "setup_table", "format_setup", "COMPILATIONS", "COMPILE_SECONDS",
-           "INIT_STATE_SECONDS", "STAGE_SECONDS", "PROJECTION_COLUMNS",
+           "INIT_STATE_SECONDS", "STAGE_SECONDS", "STAGINGS",
+           "OPTIMIZER_ROWS", "PROJECTION_COLUMNS",
            "FIRST_DISPATCH_SECONDS", "DEVICE_BYTES", "PROGRAM_BYTES",
            "KERNEL_OPERAND_BYTES"]

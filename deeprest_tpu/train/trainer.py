@@ -190,6 +190,16 @@ def off_table_bound(capacity: int, width: int, steps: int) -> int:
     return max(0, min(crossover, capacity - width - _CHUNK))
 
 
+def off_table_trips(capacity: int, width: int, steps: int, stale: int) -> int:
+    """The chunk trips that the off-table pass of one dispatch makes for a
+    state with ``stale`` stale rows: a trip a chunk of them, none with
+    none; past :func:`off_table_bound` the pass is the loop over the whole
+    leaves, which reads as every chunk of them."""
+    if stale > off_table_bound(capacity, width, steps):
+        return capacity // _CHUNK
+    return _chunks(stale)
+
+
 def rows_visited(capacity: int, width: int, steps: int, stale: int) -> int:
     """The rows of a w_ih leaf that a dispatch of the compact superstep
     writes for a state with ``stale`` stale rows: the table's, and the
@@ -251,11 +261,11 @@ class Trainer:
         # recent train_epoch's dispatches: profile_epoch lowers exactly
         # this to name the trace's operations.
         self._dispatched: tuple | None = None
-        # Whether stage_dataset has run, its last call's compact table
+        # How often stage_dataset has run, its last call's compact table
         # (host copy; None for a feed without one) and what the rule of the
         # compact form decided there (empty for a feed that is not sparse):
         # the stage span's tags.
-        self._staged_before = False
+        self._stagings = 0
         self._staged_table: np.ndarray | None = None
         self._staged_form: dict = {}
         # Whether a train_epoch has finished (device memory is read once,
@@ -661,13 +671,18 @@ class Trainer:
             "projection sums over), total (F)",
             labelnames=("kind",))
         self._m_optimizer_rows = obs_metrics.REGISTRY.gauge(
-            "deeprest_train_optimizer_rows",
+            obs_setup.OPTIMIZER_ROWS,
             "rows of each layer-0 input weight over which the last epoch's "
             "steps on a staged sparse corpus were Adam (updated), and which "
             "its dispatches wrote (visited), of F (total); stale: rows off "
             "the staged table that carried a nonzero moment when the epoch "
-            "began (counted on a compact base only)",
+            "began; bound: the most stale rows a dispatch of its shapes "
+            "visits row by row; trips: the chunk trips its off-table pass "
+            "made (all of F's chunks past the bound); the last three "
+            "counted on a compact base only",
             labelnames=("kind",))
+        self._m_stagings = obs_metrics.REGISTRY.counter(
+            obs_setup.STAGINGS, "stage_dataset calls of this process")
         self._m_stage_seconds = obs_metrics.REGISTRY.gauge(
             obs_setup.STAGE_SECONDS,
             "host seconds of the last stage_dataset call")
@@ -833,10 +848,13 @@ class Trainer:
         the steps of a dispatch.  With no stale row the step is Adam on
         the table's rows: ``updated`` and ``visited`` are its width.  With
         one, it is Adam over all F (``updated``), of which the dispatches
-        wrote :func:`rows_visited` (``visited``).  ``stale`` None where no
-        table was consulted (the per-step and accumulation paths, a base
-        in its dense form): every step ran over and wrote all F rows, and
-        ``stale`` is left as it was."""
+        wrote :func:`rows_visited` (``visited``) in
+        :func:`off_table_trips` trips of the off-table pass (``trips``),
+        row by row up to :func:`off_table_bound` (``bound``).  ``stale``
+        None where no table was consulted (the per-step and accumulation
+        paths, a base in its dense form): every step ran over and wrote
+        all F rows, and ``stale``, ``bound`` and ``trips`` are left as
+        they were."""
         if not isinstance(x_base, SparseBase):
             return
         updated = visited = x_base.capacity
@@ -844,11 +862,15 @@ class Trainer:
             self._m_readbacks.inc(sink="optimizer_rows")
             # graftlint: disable=JX003 -- designed sink: one scalar an epoch, dispatched before its first chunk and read after its last
             stale = int(stale)
-            visited = rows_visited(x_base.capacity, x_base.width, steps,
-                                   stale)
+            shapes = x_base.capacity, x_base.width, steps
+            visited = rows_visited(*shapes, stale)
             if not stale:
                 updated = x_base.width
             self._m_optimizer_rows.set(stale, kind="stale")
+            self._m_optimizer_rows.set(off_table_bound(*shapes),
+                                       kind="bound")
+            self._m_optimizer_rows.set(off_table_trips(*shapes, stale),
+                                       kind="trips")
         self._m_optimizer_rows.set(updated, kind="updated")
         self._m_optimizer_rows.set(visited, kind="visited")
         self._m_optimizer_rows.set(x_base.capacity, kind="total")
@@ -1254,7 +1276,9 @@ class Trainer:
         A trainer stages again whenever its corpus moves (every refresh of
         train/stream.py, a resumed run on another week): each call is one
         span ``deeprest-trainer/train.stage``, tagged ``restage`` (this
-        trainer has staged before), ``width`` (the compact table's, else
+        trainer has staged before), ``nth`` (which staging of this
+        trainer's life it is, from 1; ``deeprest_train_stagings_total``
+        counts the process's), ``width`` (the compact table's, else
         the columns staged, 0 for nothing), for a sparse corpus what the
         rule of the compact form decided (``form`` ``compact`` or
         ``dense``, the ``live`` set's size, the ``padded`` width it
@@ -1275,7 +1299,8 @@ class Trainer:
             self._staged_form = {}
             staged = self._stage(bundle)
             table = self._staged_table
-            tags = {"restage": self._staged_before,
+            tags = {"restage": self._stagings > 0,
+                    "nth": self._stagings + 1,
                     "width": (len(table) if table is not None else
                               0 if staged is None else bundle.feature_dim),
                     **self._staged_form}
@@ -1283,7 +1308,8 @@ class Trainer:
                 tags["left"] = int(np.setdiff1d(before, table).size)
                 tags["entered"] = int(np.setdiff1d(table, before).size)
             span.tag(**tags)
-        self._staged_before = True
+        self._stagings += 1
+        self._m_stagings.inc()
         self._m_stage_seconds.set(clock.elapsed())
         self._publish_device_bytes("stage")
         return staged

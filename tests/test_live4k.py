@@ -427,10 +427,11 @@ def test_the_cell_and_its_metric_are_in_the_contract():
                  "init_state_peak_gb.train", "steady_hbm_gb.train",
                  "gru_kernel_vmem_pct.train", "dropout_draws_per_step.train",
                  "proj_dead_columns_pct.train"):
-        assert metrics[name]["workloads"][-1] == "tenk-train-live4k", name
+        assert "tenk-train-live4k" in metrics[name]["workloads"], name
     dead = metrics["proj_dead_columns_pct.train"]
-    assert dead["workloads"] == ["tenk-train-sparse", "tenk-train-dp4",
-                                 "tenk-retrain-drift", "tenk-train-live4k"]
+    # later cells are appended (ISSUE 40's); what was there keeps its place
+    assert dead["workloads"][:4] == ["tenk-train-sparse", "tenk-train-dp4",
+                                     "tenk-retrain-drift", "tenk-train-live4k"]
     assert (dead["unit"], dead["better"], dead["moves"], dead["source"]) == (
         "%", "lower", "train_steps_per_s", "program_counter")
     assert sum(c["chips"] == 4 for c in bench["workloads"]) == 1
