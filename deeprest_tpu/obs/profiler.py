@@ -347,6 +347,17 @@ def kernel_operand_spaces(hlo_text: str, names) -> dict[str, dict[str, int]]:
     return {kernel: dict(found) for kernel, found in out.items()}
 
 
+def _array_op_under(m, line: str, opcode: str, scope: str) -> bool:
+    """An ``opcode`` instruction whose ``op_name`` has ``scope`` among its
+    path's components and whose result has more than one element."""
+    op_name = _OP_NAME.search(line)
+    if (m["opcode"] != opcode or not op_name
+            or scope not in re.split(r"[/()]", op_name[1])):
+        return False
+    dims = _ARRAY.search(_result_type(m, line))[2]
+    return any(int(d) > 1 for d in dims.split(",") if d)
+
+
 def threefry_draws(hlo_text: str, scope: str) -> list[str]:
     """The places of an optimized HLO module's text that generate random
     bits under ``scope``, by name: every fusion instruction whose fused
@@ -364,23 +375,33 @@ def threefry_draws(hlo_text: str, scope: str) -> list[str]:
     (models/qrnn.py); a loop's body is counted once however often it runs,
     and XLA:CPU rolls the rounds into a loop of their own, so there the
     count says little."""
-    def round_of(m, line) -> bool:
-        op_name = _OP_NAME.search(line)
-        if (m["opcode"] != "xor" or not op_name
-                or scope not in re.split(r"[/()]", op_name[1])):
-            return False
-        dims = _ARRAY.search(_result_type(m, line))[2]
-        return any(int(d) > 1 for d in dims.split(",") if d)
-
     computations = _instruction_lines(hlo_text)[0]
     holders = {comp for comp, lines in computations.items()
-               if any(round_of(m, line) for m, line in lines)}
+               if any(_array_op_under(m, line, "xor", scope)
+                      for m, line in lines)}
     fusions = [(m["name"], _CALLS.search(line))
                for lines in computations.values() for m, line in lines
                if m["opcode"] == "fusion"]
     fused = {calls[1] for _, calls in fusions if calls}
     return ([name for name, calls in fusions if calls and calls[1] in holders]
             + sorted(holders - fused))
+
+
+def time_reversals(hlo_text: str, scope: str) -> list[str]:
+    """The ``reverse`` instructions of an optimized HLO module's text under
+    ``scope``, by name: fused into a neighbour or an operation of their
+    own, each is an array read back to front to compute nothing (what a
+    ``jnp.flip`` round a kernel that only scans forward lowers to, and what
+    autodiff mirrors in the backward pass).  A loop's body is counted once
+    however often it runs, so for a superstep the count is a train step's;
+    a reversal of a single element is none.  The recurrence kernels walk
+    the reverse direction's time blocks back to front themselves
+    (ops/pallas_gru.py), so a compiled step that holds one under
+    ``recurrence`` has grown a flip again."""
+    return [m["name"]
+            for lines in _instruction_lines(hlo_text)[0].values()
+            for m, line in lines
+            if _array_op_under(m, line, "reverse", scope)]
 
 
 # -- the trace → the table -------------------------------------------------
@@ -599,7 +620,7 @@ def format_table(table: dict) -> str:
 
 __all__ = ["OTHER", "UNATTRIBUTED", "COLLECTIVE", "COLLECTIVE_KINDS",
            "collective_kind", "collective_bytes", "kernel_operand_spaces",
-           "threefry_draws",
+           "threefry_draws", "time_reversals",
            "PROGRAM_SPAN_PREFIXES", "ProfilerBusy", "capture",
            "trace_window", "scope_table", "fused_scopes", "module_name",
            "layer_table", "layer_table_of", "read_planes", "find_xplane",

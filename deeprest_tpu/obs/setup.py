@@ -57,6 +57,7 @@ FIRST_DISPATCH_SECONDS = "deeprest_train_first_dispatch_seconds"
 DEVICE_BYTES = "deeprest_train_device_bytes"
 PROGRAM_BYTES = "deeprest_train_program_bytes"
 KERNEL_OPERAND_BYTES = "deeprest_train_kernel_operand_bytes"
+TIME_REVERSALS = "deeprest_train_time_reversals"
 
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 _CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
@@ -202,8 +203,9 @@ def setup_table() -> dict:
     rows, the ``bound`` up to which they are visited row by row, the
     ``trips`` of a dispatch); the compilations
     by program and phase (count, seconds, misses); device memory at the
-    three moments; the superstep executable's bytes and where its kernels'
-    operands live.  What was never set is left out."""
+    three moments; the superstep executable's bytes, where its kernels'
+    operands live and how many arrays a step reverses in time round them
+    (``time_reversals``).  What was never set is left out."""
     seconds = {(s["program"], s["phase"]): v
                for s, v in _series(COMPILE_SECONDS)}
     compilations: dict = {}
@@ -218,6 +220,7 @@ def setup_table() -> dict:
             row["misses"] += int(n)
     stage = _series(STAGE_SECONDS)
     stagings = _series(STAGINGS)
+    reversals = _series(TIME_REVERSALS)
     rows = _by(OPTIMIZER_ROWS, "kind")
     columns = {k: int(v) for k, v in _by(PROJECTION_COLUMNS, "kind").items()}
     feed = None
@@ -237,6 +240,7 @@ def setup_table() -> dict:
         "device_bytes": _by(DEVICE_BYTES, "at", "kind"),
         "program_bytes": _by(PROGRAM_BYTES, "kind"),
         "kernel_operand_bytes": _by(KERNEL_OPERAND_BYTES, "kernel", "space"),
+        "time_reversals": int(reversals[0][1]) if reversals else None,
     }
     return {k: v for k, v in table.items() if v not in (None, {}, [])}
 
@@ -289,6 +293,8 @@ def format_setup(table: dict) -> str:
     for kernel, found in table.get("kernel_operand_bytes", {}).items():
         parts.append(f"{kernel} operands " + ", ".join(
             f"{space} {size(v)}" for space, v in found.items()))
+    if "time_reversals" in table:
+        parts.append(f"{table['time_reversals']} reversals in time a step")
     return "set-up: " + "; ".join(parts)
 
 
@@ -297,4 +303,4 @@ __all__ = ["OTHER", "UNCACHED", "phase", "current_phase", "install",
            "INIT_STATE_SECONDS", "STAGE_SECONDS", "STAGINGS",
            "OPTIMIZER_ROWS", "PROJECTION_COLUMNS",
            "FIRST_DISPATCH_SECONDS", "DEVICE_BYTES", "PROGRAM_BYTES",
-           "KERNEL_OPERAND_BYTES"]
+           "KERNEL_OPERAND_BYTES", "TIME_REVERSALS"]

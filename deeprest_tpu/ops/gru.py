@@ -21,9 +21,9 @@ On a TPU (backend 'auto') the scan is the fused kernel of
 ops/pallas_gru.py: one kernel call a direction, on projections and hidden
 states in the kernels' ``[E, T, B, ·]`` order.  This module does the layout
 work round the calls, and there is one form of it: a reverse direction is
-flipped in time going in and coming out, and a bidirectional layer joins its
-two directions on the last axis BEFORE the one transpose to ``[E, B, T,
-2H]`` (:func:`_bidir_pallas`).
+the kernels walking the same arrays back to front (no array is flipped in
+time), and a bidirectional layer joins its two directions on the last axis
+BEFORE the one transpose to ``[E, B, T, 2H]`` (:func:`_bidir_pallas`).
 """
 
 from __future__ import annotations
@@ -152,16 +152,18 @@ def _project(params: GRUParams, x: jax.Array) -> jax.Array:
         return proj.astype(_kernel_io_dtype(proj.dtype))
 
 
-def _recur_local(proj, w_hh, b_hh, h0, interpret: bool):
+def _recur_local(proj, w_hh, b_hh, h0, interpret: bool, reverse: bool):
     """The kernel call on the arrays one device holds.
 
-    One direction, already in scan order: ``proj [E, T, B, 3H]``, ``w_hh
-    [E, H, 3H]``, ``b_hh [E, 3H]``, ``h0 [E, B, H]`` (the kernel only ever
-    scans its grid forward).  Shape hygiene for the kernel's tiling happens
-    here, per device: rows pad to the sublane, experts and time to the
-    kernel's widest blocks.  The time pad sits at the END of scan order,
-    beyond every real output: sliced off afterwards, zero incoming gradient
-    in the VJP.  Returns ``[E, T, B, H]``."""
+    One direction, time-aligned with the input whichever way it scans:
+    ``proj [E, T, B, 3H]``, ``w_hh [E, H, 3H]``, ``b_hh [E, 3H]``, ``h0
+    [E, B, H]`` (``reverse`` is the order in which the kernels visit the
+    time axis).  Shape hygiene for the kernel's tiling happens here, per
+    device: rows pad to the sublane, experts and time to the kernel's
+    widest blocks.  The time pad sits at the END of scan order (the FRONT
+    of the array when ``reverse``), beyond every real output: sliced off
+    afterwards, zero incoming gradient in the VJP.  Returns ``[E, T, B,
+    H]``."""
     from deeprest_tpu.ops import pallas_gru
 
     e, t, b, _ = proj.shape
@@ -169,18 +171,20 @@ def _recur_local(proj, w_hh, b_hh, h0, interpret: bool):
     b_pad = pallas_gru.pad_batch(b, io_dtype) - b
     e_pad = pallas_gru.pad_experts(e) - e
     t_pad = pallas_gru.pad_time(t) - t
-    proj = jnp.pad(proj, ((0, e_pad), (0, t_pad), (0, b_pad), (0, 0)))
+    t_pads = (t_pad, 0) if reverse else (0, t_pad)
+    proj = jnp.pad(proj, ((0, e_pad), t_pads, (0, b_pad), (0, 0)))
     # W_hh ships in the dot dtype: for bf16 models an f32 copy would
     # double its HBM/VMEM footprint only to be downcast inside every grid
     # program.  b_hh stays f32 (it is ADDED to the f32 accumulator).
     w_hh = jnp.pad(w_hh.astype(io_dtype), ((0, e_pad), (0, 0), (0, 0)))
     b_hh = jnp.pad(b_hh.astype(jnp.float32), ((0, e_pad), (0, 0)))
     h0 = jnp.pad(h0.astype(jnp.float32), ((0, e_pad), (0, b_pad), (0, 0)))
-    h_all = pallas_gru.gru_recurrence(proj, w_hh, b_hh, h0, interpret)
-    return h_all[:e, :t, :b]
+    h_all = pallas_gru.gru_recurrence(proj, w_hh, b_hh, h0, interpret,
+                                      reverse)
+    return h_all[:e, t_pads[0]:t_pads[0] + t, :b]
 
 
-def _recurrence(proj, w_hh, b_hh, h0, interpret: bool, mesh):
+def _recurrence(proj, w_hh, b_hh, h0, interpret: bool, reverse: bool, mesh):
     """:func:`_recur_local`, under ``shard_map`` when ``mesh`` has more
     than one device.
 
@@ -191,7 +195,7 @@ def _recurrence(proj, w_hh, b_hh, h0, interpret: bool, mesh):
     operand is replicated over it.  ``shard_map``'s transpose sums the
     weight cotangents over ``data``."""
     if mesh is None or mesh.size == 1:
-        return _recur_local(proj, w_hh, b_hh, h0, interpret)
+        return _recur_local(proj, w_hh, b_hh, h0, interpret, reverse)
     from jax.sharding import PartitionSpec as P
 
     n_data, n_expert = mesh.shape["data"], mesh.shape["expert"]
@@ -207,7 +211,7 @@ def _recurrence(proj, w_hh, b_hh, h0, interpret: bool, mesh):
         h0 = jnp.pad(h0, ((0, 0), (0, b_pad), (0, 0)))
     rows = P("expert", None, "data", None)
     out = jax.shard_map(
-        lambda *a: _recur_local(*a, interpret), mesh=mesh,
+        lambda *a: _recur_local(*a, interpret, reverse), mesh=mesh,
         in_specs=(rows, P("expert", None, None), P("expert", None),
                   P("expert", "data", None)),
         out_specs=rows, check_vma=False,
@@ -227,16 +231,14 @@ def _hidden_scan_order(
     MXU einsum), then the pallas recurrence of ops/pallas_gru.py (see that
     module for the kernel design).  Returns the hidden states in the
     kernels' order ``[E, T, B, H]``, time-aligned with ``x``: a reverse
-    direction's projection is flipped going in and its states coming out."""
+    direction is the kernels walking the projection back to front and
+    writing each state where its input lay, so nothing is flipped."""
     proj = _project(params, x)
     # the kernels carry their own names inside this scope; what is left
     # under `recurrence` is the layout work around them
     with jax.named_scope(scopes.RECURRENCE):
-        if reverse:
-            proj = jnp.flip(proj, axis=1)
-        h_all = _recurrence(proj, params.w_hh, params.b_hh, h0, interpret,
-                            mesh)
-        return jnp.flip(h_all, axis=1) if reverse else h_all
+        return _recurrence(proj, params.w_hh, params.b_hh, h0, interpret,
+                           reverse, mesh)
 
 
 def _gru_pallas(

@@ -8,13 +8,20 @@ This kernel runs the whole time loop *inside one pallas invocation*:
 - grid = (expert_blocks, T) with time as the innermost (sequential) grid
   dimension; the hidden state lives in a VMEM scratch buffer that persists
   across time steps — zero HBM traffic for the carry;
+- the DIRECTION of the scan is the order in which the grid visits the
+  array's time axis, never a copy of the array: the forward direction's
+  grid step ``j`` is time block ``j`` and the loop inside a block ascends;
+  the reverse direction's (``reverse=True``) is block ``nb - 1 - j`` by the
+  blocks' ``index_map`` and the loop descends.  Every array a kernel reads
+  or writes stays time-aligned with ``proj`` in HBM;
 - the hoisted input projections ``proj = x @ W_ih + b_ih`` (computed
   outside, one large MXU matmul) stream through VMEM blocks, double-
   buffered by the pallas pipeline;
 - ``W_hh`` is indexed only by the expert block, so it stays resident in
   VMEM for all T steps of that block;
-- the backward pass is a second pallas kernel walking the grid in reverse
-  time order, recomputing gate activations from (proj, h_prev and the
+- the backward pass is a second pallas kernel walking time AGAINST the
+  scan's order (back to front for the forward direction, front to back
+  for the reverse one), recomputing gate activations from (proj, h_prev and the
   hidden-side gate pre-activations the training forward stashed) and
   accumulating weight gradients in VMEM scratch, flushed to HBM on the
   final step.
@@ -110,6 +117,20 @@ def _resident(block_shape):
                         pipeline_mode=pl.Buffered(1))
 
 
+def _time_map(nb: int, descending: bool):
+    """Index map of a block indexed by the sequential time grid: grid step
+    ``j`` is time block ``j``, or block ``nb - 1 - j`` where the kernel
+    walks the array's time axis back to front."""
+    if descending:
+        return lambda i, j: (i, nb - 1 - j, 0, 0)
+    return lambda i, j: (i, j, 0, 0)
+
+
+def _walk(t_blk: int, descending: bool):
+    """The steps of one time block in the order the kernel takes them."""
+    return reversed(range(t_blk)) if descending else range(t_blk)
+
+
 def _gates(xproj, gates_h):
     """Shared gate math. xproj/gates_h: [B, 3H] → (r, z, n)."""
     xr, xz, xn = jnp.split(xproj, 3, axis=-1)
@@ -125,7 +146,8 @@ def _gates(xproj, gates_h):
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(proj_ref, w_ref, b_ref, h0_ref, *refs, dot_dtype, emit_prev):
+def _fwd_kernel(proj_ref, w_ref, b_ref, h0_ref, *refs, dot_dtype, emit_prev,
+                reverse):
     # Training (emit_prev=True) also streams out the PRE-update hidden
     # state per step: the VJP consumes h_prev directly instead of
     # re-materializing it outside the kernel as concat(h0, h_all[:-1]) —
@@ -141,7 +163,7 @@ def _fwd_kernel(proj_ref, w_ref, b_ref, h0_ref, *refs, dot_dtype, emit_prev):
         (out_ref, h_scr), prev_ref, gates_ref = refs, None, None
     t = pl.program_id(1)
 
-    @pl.when(t == 0)
+    @pl.when(t == 0)  # first grid step == first time block in scan order
     def _init():
         h_scr[...] = h0_ref[...].astype(jnp.float32)
 
@@ -166,7 +188,7 @@ def _fwd_kernel(proj_ref, w_ref, b_ref, h0_ref, *refs, dot_dtype, emit_prev):
         hs[i] = (1.0 - z) * n + z * hs[i]
         out_ref[i, tt] = hs[i].astype(out_ref.dtype)
 
-    for tt in range(t_blk):           # time OUTER
+    for tt in _walk(t_blk, reverse):  # time OUTER, in scan order
         for i in range(n_e):          # experts INNER: independent matmuls
             step(i, tt)
     for i in range(n_e):
@@ -227,7 +249,8 @@ def _fwd_per_expert_bytes(b, g3, h, proj_dtype, emit_prev,
     )
 
 
-def _fwd_call(proj, w_hh, b_hh, h0, interpret, emit_prev=False):
+def _fwd_call(proj, w_hh, b_hh, h0, interpret, emit_prev=False,
+              reverse=False):
     e, t, b, g3 = proj.shape
     h = g3 // 3
     assert t % _T_BLK == 0, (t, _T_BLK)   # callers pad_time first
@@ -239,21 +262,24 @@ def _fwd_call(proj, w_hh, b_hh, h0, interpret, emit_prev=False):
         f"forward{' (training)' if emit_prev else ''} "
         f"E={e} T={t} B={b} H={h} {proj.dtype}")
     eb = e // e_blk
-    grid = (eb, t // t_blk)
-    h_spec = pl.BlockSpec((e_blk, t_blk, b, h), lambda i, j: (i, j, 0, 0))
+    nb = t // t_blk
+    grid = (eb, nb)
+    # the reverse direction walks the time blocks back to front
+    by_time = _time_map(nb, reverse)
+    g_spec = pl.BlockSpec((e_blk, t_blk, b, g3), by_time)
+    h_spec = pl.BlockSpec((e_blk, t_blk, b, h), by_time)
     h_shape = jax.ShapeDtypeStruct((e, t, b, h), out_dtype)
     out_specs, out_shape = h_spec, h_shape
     if emit_prev:
-        out_specs = [h_spec, h_spec, pl.BlockSpec(
-            (e_blk, t_blk, b, g3), lambda i, j: (i, j, 0, 0))]
+        out_specs = [h_spec, h_spec, g_spec]
         out_shape = [h_shape, h_shape,
                      jax.ShapeDtypeStruct((e, t, b, g3), proj.dtype)]
     return pl.pallas_call(
         functools.partial(_fwd_kernel, dot_dtype=_dot_dtype_for(proj.dtype),
-                          emit_prev=emit_prev),
+                          emit_prev=emit_prev, reverse=reverse),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((e_blk, t_blk, b, g3), lambda i, j: (i, j, 0, 0)),
+            g_spec,
             _resident((e_blk, h, g3)),
             _resident((e_blk, g3)),
             _resident((e_blk, b, h)),
@@ -276,14 +302,14 @@ def _fwd_call(proj, w_hh, b_hh, h0, interpret, emit_prev=False):
 
 def _bwd_kernel(proj_ref, hprev_ref, gates_in_ref, w_ref, b_ref, dout_ref,
                 dproj_ref, dw_ref, db_ref, dh0_ref,
-                dh_scr, dw_scr, db_scr, dg_scr, *, dot_dtype):
+                dh_scr, dw_scr, db_scr, dg_scr, *, dot_dtype, reverse):
     # b_ref (b_hh) is not read: the stashed gates hold it already.  It
     # stays an operand because taking it away changes the compiled program
     # and the byte model (ROADMAP, speed queue).
     t = pl.program_id(1)
     t_total = pl.num_programs(1)
 
-    @pl.when(t == 0)  # first grid step == last time block
+    @pl.when(t == 0)  # first grid step == last time block in scan order
     def _init():
         dh_scr[...] = jnp.zeros_like(dh_scr)
         dw_scr[...] = jnp.zeros_like(dw_scr)
@@ -336,7 +362,7 @@ def _bwd_kernel(proj_ref, hprev_ref, gates_in_ref, w_ref, b_ref, dout_ref,
         for k, dgate in enumerate((da_r, da_z, dhn)):
             dbs[i][k] = dbs[i][k] + jnp.sum(dgate, axis=0, keepdims=True)
 
-    for tt in reversed(range(t_blk)):      # time OUTER, back-to-front
+    for tt in _walk(t_blk, not reverse):   # time OUTER, against scan order
         for i in range(n_e):               # experts INNER
             step(i, tt)
     for i in range(n_e):
@@ -355,7 +381,7 @@ def _bwd_kernel(proj_ref, hprev_ref, gates_in_ref, w_ref, b_ref, dout_ref,
         for k in range(3):
             db_scr[i:i + 1, k * hh:(k + 1) * hh] = dbs[i][k]
 
-    @pl.when(t == t_total - 1)  # last grid step == time 0: flush accumulators
+    @pl.when(t == t_total - 1)  # last grid step == the scan's first: flush
     def _flush():
         dw_ref[...] = dw_scr[...]
         db_ref[...] = db_scr[...]
@@ -384,7 +410,8 @@ def _bwd_per_expert_bytes(b, g3, h, proj_dtype, hp_io, do_io, w_itemsize):
     )
 
 
-def _bwd_call(proj, h_prev_all, gates_all, w_hh, b_hh, dout, interpret):
+def _bwd_call(proj, h_prev_all, gates_all, w_hh, b_hh, dout, interpret,
+              reverse=False):
     e, t, b, g3 = proj.shape
     h = g3 // 3
     assert t % _T_BLK == 0, (t, _T_BLK)   # callers pad_time first
@@ -396,20 +423,25 @@ def _bwd_call(proj, h_prev_all, gates_all, w_hh, b_hh, dout, interpret):
     eb = e // e_blk
     nb = t // t_blk
     grid = (eb, nb)
-    rev = lambda i, j: (i, nb - 1 - j, 0, 0)  # walk time blocks back-to-front
+    # against scan order: back to front, and front to back where the
+    # forward kernel walked the array back to front
+    by_time = _time_map(nb, not reverse)
+    g_spec = pl.BlockSpec((e_blk, t_blk, b, g3), by_time)
+    h_spec = pl.BlockSpec((e_blk, t_blk, b, h), by_time)
     dproj, dw, db, dh0 = pl.pallas_call(
-        functools.partial(_bwd_kernel, dot_dtype=_dot_dtype_for(proj.dtype)),
+        functools.partial(_bwd_kernel, dot_dtype=_dot_dtype_for(proj.dtype),
+                          reverse=reverse),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((e_blk, t_blk, b, g3), rev),
-            pl.BlockSpec((e_blk, t_blk, b, h), rev),
-            pl.BlockSpec((e_blk, t_blk, b, g3), rev),
+            g_spec,
+            h_spec,
+            g_spec,
             _resident((e_blk, h, g3)),
             _resident((e_blk, g3)),
-            pl.BlockSpec((e_blk, t_blk, b, h), rev),
+            h_spec,
         ],
         out_specs=[
-            pl.BlockSpec((e_blk, t_blk, b, g3), rev),
+            g_spec,
             _resident((e_blk, h, g3)),
             _resident((e_blk, g3)),
             _resident((e_blk, b, h)),
@@ -440,8 +472,8 @@ def _bwd_call(proj, h_prev_all, gates_all, w_hh, b_hh, dout, interpret):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def gru_recurrence(proj, w_hh, b_hh, h0, interpret=False):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def gru_recurrence(proj, w_hh, b_hh, h0, interpret=False, reverse=False):
     """Run the GRU time recurrence over pre-projected inputs.
 
     Args:
@@ -455,31 +487,44 @@ def gru_recurrence(proj, w_hh, b_hh, h0, interpret=False):
       b_hh: ``[E, 3H]`` hidden bias.
       h0: ``[E, B, H]`` initial hidden state.
       interpret: run the pallas kernels in interpret mode (CPU testing).
+      reverse: scan the time axis back to front (static).  Only the ORDER
+        in which the two kernels visit time changes (module header): every
+        array stays time-aligned with ``proj``, ``h0`` enters at the last
+        step of the array and ``h_all[:, t]`` is the state after
+        consuming ``proj[:, t:]``.  The values are those of flipping
+        ``proj`` in time, scanning forward and flipping ``h_all`` back, bit
+        for bit, and so is every gradient but ``dW_hh``: the backward
+        kernel contracts a whole time block in one dot, and the block now
+        lies in array order, the reverse of scan order, so the same
+        float32 sum is associated in another order inside the dot (about
+        1e-7 of the leaf's largest magnitude).
 
     Returns: ``[E, T, B, H]`` hidden states — f32 for f32 models, bf16 for
     bf16 models (_out_dtype_for: the model casts to its own dtype right
     after the kernel anyway, and f32 storage doubled the largest stream).
     """
-    return _fwd_call(proj, w_hh, b_hh, h0, interpret)
+    return _fwd_call(proj, w_hh, b_hh, h0, interpret, reverse=reverse)
 
 
-def _vjp_fwd(proj, w_hh, b_hh, h0, interpret):
+def _vjp_fwd(proj, w_hh, b_hh, h0, interpret, reverse):
     # Training forward streams h_prev out of the kernel directly — the
     # backward consumes it without the concat(h0, h_all[:-1]) round-trip,
     # and h_all itself is NOT a residual (the recompute needs only
     # h_prev).  h0 rides along for its dtype/shape (tiny next to the
     # [E,T,B,H] stash this replaces).  The pre-activation hidden gates
     # ride as a third output so the backward skips its recompute dot.
+    # Both stashes are stored time-aligned with proj whichever way the
+    # scan ran, which is all the backward kernel needs.
     h_all, h_prev_all, gates_all = _fwd_call(proj, w_hh, b_hh, h0, interpret,
-                                             emit_prev=True)
+                                             emit_prev=True, reverse=reverse)
     return h_all, (proj, w_hh, b_hh, h0, h_prev_all, gates_all)
 
 
-def _vjp_bwd(interpret, res, dout):
+def _vjp_bwd(interpret, reverse, res, dout):
     proj, w_hh, b_hh, h0, h_prev_all, gates_all = res
     dproj, dw, db, dh0 = _bwd_call(
         proj, h_prev_all, gates_all, w_hh, b_hh,
-        dout.astype(_out_dtype_for(proj.dtype)), interpret
+        dout.astype(_out_dtype_for(proj.dtype)), interpret, reverse
     )
     return (dproj, dw.astype(w_hh.dtype), db.astype(b_hh.dtype),
             dh0.astype(h0.dtype))
@@ -508,9 +553,10 @@ def pad_time(t: int) -> int:
     """Round the time axis up to the kernel's time-block granularity.
 
     ``gru_recurrence`` requires ``T % _T_BLK == 0``; callers pad ``proj``
-    with zeros at the END of scan order to this length and slice the
-    output back to ``t`` (the tail contributes zero gradient — see
-    ops/gru.py's pallas path)."""
+    with zeros at the END of scan order to this length (the end of the
+    array, or its front for the reverse direction) and slice the output
+    back to ``t`` (the tail contributes zero gradient — see ops/gru.py's
+    pallas path)."""
     return int(np.ceil(t / _T_BLK) * _T_BLK)
 
 

@@ -14,6 +14,8 @@ DENSIFY = "densify"         # ops/densify.py
 MASK = "mask"               # the feature mask and its fold (models/qrnn.py)
 IN_PROJ = "in_proj"         # the hoisted input projection (ops/gru.py)
 RECURRENCE = "recurrence"   # the scan, or the layout work round the kernels
+                            # (pads, the join, the one transpose; no reversal
+                            # in time since ISSUE 41: the kernels walk it)
 DROPOUT = "dropout"         # the step's key (trainer); the mask's ONE draw, a
                             # fusion of its own since ISSUE 36 (models/qrnn.py)
 MIXING = "mixing"           # cross-expert mixing (models/qrnn.py)

@@ -728,6 +728,11 @@ class Trainer:
             "dropout scope's random bits: 1 a forward pass where the mask "
             "is drawn once and kept for the backward pass (set where the "
             "step draws one)")
+        self._m_time_reversals = obs_metrics.REGISTRY.gauge(
+            obs_setup.TIME_REVERSALS,
+            "arrays a step of the compiled superstep reverses in time under "
+            "the recurrence scope (fused or not): 0 where the kernels walk "
+            "the reverse direction's time blocks back to front themselves")
         self._m_snapshots = obs_metrics.REGISTRY.counter(
             "deeprest_train_snapshots_total",
             "preemption-safe cursor snapshots written")
@@ -810,7 +815,10 @@ class Trainer:
         tree would say it) and in how many places a step generates
         the dropout mask's random bits (``deeprest_train_dropout_draws``:
         the program draws the mask once a forward pass, and the compiler
-        draws it again wherever it fuses the draw into a consumer)."""
+        draws it again wherever it fuses the draw into a consumer) and
+        reverses an array in time round the recurrence
+        (``deeprest_train_time_reversals``: none since the kernels walk
+        the reverse direction themselves)."""
         from deeprest_tpu.obs import profiler
 
         self._program_published = True
@@ -839,6 +847,8 @@ class Trainer:
         draws = profiler.threefry_draws(text, scopes.DROPOUT)
         if draws:
             self._m_dropout_draws.set(len(draws))
+        self._m_time_reversals.set(
+            len(profiler.time_reversals(text, scopes.RECURRENCE)))
 
     def _publish_optimizer_rows(self, x_base, stale=None, steps=0) -> None:
         """The epoch's ``deeprest_train_optimizer_rows``, for a staged

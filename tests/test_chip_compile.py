@@ -165,6 +165,32 @@ def test_kernel_under_mesh(topo, dtype, shape):
     assert "all-gather" not in compiled.as_text()
 
 
+def _time_reversals(text: str) -> list[str]:
+    from deeprest_tpu.obs import profiler
+    from deeprest_tpu.ops import scopes
+
+    return profiler.time_reversals(text, scopes.RECURRENCE)
+
+
+@pytest.mark.parametrize("experts,shape", [
+    (E, (1, 1, 1)), (200, (1, 1, 1)),           # both benchmark widths
+    (E, (4, 1, 1)),                             # `tenk-train-dp4`'s mesh
+])
+def test_reverse_direction_reverses_no_array_in_time(topo, experts, shape):
+    """The reverse direction is the kernels walking the time blocks back
+    to front (ISSUE 41): the gradient of the bidirectional layer holds its
+    four kernel calls and no ``reverse`` at all, where flipping round a
+    forward-only kernel compiled to five (three of ``[E,60,32,384]``, two
+    of ``[E,60,32,128]``)."""
+    n = int(np.prod(shape))
+    mesh = Mesh(np.asarray(topo.devices[:n]).reshape(shape), AXES)
+    compiled = _gru_grad(mesh, jnp.bfloat16, B * shape[0], experts).compile()
+    text = compiled.as_text()
+    assert _kernel_calls(compiled) == 4
+    assert _time_reversals(text) == []
+    assert " reverse(" not in text
+
+
 # ---------------------------------------------------------------------------
 # the fused serving program, every rung an accelerator builds
 # ---------------------------------------------------------------------------
@@ -367,14 +393,34 @@ def test_compact_superstep_draws_the_dropout_mask_once(compact_superstep):
     _assert_masks_drawn_once(compact_superstep.as_text())
 
 
-def test_dense_superstep_e200_draws_the_dropout_mask_once(one_chip):
-    """E=200: `tt-train-dense`'s program (the dense feed, F=2,048, a
-    3 x 50 plan), in which the mask is 98.3 MB; the superstep still needs
-    less than `init_state` leaves at its peak (9.317 GB: the ledger's
-    `hbm_peak_gb`) beside the staged corpus, so the peak stays
-    `init_state`'s."""
-    compiled = _train_step_lowered(one_chip, 2048, False, superstep=True,
-                                   experts=200).compile()
+@pytest.fixture(scope="module")
+def dense_superstep_e200(one_chip):
+    """`tt-train-dense`'s program (the dense feed, E=200, F=2,048, a
+    3 x 50 plan), compiled once for the two tests that read it."""
+    return _train_step_lowered(one_chip, 2048, False, superstep=True,
+                               experts=200).compile()
+
+
+@pytest.mark.parametrize("cell", ["compact_superstep",
+                                  "dense_superstep_e200"])
+def test_superstep_reverses_no_array_in_time(request, cell):
+    """The whole-step programs of the 10k cells (E=40) and of
+    `tt-train-dense` (E=200): four kernel calls as before and no
+    ``reverse`` under ``recurrence`` (ISSUE 41; the parent's text holds
+    five), which is what ``deeprest_train_time_reversals`` reads on the
+    chip."""
+    compiled = request.getfixturevalue(cell)
+    assert _kernel_calls(compiled) == 4
+    assert _time_reversals(compiled.as_text()) == []
+
+
+def test_dense_superstep_e200_draws_the_dropout_mask_once(
+        dense_superstep_e200):
+    """E=200: `tt-train-dense`'s program, in which the mask is 98.3 MB; the
+    superstep still needs less than `init_state` leaves at its peak (9.317
+    GB: the ledger's `hbm_peak_gb`) beside the staged corpus, so the peak
+    stays `init_state`'s."""
+    compiled = dense_superstep_e200
     assert _kernel_calls(compiled) == 4
     _assert_masks_drawn_once(compiled.as_text(), experts=200)
     mem = compiled.memory_analysis()
@@ -541,6 +587,7 @@ def test_compact_superstep_under_data4_reduces_the_compact_gradients(topo):
     text = compiled.as_text()
     assert _kernel_calls(compiled) == 4
     assert "all-gather" not in text
+    assert _time_reversals(text) == []
     _assert_masks_drawn_once(text)          # each chip's own 32 windows
     moved = profiler.collective_bytes(text)
     print(f"compact 10k superstep under data=4 for a described v5e:2x2: "

@@ -658,12 +658,18 @@ _KERNEL_BYTES = {("gru_kernel_fwd", "vmem"): 1.0, ("gru_kernel_fwd", "hbm"): 2.0
     # ISSUE 36: how often the compiled step draws the dropout mask
     ("dropout_draws:draws_per_step", "deeprest_train_dropout_draws", (),
      {(): 1.0}, 1.0),
+    # ISSUE 41: the arrays a compiled step reverses in time round the
+    # recurrence kernels; a gauge SET to 0 is a reading, not nothing
+    ("time_reversals:reversals_per_step", obs_setup.TIME_REVERSALS, (),
+     {(): 0.0}, 0.0),
+    ("time_reversals:reversals_per_step", obs_setup.TIME_REVERSALS, (),
+     {(): 5.0}, 5.0),
 ])
 def test_the_setup_readers(monkeypatch, reader, metric, labels, series,
                            expected):
-    """chipbench/readers/setup.py and dropout_draws.py: nothing (not an
-    error) from a program without the gauge or with the gauge never set,
-    the value with it set."""
+    """chipbench/readers/setup.py, dropout_draws.py and time_reversals.py:
+    nothing (not an error) from a program without the gauge or with the
+    gauge never set, the value with it set."""
     import importlib
 
     from deeprest_tpu.obs import metrics
@@ -680,3 +686,63 @@ def test_the_setup_readers(monkeypatch, reader, metric, labels, series,
     for key, value in series.items():
         made.inc(value, **dict(zip(labels, key)))
     assert read({}) == pytest.approx(expected)
+
+
+# -- (g) reversals in time round the recurrence kernels (ISSUE 41) ----------
+
+_REVERSALS_HLO = """HloModule jit_train_superstep, entry_computation_layout={()->f32[]}
+
+%fused_flip (p: bf16[40,60,32,384]) -> bf16[40,60,32,384] {
+  %p = bf16[40,60,32,384]{3,2,1,0} parameter(0)
+  %rev.1 = bf16[40,60,32,384]{3,2,1,0} reverse(%p), dimensions={1}, metadata={op_name="jit(train_superstep)/while/body/jvp(QuantileGRU)/recurrence/rev"}
+  ROOT %neg = bf16[40,60,32,384]{3,2,1,0} negate(%rev.1), metadata={op_name="jit(train_superstep)/while/body/jvp(QuantileGRU)/recurrence/neg"}
+}
+
+%fused_elsewhere (p: f32[60,8]) -> f32[60,8] {
+  %p = f32[60,8]{1,0} parameter(0)
+  ROOT %rev.2 = f32[60,8]{1,0} reverse(%p), dimensions={0}, metadata={op_name="jit(train_superstep)/while/body/jvp(QuantileGRU)/heads/rev"}
+}
+
+ENTRY %main (a: bf16[40,60,32,384], b: bf16[40,60,32,128], c: f32[1], d: f32[60,8]) -> f32[] {
+  %a = bf16[40,60,32,384]{3,2,1,0} parameter(0)
+  %b = bf16[40,60,32,128]{3,2,1,0} parameter(1)
+  %c = f32[1]{0} parameter(2)
+  %d = f32[60,8]{1,0} parameter(3)
+  %fusion.1 = bf16[40,60,32,384]{3,2,1,0} fusion(%a), kind=kLoop, calls=%fused_flip, metadata={op_name="jit(train_superstep)/while/body/jvp(QuantileGRU)/recurrence/neg"}
+  %fusion.2 = f32[60,8]{1,0} fusion(%d), kind=kLoop, calls=%fused_elsewhere, metadata={op_name="jit(train_superstep)/while/body/jvp(QuantileGRU)/heads/rev"}
+  %rev.9 = bf16[40,60,32,128]{3,2,1,0:T(8,128)(2,1)S(1)} reverse(%b), dimensions={1}, metadata={op_name="jit(train_superstep)/while/body/transpose(jvp(QuantileGRU))/recurrence/rev"}
+  %rev.3 = f32[1]{0} reverse(%c), dimensions={0}, metadata={op_name="jit(train_superstep)/while/body/jvp(QuantileGRU)/recurrence/rev"}
+  %copy.4 = bf16[40,60,32,128]{3,2,1,0} copy(%rev.9), metadata={op_name="jit(train_superstep)/while/body/jvp(QuantileGRU)/recurrence/copy"}
+  ROOT %zero = f32[] constant(0)
+}
+"""
+
+
+def test_time_reversals_names_the_reversals_under_the_scope():
+    """Fused into a neighbour (``rev.1``) or an operation of its own
+    (``rev.9``, the backward pass's, in VMEM), each counts; a reversal of
+    one element, another scope's, and a copy do not; a text without any
+    gives none."""
+    assert profiler.time_reversals(_REVERSALS_HLO, "recurrence") == [
+        "rev.1", "rev.9"]
+    assert profiler.time_reversals(_REVERSALS_HLO, "heads") == ["rev.2"]
+    assert profiler.time_reversals(_REVERSALS_HLO, "mixing") == []
+    flipless = _REVERSALS_HLO.replace(" reverse(", " copy(")
+    assert profiler.time_reversals(flipless, "recurrence") == []
+
+
+def test_first_epoch_sets_the_time_reversals_gauge(tiny):
+    """``deeprest_train_time_reversals`` is what ``time_reversals`` finds
+    in the text of the executable the epoch dispatched, and a count of 0 is
+    SET (here the scan backend's, which indexes and reverses nothing);
+    ``profile_epoch``'s ``setup`` and the ``set-up:`` line carry it."""
+    _epoch(tiny)
+    gauge = REGISTRY.get(obs_setup.TIME_REVERSALS)
+    counted = profiler.time_reversals(
+        tiny["trainer"]._dispatched_program_text(tiny["state"]),
+        scopes.RECURRENCE)
+    assert gauge.series() == {(): float(len(counted))}
+    table = obs_setup.setup_table()
+    assert table["time_reversals"] == len(counted)
+    assert f"{len(counted)} reversals in time a step" \
+        in obs_setup.format_setup(table)
