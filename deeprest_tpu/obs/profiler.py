@@ -338,10 +338,8 @@ def kernel_operand_spaces(hlo_text: str, names) -> dict[str, dict[str, int]]:
             if types is None:
                 types = {d["name"]: _result_type(d, text)
                          for d, text in lines}
-            head = line[line.index(" custom-call(") + len(" custom-call("):]
             found = out.setdefault(kernel, collections.Counter())
-            for handed in [m["name"],
-                           *_OPERAND.findall(head[:head.index(")")])]:
+            for handed in [m["name"], *_operands(m, line)]:
                 for n, space in _arrays(types[handed]):
                     found[space] += n
     return {kernel: dict(found) for kernel, found in out.items()}
@@ -402,6 +400,69 @@ def time_reversals(hlo_text: str, scope: str) -> list[str]:
             for lines in _instruction_lines(hlo_text)[0].values()
             for m, line in lines
             if _array_op_under(m, line, "reverse", scope)]
+
+
+def kernel_edge_passes(hlo_text: str, scope: str, kernel: str) -> list[str]:
+    """The instructions of an optimized HLO module's text that only read
+    again, or write again, a whole operand or result of a pallas kernel
+    call, by name; each is a pass over the array that computes nothing the
+    kernel does not hold.  Two kinds are found, what autodiff put round the
+    recurrence kernels while the VJP's boundary lay at the kernel call
+    (ops/pallas_gru.py):
+
+    - a ``reduce`` with a result of a ``kernel`` call (a
+      ``tpu_custom_call`` of that name, less XLA's ``.N``) among its
+      operands, alone or in a fusion that holds no dot: the input bias's
+      gradient summed from ``dproj``, which the backward kernel now sums
+      from the gate gradients in its registers;
+    - an instruction under ``scope`` that a ``split`` lowered to (a
+      fusion labelled with it, a ``slice`` left alone): the joined
+      cotangent of a bidirectional layer cut into one array a direction,
+      which the backward kernels now read in place.
+
+    Instructions inside a fusion are its fusion's; a loop's body is counted
+    once however often it runs, so for a superstep the count is a train
+    step's."""
+    computations = _instruction_lines(hlo_text)[0]
+    fused = {}                  # a fusion's computation -> the opcodes in it
+    for lines in computations.values():
+        for m, line in lines:
+            calls = _CALLS.search(line) if m["opcode"] == "fusion" else None
+            if calls:
+                fused[calls[1]] = {i["opcode"] for i, _
+                                   in computations.get(calls[1], ())}
+    found = []
+    for comp, lines in computations.items():
+        if comp in fused:
+            continue
+        made = {m["name"]: (m, line) for m, line in lines}
+
+        def of_kernel(name: str) -> bool:
+            m, line = made.get(name, (None, ""))
+            if m is not None and m["opcode"] == "get-tuple-element":
+                return of_kernel(_operands(m, line)[0])
+            base, _, number = name.rpartition(".")
+            return (_KERNEL_MARK in line
+                    and (base if number.isdigit() else name) == kernel)
+
+        for m, line in lines:
+            opcode, op_name = m["opcode"], _OP_NAME.search(line)
+            inside = ({opcode} if opcode != "fusion"
+                      else fused.get(_CALLS.search(line)[1], set()))
+            parts = re.split(r"[/()]", op_name[1]) if op_name else []
+            if ("reduce" in inside and not inside & {"dot", "convolution"}
+                    and any(of_kernel(o) for o in _operands(m, line))):
+                found.append(m["name"])
+            elif (opcode in ("fusion", "slice") and scope in parts
+                    and parts[-1] == "split"):
+                found.append(m["name"])
+    return found
+
+
+def _operands(m, line: str) -> list[str]:
+    """The names of an instruction's operands."""
+    head = line[line.index(f" {m['opcode']}(") + len(m["opcode"]) + 2:]
+    return _OPERAND.findall(head[:head.index(")")])
 
 
 # -- the trace → the table -------------------------------------------------
@@ -620,7 +681,7 @@ def format_table(table: dict) -> str:
 
 __all__ = ["OTHER", "UNATTRIBUTED", "COLLECTIVE", "COLLECTIVE_KINDS",
            "collective_kind", "collective_bytes", "kernel_operand_spaces",
-           "threefry_draws", "time_reversals",
+           "threefry_draws", "time_reversals", "kernel_edge_passes",
            "PROGRAM_SPAN_PREFIXES", "ProfilerBusy", "capture",
            "trace_window", "scope_table", "fused_scopes", "module_name",
            "layer_table", "layer_table_of", "read_planes", "find_xplane",

@@ -58,6 +58,7 @@ DEVICE_BYTES = "deeprest_train_device_bytes"
 PROGRAM_BYTES = "deeprest_train_program_bytes"
 KERNEL_OPERAND_BYTES = "deeprest_train_kernel_operand_bytes"
 TIME_REVERSALS = "deeprest_train_time_reversals"
+KERNEL_EDGE_PASSES = "deeprest_train_kernel_edge_passes"
 
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 _CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
@@ -204,8 +205,10 @@ def setup_table() -> dict:
     ``trips`` of a dispatch); the compilations
     by program and phase (count, seconds, misses); device memory at the
     three moments; the superstep executable's bytes, where its kernels'
-    operands live and how many arrays a step reverses in time round them
-    (``time_reversals``).  What was never set is left out."""
+    operands live, how many arrays a step reverses in time round them
+    (``time_reversals``) and how many passes it makes over a kernel's
+    operand or result only to cut or to sum it (``kernel_edge_passes``).
+    What was never set is left out."""
     seconds = {(s["program"], s["phase"]): v
                for s, v in _series(COMPILE_SECONDS)}
     compilations: dict = {}
@@ -221,6 +224,7 @@ def setup_table() -> dict:
     stage = _series(STAGE_SECONDS)
     stagings = _series(STAGINGS)
     reversals = _series(TIME_REVERSALS)
+    edge_passes = _series(KERNEL_EDGE_PASSES)
     rows = _by(OPTIMIZER_ROWS, "kind")
     columns = {k: int(v) for k, v in _by(PROJECTION_COLUMNS, "kind").items()}
     feed = None
@@ -241,6 +245,8 @@ def setup_table() -> dict:
         "program_bytes": _by(PROGRAM_BYTES, "kind"),
         "kernel_operand_bytes": _by(KERNEL_OPERAND_BYTES, "kernel", "space"),
         "time_reversals": int(reversals[0][1]) if reversals else None,
+        "kernel_edge_passes": (int(edge_passes[0][1]) if edge_passes
+                               else None),
     }
     return {k: v for k, v in table.items() if v not in (None, {}, [])}
 
@@ -295,6 +301,9 @@ def format_setup(table: dict) -> str:
             f"{space} {size(v)}" for space, v in found.items()))
     if "time_reversals" in table:
         parts.append(f"{table['time_reversals']} reversals in time a step")
+    if "kernel_edge_passes" in table:
+        parts.append(f"{table['kernel_edge_passes']} passes at the kernels' "
+                     "edge a step")
     return "set-up: " + "; ".join(parts)
 
 
@@ -303,4 +312,4 @@ __all__ = ["OTHER", "UNCACHED", "phase", "current_phase", "install",
            "INIT_STATE_SECONDS", "STAGE_SECONDS", "STAGINGS",
            "OPTIMIZER_ROWS", "PROJECTION_COLUMNS",
            "FIRST_DISPATCH_SECONDS", "DEVICE_BYTES", "PROGRAM_BYTES",
-           "KERNEL_OPERAND_BYTES", "TIME_REVERSALS"]
+           "KERNEL_OPERAND_BYTES", "TIME_REVERSALS", "KERNEL_EDGE_PASSES"]

@@ -20,10 +20,13 @@ comparable against the public torch API.
 On a TPU (backend 'auto') the scan is the fused kernel of
 ops/pallas_gru.py: one kernel call a direction, on projections and hidden
 states in the kernels' ``[E, T, B, ·]`` order.  This module does the layout
-work round the calls, and there is one form of it: a reverse direction is
-the kernels walking the same arrays back to front (no array is flipped in
-time), and a bidirectional layer joins its two directions on the last axis
-BEFORE the one transpose to ``[E, B, T, 2H]`` (:func:`_bidir_pallas`).
+work round the calls, and there is one form of it (:func:`_layer_pallas`):
+a reverse direction is the kernels walking the same arrays back to front
+(no array is flipped in time), and a layer's directions go through ONE
+``pallas_gru.gru_recurrence``, which adds each direction's input bias and
+joins the directions on the last axis BEFORE the one transpose to ``[E, B,
+T, 2H]``; its backward kernels read the joined cotangent where it lies and
+return the input bias's gradient themselves.
 """
 
 from __future__ import annotations
@@ -144,62 +147,57 @@ def _kernel_io_dtype(dtype) -> jnp.dtype:
 
 
 def _project(params: GRUParams, x: jax.Array) -> jax.Array:
-    """Hoisted input projection ``x @ W_ih + b_ih`` → [E, T, B, 3H] in the
-    kernel's I/O dtype."""
+    """Hoisted input projection ``x @ W_ih`` → [E, T, B, 3H] in the
+    kernel's I/O dtype, WITHOUT ``b_ih``: the add is the first operation
+    inside the recurrence's VJP (pallas_gru.gru_recurrence), whose
+    backward kernels return the bias's gradient.  XLA fuses the add into
+    this dot either way."""
     eq = "btf,efg->etbg" if x.ndim == 3 else "ebtf,efg->etbg"
     with jax.named_scope(scopes.IN_PROJ):
-        proj = jnp.einsum(eq, x, params.w_ih) + params.b_ih[:, None, None, :]
-        return proj.astype(_kernel_io_dtype(proj.dtype))
+        xw = jnp.einsum(eq, x, params.w_ih)
+        return xw.astype(_kernel_io_dtype(
+            jnp.result_type(xw.dtype, params.b_ih.dtype)))
 
 
-def _recur_local(proj, w_hh, b_hh, h0, interpret: bool, reverse: bool):
-    """The kernel call on the arrays one device holds.
+def _recur_local(directions, interpret: bool, reverses: tuple[bool, ...]):
+    """The layer's kernel calls on the arrays one device holds.
 
-    One direction, time-aligned with the input whichever way it scans:
-    ``proj [E, T, B, 3H]``, ``w_hh [E, H, 3H]``, ``b_hh [E, 3H]``, ``h0
-    [E, B, H]`` (``reverse`` is the order in which the kernels visit the
-    time axis).  Shape hygiene for the kernel's tiling happens here, per
-    device: rows pad to the sublane, experts and time to the kernel's
-    widest blocks.  The time pad sits at the END of scan order (the FRONT
-    of the array when ``reverse``), beyond every real output: sliced off
-    afterwards, zero incoming gradient in the VJP.  Returns ``[E, T, B,
-    H]``."""
+    ``directions``: a direction each, ``(xw [E, T, B, 3H], b_ih [E, 3H],
+    w_hh [E, H, 3H], b_hh [E, 3H], h0 [E, B, H])``, time-aligned with the
+    input whichever way it scans (``reverses``: the order in which the
+    kernels visit the time axis).  Returns the directions' states joined
+    on the last axis, ``[E, T, B, n*H]``.  Shape hygiene for the kernels'
+    tiling happens per device, inside the call."""
     from deeprest_tpu.ops import pallas_gru
 
-    e, t, b, _ = proj.shape
-    io_dtype = proj.dtype
-    b_pad = pallas_gru.pad_batch(b, io_dtype) - b
-    e_pad = pallas_gru.pad_experts(e) - e
-    t_pad = pallas_gru.pad_time(t) - t
-    t_pads = (t_pad, 0) if reverse else (0, t_pad)
-    proj = jnp.pad(proj, ((0, e_pad), t_pads, (0, b_pad), (0, 0)))
     # W_hh ships in the dot dtype: for bf16 models an f32 copy would
     # double its HBM/VMEM footprint only to be downcast inside every grid
     # program.  b_hh stays f32 (it is ADDED to the f32 accumulator).
-    w_hh = jnp.pad(w_hh.astype(io_dtype), ((0, e_pad), (0, 0), (0, 0)))
-    b_hh = jnp.pad(b_hh.astype(jnp.float32), ((0, e_pad), (0, 0)))
-    h0 = jnp.pad(h0.astype(jnp.float32), ((0, e_pad), (0, b_pad), (0, 0)))
-    h_all = pallas_gru.gru_recurrence(proj, w_hh, b_hh, h0, interpret,
-                                      reverse)
-    return h_all[:e, t_pads[0]:t_pads[0] + t, :b]
+    directions = tuple(
+        (xw, b_ih, w_hh.astype(xw.dtype), b_hh.astype(jnp.float32),
+         h0.astype(jnp.float32))
+        for xw, b_ih, w_hh, b_hh, h0 in directions)
+    return pallas_gru.gru_recurrence(directions, interpret, reverses)
 
 
-def _recurrence(proj, w_hh, b_hh, h0, interpret: bool, reverse: bool, mesh):
-    """:func:`_recur_local`, under ``shard_map`` when ``mesh`` has more
-    than one device.
+def _recurrence(directions, interpret: bool, reverses: tuple[bool, ...],
+                mesh):
+    """:func:`_recur_local`, under ONE ``shard_map`` a layer when ``mesh``
+    has more than one device.
 
     Mosaic kernels cannot be partitioned by GSPMD, and the recurrence is
-    independent over rows and over experts, so each device runs the kernel
+    independent over rows and over experts, so each device runs the kernels
     on its own ``[E/expert, T, B/data, ·]`` block.  The ``model`` axis only
     shards the hoisted input projection, which stays outside; here every
-    operand is replicated over it.  ``shard_map``'s transpose sums the
-    weight cotangents over ``data``."""
+    operand is replicated over it.  A device's backward kernel sums the
+    bias gradients over its own rows, and ``shard_map``'s transpose sums
+    the weight and bias cotangents over ``data``."""
     if mesh is None or mesh.size == 1:
-        return _recur_local(proj, w_hh, b_hh, h0, interpret, reverse)
+        return _recur_local(directions, interpret, reverses)
     from jax.sharding import PartitionSpec as P
 
     n_data, n_expert = mesh.shape["data"], mesh.shape["expert"]
-    e, _, b, _ = proj.shape
+    e, _, b, _ = directions[0][0].shape
     if e % n_expert:
         raise ValueError(f"{e} experts do not divide over the mesh's "
                          f"expert axis of {n_expert}")
@@ -207,53 +205,55 @@ def _recurrence(proj, w_hh, b_hh, h0, interpret: bool, reverse: bool, mesh):
     # to it; every row is independent, so the pad rows are sliced off.
     b_pad = -b % n_data
     if b_pad:
-        proj = jnp.pad(proj, ((0, 0), (0, 0), (0, b_pad), (0, 0)))
-        h0 = jnp.pad(h0, ((0, 0), (0, b_pad), (0, 0)))
+        directions = tuple(
+            (jnp.pad(xw, ((0, 0), (0, 0), (0, b_pad), (0, 0))), b_ih, w_hh,
+             b_hh, jnp.pad(h0, ((0, 0), (0, b_pad), (0, 0))))
+            for xw, b_ih, w_hh, b_hh, h0 in directions)
     rows = P("expert", None, "data", None)
+    direction = (rows, P("expert", None), P("expert", None, None),
+                 P("expert", None), P("expert", "data", None))
     out = jax.shard_map(
-        lambda *a: _recur_local(*a, interpret, reverse), mesh=mesh,
-        in_specs=(rows, P("expert", None, None), P("expert", None),
-                  P("expert", "data", None)),
-        out_specs=rows, check_vma=False,
-    )(proj, w_hh, b_hh, h0)
+        lambda d: _recur_local(d, interpret, reverses), mesh=mesh,
+        in_specs=((direction,) * len(directions),), out_specs=rows,
+        check_vma=False,
+    )(directions)
     return out[:, :, :b] if b_pad else out
 
 
-def _hidden_scan_order(
-    params: GRUParams,
+def _layer_pallas(
+    directions,
     x: jax.Array,
-    h0: jax.Array,
-    reverse: bool,
-    interpret: bool,
-    mesh,
-) -> jax.Array:
-    """One direction through the kernels: hoisted input projection (one
-    MXU einsum), then the pallas recurrence of ops/pallas_gru.py (see that
-    module for the kernel design).  Returns the hidden states in the
-    kernels' order ``[E, T, B, H]``, time-aligned with ``x``: a reverse
-    direction is the kernels walking the projection back to front and
-    writing each state where its input lay, so nothing is flipped."""
-    proj = _project(params, x)
-    # the kernels carry their own names inside this scope; what is left
-    # under `recurrence` is the layout work around them
-    with jax.named_scope(scopes.RECURRENCE):
-        return _recurrence(proj, params.w_hh, params.b_hh, h0, interpret,
-                           reverse, mesh)
-
-
-def _gru_pallas(
-    params: GRUParams,
-    x: jax.Array,
-    h0: jax.Array,
-    reverse: bool,
     interpret: bool,
     mesh=None,
 ) -> jax.Array:
-    """Fused-kernel path of one direction.  Output matches the scan
-    path's layout and time-alignment."""
-    h_all = _hidden_scan_order(params, x, h0, reverse, interpret, mesh)
+    """Fused-kernel path of one layer.  ``directions``: a direction each,
+    ``(params, h0, reverse)``, one for :func:`gru` and (forward, reverse)
+    for :func:`bidirectional_gru`.  A direction is its hoisted input
+    projection (one MXU einsum) and one kernel call a pass of the pallas
+    recurrence of ops/pallas_gru.py (see that module for the kernel
+    design), time-aligned with ``x``: a reverse direction is the kernels
+    walking the projection back to front and writing each state where its
+    input lay, so nothing is flipped.  The directions' hidden states are
+    joined on the last axis while still in the kernels' ``[E, T, B, H]``
+    order, inside the recurrence's VJP, BEFORE the one transpose to ``[E,
+    B, T, n*H]``.
+
+    The values are those of transposing each direction and joining
+    afterwards, bit for bit; the order matters to the compiler.  Handed two
+    transposed halves it drew the dropout mask again in every fusion that
+    reads the joined array (six a train step, for two here; the model has
+    held the draw itself to one since ISSUE 36), and on the chip the
+    operations outside the kernels took 7.54 ms a step for 5.86 at E=40
+    and 48.1 for 40.1 at E=200 (PERF.md section 6, PR 29)."""
+    operands = tuple((_project(p, x), p.b_ih, p.w_hh, p.b_hh, h0)
+                     for p, h0, _ in directions)
+    # the kernels carry their own names inside this scope; what is left
+    # under `recurrence` is the layout work around them
     with jax.named_scope(scopes.RECURRENCE):
-        return jnp.moveaxis(h_all, 1, 2).astype(x.dtype)  # [E,B,T,H]
+        out = _recurrence(operands, interpret,
+                          tuple(reverse for _, _, reverse in directions),
+                          mesh)
+        return jnp.moveaxis(out, 1, 2).astype(x.dtype)      # [E,B,T,n*H]
 
 
 def gru(
@@ -297,9 +297,9 @@ def gru(
         from deeprest_tpu.ops import pallas_gru
 
         if pallas_gru.supported(x.shape[-2], params.hidden_size):
-            return _gru_pallas(params, x, h0, reverse,
-                               interpret=resolved == "pallas_interpret",
-                               mesh=mesh)
+            return _layer_pallas(((params, h0, reverse),), x,
+                                 interpret=resolved == "pallas_interpret",
+                                 mesh=mesh)
         if backend != "auto":
             # An explicit pallas request that silently ran the scan path
             # would hide a perf bug; 'auto' falls through quietly by design.
@@ -312,34 +312,6 @@ def gru(
                 stacklevel=2,
             )
     return _gru_scan(params, x, h0, reverse=reverse, unroll=unroll)
-
-
-def _bidir_pallas(
-    fwd: GRUParams,
-    bwd: GRUParams,
-    x: jax.Array,
-    interpret: bool,
-    mesh=None,
-) -> jax.Array:
-    """Fused-kernel path of both directions: one kernel call a direction,
-    exactly those of two :func:`gru` calls, and the two hidden states
-    joined on the last axis while still in the kernels' ``[E, T, B, H]``
-    order, BEFORE the one transpose to ``[E, B, T, 2H]``.
-
-    The values are those of transposing each direction and joining
-    afterwards, bit for bit; the order matters to the compiler.  Handed two
-    transposed halves it drew the dropout mask again in every fusion that
-    reads the joined array (six a train step, for two here; the model has
-    held the draw itself to one since ISSUE 36), and on the chip the
-    operations outside the kernels took 7.54 ms a step for 5.86 at E=40
-    and 48.1 for 40.1 at E=200 (PERF.md section 6, PR 29)."""
-    e, b, h = fwd.w_ih.shape[0], x.shape[-3], fwd.hidden_size
-    h0 = jnp.zeros((e, b, h), jnp.float32)
-    out_f = _hidden_scan_order(fwd, x, h0, False, interpret, mesh)
-    out_b = _hidden_scan_order(bwd, x, h0, True, interpret, mesh)
-    with jax.named_scope(scopes.RECURRENCE):
-        out = jnp.concatenate([out_f, out_b], axis=-1)
-        return jnp.moveaxis(out, 1, 2).astype(x.dtype)      # [E,B,T,2H]
 
 
 def bidirectional_gru(
@@ -361,7 +333,9 @@ def bidirectional_gru(
         from deeprest_tpu.ops import pallas_gru
 
         if pallas_gru.supported(x.shape[-2], fwd.hidden_size):
-            return _bidir_pallas(fwd, bwd, x,
+            h0 = jnp.zeros((fwd.w_ih.shape[0], x.shape[-3], fwd.hidden_size),
+                           jnp.float32)
+            return _layer_pallas(((fwd, h0, False), (bwd, h0, True)), x,
                                  interpret=resolved == "pallas_interpret",
                                  mesh=mesh)
     # The scan backend, and an H the kernels do not take (``gru`` warns

@@ -23,13 +23,26 @@ This kernel runs the whole time loop *inside one pallas invocation*:
   scan's order (back to front for the forward direction, front to back
   for the reverse one), recomputing gate activations from (proj, h_prev and the
   hidden-side gate pre-activations the training forward stashed) and
-  accumulating weight gradients in VMEM scratch, flushed to HBM on the
-  final step.
+  accumulating weight and bias gradients in VMEM scratch, flushed to HBM
+  on the final step.
 
 Only the recurrence is hand-written: input/output projections, the feature
 mask, mixing, and heads remain plain XLA einsums (models/qrnn.py), which
 XLA already fuses well. Numerics match ops/gru.py's scan (gate order r,z,n;
 ``n = tanh(x_n + b_in + r · (h·W_hn + b_hn))``).
+
+The VJP's boundary (:func:`gru_recurrence`) is one LAYER, not one kernel
+call: it starts at the add of the input bias to the projection's einsum
+and ends behind the join of the layer's directions, one operation further
+out on each side than the kernels' values need.  Autodiff of those two
+operations outside it read the kernels' largest arrays again to compute
+nothing the backward kernel does not hold: a ``reduce_sum`` over each
+direction's ``dproj`` for ``db_ih`` (the kernel has the float32 gate
+gradients in registers and sums them for ``db_hh`` already), and a
+``split`` that copied the joined cotangent into one array a direction (a
+``BlockSpec`` reads a direction's H lanes where they lie).  With both
+inside, the compiled step holds neither
+(obs/profiler.kernel_edge_passes; PERF.md section 6, PR 42).
 
 Used automatically on TPU backends (ops/gru.py dispatch); `interpret=True`
 makes every entry point runnable on CPU for tests.
@@ -63,6 +76,9 @@ _E_BLK = 8
 _T_BLK = 6
 # f32 sublane granularity — batch is padded up to this.
 _SUBLANE = 8
+# [H] sums the backward kernel keeps for the two biases' gradients: of
+# da_r, da_z, dhn and dtanh (_bwd_kernel).
+_N_DB = 4
 # Scoped-VMEM budget for one kernel program: the compiler's 16 MiB limit
 # less 1 MiB for Mosaic's own fixed scratch.  The per-expert byte models
 # below count everything else: blocks indexed by the sequential time grid
@@ -117,13 +133,15 @@ def _resident(block_shape):
                         pipeline_mode=pl.Buffered(1))
 
 
-def _time_map(nb: int, descending: bool):
+def _time_map(nb: int, descending: bool, lane_block: int = 0):
     """Index map of a block indexed by the sequential time grid: grid step
     ``j`` is time block ``j``, or block ``nb - 1 - j`` where the kernel
-    walks the array's time axis back to front."""
+    walks the array's time axis back to front.  ``lane_block``: which
+    block of the last axis, for an array wider than its block there (the
+    joined cotangent, of which a direction reads its own H lanes)."""
     if descending:
-        return lambda i, j: (i, nb - 1 - j, 0, 0)
-    return lambda i, j: (i, j, 0, 0)
+        return lambda i, j: (i, nb - 1 - j, 0, lane_block)
+    return lambda i, j: (i, j, 0, lane_block)
 
 
 def _walk(t_blk: int, descending: bool):
@@ -300,12 +318,12 @@ def _fwd_call(proj, w_hh, b_hh, h0, interpret, emit_prev=False,
 # ---------------------------------------------------------------------------
 
 
-def _bwd_kernel(proj_ref, hprev_ref, gates_in_ref, w_ref, b_ref, dout_ref,
+def _bwd_kernel(proj_ref, hprev_ref, gates_in_ref, w_ref, dout_ref,
                 dproj_ref, dw_ref, db_ref, dh0_ref,
                 dh_scr, dw_scr, db_scr, dg_scr, *, dot_dtype, reverse):
-    # b_ref (b_hh) is not read: the stashed gates hold it already.  It
-    # stays an operand because taking it away changes the compiled program
-    # and the byte model (ROADMAP, speed queue).
+    # b_hh is no operand: the stashed gates hold it already.  dout_ref is
+    # this direction's H lanes of the layer's joined cotangent, cut out by
+    # the block's index_map (_bwd_call).
     t = pl.program_id(1)
     t_total = pl.num_programs(1)
 
@@ -319,11 +337,14 @@ def _bwd_kernel(proj_ref, hprev_ref, gates_in_ref, w_ref, b_ref, dout_ref,
     ws = [w_ref[i].astype(dot_dtype) for i in range(n_e)]
     dhs = [dh_scr[i] for i in range(n_e)]
     hh = dh_scr.shape[-1]
-    # Bias-gradient accumulators, one [1, H] row per gate: Mosaic refuses a
-    # 1-D concat of the three [H] sums ("Input offsets outside of the first
+    # Bias-gradient accumulators, one [1, H] row per sum: Mosaic refuses a
+    # 1-D concat of the [H] sums ("Input offsets outside of the first
     # tile"), so they stay apart and land in their 128-aligned lane slices
-    # of db_scr at the end of the program, like dproj/dg below.
-    dbs = [[db_scr[i:i + 1, k * hh:(k + 1) * hh] for k in range(3)]
+    # of db_scr at the end of the program, like dproj/dg below.  Four sums
+    # serve both biases (_N_DB): db_hh is (da_r, da_z, dhn), db_ih is (da_r,
+    # da_z, dtanh), the row sums of what goes out as dproj, taken here from
+    # the float32 gate gradients and not from a second pass over dproj.
+    dbs = [[db_scr[i:i + 1, k * hh:(k + 1) * hh] for k in range(_N_DB)]
            for i in range(n_e)]
 
     def step(i, tt):
@@ -359,7 +380,7 @@ def _bwd_kernel(proj_ref, hprev_ref, gates_in_ref, w_ref, b_ref, dout_ref,
             dg_scr[i, tt], ws[i], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        for k, dgate in enumerate((da_r, da_z, dhn)):
+        for k, dgate in enumerate((da_r, da_z, dhn, dtanh)):
             dbs[i][k] = dbs[i][k] + jnp.sum(dgate, axis=0, keepdims=True)
 
     for tt in _walk(t_blk, not reverse):   # time OUTER, against scan order
@@ -378,7 +399,7 @@ def _bwd_kernel(proj_ref, hprev_ref, gates_in_ref, w_ref, b_ref, dout_ref,
             preferred_element_type=jnp.float32,
         )
         dh_scr[i] = dhs[i]
-        for k in range(3):
+        for k in range(_N_DB):
             db_scr[i:i + 1, k * hh:(k + 1) * hh] = dbs[i][k]
 
     @pl.when(t == t_total - 1)  # last grid step == the scan's first: flush
@@ -400,21 +421,29 @@ def _bwd_per_expert_bytes(b, g3, h, proj_dtype, hp_io, do_io, w_itemsize):
         2 * (t_blk * b * g3 * io + t_blk * b * h * (hp_io + do_io)
              + t_blk * b * g3 * io
              + t_blk * b * g3 * io)
-        # resident: W_hh + b_hh in, dW/db/dh0 out, dh/dW/db scratch,
-        # dgates stash (dot dtype) for the block-batched dW dot
-        + h * g3 * w_itemsize + g3 * 4
-        + h * g3 * 4 + g3 * 4 + b * h * 4
-        + b * h * 4 + h * g3 * 4 + g3 * 4
+        # resident: W_hh in, dW/db/dh0 out, dh/dW/db scratch (db: the
+        # _N_DB sums of both biases), dgates stash (dot dtype) for the
+        # block-batched dW dot
+        + h * g3 * w_itemsize
+        + h * g3 * 4 + _N_DB * h * 4 + b * h * 4
+        + b * h * 4 + h * g3 * 4 + _N_DB * h * 4
         + t_blk * b * g3 * dot_io
         + _temp_bytes(b, g3, h, proj_dtype)
     )
 
 
-def _bwd_call(proj, h_prev_all, gates_all, w_hh, b_hh, dout, interpret,
-              reverse=False):
+def _bwd_call(proj, h_prev_all, gates_all, w_hh, dout, interpret,
+              reverse=False, half=0):
+    """One direction's backward kernel.  ``dout`` is the cotangent of the
+    layer's JOINED hidden states ``[E, T, B, n*H]``, whole: the kernel
+    reads this direction's H lanes of it where they lie (``half``, static:
+    which H-wide block of the last axis), so nothing cuts the joined array
+    apart first.  Returns ``dproj``, ``dW_hh``, ``db_hh``, ``dh0`` and
+    ``db_ih``, the last the row sums of ``dproj`` in float32."""
     e, t, b, g3 = proj.shape
     h = g3 // 3
     assert t % _T_BLK == 0, (t, _T_BLK)   # callers pad_time first
+    assert dout.shape[:3] == (e, t, b) and dout.shape[3] % h == 0, dout.shape
     per_expert = _bwd_per_expert_bytes(
         b, g3, h, proj.dtype, h_prev_all.dtype.itemsize,
         dout.dtype.itemsize, w_hh.dtype.itemsize)
@@ -437,25 +466,25 @@ def _bwd_call(proj, h_prev_all, gates_all, w_hh, b_hh, dout, interpret,
             h_spec,
             g_spec,
             _resident((e_blk, h, g3)),
-            _resident((e_blk, g3)),
-            h_spec,
+            pl.BlockSpec((e_blk, t_blk, b, h), _time_map(nb, not reverse,
+                                                         half)),
         ],
         out_specs=[
             g_spec,
             _resident((e_blk, h, g3)),
-            _resident((e_blk, g3)),
+            _resident((e_blk, _N_DB * h)),
             _resident((e_blk, b, h)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((e, t, b, g3), proj.dtype),
             jax.ShapeDtypeStruct((e, h, g3), jnp.float32),
-            jax.ShapeDtypeStruct((e, g3), jnp.float32),
+            jax.ShapeDtypeStruct((e, _N_DB * h), jnp.float32),
             jax.ShapeDtypeStruct((e, b, h), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((e_blk, b, h), jnp.float32),
             pltpu.VMEM((e_blk, h, g3), jnp.float32),
-            pltpu.VMEM((e_blk, g3), jnp.float32),
+            pltpu.VMEM((e_blk, _N_DB * h), jnp.float32),
             pltpu.VMEM((e_blk, t_blk, b, g3), _dot_dtype_for(proj.dtype)),
         ],
         compiler_params=pltpu.CompilerParams(
@@ -463,71 +492,159 @@ def _bwd_call(proj, h_prev_all, gates_all, w_hh, b_hh, dout, interpret,
         ),
         interpret=interpret,
         name=scopes.GRU_KERNEL_BWD,
-    )(proj, h_prev_all, gates_all, w_hh, b_hh, dout)
-    return dproj, dw, db, dh0
+    )(proj, h_prev_all, gates_all, w_hh, dout)
+    # the four sums are (da_r, da_z, dhn, dtanh): the first two are both
+    # biases' to the bit
+    db_hh = db[:, :g3]
+    db_ih = jnp.concatenate([db[:, :2 * h], db[:, g3:]], axis=-1)
+    return dproj, dw, db_hh, dh0, db_ih
 
 
 # ---------------------------------------------------------------------------
-# custom-VJP wrapper
+# custom-VJP wrapper: one layer, every direction of it
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def gru_recurrence(proj, w_hh, b_hh, h0, interpret=False, reverse=False):
-    """Run the GRU time recurrence over pre-projected inputs.
+def _pads(e: int, t: int, b: int, dtype, reverse: bool):
+    """``jnp.pad`` widths (experts, time, rows) of one direction's kernel
+    call: rows pad to the sublane, experts and time to the kernels' widest
+    blocks.  The time pad sits at the END of scan order (the FRONT of the
+    array when ``reverse``), beyond every real output."""
+    t_pad = pad_time(t) - t
+    return ((0, pad_experts(e) - e), (t_pad, 0) if reverse else (0, t_pad),
+            (0, pad_batch(b, dtype) - b))
+
+
+def _kernel_operands(direction, reverse: bool):
+    """(proj, w_hh, b_hh, h0) as one direction's kernels take them: the
+    input bias added to the projection's einsum (XLA fuses the add and the
+    cast into the dot that makes ``xw``), every array padded (:func:`_pads`;
+    nothing at a shape the blocks divide)."""
+    xw, b_ih, w_hh, b_hh, h0 = direction
+    e, t, b, _ = xw.shape
+    with jax.named_scope(scopes.IN_PROJ):
+        proj = (xw + b_ih[:, None, None, :]).astype(xw.dtype)
+    pad_e, pad_t, pad_b = _pads(e, t, b, proj.dtype, reverse)
+    none = (0, 0)
+    return (jnp.pad(proj, (pad_e, pad_t, pad_b, none)),
+            jnp.pad(w_hh, (pad_e, none, none)), jnp.pad(b_hh, (pad_e, none)),
+            jnp.pad(h0, (pad_e, pad_b, none)))
+
+
+def _unpadded(h_all, e: int, t: int, b: int, reverse: bool):
+    """The real ``[E, T, B, H]`` states of a padded call's."""
+    t0 = h_all.shape[1] - t if reverse else 0
+    return h_all[:e, t0:t0 + t, :b]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def gru_recurrence(directions, interpret=False, reverses=(False,)):
+    """Run the GRU time recurrence of ONE layer over its pre-projected
+    inputs: every direction of it, joined on the last axis.
+
+    The VJP's boundary.  It spans one operation more on each side of the
+    kernels than the kernels need for their values, because of what the
+    backward pass can then leave out.  In front, the ADD of the input bias
+    ``b_ih`` to the projection's einsum: the backward kernel holds the
+    float32 gate gradients whose row sums are ``db_ih`` and hands them back
+    beside ``db_hh``, where autodiff of an add outside would read each
+    direction's ``dproj`` a second time to sum its bf16 roundings.  Behind,
+    the JOIN of the directions: the backward pass receives the cotangent of
+    the joined array and gives it whole to each direction's kernel, which
+    reads its own H lanes of it through its ``BlockSpec``, where autodiff
+    of a ``concatenate`` outside would first copy the halves apart.  The
+    forward pass computes what the same operations outside would, to the
+    bit, and compiles to the same operations.
 
     Args:
-      proj: ``[E, T, B, 3H]`` — ``x @ W_ih + b_ih`` per expert (gate order
-        r, z, n along the last axis); f32 or bf16.  bf16 proj selects the
-        bf16-dot path (_dot_dtype_for): matmuls run bf16 with f32
-        accumulation while the carry and gate math stay f32 in VMEM —
-        bf16 I/O also halves the dominant HBM stream, and
-        ``dproj`` comes back in the same dtype).
-      w_hh: ``[E, H, 3H]`` hidden-to-hidden weights.
-      b_hh: ``[E, 3H]`` hidden bias.
-      h0: ``[E, B, H]`` initial hidden state.
+      directions: a tuple, one entry a direction, of ``(xw, b_ih, w_hh,
+        b_hh, h0)``.  ``xw``: ``[E, T, B, 3H]``, ``x @ W_ih`` per expert
+        WITHOUT its bias (gate order r, z, n along the last axis), f32 or
+        bf16: the kernels' I/O dtype.  bf16 selects the bf16-dot path
+        (_dot_dtype_for): matmuls run bf16 with f32 accumulation while the
+        carry and gate math stay f32 in VMEM — bf16 I/O also halves the
+        dominant HBM stream, and ``dxw`` comes back in the same dtype.
+        ``b_ih``: ``[E, 3H]`` input bias; ``w_hh``: ``[E, H, 3H]``
+        hidden-to-hidden weights; ``b_hh``: ``[E, 3H]`` hidden bias;
+        ``h0``: ``[E, B, H]`` initial hidden state.  No shape needs to
+        divide the kernels' blocks: the pads and their slices are in here.
       interpret: run the pallas kernels in interpret mode (CPU testing).
-      reverse: scan the time axis back to front (static).  Only the ORDER
-        in which the two kernels visit time changes (module header): every
-        array stays time-aligned with ``proj``, ``h0`` enters at the last
-        step of the array and ``h_all[:, t]`` is the state after
-        consuming ``proj[:, t:]``.  The values are those of flipping
-        ``proj`` in time, scanning forward and flipping ``h_all`` back, bit
-        for bit, and so is every gradient but ``dW_hh``: the backward
-        kernel contracts a whole time block in one dot, and the block now
-        lies in array order, the reverse of scan order, so the same
-        float32 sum is associated in another order inside the dot (about
-        1e-7 of the leaf's largest magnitude).
+      reverses: a direction each, whether it scans the time axis back to
+        front (static).  Only the ORDER in which the two kernels visit time
+        changes (module header): every array stays time-aligned with
+        ``xw``, ``h0`` enters at the last step of the array and the state
+        at ``t`` is the one after consuming ``xw[:, t:]``.  The values are
+        those of flipping ``xw`` in time, scanning forward and flipping the
+        states back, bit for bit, and so is every gradient but ``dW_hh``:
+        the backward kernel contracts a whole time block in one dot, and
+        the block now lies in array order, the reverse of scan order, so
+        the same float32 sum is associated in another order inside the dot
+        (about 1e-7 of the leaf's largest magnitude).
 
-    Returns: ``[E, T, B, H]`` hidden states — f32 for f32 models, bf16 for
-    bf16 models (_out_dtype_for: the model casts to its own dtype right
-    after the kernel anyway, and f32 storage doubled the largest stream).
+    Returns: ``[E, T, B, n*H]`` hidden states, direction ``d``'s in lanes
+    ``[d*H, (d+1)*H)`` — f32 for f32 models, bf16 for bf16 models
+    (_out_dtype_for: the model casts to its own dtype right after the
+    kernel anyway, and f32 storage doubled the largest stream).
     """
-    return _fwd_call(proj, w_hh, b_hh, h0, interpret, reverse=reverse)
+    return _joined_fwd(directions, interpret, reverses, emit_prev=False)[0]
 
 
-def _vjp_fwd(proj, w_hh, b_hh, h0, interpret, reverse):
+def _joined_fwd(directions, interpret, reverses, emit_prev):
+    """The forward kernel of each direction and the join; with
+    ``emit_prev`` the training forward, which also returns each direction's
+    residuals."""
+    outs, residuals = [], []
+    for direction, reverse in zip(directions, reverses, strict=True):
+        e, t, b, _ = direction[0].shape
+        proj, w_hh, b_hh, h0 = _kernel_operands(direction, reverse)
+        out = _fwd_call(proj, w_hh, b_hh, h0, interpret, emit_prev=emit_prev,
+                        reverse=reverse)
+        if emit_prev:
+            h_all, h_prev_all, gates_all = out
+            residuals.append((proj, w_hh, h_prev_all, gates_all,
+                              direction[1], b_hh, h0))
+        else:
+            h_all = out
+        outs.append(_unpadded(h_all, e, t, b, reverse))
+    return jnp.concatenate(outs, axis=-1), tuple(residuals)
+
+
+def _vjp_fwd(directions, interpret, reverses):
     # Training forward streams h_prev out of the kernel directly — the
     # backward consumes it without the concat(h0, h_all[:-1]) round-trip,
     # and h_all itself is NOT a residual (the recompute needs only
-    # h_prev).  h0 rides along for its dtype/shape (tiny next to the
-    # [E,T,B,H] stash this replaces).  The pre-activation hidden gates
-    # ride as a third output so the backward skips its recompute dot.
-    # Both stashes are stored time-aligned with proj whichever way the
-    # scan ran, which is all the backward kernel needs.
-    h_all, h_prev_all, gates_all = _fwd_call(proj, w_hh, b_hh, h0, interpret,
-                                             emit_prev=True, reverse=reverse)
-    return h_all, (proj, w_hh, b_hh, h0, h_prev_all, gates_all)
+    # h_prev).  The pre-activation hidden gates ride as a third output so
+    # the backward skips its recompute dot.  Both stashes are stored
+    # time-aligned with proj whichever way the scan ran, which is all the
+    # backward kernel needs.  proj (with its bias) is the residual, xw is
+    # none: it never leaves the dot's fusion.  The two biases and h0 ride
+    # along for their dtypes (tiny next to the stashes).
+    return _joined_fwd(directions, interpret, reverses, emit_prev=True)
 
 
-def _vjp_bwd(interpret, reverse, res, dout):
-    proj, w_hh, b_hh, h0, h_prev_all, gates_all = res
-    dproj, dw, db, dh0 = _bwd_call(
-        proj, h_prev_all, gates_all, w_hh, b_hh,
-        dout.astype(_out_dtype_for(proj.dtype)), interpret, reverse
-    )
-    return (dproj, dw.astype(w_hh.dtype), db.astype(b_hh.dtype),
-            dh0.astype(h0.dtype))
+def _vjp_bwd(interpret, reverses, residuals, dout):
+    e, t, b, _ = dout.shape
+    io_dtype = residuals[0][0].dtype
+    dout = dout.astype(_out_dtype_for(io_dtype))     # the joined array, once
+    grads = []
+    for half, (reverse, res) in enumerate(zip(reverses, residuals)):
+        proj, w_hh, h_prev_all, gates_all, b_ih, b_hh, h0 = res
+        # the joined cotangent, padded as this direction's arrays are (the
+        # pad's rows, steps and experts get a zero cotangent and give zero
+        # gate gradients); at a shape that pads nothing, and every
+        # benchmark cell's is one, it is the array itself for every
+        # direction
+        pad_e, pad_t, pad_b = _pads(e, t, b, io_dtype, reverse)
+        padded = jnp.pad(dout, (pad_e, pad_t, pad_b, (0, 0)))
+        dproj, dw, db_hh, dh0, db_ih = _bwd_call(
+            proj, h_prev_all, gates_all, w_hh, padded, interpret, reverse,
+            half)
+        grads.append((_unpadded(dproj, e, t, b, reverse),
+                      db_ih[:e].astype(b_ih.dtype),
+                      dw[:e].astype(w_hh.dtype),
+                      db_hh[:e].astype(b_hh.dtype),
+                      dh0[:e, :b].astype(h0.dtype)))
+    return (tuple(grads),)
 
 
 gru_recurrence.defvjp(_vjp_fwd, _vjp_bwd)
@@ -552,11 +669,9 @@ def pad_batch(b: int, dtype=None) -> int:
 def pad_time(t: int) -> int:
     """Round the time axis up to the kernel's time-block granularity.
 
-    ``gru_recurrence`` requires ``T % _T_BLK == 0``; callers pad ``proj``
-    with zeros at the END of scan order to this length (the end of the
-    array, or its front for the reverse direction) and slice the output
-    back to ``t`` (the tail contributes zero gradient — see ops/gru.py's
-    pallas path)."""
+    The kernel calls require ``T % _T_BLK == 0``; ``gru_recurrence`` pads
+    ``proj`` at the END of scan order to this length (:func:`_pads`) and
+    slices the states back to ``t``; the tail gets a zero cotangent."""
     return int(np.ceil(t / _T_BLK) * _T_BLK)
 
 

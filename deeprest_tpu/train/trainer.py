@@ -733,6 +733,13 @@ class Trainer:
             "arrays a step of the compiled superstep reverses in time under "
             "the recurrence scope (fused or not): 0 where the kernels walk "
             "the reverse direction's time blocks back to front themselves")
+        self._m_kernel_edge_passes = obs_metrics.REGISTRY.gauge(
+            obs_setup.KERNEL_EDGE_PASSES,
+            "instructions of a step of the compiled superstep that only "
+            "read or write again a whole operand or result of a recurrence "
+            "kernel call (a sum over dproj for the input bias, the split "
+            "of the joined cotangent): 0 where the backward kernels do "
+            "both themselves")
         self._m_snapshots = obs_metrics.REGISTRY.counter(
             "deeprest_train_snapshots_total",
             "preemption-safe cursor snapshots written")
@@ -818,7 +825,10 @@ class Trainer:
         draws it again wherever it fuses the draw into a consumer) and
         reverses an array in time round the recurrence
         (``deeprest_train_time_reversals``: none since the kernels walk
-        the reverse direction themselves)."""
+        the reverse direction themselves) or passes over a kernel's operand
+        or result only to cut or to sum it
+        (``deeprest_train_kernel_edge_passes``: none since the recurrence's
+        VJP spans the bias add and the join)."""
         from deeprest_tpu.obs import profiler
 
         self._program_published = True
@@ -849,6 +859,8 @@ class Trainer:
             self._m_dropout_draws.set(len(draws))
         self._m_time_reversals.set(
             len(profiler.time_reversals(text, scopes.RECURRENCE)))
+        self._m_kernel_edge_passes.set(len(profiler.kernel_edge_passes(
+            text, scopes.RECURRENCE, scopes.GRU_KERNEL_BWD)))
 
     def _publish_optimizer_rows(self, x_base, stale=None, steps=0) -> None:
         """The epoch's ``deeprest_train_optimizer_rows``, for a staged

@@ -664,11 +664,17 @@ _KERNEL_BYTES = {("gru_kernel_fwd", "vmem"): 1.0, ("gru_kernel_fwd", "hbm"): 2.0
      {(): 0.0}, 0.0),
     ("time_reversals:reversals_per_step", obs_setup.TIME_REVERSALS, (),
      {(): 5.0}, 5.0),
+    # ISSUE 42: the passes a compiled step makes over a recurrence kernel's
+    # operand or result only to cut or to sum it; 0 is a reading too
+    ("kernel_edge_passes:passes_per_step", obs_setup.KERNEL_EDGE_PASSES, (),
+     {(): 0.0}, 0.0),
+    ("kernel_edge_passes:passes_per_step", obs_setup.KERNEL_EDGE_PASSES, (),
+     {(): 3.0}, 3.0),
 ])
 def test_the_setup_readers(monkeypatch, reader, metric, labels, series,
                            expected):
-    """chipbench/readers/setup.py, dropout_draws.py and time_reversals.py:
-    nothing (not an error) from a program without the gauge or with the
+    """chipbench/readers/setup.py, dropout_draws.py, time_reversals.py and
+    kernel_edge_passes.py: nothing (not an error) from a program without the gauge or with the
     gauge never set, the value with it set."""
     import importlib
 
@@ -745,4 +751,94 @@ def test_first_epoch_sets_the_time_reversals_gauge(tiny):
     table = obs_setup.setup_table()
     assert table["time_reversals"] == len(counted)
     assert f"{len(counted)} reversals in time a step" \
+        in obs_setup.format_setup(table)
+
+
+# -- (h) passes at the recurrence kernels' edge (ISSUE 42) ------------------
+
+_STEP = "jit(train_superstep)/while/body/transpose(jvp(QuantileGRU))"
+_EDGE_HLO = f"""HloModule jit_train_superstep, entry_computation_layout={{()->f32[]}}
+
+%add (a: bf16[], b: bf16[]) -> bf16[] {{
+  %a = bf16[] parameter(0)
+  %b = bf16[] parameter(1)
+  ROOT %sum = bf16[] add(%a, %b), metadata={{op_name="{_STEP}/in_proj/reduce_sum"}}
+}}
+
+%fused_split (p: bf16[40,60,32,256]) -> (bf16[40,60,32,128], bf16[40,60,32,128]) {{
+  %p = bf16[40,60,32,256]{{3,2,1,0}} parameter(0)
+  %split.8 = bf16[40,60,32,128]{{3,2,1,0}} slice(%p), slice={{[0:40], [0:60], [0:32], [0:128]}}, metadata={{op_name="{_STEP}/recurrence/split"}}
+  %split.9 = bf16[40,60,32,128]{{3,2,1,0}} slice(%p), slice={{[0:40], [0:60], [0:32], [128:256]}}, metadata={{op_name="{_STEP}/recurrence/split"}}
+  ROOT %tuple.1 = (bf16[40,60,32,128]{{3,2,1,0}}, bf16[40,60,32,128]{{3,2,1,0}}) tuple(%split.8, %split.9)
+}}
+
+%fused_sum (p: bf16[40,60,32,384]) -> bf16[40,384] {{
+  %p = bf16[40,60,32,384]{{3,2,1,0}} parameter(0)
+  %zero = bf16[] constant(0)
+  ROOT %reduce_sum.3 = bf16[40,384]{{1,0}} reduce(%p, %zero), dimensions={{1,2}}, to_apply=%add, metadata={{op_name="{_STEP}/in_proj/reduce_sum"}}
+}}
+
+%fused_dot (p: bf16[40,60,32,384], q: bf16[32,60,512]) -> (f32[40,512,384], bf16[40,384]) {{
+  %p = bf16[40,60,32,384]{{3,2,1,0}} parameter(0)
+  %q = bf16[32,60,512]{{2,1,0}} parameter(1)
+  %zero = bf16[] constant(0)
+  %dw = f32[40,512,384]{{2,1,0}} convolution(%q, %p), dim_labels=0fb_0io->b0f, metadata={{op_name="{_STEP}/in_proj/btf,efg->etbg/dot_general"}}
+  %reduce_sum.4 = bf16[40,384]{{1,0}} reduce(%p, %zero), dimensions={{1,2}}, to_apply=%add, metadata={{op_name="{_STEP}/in_proj/reduce_sum"}}
+  ROOT %tuple.2 = (f32[40,512,384]{{2,1,0}}, bf16[40,384]{{1,0}}) tuple(%dw, %reduce_sum.4)
+}}
+
+ENTRY %main (d: bf16[40,60,32,256], x: bf16[32,60,512], m: bf16[40,60,32,384]) -> f32[] {{
+  %d = bf16[40,60,32,256]{{3,2,1,0}} parameter(0)
+  %x = bf16[32,60,512]{{2,1,0}} parameter(1)
+  %m = bf16[40,60,32,384]{{3,2,1,0}} parameter(2)
+  %zero = bf16[] constant(0)
+  %fusion.201 = (bf16[40,60,32,128]{{3,2,1,0}}, bf16[40,60,32,128]{{3,2,1,0:T(8,128)(2,1)S(1)}}) fusion(%d), kind=kLoop, calls=%fused_split, metadata={{op_name="{_STEP}/recurrence/split"}}
+  %half.0 = bf16[40,60,32,128]{{3,2,1,0}} get-tuple-element(%fusion.201), index=0, metadata={{op_name="{_STEP}/recurrence/split"}}
+  %half.1 = bf16[40,60,32,128]{{3,2,1,0}} slice(%d), slice={{[0:40], [0:60], [0:32], [128:256]}}, metadata={{op_name="{_STEP}/recurrence/split"}}
+  %elsewhere = bf16[40,60,32,128]{{3,2,1,0}} slice(%d), slice={{[0:40], [0:60], [0:32], [0:128]}}, metadata={{op_name="{_STEP}/mixing/split"}}
+  %gru_kernel_bwd.6 = (bf16[40,60,32,384]{{3,2,1,0}}, f32[40,512]{{1,0}}) custom-call(%m, %half.0), custom_call_target="tpu_custom_call", metadata={{op_name="{_STEP}/recurrence/gru_kernel_bwd/pallas_call"}}
+  %gru_kernel_bwd.7 = (bf16[40,60,32,384]{{3,2,1,0}}, f32[40,512]{{1,0}}) custom-call(%m, %half.1), custom_call_target="tpu_custom_call", metadata={{op_name="{_STEP}/recurrence/gru_kernel_bwd/pallas_call"}}
+  %gru_kernel_fwd.1 = bf16[40,60,32,384]{{3,2,1,0}} custom-call(%m), custom_call_target="tpu_custom_call", metadata={{op_name="{_STEP}/recurrence/gru_kernel_fwd/pallas_call"}}
+  %dproj.6 = bf16[40,60,32,384]{{3,2,1,0}} get-tuple-element(%gru_kernel_bwd.6), index=0
+  %dproj.7 = bf16[40,60,32,384]{{3,2,1,0}} get-tuple-element(%gru_kernel_bwd.7), index=0
+  %reduce.56 = bf16[40,384]{{1,0:T(8,128)(2,1)S(1)}} reduce(%dproj.6, %zero), dimensions={{1,2}}, to_apply=%add, metadata={{op_name="{_STEP}/in_proj/reduce_sum"}}
+  %fusion.57 = bf16[40,384]{{1,0}} fusion(%dproj.7), kind=kInput, calls=%fused_sum, metadata={{op_name="{_STEP}/in_proj/reduce_sum"}}
+  %fusion.17 = (f32[40,512,384]{{2,1,0}}, bf16[40,384]{{1,0}}) fusion(%dproj.6, %x), kind=kOutput, calls=%fused_dot, metadata={{op_name="{_STEP}/in_proj/btf,efg->etbg/dot_general"}}
+  %reduce.9 = bf16[40,384]{{1,0}} reduce(%m, %zero), dimensions={{1,2}}, to_apply=%add, metadata={{op_name="{_STEP}/in_proj/reduce_sum"}}
+  %reduce.8 = bf16[40,384]{{1,0}} reduce(%gru_kernel_fwd.1, %zero), dimensions={{1,2}}, to_apply=%add, metadata={{op_name="{_STEP}/heads/reduce_sum"}}
+  ROOT %out = f32[] constant(0)
+}}
+"""
+
+
+def test_kernel_edge_passes_names_the_split_and_the_sums_of_a_result():
+    """What counts: the fusion a ``split`` under the scope became (once,
+    not once a slice inside it) and a slice of it left alone; a ``reduce``
+    over a result of the named kernel, alone (``reduce.56``) or fused
+    (``fusion.57``).  What does not: a ``split`` under another scope, a
+    sum that rides in a dot's fusion (no pass of its own), a sum over an
+    array no kernel wrote or over another kernel's result."""
+    found = profiler.kernel_edge_passes(_EDGE_HLO, "recurrence",
+                                        "gru_kernel_bwd")
+    assert found == ["fusion.201", "half.1", "reduce.56", "fusion.57"]
+    assert profiler.kernel_edge_passes(_EDGE_HLO, "mixing",
+                                       "gru_kernel_fwd") == [
+        "elsewhere", "reduce.8"]
+    assert profiler.kernel_edge_passes(_EDGE_HLO, "heads", "no_kernel") == []
+
+
+def test_first_epoch_sets_the_kernel_edge_passes_gauge(tiny):
+    """``deeprest_train_kernel_edge_passes`` is what ``kernel_edge_passes``
+    finds in the text of the executable the epoch dispatched, and a count
+    of 0 is SET (here the scan backend's, which calls no kernel);
+    ``profile_epoch``'s ``setup`` and the ``set-up:`` line carry it."""
+    _epoch(tiny)
+    gauge = REGISTRY.get(obs_setup.KERNEL_EDGE_PASSES)
+    counted = profiler.kernel_edge_passes(
+        tiny["trainer"]._dispatched_program_text(tiny["state"]),
+        scopes.RECURRENCE, scopes.GRU_KERNEL_BWD)
+    assert gauge.series() == {(): float(len(counted))}
+    table = obs_setup.setup_table()
+    assert table["kernel_edge_passes"] == len(counted)
+    assert f"{len(counted)} passes at the kernels' edge a step" \
         in obs_setup.format_setup(table)

@@ -125,7 +125,7 @@ def joined_and_two_calls(request):
 
 def test_bidirectional_values_are_two_gru_calls_joined(joined_and_two_calls):
     """The pallas path joins the two directions in the kernels' own order
-    before its one transpose (ops/gru._bidir_pallas): layout work only, so
+    before its one transpose (ops/gru._layer_pallas): layout work only, so
     the layer's output is bit for bit that of joining afterwards."""
     r = joined_and_two_calls
     np.testing.assert_array_equal(r["joined"][0], r["two_calls"][0])
@@ -320,8 +320,9 @@ def walked_and_flipped(request):
     independent form of the same kernels: flip the projection in time,
     scan forward, flip the states back.  ``h_all`` and the gradients to
     ``proj``, ``w_hh``, ``b_hh``, ``h0`` of each, through
-    ``ops.gru._recur_local`` (the kernel call with its pads; at an aligned
-    shape it is ``pallas_gru.gru_recurrence`` itself)."""
+    ``ops.gru._recur_local`` (``pallas_gru.gru_recurrence`` of one
+    direction, which pads what the blocks do not divide; the input bias it
+    adds is zero here)."""
     from deeprest_tpu.ops.gru import _recur_local
 
     shape, dtype = request.param
@@ -334,12 +335,15 @@ def walked_and_flipped(request):
     h0 = jax.random.normal(kh, (e, b, H))
     weight = jax.random.normal(kc, (e, t, b, H))
 
+    def one(proj, w_hh, b_hh, h0, reverse):
+        direction = (proj, jnp.zeros_like(b_hh), w_hh, b_hh, h0)
+        return _recur_local((direction,), True, (reverse,))
+
     def walked(proj, w_hh, b_hh, h0):
-        return _recur_local(proj, w_hh, b_hh, h0, True, True)
+        return one(proj, w_hh, b_hh, h0, True)
 
     def flipped(proj, w_hh, b_hh, h0):
-        return jnp.flip(_recur_local(jnp.flip(proj, 1), w_hh, b_hh, h0,
-                                     True, False), 1)
+        return jnp.flip(one(jnp.flip(proj, 1), w_hh, b_hh, h0, False), 1)
 
     def run(layer):
         def loss(*args):
@@ -414,3 +418,4 @@ def test_bidirectional_matches_scan_over_three_time_blocks():
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
     for got, want in zip(grads, g_ref):
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
