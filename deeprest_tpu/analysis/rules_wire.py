@@ -8,7 +8,7 @@ the single buffered append is bounded by an explicit ``len() >= cap``
 backpressure check.  WR001 keeps future edits from re-introducing
 per-frame allocations or blocking calls into that loop — the failure
 mode is invisible in tests (correct output, 10x slower) and only shows
-up as a wire_bench regression.
+up as a receiver that falls behind its producers.
 """
 
 from __future__ import annotations
@@ -48,8 +48,8 @@ class WR001BlockingOrUnboundedInRecvLoop(Rule):
     id = "WR001"
     title = ("per-frame allocation or blocking call in a wire receiver's "
              "recv hot loop")
-    guards = ("round 24: the firehose's >=10x-over-tailer bar "
-              "(benchmarks/wire_bench.json) holds because the per-frame "
+    guards = ("round 24: the firehose keeps up with its producers "
+              "(a host rate, read on the host) because the per-frame "
               "recv loop is frame accounting only — no file I/O, no "
               "stdout, no whole-connection json.loads, no unbounded "
               "buffering.  Each of those is a silent throughput cliff: "

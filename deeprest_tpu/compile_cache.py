@@ -2,7 +2,7 @@
 
 A chip run starts with no compiled code, and the flagship step takes tens
 of seconds to compile, so every entry point (``deeprest_tpu`` CLI,
-``chip_smoke.py``, ``bench.py``, the tests) shares one cache.  Its path is
+``chip_smoke.py``, ``chipbench``, the tests) shares one cache.  Its path is
 part of the cache key's environment, so it has to be stable: no temporary
 name, pid or time in it.
 

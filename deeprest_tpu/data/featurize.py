@@ -310,8 +310,8 @@ class CallPathSpace:
                           out: np.ndarray | None = None) -> np.ndarray:
         """The historical per-span accumulation loop, kept verbatim as the
         semantic specification of ``extract``: parity tests pin the
-        vectorized path against it bit-for-bit, and benchmarks/etl_bench.py
-        uses it as the old-throughput baseline."""
+        vectorized path against it bit-for-bit
+        (tests/test_featurize.py)."""
         self.freeze()
         if out is not None:
             out[:] = 0.0

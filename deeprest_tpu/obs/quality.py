@@ -397,7 +397,7 @@ class QualityMonitor:
         # the model has matured through model_warmup_refreshes.
         self._model_armed = True
         # verdict-transition event log (bucket index, stream, state) —
-        # what drift_bench reads detection latency off
+        # what tests/test_quality.py reads detection latency off
         self.events: list[tuple[int, str, str]] = []
         reg = registry or obs_metrics.REGISTRY
         self._m_sweeps = reg.expose(obs_metrics.Counter(
@@ -641,7 +641,7 @@ class QualityMonitor:
     def _log_transitions_locked(self, bucket: int) -> list:
         """Append newly-entered/exited states to the event log — one
         ``(bucket_index, stream, state)`` row per transition, the record
-        drift_bench reads detection latency off (caller holds the lock)."""
+        detection latency is read off (caller holds the lock)."""
         fresh = []
         streams = [("feature_drift",
                     VERDICT_DRIFT if self._drift_machine.active
@@ -665,8 +665,8 @@ class QualityMonitor:
         # fault, not the application's.  The loop disambiguates
         # temporally: drift triggers a retrain, the reference re-anchors,
         # and whatever excess SURVIVES the fresh model is real anomaly
-        # (the ransomware-mid-drift scenario in drift_bench pins exactly
-        # this sequence).
+        # (the anomaly-mid-drift stream of tests/test_quality.py pins
+        # exactly this sequence).
         if self._drift_machine.active:
             return VERDICT_DRIFT
         if self._anomaly_machines[e].active:

@@ -53,7 +53,7 @@ import numpy as np
 
 from deeprest_tpu.ops import scopes
 
-try:  # host-only callers (benchmarks, lint) may lack an initialized backend
+try:  # host-only callers (lint) may lack an initialized backend
     import flax.struct
     import jax
     import jax.numpy as jnp

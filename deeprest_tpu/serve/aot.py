@@ -27,7 +27,7 @@ Three contracts keep this honest:
 - **Identical lowering.**  The serialized executable is compiled from
   the SAME traced program the lazy jit path would compile, with default
   options on the same backend — outputs are bit-identical either way
-  (asserted by benchmarks/fleet_bench.py's parity arm).
+  (asserted by tests/test_fleet.py's AOT cases).
 - **Loud staleness.**  A manifest whose fingerprint (jax version, XLA
   platform, geometry, params tree signature) mismatches the live engine
   is never partially loaded: the whole load falls back to compile, with

@@ -18,8 +18,8 @@ Policies:
   carry 100× the windows of a single-window predict.
 - **Admission** — a bounded global in-flight depth.  Beyond it, requests
   FAIL FAST with 429 + ``Retry-After`` instead of queueing into collapse
-  (the closed-loop serve_bench at concurrency 1024 pins p99 staying
-  bounded).  A small bounded wait absorbs micro-bursts; the queue itself
+  (tests/test_router.py holds the 429 and its header over real HTTP).
+  A small bounded wait absorbs micro-bursts; the queue itself
   is also bounded.
 - **Fairness** — smooth weighted round-robin over the ``X-Tenant`` key.
   When slots free up, waiting tenants are granted in WRR order, so a

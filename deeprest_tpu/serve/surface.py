@@ -37,7 +37,7 @@ Shape of the thing:
 Parity is measured, not assumed: every build interpolates its held-out
 jitter probes and compares against their direct estimates from the SAME
 folded batch; the resulting envelope is stored on the surface, exposed
-on /healthz, and pinned by tests and benchmarks/whatif_bench.py.
+on /healthz, and pinned by tests/test_surface.py.
 """
 
 from __future__ import annotations

@@ -132,7 +132,7 @@ class SparseSeriesRing:
     @property
     def nbytes(self) -> int:
         """Resident buffer bytes (the memory-ceiling number
-        benchmarks/tenk_bench.py banks)."""
+        tests/test_sparse.py holds at a month of minutes)."""
         return (self._cols._buf.nbytes + self._vals._buf.nbytes
                 + self._nnz._buf.nbytes)
 

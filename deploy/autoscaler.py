@@ -19,8 +19,8 @@ Two capacity bases, used in preference order:
    program, the estimator predicts the configured metric's series, and
    ``desired = ceil(peak_predicted / (unit_capacity * target))``.
 2. **measured** — no estimator: ``desired = ceil(peak_rps /
-   (capacity_rps_per_replica * target))`` with the per-replica rps taken
-   from the committed serve_bench headline.
+   (capacity_rps_per_replica * target))`` with the per-replica rps as
+   the deployment measured it on its own replicas.
 
 Run it in-process (``deeprest_tpu serve --replicas N --autoscale ...``
 starts the loop thread next to the server) or drive :meth:`step`
@@ -44,8 +44,8 @@ class AutoscalerConfig:
     # fraction of a replica's capacity the plane should run at — headroom
     # for bursts between control ticks
     target_utilization: float = 0.7
-    # measured basis: requests/s one replica sustains (serve_bench's
-    # batched headline is the honest source)
+    # measured basis: requests/s one replica sustains, as the deployment
+    # measured it (the benchmark has no serving cell yet)
     capacity_rps_per_replica: float | None = None
     # model basis: what the estimator predicts for the serving plane
     endpoint: str = "deeprest-predictor_/v1/predict"

@@ -53,6 +53,19 @@ def assert_fold_equal(actual, desired):
     np.testing.assert_allclose(actual, desired, rtol=0.0, atol=atol)
 
 
+@pytest.fixture
+def hand_clock(monkeypatch):
+    """``obs.metrics.Stopwatch``'s clock, moved by hand: ``now[0] += s``."""
+    from types import SimpleNamespace
+
+    from deeprest_tpu.obs import metrics as obs_metrics
+
+    now = [100.0]
+    monkeypatch.setattr(obs_metrics, "time",
+                        SimpleNamespace(perf_counter=lambda: now[0]))
+    return now
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",

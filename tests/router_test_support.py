@@ -39,7 +39,10 @@ def build_slow(delay_s: float = 1.0, scale: float = 1.0, ladder=(8,)):
                        delay_s)
 
 
-def build_tiny(scale: float = 1.0, ladder=(8,)):
+def build_tiny(scale: float = 1.0, ladder=(8,), f: int = F, e: int = E,
+               h: int = H, w: int = W, quant: str = "off"):
+    """A random-init predictor at unit min/max stats; the same seed, so the
+    same tree at the same widths whatever ``quant`` serves it as."""
     import jax
 
     from deeprest_tpu.config import ModelConfig
@@ -47,18 +50,18 @@ def build_tiny(scale: float = 1.0, ladder=(8,)):
     from deeprest_tpu.models.qrnn import QuantileGRU
     from deeprest_tpu.serve import Predictor
 
-    mc = ModelConfig(feature_dim=F, num_metrics=E, hidden_size=H,
+    mc = ModelConfig(feature_dim=f, num_metrics=e, hidden_size=h,
                      dropout_rate=0.0)
     model = QuantileGRU(config=mc)
     params = model.init(jax.random.PRNGKey(0),
-                        np.zeros((1, W, F), np.float32),
+                        np.zeros((1, w, f), np.float32),
                         deterministic=True)["params"]
     if scale != 1.0:
         params = jax.tree.map(lambda a: a * scale, params)
     return Predictor(
         params, mc,
         x_stats=MinMaxStats(min=np.float32(0.0), max=np.float32(1.0)),
-        y_stats=MinMaxStats(min=np.zeros((E,), np.float32),
-                            max=np.ones((E,), np.float32)),
-        metric_names=[f"c{i}_cpu" for i in range(E)],
-        window_size=W, ladder=tuple(ladder))
+        y_stats=MinMaxStats(min=np.zeros((e,), np.float32),
+                            max=np.ones((e,), np.float32)),
+        metric_names=[f"c{i}_cpu" for i in range(e)],
+        window_size=w, ladder=tuple(ladder), quant=quant)

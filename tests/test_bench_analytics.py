@@ -1,6 +1,6 @@
-"""The bench's analytic perf model: FLOPs/step, chip-peak lookup, MFU block
-(round-2 verdict missing #6 — the bench must carry its own absolute anchor).
-Importing bench.py touches no JAX backend (its design guarantee)."""
+"""The program's own analytic count of a training step and the chip-peak
+lookup (`bench.py`, which imports nothing), pinned by hand: the yardstick's
+`chipbench/flops.py` is cross-checked against them."""
 
 import numpy as np
 import pytest
@@ -37,33 +37,3 @@ def test_chip_peak_lookup():
     for kind in ("cpu", "TPU v4", ""):
         with pytest.raises(ValueError, match="no published peak"):
             bench.chip_peak_tflops(kind)
-
-
-def test_mfu_block_shape():
-    measured = {"steps_per_sec": 100.0, "device_kind": "TPU v5 lite",
-                "model_state_bytes": 123}
-    block = bench._mfu_block(measured, bench.F)
-    assert block["chip_peak_bf16_tflops"] == 197.0
-    np.testing.assert_allclose(
-        block["sustained_tflops"],
-        100.0 * bench.train_step_tflops(bench.B, bench.T, bench.F,
-                                        bench.E, bench.H), rtol=1e-2)
-    assert 0 < block["mfu_pct"] < 100
-    assert block["model_state_bytes"] == 123
-    with pytest.raises(ValueError, match="no published peak"):
-        bench._mfu_block({"steps_per_sec": 10.0, "device_kind": "cpu"},
-                         bench.F)
-
-
-def test_bench_has_no_way_back_from_a_failure():
-    """bench.py changes neither backend nor platform after a failure: the
-    names of the machinery that did are gone, and `--cpu` is read in one
-    place, the parent's main."""
-    import inspect
-
-    src = inspect.getsource(bench)
-    for gone in ("rnn_backend_fallback", "last_good", "_measure_with_fallback",
-                 "TPU_PROBE", "--probe"):
-        assert gone not in src, gone
-    assert src.count('"--cpu" in sys.argv') == 1
-    assert "jax_platforms" not in src

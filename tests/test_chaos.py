@@ -753,12 +753,10 @@ def test_retry_budget_exhaustion_is_fast_503():
                              alive=False) for i in range(3)]
     router = _router(all_dead, retry_budget=1, eject_after_failures=1)
     try:
-        t0 = time.monotonic()
         with pytest.raises(ServingError) as exc:
             router.predict_series(np.zeros((W, 6)))
         assert exc.value.status == 503
-        assert time.monotonic() - t0 < 2.0, "budget 503 must be fast"
-        # total attempts bounded by budget + 1
+        # fast because bounded: total attempts are budget + 1
         assert sum(r.calls for r in all_dead) == 2
     finally:
         router.close()
@@ -769,11 +767,10 @@ def test_all_replicas_ejected_sheds_fast_until_rejoin():
     router = _router([r], eject_after_failures=1, probe_interval_s=0.3)
     try:
         router.eject("r0", reason="chaos schedule")
-        t0 = time.monotonic()
         with pytest.raises(ServingError) as exc:
             router.predict_series(np.zeros((W, 6)))
         assert exc.value.status == 503
-        assert time.monotonic() - t0 < 2.0, "ejected plane must shed fast"
+        assert r.calls == 0, "an ejected plane sheds without dispatching"
         # the probe rejoins the thread replica (no restart to perform)
         deadline = time.monotonic() + 5.0
         while True:
@@ -844,10 +841,10 @@ def test_process_replica_deadline_turns_wedge_into_typed_error():
     rep = ProcessReplica(_proc_spec(delay_s=30.0), name="p0",
                          boot_timeout_s=300.0, request_timeout_s=1.0)
     try:
-        t0 = time.monotonic()
+        # the worker would answer after 30 s: an error at all is the
+        # 1 s deadline's
         with pytest.raises(ReplicaDeadError) as exc:
             rep.predict_series(traffic)
-        assert time.monotonic() - t0 < 10.0
         assert exc.value.retriable is False
         assert "alive" in str(exc.value)
         assert rep.alive()

@@ -161,6 +161,31 @@ def test_aot_fingerprint_mismatch_falls_back_to_compile(traffic):
         assert out.shape[0] == len(traffic)          # lazy path still serves
 
 
+def test_load_aot_serves_another_tenants_params_without_the_lazy_jit(
+        traffic):
+    """Artifacts are params-agnostic: exported from one tenant, loaded into
+    a predictor of the same architecture with OTHER params, they answer
+    bit for bit what that predictor's own compile answers, every rung
+    loaded and the lazy jit cache untouched (serve/aot.py, "Identical
+    lowering")."""
+    from deeprest_tpu.serve.aot import export_aot, load_aot
+
+    compiled = np.asarray(
+        build_tiny(scale=2.0, ladder=(8,)).predict_series(traffic))
+    with tempfile.TemporaryDirectory() as ckpt:
+        manifest = export_aot(build_tiny(scale=1.0, ladder=(8,)), ckpt)
+        assert manifest["entries"]
+        assert all(e["bytes"] > 0 for e in manifest["entries"])
+        tgt = build_tiny(scale=2.0, ladder=(8,))
+        res = load_aot(tgt, ckpt)
+        assert res["loaded"] == len(manifest["entries"])
+        assert res["fallback_rungs"] == [] and res["reason"] is None
+        assert res["bytes"] == sum(e["bytes"] for e in manifest["entries"])
+        assert np.array_equal(
+            compiled, np.asarray(tgt.predict_series(traffic)))
+        assert tgt.jit_cache_size() == 0
+
+
 # ---------------------------------------------------------------------------
 # Fleet-tier chaos coverage (satellite 2)
 
@@ -248,6 +273,88 @@ def test_eviction_under_live_load_restores_without_compile(traffic):
     st = pool.stats()
     assert st["spills"] > 0 and st["restores"] > 0
     assert st["resident"] == 1          # the budget held
+
+
+def test_twelve_apps_over_a_budget_of_four_hold_ledger_and_residency(
+        traffic):
+    """More apps than the device budget, reached at random: the executable
+    ledger frozen after ONE app's warm-up holds for all twelve, the LRU
+    keeps residency at the budget (a tier, not a leak) with spills and
+    restores both counted, and the sampled apps answer after the storm
+    what they answered before it, bit for bit."""
+    apps, budget = 12, 4
+    pool = PredictorPool(hbm_budget=budget, aot=False)
+    for i in range(apps):
+        pool.admit(f"app{i:02d}",
+                   build_tiny(scale=1.0 + 0.01 * i, ladder=(8,)))
+    pool.resolve("app00").predictor().predict_series(traffic)
+    warm = pool.freeze()
+    refs = {f"app{i:02d}": np.asarray(
+        pool.resolve(f"app{i:02d}").predictor().predict_series(traffic))
+        for i in range(apps)}
+    assert pool.assert_frozen() == warm            # zero compiles an app
+    assert len({r.tobytes() for r in refs.values()}) == apps
+    before = pool.stats()
+    rng = np.random.default_rng(23)
+    for _ in range(60):
+        pool.resolve(f"app{int(rng.integers(0, apps)):02d}") \
+            .predictor().predict_series(traffic)
+    for name in ("app00", "app01", "app02"):
+        assert np.array_equal(refs[name], np.asarray(
+            pool.resolve(name).predictor().predict_series(traffic)))
+    pool.assert_frozen()
+    after = pool.stats()
+    assert after["spills"] > before["spills"]
+    assert after["restores"] > before["restores"]
+    assert after["resident"] == budget
+    assert after["resident"] + after["spilled"] == apps
+
+
+def test_tenant_answers_unmoved_by_a_noisy_neighbours_load_and_reload(
+        traffic):
+    """Tenant A read eight times while tenant B is hammered from another
+    thread and hot-swapped mid-storm: A's bytes never move, B's do, B's
+    invalidation is counted once under its reason, nothing compiles."""
+    pool = PredictorPool(hbm_budget=2, aot=False)
+    pool.admit("a", build_tiny(scale=1.0, ladder=(8,)))
+    pool.admit("b", build_tiny(scale=2.0, ladder=(8,)))
+
+    def answer(tenant):
+        return np.asarray(
+            pool.resolve(tenant).predictor().predict_series(traffic))
+
+    ref_a, b_before = answer("a"), answer("b")
+    pool.freeze()
+    assert all(np.array_equal(ref_a, answer("a")) for _ in range(3))
+    stop, swapped, errors = threading.Event(), threading.Event(), []
+
+    def hammer():
+        try:
+            for k in range(10_000):
+                if stop.is_set():
+                    return
+                answer("b")
+                if k == 2:
+                    pool.reload("b", build_tiny(scale=3.0, ladder=(8,)),
+                                reason="storm-reload")
+                    swapped.set()
+        except Exception as exc:      # surfaced below, not swallowed
+            errors.append(repr(exc))
+            swapped.set()
+
+    th = threading.Thread(target=hammer, daemon=True)
+    th.start()
+    concurrent = [np.array_equal(ref_a, answer("a")) for _ in range(8)]
+    assert swapped.wait(timeout=60)
+    concurrent.append(np.array_equal(ref_a, answer("a")))
+    stop.set()
+    th.join(timeout=60)
+    assert not errors, errors
+    assert all(concurrent)
+    assert not np.array_equal(b_before, answer("b"))
+    assert pool.peek("b").invalidations() == {"storm-reload": 1}
+    assert pool.peek("a").invalidations() == {}
+    pool.assert_frozen()
 
 
 # ---------------------------------------------------------------------------

@@ -215,14 +215,15 @@ def test_registry_kind_mismatch_rejected():
         reg.gauge("m_total")
 
 
-def test_stopwatch():
+def test_stopwatch(hand_clock):
     sw = Stopwatch()
-    time.sleep(0.01)
-    e = sw.elapsed()
-    assert 0.005 < e < 5.0
+    hand_clock[0] += 0.25
+    assert sw.elapsed() == 0.25
     h = Histogram("sw_seconds")
-    sw.observe_into(h)
+    assert sw.observe_into(h) == 0.25
     assert h.snapshot()["count"] == 1
+    sw.restart()
+    assert sw.elapsed() == 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -248,6 +249,83 @@ def test_span_propagation_thread_replicas(recorder_on):
     assert (names["fused.predict"].parent_id
             == names["replica.predict"].span_id)
     router.close()
+
+
+def _one_served_request():
+    """One request as the HTTP handler opens it: root span + the engine's
+    own ``fused.predict``.  Counted by the engine's dispatch statistics."""
+    pred = build_tiny()
+    traffic = np.random.default_rng(0).random((W * 3, F), np.float32)
+    pred.predict_series(traffic)                     # compile outside
+
+    def run():
+        with obs.span("/v1/predict", component="deeprest-predictor"):
+            return np.asarray(pred.predict_series(traffic))
+
+    return run, lambda: pred.fused.stats()["pages"], {
+        "/v1/predict", "fused.predict"}, True
+
+
+def _one_train_epoch():
+    """One epoch of a toy trainer on the host feed.  Counted by the
+    registry's always-live series."""
+    from deeprest_tpu.config import Config, ModelConfig, TrainConfig
+    from deeprest_tpu.data.windows import MinMaxStats
+    from deeprest_tpu.obs.metrics import REGISTRY
+    from deeprest_tpu.train import Trainer
+    from deeprest_tpu.train.data import DatasetBundle
+
+    names = [f"c{i}_cpu" for i in range(E)]
+    cfg = Config(model=ModelConfig(feature_dim=F, num_metrics=E,
+                                   hidden_size=8, dropout_rate=0.0),
+                 train=TrainConfig(batch_size=8, window_size=W,
+                                   log_every_steps=0))
+    trainer = Trainer(cfg, F, names)
+    rng = np.random.default_rng(0)
+    x = rng.random((24, W, F), np.float32)
+    y = rng.random((24, W, E), np.float32)
+    stats = MinMaxStats(min=np.float32(0.0), max=np.float32(1.0))
+    bundle = DatasetBundle(x_train=x, y_train=y, x_test=x[:4], y_test=y[:4],
+                           x_stats=stats, y_stats=stats, metric_names=names,
+                           split=24, window_size=W)
+    box = {"state": trainer.init_state(x)}
+
+    def run():
+        box["state"], loss = trainer.train_epoch(
+            box["state"], bundle, np.random.default_rng(1))
+        return np.asarray(loss)
+
+    run()                                            # compile outside
+    return run, lambda: REGISTRY.get(
+        "deeprest_train_epochs_total").value(), {"train.epoch"}, False
+
+
+@pytest.mark.parametrize("path", [_one_served_request, _one_train_epoch],
+                         ids=["serve", "train"])
+def test_recorder_off_leaves_no_span_and_the_counters_still_advance(path):
+    """The two hot paths with the recorder off and then on: off, the ring
+    stays empty while the path's counter advances; on, the same call
+    leaves the path's spans and counts the same (and a request, which
+    moves no state, answers the same bytes)."""
+    run, counter, expected, repeatable = path()
+    assert obs.RECORDER.enabled is False             # the process default
+    obs.RECORDER.clear()
+    c0 = counter()
+    off = run()
+    c1 = counter()
+    assert len(obs.RECORDER) == 0
+    assert c1 > c0
+    obs.RECORDER.enabled = True
+    try:
+        on = run()
+        names = {s.name for s in obs.RECORDER.snapshot()}
+    finally:
+        obs.RECORDER.enabled = False
+        obs.RECORDER.clear()
+    assert expected <= names, names
+    assert counter() - c1 == c1 - c0
+    assert on.shape == off.shape and np.all(np.isfinite(on))
+    assert not repeatable or np.array_equal(on, off)
 
 
 def test_span_propagation_batcher_worker(recorder_on):

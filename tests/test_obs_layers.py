@@ -11,7 +11,6 @@ import json
 import os
 import subprocess
 import sys
-import time
 
 import numpy as np
 import pytest
@@ -242,16 +241,13 @@ def test_two_epochs_fill_the_phase_counters(tiny):
     start_step = trainer._global_step
     before = _totals()
     first = _phase_values("deeprest_train_last_epoch_phase_seconds")
-    t0 = time.perf_counter()
     _epoch(tiny)
-    wall = time.perf_counter() - t0
     after = _totals()
     last = _phase_values("deeprest_train_last_epoch_phase_seconds")
 
     assert set(last) == set(EPOCH_PHASES)
     assert all(v > 0 for v in last.values()), last
     assert last != first
-    assert sum(last.values()) <= wall
     assert after["epochs"] - before["epochs"] == 1
     assert after["dispatches"] - before["dispatches"] == chunks
     # one readback per chunk in which a multiple of LOG_EVERY falls, and
@@ -286,24 +282,22 @@ def test_epoch_spans_are_children_of_one_epoch_span(tiny):
     assert all(s.parent_id == epoch[0].span_id for s in phases)
 
 
-def test_nested_phase_is_timed_exclusively_and_a_failed_unit_publishes_nothing():
+def test_nested_phase_is_timed_exclusively_and_a_failed_unit_publishes_nothing(
+        hand_clock):
+    now = hand_clock
     reg = obs.MetricsRegistry()
     clock = obs.PhaseClock(
         "unit", "deeprest-test", ("outer", "inner", "unused"),
         last_seconds=reg.gauge("s_last", labelnames=("phase",)),
         units_total=reg.counter("units"))
-    t0 = time.perf_counter()
     with clock.unit() as phase:
         with phase("outer"):
-            time.sleep(0.01)
+            now[0] += 1.0
             with phase("inner"):
-                time.sleep(0.02)
-    wall = time.perf_counter() - t0
-    outer = clock.last_seconds.value(phase="outer")
-    inner = clock.last_seconds.value(phase="inner")
-    assert inner >= 0.02 and 0.01 <= outer < inner
-    assert outer + inner <= wall
-    assert clock.last_seconds.series()[("unused",)] == 0.0
+                now[0] += 2.0
+            now[0] += 0.5
+    assert clock.last_seconds.series() == {
+        ("outer",): 1.5, ("inner",): 2.0, ("unused",): 0.0}
     with pytest.raises(ValueError):
         with clock.unit() as phase:
             with phase("nope"):
@@ -519,14 +513,11 @@ def test_train_profile_dir_writes_layers_json(tmp_path, capsys):
 def test_init_state_sets_its_four_phases(tiny):
     trainer, bundle = tiny["trainer"], tiny["bundle"]
     gauge = REGISTRY.get(obs_setup.INIT_STATE_SECONDS)
-    t0 = time.perf_counter()
     spans = _recorded(
         lambda: trainer.init_state(trainer.sample_input(bundle)))
-    wall = time.perf_counter() - t0
     seconds = {p: gauge.value(phase=p) for p in INIT_PHASES}
     assert set(k[0] for k in gauge.series()) == set(INIT_PHASES)
     assert all(v > 0 for v in seconds.values()), seconds
-    assert sum(seconds.values()) <= wall
     ours = [s for s in spans if s.component == "deeprest-trainer"]
     whole = [s for s in ours if s.name == "train.init_state"]
     assert len(whole) == 1
@@ -842,3 +833,61 @@ def test_first_epoch_sets_the_kernel_edge_passes_gauge(tiny):
     assert table["kernel_edge_passes"] == len(counted)
     assert f"{len(counted)} passes at the kernels' edge a step" \
         in obs_setup.format_setup(table)
+
+
+# -- the seam to the benchmark ----------------------------------------------
+
+# The program series that `chipbench/readers/*.py` (and the runners beside
+# them) read BY NAME, each with the label names the reader indexes and the
+# label values it asks for that a CPU run sets.  A copy, on purpose: a PR
+# that renames a series or a kind fails here, before the ledger writes
+# `null` under `per_layer`.  What only a chip or a mesh sets (memory
+# statistics, the compiler's memory spaces, collectives) has no row here.
+_BENCHMARK_SERIES = [
+    ("deeprest_compilations_total", ("program", "phase"),
+     [{"program": "train_superstep", "phase": "first_dispatch"}]),
+    ("deeprest_compile_seconds_total", ("program", "phase"),
+     [{"program": "train_superstep", "phase": "first_dispatch"}]),
+    ("deeprest_train_collective_bytes", (), []),
+    ("deeprest_train_device_bytes", ("at", "kind"), []),
+    ("deeprest_train_dropout_draws", (), [{}]),
+    ("deeprest_train_epochs_total", (), [{}]),
+    ("deeprest_train_init_state_seconds", ("phase",),
+     [{"phase": p} for p in ("model_init", "shard", "opt_init", "pin")]),
+    ("deeprest_train_kernel_edge_passes", (), [{}]),
+    ("deeprest_train_kernel_operand_bytes", ("space",), []),
+    ("deeprest_train_last_epoch_phase_seconds", ("phase",),
+     [{"phase": p} for p in ("plan_build", "plan_h2d", "loss_readback")]),
+    ("deeprest_train_last_stage_seconds", (), [{}]),
+    ("deeprest_train_optimizer_rows", ("kind",),
+     [{"kind": k} for k in ("total", "updated", "visited", "stale",
+                            "trips", "bound")]),
+    ("deeprest_train_projection_columns", ("kind",),
+     [{"kind": k} for k in ("live", "contracted", "total", "padded",
+                            "bound")]),
+    ("deeprest_train_readbacks_total", (), [{"sink": "epoch_losses"}]),
+    ("deeprest_train_superstep_dispatches_total", (), [{}]),
+    ("deeprest_train_time_reversals", (), [{}]),
+]
+
+
+@pytest.fixture(scope="module")
+def compact_after_an_epoch(tiny_compact):
+    _epoch(tiny_compact)
+    return tiny_compact
+
+
+@pytest.mark.parametrize("name, indexed, set_on_the_cpu", _BENCHMARK_SERIES,
+                         ids=[row[0] for row in _BENCHMARK_SERIES])
+def test_the_series_the_benchmark_reads_by_name(compact_after_an_epoch, name,
+                                                indexed, set_on_the_cpu):
+    """After `init_state`, staging and one staged sparse epoch on the
+    compact form: the series is registered under the reader's name with
+    the label names the reader indexes, and holds a row for every label
+    value the reader asks for."""
+    metric = REGISTRY.get(name)
+    assert metric is not None, f"{name} is not registered"
+    assert set(indexed) <= set(metric.labelnames), metric.labelnames
+    rows = [dict(zip(metric.labelnames, key)) for key in metric.series()]
+    for want in set_on_the_cpu:
+        assert any(want.items() <= row.items() for row in rows), (want, rows)

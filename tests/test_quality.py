@@ -628,6 +628,106 @@ def test_clean_corpus_produces_zero_verdicts(tmp_path):
     assert v["states"][VERDICT_ANOMALY] == 0
 
 
+# ---------------------------------------------------------------------------
+# A topology shift with an anomaly riding the drifted regime, on the
+# simulator's corpus: when the loop flags what, counted in buckets and sweeps
+
+
+_CYCLE = 30                       # buckets a traffic cycle
+_SHIFT_AT = 8 * _CYCLE            # the AFTER topology takes over
+_ANOMALY_AT = _SHIFT_AT + 5 * _CYCLE
+_BUCKETS = _SHIFT_AT + 10 * _CYCLE
+
+
+@pytest.fixture(scope="module")
+def anomaly_mid_drift():
+    """One stream through trainer and controller: eight services grow to
+    fourteen at ``_SHIFT_AT``; from ``_ANOMALY_AT``, after the loop has had
+    time to retrain through the drift, one store of the new topology is
+    attacked.  Windows span whole traffic cycles (the generator re-draws
+    the API mix a cycle, so a shorter window reads phase as drift) and the
+    enter threshold sits between the mix's natural churn (PSI up to ~0.85)
+    and the shift's signal (1.7-3.5)."""
+    from deeprest_tpu.workload.scenarios import normal_scenario
+    from deeprest_tpu.workload.simulator import (
+        build_shifted_app, simulate_drift_corpus_iter,
+    )
+    from deeprest_tpu.workload.telemetry import Anomaly
+
+    capacity, window = 128, 8
+    sc = normal_scenario(seed=0)
+    sc.calls_per_user, sc.base_users = 0.5, 40.0
+    sc.peak_range, sc.cycle_len = (56.0, 80.0), _CYCLE
+    before, after, endpoints = build_shifted_app(sc, 8, 14, 4, seed=0)
+    store = next(c for c in after.components
+                 if c.endswith(("-mongodb", "-redis")))
+    buckets = simulate_drift_corpus_iter(
+        sc, _BUCKETS, _SHIFT_AT, before, after, endpoints,
+        anomalies=[Anomaly(kind="ransomware", component=store,
+                           start=_ANOMALY_AT, end=_BUCKETS, magnitude=8.0)])
+    qc = QualityConfig(
+        enabled=True, sweep_every_buckets=_CYCLE // 2,
+        live_window=2 * _CYCLE, reference_window=4 * _CYCLE,
+        min_sweep_buckets=window, sustain_enter=2, sustain_exit=2,
+        drift_enter=1.0, drift_exit=0.5,
+        calibration_enter=0.5, calibration_exit=0.25,
+        retrain_cooldown_buckets=3 * _CYCLE, model_warmup_refreshes=4)
+    st = StreamingTrainer(
+        Config(model=ModelConfig(feature_dim=capacity, hidden_size=8),
+               train=TrainConfig(batch_size=8, window_size=window, seed=0,
+                                 eval_stride=1, eval_max_cycles=2,
+                                 log_every_steps=0)),
+        StreamConfig(refresh_buckets=40, finetune_epochs=2,
+                     history_max=360, eval_holdout=2),
+        ckpt_dir=None,
+        feature_config=FeaturizeConfig(hash_features=True,
+                                       capacity=capacity))
+    controller = DriftController(st, qc)
+    events, seen = [], 0          # (stream bucket, stream, state)
+    for i, bucket in enumerate(buckets):
+        st.ingest(bucket)
+        if st.ready():
+            st.refresh()
+        if controller.monitor is not None:
+            fresh = controller.monitor.events[seen:]
+            seen += len(fresh)
+            events.extend((i, s, state) for _, s, state in fresh)
+    return dict(events=events, qc=qc, store=store,
+                stats=controller.stats)
+
+
+def test_shift_flagged_inside_the_budget_and_never_before_it(
+        anomaly_mid_drift):
+    """Zero drift flags before the shift; the flag within the sweeps it
+    takes the live window to refill with the new regime, plus the sustain
+    and two of slack; a retrain fired and the verdict left drift after."""
+    r = anomaly_mid_drift
+    qc, drift = r["qc"], [b for b, s, state in r["events"]
+                          if s == "feature_drift" and state == VERDICT_DRIFT]
+    assert drift and min(drift) >= _SHIFT_AT, drift
+    sweeps = (drift[0] - _SHIFT_AT) / qc.sweep_every_buckets
+    budget = (qc.live_window + qc.sweep_every_buckets
+              * (qc.sustain_enter + 2)) / qc.sweep_every_buckets
+    assert sweeps <= budget, (sweeps, budget)
+    assert r["stats"]["retrains_triggered"] >= 1
+    assert any(b > drift[0] for b, s, state in r["events"]
+               if s == "feature_drift" and state == VERDICT_OK)
+
+
+def test_anomaly_mid_drift_flagged_on_the_attacked_stores_metrics(
+        anomaly_mid_drift):
+    """What survives the retrained model is the anomaly: flagged at or
+    after its start, on metrics of the attacked store and of no other
+    component."""
+    r = anomaly_mid_drift
+    flagged = [(b, s) for b, s, state in r["events"]
+               if state == VERDICT_ANOMALY and b >= _ANOMALY_AT]
+    assert flagged, r["events"][-20:]
+    assert all(s.startswith(r["store"]) for _, s in flagged), flagged
+    assert not any(state == VERDICT_ANOMALY and s.startswith(r["store"])
+                   for b, s, state in r["events"] if b < _ANOMALY_AT)
+
+
 def test_manual_override_suppresses_auto_retrain():
     st = StreamingTrainer(
         _trainer_config(), _stream_config(), ckpt_dir=None,

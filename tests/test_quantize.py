@@ -196,8 +196,8 @@ def test_from_checkpoint_quant_modes(ckpt):
     # space, asserted above); the serving wire amplifies it through
     # de-normalization (y range) and delta integration (prefix-sum
     # accumulates per-window drift over the series), so here the check
-    # is a loose sanity bound, not the envelope itself — quant_bench
-    # pins the envelope transfer on the unit-stats serving path.
+    # is a loose sanity bound, not the envelope itself, which
+    # test_serving_drift_inside_the_stored_envelope holds at unit stats.
     assert float(np.max(np.abs(out_q - out_off))) < 0.5
     # stats name the mode
     assert pred_q.jit_cache_stats()["quant"] == "int8"
@@ -245,6 +245,64 @@ def test_bf16_mode_parity(ckpt):
     env = pred.parity_envelope
     assert env["mode"] == "bf16"
     assert all(env["measured"][k] <= env["budget"][k] for k in env["budget"])
+
+
+# ---------------------------------------------------------------------------
+# the serving path at unit stats: one tree, one Predictor a mode
+
+
+_MODES = ("off", "int8", "bf16")
+_SERIES = 96
+
+
+@pytest.fixture(scope="module")
+def unit_stats_predictors():
+    """The same float32 tree served at every mode with unit min/max stats,
+    so de-normalization amplifies nothing and the serving wire can be held
+    to the envelope itself; each warmed by the same one request."""
+    from router_test_support import build_tiny
+
+    f = 96
+    preds = {mode: build_tiny(f=f, e=3, h=48, w=12, quant=mode)
+             for mode in _MODES}
+    traffic = np.random.default_rng(7).random(
+        (_SERIES, f)).astype(np.float32)
+    answers = {m: np.asarray(p.predict_series(traffic), np.float64)
+               for m, p in preds.items()}
+    warm = {m: p.jit_cache_size() for m, p in preds.items()}
+    return dict(preds=preds, traffic=traffic, answers=answers, warm=warm)
+
+
+@pytest.mark.parametrize("mode", ["int8", "bf16"])
+def test_serving_drift_inside_the_stored_envelope(unit_stats_predictors,
+                                                  mode):
+    """A held-out series (not the quantize-time probe) through the fused
+    path stays inside the mode's budget, which holds one cell a metric and
+    quantile."""
+    u = unit_stats_predictors
+    env = u["preds"][mode].parity_envelope
+    assert len(env["budget"]) == len(env["measured"]) == 9   # 3 x 3
+    drift = float(np.max(np.abs(u["answers"][mode] - u["answers"]["off"])))
+    assert 0.0 < drift <= max(env["budget"].values())
+
+
+def test_executable_count_is_the_same_at_every_mode(unit_stats_predictors):
+    warm = unit_stats_predictors["warm"]
+    assert warm["off"] is not None and warm["off"] >= 1
+    assert len(set(warm.values())) == 1, warm
+
+
+@pytest.mark.parametrize("mode", _MODES)
+def test_no_executable_added_after_the_warm_up(unit_stats_predictors, mode):
+    """The same series again and its first half (a second use of the
+    rung) compile nothing at any mode."""
+    u = unit_stats_predictors
+    pred, traffic = u["preds"][mode], u["traffic"]
+    np.testing.assert_array_equal(
+        np.asarray(pred.predict_series(traffic), np.float64),
+        u["answers"][mode])
+    pred.predict_series(traffic[: _SERIES // 2])
+    assert pred.jit_cache_size() == u["warm"][mode]
 
 
 def test_invalid_quant_mode_rejected(ckpt):

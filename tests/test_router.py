@@ -72,6 +72,69 @@ def test_routed_results_byte_identical(pred8, traffic, n):
         router.close()
 
 
+@pytest.mark.parametrize("clients", [4, 8])
+def test_two_replicas_share_a_closed_loop_of_http_clients(traffic, clients):
+    """Closed-loop clients over real HTTP against two replicas whose
+    requests outlast the clients' start: both replicas serve, every answer
+    is a 200 carrying the direct path's numbers, every request was
+    admitted and none shed, and what the replicas served sums to what the
+    clients sent."""
+    import http.client
+
+    from router_test_support import build_slow
+
+    each = 2
+    reference = build_tiny(ladder=(8,)).predict_series(traffic)
+    router = ReplicaRouter(
+        [EngineReplica(build_slow(delay_s=0.2, ladder=(8,)), name=f"r{i}")
+         for i in range(2)],
+        config=RouterConfig(admission_depth=clients, max_wait_s=30.0))
+    service = PredictionService(router, None, backend="two-replicas")
+    server = PredictionServer(service, port=0).start()
+    try:
+        payload = json.dumps({"traffic": traffic.tolist()}).encode()
+        barrier = threading.Barrier(clients)
+        answers, errors = [], []
+
+        def client():
+            barrier.wait()
+            for _ in range(each):
+                try:
+                    conn = http.client.HTTPConnection(*server.address,
+                                                      timeout=60)
+                    conn.request(
+                        "POST", "/v1/predict", body=payload,
+                        headers={"Content-Type": "application/json"})
+                    resp = conn.getresponse()
+                    answers.append((resp.status, json.loads(resp.read())))
+                    conn.close()
+                except Exception as exc:
+                    errors.append(repr(exc))
+
+        threads = [threading.Thread(target=client) for _ in range(clients)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        requests = clients * each
+        assert not errors, errors
+        assert len(answers) == requests
+        assert {status for status, _ in answers} == {200}
+        want = service.predict({"traffic": traffic.tolist()})["predictions"]
+        np.testing.assert_array_equal(np.asarray(want, np.float32),
+                                      reference)
+        assert all(body["predictions"] == want for _, body in answers)
+        stats = router.router_stats()
+        served = [r["served_requests"] for r in stats["replicas"]]
+        assert len(served) == 2 and min(served) > 0
+        assert sum(served) == requests + 1          # + the reference call
+        adm = stats["admission"]
+        assert adm["depth"] == clients
+        assert adm["admitted"] >= requests and adm["rejected"] == 0
+    finally:
+        server.stop()
+
+
 def test_router_exposes_serving_protocol(pred8):
     router = ReplicaRouter.build(pred8, 2)
     try:

@@ -6,6 +6,7 @@ dense fused serving — with the K-cap overflow raising loudly and the serve
 plane compiling nothing new once warmed."""
 
 import dataclasses
+import functools
 
 import numpy as np
 import pytest
@@ -133,11 +134,74 @@ def test_sparse_ring_densify_parity_across_wrap_and_eviction():
     assert len(sparse_ring) == 0
 
 
-def test_sparse_ring_is_much_smaller_than_dense():
+@pytest.mark.parametrize("rows", [1024, 30 * 24 * 60],
+                         ids=["1024-rows", "a-month-of-minutes"])
+def test_sparse_ring_is_much_smaller_than_dense(rows):
     # the memory-ceiling claim at the 10k width, in ring-resident bytes
-    sparse = SparseSeriesRing(1024, 10240, 64)
-    dense_bytes = 2 * 1024 * 10240 * 4            # SeriesRing 2× buffer
+    # (the dense ring's are computed: a month of them is 3.5 GB)
+    sparse = SparseSeriesRing(rows, 10240, 64)
+    assert sparse.maxlen == rows
+    dense_bytes = 2 * rows * 10240 * 4            # SeriesRing 2× buffer
     assert dense_bytes / sparse.nbytes > 20
+
+
+# ---------------------------------------------------------------------------
+# the feed's bytes at the published widths (W=60, K=64), from the arrays
+# prepare_dataset hands the staged feed
+
+
+@functools.lru_cache(maxsize=None)
+def _published_width_bundle(capacity: int, rows: int = 150):
+    from deeprest_tpu.data.featurize import FeaturizedData
+
+    rng = np.random.default_rng(capacity)
+    traffic = np.zeros((rows, capacity), np.float32)
+    for row in traffic:                 # 4-31 hot call paths a bucket
+        hot = rng.choice(capacity, size=rng.integers(4, 32), replace=False)
+        row[hot] = rng.integers(1, 200, size=len(hot))
+    data = FeaturizedData(
+        traffic=traffic,
+        resources={"svc_cpu": rng.random(rows).astype(np.float32)},
+        invocations={"general": np.ones(rows, np.float32)},
+        space=CallPathSpace(config=FeaturizeConfig(
+            hash_features=True, capacity=capacity)).freeze())
+    return prepare_dataset(data, TrainConfig(
+        window_size=60, device_data="always", sparse_feed=True,
+        sparse_nnz_cap=64))
+
+
+_PUBLISHED_WIDTHS = [512, 2048, 10240]
+
+
+@pytest.mark.parametrize("capacity", _PUBLISHED_WIDTHS)
+def test_window_feed_bytes_dense_against_padded_coo(capacity):
+    """What one window costs to ship: [W, F] float32 against W padded-COO
+    rows of K int32 columns and K float32 counts, F / 2K times less."""
+    b = _published_width_bundle(capacity)
+    w = b.window_size
+    assert b.x_cols.dtype == np.int32 and b.x_vals.dtype == np.float32
+    assert b.x_cols.shape[1:] == b.x_vals.shape[1:] == (64,)
+    dense = b.x_base[:w].nbytes
+    sparse = b.x_cols[:w].nbytes + b.x_vals[:w].nbytes
+    assert dense == w * capacity * 4 and sparse == w * 64 * 8
+    assert dense // sparse == capacity // 128
+    if capacity == 10240:
+        assert dense // sparse >= 20
+
+
+@pytest.mark.parametrize("capacity", _PUBLISHED_WIDTHS)
+def test_staged_base_bytes_dense_against_padded_coo(capacity):
+    """What the staged feed ships once: the [T, F] base against the COO
+    rows with their lengths; the ratio does not depend on T."""
+    b = _published_width_bundle(capacity)
+    rows = len(b.x_base)
+    assert b.sparse_capacity == capacity and b.x_nnz.shape == (rows,)
+    dense = b.x_base.nbytes
+    sparse = b.x_cols.nbytes + b.x_vals.nbytes + b.x_nnz.nbytes
+    assert sparse == rows * (64 * 8 + 4)
+    assert dense * (64 * 8 + 4) == sparse * capacity * 4
+    if capacity == 10240:
+        assert dense / sparse >= 20
 
 
 # ---------------------------------------------------------------------------

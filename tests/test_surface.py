@@ -3,9 +3,8 @@ interpolation parity, the LRU + byte bounds, /v1/whatif interception,
 reload-eager invalidation under concurrent reads, and the CLI surface.
 
 Fast tier by design: a deterministic stub synthesizer over build_tiny's
-feature space keeps every test dispatch-cheap (the real corpus→space→
-synthesizer pipeline rides benchmarks/whatif_bench.py --quick, which is
-also tier-1).
+feature space keeps every test dispatch-cheap; the real corpus→space→
+synthesizer pipeline is under the sixteen concurrent readers below.
 """
 
 import threading
@@ -142,8 +141,8 @@ def test_parity_envelope_pinned(service):
     """The measured surface-vs-direct envelope on held-out jitter mixes:
     documented tolerance 0.5 (worst gap, relative to each capacity
     series' dynamic range) for the coarse 3-point grid over the tiny
-    random-init model — real trained models and denser grids measure
-    far lower (benchmarks/whatif_bench.json)."""
+    random-init model; a denser grid measures lower
+    (test_denser_grid_tightens_parity)."""
     r = service.whatif_surface(
         {"base_traffic": BASE, "factor": 1.5, "wait": True})
     parity = r["surface"]["parity"]
@@ -224,6 +223,72 @@ def test_whatif_route_interception(service):
     assert other["surface"]["hit"] is False
     s = service.surface.stats()
     assert s["hits"] >= 1 and s["misses"] >= 2
+
+
+def test_sixteen_concurrent_readers_of_a_warmed_surface_all_hit():
+    """The real pipeline (simulated corpus → call-path space → fitted
+    synthesizer) behind a warmed surface, read by sixteen threads at grid
+    vertices and at mixes inside the hull: every answer interpolated
+    (zero misses, counted by the readers and by the manager), the parity
+    envelope measured, no executable added; the same route without a
+    surface answers with no ``surface`` key."""
+    from deeprest_tpu.config import FeaturizeConfig
+    from deeprest_tpu.data.featurize import CallPathSpace, featurize_buckets
+    from deeprest_tpu.data.synthesize import TraceSynthesizer
+    from deeprest_tpu.workload import normal_scenario, simulate_corpus
+
+    scn = normal_scenario(0)
+    scn.calls_per_user = 0.3
+    corpus = simulate_corpus(scn, 40)
+    space = CallPathSpace(config=FeaturizeConfig(round_to=8))
+    featurize_buckets(corpus, space=space)
+    synth = TraceSynthesizer(space).fit(corpus)
+    pred = build_tiny(f=space.capacity, h=16, w=12)
+    grid = (0.5, 1.0, 2.0, 4.0)
+    eps = sorted(synth.endpoints)[:2]
+    base = [{eps[0]: 10, eps[1]: 30}] * 24
+    cached = PredictionService(pred, synth, surface=SurfaceConfig(
+        enabled=True, grid=grid, max_axes=2, jitter=4, warm_async=False))
+    direct = PredictionService(pred, synth)
+    try:
+        r = cached.whatif_surface(
+            {"base_traffic": base, "factor": 1.0, "wait": True})
+        assert r["surface"]["hit"] is True
+        ms = MixSpace(base, grid, max_axes=2)
+        scales = list(ms.vertices()) + [(0.7, 1.3), (1.5, 2.5),
+                                        (1.0, 3.0), (2.2, 1.1)]
+        pool = [ms.program_at(s) for s in scales]
+        out = direct.whatif_estimate({"expected_traffic": pool[0]})
+        assert "surface" not in out
+        warm = pred.jit_cache_size()
+        before = cached.surface.stats()
+        readers, each = 16, 25
+        missed = [0] * readers
+        barrier = threading.Barrier(readers)
+
+        def reader(tid):
+            barrier.wait()
+            for j in range(each):
+                got = cached.whatif_estimate(
+                    {"expected_traffic": pool[(tid * 5 + j) % len(pool)]})
+                missed[tid] += not got["surface"]["hit"]
+
+        threads = [threading.Thread(target=reader, args=(tid,))
+                   for tid in range(readers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        after = cached.surface.stats()
+        assert sum(missed) == 0
+        assert after["misses"] == before["misses"]
+        assert after["hits"] - before["hits"] == readers * each
+        assert after["builds"] == before["builds"] == 1
+        assert 0.0 <= after["parity_max_rel_err"] <= 0.5
+        assert pred.jit_cache_size() == warm
+    finally:
+        cached.close()
+        direct.close()
 
 
 def test_baseline_memoized_across_scaling_calls(service):

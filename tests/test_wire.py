@@ -7,9 +7,8 @@ receiver featurizes through ``trace_columns_from_dict`` +
 ``sparse_from_columns`` while the tailer path walks Span objects through
 ``extract_sparse`` — the two must produce identical arrays for identical
 traffic, and a StreamingTrainer fed either way must land on
-BIT-IDENTICAL params at the refresh boundary (the full-size twin of
-that assertion, plus the zero-post-warmup-compile gate, lives in
-benchmarks/wire_bench.py)."""
+BIT-IDENTICAL params at the refresh boundary
+(test_wire_vs_tailer_training_bit_parity)."""
 
 import json
 import socket
@@ -149,6 +148,41 @@ def test_wire_featurized_parity_end_to_end():
     assert stats["dropped"] == 0
     assert stats["spans"] == sum(1 for b in corpus
                                  for tr in b.traces for _ in tr.walk())
+
+
+def test_byte_stable_blobs_hit_the_bytes_to_columns_memo():
+    """``encode_bucket_payload`` is byte-stable, so a corpus sent again
+    finds every trace blob in the receiver's bytes→columns memo: over the
+    two passes more than half the lookups are hits, the second pass is all
+    hits, and a memoized row is bit for bit the row that was parsed."""
+    corpus = _corpus(10)
+    payloads = [encode_bucket_payload(b) for b in corpus]
+    traces = sum(len(b.traces) for b in corpus)
+    rx = SpanFirehoseReceiver("127.0.0.1", 0, space=_space(),
+                              queue_depth=64).start()
+    client = WireClient(rx.address, client_id="memo",
+                        pending_limit=64).connect()
+    try:
+        passes = []
+        for _ in range(2):
+            for payload in payloads:
+                client._send_batch(payload, flags=0)
+            passes.append((_drain(rx, len(payloads)),
+                           rx.memo_hits, rx.memo_misses))
+        client.flush()
+        stats = rx.stats()
+    finally:
+        client.close()
+        rx.close()
+    (cold, hits0, misses0), (warm, hits1, misses1) = passes
+    assert hits0 + misses0 == traces and misses0 > 0
+    assert misses1 == misses0 and hits1 - hits0 == traces
+    assert stats["memo_hit_rate"] == hits1 / (hits1 + misses1) > 0.5
+    assert stats["dropped"] == 0 and stats["batches"] == 2 * len(corpus)
+    for (row_c, metrics_c), (row_w, metrics_w) in zip(cold, warm):
+        np.testing.assert_array_equal(row_c[0], row_w[0])
+        np.testing.assert_array_equal(row_c[1], row_w[1])
+        assert metrics_c == metrics_w
 
 
 def test_wire_dense_mode_rejected():
