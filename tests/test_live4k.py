@@ -13,10 +13,12 @@ Adam), and three steps of the window's own compiled superstep are compared
 with `chipbench/reference/qrnn_ref.py`.  On the chip the benchmark's cell
 `tenk-train-live4k` makes the COMPACT side's comparison since ISSUE 39 (a
 table of 4,096 of 10,240, under the bound of 5,120) at the configuration's
-widths in bfloat16 (chipbench/limits/); the dense form of a sparse base is in
-no cell, so these cases and `test_the_model_axis_decides_before_the_rule`
-are its guard.  Here it is float32 at toy widths.  No number of this file is
-a device number.
+widths in bfloat16 (chipbench/limits/), and since ISSUE 44 the cell
+`tenk-train-alllive` makes the DENSE side's (every one of the 10,240 columns
+live: the `alllive` side here, with the control that cell's limits are held
+by); `test_the_model_axis_decides_before_the_rule` guards the dense form's
+other cause, which no cell runs.  Here it is float32 at toy widths.  No
+number of this file is a device number.
 """
 
 import json
@@ -55,8 +57,14 @@ SEED = 3_000_000_038           # as large as the driver's
 E, F, H, W, B, K = 10, 512, 8, 6, 4, 16
 DIMS = (E, F, H, len(QUANTILES))
 BOUND = F // 2
-SIDES = {"compact": 256, "dense": 257}
-PADDED = {"compact": 256, "dense": 512}
+# `alllive` (ISSUE 44, the cell `tenk-train-alllive`): every column of F is
+# hot and carries traffic, the plainest corpus that takes the dense form
+SIDES = {"compact": 256, "dense": 257, "alllive": F}
+PADDED = {"compact": 256, "dense": 512, "alllive": 512}
+FORM = {"compact": "compact", "dense": "dense", "alllive": "dense"}
+# distinct call paths a bucket: all 512 columns are hit in 400 buckets only
+# with more of them a bucket (8 to 15, under K)
+NNZ = {"compact": (3, 12), "dense": (3, 12), "alllive": (8, 16)}
 KINDS = ("live", "padded", "bound", "contracted", "total")
 
 # Program and reference both compute in float32 here, the reference at
@@ -84,11 +92,10 @@ def _staged_with_tags(trainer, bundle):
     return staged[0], span.tags
 
 
-@pytest.fixture(scope="module", params=sorted(SIDES))
-def side(request):
+def _three_steps(name):
     """The `train` runner's phases 1 to 3 and 6 at the small size, with the
     runner's own functions for the rows, the batches and the numbers."""
-    hot = SIDES[request.param]
+    hot = SIDES[name]
     mcfg = ModelConfig(feature_dim=F, num_metrics=E, hidden_size=H,
                        quantiles=QUANTILES, dropout_rate=0.5,
                        compute_dtype="float32")
@@ -97,8 +104,8 @@ def side(request):
                        sparse_nnz_cap=K, steps_per_superstep=8,
                        log_every_steps=0)
     raw = corpus.generate(
-        {"buckets": 400, "hot_paths": hot, "nnz_lo": 3, "nnz_hi": 12,
-         "day": 100, "resources": RESOURCES}, SEED,
+        {"buckets": 400, "hot_paths": hot, "nnz_lo": NNZ[name][0],
+         "nnz_hi": NNZ[name][1], "day": 100, "resources": RESOURCES}, SEED,
         {"feature_dim": F, "num_metrics": E})
     live = int(raw["traffic"].any(axis=0).sum())
     space = CallPathSpace(config=FeaturizeConfig(
@@ -147,10 +154,19 @@ def side(request):
     reference = ref.train_three_steps(
         ref.init_params(key, *DIMS), runner.check_batches(raw, tcfg, starts),
         tcfg.seed, QUANTILES, 0.5, "f32")
-    return {"form": request.param, "live": live, "base": staged[0],
-            "tags": tags, "gauge": gauge, "line": line,
+    return {"name": name, "form": FORM[name], "live": live,
+            "base": staged[0], "tags": tags, "gauge": gauge, "line": line,
             "gaps": runner.compare(program, reference),
             "numbers": (program, reference)}
+
+
+_SOUND = {}         # what `side` made, by name, for the case that needs one
+
+
+@pytest.fixture(scope="module", params=sorted(SIDES))
+def side(request):
+    _SOUND[request.param] = _three_steps(request.param)
+    return _SOUND[request.param]
 
 
 @pytest.mark.parametrize("number", sorted(TOLERANCE))
@@ -162,7 +178,7 @@ def test_superstep_against_the_reference_on_either_side(side, number):
 def test_the_corpus_was_staged_in_the_form_its_side_names(side):
     base = side["base"]
     assert isinstance(base, SparseBase) and base.capacity == F
-    assert side["live"] == SIDES[side["form"]]      # every hot path was hit
+    assert side["live"] == SIDES[side["name"]]      # every hot path was hit
     if side["form"] == "compact":
         assert base.width == BOUND == len(np.asarray(base.live))
     else:
@@ -171,7 +187,7 @@ def test_the_corpus_was_staged_in_the_form_its_side_names(side):
 
 def test_the_stage_span_says_what_the_rule_weighed(side):
     tags = side["tags"]
-    padded = PADDED[side["form"]]
+    padded = PADDED[side["name"]]
     assert {k: tags[k] for k in ("form", "live", "padded", "bound")} == {
         "form": side["form"], "live": side["live"], "padded": padded,
         "bound": BOUND}
@@ -182,7 +198,7 @@ def test_the_stage_span_says_what_the_rule_weighed(side):
 def test_the_gauge_has_five_kinds(side):
     compact = side["form"] == "compact"
     assert side["gauge"] == {
-        "live": side["live"], "padded": PADDED[side["form"]],
+        "live": side["live"], "padded": PADDED[side["name"]],
         "bound": BOUND, "contracted": BOUND if compact else F, "total": F}
 
 
@@ -191,10 +207,33 @@ def test_the_set_up_line_carries_the_form(side):
     want = {"compact": f"sparse feed compact ({live} live call paths of 512, "
                        "padded to 256, bound 256, 256 contracted)",
             "dense": f"sparse feed dense ({live} live call paths of 512, "
-                     "padded to 512, bound 256, 512 contracted)"}
+                     f"padded to {PADDED[side['name']]}, bound 256, 512 "
+                     "contracted)"}
     assert line.startswith("set-up: ") and "\n" not in line
     assert want[side["form"]] in line
     assert line.index("stage ") < line.index("sparse feed ")
+
+
+def test_the_dense_forms_dropped_columns_control_is_seen(monkeypatch):
+    """ISSUE 44's control of `tenk-train-alllive` at this size: the feed
+    thresholds before the program's guard (the counts of the less-hit half
+    of the columns never reach the staged rows) and the form stays dense,
+    which `control_on_chip_live4k.py`'s table of the most-hit half cannot
+    show where half of F pads over the bound.  The dropped columns' rows of
+    the w_ih leaves never move: the leaves' change reads a thousand times
+    the sound gap, at a w_ih leaf."""
+    import deeprest_tpu.train.trainer as T
+    from chipbench.tests import control_on_chip_alllive
+
+    side = _SOUND.get("alllive") or _three_steps("alllive")
+    monkeypatch.setattr(T, "stage_sparse_base", T.stage_sparse_base)
+    control_on_chip_alllive.most_hit_half_only_dense()
+    dropped = _three_steps("alllive")
+    assert dropped["base"].live is None and dropped["gauge"]["live"] == F
+    assert dropped["gaps"]["delta_norm_gap"] > 1000 * side["gaps"][
+        "delta_norm_gap"], (dropped["gaps"], side["gaps"])
+    assert dropped["gaps"]["delta_norm_gap_leaf"] in ("gru_fwd_w_ih",
+                                                      "gru_bwd_w_ih")
 
 
 def test_the_model_axis_decides_before_the_rule():
@@ -434,8 +473,50 @@ def test_the_cell_and_its_metric_are_in_the_contract():
                                      "tenk-retrain-drift", "tenk-train-live4k"]
     assert (dead["unit"], dead["better"], dead["moves"], dead["source"]) == (
         "%", "lower", "train_steps_per_s", "program_counter")
-    assert sum(c["chips"] == 4 for c in bench["workloads"]) == 1
+    assert sum(c["chips"] == 4 for c in bench["workloads"]) == 2
     limits = _load("chipbench", "limits", "tenk-train-live4k.json")
+    assert set(limits["limits"]) == {"loss_rel_gap", "grad_norm_gap",
+                                     "delta_norm_gap"}
+
+
+def test_the_all_live_cell_is_endpoints_10k_on_a_mix_with_no_dead_column():
+    """`tenk-train-alllive` (ISSUE 44): the accepted configuration
+    `endpoints-10k` x a mix that is `week-sparse`'s in everything but the
+    live set, through the `train` runner and the `corpus` generator as they
+    stand; the rule sends it to the dense form; the cell is in the lists of
+    the metrics it reports, and the benchmark holds eight cells, two of them
+    on four chips."""
+    bench = _load("BENCHMARK.json")
+    cell = {c["name"]: c for c in bench["workloads"]}["tenk-train-alllive"]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "endpoints-10k", "week-alllive", 1)
+    assert len(cell["why"]) <= 200
+    mix = _load("chipbench", "traffic", "week-alllive.json")
+    base = _load("chipbench", "traffic", "week-sparse.json")
+    assert (mix["runner"], mix["generator"]) == ("train", "corpus")
+    model = _load("chipbench", "configs", "endpoints-10k.json")["model"]
+    f = model["feature_dim"]
+    assert mix["params"] == dict(base["params"], hot_paths=f) and f == 10240
+    padded, bound = compact_rule(f, f)
+    assert (padded, bound) == (16384, 5120) and padded > bound
+    # a few columns short of F still pads over the bound: never a table
+    assert compact_rule(f - 64, f)[0] > bound
+    assert compact_table(np.arange(f - 64, dtype=np.int32), f) is None
+    metrics = {m["name"]: m for m in bench["end_to_end"] + bench["per_layer"]}
+    from test_mesh_dp4 import LISTED_IN_EVERY_TRAIN_CELL
+
+    listed = ("train_steps_per_s",
+              "hbm_peak_gb") + LISTED_IN_EVERY_TRAIN_CELL
+    for name in listed:
+        assert metrics[name]["workloads"][-2:] == [
+            "tenk-train-live4k-dp4", "tenk-train-alllive"], name
+    for name, m in metrics.items():
+        if name not in listed:
+            assert "tenk-train-alllive" not in m.get("workloads", ()), name
+    assert len(bench["workloads"]) == 8
+    assert sum(c["chips"] == 4 for c in bench["workloads"]) == 2
+    limits = _load("chipbench", "limits", "tenk-train-alllive.json")
+    assert limits["cell"] == "tenk-train-alllive"
     assert set(limits["limits"]) == {"loss_rel_gap", "grad_norm_gap",
                                      "delta_norm_gap"}
 

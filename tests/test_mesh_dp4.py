@@ -2,7 +2,9 @@
 mesh ``data=4``, held on the CPU's virtual devices to the plain one-device
 reference on the GLOBAL batch and to the same program at ``data=1``; what
 the program records of its collectives; the benchmark's three readers of
-them on a recorded slice of a four-chip trace; and the cell's files.
+them on a recorded slice of a four-chip trace; and the cell's files.  Since
+ISSUE 44 (`endpoints-10k-live4k-dp4`) the comparisons with the reference also
+run at the widest table the compact form's rule admits (`HOT["widest"]`).
 
 On the chips the benchmark's cell `tenk-train-dp4` makes the comparison at
 the configuration's own widths in bfloat16 (chipbench/limits/); here it is
@@ -59,9 +61,16 @@ TOLERANCE = {"loss_rel_gap": 2e-6, "grad_norm_gap": 1e-5,
              "delta_norm_gap": 2e-6}
 
 
-def _corpus():
+# ISSUE 44 (`endpoints-10k-live4k-dp4`): the same program at the widest table
+# the rule of the compact form admits, F // 2 columns, where the comparisons
+# above run at a table of 128 (16 live paths padded to the least width): as
+# `tenk-train-live4k-dp4`'s 4,096 of 10,240 is to `tenk-train-dp4`'s 256.
+HOT = {"narrow": 16, "widest": F // 2}
+
+
+def _corpus(hot=HOT["narrow"]):
     model = {"feature_dim": F, "num_metrics": E}
-    raw = corpus.generate({"buckets": 400, "hot_paths": 16, "nnz_lo": 2,
+    raw = corpus.generate({"buckets": 400, "hot_paths": hot, "nnz_lo": 2,
                            "nnz_hi": 6, "day": 100, "resources": RESOURCES},
                           SEED, model)
     space = CallPathSpace(config=FeaturizeConfig(
@@ -118,10 +127,9 @@ def _three_steps(data_axis: int, raw, data, leave_out_a_chip=False):
     return program, trainer, state, bundle, staged, (tcfg, starts, key)
 
 
-@pytest.fixture(scope="module")
-def runs():
-    raw, data = _corpus()
-    out = {d: _three_steps(d, raw, data) for d in (4, 1)}
+def _runs(hot, meshes):
+    raw, data = _corpus(hot)
+    out = {d: _three_steps(d, raw, data) for d in meshes}
     tcfg, starts, key = out[4][5]
     out["reference"] = ref.train_three_steps(
         ref.init_params(key, E, F, H, len(QUANTILES)),
@@ -131,13 +139,79 @@ def runs():
     return out
 
 
+@pytest.fixture(scope="module")
+def runs():
+    return _runs(HOT["narrow"], (4, 1))
+
+
+@pytest.fixture(scope="module")
+def widest():
+    """The four-device compile at the rule's bound, once for its cases."""
+    return _runs(HOT["widest"], (4,))
+
+
+def _runs_at(request, table):
+    """The module's runs at a table of ``HOT``: `runs` or `widest`."""
+    return request.getfixturevalue({"narrow": "runs"}.get(table, table))
+
+
 # -- (a) data=4 against the plain reference on the global batch -------------
 
 
 @pytest.mark.parametrize("number", sorted(TOLERANCE))
-def test_data_parallel_superstep_against_the_reference(runs, number):
+@pytest.mark.parametrize("table", sorted(HOT))
+def test_data_parallel_superstep_against_the_reference(request, table, number):
+    runs = _runs_at(request, table)
     gaps = runner.compare(runs[4][0], runs["reference"])
     assert gaps[number] <= TOLERANCE[number], (gaps, runs[4][0])
+
+
+def test_the_widest_table_is_the_rules_bound(widest):
+    """What `widest` staged: the compact form at a table of F // 2 columns,
+    every one of them live or all but a few (the live set pads to it)."""
+    base = widest[4][4][0]
+    assert base.live is not None and base.width == F // 2 == len(
+        np.asarray(base.live))
+    live = int(widest["corpus"][0]["traffic"].any(axis=0).sum())
+    assert F // 4 < live <= F // 2
+
+
+def test_the_widest_table_under_data4_is_recorded_as_it_ran(widest):
+    """What ISSUE 44 reads on the chips for `tenk-train-live4k-dp4`, at this
+    size: the `train.stage` span says the compact form at the bound, the
+    epoch's span says the mesh, the collectives' gauge equals the dispatched
+    program's own bytes and holds the w_ih gradients at the TABLE's rows
+    (sixteen times `runs`' table here), and the two gauges of the compact
+    feed read the table's width on every chip's behalf."""
+    from test_obs_layers import _recorded
+
+    _, trainer, state, bundle, _, rest = widest[4]
+    staged = []
+    spans = _recorded(lambda: staged.append(trainer.stage_dataset(bundle)))
+    (stage,) = [s for s in spans if s.name == "train.stage"]
+    assert {k: stage.tags[k] for k in ("form", "padded", "bound", "width")
+            } == {"form": "compact", "padded": F // 2, "bound": F // 2,
+                  "width": F // 2}
+    gauge = REGISTRY.get("deeprest_train_collective_bytes")
+    gauge._series.clear()
+    trainer._program_published = False
+    out = []
+    spans = _recorded(lambda: out.append(trainer.train_epoch(
+        state, bundle, np.random.default_rng(0), staged=staged[0])))
+    state = out[0][0]
+    widest[4] = (widest[4][0], trainer, state, bundle, staged[0], rest)
+    assert [s.tags["mesh"] for s in spans if s.name == "train.epoch"] == [
+        "4x1x1"]
+    read = {k[0]: v for k, v in gauge.series().items()}
+    assert read == _gradient_bytes(trainer._dispatched_program_text(state))
+    width = F // 2
+    rows = 4 * 2 * E * width * 3 * H
+    assert rows < read["all-reduce"] < rows + 4 * E * H * F
+    cols = REGISTRY.get("deeprest_train_projection_columns")
+    adam = REGISTRY.get("deeprest_train_optimizer_rows")
+    assert cols.value(kind="contracted") == width == adam.value(
+        kind="updated")
+    assert adam.value(kind="stale") == 0
 
 
 # -- (b) data=4 against data=1 ----------------------------------------------
@@ -152,16 +226,21 @@ def test_data_parallel_superstep_against_one_device(runs, number):
     assert gaps[number] <= TOLERANCE[number], gaps
 
 
-def test_a_chips_rows_left_out_of_the_mean_is_seen(runs):
+@pytest.mark.parametrize("table", sorted(HOT))
+def test_a_chips_rows_left_out_of_the_mean_is_seen(request, table):
+    runs = _runs_at(request, table)
     raw, data = runs["corpus"]
     short = _three_steps(4, raw, data, leave_out_a_chip=True)[0]
     gaps = runner.compare(short, runs["reference"])
     assert gaps["loss_rel_gap"] > 1000 * TOLERANCE["loss_rel_gap"], gaps
 
 
-def test_state_is_the_same_on_every_chip(runs):
+@pytest.mark.parametrize("table", sorted(HOT))
+def test_state_is_the_same_on_every_chip(request, table):
+    runs = _runs_at(request, table)
     state = runs[4][2]
-    for leaf in jax.tree.leaves(state.params):
+    for leaf in jax.tree.leaves(state.params) + jax.tree.leaves(
+            state.opt_state):
         shards = [np.asarray(s.data) for s in leaf.addressable_shards]
         assert len(shards) == 4
         assert all(np.array_equal(shards[0], s) for s in shards[1:])
@@ -192,6 +271,8 @@ def _gradient_bytes(hlo_text: str) -> dict:
 
 def test_collective_bytes_gauge_and_the_one_device_gauges(runs):
     _, trainer, state, bundle, staged, _ = runs[4]
+    # the gauges are the process's: another fixture may have staged since
+    staged = trainer.stage_dataset(bundle)
     gauge = REGISTRY.get("deeprest_train_collective_bytes")
     gauge._series.clear()
     was = obs.RECORDER.enabled
@@ -400,28 +481,61 @@ def _load(*path):
         return json.load(fh)
 
 
-def test_the_cells_files_exist_and_say_what_the_issue_says():
+# the two cells across chips: `tenk-train-dp4` (ISSUE 31) and, at the widest
+# table, `tenk-train-live4k-dp4` (ISSUE 44).  `mix_of`: the one-chip mix whose
+# parameters the cell's mix holds letter for letter.
+MESH_CELLS = {
+    "tenk-train-dp4": {
+        "config": "endpoints-10k-dp4", "mix_of": "week-sparse",
+        "reduced": ["chips", "corpus_days", "mesh"], "hot_paths": 256,
+        "reduced_a_step": 23_839_368, "words": ("the same float32 state",)},
+    "tenk-train-live4k-dp4": {
+        "config": "endpoints-10k-live4k-dp4", "mix_of": "week-live4k",
+        "reduced": ["chips", "mesh", "corpus_days"], "hot_paths": 4096,
+        "reduced_a_step": 259_768_968,
+        "words": ("the same float32 state", "mean gradient over all 128",
+                  "no column dropped", "Adam on every row of every leaf")},
+}
+LISTED_IN_EVERY_TRAIN_CELL = (
+    "proj_columns_pct.train", "adam_rows_pct.train",
+    "proj_dead_columns_pct.train", "init_state_s.train", "compile_s.train",
+    "compilations.train", "init_state_peak_gb.train", "steady_hbm_gb.train",
+    "gru_kernel_vmem_pct.train", "dropout_draws_per_step.train",
+    "time_reversals_per_step.train", "kernel_edge_passes_per_step.train")
+
+
+@pytest.mark.parametrize("name", sorted(MESH_CELLS))
+def test_the_cells_files_exist_and_say_what_the_issue_says(name):
+    want = MESH_CELLS[name]
     bench = _load("BENCHMARK.json")
-    cell = {c["name"]: c for c in bench["workloads"]}["tenk-train-dp4"]
-    assert cell["chips"] == 4 and cell["config"] == "endpoints-10k-dp4"
+    cell = {c["name"]: c for c in bench["workloads"]}[name]
+    assert cell["chips"] == 4 and cell["config"] == want["config"]
+    assert len(cell["why"]) <= 200
     entry = {c["name"]: c for c in bench["configs"]}[cell["config"]]
     cfg = _load(entry["file"])
     one = _load("chipbench", "configs", "endpoints-10k.json")
     assert cfg["model"] == one["model"]              # no width cut
     assert cfg["train"] == {**one["train"], "batch_size": 128}
     assert cfg["mesh"] == {"data": 4, "expert": 1, "model": 1}
-    assert cfg["reduced"] == entry["reduced"] == ["chips", "corpus_days",
-                                                  "mesh"]
-    # two deployments of one brief line: the driver takes a configuration
-    # with another's source AND reduced keys as no new configuration
-    first = {c["name"]: c for c in bench["configs"]}["endpoints-10k"]
-    assert entry["source"] != first["source"] and len(entry["source"]) <= 200
-    assert set(entry["reduced"]) != set(first["reduced"])
-    assert cfg["all_reduce_bytes_per_step"] > 0
+    assert cfg["runners"] == ["train_mesh"] and cfg["chips"] == 4
+    assert cfg["reduced"] == entry["reduced"] == want["reduced"]
+    assert entry["source"] == cfg["source"] and len(entry["source"]) <= 200
+    # several deployments of one brief line: the driver takes a
+    # configuration with another's source AND reduced keys as no new one
+    for other in bench["configs"]:
+        if other["name"] != entry["name"]:
+            assert (other["source"], set(other["reduced"])) != (
+                entry["source"], set(entry["reduced"])), other["name"]
+            assert other["file"] != entry["file"]
+    assert cfg["all_reduce_bytes_per_step"] == want["reduced_a_step"]
+    for words in want["words"]:
+        assert words in cfg["guarantee"], words
     mix = _load("chipbench", "traffic", cell["traffic"] + ".json")
-    assert mix["runner"] == "train_mesh"
+    assert (mix["runner"], mix["generator"]) == ("train_mesh", "corpus")
     assert mix["params"] == _load("chipbench", "traffic",
-                                  "week-sparse.json")["params"]
+                                  want["mix_of"] + ".json")["params"]
+    assert mix["params"]["hot_paths"] == want["hot_paths"]
+    assert mix["params"]["buckets"] == 10080
     limits = _load("chipbench", "limits", cell["name"] + ".json")
     assert set(limits["limits"]) == {"loss_rel_gap", "grad_norm_gap",
                                      "delta_norm_gap"}
@@ -431,19 +545,41 @@ def test_the_cells_files_exist_and_say_what_the_issue_says():
         assert os.path.exists(os.path.join(
             REPO, "chipbench", "readers", module + ".py"))
         if m["layer"] == "mesh":
-            assert m["workloads"] == [cell["name"]]
+            # ISSUE 44's cell appended; what was there keeps its place
+            assert m["workloads"] == sorted(MESH_CELLS)
             assert spec["runners"] == ["train_mesh"]
             assert callable(getattr(collectives, func))
     for name in ("train_steps_per_s", "hbm_peak_gb"):
         metric = {m["name"]: m for m in bench["end_to_end"]}[name]
         assert cell["name"] in metric["workloads"]
-    # the accepted per-layer metrics: the two gauges of the compact feed
-    # name the cell; the seven that apply by runner name keep no list (the
-    # runner is read as the `train` run it is)
+    # the accepted per-layer metrics that list their cells name this one;
+    # the seven that apply by runner name keep no list (the runner is read
+    # as the `train` run it is)
     by_name = {m["name"]: m for m in bench["per_layer"]}
-    for name in ("proj_columns_pct.train", "adam_rows_pct.train"):
-        assert cell["name"] in by_name[name]["workloads"]  # later cells follow
+    for name in LISTED_IN_EVERY_TRAIN_CELL:
+        assert cell["name"] in by_name[name]["workloads"], name
     unlisted = [m["name"] for m in bench["per_layer"] if "workloads" not in m]
     assert len(unlisted) == 7
     assert all(_load("chipbench", "layer_metrics", name + ".json")["runners"]
                == ["train"] for name in unlisted)
+    # the quota: of eight cells two may ask for four chips, and these do
+    assert len(bench["workloads"]) == 8 and len(bench["configs"]) == 7
+    assert sorted(c["name"] for c in bench["workloads"]
+                  if c["chips"] == 4) == sorted(MESH_CELLS)
+
+
+def test_the_wide_cell_is_the_rules_widest_table_under_the_mesh():
+    """`tenk-train-live4k-dp4`'s live set pads to a table of 4,096, under the
+    bound the rule reads while the mesh's `model` axis is 1 (F // 2), and one
+    path more would not: the compact form at its widest, on every chip."""
+    from deeprest_tpu.ops.densify import compact_rule
+
+    cfg = _load("chipbench", "configs", "endpoints-10k-live4k-dp4.json")
+    mix = _load("chipbench", "traffic", "week-live4k-dp4.json")
+    f, hot = cfg["model"]["feature_dim"], mix["params"]["hot_paths"]
+    assert cfg["mesh"]["model"] == 1
+    assert compact_rule(hot, f) == (4096, f // 2)
+    assert compact_rule(hot + 1, f)[0] > f // 2
+    assert set(cfg["assumed"]) == set(_load(
+        "chipbench", "configs", "endpoints-10k-dp4.json")["assumed"]) | {
+            "live_paths"}
