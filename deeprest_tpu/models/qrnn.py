@@ -54,6 +54,7 @@ import jax.numpy as jnp
 from deeprest_tpu.config import ModelConfig
 from deeprest_tpu.ops import scopes
 from deeprest_tpu.ops.gru import GRUParams, bidirectional_gru, gru
+from deeprest_tpu.parallel.sharding import carried_rows_split, pin_folded_rows
 
 MASK_PARAM_NAMES = ("mask_w1", "mask_b1", "mask_w2", "mask_b2")
 # Layer-0 input weights the soft mask folds into ((x ⊙ m) @ W ≡ x @ (m ⊙ W));
@@ -294,7 +295,15 @@ class QuantileGRU(nn.Module):
             elif live_w_ih is None:
                 w_ih = take_columns(p.w_ih, live_cols, self.mesh)
             else:
+                # The caller's carried rows, which a mesh with a `data`
+                # axis splits over it (parallel/sharding.py): a chip folds
+                # and casts its own, and what the projection contracts is
+                # the gathered weight in the compute dtype, the very array
+                # the fold and `cast` below make on one chip.
                 w_ih = live_w_ih[name]
+                if carried_rows_split(self.mesh, w_ih.shape[1]) > 1:
+                    return p._replace(w_ih=pin_folded_rows(
+                        self.mesh, _fold(mask, w_ih).astype(compute_dtype)))
             return p._replace(w_ih=_fold(mask, w_ih))
 
         def cast(p: GRUParams) -> GRUParams:

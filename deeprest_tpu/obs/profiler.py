@@ -18,13 +18,19 @@ things the program owns into one table of layers:
   which XLA takes from one of the operations it fused; what else it holds
   (:func:`fused_scopes`) is listed beside the row, because XLA does fuse
   Adam's update of a weight into the matmul that makes its gradient.
-- **Collectives.**  The partitioner puts the all-reduces of a mesh in and
+- **Collectives.**  The partitioner puts the collectives of a mesh in and
   gives them no scope of their own (they carry the ``op_name`` of whatever
-  they reduce), so they are found by instruction kind: one row
-  :data:`COLLECTIVE`.  Like every row it holds self time, and the device
+  they reduce or gather), so they are found by instruction kind: one row
+  :data:`COLLECTIVE`.  XLA:TPU wraps some of them in a ``fusion`` of
+  ``kind=kCustom`` named for no collective (a reduce-scatter is a
+  ``fusion.N`` that calls ``%all-reduce-scatter``; an asynchronous one is
+  a chain of fusions, ``async-collective-start``, ``async-collective-done``
+  and between them the fusions of the work it hides behind, each holding
+  a piece with one ``chain_id``): such a wrapper is found by what its
+  computation holds.  Like every row it holds self time, and the device
   runs one operation at a time, so it is the part of the collectives'
-  time in which the chip ran nothing else (what an asynchronous pair
-  hides between its ``-start`` and its ``-done`` lies in the rows of what
+  time in which the chip ran nothing else (what an asynchronous pair, or
+  chain, hides between its start and its done lies in the rows of what
   ran then).  :func:`collective_bytes` reads what they move from the
   program's text.
 - **The program's spans.**  An enabled span (obs/spans.py) enters a
@@ -136,6 +142,13 @@ _NO_EVENT = frozenset({"parameter", "constant", "get-tuple-element", "tuple",
                        "bitcast"})
 _COLLECTIVE = re.compile(
     rf"^({'|'.join(COLLECTIVE_KINDS)})(-start|-done)?(?:\.\d+)?$")
+# XLA:TPU's wrappers round a collective, by the name of the computation a
+# `kind=kCustom` fusion calls or of the fusion itself
+_WRAPPED = re.compile(
+    r"^(all-reduce-scatter|async-collective)(-start|-done)?(?:\.\d+)?$")
+ASYNC_COLLECTIVE = "async-collective"
+_KCUSTOM = "kind=kCustom"
+_CHAIN = re.compile(r'\bchannel_id=(\d+).*\bchain_id="(\d+)"')
 _CALLED = re.compile(
     r"\b(?:body|condition|to_apply|calls|true_computation|false_computation)"
     r"=%?([\w.\-]+)|\bbranch_computations=\{([^}]*)\}")
@@ -149,11 +162,21 @@ _SPACES = {"1": "vmem"}
 
 
 def collective_kind(name: str) -> tuple[str, str] | None:
-    """``all-reduce-start.3`` (an opcode, or an instruction's name as the
-    trace gives it) -> ``("all-reduce", "-start")``; None for what is no
-    collective."""
-    m = _COLLECTIVE.match(name.lstrip("%"))
-    return (m[1], m[2] or "") if m else None
+    """``all-reduce-start.3`` (an opcode, an instruction's name as the
+    trace gives it, or the name of the computation a ``kCustom`` fusion
+    calls) -> ``("all-reduce", "-start")``; XLA:TPU's
+    ``all-reduce-scatter.1`` -> ``("reduce-scatter", "")`` and its
+    ``async-collective-done`` -> ``(ASYNC_COLLECTIVE, "-done")``, whose
+    kind proper only the text says; None for what is no collective."""
+    name = name.lstrip("%")
+    m = _COLLECTIVE.match(name)
+    if m:
+        return m[1], m[2] or ""
+    m = _WRAPPED.match(name)
+    if not m:
+        return None
+    return ("reduce-scatter" if m[1] == "all-reduce-scatter"
+            else ASYNC_COLLECTIVE), m[2] or ""
 
 
 def _scope_of(op_name: str, names) -> tuple[str, str]:
@@ -187,10 +210,12 @@ def _instruction_lines(hlo_text: str):
 
 
 def _parse_hlo(hlo_text: str):
-    """{computation: [(instruction, opcode, op_name, called computation)]}
-    plus the set of computations that run inside another instruction (a
-    fusion's body, a reduce's combiner)."""
-    computations, inner = {}, set()
+    """{computation: [(instruction, opcode, op_name, called computation)]},
+    the set of computations that run inside another instruction (a
+    fusion's body, a reduce's combiner), and the ``kind=kCustom`` fusion
+    instructions whose computation holds a collective (XLA:TPU's wrappers:
+    a reduce-scatter, the start and the done of an asynchronous chain)."""
+    computations, inner, custom = {}, set(), {}
     for comp, lines in _instruction_lines(hlo_text)[0].items():
         current = computations[comp] = []
         for m, line in lines:
@@ -199,10 +224,15 @@ def _parse_hlo(hlo_text: str):
             inner.update(_TO_APPLY.findall(line))
             if calls:
                 inner.add(calls[1])
+                if _KCUSTOM in line:
+                    custom[m["name"]] = calls[1]
             current.append((m["name"], m["opcode"],
                             op_name[1] if op_name else "",
                             calls[1] if calls else None))
-    return computations, inner
+    wrappers = {name for name, comp in custom.items()
+                if any(collective_kind(opcode)
+                       for _, opcode, _, _ in computations.get(comp, ()))}
+    return computations, inner, wrappers
 
 
 def module_name(hlo_text: str) -> str | None:
@@ -216,12 +246,15 @@ def scope_table(hlo_text: str, names) -> dict[str, tuple[str, str]]:
     """``{instruction: (scope, pass)}`` for every instruction of an
     optimized HLO module's text (``jit(f).lower(..).compile().as_text()``)
     that can be an event on the device: a fusion under its own label, a
-    collective (by its kind, whatever it reduces) under ``(COLLECTIVE,
-    "-")``, instructions under none of the scope and kernel ``names``
-    under ``(OTHER, "-")``."""
-    computations, inner = _parse_hlo(hlo_text)
+    collective (by its kind, whatever it reduces, and XLA:TPU's wrapper
+    fusions of one by what they hold) under ``(COLLECTIVE, "-")``,
+    instructions under none of the scope and kernel ``names`` under
+    ``(OTHER, "-")``.  A fusion that holds a piece of an asynchronous
+    chain beside the work that hides it keeps that work's label."""
+    computations, inner, wrappers = _parse_hlo(hlo_text)
     names = frozenset(names)
-    return {name: ((COLLECTIVE, "-") if collective_kind(opcode)
+    return {name: ((COLLECTIVE, "-")
+                   if collective_kind(opcode) or name in wrappers
                    else _scope_of(op_name, names))
             for comp, instructions in computations.items() if comp not in inner
             for name, opcode, op_name, _ in instructions
@@ -232,7 +265,7 @@ def fused_scopes(hlo_text: str, names) -> dict[str, tuple[str, ...]]:
     """``{fusion instruction: the other ones of ``names`` inside it}``, for
     the fusions that hold operations of more scopes than their label
     says."""
-    computations, inner = _parse_hlo(hlo_text)
+    computations, inner, _ = _parse_hlo(hlo_text)
     names = frozenset(names)
 
     def inside(comp: str) -> set[str]:
@@ -282,36 +315,74 @@ def _result_type(m, line: str) -> str:
     return head[:head.index(f" {m['opcode']}(")]
 
 
-def collective_bytes(hlo_text: str) -> dict[str, int]:
-    """``{kind: bytes}`` that one pass through an optimized HLO module
-    gets from its collectives: the result of every instruction of a
+def collective_bytes(hlo_text: str, steps: int = 1) -> dict[str, int]:
+    """``{kind: bytes}`` that one step of an optimized HLO module gets
+    from its collectives: the result of every instruction of a
     :data:`COLLECTIVE_KINDS` kind (of an asynchronous pair the ``-done``,
     whose result is the collective's; its ``-start`` not) that the entry
-    computation reaches, the body of a loop taken once (for a superstep:
-    one train step) and of a conditional's branches the one that moves
-    most.  Kinds the program does not use are left out; a program for one
-    device gives ``{}``."""
+    computation reaches.  A collective inside a ``fusion`` is its
+    fusion's: XLA:TPU's ``%all-reduce-scatter`` (an all-reduce and a
+    chip's slice of it) counts as the reduce-scatter it is, by the slice,
+    and the pieces of an asynchronous chain (one ``chain_id`` on one
+    channel, in ``async-collective-start``, the fusions between and
+    ``async-collective-done``) count once.  The body of a loop is taken
+    once (for a superstep: one train step) and of a conditional's branches
+    the one that moves most; what stands outside every loop runs once a
+    dispatch, and is divided by ``steps``, the loop's trips.  Kinds the
+    program does not use are left out; a program for one device gives
+    ``{}``.  On the links an all-reduce of a result of S bytes over n
+    chips moves 2 S (n - 1) / n a chip, an all-gather of a result of S
+    moves S (n - 1) / n, a reduce-scatter of a result of S moves
+    S (n - 1)."""
     bodies, entry = _instruction_lines(hlo_text)
+    chains = set()
 
-    def cost(comp: str) -> collections.Counter:
+    def held(comp: str) -> collections.Counter:
+        """What the computation of a fusion holds."""
         total = collections.Counter()
+        for m, line in bodies.get(comp, ()):
+            kind = collective_kind(m["opcode"])
+            chain = _CHAIN.search(line)
+            if (not kind or kind[1] == "-start"
+                    or chain and chain.groups() in chains):
+                continue
+            if chain:
+                chains.add(chain.groups())
+            total[kind[0]] += _array_bytes(_result_type(m, line))
+        return total
+
+    def cost(comp: str):
+        """(once a dispatch, once a trip of a loop) of a computation."""
+        once, looped = collections.Counter(), collections.Counter()
         for m, line in bodies.get(comp, ()):
             opcode = m["opcode"]
             kind = collective_kind(opcode)
             if kind and kind[1] != "-start":
-                total[kind[0]] += _array_bytes(_result_type(m, line))
+                once[kind[0]] += _array_bytes(_result_type(m, line))
+            called = [c.lstrip("%")
+                      for one, many in _CALLED.findall(line)
+                      for c in ([one] if one else re.split(r",\s*", many))]
             if opcode == "fusion":
+                for c in called:
+                    if collective_kind(c) == ("reduce-scatter", ""):
+                        once["reduce-scatter"] += _array_bytes(
+                            _result_type(m, line))
+                    else:
+                        once.update(held(c))
                 continue
-            costs = [cost(c.lstrip("%"))
-                     for one, many in _CALLED.findall(line)
-                     for c in ([one] if one else re.split(r",\s*", many))]
+            costs = [cost(c) for c in called]
             if opcode == "conditional" and costs:
-                costs = [max(costs, key=lambda c: sum(c.values()))]
-            for c in costs:
-                total.update(c)
-        return total
+                costs = [max(costs, key=lambda c: sum((c[0] + c[1]).values()))]
+            for a, b in costs:
+                (looped if opcode == "while" else once).update(a)
+                looped.update(b)
+        return once, looped
 
-    return dict(cost(entry)) if entry else {}
+    if not entry:
+        return {}
+    once, looped = cost(entry)
+    return {kind: looped[kind] + round(once[kind] / steps)
+            for kind in {*once, *looped} if once[kind] or looped[kind]}
 
 
 def kernel_operand_spaces(hlo_text: str, names) -> dict[str, dict[str, int]]:
@@ -680,9 +751,9 @@ def format_table(table: dict) -> str:
 
 
 __all__ = ["OTHER", "UNATTRIBUTED", "COLLECTIVE", "COLLECTIVE_KINDS",
-           "collective_kind", "collective_bytes", "kernel_operand_spaces",
-           "threefry_draws", "time_reversals", "kernel_edge_passes",
-           "PROGRAM_SPAN_PREFIXES", "ProfilerBusy", "capture",
+           "ASYNC_COLLECTIVE", "collective_kind", "collective_bytes",
+           "kernel_operand_spaces", "threefry_draws", "time_reversals",
+           "kernel_edge_passes", "PROGRAM_SPAN_PREFIXES", "ProfilerBusy", "capture",
            "trace_window", "scope_table", "fused_scopes", "module_name",
            "layer_table", "layer_table_of", "read_planes", "find_xplane",
            "format_table"]

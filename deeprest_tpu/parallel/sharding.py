@@ -6,7 +6,21 @@ dimension F where it appears (the mask output and the layer-0 GRU input
 projections — the two places that grow with the endpoint vocabulary,
 SURVEY.md §7.3); everything else is replicated.  The batch shards on
 ``data``.  No manual collectives anywhere: the cross-expert mixing sum and
-the gradient all-reduce are inserted by GSPMD from these annotations.
+the gradients' reduction are inserted by GSPMD from these annotations.
+
+Under a ``data`` axis the state is whole on every chip and every gradient
+is all-reduced, with one exception that lasts a dispatch of the compact
+superstep (train/trainer.py): the table's rows of the two layer-0 w_ih
+leaves and of their moments, which ride its scan, are split over ``data``
+along the row axis (:data:`CARRIED_ROWS_RULES`).  A chip then steps its
+own rows only: the bfloat16 folded weight ``bf16(mask * w_ih)`` is
+all-gathered for the projection (:func:`pin_folded_rows`), the
+projection's bfloat16 weight gradient comes back reduce-scattered, and
+Adam runs on ``U_pad / data`` rows a chip; after the scan the six float32
+arrays are all-gathered once (pinned by the table below, as every state
+is) and put into the whole leaves, so the state a dispatch returns is
+whole again.
+Every collective is still the partitioner's.
 
 The table below (:data:`PARTITION_RULES`) is the SINGLE owner of those
 decisions: an ordered ``(regex, PartitionSpec)`` list matched against
@@ -56,6 +70,36 @@ PARTITION_RULES: tuple[tuple[str, P], ...] = (
     #    step (scalar), the PRNG key, Adam's update counter.
     (r"(^|/)(step|rng|count)$", P()),
 )
+
+
+# The table's rows of a layer-0 w_ih leaf (and of its Adam mirrors) while
+# they ride the compact superstep's scan in the leaf's place: ``[E, U_pad,
+# 3H]``, split over ``data`` along the rows.  Matched BEFORE the table
+# above, for a state whose w_ih leaves are such rows
+# (``state_sharding(..., carried_rows=True)``); a compact table lives on a
+# mesh whose ``model`` axis is 1 (``Trainer._stage_sparse``).
+CARRIED_ROWS_RULES: tuple[tuple[str, P], ...] = (
+    (r"(^|/)gru_(fwd|bwd)_w_ih$", P("expert", "data", None)),
+)
+
+
+def carried_rows_split(mesh: Mesh | None, width: int) -> int:
+    """Over how many chips the ``width`` carried rows of a w_ih leaf are
+    split: the mesh's ``data`` axis where it divides them (a padded live
+    set is a power of two of at least 128), else 1 (the rows whole on every
+    chip, and the compiled program the one it was without this rule)."""
+    data = 1 if mesh is None else mesh.shape["data"]
+    return data if data > 1 and width % data == 0 else 1
+
+
+def pin_folded_rows(mesh: Mesh, rows: jax.Array) -> jax.Array:
+    """The folded, cast ``[E, U_pad, 3H]`` weight that the projection
+    contracts, whole on every chip of ``data`` where the carried rows are
+    split over it: the all-gather of a step (and, transposed, the
+    reduce-scatter of the weight's gradient).  For a caller that has asked
+    :func:`carried_rows_split`."""
+    return jax.lax.with_sharding_constraint(
+        rows, NamedSharding(mesh, P("expert", None, None)))
 
 
 def leaf_path_name(path: Sequence[Any]) -> str:
@@ -118,18 +162,21 @@ def param_specs(params: Mapping[str, Any]) -> dict[str, P]:
     return match_partition_rules(dict(params), strict=True)
 
 
-def state_specs(state: Any) -> Any:
+def state_specs(state: Any, carried_rows: bool = False) -> Any:
     """PartitionSpec pytree for a full TrainState (params, optimizer
-    mirrors, step/rng bookkeeping), strictly rule-resolved."""
-    return match_partition_rules(state, strict=True)
+    mirrors, step/rng bookkeeping), strictly rule-resolved.
+    ``carried_rows``: the state's layer-0 w_ih leaves are the table's rows
+    that ride the compact superstep's scan (:data:`CARRIED_ROWS_RULES`)."""
+    rules = (CARRIED_ROWS_RULES if carried_rows else ()) + PARTITION_RULES
+    return match_partition_rules(state, rules, strict=True)
 
 
-def state_sharding(mesh: Mesh, state: Any) -> Any:
+def state_sharding(mesh: Mesh, state: Any, carried_rows: bool = False) -> Any:
     """NamedSharding pytree for a full TrainState on ``mesh`` — what the
     trainer's ``pin_state`` constrains every step output to, and what
     checkpoint restore assembles shards into."""
     return jax.tree.map(lambda spec: NamedSharding(mesh, spec),
-                        state_specs(state),
+                        state_specs(state, carried_rows),
                         is_leaf=lambda x: isinstance(x, P))
 
 

@@ -51,7 +51,9 @@ from deeprest_tpu.parallel.elastic import (
 from deeprest_tpu.parallel.mesh import (
     AXES, NoValidMeshError, make_mesh, mesh_config_of, shrink_mesh_config,
 )
-from deeprest_tpu.parallel.sharding import shard_params, state_sharding
+from deeprest_tpu.parallel.sharding import (
+    carried_rows_split, shard_params, state_sharding,
+)
 from deeprest_tpu.train.data import DatasetBundle, eval_window_indices
 from deeprest_tpu.train.metrics import Throughput, mae_report
 
@@ -325,7 +327,8 @@ class Trainer:
         self._program_published = False
         self._dispatched_once: set[str] = set()
 
-        def pin_state(state: TrainState) -> TrainState:
+        def pin_state(state: TrainState,
+                      carried_rows: bool = False) -> TrainState:
             """Constrain every leaf to its CANONICAL named sharding, all
             resolved from the ONE rule table (parallel/sharding.py
             PARTITION_RULES — params, their optimizer mirrors, and the
@@ -341,11 +344,21 @@ class Trainer:
             output to one signature keeps the jit cache at one executable
             per step function (the no-recompile probe) and is what makes
             the superstep scan bit-identical to the per-step loop.
+
+            ``carried_rows``: the state's w_ih leaves are the table's rows
+            that ride the compact superstep's scan, split over ``data``
+            (the rule table's CARRIED_ROWS_RULES).
             """
-            return jax.tree.map(jax.lax.with_sharding_constraint,
-                                state, state_sharding(self.mesh, state))
+            return jax.tree.map(
+                jax.lax.with_sharding_constraint, state,
+                state_sharding(self.mesh, state, carried_rows))
 
         self._pin_state = jax.jit(pin_state)
+
+        def split_rows(live_cols) -> bool:
+            """Whether the rows of this table that ride the compact
+            superstep's scan are split over the mesh's ``data`` axis."""
+            return carried_rows_split(self.mesh, live_cols.shape[0]) > 1
 
         @jax.named_scope(scopes.OPTIMIZER)
         def apply_gradients(state: TrainState, grads):
@@ -365,7 +378,9 @@ class Trainer:
             # with respect to them and runs the one tx.update on them with
             # the shared count: no [E, F, 3H] array is read or made.  The
             # whole leaves of `full_w_ih` ride along unread (flax holds a
-            # supplied leaf to its init shape).
+            # supplied leaf to its init shape).  Under a `data` axis that
+            # divides them the rows are a chip's own quarter (see
+            # train_superstep), and stay so in the state this returns.
             dropout_rng = dropout_key(state)
             w_ih = full_w_ih or {}
 
@@ -381,7 +396,8 @@ class Trainer:
             params, opt_state = apply_gradients(state, grads)
             return (
                 pin_state(TrainState(step=state.step + 1, params=params,
-                                     opt_state=opt_state, rng=state.rng)),
+                                     opt_state=opt_state, rng=state.rng),
+                          carried_rows=bool(w_ih) and split_rows(live_cols)),
                 loss,
             )
 
@@ -443,10 +459,27 @@ class Trainer:
             # differentiating with respect to them and updating them; and
             # they are put back once, into the donated leaves, after it.
             # Nothing [E, F, 3H] is named inside the scan.
+            #
+            # Under a `data` axis that divides the table's width the six
+            # carried arrays are split over it along the rows for the
+            # length of the dispatch (parallel/sharding.py owns the spec):
+            # a chip takes its own U_pad / data rows from its own whole
+            # leaves, and each step folds and casts them, gathers the
+            # bfloat16 folded weight for the projection, gets that
+            # weight's bfloat16 gradient back reduce-scattered (the same
+            # sum of the same addends an all-reduce makes, element by
+            # element) and runs the fold's backward and Adam on its rows.
+            # After the scan the six arrays are gathered once, so what is
+            # put into the donated leaves, and the state the dispatch
+            # returns, is whole and the same on every chip.  There is one
+            # float32 copy of a carried row inside a dispatch where there
+            # were `data`; every update is still plain Adam on the mean
+            # gradient over all the windows of the global batch.
             live_cols = live_cols_of(x_base)
             full_w_ih = ({k: v for k, v in state.params.items()
                           if k in MASKED_PARAM_NAMES}
                          if live_cols is not None else {})
+            split = bool(full_w_ih) and split_rows(live_cols)
 
             def body(st, step_plan):
                 starts, wb = step_plan
@@ -463,12 +496,15 @@ class Trainer:
 
                 return jax.lax.cond(jnp.any(wb > 0), run, skip, st)
 
-            rows, losses = jax.lax.scan(
-                body,
-                take_w_ih(state, live_cols, self.mesh) if full_w_ih else state,
-                (starts_c, weights_c))
+            carry = (take_w_ih(state, live_cols, self.mesh) if full_w_ih
+                     else state)
+            if split:
+                carry = pin_state(carry, carried_rows=True)
+            rows, losses = jax.lax.scan(body, carry, (starts_c, weights_c))
             if not full_w_ih:
                 return rows, losses
+            if split:
+                rows = pin_state(rows)        # whole again: six gathers
 
             # Off the table the gradient is exactly zero, so what a row
             # there does in a dispatch depends on its moments, the count
@@ -679,7 +715,10 @@ class Trainer:
             "began; bound: the most stale rows a dispatch of its shapes "
             "visits row by row; trips: the chunk trips its off-table pass "
             "made (all of F's chunks past the bound); the last three "
-            "counted on a compact base only",
+            "counted on a compact base only; per_chip: the rows whose Adam "
+            "ONE chip ran a step (the table's width over the mesh's data "
+            "axis where the compact superstep splits its carried rows over "
+            "it, else updated)",
             labelnames=("kind",))
         self._m_stagings = obs_metrics.REGISTRY.counter(
             obs_setup.STAGINGS, "stage_dataset calls of this process")
@@ -806,8 +845,9 @@ class Trainer:
         self._m_device_bytes.set(fullest.get("peak_bytes_in_use", 0),
                                  at=at, kind="peak")
 
-    def _publish_program(self, state) -> None:
-        """What the compiler made of the superstep this epoch dispatched,
+    def _publish_program(self, state, steps: int = 1) -> None:
+        """What the compiler made of the superstep this epoch dispatched
+        (``steps``: the trips of its scan),
         once for each build of the programs, from the executable the
         dispatch left in the jit's cache (:meth:`_dispatched_executable`:
         no second compile): its ``memory_analysis`` into
@@ -818,8 +858,9 @@ class Trainer:
         time with nothing in the kernel changed) and, under a mesh of
         more than one device, what a step hands to each kind of collective
         (``deeprest_train_collective_bytes{op}``: the partitioner decides
-        what is reduced and in which type, so no sum over the gradient
-        tree would say it) and in how many places a step generates
+        what is reduced or gathered and in which type, so no sum over the
+        gradient tree would say it; what stands outside the scan, the
+        carried rows' gathers of a dispatch, counts a ``steps``-th) and in how many places a step generates
         the dropout mask's random bits (``deeprest_train_dropout_draws``:
         the program draws the mask once a forward pass, and the compiler
         draws it again wherever it fuses the draw into a consumer) and
@@ -852,7 +893,7 @@ class Trainer:
             for space, n in spaces.items():
                 self._m_kernel_operand_bytes.set(n, kernel=kernel,
                                                  space=space)
-        for op, n in profiler.collective_bytes(text).items():
+        for op, n in profiler.collective_bytes(text, steps).items():
             self._m_collective_bytes.set(n, op=op)
         draws = profiler.threefry_draws(text, scopes.DROPOUT)
         if draws:
@@ -876,10 +917,13 @@ class Trainer:
         None where no table was consulted (the per-step and accumulation
         paths, a base in its dense form): every step ran over and wrote
         all F rows, and ``stale``, ``bound`` and ``trips`` are left as
-        they were."""
+        they were.  ``per_chip``: the rows whose Adam one chip ran a step,
+        the carried rows' share of the table under a ``data`` axis
+        (parallel/sharding.py), else ``updated``."""
         if not isinstance(x_base, SparseBase):
             return
         updated = visited = x_base.capacity
+        split = 1
         if stale is not None:
             self._m_readbacks.inc(sink="optimizer_rows")
             # graftlint: disable=JX003 -- designed sink: one scalar an epoch, dispatched before its first chunk and read after its last
@@ -888,12 +932,15 @@ class Trainer:
             visited = rows_visited(*shapes, stale)
             if not stale:
                 updated = x_base.width
+            split = carried_rows_split(self.mesh, x_base.width)
             self._m_optimizer_rows.set(stale, kind="stale")
             self._m_optimizer_rows.set(off_table_bound(*shapes),
                                        kind="bound")
             self._m_optimizer_rows.set(off_table_trips(*shapes, stale),
                                        kind="trips")
         self._m_optimizer_rows.set(updated, kind="updated")
+        self._m_optimizer_rows.set(
+            x_base.width // split if split > 1 else updated, kind="per_chip")
         self._m_optimizer_rows.set(visited, kind="visited")
         self._m_optimizer_rows.set(x_base.capacity, kind="total")
 
@@ -1695,7 +1742,7 @@ class Trainer:
         if not self._program_published:
             # the host reads the executable while the device runs the
             # epoch's last chunk
-            self._publish_program(state)
+            self._publish_program(state, s // cfg.grad_accum_windows)
         with phase("device_wait"):
             jax.block_until_ready(state.params)
         if measuring:

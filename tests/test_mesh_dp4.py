@@ -11,6 +11,7 @@ the configuration's own widths in bfloat16 (chipbench/limits/); here it is
 float32 at toy widths.  No number of this file is a device number.
 """
 
+import importlib
 import json
 import os
 import re
@@ -203,7 +204,8 @@ def test_the_widest_table_under_data4_is_recorded_as_it_ran(widest):
     assert [s.tags["mesh"] for s in spans if s.name == "train.epoch"] == [
         "4x1x1"]
     read = {k[0]: v for k, v in gauge.series().items()}
-    assert read == _gradient_bytes(trainer._dispatched_program_text(state))
+    assert read == _gradient_bytes(trainer._dispatched_program_text(state),
+                                   len(trainer._last_epoch_losses))
     width = F // 2
     rows = 4 * 2 * E * width * 3 * H
     assert rows < read["all-reduce"] < rows + 4 * E * H * F
@@ -249,9 +251,11 @@ def test_state_is_the_same_on_every_chip(request, table):
 # -- (c) what the program records under a mesh ------------------------------
 
 
-def _gradient_bytes(hlo_text: str) -> dict:
-    """Bytes by kind of the collectives in ``hlo_text``, counted here from
-    the instructions' own result types."""
+def _gradient_bytes(hlo_text: str, steps: int) -> dict:
+    """Bytes a step by kind of the collectives in ``hlo_text``, counted
+    here from the instructions' own result types; one that no loop holds
+    (its ``op_name`` says so: since ISSUE 45 the gathers of the carried
+    rows, once a dispatch) counts a ``steps``-th."""
     sizes = {"f32": 4, "u32": 4, "s32": 4, "pred": 1, "bf16": 2}
     out = {}
     for line in hlo_text.splitlines():
@@ -265,8 +269,9 @@ def _gradient_bytes(hlo_text: str) -> dict:
                 r"\b(f32|u32|s32|pred|bf16)\[([\d,]*)\]", result):
             n += sizes[dtype] * int(np.prod(
                 [int(d) for d in dims.split(",") if d] or [1]))
-        out[kind[0]] = out.get(kind[0], 0) + n
-    return out
+        looped = "/while/" in profiler._OP_NAME.search(line)[1]
+        out[kind[0]] = out.get(kind[0], 0) + (n if looped else n / steps)
+    return {kind: round(n) for kind, n in out.items()}
 
 
 def test_collective_bytes_gauge_and_the_one_device_gauges(runs):
@@ -287,7 +292,8 @@ def test_collective_bytes_gauge_and_the_one_device_gauges(runs):
     runs[4] = (runs[4][0], trainer, state, bundle, staged, runs[4][5])
     text = trainer._dispatched_program_text(state)
     read = {k[0]: v for k, v in gauge.series().items()}
-    assert read == _gradient_bytes(text) and read["all-reduce"] > 0
+    assert read == _gradient_bytes(text, len(trainer._last_epoch_losses))
+    assert read["all-reduce"] > 0
     # what is reduced is the gradient: the w_ih leaves at the table's rows
     # (not F), and NOT the mask weights' [E, H, F], which every chip
     # derives from the reduced w_ih gradient
@@ -391,6 +397,204 @@ ENTRY %main (a: f32[8]) -> f32[8] {
 """
     assert profiler.collective_bytes(hlo) == {"all-reduce": 32,
                                               "reduce-scatter": 16}
+
+
+# -- (c2) ISSUE 45: the carried rows split over `data` -----------------------
+
+# XLA:TPU's forms of the collectives that the split brings, cut from the
+# compact superstep compiled for a described v5e:2x2 at toy shapes: a
+# reduce-scatter is a `kCustom` fusion named for no collective; an
+# asynchronous gather is a chain of three fusions, each holding a piece with
+# the chain's id, the middle one beside the matmul that hides it; a
+# dispatch's gathers stand in the entry computation, outside the step's loop.
+_SCATTER_HLO = """HloModule jit_train_superstep
+
+%add (x: bf16[], y: bf16[]) -> bf16[] {
+  ROOT %a = bf16[] add(%x, %y)
+}
+
+%all-reduce-scatter.1 (input.1: bf16[4,16,8]) -> bf16[4,4,8] {
+  %input.1 = bf16[4,16,8]{2,1,0} parameter(0)
+  %all-reduce.19 = bf16[4,16,8]{2,1,0} all-reduce(%input.1), channel_id=22, replica_groups={{0,1,2,3}}, to_apply=%add, frontend_attributes={from-cross-replica-sharding="true"}
+  ROOT %dynamic-slice.34 = bf16[4,4,8]{2,1,0} dynamic-slice(%all-reduce.19, %c, %i, %c), dynamic_slice_sizes={4,4,8}
+}
+
+%body (p: (bf16[4,16,8])) -> (bf16[4,16,8]) {
+  %dw = bf16[4,16,8]{2,1,0} fusion(%p), kind=kOutput, calls=%fused_dot, metadata={op_name="jit(train_superstep)/while/body/transpose(jvp(QuantileGRU))/in_proj/dot_general"}
+  %fusion.26 = bf16[4,4,8]{2,1,0} fusion(%dw), kind=kCustom, calls=%all-reduce-scatter.1, metadata={op_name="jit(train_superstep)/while/body/transpose(jvp(QuantileGRU))/in_proj/dot_general"}
+  %all-reduce.21 = f32[4,8]{1,0} all-reduce(%b), channel_id=9, to_apply=%add, metadata={op_name="jit(train_superstep)/while/body/transpose(jvp(QuantileGRU))/recurrence/psum"}
+}
+
+ENTRY %main (a: bf16[4,16,8]) -> bf16[4,16,8] {
+  %while.1 = (bf16[4,16,8]{2,1,0}) while(%t), condition=%cond, body=%body
+}
+"""
+
+_CHAIN_HLO = """HloModule jit_train_superstep
+
+%fused_computation.280 (param_0: bf16[4,4,8]) -> (bf16[4,4,8], bf16[4,16,8], u32[]) {
+  %all-gather.17 = bf16[4,16,8]{2,1,0} all-gather(%param_0), channel_id=4, dimensions={1}, frontend_attributes={chain_id="0"}
+  ROOT %custom-call.46 = (bf16[4,4,8]{2,1,0}, bf16[4,16,8]{2,1,0}, u32[]{:S(2)}) custom-call(%all-gather.17), custom_call_target="AsyncCollectiveStart"
+}
+
+%async_collective_fusion.187 (param_0: bf16[4,4,8], param_1: bf16[4,16,8]) -> (bf16[4,6,2,8], bf16[4,16,8]) {
+  %convolution.17 = bf16[4,6,2,8]{3,2,1,0} convolution(%x, %w), metadata={op_name="jit(train_superstep)/while/body/jvp(QuantileGRU)/in_proj/dot_general"}
+  %all-gather.19 = bf16[4,16,8]{2,1,0} all-gather(%param_0), channel_id=4, dimensions={1}, frontend_attributes={chain_id="0"}
+  ROOT %tuple.386 = (bf16[4,6,2,8]{3,2,1,0}, bf16[4,16,8]{2,1,0}) tuple(%convolution.17, %all-gather.19)
+}
+
+%fused_computation.282 (param_0: bf16[4,4,8], param_1: bf16[4,16,8]) -> bf16[4,16,8] {
+  %all-gather.21 = bf16[4,16,8]{2,1,0} all-gather(%param_0), channel_id=4, dimensions={1}, frontend_attributes={chain_id="0"}
+  ROOT %custom-call.48 = bf16[4,16,8]{2,1,0} custom-call(%param_0, %param_1, %all-gather.21), custom_call_target="AsyncCollectiveDone"
+}
+
+%body (p: (bf16[4,4,8])) -> (bf16[4,4,8]) {
+  %all-gather.46 = bf16[4,16,8]{2,1,0} all-gather(%folded.fwd), channel_id=3, dimensions={1}, frontend_attributes={async_collective_name="all-gather-start"}, metadata={op_name="jit(train_superstep)/while/body/jvp(QuantileGRU)/convert_element_type"}
+  %async-collective-start = (bf16[4,4,8]{2,1,0}, bf16[4,16,8]{2,1,0}, u32[]{:S(2)}) fusion(%folded.bwd), kind=kCustom, calls=%fused_computation.280, metadata={op_name="jit(train_superstep)/while/body/jvp(QuantileGRU)/convert_element_type"}
+  %fusion.187 = (bf16[4,6,2,8]{3,2,1,0}, bf16[4,16,8]{2,1,0}) fusion(%g0, %g1, %all-gather.46), kind=kOutput, calls=%async_collective_fusion.187, metadata={op_name="jit(train_superstep)/while/body/jvp(QuantileGRU)/in_proj/dot_general"}
+  %async-collective-done = bf16[4,16,8]{2,1,0} fusion(%g2, %g3), kind=kCustom, calls=%fused_computation.282, metadata={op_name="jit(train_superstep)/while/body/jvp(QuantileGRU)/convert_element_type"}
+}
+
+ENTRY %main (a: bf16[4,4,8]) -> bf16[4,4,8] {
+  %while.1 = (bf16[4,4,8]{2,1,0}) while(%t), condition=%cond, body=%body
+}
+"""
+
+_DISPATCH_HLO = """HloModule jit_train_superstep
+
+%body (p: (f32[4,4,8])) -> (f32[4,4,8]) {
+  %all-reduce.8 = f32[]{:T(128)} all-reduce(%loss), channel_id=2, to_apply=%add, metadata={op_name="jit(train_superstep)/while/body/jvp(loss)/reduce_sum"}
+}
+
+ENTRY %main (a: f32[4,4,8]) -> f32[4,16,8] {
+  %while.1 = (f32[4,4,8]{2,1,0}) while(%t), condition=%cond, body=%body
+  %all-gather.47 = f32[4,16,8]{2,1,0} all-gather(%rows.0), channel_id=18, dimensions={1}, metadata={op_name="jit(train_superstep)/sharding_constraint"}
+  %all-gather.48 = f32[4,16,8]{2,1,0} all-gather(%rows.1), channel_id=19, dimensions={1}, metadata={op_name="jit(train_superstep)/sharding_constraint"}
+}
+"""
+
+
+@pytest.mark.parametrize("text, steps, expected, row", [
+    # the fusion's result, a chip's slice, as the reduce-scatter it is; the
+    # all-reduce inside it is no all-reduce of the step
+    (_SCATTER_HLO, 1, {"reduce-scatter": 4 * 4 * 8 * 2, "all-reduce": 128},
+     ["all-reduce.21", "fusion.26"]),
+    # the chain's three pieces once, beside the step's synchronous gather;
+    # the fusion that hides the chain keeps its matmul's label
+    (_CHAIN_HLO, 1, {"all-gather": 2 * 4 * 16 * 8 * 2},
+     ["all-gather.46", "async-collective-done", "async-collective-start"]),
+    # what stands outside the loop, divided by the dispatch's steps
+    (_DISPATCH_HLO, 8, {"all-gather": 2 * 4 * 16 * 8 * 4 // 8,
+                        "all-reduce": 4},
+     ["all-gather.47", "all-gather.48", "all-reduce.8"]),
+], ids=["all_reduce_scatter_fusion", "chained_asynchronous_gather",
+        "gathers_outside_the_loop"])
+def test_collective_bytes_sees_xla_tpus_forms(text, steps, expected, row):
+    assert profiler.collective_bytes(text, steps) == expected
+    table = profiler.scope_table(text, ("in_proj", "recurrence"))
+    assert sorted(k for k, v in table.items()
+                  if v == (profiler.COLLECTIVE, "-")) == row
+    if "fusion.187" in table:
+        assert table["fusion.187"] == ("in_proj", "fwd")
+    # a trace's event finds the row by its name where the map has it, and
+    # the asynchronous fusions by their names alone
+    for name in row:
+        assert profiler._row_key(name, f"%{name} = ...", table) == (
+            profiler.COLLECTIVE, "-")
+    assert profiler._row_key("async-collective-done.3", "", None) == (
+        profiler.COLLECTIVE, "-")
+
+
+@pytest.mark.parametrize("name, kind", [
+    ("all-reduce-scatter.1", ("reduce-scatter", "")),
+    ("%all-reduce-scatter", ("reduce-scatter", "")),
+    ("async-collective-start", (profiler.ASYNC_COLLECTIVE, "-start")),
+    ("async-collective-done.2", (profiler.ASYNC_COLLECTIVE, "-done")),
+    ("async_collective_fusion.187", None),
+    ("fusion.26", None),
+])
+def test_collective_kind_of_xla_tpus_wrappers(name, kind):
+    assert profiler.collective_kind(name) == kind
+
+
+@pytest.mark.parametrize("data, width, split", [
+    (4, 4096, 4), (4, 128, 4), (1, 4096, 1), (4, 130, 1), (None, 4096, 1)])
+def test_the_carried_rows_split_is_the_data_axis_where_it_divides(
+        data, width, split):
+    from deeprest_tpu.parallel import sharding
+    from deeprest_tpu.parallel.mesh import make_mesh
+
+    mesh = None if data is None else make_mesh(MeshConfig(data=data))
+    assert sharding.carried_rows_split(mesh, width) == split
+
+
+def test_the_carried_rows_spec_has_one_owner(runs, monkeypatch):
+    """The spec of the rows that ride the scan is `parallel/sharding.py`'s
+    rule and nobody else's: a state of carried rows resolves its six w_ih
+    leaves to rows over `data` and every other leaf as ever; with the rule
+    taken out of the module the same call gives the whole-state table's
+    answer; and the trainer and the model write no sharding of their own."""
+    from jax.sharding import PartitionSpec as P
+
+    from deeprest_tpu.models import qrnn
+    from deeprest_tpu.parallel import sharding
+    from deeprest_tpu.train import trainer as trainer_module
+
+    _, trainer, state, _, staged, _ = runs[4]
+    rows = trainer_module.take_w_ih(state, staged[0].live, trainer.mesh)
+    whole, carried = (sharding.state_specs(rows, carried_rows=flag)
+                      for flag in (False, True))
+    differ = {sharding.leaf_path_name(path)
+              for (path, a), b in zip(
+                  jax.tree_util.tree_leaves_with_path(
+                      whole, is_leaf=lambda x: isinstance(x, P)),
+                  jax.tree.leaves(carried,
+                                  is_leaf=lambda x: isinstance(x, P)))
+              if a != b}
+    assert differ == {f"{tree}/gru_{d}_w_ih" for d in ("fwd", "bwd")
+                      for tree in ("params", "opt_state/0/mu",
+                                   "opt_state/0/nu")}
+    assert carried.params["gru_fwd_w_ih"] == P("expert", "data", None)
+    assert whole.params["gru_fwd_w_ih"] == P("expert", "model", None)
+    monkeypatch.setattr(sharding, "CARRIED_ROWS_RULES", ())
+    assert sharding.state_specs(rows, carried_rows=True) == whole
+    for module in (trainer_module, qrnn):
+        with open(module.__file__) as fh:
+            source = fh.read()
+        assert "NamedSharding" not in source
+        assert "with_sharding_constraint(" not in source.replace(
+            "jax.lax.with_sharding_constraint, state", "")
+
+
+def test_a_chip_steps_a_quarter_of_the_tables_rows(runs):
+    """`deeprest_train_optimizer_rows{kind="per_chip"}` and the benchmark's
+    reader of it: a quarter of the table's width under `data`=4, where the
+    compiled step holds the carried rows and their moments a quarter at a
+    time, and the width on one device, whose step holds no such array."""
+    from chipbench.readers import carried_rows
+
+    rows = REGISTRY.get("deeprest_train_optimizer_rows")
+    read = {}
+    for data in (4, 1):
+        _, trainer, state, bundle, staged, rest = runs[data]
+        staged = trainer.stage_dataset(bundle)      # the process's gauges
+        if data == 1:        # an earlier case's epoch took that state
+            state = trainer.init_state(trainer.sample_input(bundle))
+        state, _ = trainer.train_epoch(state, bundle,
+                                       np.random.default_rng(2),
+                                       staged=staged)
+        runs[data] = (runs[data][0], trainer, state, bundle, staged, rest)
+        width = staged[0].width
+        read[data] = (rows.value(kind="per_chip"), rows.value(kind="updated"),
+                      carried_rows.per_chip_pct({}))
+        text = trainer._dispatched_program_text(state)
+        quarter = f"f32[{E},{width // 4},{3 * H}]"
+        assert (quarter in text) == (data == 4)
+    assert read[4] == (width // 4, width, 25.0)
+    assert read[1] == (width, width, 100.0)
+    # a program without the kind (an older commit) reads as nothing
+    rows._series.pop(("per_chip",))
+    assert carried_rows.per_chip_pct({}) is None
 
 
 # -- (d) the three readers on a recorded slice of a four-chip trace ---------
@@ -546,9 +750,11 @@ def test_the_cells_files_exist_and_say_what_the_issue_says(name):
             REPO, "chipbench", "readers", module + ".py"))
         if m["layer"] == "mesh":
             # ISSUE 44's cell appended; what was there keeps its place
+            # (ISSUE 45's metric, the last, has a reader of its own)
             assert m["workloads"] == sorted(MESH_CELLS)
             assert spec["runners"] == ["train_mesh"]
-            assert callable(getattr(collectives, func))
+            assert callable(getattr(importlib.import_module(
+                f"chipbench.readers.{module}"), func))
     for name in ("train_steps_per_s", "hbm_peak_gb"):
         metric = {m["name"]: m for m in bench["end_to_end"]}[name]
         assert cell["name"] in metric["workloads"]

@@ -100,7 +100,7 @@ def test_scope_map_of_the_compiled_superstep_holds_every_scope(tiny):
     # Of the instructions JAX named (the compiler's own layout copies
     # carry no op_name and no scope can claim them), under a tenth fall
     # to `other`: the superstep loop's bookkeeping around the step.
-    computations, inner = profiler._parse_hlo(text)
+    computations, inner, _ = profiler._parse_hlo(text)
     named = [profiler._scope_of(op_name, NAMES)[0]
              for comp, instructions in computations.items()
              if comp not in inner
@@ -861,7 +861,7 @@ _BENCHMARK_SERIES = [
     ("deeprest_train_last_stage_seconds", (), [{}]),
     ("deeprest_train_optimizer_rows", ("kind",),
      [{"kind": k} for k in ("total", "updated", "visited", "stale",
-                            "trips", "bound")]),
+                            "trips", "bound", "per_chip")]),
     ("deeprest_train_projection_columns", ("kind",),
      [{"kind": k} for k in ("live", "contracted", "total", "padded",
                             "bound")]),
