@@ -45,6 +45,7 @@ feed, serving) the call is what it was.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Mapping
 
 import flax.linen as nn
@@ -54,7 +55,9 @@ import jax.numpy as jnp
 from deeprest_tpu.config import ModelConfig
 from deeprest_tpu.ops import scopes
 from deeprest_tpu.ops.gru import GRUParams, bidirectional_gru, gru
-from deeprest_tpu.parallel.sharding import carried_rows_split, pin_folded_rows
+from deeprest_tpu.parallel.sharding import (
+    carried_rows_split, project_split_rows,
+)
 
 MASK_PARAM_NAMES = ("mask_w1", "mask_b1", "mask_w2", "mask_b2")
 # Layer-0 input weights the soft mask folds into ((x ⊙ m) @ W ≡ x @ (m ⊙ W));
@@ -289,21 +292,19 @@ class QuantileGRU(nn.Module):
         if live_cols is not None:
             mask = take_columns(mask, live_cols)                      # [E, U]
 
+        # The caller's carried rows, which a mesh with a `data` axis splits
+        # over it (parallel/sharding.py): a chip folds and casts its own,
+        # and the projection and its backward are project_split_rows'.
+        split = (live_w_ih is not None and carried_rows_split(
+            self.mesh, live_cols.shape[0]) > 1)
+
         def masked(p: GRUParams, name: str) -> GRUParams:
             if live_cols is None:
                 w_ih = p.w_ih
             elif live_w_ih is None:
                 w_ih = take_columns(p.w_ih, live_cols, self.mesh)
             else:
-                # The caller's carried rows, which a mesh with a `data`
-                # axis splits over it (parallel/sharding.py): a chip folds
-                # and casts its own, and what the projection contracts is
-                # the gathered weight in the compute dtype, the very array
-                # the fold and `cast` below make on one chip.
                 w_ih = live_w_ih[name]
-                if carried_rows_split(self.mesh, w_ih.shape[1]) > 1:
-                    return p._replace(w_ih=pin_folded_rows(
-                        self.mesh, _fold(mask, w_ih).astype(compute_dtype)))
             return p._replace(w_ih=_fold(mask, w_ih))
 
         def cast(p: GRUParams) -> GRUParams:
@@ -313,6 +314,8 @@ class QuantileGRU(nn.Module):
         for layer in range(cfg.num_layers):
             sfx = "" if layer == 0 else f"_l{layer}"
             in_dim = f if layer == 0 else cfg.rnn_out_dim
+            project = (functools.partial(project_split_rows, self.mesh)
+                       if split and layer == 0 else None)
             fwd = gru_params(f"gru_fwd{sfx}", in_dim)
             if layer == 0:
                 fwd = masked(fwd, MASKED_PARAM_NAMES[0])
@@ -322,10 +325,10 @@ class QuantileGRU(nn.Module):
                     bwd = masked(bwd, MASKED_PARAM_NAMES[1])
                 out = bidirectional_gru(cast(fwd), cast(bwd), out,
                                         backend=cfg.rnn_backend,
-                                        mesh=self.mesh)
+                                        mesh=self.mesh, project=project)
             else:
                 out = gru(cast(fwd), out, backend=cfg.rnn_backend,
-                          mesh=self.mesh)
+                          mesh=self.mesh, project=project)
             # layer 0 broadcasts [B,T,F] across experts; the output (and all
             # deeper layers) carry the expert axis: [E,B,T,D].
         # The post-RNN path stays in the model's compute dtype (bf16 for

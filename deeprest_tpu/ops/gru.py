@@ -110,6 +110,7 @@ def _gru_scan(
     h0: jax.Array,
     reverse: bool,
     unroll: int,
+    project=None,
 ) -> jax.Array:
     """Core scan. x: [E, B, T, F]; h0: [E, B, H] → outputs [E, B, T, H]."""
     # Hoisted input projection: one big MXU matmul over all time steps,
@@ -118,7 +119,9 @@ def _gru_scan(
     # folded into w_ih by the caller instead — see models/qrnn.py).
     eq = "btf,efg->tebg" if x.ndim == 3 else "ebtf,efg->tebg"
     with jax.named_scope(scopes.IN_PROJ):
-        proj = jnp.einsum(eq, x, params.w_ih) + params.b_ih[:, None, :]
+        xw = (jnp.einsum(eq, x, params.w_ih) if project is None
+              else jnp.moveaxis(project(x, params.w_ih), 1, 0))
+        proj = xw + params.b_ih[:, None, :]
 
     def step(h, xproj):
         # xproj: [E,B,3H]; h: [E,B,H]
@@ -146,15 +149,16 @@ def _kernel_io_dtype(dtype) -> jnp.dtype:
     return jnp.bfloat16 if dtype == jnp.bfloat16 else jnp.float32
 
 
-def _project(params: GRUParams, x: jax.Array) -> jax.Array:
+def _project(params: GRUParams, x: jax.Array, project=None) -> jax.Array:
     """Hoisted input projection ``x @ W_ih`` → [E, T, B, 3H] in the
     kernel's I/O dtype, WITHOUT ``b_ih``: the add is the first operation
     inside the recurrence's VJP (pallas_gru.gru_recurrence), whose
     backward kernels return the bias's gradient.  XLA fuses the add into
-    this dot either way."""
+    this dot either way.  ``project``: see :func:`gru`."""
     eq = "btf,efg->etbg" if x.ndim == 3 else "ebtf,efg->etbg"
     with jax.named_scope(scopes.IN_PROJ):
-        xw = jnp.einsum(eq, x, params.w_ih)
+        xw = (jnp.einsum(eq, x, params.w_ih) if project is None
+              else project(x, params.w_ih))
         return xw.astype(_kernel_io_dtype(
             jnp.result_type(xw.dtype, params.b_ih.dtype)))
 
@@ -225,6 +229,7 @@ def _layer_pallas(
     x: jax.Array,
     interpret: bool,
     mesh=None,
+    project=None,
 ) -> jax.Array:
     """Fused-kernel path of one layer.  ``directions``: a direction each,
     ``(params, h0, reverse)``, one for :func:`gru` and (forward, reverse)
@@ -245,7 +250,7 @@ def _layer_pallas(
     held the draw itself to one since ISSUE 36), and on the chip the
     operations outside the kernels took 7.54 ms a step for 5.86 at E=40
     and 48.1 for 40.1 at E=200 (PERF.md section 6, PR 29)."""
-    operands = tuple((_project(p, x), p.b_ih, p.w_hh, p.b_hh, h0)
+    operands = tuple((_project(p, x, project), p.b_ih, p.w_hh, p.b_hh, h0)
                      for p, h0, _ in directions)
     # the kernels carry their own names inside this scope; what is left
     # under `recurrence` is the layout work around them
@@ -264,6 +269,7 @@ def gru(
     unroll: int = 4,
     backend: str = "auto",
     mesh=None,
+    project=None,
 ) -> jax.Array:
     """Single-direction GRU over the time axis.
 
@@ -284,6 +290,10 @@ def gru(
           sharded over, if any.  The pallas kernel then runs under
           ``shard_map`` over ``data`` and ``expert``; the scan ignores it
           (GSPMD partitions it from the operands' shardings).
+      project: the input projection of windows ``x [B, T, F]`` where it is
+          not the plain einsum: ``(x, w_ih) -> [E, T, B, 3H]``, the same
+          values with a backward of its own (the carried rows split over
+          ``data``: parallel/sharding.project_split_rows).
 
     Returns: ``[E, B, T, H]`` hidden states.
     """
@@ -299,7 +309,7 @@ def gru(
         if pallas_gru.supported(x.shape[-2], params.hidden_size):
             return _layer_pallas(((params, h0, reverse),), x,
                                  interpret=resolved == "pallas_interpret",
-                                 mesh=mesh)
+                                 mesh=mesh, project=project)
         if backend != "auto":
             # An explicit pallas request that silently ran the scan path
             # would hide a perf bug; 'auto' falls through quietly by design.
@@ -311,7 +321,8 @@ def gru(
                 " falling back to lax.scan",
                 stacklevel=2,
             )
-    return _gru_scan(params, x, h0, reverse=reverse, unroll=unroll)
+    return _gru_scan(params, x, h0, reverse=reverse, unroll=unroll,
+                     project=project)
 
 
 def bidirectional_gru(
@@ -321,11 +332,13 @@ def bidirectional_gru(
     unroll: int = 4,
     backend: str = "auto",
     mesh=None,
+    project=None,
 ) -> jax.Array:
     """Bidirectional GRU: ``[E, B, T, F] → [E, B, T, 2H]``.
 
     Output layout matches torch: last-dim halves are (forward, backward),
-    each time-aligned with the input.  ``mesh`` as in :func:`gru`.
+    each time-aligned with the input.  ``mesh`` and ``project`` as in
+    :func:`gru`.
     """
     fwd, bwd = resolve_weights(fwd), resolve_weights(bwd)
     resolved = _resolve_backend(backend)
@@ -337,11 +350,11 @@ def bidirectional_gru(
                            jnp.float32)
             return _layer_pallas(((fwd, h0, False), (bwd, h0, True)), x,
                                  interpret=resolved == "pallas_interpret",
-                                 mesh=mesh)
+                                 mesh=mesh, project=project)
     # The scan backend, and an H the kernels do not take (``gru`` warns
     # where pallas was asked for by name): two single-direction calls.
     out_f = gru(fwd, x, reverse=False, unroll=unroll, backend=backend,
-                mesh=mesh)
+                mesh=mesh, project=project)
     out_b = gru(bwd, x, reverse=True, unroll=unroll, backend=backend,
-                mesh=mesh)
+                mesh=mesh, project=project)
     return jnp.concatenate([out_f, out_b], axis=-1)

@@ -99,3 +99,28 @@ def make_mesh(config: MeshConfig | None = None, devices: Sequence[jax.Device] | 
         config.data, config.expert, config.model
     )
     return Mesh(grid, AXES)
+
+
+def data_ring(mesh: Mesh) -> tuple[int, ...]:
+    """The indices of the mesh's ``data`` axis in an order in which each is
+    a neighbour of the next over ICI, and the last of the first: the ring a
+    hand-written ``ppermute`` should walk.  The partitioner's collectives
+    know the topology; a permute goes where it is told, and between chips
+    that share no link it takes two and shares them with another hop.
+
+    Where the devices say where they sit (a TPU's ``coords``) that is
+    column ``x`` = 0 upwards in ``y``, then the next column downwards, and
+    so on: a ring wherever the chips form two columns (a v5e 2x2, whose
+    ``jax.devices()`` order 0, 1, 2, 3 is NOT one: 1 and 2 lie across the
+    diagonal) and a path with one long hop on wider slices.  Elsewhere (the
+    CPU's virtual devices) the axis's own order."""
+    devices = mesh.devices.reshape(mesh.shape["data"], -1)[:, 0]
+    coords = [getattr(d, "coords", None) for d in devices]
+    if any(c is None for c in coords):
+        return tuple(range(len(devices)))
+
+    def snake(i):
+        x, y, *rest = coords[i]
+        return (*rest, x, y if x % 2 == 0 else -y)
+
+    return tuple(sorted(range(len(devices)), key=snake))

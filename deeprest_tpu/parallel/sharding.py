@@ -5,8 +5,9 @@ so EP is uniformly "axis 0 on ``expert``"; TP shards the call-path feature
 dimension F where it appears (the mask output and the layer-0 GRU input
 projections — the two places that grow with the endpoint vocabulary,
 SURVEY.md §7.3); everything else is replicated.  The batch shards on
-``data``.  No manual collectives anywhere: the cross-expert mixing sum and
-the gradients' reduction are inserted by GSPMD from these annotations.
+``data``.  The cross-expert mixing sum and the gradients' reduction are
+inserted by GSPMD from these annotations; the one hand-written collective
+is the ring of the last paragraph but one.
 
 Under a ``data`` axis the state is whole on every chip and every gradient
 is all-reduced, with one exception that lasts a dispatch of the compact
@@ -20,7 +21,16 @@ Adam runs on ``U_pad / data`` rows a chip; after the scan the six float32
 arrays are all-gathered once (pinned by the table below, as every state
 is) and put into the whole leaves, so the state a dispatch returns is
 whole again.
-Every collective is still the partitioner's.
+
+Those collectives are the partitioner's but one.  XLA:TPU makes the
+weight gradient's reduce-scatter ONE synchronous fusion that needs the
+whole product first, so at a wide table the links idle through the dot
+and the MXU through the scatter.  Where a hop carries enough
+(:func:`ring_scatters`), :func:`project_split_rows` gives the projection
+a backward of its own: the dot cut into ``data`` chunks whose partial
+sums travel round the chips by ``ppermute`` while the next chunks' dots
+run (the same sum of the same four chips' partials for every element,
+in the ring's order of addition).
 
 The table below (:data:`PARTITION_RULES`) is the SINGLE owner of those
 decisions: an ordered ``(regex, PartitionSpec)`` list matched against
@@ -43,8 +53,11 @@ import re
 from typing import Any, Mapping, Sequence
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from deeprest_tpu.parallel.mesh import data_ring
 
 # Ordered: first match wins.  Patterns run (re.search) against "/"-joined
 # leaf paths such as ``params/mask_w2`` or ``opt_state/0/mu/gru_fwd_w_ih``,
@@ -95,11 +108,135 @@ def carried_rows_split(mesh: Mesh | None, width: int) -> int:
 def pin_folded_rows(mesh: Mesh, rows: jax.Array) -> jax.Array:
     """The folded, cast ``[E, U_pad, 3H]`` weight that the projection
     contracts, whole on every chip of ``data`` where the carried rows are
-    split over it: the all-gather of a step (and, transposed, the
-    reduce-scatter of the weight's gradient).  For a caller that has asked
+    split over it: the all-gather of a step.  For a caller that has asked
     :func:`carried_rows_split`."""
     return jax.lax.with_sharding_constraint(
         rows, NamedSharding(mesh, P("expert", None, None)))
+
+
+# The least travelling sum, in bytes, for which the ring below replaces the
+# partitioner's dot and reduce-scatter: half a chunk's rows of one
+# direction's gradient, ``E x U_pad / data / 2 x 3H`` in the compute dtype.
+# Read on a v5e 2x2 at E=40, H=128 in bfloat16, parent | ring in steps/s
+# (PERF.md section 6, PR 47): a table of 256, 0.98 MB a hop, 170.4 | 160.1
+# (sixteen dots over 32 columns each and twelve permutes whose latency
+# nothing hides: +0.38 ms on a 5.87 ms step); of 1,024, 3.93 MB, 121.3 |
+# 128.6; of 2,048, 7.86 MB, 86.6 | 96.2; of 4,096, 15.7 MB, 54.8 | 64.8.
+# The bound lies between the first two; a table of 512 (1.97 MB) was not
+# read and stays with the partitioner.
+RING_MIN_HOP_BYTES = 2 * 2 ** 20
+
+
+def ring_scatters(mesh: Mesh, rows: jax.Array) -> bool:
+    """Whether the gradient of the split ``rows [E, U_pad, 3H]`` goes round
+    the ring of :func:`project_split_rows` or is left to the partitioner:
+    by the bytes of one hop (:data:`RING_MIN_HOP_BYTES`).  For a caller
+    that has asked :func:`carried_rows_split`."""
+    hop = rows.size * rows.dtype.itemsize // mesh.shape["expert"] // (
+        2 * mesh.shape["data"])
+    return hop >= RING_MIN_HOP_BYTES
+
+
+def project_split_rows(mesh: Mesh, x: jax.Array, rows: jax.Array
+                       ) -> jax.Array:
+    """``einsum("btf,efg->etbg")`` of the windows ``x [B, T, U_pad]`` (split
+    over ``data`` along B) with the folded, cast ``rows [E, U_pad, 3H]``
+    that are split over ``data`` along U_pad (:func:`carried_rows_split`).
+
+    Forward: the rows pinned whole (:func:`pin_folded_rows`, the
+    partitioner's all-gather) and the einsum.  Backward, with respect to
+    the rows: the partitioner's dot and reduce-scatter one after the other
+    where the table is narrow, and where :func:`ring_scatters` says so a
+    ring (:func:`_ring_rows_gradient`) whose links work while its dots
+    do."""
+    project = _ring_project if ring_scatters(mesh, rows) else _project_pinned
+    return project(mesh, x, rows)
+
+
+def _project_pinned(mesh, x, rows):
+    return jnp.einsum("btf,efg->etbg", x, pin_folded_rows(mesh, rows))
+
+
+_ring_project = jax.custom_vjp(_project_pinned, nondiff_argnums=(0,))
+
+
+def _ring_project_fwd(mesh, x, rows):
+    return _ring_project(mesh, x, rows), (x, rows)
+
+
+def _ring_project_bwd(mesh, residuals, dxw):
+    x, rows = residuals
+    # nothing differentiates with respect to the windows, and this dot goes
+    # with its cotangent; it is here so that whoever does gets the truth
+    dx = jnp.einsum("etbg,efg->btf", dxw, pin_folded_rows(mesh, rows),
+                    preferred_element_type=jnp.float32).astype(x.dtype)
+    return dx, _ring_rows_gradient(mesh, x, dxw.astype(rows.dtype))
+
+
+_ring_project.defvjp(_ring_project_fwd, _ring_project_bwd)
+
+
+def _ring_rows_gradient(mesh: Mesh, x: jax.Array, dxw: jax.Array
+                        ) -> jax.Array:
+    """``einsum("btf,etbg->efg")`` summed over ``data`` and left split over
+    it along f: ``[E, U_pad, 3H]`` as ``P("expert", "data", None)``, in
+    ``dxw``'s dtype.
+
+    The collective matmul of Wang et al. (ASPLOS '23) as a reduce-scatter:
+    the rows are cut into ``data`` chunks; a chip makes its partial sum of
+    one chunk (a dot over its own windows, accumulated in float32), sends
+    it to its neighbour on :func:`~deeprest_tpu.parallel.mesh.data_ring`
+    and adds its partial of the chunk that arrives to it, in float32,
+    rounded once for the wire.  After ``data - 1`` hops every chunk has
+    every chip's partial and lies on the chip that owns it.  Each chunk's
+    rows go round in two halves, one each way, so both directions of every
+    link carry.  XLA fuses a hop's addition into its dot, which therefore
+    waits for its arrival: what a transfer hides behind is the dots of the
+    other half and of the layer's other direction, whose ring the
+    scheduler interleaves with this one (tests/test_chip_compile_dp4.py
+    holds every permute to a dot between its start and its done; with the
+    dots kept apart from the additions the step was 4% slower on the
+    chips: PERF.md section 6, PR 47)."""
+    n = mesh.shape["data"]
+    ring = np.asarray(data_ring(mesh))
+    up = [(int(ring[p]), int(ring[(p + 1) % n])) for p in range(n)]
+    down = [(j, i) for i, j in up]
+    # the chunk a chip works on at stage s, by the chip's index on `data`:
+    # the one that stage n-1 leaves at its owner, s hops on of where it began
+    place, stage = np.argsort(ring)[None, :], np.arange(n)[:, None]
+    going_up, going_down = ring[(place - 1 - stage) % n], ring[
+        (place + 1 + stage) % n]
+
+    def ring_of_dots(x, dxw):
+        chunk = x.shape[2] // n
+        half = chunk // 2
+        me = jax.lax.axis_index("data")
+
+        def chunk_dot(owners, lo, dxw):
+            cols = jax.lax.dynamic_slice_in_dim(
+                x, jnp.asarray(owners)[me] * chunk + lo, half, axis=2)
+            return jnp.einsum("btf,etbg->efg", cols, dxw,
+                              preferred_element_type=jnp.float32)
+
+        sums = [chunk_dot(going_up[0], 0, dxw).astype(dxw.dtype),
+                chunk_dot(going_down[0], half, dxw).astype(dxw.dtype)]
+        for s in range(1, n):
+            # a stage's dots wait for the sums it sends: left free, the
+            # scheduler makes every dot first and the permutes stand alone
+            # after them
+            sums, held = jax.lax.optimization_barrier((sums, dxw))
+            arrived = [jax.lax.ppermute(sums[0], "data", up),
+                       jax.lax.ppermute(sums[1], "data", down)]
+            mine = [chunk_dot(going_up[s], 0, held),
+                    chunk_dot(going_down[s], half, held)]
+            sums = [(a.astype(jnp.float32) + m).astype(dxw.dtype)
+                    for a, m in zip(arrived, mine)]
+        return jnp.concatenate(sums, axis=1)
+
+    return jax.shard_map(
+        ring_of_dots, mesh=mesh,
+        in_specs=(P("data", None, None), P("expert", None, "data", None)),
+        out_specs=P("expert", "data", None), check_vma=False)(x, dxw)
 
 
 def leaf_path_name(path: Sequence[Any]) -> str:

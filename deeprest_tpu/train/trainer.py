@@ -468,7 +468,9 @@ class Trainer:
             # bfloat16 folded weight for the projection, gets that
             # weight's bfloat16 gradient back reduce-scattered (the same
             # sum of the same addends an all-reduce makes, element by
-            # element) and runs the fold's backward and Adam on its rows.
+            # element; at a wide table round a ring of chunk dots,
+            # sharding.project_split_rows, and not by the partitioner's
+            # collective) and runs the fold's backward and Adam on its rows.
             # After the scan the six arrays are gathered once, so what is
             # put into the donated leaves, and the state the dispatch
             # returns, is whole and the same on every chip.  There is one

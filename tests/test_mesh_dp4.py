@@ -145,10 +145,23 @@ def runs():
     return _runs(HOT["narrow"], (4, 1))
 
 
+def _ring_at_any_width(patch):
+    """`tenk-train-live4k-dp4`'s side of `sharding.ring_scatters` at this
+    file's toy widths: the w_ih gradients go round the ring (ISSUE 47)."""
+    from deeprest_tpu.parallel import sharding
+
+    patch.setattr(sharding, "RING_MIN_HOP_BYTES", 0)
+
+
 @pytest.fixture(scope="module")
 def widest():
-    """The four-device compile at the rule's bound, once for its cases."""
-    return _runs(HOT["widest"], (4,))
+    """The four-device compile at the rule's bound, once for its cases; as
+    on the chips at that table, the weight gradients go round the ring
+    (`runs`, the narrow table, leaves them to the partitioner, as
+    `tenk-train-dp4` does)."""
+    with pytest.MonkeyPatch.context() as patch:
+        _ring_at_any_width(patch)
+        return _runs(HOT["widest"], (4,))
 
 
 def _runs_at(request, table):
@@ -177,15 +190,18 @@ def test_the_widest_table_is_the_rules_bound(widest):
     assert F // 4 < live <= F // 2
 
 
-def test_the_widest_table_under_data4_is_recorded_as_it_ran(widest):
+def test_the_widest_table_under_data4_is_recorded_as_it_ran(widest,
+                                                            monkeypatch):
     """What ISSUE 44 reads on the chips for `tenk-train-live4k-dp4`, at this
     size: the `train.stage` span says the compact form at the bound, the
     epoch's span says the mesh, the collectives' gauge equals the dispatched
     program's own bytes and holds the w_ih gradients at the TABLE's rows
-    (sixteen times `runs`' table here), and the two gauges of the compact
-    feed read the table's width on every chip's behalf."""
+    (sixteen times `runs`' table here; since ISSUE 47 as the ring's
+    permutes), and the two gauges of the compact feed read the table's width
+    on every chip's behalf."""
     from test_obs_layers import _recorded
 
+    _ring_at_any_width(monkeypatch)
     _, trainer, state, bundle, _, rest = widest[4]
     staged = []
     spans = _recorded(lambda: staged.append(trainer.stage_dataset(bundle)))
@@ -208,7 +224,10 @@ def test_the_widest_table_under_data4_is_recorded_as_it_ran(widest):
                                    len(trainer._last_epoch_losses))
     width = F // 2
     rows = 4 * 2 * E * width * 3 * H
-    assert rows < read["all-reduce"] < rows + 4 * E * H * F
+    # since ISSUE 47 they go round the ring: three of a chip's four quarters
+    # arrive by permute, and what is all-reduced is what any table reduces
+    assert read["collective-permute"] == rows * 3 // 4
+    assert read["all-reduce"] < rows
     cols = REGISTRY.get("deeprest_train_projection_columns")
     adam = REGISTRY.get("deeprest_train_optimizer_rows")
     assert cols.value(kind="contracted") == width == adam.value(
@@ -474,6 +493,28 @@ ENTRY %main (a: f32[4,4,8]) -> f32[4,16,8] {
 """
 
 
+# ISSUE 47: a stage of the ring as XLA:TPU schedules it for a described
+# v5e:2x2 (toy shapes): the two halves' permutes start, the stage's two chunk
+# dots run as fusions of their own, the permutes are done, and the additions
+# round what arrived for the next hop.
+_RING_HLO = """HloModule jit_train_superstep
+
+%body (p: (bf16[4,2,8])) -> (bf16[4,2,8]) {
+  %collective-permute-start.7 = (bf16[4,2,8]{2,1,0}, bf16[4,2,8]{2,1,0}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%sum.up), channel_id=5, source_target_pairs={{0,2},{2,3},{3,1},{1,0}}, metadata={op_name="jit(train_superstep)/while/body/transpose(jvp(QuantileGRU))/in_proj/shard_map/ppermute"}
+  %collective-permute-start.6 = (bf16[4,2,8]{2,1,0}, bf16[4,2,8]{2,1,0}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%sum.down), channel_id=6, source_target_pairs={{2,0},{3,2},{1,3},{0,1}}, metadata={op_name="jit(train_superstep)/while/body/transpose(jvp(QuantileGRU))/in_proj/shard_map/ppermute"}
+  %convolution_bitcast_fusion.10 = f32[4,2,8]{2,1,0} fusion(%x, %d), kind=kOutput, calls=%fused_dot, metadata={op_name="jit(train_superstep)/while/body/transpose(jvp(QuantileGRU))/in_proj/shard_map/btf,etbg->efg/dot_general"}
+  %convolution_bitcast_fusion.11 = f32[4,2,8]{2,1,0} fusion(%x, %d), kind=kOutput, calls=%fused_dot.1, metadata={op_name="jit(train_superstep)/while/body/transpose(jvp(QuantileGRU))/in_proj/shard_map/btf,etbg->efg/dot_general"}
+  %collective-permute-done.7 = bf16[4,2,8]{2,1,0} collective-permute-done(%collective-permute-start.7), metadata={op_name="jit(train_superstep)/while/body/transpose(jvp(QuantileGRU))/in_proj/shard_map/ppermute"}
+  %collective-permute-done.6 = bf16[4,2,8]{2,1,0} collective-permute-done(%collective-permute-start.6), metadata={op_name="jit(train_superstep)/while/body/transpose(jvp(QuantileGRU))/in_proj/shard_map/ppermute"}
+  %add_convert_fusion.4 = bf16[4,2,8]{2,1,0} fusion(%collective-permute-done.7, %convolution_bitcast_fusion.10), kind=kLoop, calls=%fused_add, metadata={op_name="jit(train_superstep)/while/body/transpose(jvp(QuantileGRU))/in_proj/shard_map/convert_element_type"}
+}
+
+ENTRY %main (a: bf16[4,2,8]) -> bf16[4,2,8] {
+  %while.1 = (bf16[4,2,8]{2,1,0}) while(%t), condition=%cond, body=%body
+}
+"""
+
+
 @pytest.mark.parametrize("text, steps, expected, row", [
     # the fusion's result, a chip's slice, as the reduce-scatter it is; the
     # all-reduce inside it is no all-reduce of the step
@@ -487,8 +528,13 @@ ENTRY %main (a: f32[4,4,8]) -> f32[4,16,8] {
     (_DISPATCH_HLO, 8, {"all-gather": 2 * 4 * 16 * 8 * 4 // 8,
                         "all-reduce": 4},
      ["all-gather.47", "all-gather.48", "all-reduce.8"]),
+    # the ring's permutes, each pair once by its done; its dots are no row's
+    # of the collectives
+    (_RING_HLO, 1, {"collective-permute": 2 * 4 * 2 * 8 * 2},
+     ["collective-permute-done.6", "collective-permute-done.7",
+      "collective-permute-start.6", "collective-permute-start.7"]),
 ], ids=["all_reduce_scatter_fusion", "chained_asynchronous_gather",
-        "gathers_outside_the_loop"])
+        "gathers_outside_the_loop", "ring_of_chunk_dots"])
 def test_collective_bytes_sees_xla_tpus_forms(text, steps, expected, row):
     assert profiler.collective_bytes(text, steps) == expected
     table = profiler.scope_table(text, ("in_proj", "recurrence"))
@@ -496,6 +542,9 @@ def test_collective_bytes_sees_xla_tpus_forms(text, steps, expected, row):
                   if v == (profiler.COLLECTIVE, "-")) == row
     if "fusion.187" in table:
         assert table["fusion.187"] == ("in_proj", "fwd")
+    for name in table:
+        if name.startswith(("convolution_bitcast_fusion", "add_convert")):
+            assert table[name] == ("in_proj", "bwd")
     # a trace's event finds the row by its name where the map has it, and
     # the asynchronous fusions by their names alone
     for name in row:
@@ -669,6 +718,40 @@ def test_collective_time_and_its_exposed_part(events, whole, alone):
     if whole != 400:
         assert rows.get(profiler.COLLECTIVE, 0.0) == pytest.approx(
             alone / 1e9)
+
+
+def test_a_permute_pair_costs_the_row_what_the_chip_waited_for_it():
+    """`Trainer.profile_epoch`'s `collective` row on a stage of ISSUE 47's
+    ring, as a chip's operation line draws it: a permute's start is a short
+    event, its done lasts as long as the chip waited for the arrival, and
+    the chunk dots between them are `in_proj`'s backward through the
+    compiled step's map.  The row holds the starts and the waits and none
+    of the dots' time; the benchmark's reader sees the pairs from start to
+    done and the same exposed part."""
+    table = profiler.scope_table(_RING_HLO, ("in_proj", "recurrence"))
+    events = [
+        ("%collective-permute-start.7 = (bf16[4,2,8]) "
+         "collective-permute-start(%sum.up)", 0, 4),
+        ("%collective-permute-start.6 = (bf16[4,2,8]) "
+         "collective-permute-start(%sum.down)", 4, 4),
+        ("%convolution_bitcast_fusion.10 = f32[4,2,8] fusion(%x, %d)",
+         8, 200),
+        ("%convolution_bitcast_fusion.11 = f32[4,2,8] fusion(%x, %d)",
+         208, 200),
+        ("%collective-permute-done.7 = bf16[4,2,8] "
+         "collective-permute-done(%collective-permute-start.7)", 408, 60),
+        ("%collective-permute-done.6 = bf16[4,2,8] "
+         "collective-permute-done(%collective-permute-start.6)", 468, 2),
+        ("%add_convert_fusion.4 = bf16[4,2,8] fusion(%a, %b)", 470, 30),
+    ]
+    ours = profiler.layer_table_of(_line(*events), scopes=table)
+    rows = {(r["scope"], r["pass"]): r["seconds"] for r in ours["rows"]}
+    assert rows[(profiler.COLLECTIVE, "-")] == pytest.approx(70 / 1e9)
+    assert rows[("in_proj", "bwd")] == pytest.approx(430 / 1e9)
+    assert sum(rows.values()) == pytest.approx(ours["busy_s"]) == 500 / 1e9
+    found = collectives.reduce_planes(_line(*events))
+    assert found["collective_s"] == pytest.approx(470 / 1e9)
+    assert found["exposed_s"] == pytest.approx(70 / 1e9)
 
 
 def test_readers_find_nothing_where_there_is_nothing():
