@@ -1535,7 +1535,8 @@ def build_parser() -> argparse.ArgumentParser:
                    help="gradient accumulation on the staged superstep "
                         "path: G consecutive microbatches each run "
                         "forward and backward, one optimizer update per "
-                        "G on their summed grads; requires the "
+                        "G on the gradient of their mean loss over the "
+                        "group's real windows; requires the "
                         "device-resident feed (--device-data always on "
                         "CPU); 1 = per-step updates (default)")
     p.add_argument("--snapshot-every-steps", type=int, default=0,
@@ -1620,7 +1621,8 @@ def build_parser() -> argparse.ArgumentParser:
                    help="gradient accumulation on the staged superstep "
                         "path: G consecutive microbatches each run "
                         "forward and backward, one optimizer update per "
-                        "G on their summed grads; requires the "
+                        "G on the gradient of their mean loss over the "
+                        "group's real windows; requires the "
                         "device-resident feed (--device-data always on "
                         "CPU); 1 = per-step updates (default)")
     p.add_argument("--snapshot-every-steps", type=int, default=0,

@@ -115,16 +115,27 @@ class TrainConfig:
     # Ignored when the dataset is not staged (host-feed fallback keeps the
     # per-step loop).
     steps_per_superstep: int | str = "auto"
-    # Gradient accumulation on the staged superstep path: G consecutive
-    # plan steps (microbatches) each run the shared step's forward and
-    # backward, their gradients are summed in microbatch order and the
-    # optimizer updates once per G (dropout key fold_in(step_key, g)).
-    # 1 = one update a step (default).  Requires the staged
-    # (device-resident) feed; per-microbatch losses keep their meaning and
-    # the step counter still counts real microbatches.  No benchmark cell
-    # accumulates: the recurrence kernels it once meant to fatten are 11%
-    # of the step in both cells and bound by their HBM traffic, not by MXU
-    # row occupancy (PERF.md section 5).
+    # Gradient accumulation in THE superstep (train/trainer.py
+    # train_update): G consecutive plan steps (microbatches) each run the
+    # shared step's forward and backward under their own kept mask
+    # (dropout key fold_in(fold_in(rng, step), g)), their gradients,
+    # weighted by each microbatch's share of the group's real windows, are
+    # added in microbatch order, and the optimizer updates ONCE on that
+    # sum: plain Adam on the gradient of the mean loss over all real
+    # windows of the group, what one batch of G x batch_size windows
+    # gives (a team with one chip makes a pod's update so: 8 x 32 = the
+    # 256 windows of the v5e-8 layout the brief names).  On a compact
+    # sparse base the accumulator is the table's carried rows and the
+    # other leaves, and Adam runs on the table's rows as at G = 1.
+    # 1 = one update a step (default; the trace of before the knob).
+    # Requires the staged (device-resident) feed; per-microbatch losses
+    # keep their meaning, the step counter counts real microbatches and
+    # Adam's own count counts updates.  The benchmark's cell
+    # `tenk-train-accum8` (G = 8) runs it: what accumulation amortises is
+    # the optimizer's pass over the large leaves (the [E, H, F] mask
+    # weights' Adam, 40% of a 10k step at G = 1, runs once in G), not the
+    # recurrence kernels, which are 17% of that step and see the same B
+    # rows a call whatever G is (PERF.md sections 5 and 6, PR 48).
     grad_accum_windows: int = 1
     # Sparse-first traffic feed (the 10k-endpoint tier, ROADMAP item 4):
     # traffic rows travel host→device as padded-COO ``(cols[K], vals[K])``

@@ -35,12 +35,12 @@ of ``w_ih`` (:func:`take_columns`), folds and projects ``[B, T, U_pad] x
 softmax still runs over all F and every parameter keeps its shape.  A
 caller that differentiates with respect to ``w_ih`` gets the take's
 transpose: a dense gradient that is zero at the columns left out (the
-per-step programs, the accumulation supersteps).  One that hands in the
+per-step programs).  One that hands in the
 taken rows itself (``live_w_ih``; the compact superstep, which takes them
 once a dispatch and carries them through its scan, PR 32) gets their
-gradient as ``[E, U_pad, 3H]`` and no dense one, and runs Adam on those
-rows alone (``train/trainer.py``).  Without ``live_cols`` (every dense
-feed, serving) the call is what it was.
+gradient as ``[E, U_pad, 3H]`` and no dense one, accumulates it so, and
+runs Adam on those rows alone (``train/trainer.py``).  Without
+``live_cols`` (every dense feed, serving) the call is what it was.
 """
 
 from __future__ import annotations

@@ -52,6 +52,7 @@ INIT_STATE_SECONDS = "deeprest_train_init_state_seconds"
 STAGE_SECONDS = "deeprest_train_last_stage_seconds"
 STAGINGS = "deeprest_train_stagings_total"
 OPTIMIZER_ROWS = "deeprest_train_optimizer_rows"
+ACCUMULATION = "deeprest_train_accumulation"
 PROJECTION_COLUMNS = "deeprest_train_projection_columns"
 FIRST_DISPATCH_SECONDS = "deeprest_train_first_dispatch_seconds"
 DEVICE_BYTES = "deeprest_train_device_bytes"
@@ -202,7 +203,10 @@ def setup_table() -> dict:
     process that was (``nth``), of each first dispatch; what the last
     epoch's off-table pass found and did (``off_table``: the ``stale``
     rows, the ``bound`` up to which they are visited row by row, the
-    ``trips`` of a dispatch); the compilations
+    ``trips`` of a dispatch); under gradient accumulation the
+    ``microbatches`` of an optimizer update and the ``carry_bytes`` of its
+    gradient accumulator (``accumulation``; left out with one microbatch an
+    update); the compilations
     by program and phase (count, seconds, misses); device memory at the
     three moments; the superstep executable's bytes, where its kernels'
     operands live, how many arrays a step reverses in time round them
@@ -226,6 +230,7 @@ def setup_table() -> dict:
     reversals = _series(TIME_REVERSALS)
     edge_passes = _series(KERNEL_EDGE_PASSES)
     rows = _by(OPTIMIZER_ROWS, "kind")
+    accumulation = {k: int(v) for k, v in _by(ACCUMULATION, "kind").items()}
     columns = {k: int(v) for k, v in _by(PROJECTION_COLUMNS, "kind").items()}
     feed = None
     if columns.get("total"):            # a sparse corpus was staged
@@ -238,6 +243,8 @@ def setup_table() -> dict:
         "nth": int(stagings[0][1]) if stagings else None,
         "off_table": {k: int(rows[k]) for k in ("stale", "bound", "trips")
                       if k in rows},
+        "accumulation": (accumulation
+                         if accumulation.get("microbatches", 1) > 1 else None),
         "first_dispatch_seconds": _by(FIRST_DISPATCH_SECONDS, "program"),
         "compilations": sorted(compilations.values(),
                                key=lambda r: -r["seconds"]),
@@ -277,6 +284,10 @@ def format_setup(table: dict) -> str:
     if "off_table" in table:
         parts.append("off the table " + ", ".join(
             f"{k} {v}" for k, v in table["off_table"].items()))
+    if "accumulation" in table:
+        found = table["accumulation"]
+        parts.append(f"accumulation {found['microbatches']} microbatches an "
+                     f"update, carry {found.get('carry_bytes', 0) / 1e6:.1f} MB")
     if "first_dispatch_seconds" in table:
         parts.append("first dispatch "
                      + seconds(table["first_dispatch_seconds"]) + " s")
@@ -310,6 +321,6 @@ def format_setup(table: dict) -> str:
 __all__ = ["OTHER", "UNCACHED", "phase", "current_phase", "install",
            "setup_table", "format_setup", "COMPILATIONS", "COMPILE_SECONDS",
            "INIT_STATE_SECONDS", "STAGE_SECONDS", "STAGINGS",
-           "OPTIMIZER_ROWS", "PROJECTION_COLUMNS",
+           "OPTIMIZER_ROWS", "ACCUMULATION", "PROJECTION_COLUMNS",
            "FIRST_DISPATCH_SECONDS", "DEVICE_BYTES", "PROGRAM_BYTES",
            "KERNEL_OPERAND_BYTES", "TIME_REVERSALS", "KERNEL_EDGE_PASSES"]

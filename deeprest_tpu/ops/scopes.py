@@ -21,12 +21,15 @@ DROPOUT = "dropout"         # the step's key (trainer); the mask's ONE draw, a
 MIXING = "mixing"           # cross-expert mixing (models/qrnn.py)
 HEADS = "heads"             # the quantile heads (models/qrnn.py)
 LOSS = "loss"               # ops/quantile.py
-OPTIMIZER = "optimizer"     # tx.update + apply_updates (train/trainer.py)
+ACCUMULATE = "accumulate"   # a microbatch's gradient added into the update's
+                            # accumulator (grad_accum_windows > 1; trainer)
+OPTIMIZER = "optimizer"     # tx.update + apply_updates, once an UPDATE
+                            # (train/trainer.py)
 OFF_TABLE = "off_table"     # the compact superstep's zero-gradient Adam pass
                             # over the stale rows of the w_ih leaves, by
                             # chunks or, past a bound, whole (train/trainer.py)
 STEP_SCOPES = (GATHER, DENSIFY, MASK, IN_PROJ, RECURRENCE, DROPOUT, MIXING,
-               HEADS, LOSS, OPTIMIZER, OFF_TABLE)
+               HEADS, LOSS, ACCUMULATE, OPTIMIZER, OFF_TABLE)
 
 # Not gru_fwd/gru_bwd: those are the two DIRECTIONS' parameter leaves.
 GRU_KERNEL_FWD = "gru_kernel_fwd"   # the forward pass's kernel

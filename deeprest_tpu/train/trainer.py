@@ -369,6 +369,19 @@ class Trainer:
         def dropout_key(state: TrainState):
             return jax.random.fold_in(state.rng, state.step)
 
+        def microbatch_loss(params, dropout_rng, xb, yb, wb, live_cols, w_ih,
+                            allow_empty=False):
+            # One forward pass on one batch of B windows under one kept
+            # mask; `params` holds the table's rows of the `w_ih` leaves
+            # in their place where `w_ih` is given (see train_step).
+            preds = self.model.apply(
+                {"params": {**params, **w_ih}}, xb, deterministic=False,
+                rngs={"dropout": dropout_rng}, live_cols=live_cols,
+                live_w_ih={k: params[k] for k in w_ih} or None,
+            )
+            return pinball_loss(preds, yb, quantiles, sample_weight=wb,
+                                allow_empty=allow_empty)
+
         def train_step(state: TrainState, xb, yb, wb, live_cols=None,
                        full_w_ih=None):
             # With `full_w_ih` (the compact superstep, with live_cols): the
@@ -381,18 +394,9 @@ class Trainer:
             # supplied leaf to its init shape).  Under a `data` axis that
             # divides them the rows are a chip's own quarter (see
             # train_superstep), and stay so in the state this returns.
-            dropout_rng = dropout_key(state)
             w_ih = full_w_ih or {}
-
-            def loss_fn(params):
-                preds = self.model.apply(
-                    {"params": {**params, **w_ih}}, xb, deterministic=False,
-                    rngs={"dropout": dropout_rng}, live_cols=live_cols,
-                    live_w_ih={k: params[k] for k in w_ih} or None,
-                )
-                return pinball_loss(preds, yb, quantiles, sample_weight=wb)
-
-            loss, grads = jax.value_and_grad(loss_fn)(state.params)
+            loss, grads = jax.value_and_grad(microbatch_loss)(
+                state.params, dropout_key(state), xb, yb, wb, live_cols, w_ih)
             params, opt_state = apply_gradients(state, grads)
             return (
                 pin_state(TrainState(step=state.step + 1, params=params,
@@ -431,6 +435,77 @@ class Trainer:
             return train_step(state, *gather_windows(x_base, y_base, starts),
                               wb, live_cols_of(x_base), full_w_ih)
 
+        # G = grad_accum_windows microbatches to one optimizer update, a
+        # static of the programs built here.  At G = 1 a step IS an update
+        # and every program below traces as it did before the knob existed.
+        accum_g = int(self.config.train.grad_accum_windows)
+
+        def train_update(state: TrainState, x_base, y_base, starts, wb,
+                         full_w_ih=None):
+            # One optimizer update from G microbatches (starts, wb: [G, B]):
+            # plain Adam on the gradient of the weighted mean loss over ALL
+            # real windows of the group, what one batch of G x B windows
+            # gives.  Each microbatch gathers its windows, draws its own
+            # kept mask (fold_in(fold_in(rng, step), g)) and differentiates
+            # with respect to `state.params` exactly as train_step does (on
+            # a compact base the carried [E, U_pad, 3H] rows and the other
+            # leaves), its loss weighted by its share n_g / N of the
+            # group's real windows, and adds into an accumulator of the
+            # params' shapes; tx.update runs ONCE.  The microbatches are a
+            # lax.scan UNROLLED G times: the program grows with G (at G = 8
+            # on a v5e 54 MB of code for 18, 2.87 GB of temporaries for
+            # 1.10, 20 s to compile for 9), and XLA then computes what
+            # depends on the parameters alone (the mask's softmax over F,
+            # the fold of the carried rows) once an update and not once a
+            # microbatch: 307.4 steps/s for the rolled loop's 222.6 in
+            # `tenk-train-accum8` (PERF.md section 6, PR 48).  A
+            # zero-weight pad microbatch inside a real group has share 0
+            # and adds exactly nothing (pinball_loss allow_empty guards its
+            # 0/0); a group with no real window is skipped whole by the
+            # caller.  `state.step` counts the REAL microbatches (the
+            # dropout stream's and the resume cursor's unit); Adam's count
+            # counts updates.
+            live_cols = live_cols_of(x_base)
+            w_ih = full_w_ih or {}
+            carried = bool(w_ih) and split_rows(live_cols)
+            step_key = dropout_key(state)
+            per_micro = jnp.sum(wb, axis=1)
+            share = per_micro / jnp.sum(per_micro)               # [G]
+
+            def micro(acc, plan):
+                g, starts_g, wb_g, share_g = plan
+                xb, yb = gather_windows(x_base, y_base, starts_g)
+                with jax.named_scope(scopes.DROPOUT):
+                    rng_g = jax.random.fold_in(step_key, g)
+
+                def weighted(params):
+                    loss = microbatch_loss(params, rng_g, xb, yb, wb_g,
+                                           live_cols, w_ih, allow_empty=True)
+                    return loss * share_g.astype(loss.dtype), loss
+
+                (_, loss), grads = jax.value_and_grad(
+                    weighted, has_aux=True)(state.params)
+                with jax.named_scope(scopes.ACCUMULATE):
+                    acc = jax.tree.map(jnp.add, acc, grads)
+                return acc, loss.astype(jnp.float32)
+
+            with jax.named_scope(scopes.ACCUMULATE):
+                zeros = jax.tree.map(
+                    jax.lax.with_sharding_constraint,
+                    jax.tree.map(jnp.zeros_like, state.params),
+                    state_sharding(self.mesh, state, carried).params)
+            grads, losses = jax.lax.scan(
+                micro, zeros, (jnp.arange(accum_g), starts, wb, share),
+                unroll=accum_g)
+            params, opt_state = apply_gradients(state, grads)
+            n_real = jnp.sum((per_micro > 0).astype(jnp.int32))
+            return (
+                pin_state(TrainState(step=state.step + n_real, params=params,
+                                     opt_state=opt_state, rng=state.rng),
+                          carried_rows=carried),
+                losses,
+            )
+
         def train_superstep(state: TrainState, x_base, y_base,
                             starts_plan, weights_plan, chunk):
             # One donated dispatch = S train steps via lax.scan.  The
@@ -447,10 +522,21 @@ class Trainer:
             # the bit-exactness contract with the per-step loop; the cond
             # sub-computation preserves the standalone step's rounding
             # (verified by tests/test_superstep.py).
+            #
+            # With G > 1 microbatches an update the chunk [S, B] is
+            # [S/G, G, B] (the planner rounds S up to a multiple of G), the
+            # scan advances one UPDATE a trip (train_update) and a group
+            # with no real window takes the skip branch as a padded step
+            # does; everything below (the carried rows, their split over
+            # `data`, the off-table pass) is the same code for both.
             starts_c = jax.lax.dynamic_index_in_dim(
                 starts_plan, chunk, 0, keepdims=False)       # [S, B]
             weights_c = jax.lax.dynamic_index_in_dim(
                 weights_plan, chunk, 0, keepdims=False)      # [S, B]
+            if accum_g > 1:
+                grouped = (-1, accum_g, starts_c.shape[1])
+                starts_c = starts_c.reshape(grouped)         # [S/G, G, B]
+                weights_c = weights_c.reshape(grouped)
 
             # A compact base's gradient lives on its table's rows of the
             # w_ih leaves, and Adam is elementwise.  So the table's rows of
@@ -487,14 +573,15 @@ class Trainer:
                 starts, wb = step_plan
 
                 def run(s):
-                    s2, loss = train_step_indexed(s, x_base, y_base,
-                                                  starts, wb, full_w_ih)
+                    s2, loss = (train_update if accum_g > 1
+                                else train_step_indexed)(
+                        s, x_base, y_base, starts, wb, full_w_ih)
                     # f32 losses regardless of compute dtype so the skip
                     # branch's zero matches the run branch's aval.
                     return s2, loss.astype(jnp.float32)
 
                 def skip(s):
-                    return s, jnp.zeros((), jnp.float32)
+                    return s, jnp.zeros(wb.shape[:-1], jnp.float32)
 
                 return jax.lax.cond(jnp.any(wb > 0), run, skip, st)
 
@@ -503,6 +590,8 @@ class Trainer:
             if split:
                 carry = pin_state(carry, carried_rows=True)
             rows, losses = jax.lax.scan(body, carry, (starts_c, weights_c))
+            if accum_g > 1:
+                losses = losses.reshape(-1)                  # [S] f32
             if not full_w_ih:
                 return rows, losses
             if split:
@@ -529,7 +618,10 @@ class Trainer:
             # cache).
             rows_ok = moments_off_table_are_zero(state.opt_state, live_cols)
             whole = only_w_ih(state.params), only_w_ih(state.opt_state)
-            real_steps = rows.step - state.step
+            # The updates the scan ran: at G = 1 a real step is one; under
+            # accumulation Adam's own count says (a step is a microbatch).
+            real_steps = (rows.step - state.step if accum_g == 1 else
+                          rows.opt_state[0].count - state.opt_state[0].count)
             bound = off_table_bound(x_base.capacity, live_cols.shape[0],
                                     starts_c.shape[0])
             stale, count = stale_chunks(
@@ -568,82 +660,6 @@ class Trainer:
                                    self.mesh),
             )), losses
 
-        # -- gradient accumulation -------------------------------------
-        #
-        # G consecutive plan steps (microbatches) each run the shared
-        # step's forward and backward; the optimizer update applies once
-        # per G with grads summed in microbatch order.  Zero-weight pad
-        # microbatches contribute exactly-zero grads (pinball_loss
-        # allow_empty guards the 0/0) so partially-padded trailing groups
-        # need no per-microbatch cond; a fully-padded group takes the
-        # update-level cond skip.  The step counter keeps counting REAL
-        # microbatches, and the per-update dropout key is
-        # fold_in(rng, step)-then-fold_in(·, g) — a stream of its own
-        # (grad accumulation is a different training algorithm, not
-        # pinned against G=1).
-        accum_g = int(self.config.train.grad_accum_windows)
-
-        def _accum_grads(params, x_base, y_base, starts, wb, step_key):
-            losses, total = [], None
-            for g in range(accum_g):
-                xb, yb = gather_windows(x_base, y_base, starts[g])
-
-                def loss_fn(params, g=g, xb=xb, yb=yb):
-                    preds = self.model.apply(
-                        {"params": params}, xb, deterministic=False,
-                        rngs={"dropout": jax.random.fold_in(step_key, g)},
-                        live_cols=live_cols_of(x_base))
-                    return pinball_loss(preds, yb, quantiles,
-                                        sample_weight=wb[g], allow_empty=True)
-
-                lg, gg = jax.value_and_grad(loss_fn)(params)
-                losses.append(lg)
-                total = gg if total is None else jax.tree.map(jnp.add,
-                                                              total, gg)
-            return jnp.stack(losses).astype(jnp.float32), total
-
-        def train_accum_update(state: TrainState, x_base, y_base, starts, wb):
-            """One optimizer update from G microbatches.
-            starts/wb: [G, B]."""
-            losses, grads = _accum_grads(state.params, x_base, y_base,
-                                         starts, wb, dropout_key(state))
-            params, opt_state = apply_gradients(state, grads)
-            n_real = jnp.sum(jnp.any(wb > 0, axis=1).astype(jnp.int32))
-            return (
-                pin_state(TrainState(step=state.step + n_real, params=params,
-                                     opt_state=opt_state, rng=state.rng)),
-                losses,
-            )
-
-        def train_accum_superstep(state: TrainState, x_base, y_base,
-                                  starts_plan, weights_plan, chunk):
-            # The G>1 twin of train_superstep: the [S, B] chunk reshapes
-            # to [S/G, G, B] (the epoch planner guarantees S % G == 0) and
-            # the scan advances one UPDATE (G microbatches) per step.
-            # Fully-padded groups take the cond skip — prior state passes
-            # through untouched, exactly like padded steps at G=1.
-            starts_c = jax.lax.dynamic_index_in_dim(
-                starts_plan, chunk, 0, keepdims=False)       # [S, B]
-            weights_c = jax.lax.dynamic_index_in_dim(
-                weights_plan, chunk, 0, keepdims=False)      # [S, B]
-            s, b = starts_c.shape
-            starts_c = starts_c.reshape(s // accum_g, accum_g, b)
-            weights_c = weights_c.reshape(s // accum_g, accum_g, b)
-
-            def body(st, update_plan):
-                starts, wb = update_plan
-
-                def run(s):
-                    return train_accum_update(s, x_base, y_base, starts, wb)
-
-                def skip(s):
-                    return s, jnp.zeros((accum_g,), jnp.float32)
-
-                return jax.lax.cond(jnp.any(wb > 0), run, skip, st)
-
-            state, losses = jax.lax.scan(body, state, (starts_c, weights_c))
-            return state, losses.reshape(-1)                 # [S] f32
-
         def eval_step(params, xb, yb, live_cols=None):
             preds = self.model.apply({"params": params}, xb,
                                      deterministic=True, live_cols=live_cols)
@@ -658,7 +674,6 @@ class Trainer:
         self._train_step_indexed = jax.jit(train_step_indexed, donate_argnums=0)
         self._superstep = jax.jit(train_superstep, donate_argnums=0)
         self._stale_rows = jax.jit(stale_rows)
-        self._accum_superstep = jax.jit(train_accum_superstep, donate_argnums=0)
         self._eval_step = jax.jit(eval_step)
         self._eval_step_indexed = jax.jit(eval_step_indexed)
         self._predict_step = jax.jit(
@@ -674,8 +689,8 @@ class Trainer:
     def _jitted(self) -> tuple:
         """The trainer's jitted programs."""
         return (self._train_step, self._train_step_indexed, self._superstep,
-                self._accum_superstep, self._stale_rows, self._eval_step,
-                self._eval_step_indexed, self._predict_step, self._pin_state)
+                self._stale_rows, self._eval_step, self._eval_step_indexed,
+                self._predict_step, self._pin_state)
 
     def _build_metrics(self) -> None:
         # Training-plane obs metrics (process-wide registry singletons —
@@ -721,6 +736,17 @@ class Trainer:
             "ONE chip ran a step (the table's width over the mesh's data "
             "axis where the compact superstep splits its carried rows over "
             "it, else updated)",
+            labelnames=("kind",))
+        self._m_updates = obs_metrics.REGISTRY.counter(
+            "deeprest_train_optimizer_updates_total",
+            "optimizer updates (Adam steps) the finished train epochs ran: "
+            "one a real step, one a group of grad_accum_windows microbatches")
+        self._m_accumulation = obs_metrics.REGISTRY.gauge(
+            obs_setup.ACCUMULATION,
+            "gradient accumulation of the dispatched superstep: "
+            "microbatches an optimizer update (grad_accum_windows), and "
+            "carry_bytes, the gradient accumulator an update carries across "
+            "them (the shapes its scan carries; 0 with one microbatch)",
             labelnames=("kind",))
         self._m_stagings = obs_metrics.REGISTRY.counter(
             obs_setup.STAGINGS, "stage_dataset calls of this process")
@@ -847,11 +873,13 @@ class Trainer:
         self._m_device_bytes.set(fullest.get("peak_bytes_in_use", 0),
                                  at=at, kind="peak")
 
-    def _publish_program(self, state, steps: int = 1) -> None:
+    def _publish_program(self, state, steps: int = 1, x_base=None) -> None:
         """What the compiler made of the superstep this epoch dispatched
-        (``steps``: the trips of its scan),
-        once for each build of the programs, from the executable the
-        dispatch left in the jit's cache (:meth:`_dispatched_executable`:
+        (``steps``: the trips of its scan, updates under accumulation),
+        once for each build of the programs; first, from the shapes alone,
+        ``deeprest_train_accumulation{kind}`` (:meth:`_accum_carry_bytes`
+        on the base ``x_base`` the dispatch read); then, from the executable
+        the dispatch left in the jit's cache (:meth:`_dispatched_executable`:
         no second compile): its ``memory_analysis`` into
         ``deeprest_train_program_bytes{kind}``; from its text, parsed
         once, where each pallas kernel's operands and results live
@@ -875,6 +903,10 @@ class Trainer:
         from deeprest_tpu.obs import profiler
 
         self._program_published = True
+        self._m_accumulation.set(self.config.train.grad_accum_windows,
+                                 kind="microbatches")
+        self._m_accumulation.set(self._accum_carry_bytes(state, x_base),
+                                 kind="carry_bytes")
         if not hasattr(self._dispatched[0], "lower"):
             # a caller's stand-in round the jitted program (the
             # benchmark's rehearsals wrap `_superstep` in a plain
@@ -905,19 +937,35 @@ class Trainer:
         self._m_kernel_edge_passes.set(len(profiler.kernel_edge_passes(
             text, scopes.RECURRENCE, scopes.GRU_KERNEL_BWD)))
 
+    def _accum_carry_bytes(self, state, x_base) -> int:
+        """The bytes of the gradient accumulator an update of the superstep
+        carries across its microbatches: one array a leaf of the params the
+        scan carries, which on a compact base are the table's rows of the
+        w_ih leaves (:func:`take_w_ih`) and no ``[E, F, 3H]`` array.
+        Nothing is carried with one microbatch an update."""
+        if self.config.train.grad_accum_windows == 1:
+            return 0
+        params, live = state.params, live_cols_of(x_base)
+        if live is not None and w_ih_leaves(params):
+            params = jax.eval_shape(
+                lambda tree: take_w_ih(tree, live, self.mesh), params)
+        return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+
     def _publish_optimizer_rows(self, x_base, stale=None, steps=0) -> None:
         """The epoch's ``deeprest_train_optimizer_rows``, for a staged
         sparse corpus.  ``stale`` is :func:`stale_rows` of the state the
         epoch began with on the base's table (a device scalar, read here,
         after the epoch; the superstep keeps it as it finds it), ``steps``
-        the steps of a dispatch.  With no stale row the step is Adam on
+        the optimizer updates of a dispatch (its steps, or its groups of
+        microbatches under accumulation: the table is read the same way
+        for both).  With no stale row the step is Adam on
         the table's rows: ``updated`` and ``visited`` are its width.  With
         one, it is Adam over all F (``updated``), of which the dispatches
         wrote :func:`rows_visited` (``visited``) in
         :func:`off_table_trips` trips of the off-table pass (``trips``),
         row by row up to :func:`off_table_bound` (``bound``).  ``stale``
-        None where no table was consulted (the per-step and accumulation
-        paths, a base in its dense form): every step ran over and wrote
+        None where no table was consulted (the per-step path, a base in
+        its dense form): every step ran over and wrote
         all F rows, and ``stale``, ``bound`` and ``trips`` are left as
         they were.  ``per_chip``: the rows whose Adam one chip ran a step,
         the carried rows' share of the table under a ``data`` axis
@@ -1497,13 +1545,16 @@ class Trainer:
 
         The epoch's host work is timed by phase (:data:`EPOCH_PHASES`,
         obs/phases.py): one ``train.epoch`` span, tagged with the mesh
-        ``DxExM``, with a child per phase when the recorder is on, the
-        phase-seconds counters always.  What compiles in it is counted in
+        ``DxExM`` and ``accum`` (the microbatches an optimizer update,
+        ``grad_accum_windows``), with a child per phase when the recorder
+        is on, the phase-seconds counters always.  What compiles in it is counted in
         the set-up phase ``epoch`` (after the first epoch: a recompile);
         device memory after the first one to finish is
         ``deeprest_train_device_bytes{at="first_epoch"}``."""
         with obs_setup.phase("epoch"), \
-                self._epoch_clock.unit({"mesh": self._mesh_tag}) as phase:
+                self._epoch_clock.unit({
+                    "mesh": self._mesh_tag,
+                    "accum": self.config.train.grad_accum_windows}) as phase:
             out = self._train_epoch(state, bundle, epoch_rng, staged,
                                     skip_steps, on_step, phase)
         if not self._epoch_finished:
@@ -1628,6 +1679,7 @@ class Trainer:
             jax.block_until_ready(state.params)
         if measuring:
             self.throughput.stop(steps)
+        self._m_updates.inc(len(losses))         # a step is an update here
         if staged is not None:
             self._publish_optimizer_rows(staged[0])
         # One stacked host readback for the epoch mean instead of a
@@ -1681,28 +1733,26 @@ class Trainer:
         skip_chunks = skip_steps // s
         with phase("plan_h2d"):
             starts_d, weights_d = stage_plan(self.mesh, starts, weights)
-        # The accumulation superstep and the per-step superstep
-        # share the whole driver: only the compiled scan differs.
-        superstep = (self._accum_superstep if cfg.grad_accum_windows > 1
-                     else self._superstep)
+        superstep = self._superstep
         # What the compact superstep's rule reads in each dispatch, as a
         # count, on the state this epoch begins with: dispatched here, read
         # after the epoch for the gauge, waited on nowhere in between.
         stale = None
-        if (superstep is self._superstep and live_cols_of(x_base) is not None
-                and w_ih_leaves(state.params)):
+        if live_cols_of(x_base) is not None and w_ih_leaves(state.params):
             with self._first_dispatch(self._stale_rows):
                 stale = self._stale_rows(state.opt_state, x_base.live)
         measuring = self._warmed
         if measuring:
             self.throughput.start()
         chunk_losses = []
-        steps = 0
+        steps = updates = 0
+        accum = cfg.grad_accum_windows
         with phase("dispatch"), contextlib.ExitStack() as first:
             if not self._warmed:
                 first.enter_context(self._first_dispatch(superstep))
             for c in range(skip_chunks, starts.shape[0]):
                 real = min(s, num_steps - c * s)
+                updates += -(-real // accum)     # a ragged group is one
                 state, losses_c = superstep(state, x_base, y_base,
                                             starts_d, weights_d, c)
                 # Mid-superstep (and mid-grad-accum-group) device loss: the
@@ -1738,13 +1788,14 @@ class Trainer:
                                   f"loss {vals[gs - prev - 1]:.6f}")
                 self._note_steps(state, bundle, real, on_step)
         self._m_dispatches.inc(starts.shape[0] - skip_chunks)
+        self._m_updates.inc(updates)
         # what this epoch dispatched, for profile_epoch to lower again
         self._dispatched = (superstep,
                             (x_base, y_base, starts_d, weights_d, 0))
         if not self._program_published:
             # the host reads the executable while the device runs the
             # epoch's last chunk
-            self._publish_program(state, s // cfg.grad_accum_windows)
+            self._publish_program(state, s // accum, x_base)
         with phase("device_wait"):
             jax.block_until_ready(state.params)
         if measuring:
@@ -1756,7 +1807,7 @@ class Trainer:
         with phase("loss_readback"):
             epoch_losses = np.asarray(
                 jnp.concatenate(chunk_losses))[:num_steps - skip_steps]
-            self._publish_optimizer_rows(x_base, stale, s)
+            self._publish_optimizer_rows(x_base, stale, s // accum)
         self._last_epoch_losses = epoch_losses
         return state, float(np.mean(epoch_losses, dtype=np.float64))
 

@@ -308,13 +308,16 @@ def _train_step_lowered(mesh, feature_dim, sparse, accum=1,
     if accum == 1 and not superstep:
         args = (base, y_base, sds((B,), jnp.int32), sds((B,), jnp.float32))
         return trainer._train_step_indexed.lower(state_sds, *_on(mesh, args))
+    # a chunk holds whole updates: 50 steps are 56 with 8 microbatches an
+    # update, as Trainer._superstep_len rounds them
     plan = ((2, accum, batch) if not superstep
-            else (3, 50, batch) if mesh.size == 1 else (1, 32, batch))
+            else (3, -(-50 // accum) * accum, batch) if mesh.size == 1
+            else (1, 32, batch))
     plans = _on(mesh, (sds(plan, jnp.int32), sds(plan, jnp.float32)),
                 P(None, None, "data"))
-    program = trainer._superstep if superstep else trainer._accum_superstep
-    return program.lower(state_sds, *_on(mesh, (base, y_base)), *plans,
-                         *_on(mesh, (sds((), jnp.int32),)))
+    return trainer._superstep.lower(
+        state_sds, *_on(mesh, (base, y_base)), *plans,
+        *_on(mesh, (sds((), jnp.int32),)))
 
 
 def _whole_leaf_copies(text: str) -> int:
@@ -334,7 +337,8 @@ def _assert_masks_drawn_once(text: str, passes: int = 1, experts: int = E,
     ``pred`` arrays, a byte an element, of the joined output's ``E*B*W*2H``
     elements, which the forward select and the ``heads`` cotangent's read
     (the parent: the threefry in both their fusions, and no mask in
-    memory).  The accumulation superstep's ``passes`` masks, one a
+    memory).  Under accumulation the text holds ``passes`` forward passes
+    (the microbatches' scan is unrolled), and their masks, one a
     microbatch from its own key, may come out of one fusion as siblings.
     ``rows``: the windows one chip sees."""
     from deeprest_tpu.obs import profiler
@@ -363,9 +367,9 @@ def _assert_masks_drawn_once(text: str, passes: int = 1, experts: int = E,
 def test_train_step_fits_the_chip(one_chip, feature_dim, sparse, accum):
     """Forward, backward and Adam in one program, state donated: the
     kernel is in it and arguments + temporaries fit 16 GB of HBM.  With
-    ``accum`` 4 it is the accumulation superstep instead: four passes an
-    update.  The compact form never builds a window or a folded weight F
-    wide."""
+    ``accum`` 4 it is the superstep of four microbatches an update instead
+    (ISSUE 48; their scan unrolled, so four passes an update in the text).
+    The compact form never builds a window or a folded weight F wide."""
     compiled = _train_step_lowered(one_chip, feature_dim, sparse,
                                    accum).compile()
     assert _kernel_calls(compiled) == 4 * accum
@@ -618,6 +622,48 @@ def test_compact_superstep_updates_the_leaves_in_place(compact_superstep):
     assert mem.temp_size_in_bytes < 1.0e9, mem
     assert _need(mem) < 5.5e9, mem
     assert mem.generated_code_size_in_bytes <= 20e6, mem
+
+
+def test_compact_superstep_accumulates_on_the_tables_rows(
+        one_chip, compact_superstep):
+    """`tenk-train-accum8`'s program (ISSUE 48): the compact 10k superstep
+    at 8 microbatches an update, a 3 x 56 plan, compiled for the described
+    v5e.  The microbatches' scan is unrolled (the rolled loop read 222.6
+    steps/s on the chip for 307.4: PERF.md section 6, PR 48), so the text
+    holds no ``while`` more than at one microbatch, 32 kernel calls and 8
+    masks.  The accumulator is the table's rows and the other leaves: no
+    ``[E, F, 3H]`` array is named that the superstep of one microbatch does
+    not name (the leaves themselves, taken from and put to once a
+    dispatch), no whole leaf is copied, and the mask weights' ``[E, H, F]``
+    leaf is passed over by as many fusions as at one microbatch: the sum of
+    the eight microbatches' gradients of it is made inside Adam's fusion
+    (the twin this replaced: two ``[E, F, 3H]`` float32 gradients a
+    microbatch and 2.93 GB of temporaries at a plan of two updates)."""
+    compiled = _train_step_lowered(one_chip, F_10K, "compact", accum=8,
+                                   superstep=True).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    print(f"compact 10k superstep at 8 microbatches an update for a "
+          f"described v5e: temporaries {mem.temp_size_in_bytes / 1e9:.3f} "
+          f"GB, needs {_need(mem) / 1e9:.3f} GB, code "
+          f"{mem.generated_code_size_in_bytes / 1e6:.1f} MB")
+    one = compact_superstep.as_text()
+    assert _kernel_calls(compiled) == 4 * 8
+    assert len(re.findall(r" while[(]", text)) == 10
+    _assert_masks_drawn_once(text, passes=8)
+    leaf = f"f32[{E},{F_10K},{3 * H}]"
+    assert text.count(leaf) == one.count(leaf)
+    assert _whole_leaf_copies(text) == 0
+
+    def passes_over_the_mask_weights(hlo: str) -> int:
+        """Fusions with a result of the mask weights' shape."""
+        return sum(f"f32[{E},{H},{F_10K}]" in line.split(" fusion(")[0]
+                   for line in hlo.splitlines() if " fusion(" in line)
+
+    assert 0 < passes_over_the_mask_weights(text) <= \
+        passes_over_the_mask_weights(one)
+    assert mem.temp_size_in_bytes < 3.0e9, mem
+    assert _need(mem) < 7.5e9 < HBM_BYTES / 2, mem
+    assert mem.generated_code_size_in_bytes <= 60e6, mem
 
 
 def test_compact_superstep_names_where_its_kernels_operands_live(

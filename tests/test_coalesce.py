@@ -1,7 +1,7 @@
 """Row folds and gradient accumulation: the recurrence over G·B rows
 against G calls of B rows (the serving page fold relies on rows being
-independent), the accumulation superstep against one update on the summed
-per-microbatch gradients, the VMEM block-plan re-validation at fat row
+independent), the superstep under accumulation against one update on the
+gradient of the mean loss over the group's real windows, the VMEM block-plan re-validation at fat row
 counts, serve-side page coalescing vs the pinned host reference, and the
 no-recompile probes.
 
@@ -190,10 +190,15 @@ def test_block_plan_matches_kernel_execution():
 
 @pytest.mark.parametrize("g", [2, 4])
 def test_accum_update_is_one_update_on_the_summed_gradients(bundle, g):
-    """One accumulated update == the optimizer applied once to the sum, in
-    microbatch order, of G per-microbatch gradients taken at the same
-    parameters, dropout on with key fold_in(fold_in(rng, step), g); a
-    zero-weight pad microbatch adds nothing and is not counted."""
+    """One accumulated update == the optimizer applied once to the gradient
+    of the MEAN loss over the group's real windows: the sum, in microbatch
+    order, of G per-microbatch gradients taken at the same parameters, each
+    weighted by its share n_g / N of the group's real windows (what one
+    batch of G x B windows gives; until ISSUE 48 the program summed the
+    microbatches' mean-loss gradients unweighted, G times the mean), dropout
+    on with key fold_in(fold_in(rng, step), g); a zero-weight pad microbatch
+    adds nothing and is not counted, and a ragged one counts by its real
+    windows."""
     from deeprest_tpu.ops.quantile import pinball_loss
     from deeprest_tpu.parallel.distributed import stage_plan
 
@@ -205,6 +210,9 @@ def test_accum_update_is_one_update_on_the_summed_gradients(bundle, g):
         0, bundle.num_train_windows, (1, g, b)).astype(np.int32)
     weights = np.ones((1, g, b), np.float32)
     weights[0, -1] = 0.0                         # the last microbatch is pad
+    if g > 2:
+        weights[0, -2, b // 4:] = 0.0            # the one before it ragged
+    share = weights[0].sum(axis=1) / weights[0].sum()
     params0 = jax.tree.map(np.asarray, state.params)
     opt0, rng0, step0 = state.opt_state, state.rng, int(state.step)
     key = jax.random.fold_in(rng0, step0)
@@ -214,48 +222,62 @@ def test_accum_update_is_one_update_on_the_summed_gradients(bundle, g):
         preds = t.model.apply(
             {"params": params}, x_base[idx], deterministic=False,
             rngs={"dropout": jax.random.fold_in(key, i)})
-        return pinball_loss(preds, y_base[idx], SMALL.model.quantiles,
+        loss = pinball_loss(preds, y_base[idx], SMALL.model.quantiles,
                             sample_weight=jnp.asarray(weights[0, i]),
                             allow_empty=True)
+        return loss * share[i], loss
 
     losses, total = [], None
     for i in range(g):
-        loss, grads = jax.jit(jax.value_and_grad(micro), static_argnums=1)(
-            params0, i)
+        (_, loss), grads = jax.jit(
+            jax.value_and_grad(micro, has_aux=True), static_argnums=1)(
+                params0, i)
         losses.append(float(loss))
         total = grads if total is None else jax.tree.map(jnp.add, total, grads)
     updates, _ = t.tx.update(total, jax.tree.map(jnp.asarray, opt0))
     want = jax.tree.map(lambda p, u: p + u, params0, updates)
 
-    got, got_losses = t._accum_superstep(
+    got, got_losses = t._superstep(
         state, *staged, *stage_plan(t.mesh, starts, weights), 0)
     assert_fold_equal(got_losses, np.asarray(losses, np.float32))
     assert losses[-1] == 0.0
     for a, r in zip(jax.tree.leaves(got.params), jax.tree.leaves(want)):
         assert_fold_equal(a, r)
     assert int(got.step) == step0 + g - 1        # REAL microbatches only
+    assert int(got.opt_state[0].count) == 1      # ONE update
 
 
 def test_accum_g1_config_uses_historical_superstep(bundle):
-    """grad_accum_windows=1 (the default) must route through the EXISTING
-    superstep — the G>1 machinery is never silently entered — and match
-    the per-step loop bit-for-bit exactly as before."""
+    """There is ONE superstep since ISSUE 48, and grad_accum_windows=1 (the
+    default) IS it: G is a static of its trace, at 1 nothing of the G>1
+    machinery is traced (no ``accumulate`` scope, no inner loop over
+    microbatches), and it matches the per-step loop bit for bit exactly as
+    before."""
+    from deeprest_tpu.ops import scopes
+
     t1 = trainer_with(bundle, grad_accum_windows=1, steps_per_superstep=3)
     t_step = trainer_with(bundle, steps_per_superstep=1)
+    assert not hasattr(t1, "_accum_superstep")
     s1, _ = run_epochs(t1, bundle, epochs=2)
     s_step, _ = run_epochs(t_step, bundle, epochs=2)
     assert_states_bit_equal(s1, s_step)
+    state = t1.init_state(bundle.x_train, seed=3)
+    assert scopes.ACCUMULATE not in t1._dispatched_program_text(state)
+    t2 = trainer_with(bundle, grad_accum_windows=2, steps_per_superstep=4)
+    s2, _ = run_epochs(t2, bundle, epochs=1)
+    assert scopes.ACCUMULATE in t2._dispatched_program_text(
+        t2.init_state(bundle.x_train, seed=3))
 
 
 def test_accum_one_executable_across_epochs(bundle):
     """The no-recompile probe at G>1: epochs of chunks — full and ragged,
-    fresh epoch plans — reuse ONE accum-superstep executable."""
+    fresh epoch plans — reuse ONE superstep executable."""
     t = trainer_with(bundle, grad_accum_windows=2, steps_per_superstep=4)
     staged = t.stage_dataset(bundle)
     state = t.init_state(bundle.x_train, seed=3)
     rng = np.random.default_rng(7)
     state, _ = t.train_epoch(state, bundle, rng, staged=staged)
-    probe = getattr(t._accum_superstep, "_cache_size", None)
+    probe = getattr(t._superstep, "_cache_size", None)
     if not callable(probe):
         pytest.skip("jax version exposes no jit cache probe")
     assert probe() == 1

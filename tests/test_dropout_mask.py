@@ -164,7 +164,7 @@ def test_accumulation_draws_one_mask_a_microbatch(bundle, request):
             0, bundle.num_train_windows,
             (1, g, TRAIN.batch_size)).astype(np.int32)
         plan = stage_plan(t.mesh, starts, np.ones(starts.shape, np.float32))
-        return t._accum_superstep(state, *staged, *plan, 0)
+        return t._superstep(state, *staged, *plan, 0)
 
     got = update()
     request.getfixturevalue("flax_dropout")

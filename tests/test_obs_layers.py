@@ -32,8 +32,11 @@ RECORDED = os.path.join(REPO, "chipbench", "tests", "data",
                         "recorded_v5e.xplane.pb")
 BATCH, LOG_EVERY, SUPERSTEP = 8, 4, 3
 NAMES = frozenset(scopes.STEP_SCOPES + scopes.KERNELS)
-# a superstep on a base without a compact table has no off-table pass
-NO_TABLE = tuple(s for s in scopes.STEP_SCOPES if s != scopes.OFF_TABLE)
+# a superstep on a base without a compact table has no off-table pass, and
+# one of one microbatch an update accumulates nothing (tests/test_accum8.py
+# holds `accumulate` to the superstep of several)
+NO_TABLE = tuple(s for s in scopes.STEP_SCOPES
+                 if s not in (scopes.OFF_TABLE, scopes.ACCUMULATE))
 
 
 def _staged_trainer(featurize: FeaturizeConfig):
@@ -844,6 +847,9 @@ def test_first_epoch_sets_the_kernel_edge_passes_gauge(tiny):
 # `null` under `per_layer`.  What only a chip or a mesh sets (memory
 # statistics, the compiler's memory spaces, collectives) has no row here.
 _BENCHMARK_SERIES = [
+    ("deeprest_train_accumulation", ("kind",),
+     [{"kind": k} for k in ("microbatches", "carry_bytes")]),
+    ("deeprest_train_optimizer_updates_total", (), [{}]),
     ("deeprest_compilations_total", ("program", "phase"),
      [{"program": "train_superstep", "phase": "first_dispatch"}]),
     ("deeprest_compile_seconds_total", ("program", "phase"),
