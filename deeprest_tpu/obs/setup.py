@@ -53,6 +53,7 @@ STAGE_SECONDS = "deeprest_train_last_stage_seconds"
 STAGINGS = "deeprest_train_stagings_total"
 OPTIMIZER_ROWS = "deeprest_train_optimizer_rows"
 ACCUMULATION = "deeprest_train_accumulation"
+GATHER_PIECES = "deeprest_train_projection_gather_pieces"
 PROJECTION_COLUMNS = "deeprest_train_projection_columns"
 FIRST_DISPATCH_SECONDS = "deeprest_train_first_dispatch_seconds"
 DEVICE_BYTES = "deeprest_train_device_bytes"
@@ -321,6 +322,7 @@ def format_setup(table: dict) -> str:
 __all__ = ["OTHER", "UNCACHED", "phase", "current_phase", "install",
            "setup_table", "format_setup", "COMPILATIONS", "COMPILE_SECONDS",
            "INIT_STATE_SECONDS", "STAGE_SECONDS", "STAGINGS",
-           "OPTIMIZER_ROWS", "ACCUMULATION", "PROJECTION_COLUMNS",
+           "OPTIMIZER_ROWS", "ACCUMULATION", "GATHER_PIECES",
+           "PROJECTION_COLUMNS",
            "FIRST_DISPATCH_SECONDS", "DEVICE_BYTES", "PROGRAM_BYTES",
            "KERNEL_OPERAND_BYTES", "TIME_REVERSALS", "KERNEL_EDGE_PASSES"]

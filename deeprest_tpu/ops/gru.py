@@ -119,9 +119,9 @@ def _gru_scan(
     # folded into w_ih by the caller instead — see models/qrnn.py).
     eq = "btf,efg->tebg" if x.ndim == 3 else "ebtf,efg->tebg"
     with jax.named_scope(scopes.IN_PROJ):
-        xw = (jnp.einsum(eq, x, params.w_ih) if project is None
-              else jnp.moveaxis(project(x, params.w_ih), 1, 0))
-        proj = xw + params.b_ih[:, None, :]
+        proj = (jnp.einsum(eq, x, params.w_ih) + params.b_ih[:, None, :]
+                if project is None else
+                jnp.moveaxis(project(x, params.w_ih, params.b_ih), 1, 0))
 
     def step(h, xproj):
         # xproj: [E,B,3H]; h: [E,B,H]
@@ -154,22 +154,28 @@ def _project(params: GRUParams, x: jax.Array, project=None) -> jax.Array:
     kernel's I/O dtype, WITHOUT ``b_ih``: the add is the first operation
     inside the recurrence's VJP (pallas_gru.gru_recurrence), whose
     backward kernels return the bias's gradient.  XLA fuses the add into
-    this dot either way.  ``project``: see :func:`gru`."""
+    this dot either way.  ``project`` (see :func:`gru`) makes the product
+    in pieces, so it adds the bias itself, to each: here under
+    ``stop_gradient``, since the bias's gradient still comes from the
+    kernels (``gru_recurrence(..., biased=True)``)."""
     eq = "btf,efg->etbg" if x.ndim == 3 else "ebtf,efg->etbg"
     with jax.named_scope(scopes.IN_PROJ):
         xw = (jnp.einsum(eq, x, params.w_ih) if project is None
-              else project(x, params.w_ih))
+              else project(x, params.w_ih,
+                           jax.lax.stop_gradient(params.b_ih)))
         return xw.astype(_kernel_io_dtype(
             jnp.result_type(xw.dtype, params.b_ih.dtype)))
 
 
-def _recur_local(directions, interpret: bool, reverses: tuple[bool, ...]):
+def _recur_local(directions, interpret: bool, reverses: tuple[bool, ...],
+                 biased: bool = False):
     """The layer's kernel calls on the arrays one device holds.
 
     ``directions``: a direction each, ``(xw [E, T, B, 3H], b_ih [E, 3H],
     w_hh [E, H, 3H], b_hh [E, 3H], h0 [E, B, H])``, time-aligned with the
     input whichever way it scans (``reverses``: the order in which the
-    kernels visit the time axis).  Returns the directions' states joined
+    kernels visit the time axis; ``biased``: ``xw`` came with ``b_ih`` in
+    it).  Returns the directions' states joined
     on the last axis, ``[E, T, B, n*H]``.  Shape hygiene for the kernels'
     tiling happens per device, inside the call."""
     from deeprest_tpu.ops import pallas_gru
@@ -181,11 +187,11 @@ def _recur_local(directions, interpret: bool, reverses: tuple[bool, ...]):
         (xw, b_ih, w_hh.astype(xw.dtype), b_hh.astype(jnp.float32),
          h0.astype(jnp.float32))
         for xw, b_ih, w_hh, b_hh, h0 in directions)
-    return pallas_gru.gru_recurrence(directions, interpret, reverses)
+    return pallas_gru.gru_recurrence(directions, interpret, reverses, biased)
 
 
 def _recurrence(directions, interpret: bool, reverses: tuple[bool, ...],
-                mesh):
+                mesh, biased: bool = False):
     """:func:`_recur_local`, under ONE ``shard_map`` a layer when ``mesh``
     has more than one device.
 
@@ -197,7 +203,7 @@ def _recurrence(directions, interpret: bool, reverses: tuple[bool, ...],
     bias gradients over its own rows, and ``shard_map``'s transpose sums
     the weight and bias cotangents over ``data``."""
     if mesh is None or mesh.size == 1:
-        return _recur_local(directions, interpret, reverses)
+        return _recur_local(directions, interpret, reverses, biased)
     from jax.sharding import PartitionSpec as P
 
     n_data, n_expert = mesh.shape["data"], mesh.shape["expert"]
@@ -217,7 +223,7 @@ def _recurrence(directions, interpret: bool, reverses: tuple[bool, ...],
     direction = (rows, P("expert", None), P("expert", None, None),
                  P("expert", None), P("expert", "data", None))
     out = jax.shard_map(
-        lambda d: _recur_local(d, interpret, reverses), mesh=mesh,
+        lambda d: _recur_local(d, interpret, reverses, biased), mesh=mesh,
         in_specs=((direction,) * len(directions),), out_specs=rows,
         check_vma=False,
     )(directions)
@@ -257,7 +263,7 @@ def _layer_pallas(
     with jax.named_scope(scopes.RECURRENCE):
         out = _recurrence(operands, interpret,
                           tuple(reverse for _, _, reverse in directions),
-                          mesh)
+                          mesh, biased=project is not None)
         return jnp.moveaxis(out, 1, 2).astype(x.dtype)      # [E,B,T,n*H]
 
 
@@ -291,9 +297,10 @@ def gru(
           ``shard_map`` over ``data`` and ``expert``; the scan ignores it
           (GSPMD partitions it from the operands' shardings).
       project: the input projection of windows ``x [B, T, F]`` where it is
-          not the plain einsum: ``(x, w_ih) -> [E, T, B, 3H]``, the same
-          values with a backward of its own (the carried rows split over
-          ``data``: parallel/sharding.project_split_rows).
+          not the plain einsum and add: ``(x, w_ih, b_ih) -> [E, T, B,
+          3H]``, the same values made in pieces and with a backward of its
+          own (the carried rows split over ``data``:
+          parallel/sharding.project_split_rows).
 
     Returns: ``[E, B, T, H]`` hidden states.
     """

@@ -515,15 +515,18 @@ def _pads(e: int, t: int, b: int, dtype, reverse: bool):
             (0, pad_batch(b, dtype) - b))
 
 
-def _kernel_operands(direction, reverse: bool):
+def _kernel_operands(direction, reverse: bool, biased: bool):
     """(proj, w_hh, b_hh, h0) as one direction's kernels take them: the
     input bias added to the projection's einsum (XLA fuses the add and the
-    cast into the dot that makes ``xw``), every array padded (:func:`_pads`;
-    nothing at a shape the blocks divide)."""
+    cast into the dot that makes ``xw``) unless ``xw`` came with it
+    (``biased``), every array padded (:func:`_pads`; nothing at a shape the
+    blocks divide)."""
     xw, b_ih, w_hh, b_hh, h0 = direction
     e, t, b, _ = xw.shape
-    with jax.named_scope(scopes.IN_PROJ):
-        proj = (xw + b_ih[:, None, None, :]).astype(xw.dtype)
+    proj = xw
+    if not biased:
+        with jax.named_scope(scopes.IN_PROJ):
+            proj = (xw + b_ih[:, None, None, :]).astype(xw.dtype)
     pad_e, pad_t, pad_b = _pads(e, t, b, proj.dtype, reverse)
     none = (0, 0)
     return (jnp.pad(proj, (pad_e, pad_t, pad_b, none)),
@@ -537,8 +540,9 @@ def _unpadded(h_all, e: int, t: int, b: int, reverse: bool):
     return h_all[:e, t0:t0 + t, :b]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
-def gru_recurrence(directions, interpret=False, reverses=(False,)):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
+def gru_recurrence(directions, interpret=False, reverses=(False,),
+                   biased=False):
     """Run the GRU time recurrence of ONE layer over its pre-projected
     inputs: every direction of it, joined on the last axis.
 
@@ -580,23 +584,30 @@ def gru_recurrence(directions, interpret=False, reverses=(False,)):
         the block now lies in array order, the reverse of scan order, so
         the same float32 sum is associated in another order inside the dot
         (about 1e-7 of the leaf's largest magnitude).
+      biased: every ``xw`` came WITH its bias (static): the caller's
+        projection added ``b_ih`` where it made the product, in pieces that
+        an add over the whole array here could not be fused into
+        (parallel/sharding.project_split_rows), and under
+        ``stop_gradient``, because the bias's gradient still leaves by this
+        VJP, from the backward kernels' gate gradients.
 
     Returns: ``[E, T, B, n*H]`` hidden states, direction ``d``'s in lanes
     ``[d*H, (d+1)*H)`` — f32 for f32 models, bf16 for bf16 models
     (_out_dtype_for: the model casts to its own dtype right after the
     kernel anyway, and f32 storage doubled the largest stream).
     """
-    return _joined_fwd(directions, interpret, reverses, emit_prev=False)[0]
+    return _joined_fwd(directions, interpret, reverses, biased,
+                       emit_prev=False)[0]
 
 
-def _joined_fwd(directions, interpret, reverses, emit_prev):
+def _joined_fwd(directions, interpret, reverses, biased, emit_prev):
     """The forward kernel of each direction and the join; with
     ``emit_prev`` the training forward, which also returns each direction's
     residuals."""
     outs, residuals = [], []
     for direction, reverse in zip(directions, reverses, strict=True):
         e, t, b, _ = direction[0].shape
-        proj, w_hh, b_hh, h0 = _kernel_operands(direction, reverse)
+        proj, w_hh, b_hh, h0 = _kernel_operands(direction, reverse, biased)
         out = _fwd_call(proj, w_hh, b_hh, h0, interpret, emit_prev=emit_prev,
                         reverse=reverse)
         if emit_prev:
@@ -609,7 +620,7 @@ def _joined_fwd(directions, interpret, reverses, emit_prev):
     return jnp.concatenate(outs, axis=-1), tuple(residuals)
 
 
-def _vjp_fwd(directions, interpret, reverses):
+def _vjp_fwd(directions, interpret, reverses, biased):
     # Training forward streams h_prev out of the kernel directly — the
     # backward consumes it without the concat(h0, h_all[:-1]) round-trip,
     # and h_all itself is NOT a residual (the recompute needs only
@@ -619,10 +630,11 @@ def _vjp_fwd(directions, interpret, reverses):
     # backward kernel needs.  proj (with its bias) is the residual, xw is
     # none: it never leaves the dot's fusion.  The two biases and h0 ride
     # along for their dtypes (tiny next to the stashes).
-    return _joined_fwd(directions, interpret, reverses, emit_prev=True)
+    return _joined_fwd(directions, interpret, reverses, biased,
+                       emit_prev=True)
 
 
-def _vjp_bwd(interpret, reverses, residuals, dout):
+def _vjp_bwd(interpret, reverses, biased, residuals, dout):
     e, t, b, _ = dout.shape
     io_dtype = residuals[0][0].dtype
     dout = dout.astype(_out_dtype_for(io_dtype))     # the joined array, once

@@ -6,8 +6,8 @@ dimension F where it appears (the mask output and the layer-0 GRU input
 projections — the two places that grow with the endpoint vocabulary,
 SURVEY.md §7.3); everything else is replicated.  The batch shards on
 ``data``.  The cross-expert mixing sum and the gradients' reduction are
-inserted by GSPMD from these annotations; the one hand-written collective
-is the ring of the last paragraph but one.
+inserted by GSPMD from these annotations; the hand-written collectives
+are those of the last paragraph but one.
 
 Under a ``data`` axis the state is whole on every chip and every gradient
 is all-reduced, with one exception that lasts a dispatch of the compact
@@ -15,22 +15,27 @@ superstep (train/trainer.py): the table's rows of the two layer-0 w_ih
 leaves and of their moments, which ride its scan, are split over ``data``
 along the row axis (:data:`CARRIED_ROWS_RULES`).  A chip then steps its
 own rows only: the bfloat16 folded weight ``bf16(mask * w_ih)`` is
-all-gathered for the projection (:func:`pin_folded_rows`), the
-projection's bfloat16 weight gradient comes back reduce-scattered, and
-Adam runs on ``U_pad / data`` rows a chip; after the scan the six float32
-arrays are all-gathered once (pinned by the table below, as every state
-is) and put into the whole leaves, so the state a dispatch returns is
-whole again.
+all-gathered for the projection, the projection's bfloat16 weight gradient
+comes back reduce-scattered, and Adam runs on ``U_pad / data`` rows a
+chip; after the scan the six float32 arrays are all-gathered once (pinned
+by the table below, as every state is) and put into the whole leaves, so
+the state a dispatch returns is whole again.
 
-Those collectives are the partitioner's but one.  XLA:TPU makes the
-weight gradient's reduce-scatter ONE synchronous fusion that needs the
-whole product first, so at a wide table the links idle through the dot
-and the MXU through the scatter.  Where a hop carries enough
-(:func:`ring_scatters`), :func:`project_split_rows` gives the projection
-a backward of its own: the dot cut into ``data`` chunks whose partial
-sums travel round the chips by ``ppermute`` while the next chunks' dots
-run (the same sum of the same four chips' partials for every element,
-in the ring's order of addition).
+At a narrow table those collectives are the partitioner's
+(:func:`pin_folded_rows`, autodiff's transpose).  At a wide one XLA:TPU
+leaves each standing alone: the first direction's gather is synchronous in
+front of the dot that reads it, and the weight gradient's reduce-scatter is
+ONE synchronous fusion that needs the whole product first, so the links
+idle through the dots and the MXU through the transfers.  Where a hop
+carries enough (:func:`ring_scatters`), :func:`project_split_rows` writes
+both passes of the layer-0 projection itself, under ``shard_map``.
+Forward: the gather cut along the experts (:func:`gather_pieces`), a
+group's rows arriving by ``all_gather`` while the group before it is
+projected (whole dots over all the rows, nothing summed across chips or
+groups, so every element is the dot it was).  Backward: the dot cut into
+``data`` chunks of rows whose partial sums travel round the chips by
+``ppermute`` while the next chunks' dots run (the same sum of the same
+four chips' partials for every element, in the ring's order of addition).
 
 The table below (:data:`PARTITION_RULES`) is the SINGLE owner of those
 decisions: an ordered ``(regex, PartitionSpec)`` list matched against
@@ -127,50 +132,121 @@ def pin_folded_rows(mesh: Mesh, rows: jax.Array) -> jax.Array:
 RING_MIN_HOP_BYTES = 2 * 2 ** 20
 
 
+def _sent_by_a_chip(mesh: Mesh, rows: jax.Array) -> int:
+    """The bytes of one direction's split ``rows [E, U_pad, 3H]`` that one
+    chip holds, and sends when they are gathered."""
+    return rows.size * rows.dtype.itemsize // (
+        mesh.shape["expert"] * mesh.shape["data"])
+
+
 def ring_scatters(mesh: Mesh, rows: jax.Array) -> bool:
     """Whether the gradient of the split ``rows [E, U_pad, 3H]`` goes round
     the ring of :func:`project_split_rows` or is left to the partitioner:
-    by the bytes of one hop (:data:`RING_MIN_HOP_BYTES`).  For a caller
-    that has asked :func:`carried_rows_split`."""
-    hop = rows.size * rows.dtype.itemsize // mesh.shape["expert"] // (
-        2 * mesh.shape["data"])
-    return hop >= RING_MIN_HOP_BYTES
+    by the bytes of one hop (:data:`RING_MIN_HOP_BYTES`), half a chunk's
+    rows.  For a caller that has asked :func:`carried_rows_split`."""
+    return _sent_by_a_chip(mesh, rows) // 2 >= RING_MIN_HOP_BYTES
 
 
-def project_split_rows(mesh: Mesh, x: jax.Array, rows: jax.Array
-                       ) -> jax.Array:
+# Into how many groups of experts the forward's gather is cut, placed by the
+# chips' readings (a v5e 2x2 at E=40, H=128, bfloat16, parent | pieces in
+# steps/s: PERF.md section 6, PR 49).  A table of 4,096: 64.82 | 2 unread, 4
+# 66.78, 8 68.01, 10 66.02, 20 65.43: past eight pieces the chains cost more
+# than the shorter first piece saves.  A table of 2,048 in 5 pieces: 96.23 |
+# 101.36.  A table of 1,024 in 2: 128.54 | 127.00: with fewer than four
+# pieces the weight stays whole.
+GATHER_MIN_PIECES, GATHER_MAX_PIECES = 4, 8
+
+
+def gather_pieces(mesh: Mesh, rows: jax.Array) -> int:
+    """In how many pieces a direction's folded ``rows [E, U_pad, 3H]`` reach
+    the projection of :func:`project_split_rows` each step: equal groups of
+    experts, as many as divide the experts a chip holds, up to
+    :data:`GATHER_MAX_PIECES`, of which a chip still sends
+    :data:`RING_MIN_HOP_BYTES` a piece.  1, the weight gathered whole by the
+    partitioner, where that is fewer than :data:`GATHER_MIN_PIECES` or
+    :func:`ring_scatters` leaves the layer to the partitioner."""
+    if not ring_scatters(mesh, rows):
+        return 1
+    experts = rows.shape[0] // mesh.shape["expert"]
+    sent = _sent_by_a_chip(mesh, rows)
+    pieces = max(g for g in range(1, GATHER_MAX_PIECES + 1)
+                 if experts % g == 0 and sent // g >= RING_MIN_HOP_BYTES)
+    return pieces if pieces >= GATHER_MIN_PIECES else 1
+
+
+def project_split_rows(mesh: Mesh, x: jax.Array, rows: jax.Array,
+                       bias: jax.Array) -> jax.Array:
     """``einsum("btf,efg->etbg")`` of the windows ``x [B, T, U_pad]`` (split
     over ``data`` along B) with the folded, cast ``rows [E, U_pad, 3H]``
-    that are split over ``data`` along U_pad (:func:`carried_rows_split`).
+    that are split over ``data`` along U_pad (:func:`carried_rows_split`),
+    plus the input ``bias [E, 3H]``.
 
-    Forward: the rows pinned whole (:func:`pin_folded_rows`, the
-    partitioner's all-gather) and the einsum.  Backward, with respect to
-    the rows: the partitioner's dot and reduce-scatter one after the other
-    where the table is narrow, and where :func:`ring_scatters` says so a
-    ring (:func:`_ring_rows_gradient`) whose links work while its dots
-    do."""
+    Where the table is narrow: the rows pinned whole
+    (:func:`pin_folded_rows`, the partitioner's all-gather), the einsum, and
+    for the rows' gradient the partitioner's dot and reduce-scatter one
+    after the other.  Where :func:`ring_scatters` says so, both passes are
+    cut so that the links work while the MXU does: the forward by groups of
+    experts (:func:`_gather_and_project`), the backward by chunks of rows
+    round a ring (:func:`_ring_rows_gradient`)."""
     project = _ring_project if ring_scatters(mesh, rows) else _project_pinned
-    return project(mesh, x, rows)
+    return project(mesh, x, rows, bias)
 
 
-def _project_pinned(mesh, x, rows):
-    return jnp.einsum("btf,efg->etbg", x, pin_folded_rows(mesh, rows))
+def _project_pinned(mesh, x, rows, bias):
+    return (jnp.einsum("btf,efg->etbg", x, pin_folded_rows(mesh, rows))
+            + bias[:, None, None, :])
 
 
-_ring_project = jax.custom_vjp(_project_pinned, nondiff_argnums=(0,))
+def _gather_and_project(mesh: Mesh, x: jax.Array, rows: jax.Array,
+                        bias: jax.Array) -> jax.Array:
+    """:func:`_project_pinned` with the gather cut along the axis the einsum
+    neither contracts nor finds split: expert e's projection needs expert
+    e's rows only.  A chip gathers the rows of one group of experts
+    (:func:`gather_pieces`) and projects its windows against them, whole
+    dots over all U_pad rows, while the next group's rows arrive; the
+    groups' results are joined along the experts.  Nothing is summed across
+    chips or groups: every element is the dot the pinned einsum makes, and
+    the links carry the bytes they carried.  The bias is added to each
+    group's product, where XLA fuses it into the dot as it does into the
+    whole one; added to the joined array it is a pass of its own."""
+    groups = gather_pieces(mesh, rows)
+    if groups == 1:
+        return _project_pinned(mesh, x, rows, bias)
+
+    def by_groups(x, rows, bias):
+        size = rows.shape[0] // groups
+        projected = []
+        for k in range(groups):
+            group = slice(k * size, (k + 1) * size)
+            whole = jax.lax.all_gather(rows[group], "data", axis=1,
+                                       tiled=True)
+            projected.append(jnp.einsum("btf,efg->etbg", x, whole)
+                             + bias[group, None, None, :])
+        return jnp.concatenate(projected, axis=0)
+
+    return jax.shard_map(
+        by_groups, mesh=mesh,
+        in_specs=(P("data", None, None), P("expert", "data", None),
+                  P("expert", None)),
+        out_specs=P("expert", None, "data", None), check_vma=False)(
+            x, rows, bias)
 
 
-def _ring_project_fwd(mesh, x, rows):
-    return _ring_project(mesh, x, rows), (x, rows)
+_ring_project = jax.custom_vjp(_gather_and_project, nondiff_argnums=(0,))
+
+
+def _ring_project_fwd(mesh, x, rows, bias):
+    return _ring_project(mesh, x, rows, bias), (x, rows, bias)
 
 
 def _ring_project_bwd(mesh, residuals, dxw):
-    x, rows = residuals
+    x, rows, bias = residuals
     # nothing differentiates with respect to the windows, and this dot goes
     # with its cotangent; it is here so that whoever does gets the truth
     dx = jnp.einsum("etbg,efg->btf", dxw, pin_folded_rows(mesh, rows),
                     preferred_element_type=jnp.float32).astype(x.dtype)
-    return dx, _ring_rows_gradient(mesh, x, dxw.astype(rows.dtype))
+    dbias = jnp.sum(dxw, axis=(1, 2), dtype=jnp.float32).astype(bias.dtype)
+    return dx, _ring_rows_gradient(mesh, x, dxw.astype(rows.dtype)), dbias
 
 
 _ring_project.defvjp(_ring_project_fwd, _ring_project_bwd)

@@ -52,7 +52,7 @@ from deeprest_tpu.parallel.mesh import (
     AXES, NoValidMeshError, make_mesh, mesh_config_of, shrink_mesh_config,
 )
 from deeprest_tpu.parallel.sharding import (
-    carried_rows_split, shard_params, state_sharding,
+    carried_rows_split, gather_pieces, shard_params, state_sharding,
 )
 from deeprest_tpu.train.data import DatasetBundle, eval_window_indices
 from deeprest_tpu.train.metrics import Throughput, mae_report
@@ -551,7 +551,8 @@ class Trainer:
             # length of the dispatch (parallel/sharding.py owns the spec):
             # a chip takes its own U_pad / data rows from its own whole
             # leaves, and each step folds and casts them, gathers the
-            # bfloat16 folded weight for the projection, gets that
+            # bfloat16 folded weight for the projection (at a wide table
+            # by groups of experts, beside the groups' dots), gets that
             # weight's bfloat16 gradient back reduce-scattered (the same
             # sum of the same addends an all-reduce makes, element by
             # element; at a wide table round a ring of chunk dots,
@@ -737,6 +738,13 @@ class Trainer:
             "axis where the compact superstep splits its carried rows over "
             "it, else updated)",
             labelnames=("kind",))
+        self._m_gather_pieces = obs_metrics.REGISTRY.gauge(
+            obs_setup.GATHER_PIECES,
+            "pieces in which a direction's folded layer-0 input weight "
+            "reaches the projection each step where the compact superstep "
+            "splits its carried rows over the mesh's data axis: the groups "
+            "of experts gathered beside the projection's dots, 1 where the "
+            "partitioner gathers it whole (parallel/sharding.gather_pieces)")
         self._m_updates = obs_metrics.REGISTRY.counter(
             "deeprest_train_optimizer_updates_total",
             "optimizer updates (Adam steps) the finished train epochs ran: "
@@ -969,7 +977,8 @@ class Trainer:
         all F rows, and ``stale``, ``bound`` and ``trips`` are left as
         they were.  ``per_chip``: the rows whose Adam one chip ran a step,
         the carried rows' share of the table under a ``data`` axis
-        (parallel/sharding.py), else ``updated``."""
+        (parallel/sharding.py), else ``updated``; beside it, where they are
+        split, the pieces a direction's folded weight is gathered in."""
         if not isinstance(x_base, SparseBase):
             return
         updated = visited = x_base.capacity
@@ -991,6 +1000,12 @@ class Trainer:
         self._m_optimizer_rows.set(updated, kind="updated")
         self._m_optimizer_rows.set(
             x_base.width // split if split > 1 else updated, kind="per_chip")
+        if split > 1:
+            model = self.model_config
+            self._m_gather_pieces.set(gather_pieces(
+                self.mesh, jax.ShapeDtypeStruct(
+                    (model.num_metrics, x_base.width, 3 * model.hidden_size),
+                    jnp.dtype(model.compute_dtype))))
         self._m_optimizer_rows.set(visited, kind="visited")
         self._m_optimizer_rows.set(x_base.capacity, kind="total")
 

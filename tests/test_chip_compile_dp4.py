@@ -12,7 +12,9 @@ folded weight is gathered and the six float32 arrays are gathered once a
 dispatch.  Since ISSUE 47 the weight's gradient is no dot and reduce-scatter
 one after the other but a ring of chunk dots whose partial sums travel by
 `collective-permute` while the next chunk's dots run
-(`sharding.project_split_rows`).
+(`sharding.project_split_rows`).  Since ISSUE 49 the folded weight's gather
+is cut too, along the experts: a group's rows arrive while the group before
+it is projected, so of a step's gathers only the first piece stands alone.
 
 Nothing runs here: a pass is a count of bytes and of instructions, never a
 rate.  The chips' readings are PERF.md's (section 6, PRs 44 and 45).
@@ -48,6 +50,11 @@ ROWS = TABLE_4K // SPLIT            # the rows one chip carries
 # half of a chunk's rows each way round
 PERMUTES = 2 * (SPLIT - 1) * 2
 HALF = f"bf16[{E},{ROWS // 2},{3 * H}]"
+# the forward's gather of a step (ISSUE 49): a direction's folded weight in
+# eight groups of five experts, 3.9 MB from each chip a piece
+PIECES = 8
+PIECE = f"bf16[{E // PIECES},{TABLE_4K},{3 * H}]"
+GATHERED_A_STEP = 298_844_160       # the two folded weights and 6 x f32 / 32
 
 
 @pytest.fixture(scope="module")
@@ -169,6 +176,75 @@ def test_wide_table_under_data4_hides_every_permute_behind_a_dot(
                    for _, line in body)          # no partial kept apart
 
 
+def _gather_chains(text: str, body: list) -> dict:
+    """The asynchronous gathers of the loop: ``{chain_id: [(position in the
+    schedule, instruction name), ...]}`` of the fusions whose computation
+    holds a piece of the chain (XLA:TPU's start, the fusion the transfer
+    runs beside, the done)."""
+    computations, _ = profiler._instruction_lines(text)
+    chain_of = {}
+    for comp, lines in computations.items():
+        for m, line in lines:
+            found = re.search(r'chain_id="(\d+)"', line)
+            if found and m["opcode"] == "all-gather":
+                chain_of[comp] = int(found[1])
+    chains = {}
+    for at, (m, line) in enumerate(body):
+        called = profiler._CALLS.search(line)
+        if m["opcode"] == "fusion" and called and called[1] in chain_of:
+            chains.setdefault(chain_of[called[1]], []).append(
+                (at, m["name"], line))
+    return chains
+
+
+def test_wide_table_under_data4_hides_every_gather_behind_a_projection(
+        wide_table_under_data4):
+    """ISSUE 49's point: a direction's folded weight reaches the projection
+    in `PIECES` groups of experts, and every piece of a step but the first
+    is an asynchronous chain whose transfer runs INSIDE a forward dot of
+    `in_proj` (the group before it, the second direction's first piece
+    beside the first direction's last dot): between a chain's start and its
+    done lies exactly that fusion.  The one synchronous `all-gather` of the
+    loop is the step's first piece.  The bytes gathered a step are the
+    parent's, nothing is gathered whole, no partial sum of the projection
+    is kept (the contraction is not cut), and the groups' results are
+    joined where the dots write them: no `concatenate` is left."""
+    _, text = wide_table_under_data4
+    assert profiler.collective_bytes(text, STEPS)[
+        "all-gather"] == GATHERED_A_STEP
+    body = _schedule(text)
+    alone = [(at, line) for at, (m, line) in enumerate(body)
+             if m["opcode"] == "all-gather"]
+    assert len(alone) == 1 and f" = {PIECE}" in alone[0][1], alone
+    chains = _gather_chains(text, body)
+    assert len(chains) == 2 * PIECES - 1, sorted(chains)
+    forward_dot = re.compile(
+        rf'op_name="[^"]*/jvp\(QuantileGRU\)/{scopes.IN_PROJ}/[^"]*dot_general')
+    spans = []
+    for chain, pieces in chains.items():
+        (begin, start, _), (at, _, dot), (end, done, last) = pieces
+        assert profiler.collective_kind(start) == (
+            profiler.ASYNC_COLLECTIVE, "-start"), pieces
+        assert profiler.collective_kind(done) == (
+            profiler.ASYNC_COLLECTIVE, "-done"), pieces
+        assert begin < at < end and forward_dot.search(dot), (chain, dot)
+        assert f" = {PIECE}" in last          # what arrives: a group's rows
+        spans.append((begin, end))
+    # one transfer at a time, each behind the step's first piece
+    spans.sort()
+    assert alone[0][0] < spans[0][0]
+    assert all(done < begin for (_, done), (begin, _) in zip(spans, spans[1:]))
+    # the 2 x PIECES dots: all but the step's last hide a transfer
+    dots = [m["name"] for m, line in body
+            if m["opcode"] == "fusion" and forward_dot.search(line)]
+    assert len(dots) == 2 * PIECES, dots
+    loop = _loop(text)
+    assert not any(f"bf16[{E},{TABLE_4K},{3 * H}]" in line for line in loop)
+    assert not any(f" = f32[{E},{W},{B},{3 * H}]" in line for line in loop)
+    assert not any(re.search(rf" = bf16\[{E},{W},{B},{3 * H}\]\S* "
+                             r"(concatenate|copy)\(", line) for line in loop)
+
+
 def test_wide_table_under_data4_walks_the_chips_as_they_sit(
         wide_table_under_data4, topo):
     """The ring's neighbours are neighbours on the board (`parallel/mesh.
@@ -249,10 +325,11 @@ def test_wide_table_under_data4_names_its_collectives_for_the_readers(
     `collective` row: a synchronous instruction, the ring's permutes by
     their `-start` and `-done` (ISSUE 47: names the benchmark's reader
     knows too, where it did not see the `fusion.N` scatters they replace),
-    XLA:TPU's wrapper fusions (the start and the done of the asynchronous
-    gather, whose three pieces carry one `chain_id` and count once), and no
+    XLA:TPU's wrapper fusions (the start and the done of each asynchronous
+    gather, whose three pieces carry one `chain_id` and count once: since
+    ISSUE 49 every piece of the folded weights but the step's first), and no
     fusion of the work a chain or a permute hides behind: the chunk dots
-    stay in `in_proj`'s row."""
+    and the groups' projections stay in `in_proj`'s rows."""
     _, text = wide_table_under_data4
     table = profiler.scope_table(text, scopes.STEP_SCOPES + scopes.KERNELS)
     rows = sorted(k for k, v in table.items()
@@ -263,12 +340,12 @@ def test_wide_table_under_data4_names_its_collectives_for_the_readers(
         kind = profiler.collective_kind(name) or ("wrapped", "")
         kinds[kind] = kinds.get(kind, 0) + 1
     assert ("wrapped", "") not in kinds          # ISSUE 45's two scatters
-    assert kinds.pop((profiler.ASYNC_COLLECTIVE, "-start")) == 1
-    assert kinds.pop((profiler.ASYNC_COLLECTIVE, "-done")) == 1
+    assert kinds.pop((profiler.ASYNC_COLLECTIVE, "-start")) == 2 * PIECES - 1
+    assert kinds.pop((profiler.ASYNC_COLLECTIVE, "-done")) == 2 * PIECES - 1
     assert kinds.pop(("collective-permute", "-start")) == PERMUTES
     assert kinds.pop(("collective-permute", "-done")) == PERMUTES
     assert set(kinds) == {("all-reduce", ""), ("all-gather", "")}, kinds
-    assert kinds[("all-gather", "")] == 1 + 6     # a step's other, the six
+    assert kinds[("all-gather", "")] == 1 + 6     # a step's first, the six
     # a collective instruction anywhere is in the row itself or inside a
     # fusion: a wrapper of the row, or the one that hides the chain
     computations, _ = profiler._instruction_lines(text)
@@ -281,17 +358,47 @@ def test_wide_table_under_data4_names_its_collectives_for_the_readers(
     fused = {comp for comp in holders if comp in calling.values()}
     hiding = [name for name, comp in calling.items()
               if comp in fused and name not in rows]
-    assert len(hiding) == 1 and table[hiding[0]] == (scopes.IN_PROJ, "fwd")
+    assert len(hiding) == 2 * PIECES - 1 and all(
+        table[name] == (scopes.IN_PROJ, "fwd") for name in hiding), hiding
     dots = [m["name"] for m, line in _schedule(text)
             if m["opcode"] == "fusion" and f" = {HALF}" in line]
     assert len(dots) == 2 * SPLIT * 2 and all(
         table[name] == (scopes.IN_PROJ, "bwd") for name in dots), dots
     chained = set(re.findall(r'chain_id="(\d+)"', text))
-    assert len(chained) == 1
+    assert len(chained) == 2 * PIECES - 1
     for comp in holders - fused:
         for m, _ in computations[comp]:
             if profiler.collective_kind(m["opcode"]):
                 assert m["name"] in rows, m["name"]
+
+
+def test_narrow_table_under_data4_stays_with_the_partitioner(topo):
+    """`tenk-train-dp4`'s table of 256 under the same mesh lies under the
+    rule (`sharding.ring_scatters`: 0.98 MB a hop), so nothing of ISSUEs 47
+    and 49 engages: the folded weights are gathered WHOLE (one synchronously,
+    the other in the one asynchronous chain), the gradients are the
+    partitioner's two `%all-reduce-scatter` fusions, no permute, no piece,
+    and the bytes a step are PR 45's.  (Instruction by instruction the text
+    is the parent's of ISSUE 49 in the parent's order, shapes and layouts,
+    under other instruction numbers: PERF.md section 6, PR 49.)"""
+    mesh = Mesh(np.asarray(topo.devices).reshape(4, 1, 1), AXES)
+    text = _train_step_lowered(mesh, F_10K, "compact", superstep=True,
+                               batch=4 * B, table=256).compile().as_text()
+    assert profiler.collective_bytes(text, STEPS) == {
+        "all-reduce": SMALL_A_STEP, "reduce-scatter": 3_932_160,
+        "all-gather": 18_677_760}
+    assert "collective-permute" not in text
+    assert len(set(re.findall(r'chain_id="(\d+)"', text))) == 1
+    whole = f"bf16[{E},256,{3 * H}]"
+    gathered = [line for line in _loop(text) if re.search(
+        r"^\s*(ROOT )?%\S+ = \S+ all-gather\(", line)]
+    assert gathered and all(f" = {whole}" in line for line in gathered)
+    scatters = [line for line in text.splitlines()
+                if " fusion(" in line and "all-reduce-scatter" in line]
+    assert len(scatters) == 2, len(scatters)
+    # no group of experts' rows anywhere: every array of the table's rows
+    # holds all forty experts
+    assert not re.search(rf"bf16\[\d+,256,{3 * H}\]", text.replace(whole, ""))
 
 
 def test_one_chip_compact_superstep_holds_nothing_of_the_split(topo):

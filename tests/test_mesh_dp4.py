@@ -646,6 +646,43 @@ def test_a_chip_steps_a_quarter_of_the_tables_rows(runs):
     assert carried_rows.per_chip_pct({}) is None
 
 
+def test_the_folded_weight_arrives_in_the_rules_pieces(runs, widest):
+    """`deeprest_train_projection_gather_pieces` and the benchmark's reader of
+    it (ISSUE 49): set beside `per_chip` by a trainer whose carried rows are
+    split over `data`, to `sharding.gather_pieces` of the table's rows where
+    a hop carries the ring's bound (here: at any width, five groups of one
+    expert; with a bound that admits two pieces of E = 5, one), to 1 where
+    the table is narrow and the partitioner gathers the weight whole; one
+    chip leaves it absent, and a registry without it reads as nothing."""
+    from chipbench.readers import gather_pieces
+    from deeprest_tpu.obs import setup as obs_setup
+    from deeprest_tpu.parallel import sharding
+
+    gauge = REGISTRY.get(obs_setup.GATHER_PIECES)
+    assert gauge is not None
+
+    def published(run):
+        _, trainer, _, _, staged, _ = run
+        gauge._series.clear()
+        trainer._publish_optimizer_rows(staged[0], 0, 10)
+        return gauge.series(), gather_pieces.per_step({})
+
+    assert published(runs[4]) == ({(): 1.0}, 1.0)       # under the rule
+    width = widest[4][4][0].width
+    sent = E * width // 4 * 3 * H * 4        # float32 rows, by one chip
+    for bound, pieces in ((0, E), (sent // 2, 1), (sent // 5, E)):
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(sharding, "RING_MIN_HOP_BYTES", bound)
+            assert published(widest[4]) == ({(): float(pieces)},
+                                            float(pieces)), bound
+    assert published(runs[1]) == ({}, None)             # rows not split
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(REGISTRY, "_metrics", {
+            k: v for k, v in REGISTRY._metrics.items()
+            if k != obs_setup.GATHER_PIECES})
+        assert gather_pieces.per_step({}) is None       # an older commit
+
+
 # -- (d) the three readers on a recorded slice of a four-chip trace ---------
 
 

@@ -868,6 +868,7 @@ _BENCHMARK_SERIES = [
     ("deeprest_train_optimizer_rows", ("kind",),
      [{"kind": k} for k in ("total", "updated", "visited", "stale",
                             "trips", "bound", "per_chip")]),
+    ("deeprest_train_projection_gather_pieces", (), []),    # a mesh's
     ("deeprest_train_projection_columns", ("kind",),
      [{"kind": k} for k in ("live", "contracted", "total", "padded",
                             "bound")]),

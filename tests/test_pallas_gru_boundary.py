@@ -171,3 +171,45 @@ def test_input_bias_gradient_is_the_float32_row_sum(layer_and_parent_form):
         print(f"db_ih ({d}, {r['dtype']}) from the float32 sum of dproj: "
               f"{mine:.3e}, the parent form's {parent:.3e}")
         assert mine <= (1e-6 if r["dtype"] == "float32" else parent)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_a_projection_that_brings_the_bias_moves_no_value_and_no_gradient(
+        dtype, monkeypatch):
+    """ISSUE 49: a caller's ``project`` makes ``x @ W_ih + b_ih`` itself (in
+    pieces; here whole), the layer hands it the bias under
+    ``stop_gradient`` and tells the recurrence the add is done
+    (``gru_recurrence(..., biased=True)``), whose backward kernels still
+    return the bias's gradient: the output and every gradient, ``db_ih``
+    among them, are those of the layer without ``project``, bit for bit."""
+    from deeprest_tpu.ops import pallas_gru
+
+    monkeypatch.setattr(pallas_gru, "_T_BLK", 2)
+    e, b, t = 8, 8, 4                          # two time blocks
+    dtype, f = jnp.dtype(dtype), 7
+    kf, kb, kx, kw = jax.random.split(jax.random.PRNGKey(49), 4)
+    ps = (init_gru_params(kf, e, f, H, dtype),
+          init_gru_params(kb, e, f, H, dtype))
+    x = jax.random.normal(kx, (b, t, f), dtype)
+    weight = jax.random.normal(kw, (e, b, t, 2 * H), jnp.float32)
+    seen = []
+
+    def project(x, w_ih, b_ih):
+        seen.append(b_ih)
+        return jnp.einsum("btf,efg->etbg", x, w_ih) + b_ih[:, None, None, :]
+
+    def run(project):
+        def loss(ps, x):
+            out = bidirectional_gru(*ps, x, backend="pallas_interpret",
+                                    project=project)
+            return jnp.sum(out.astype(jnp.float32) * weight), out
+        (_, out), (g_ps, g_x) = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True))(ps, x)
+        return [np.asarray(v, np.float32)
+                for v in (out, g_x, *g_ps[0], *g_ps[1])]
+
+    plain, brought = run(None), run(project)
+    assert len(seen) == 2                      # a call a direction
+    for name, was, now in zip(_LEAVES[:2] + _LEAVES[3:], plain, brought):
+        assert np.any(was), name
+        np.testing.assert_array_equal(now, was, err_msg=name)

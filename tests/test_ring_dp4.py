@@ -8,6 +8,10 @@ path, and the chips' own rows.  `tests/test_mesh_dp4.py` holds the whole
 program to the reference under the same mesh; `tests/test_chip_compile_dp4.py`
 holds what XLA:TPU makes of the ring for a described `v5e:2x2`.
 
+Since ISSUE 49 the forward is cut too, along the experts
+(`_gather_and_project`: a group's rows gathered while the group before it is
+projected), and is held here to the pinned einsum bit for bit: no sum is cut.
+
 No number of this file is a device number.
 """
 
@@ -39,16 +43,21 @@ def _mesh(data):
     return make_mesh(MeshConfig(data=data), jax.devices()[:data])
 
 
-def _operands(mesh, width, dtype, batch_a_chip=2):
+def _bias(rows):
+    return jax.random.normal(jax.random.PRNGKey(5),
+                             (rows.shape[0], rows.shape[-1]), rows.dtype)
+
+
+def _operands(mesh, width, dtype, batch_a_chip=2, experts=E):
     """Windows, split rows and a cotangent of the projection, placed as the
     compact superstep places them."""
     b = batch_a_chip * mesh.shape["data"]
     k = jax.random.split(jax.random.PRNGKey(width), 3)
     put = lambda a, spec: jax.device_put(a, NamedSharding(mesh, spec))
     return (put(jax.random.normal(k[0], (b, T, width), dtype), P("data")),
-            put(jax.random.normal(k[1], (E, width, G), dtype),
+            put(jax.random.normal(k[1], (experts, width, G), dtype),
                 P("expert", "data", None)),
-            put(jax.random.normal(k[2], (E, T, b, G), dtype),
+            put(jax.random.normal(k[2], (experts, T, b, G), dtype),
                 P("expert", None, "data", None)))
 
 
@@ -73,8 +82,8 @@ def _split_over_data(mesh, rows) -> bool:
 
 def _ring(mesh, x, rows, dxw):
     return jax.jit(lambda x, rows, dxw: jax.vjp(
-        lambda r: sharding.project_split_rows(mesh, x, r), rows)[1](dxw)[0]
-    )(x, rows, dxw)
+        lambda r: sharding.project_split_rows(mesh, x, r, _bias(r)),
+        rows)[1](dxw)[0])(x, rows, dxw)
 
 
 # bfloat16's spacing at the largest magnitude (the ring rounds a running sum
@@ -109,6 +118,62 @@ def test_ring_equals_the_dot_and_scatter_it_replaces(
         gap = np.abs(np.asarray(shard.data, np.float32)
                      - np.asarray(exact[:, lo:lo + chunk]))
         assert gap.max() <= SPACING[dtype] * top
+
+
+def _forward_both_ways(mesh, x, rows):
+    """(the cut forward, the pinned einsum and add) of the same operands."""
+    bias = _bias(rows)
+    return (jax.jit(lambda x, rows: sharding.project_split_rows(
+                mesh, x, rows, bias))(x, rows),
+            jax.jit(lambda x, rows: sharding._project_pinned(
+                mesh, x, rows, bias))(x, rows))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("width", [128, 256, 4096])
+@pytest.mark.parametrize("data", [2, 4])
+def test_forward_by_groups_of_experts_equals_the_pinned_einsum_to_the_bit(
+        data, width, dtype, ring_at_any_width):
+    """Every element is the dot the pinned einsum makes, with the bias added
+    to it: over all the rows, in the operands' dtype, nothing summed across
+    chips or groups.  Here a group is one expert of eight (the fixture's
+    bound of 0 bytes)."""
+    mesh = _mesh(data)
+    x, rows, _ = _operands(mesh, width, dtype, experts=8)
+    assert sharding.gather_pieces(mesh, rows) == 8
+    got, want = _forward_both_ways(mesh, x, rows)
+    assert got.dtype == want.dtype == dtype and got.shape == want.shape
+    assert got.sharding.is_equivalent_to(want.sharding, got.ndim)
+    assert np.array_equal(np.asarray(got, np.float32),
+                          np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("experts, carry, pieces", [
+    (12, 5, 4),     # five pieces would carry enough, five does not divide
+    (6, 7, 6), (16, 99, 8),             # no more than eight
+    (7, 5, 1), (6, 3, 1), (3, 99, 1),   # fewer than four: whole
+])
+def test_an_expert_count_the_pieces_do_not_divide_takes_fewer_groups(
+        experts, carry, pieces, monkeypatch):
+    """The groups are equal: of the numbers of pieces up to eight that still
+    carry the bound a chip (here ``carry`` of them), the largest that
+    divides the experts a chip holds, and under four pieces the weight
+    stays whole (the partitioner's gather); the forward cut so is still the
+    pinned einsum to the bit."""
+    mesh = _mesh(4)
+    x, rows, _ = _operands(mesh, 256, jnp.bfloat16, experts=experts)
+    sent = rows.size * rows.dtype.itemsize // 4        # by one chip a step
+    monkeypatch.setattr(sharding, "RING_MIN_HOP_BYTES", sent // carry)
+    assert sharding.ring_scatters(mesh, rows)
+    assert sharding.gather_pieces(mesh, rows) == pieces
+    got, want = _forward_both_ways(mesh, x, rows)
+    assert np.array_equal(np.asarray(got, np.float32),
+                          np.asarray(want, np.float32))
+    text = jax.jit(lambda x, rows: sharding.project_split_rows(
+        mesh, x, rows, _bias(rows))).lower(x, rows).as_text()
+    gathers = len(re.findall(r"stablehlo\.all_gather", text))
+    assert gathers == (pieces if pieces > 1 else 0)     # whole: a constraint
 
 
 @pytest.mark.parametrize("order", [(0, 1, 3, 2), (2, 0, 3, 1), (3, 2, 1, 0)])
@@ -199,8 +264,9 @@ def test_gradient_through_the_model_equals_autodiff_of_the_pinned_einsum(
     loss, rows = _loss_of_rows(mesh, dtype)
     value, grads = jax.jit(jax.value_and_grad(loss))(rows)
     monkeypatch.setattr(
-        qrnn, "project_split_rows", lambda mesh, x, rows: jnp.einsum(
-            "btf,efg->etbg", x, sharding.pin_folded_rows(mesh, rows)))
+        qrnn, "project_split_rows", lambda mesh, x, rows, bias: jnp.einsum(
+            "btf,efg->etbg", x, sharding.pin_folded_rows(mesh, rows))
+        + bias[:, None, None, :])
     loss, rows = _loss_of_rows(mesh, dtype)
     value_was, grads_was = jax.jit(jax.value_and_grad(loss))(rows)
     assert float(value) == pytest.approx(float(value_was), rel=1e-5)
@@ -237,20 +303,27 @@ def test_one_device_and_a_width_the_axis_does_not_divide_take_the_einsum(
         assert "collective_permute" not in text
 
 
-@pytest.mark.parametrize("table, dtype, data, ring", [
-    (4096, jnp.bfloat16, 4, True),      # `tenk-train-live4k-dp4`: 15.7 MB a hop
-    (256, jnp.bfloat16, 4, False),      # `tenk-train-dp4`: 0.98 MB, and slower
-    (2048, jnp.bfloat16, 4, True),      # read at +11.0%
-    (1024, jnp.bfloat16, 4, True),      # read at +5.9%: 3.93 MB a hop
-    (512, jnp.bfloat16, 4, False),      # not read; 1.97 MB
-    (512, jnp.float32, 4, True), (1024, jnp.bfloat16, 8, False),
+@pytest.mark.parametrize("table, dtype, data, ring, pieces", [
+    # `tenk-train-live4k-dp4`: 15.7 MB a hop; eight groups of five experts
+    (4096, jnp.bfloat16, 4, True, 8),
+    (256, jnp.bfloat16, 4, False, 1),   # `tenk-train-dp4`: 0.98 MB, and slower
+    (2048, jnp.bfloat16, 4, True, 5),   # read at +11.0%; in five pieces +5.3%
+    (1024, jnp.bfloat16, 4, True, 1),   # read at +5.9%; in two pieces -1.2%
+    (512, jnp.bfloat16, 4, False, 1),   # not read; 1.97 MB
+    (512, jnp.float32, 4, True, 1), (1024, jnp.bfloat16, 8, False, 1),
+    (8192, jnp.bfloat16, 4, True, 8),
 ])
-def test_the_ring_engages_by_the_bytes_of_a_hop(table, dtype, data, ring):
+def test_the_ring_engages_by_the_bytes_of_a_hop(table, dtype, data, ring,
+                                                pieces):
     """`sharding.ring_scatters`, the one rule beside `carried_rows_split`:
     the two four-chip cells lie on either side of it, as the chips read them
-    (PERF.md section 6, PR 47), and it reads shapes and nothing else."""
+    (PERF.md section 6, PR 47), and it reads shapes and nothing else.  Where
+    it engages, the forward's gather comes in `sharding.gather_pieces`
+    groups of experts, four to eight of at least the same bytes from a chip
+    each, as the chips read them (PERF.md section 6, PR 49); else whole."""
     rows = jax.ShapeDtypeStruct((40, table, 384), dtype)
     assert sharding.ring_scatters(_mesh(data), rows) is ring
+    assert sharding.gather_pieces(_mesh(data), rows) == pieces
 
 
 def test_a_narrow_table_leaves_its_gradients_to_the_partitioner():
