@@ -25,6 +25,19 @@ This module holds the three pieces that are not one trainer's:
   profiler's clock like every other (outside every span it is counted and
   no span: it would be a trace of its own in the plane's exports).  A compilation in phase ``epoch``
   after the first epoch is a recompile, with its name.
+  The two stages before a compilation are heard the same way and kept
+  beside it: ``deeprest_trace_seconds_total{program,phase}`` (Python
+  tracing the function to a jaxpr) and
+  ``deeprest_lower_seconds_total{program,phase}`` (the jaxpr to an MLIR
+  module), as SELF time.  Tracing nests (every jitted ``jnp`` helper
+  sends its own event inside the superstep's, thousands a process),
+  lowering can trace, and tracing can compile and run a constant: only
+  the OUTERMOST trace or lower event open on a thread is counted, under
+  its own program and stage, less the compilations that ended inside it.
+  So the three counters of a ``(program, phase)`` add up to the wall time
+  the thread spent in jax's pipeline for it.  Under an open span the
+  outermost event is also a span ``deeprest-jax/trace`` or
+  ``deeprest-jax/lower`` tagged ``program``; a nested event is no span.
 - :func:`setup_table` / :func:`format_setup` — the set-up gauges of the
   process registry (the form a staged sparse corpus took among them) as
   one table (``Trainer.profile_epoch``'s ``setup``,
@@ -48,6 +61,8 @@ UNCACHED = "uncached"
 
 COMPILATIONS = "deeprest_compilations_total"
 COMPILE_SECONDS = "deeprest_compile_seconds_total"
+TRACE_SECONDS = "deeprest_trace_seconds_total"
+LOWER_SECONDS = "deeprest_lower_seconds_total"
 INIT_STATE_SECONDS = "deeprest_train_init_state_seconds"
 STAGE_SECONDS = "deeprest_train_last_stage_seconds"
 STAGINGS = "deeprest_train_stagings_total"
@@ -62,11 +77,19 @@ KERNEL_OPERAND_BYTES = "deeprest_train_kernel_operand_bytes"
 TIME_REVERSALS = "deeprest_train_time_reversals"
 KERNEL_EDGE_PASSES = "deeprest_train_kernel_edge_passes"
 
-_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 _CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
                  "/jax/compilation_cache/cache_misses": "miss"}
 
-_WRAPPED = re.compile(r"\w+\((.*)\)")
+# the stages of jax's pipeline the listener hears, by the event that
+# brackets each (a scalar at its start, a duration at its end)
+_COMPILE, _TRACE, _LOWER = "compile", "trace", "lower"
+_STAGES = {"/jax/core/compile/jaxpr_trace_duration": _TRACE,
+           "/jax/core/compile/jaxpr_to_mlir_module_duration": _LOWER,
+           "/jax/core/compile/backend_compile_duration": _COMPILE}
+
+# `jit(train_superstep)` (a compilation, a lowering) and
+# `jit_train_superstep` (a module's name); tracing sends the bare name
+_WRAPPED = re.compile(r"\w+\((.*)\)|jit_(.*)")
 
 _PHASE: contextvars.ContextVar[str] = contextvars.ContextVar(
     "deeprest_obs_setup_phase", default=OTHER)
@@ -96,41 +119,79 @@ def _compilations():
         labelnames=("program", "phase", "cache"))
 
 
-def _compile_seconds():
-    return REGISTRY.counter(
-        COMPILE_SECONDS,
-        "seconds of those compilations (a cache hit's are its load)",
-        labelnames=("program", "phase"))
+_SECONDS = {
+    _COMPILE: (COMPILE_SECONDS,
+               "seconds of those compilations (a cache hit's are its load)"),
+    _TRACE: (TRACE_SECONDS,
+             "seconds a thread spent tracing jitted programs to jaxprs, by "
+             "the outermost program and the set-up phase open on it: self "
+             "time (what nests inside is counted once, a compilation inside "
+             "it only as a compilation)"),
+    _LOWER: (LOWER_SECONDS,
+             "seconds a thread spent lowering jaxprs to MLIR modules, self "
+             "time as tracing's"),
+}
+
+
+def _seconds(stage: str):
+    name, text = _SECONDS[stage]
+    return REGISTRY.counter(name, text, labelnames=("program", "phase"))
+
+
+class _Open(threading.local):
+    """What is open on this thread."""
+
+    cache = UNCACHED    # the cache's verdict on the open compilation
+    span = None         # its span
+    depth = 0           # the open trace and lower events
+    compiled = 0.0      # seconds of the compilations inside the outermost
+    stage_span = None   # the outermost's span
 
 
 class _Listener:
-    """``jax.monitoring`` hands a compilation's start as a scalar event,
-    the cache's verdict as a plain event inside it and its seconds as a
-    duration event at its end, all on the compiling thread."""
+    """``jax.monitoring`` hands a stage's start as a scalar event and its
+    seconds as a duration event at its end, the cache's verdict on a
+    compilation as a plain event inside it, all on the thread that does
+    the work."""
 
     def __init__(self):
         self.programs: set[str] = set()
-        self._open = threading.local()
+        self._open = _Open()
 
     def _program(self, fun_name) -> str:
-        """``jit(train_superstep)`` -> ``train_superstep`` if it is one of
+        """``train_superstep``, ``jit(train_superstep)`` and
+        ``jit_train_superstep`` -> ``train_superstep`` if it is one of
         ours."""
-        m = _WRAPPED.fullmatch(fun_name or "")
-        name = m[1] if m else fun_name
+        name = fun_name or ""
+        if name not in self.programs:
+            m = _WRAPPED.fullmatch(name)
+            name = (m[1] or m[2]) if m else name
         return name if name in self.programs else OTHER
 
+    def _span(self, stage: str, fun_name):
+        """An entered span for the stage that begins, only under an open
+        one: outside every span it would be a trace of its own in the
+        plane's exports."""
+        if current_context() is None:
+            return None
+        span = RECORDER.span(stage, "deeprest-jax",
+                             {"program": self._program(fun_name)})
+        span.__enter__()
+        return span
+
     def began(self, event: str, _value, fun_name=None, **_kw) -> None:
-        if event != _BACKEND_COMPILE:
+        stage = _STAGES.get(event)
+        if stage is None:
             return
-        self._open.cache = UNCACHED
-        # a span only under an open one: a compilation outside every span
-        # would be a trace of its own in the plane's exports
-        self._open.span = None
-        if current_context() is not None:
-            self._open.span = RECORDER.span(
-                "compile", "deeprest-jax",
-                {"program": self._program(fun_name)})
-            self._open.span.__enter__()
+        at = self._open
+        if stage == _COMPILE:
+            at.cache = UNCACHED
+            at.span = self._span(stage, fun_name)
+            return
+        at.depth += 1
+        if at.depth == 1:
+            at.compiled = 0.0
+            at.stage_span = self._span(stage, fun_name)
 
     def cache(self, event: str, **_kw) -> None:
         verdict = _CACHE_EVENTS.get(event)
@@ -139,19 +200,37 @@ class _Listener:
 
     def ended(self, event: str, seconds: float, fun_name=None,
               **_kw) -> None:
-        if event != _BACKEND_COMPILE:
+        stage = _STAGES.get(event)
+        if stage is None:
             return
-        cache = getattr(self._open, "cache", UNCACHED)
-        span = getattr(self._open, "span", None)
-        self._open.cache, self._open.span = UNCACHED, None
+        at = self._open
+        if stage != _COMPILE:
+            if not at.depth:    # it began before the listener was there
+                return
+            at.depth -= 1
+            if at.depth:        # inside another: the outermost holds it
+                return
+        seconds = max(float(seconds), 0.0)
+        if stage == _COMPILE:
+            cache, span = at.cache, at.span
+            at.cache, at.span = UNCACHED, None
+            if span is not None:
+                span.tag(cache=cache).__exit__(None, None, None)
+            if at.depth:
+                at.compiled += seconds
+            program, where = self._program(fun_name), current_phase()
+            # looked up by name each time: a registry reset between two
+            # compilations (tests) must not leave the counts in dropped
+            # objects
+            _compilations().inc(program=program, phase=where, cache=cache)
+            _seconds(stage).inc(seconds, program=program, phase=where)
+            return
+        span, at.stage_span = at.stage_span, None
         if span is not None:
-            span.tag(cache=cache).__exit__(None, None, None)
-        program, where = self._program(fun_name), current_phase()
-        # looked up by name each time: a registry reset between two
-        # compilations (tests) must not leave the counts in dropped objects
-        _compilations().inc(program=program, phase=where, cache=cache)
-        _compile_seconds().inc(max(float(seconds), 0.0), program=program,
-                               phase=where)
+            span.__exit__(None, None, None)
+        _seconds(stage).inc(max(seconds - at.compiled, 0.0),
+                            program=self._program(fun_name),
+                            phase=current_phase())
 
 
 _listener: _Listener | None = None
@@ -207,25 +286,34 @@ def setup_table() -> dict:
     ``trips`` of a dispatch); under gradient accumulation the
     ``microbatches`` of an optimizer update and the ``carry_bytes`` of its
     gradient accumulator (``accumulation``; left out with one microbatch an
-    update); the compilations
-    by program and phase (count, seconds, misses); device memory at the
+    update); jax's pipeline by program and phase (``compilations``: count,
+    seconds and misses of the compilations, ``trace_seconds`` and
+    ``lower_seconds`` of the two stages before them, a row also where a
+    program traced and nothing compiled; the costliest first); device
+    memory at the
     three moments; the superstep executable's bytes, where its kernels'
     operands live, how many arrays a step reverses in time round them
     (``time_reversals``) and how many passes it makes over a kernel's
     operand or result only to cut or to sum it (``kernel_edge_passes``).
     What was never set is left out."""
-    seconds = {(s["program"], s["phase"]): v
-               for s, v in _series(COMPILE_SECONDS)}
     compilations: dict = {}
+
+    def row(found: dict) -> dict:
+        return compilations.setdefault(
+            (found["program"], found["phase"]),
+            {"program": found["program"], "phase": found["phase"],
+             "count": 0, "misses": 0, "seconds": 0.0, "trace_seconds": 0.0,
+             "lower_seconds": 0.0})
+
     for s, n in _series(COMPILATIONS):
-        row = compilations.setdefault(
-            (s["program"], s["phase"]),
-            {"program": s["program"], "phase": s["phase"], "count": 0,
-             "misses": 0,
-             "seconds": seconds.get((s["program"], s["phase"]), 0.0)})
-        row["count"] += int(n)
+        row(s)["count"] += int(n)
         if s["cache"] != "hit":
-            row["misses"] += int(n)
+            row(s)["misses"] += int(n)
+    for key, name in (("seconds", COMPILE_SECONDS),
+                      ("trace_seconds", TRACE_SECONDS),
+                      ("lower_seconds", LOWER_SECONDS)):
+        for s, v in _series(name):
+            row(s)[key] = v
     stage = _series(STAGE_SECONDS)
     stagings = _series(STAGINGS)
     reversals = _series(TIME_REVERSALS)
@@ -247,8 +335,10 @@ def setup_table() -> dict:
         "accumulation": (accumulation
                          if accumulation.get("microbatches", 1) > 1 else None),
         "first_dispatch_seconds": _by(FIRST_DISPATCH_SECONDS, "program"),
-        "compilations": sorted(compilations.values(),
-                               key=lambda r: -r["seconds"]),
+        "compilations": sorted(
+            compilations.values(),
+            key=lambda r: -(r["seconds"] + r["trace_seconds"]
+                            + r["lower_seconds"])),
         "device_bytes": _by(DEVICE_BYTES, "at", "kind"),
         "program_bytes": _by(PROGRAM_BYTES, "kind"),
         "kernel_operand_bytes": _by(KERNEL_OPERAND_BYTES, "kernel", "space"),
@@ -294,13 +384,19 @@ def format_setup(table: dict) -> str:
                      + seconds(table["first_dispatch_seconds"]) + " s")
     rows = table.get("compilations", ())
     if rows:
+        def total(key: str):
+            return sum(r[key] for r in rows)
+
         parts.append(
-            f"{sum(r['count'] for r in rows)} compilations in "
-            f"{sum(r['seconds'] for r in rows):.3f} s, "
-            f"{sum(r['misses'] for r in rows)} not from the cache ("
+            f"{total('count')} compilations in {total('seconds'):.3f} s, "
+            f"{total('misses')} not from the cache, traced "
+            f"{total('trace_seconds'):.3f} s, lowered "
+            f"{total('lower_seconds'):.3f} s ("
             + ", ".join(f"{r['program']} in {r['phase']} {r['count']} in "
                         f"{r['seconds']:.3f} s"
                         + (f", {r['misses']} missed" if r["misses"] else "")
+                        + f", traced {r['trace_seconds']:.3f} s, lowered "
+                        f"{r['lower_seconds']:.3f} s"
                         for r in rows) + ")")
     for at, found in table.get("device_bytes", {}).items():
         parts.append(f"device memory at {at} {size(found.get('in_use', 0))} "
@@ -321,6 +417,7 @@ def format_setup(table: dict) -> str:
 
 __all__ = ["OTHER", "UNCACHED", "phase", "current_phase", "install",
            "setup_table", "format_setup", "COMPILATIONS", "COMPILE_SECONDS",
+           "TRACE_SECONDS", "LOWER_SECONDS",
            "INIT_STATE_SECONDS", "STAGE_SECONDS", "STAGINGS",
            "OPTIMIZER_ROWS", "ACCUMULATION", "GATHER_PIECES",
            "PROJECTION_COLUMNS",

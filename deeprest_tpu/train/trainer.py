@@ -815,9 +815,6 @@ class Trainer:
             "kernel call (a sum over dproj for the input bias, the split "
             "of the joined cotangent): 0 where the backward kernels do "
             "both themselves")
-        self._m_snapshots = obs_metrics.REGISTRY.counter(
-            "deeprest_train_snapshots_total",
-            "preemption-safe cursor snapshots written")
         # Elastic-remeshing legs (detect -> rebuild -> restore -> resume),
         # one increment per event — never on the step path.
         self._m_device_losses = obs_metrics.REGISTRY.counter(
@@ -826,15 +823,11 @@ class Trainer:
         self._m_remeshes = obs_metrics.REGISTRY.counter(
             "deeprest_train_remeshes_total",
             "elastic remesh outcomes", labelnames=("outcome",))
-        self._m_mesh_devices = obs_metrics.REGISTRY.gauge(
-            "deeprest_train_mesh_devices",
-            "devices in the trainer's current mesh")
         self._m_recovery = obs_metrics.REGISTRY.gauge(
             "deeprest_train_remesh_recovery_seconds",
             "wall seconds of the last remesh recovery "
             "(detect through restore; the first post-restore dispatch "
             "additionally pays one compile per new mesh shape)")
-        self._m_mesh_devices.set(self.mesh.devices.size)
 
     def _jit_cache_size(self) -> int | None:
         """Total compiled-executable count across the trainer's jitted
@@ -1080,7 +1073,6 @@ class Trainer:
         path = self.save(self._snapshot_dir, state, bundle,
                          extra_host_state=extra)
         self._snapshots_written += 1
-        self._m_snapshots.inc()
         # Retention GC AFTER the durable save: only cursor snapshots are
         # candidates and the newest `snapshot_keep` always survive, so
         # the restore target of any concurrent resume/remesh is never
@@ -1156,7 +1148,6 @@ class Trainer:
             # arguments into an old-mesh wrapper raises, it does not
             # retrace).  One program set per live mesh shape.
             self._build_programs()
-            self._m_mesh_devices.set(cfg.size)
             sp.tag(mesh=f"{cfg.data}x{cfg.expert}x{cfg.model}")
         return len(healthy)
 

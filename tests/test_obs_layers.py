@@ -618,13 +618,346 @@ def test_setup_table_and_its_line(tiny):
     table = obs_setup.setup_table()
     assert list(table["init_state_seconds"]) == list(INIT_PHASES)
     rows = table["compilations"]
-    assert rows == sorted(rows, key=lambda r: -r["seconds"])
+    assert rows == sorted(rows, key=lambda r: -(
+        r["seconds"] + r["trace_seconds"] + r["lower_seconds"]))
     assert all(r["misses"] <= r["count"] for r in rows)
     assert sum(r["count"] for r in rows) == _compilations()
     json.dumps(table)                       # layers.json carries it
     line = obs_setup.format_setup(table)
     assert line.startswith("set-up: init_state ") and "\n" not in line
     assert "compilations in" in line
+
+
+# -- (e') tracing and lowering beside the compilations (ISSUE 50) -----------
+
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_COMPILE = "/jax/core/compile/backend_compile_duration"
+_STAGE_COUNTERS = (obs_setup.TRACE_SECONDS, obs_setup.LOWER_SECONDS,
+                   obs_setup.COMPILE_SECONDS)
+
+
+def _stage_series(registry=REGISTRY):
+    """{counter: {(program, phase): seconds}} of the three stage counters."""
+    return {name: dict(found.series()) if found else {}
+            for name in _STAGE_COUNTERS
+            for found in [registry.get(name)]}
+
+
+@pytest.fixture
+def by_hand(monkeypatch):
+    """A listener nobody registered with jax, fed by hand, counting into a
+    registry of its own: ``feed(events)`` takes ``(event, fun_name,
+    seconds)`` for an end and ``(event, fun_name)`` for a start."""
+    from deeprest_tpu.obs import metrics
+
+    registry = metrics.MetricsRegistry()
+    monkeypatch.setattr(obs_setup, "REGISTRY", registry)
+    listener = obs_setup._Listener()
+    listener.programs.add("train_superstep")
+
+    def feed(events):
+        for event, fun_name, *seconds in events:
+            if seconds:
+                listener.ended(event, seconds[0], fun_name=fun_name)
+            else:
+                listener.began(event, 0.0, fun_name=fun_name)
+
+    return feed, registry
+
+
+@pytest.mark.parametrize("spelling, program", [
+    ("train_superstep", "train_superstep"),         # tracing's
+    ("jit_train_superstep", "train_superstep"),     # a module's name
+    ("jit(train_superstep)", "train_superstep"),    # lowering's, compiling's
+    ("jit(somebody_elses)", "other"),
+    ("_where", "other"),
+    (None, "other"),
+])
+def test_the_listener_resolves_every_spelling_of_a_program(by_hand, spelling,
+                                                           program):
+    feed, registry = by_hand
+    feed([(_TRACE, spelling), (_TRACE, spelling, 2.0),
+          (_LOWER, spelling), (_LOWER, spelling, 0.5),
+          (_COMPILE, spelling), (_COMPILE, spelling, 0.25)])
+    assert _stage_series(registry) == {
+        obs_setup.TRACE_SECONDS: {(program, "other"): 2.0},
+        obs_setup.LOWER_SECONDS: {(program, "other"): 0.5},
+        obs_setup.COMPILE_SECONDS: {(program, "other"): 0.25}}
+    assert registry.get(obs_setup.COMPILATIONS).series() == {
+        (program, "other", "uncached"): 1.0}
+
+
+def _nested_trace(feed):
+    feed([(_TRACE, "train_superstep"), (_TRACE, "_where"),
+          (_TRACE, "_broadcast_arrays"), (_TRACE, "_broadcast_arrays", 0.25),
+          (_TRACE, "_where", 0.5), (_TRACE, "add"), (_TRACE, "add", 0.125),
+          (_TRACE, "train_superstep", 8.0)])
+    return {obs_setup.TRACE_SECONDS: {("train_superstep", "other"): 8.0}}
+
+
+def _trace_inside_a_lower(feed):
+    feed([(_LOWER, "jit(train_superstep)"), (_TRACE, "custom_vjp_rule"),
+          (_TRACE, "custom_vjp_rule", 0.5),
+          (_LOWER, "jit(train_superstep)", 1.5),
+          (_TRACE, "later"), (_TRACE, "later", 0.25)])
+    return {obs_setup.LOWER_SECONDS: {("train_superstep", "other"): 1.5},
+            obs_setup.TRACE_SECONDS: {("other", "other"): 0.25}}
+
+
+def _compilation_inside_a_trace(feed):
+    # tracing compiles and runs a constant: that program's own three stages
+    # inside the superstep's trace; then the superstep's own compilation
+    feed([(_TRACE, "train_superstep"),
+          (_TRACE, "iota"), (_TRACE, "iota", 0.125),
+          (_LOWER, "jit(iota)"), (_LOWER, "jit(iota)", 0.125),
+          (_COMPILE, "jit(iota)"), (_COMPILE, "jit(iota)", 0.5),
+          (_COMPILE, "jit(iota)"), (_COMPILE, "jit(iota)", 0.25),
+          (_TRACE, "train_superstep", 8.0),
+          (_COMPILE, "jit(train_superstep)"),
+          (_COMPILE, "jit(train_superstep)", 0.625)])
+    return {obs_setup.TRACE_SECONDS: {("train_superstep", "other"): 7.25},
+            obs_setup.COMPILE_SECONDS: {("other", "other"): 0.75,
+                                        ("train_superstep", "other"): 0.625}}
+
+
+def _under_a_phase(feed):
+    with obs_setup.phase("init_state"):
+        feed([(_TRACE, "zeros"), (_TRACE, "zeros", 0.5)])
+        with obs_setup.phase("first_dispatch"):
+            feed([(_LOWER, "jit_train_superstep"),
+                  (_LOWER, "jit_train_superstep", 1.0)])
+    feed([(_TRACE, "zeros"), (_TRACE, "zeros", 0.25)])
+    return {obs_setup.TRACE_SECONDS: {("other", "init_state"): 0.5,
+                                      ("other", "other"): 0.25},
+            obs_setup.LOWER_SECONDS: {
+                ("train_superstep", "first_dispatch"): 1.0}}
+
+
+def _an_end_with_no_start(feed):
+    # the listener was installed inside an open trace: its end is nobody's,
+    # and what follows is counted as ever
+    feed([(_TRACE, "begun_before", 3.0), (_TRACE, "train_superstep"),
+          (_TRACE, "train_superstep", 1.0)])
+    return {obs_setup.TRACE_SECONDS: {("train_superstep", "other"): 1.0}}
+
+
+def _more_compiled_than_traced(feed):
+    # clocks differ: self time is never negative
+    feed([(_TRACE, "train_superstep"), (_COMPILE, "jit(iota)"),
+          (_COMPILE, "jit(iota)", 0.5), (_TRACE, "train_superstep", 0.25)])
+    return {obs_setup.TRACE_SECONDS: {("train_superstep", "other"): 0.0},
+            obs_setup.COMPILE_SECONDS: {("other", "other"): 0.5}}
+
+
+@pytest.mark.parametrize("events", [
+    _nested_trace, _trace_inside_a_lower, _compilation_inside_a_trace,
+    _under_a_phase, _an_end_with_no_start, _more_compiled_than_traced],
+    ids=lambda f: f.__name__.strip("_"))
+def test_only_the_outermost_stage_is_counted_as_self_time(by_hand, events):
+    feed, registry = by_hand
+    want = events(feed)
+    found = {name: series for name, series in _stage_series(registry).items()
+             if series}
+    assert found == want
+
+
+def test_the_listener_keeps_two_threads_apart(by_hand):
+    """A trace open on one thread holds nothing of another's: the other's
+    own trace is outermost there, and its compilation is subtracted from
+    neither."""
+    import threading
+
+    feed, registry = by_hand
+    opened, finished = threading.Event(), threading.Event()
+
+    def first():
+        feed([(_TRACE, "train_superstep")])
+        opened.set()
+        assert finished.wait(timeout=30)
+        feed([(_TRACE, "train_superstep", 8.0)])
+
+    def second():
+        assert opened.wait(timeout=30)
+        with obs_setup.phase("stage"):
+            feed([(_TRACE, "elsewhere"), (_TRACE, "elsewhere", 0.5),
+                  (_COMPILE, "jit(elsewhere)"),
+                  (_COMPILE, "jit(elsewhere)", 2.0)])
+        finished.set()
+
+    threads = [threading.Thread(target=first), threading.Thread(target=second)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+    assert not any(t.is_alive() for t in threads)
+    assert _stage_series(registry) == {
+        obs_setup.TRACE_SECONDS: {("train_superstep", "other"): 8.0,
+                                  ("other", "stage"): 0.5},
+        obs_setup.LOWER_SECONDS: {},
+        obs_setup.COMPILE_SECONDS: {("other", "stage"): 2.0}}
+
+
+def _superstep_seconds():
+    return {name: sum(v for (program, _), v in series.items()
+                      if program == "train_superstep")
+            for name, series in _stage_series().items()}
+
+
+def test_a_trainer_traces_and_lowers_its_superstep_once():
+    """A real tiny trainer after ``init_state`` and staging: the first
+    epoch adds trace and lower seconds under ``train_superstep``, the
+    second adds nothing (a retrace in a later epoch would show here, with
+    its name)."""
+    fresh = _staged_trainer(FeaturizeConfig(hash_features=True, capacity=512))
+    was = _superstep_seconds()
+    init = _stage_series()[obs_setup.TRACE_SECONDS]
+    assert init.get(("other", "init_state"), 0) > 0     # model.init's
+    _epoch(fresh)
+    first = _superstep_seconds()
+    assert first[obs_setup.TRACE_SECONDS] > was[obs_setup.TRACE_SECONDS]
+    assert first[obs_setup.LOWER_SECONDS] > was[obs_setup.LOWER_SECONDS]
+    assert _stage_series()[obs_setup.TRACE_SECONDS][
+        ("train_superstep", "first_dispatch")] > 0
+    _epoch(fresh)
+    assert _superstep_seconds() == first
+
+
+def test_the_three_stages_of_a_call_fit_inside_its_wall_time():
+    """A function that sleeps 50 ms in its Python body: at least that is
+    tracing, and trace + lower + compile is no more than the call took
+    (every nested ``jnp`` helper's trace inside it counted once)."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    def sleeps_while_traced(x):
+        time.sleep(0.05)
+        return jnp.where(x > 0, jnp.sin(x), 0.0).sum() + jnp.arange(3.0).sum()
+
+    obs_setup.install(["sleeps_while_traced"])
+    args = jnp.ones(5)
+    args.block_until_ready()
+    began = time.perf_counter()
+    with obs_setup.phase("stage"):
+        jax.jit(sleeps_while_traced)(args).block_until_ready()
+    wall = time.perf_counter() - began
+    seconds = [series.get(("sleeps_while_traced", "stage"), 0.0)
+               for series in _stage_series().values()]
+    assert seconds[0] >= 0.05, seconds
+    assert all(v > 0 for v in seconds), seconds
+    assert sum(seconds) <= wall, (seconds, wall)
+
+
+def test_an_outermost_stage_is_a_span_and_a_nested_one_is_not():
+    import jax
+    import jax.numpy as jnp
+
+    def spanned_for_the_listener(x):
+        return jnp.where(x > 0, x, 0.0).sum()       # `_where` traces inside
+
+    def unspanned_for_the_listener(x):
+        return jnp.where(x > 0, x, 1.0).sum()
+
+    obs_setup.install(["spanned_for_the_listener",
+                       "unspanned_for_the_listener"])
+    args = np.arange(5, dtype=np.float32)
+
+    def under_a_span():
+        with obs.RECORDER.span("test.unit", "deeprest-test"):
+            jax.jit(spanned_for_the_listener)(args)
+
+    spans = _recorded(under_a_span)
+    unit = [s for s in spans if s.name == "test.unit"]
+    ours = [s for s in spans if s.component == "deeprest-jax"]
+    assert [s.name for s in ours] == ["trace", "lower", "compile"]
+    assert all(s.tags["program"] == "spanned_for_the_listener" for s in ours)
+    assert all(s.parent_id == unit[0].span_id for s in ours)
+    assert _stage_series()[obs_setup.TRACE_SECONDS][
+        ("spanned_for_the_listener", "other")] >= ours[0].duration_s * 0.5
+
+    # outside every span: counted, and no trace of its own; with the
+    # recorder off: counted, and no span at all
+    assert not _recorded(lambda: jax.jit(spanned_for_the_listener)(args[:3]))
+    assert not obs.RECORDER.enabled
+    obs.RECORDER.clear()
+    with obs.RECORDER.span("test.unit", "deeprest-test"):
+        jax.jit(unspanned_for_the_listener)(args)
+    assert not obs.RECORDER.drain()
+    found = _stage_series()
+    assert all(found[name][("unspanned_for_the_listener", "other")] > 0
+               for name in _STAGE_COUNTERS)
+
+
+def test_setup_table_rows_carry_the_three_stages(by_hand):
+    feed, registry = by_hand
+    with obs_setup.phase("first_dispatch"):
+        feed([(_TRACE, "train_superstep"), (_TRACE, "train_superstep", 7.9),
+              (_LOWER, "jit(train_superstep)"),
+              (_LOWER, "jit(train_superstep)", 1.5),
+              (_COMPILE, "jit(train_superstep)"),
+              (_COMPILE, "jit(train_superstep)", 0.61)])
+    with obs_setup.phase("epoch"):          # a retrace whose program is cached
+        feed([(_TRACE, "train_superstep"), (_TRACE, "train_superstep", 9.0)])
+    feed([(_COMPILE, "jit(iota)"), (_COMPILE, "jit(iota)", 0.25)])
+    table = obs_setup.setup_table()
+    assert table["compilations"] == [
+        {"program": "train_superstep", "phase": "first_dispatch", "count": 1,
+         "misses": 1, "seconds": 0.61, "trace_seconds": 7.9,
+         "lower_seconds": 1.5},
+        {"program": "train_superstep", "phase": "epoch", "count": 0,
+         "misses": 0, "seconds": 0.0, "trace_seconds": 9.0,
+         "lower_seconds": 0.0},
+        {"program": "other", "phase": "other", "count": 1, "misses": 1,
+         "seconds": 0.25, "trace_seconds": 0.0, "lower_seconds": 0.0}]
+    line = obs_setup.format_setup(table)
+    assert ("train_superstep in first_dispatch 1 in 0.610 s, 1 missed, "
+            "traced 7.900 s, lowered 1.500 s") in line
+    assert "train_superstep in epoch 0 in 0.000 s, traced 9.000 s" in line
+    assert ("2 compilations in 0.860 s, 2 not from the cache, traced "
+            "16.900 s, lowered 1.500 s (") in line
+
+
+_STAGE_SERIES = {
+    obs_setup.TRACE_SECONDS: {("other", "other"): 9.0,
+                              ("other", "init_state"): 3.0,
+                              ("train_superstep", "other"): 8.0,
+                              ("train_superstep", "epoch"): 0.5},
+    obs_setup.LOWER_SECONDS: {("other", "other"): 4.0,
+                              ("other", "init_state"): 1.0,
+                              ("train_superstep", "other"): 1.5},
+    obs_setup.COMPILE_SECONDS: {("other", "other"): 2.0,
+                                ("stale_rows", "first_dispatch"): 0.125,
+                                ("train_superstep", "other"): 0.25},
+}
+
+
+@pytest.mark.parametrize("reader, expected", [
+    ("trace_s", 11.5), ("lower_s", 2.5), ("superstep_build_s", 10.25)])
+def test_the_stage_readers(monkeypatch, reader, expected):
+    """chipbench/readers/setup_stages.py: the sum bar ``other``/``other``
+    (the harness's own), the superstep's three stages in every phase;
+    nothing (not an error) from a program without the counters: the
+    parent's, which has the compile counter alone."""
+    from chipbench.readers import setup_stages
+    from deeprest_tpu.obs import metrics
+
+    registry = metrics.MetricsRegistry()
+    monkeypatch.setattr(metrics, "REGISTRY", registry)
+    read = getattr(setup_stages, reader)
+    assert read({}) is None
+    made = {}
+    for name in _STAGE_COUNTERS[::-1]:      # the parent's counter first
+        made[name] = registry.counter(name, labelnames=("program", "phase"))
+        if name == obs_setup.COMPILE_SECONDS:
+            for key, value in _STAGE_SERIES[name].items():
+                made[name].inc(value, program=key[0], phase=key[1])
+        assert read({}) is None     # the parent's; registered, never moved
+    for name in _STAGE_COUNTERS[:2]:
+        for key, value in _STAGE_SERIES[name].items():
+            made[name].inc(value, program=key[0], phase=key[1])
+    assert read({}) == pytest.approx(expected)
 
 
 _KERNEL_BYTES = {("gru_kernel_fwd", "vmem"): 1.0, ("gru_kernel_fwd", "hbm"): 2.0,
@@ -854,6 +1187,12 @@ _BENCHMARK_SERIES = [
      [{"program": "train_superstep", "phase": "first_dispatch"}]),
     ("deeprest_compile_seconds_total", ("program", "phase"),
      [{"program": "train_superstep", "phase": "first_dispatch"}]),
+    ("deeprest_trace_seconds_total", ("program", "phase"),
+     [{"program": "train_superstep", "phase": "first_dispatch"},
+      {"program": "other", "phase": "init_state"}]),
+    ("deeprest_lower_seconds_total", ("program", "phase"),
+     [{"program": "train_superstep", "phase": "first_dispatch"},
+      {"program": "other", "phase": "init_state"}]),
     ("deeprest_train_collective_bytes", (), []),
     ("deeprest_train_device_bytes", ("at", "kind"), []),
     ("deeprest_train_dropout_draws", (), [{}]),
