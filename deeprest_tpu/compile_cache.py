@@ -10,6 +10,19 @@ name, pid or time in it.
   in code, so whoever runs the program can place the cache.
 - unset: ``<checkout>/.jax_cache`` (listed in ``.gitignore``), exported
   under that name so that JAX and every child process find it.
+
+The directory holds jax's entries (one a lowered module, found only after
+a process has traced and lowered the program again) and, in
+``deeprest-kept/``, the training superstep's executable as
+``deeprest_tpu/train/kept.py`` keeps it: one file a program, configuration,
+mesh and argument shapes, found by a key made before anything is traced
+(the package's sources, the versions, the whole ``Config`` but its seed, the
+mesh, the arguments' types, the compiler's flags).  A file of another key
+is stale: the process traces as before and overwrites it, so the directory
+does not grow with edits.  Deleting ``deeprest-kept/``, any file in it, or
+the whole cache is always safe.  The tests share the cache on purpose and
+give each test a ``deeprest-kept/`` of its own (tests/conftest.py): the
+key cannot see a function a test replaced at run time.
 """
 
 from __future__ import annotations

@@ -18,7 +18,9 @@ This module holds the three pieces that are not one trainer's:
   ``model.init`` runs one by one, a caller's own programs), so the label
   has a dozen values; ``cache`` is ``hit`` or ``miss`` by the cache's own
   event inside that compilation, ``uncached`` where it sent neither (the
-  cache is off, or the entry was under its thresholds and not written).
+  cache is off, or the entry was under its thresholds and not written),
+  ``kept`` for an executable that ``train/kept.py`` loaded from its store
+  before anything was traced (:func:`count_kept_load`: no event of jax's).
   With the span recorder on, a compilation under an open span is also a
   span ``deeprest-jax/compile`` tagged ``program`` and ``cache``, a child
   of that span, entered when the compilation begins: it lies on the
@@ -76,6 +78,8 @@ PROGRAM_BYTES = "deeprest_train_program_bytes"
 KERNEL_OPERAND_BYTES = "deeprest_train_kernel_operand_bytes"
 TIME_REVERSALS = "deeprest_train_time_reversals"
 KERNEL_EDGE_PASSES = "deeprest_train_kernel_edge_passes"
+KEPT_EXECUTABLES = "deeprest_train_kept_executables_total"
+KEPT = "kept"           # the `cache` of an executable train/kept.py loaded
 
 _CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
                  "/jax/compilation_cache/cache_misses": "miss"}
@@ -233,6 +237,24 @@ class _Listener:
                             phase=current_phase())
 
 
+def compilations_of(program: str) -> dict[str, int]:
+    """The process's compilations of ``program`` so far, by ``cache``."""
+    found: dict[str, int] = {}
+    for labels, n in _series(COMPILATIONS):
+        if labels["program"] == program:
+            found[labels["cache"]] = found.get(labels["cache"], 0) + int(n)
+    return found
+
+
+def count_kept_load(program: str, seconds: float) -> None:
+    """An executable of ``program`` came from train/kept.py's store in
+    ``seconds``: counted where the compilation cache's load would have
+    been, in the phase open on this thread, with ``cache="kept"``."""
+    where = current_phase()
+    _compilations().inc(program=program, phase=where, cache=KEPT)
+    _seconds(_COMPILE).inc(seconds, program=program, phase=where)
+
+
 _listener: _Listener | None = None
 _install_lock = threading.Lock()
 
@@ -287,7 +309,8 @@ def setup_table() -> dict:
     ``microbatches`` of an optimizer update and the ``carry_bytes`` of its
     gradient accumulator (``accumulation``; left out with one microbatch an
     update); jax's pipeline by program and phase (``compilations``: count,
-    seconds and misses of the compilations, ``trace_seconds`` and
+    seconds and misses of the compilations, how many of them were
+    executables train/kept.py loaded (``kept``), ``trace_seconds`` and
     ``lower_seconds`` of the two stages before them, a row also where a
     program traced and nothing compiled; the costliest first); device
     memory at the
@@ -302,12 +325,14 @@ def setup_table() -> dict:
         return compilations.setdefault(
             (found["program"], found["phase"]),
             {"program": found["program"], "phase": found["phase"],
-             "count": 0, "misses": 0, "seconds": 0.0, "trace_seconds": 0.0,
-             "lower_seconds": 0.0})
+             "count": 0, "misses": 0, "kept": 0, "seconds": 0.0,
+             "trace_seconds": 0.0, "lower_seconds": 0.0})
 
     for s, n in _series(COMPILATIONS):
         row(s)["count"] += int(n)
-        if s["cache"] != "hit":
+        if s["cache"] == KEPT:
+            row(s)["kept"] += int(n)
+        elif s["cache"] != "hit":
             row(s)["misses"] += int(n)
     for key, name in (("seconds", COMPILE_SECONDS),
                       ("trace_seconds", TRACE_SECONDS),
@@ -389,12 +414,15 @@ def format_setup(table: dict) -> str:
 
         parts.append(
             f"{total('count')} compilations in {total('seconds'):.3f} s, "
-            f"{total('misses')} not from the cache, traced "
+            f"{total('misses')} not from the cache"
+            + (f", {total('kept')} kept" if total("kept") else "")
+            + ", traced "
             f"{total('trace_seconds'):.3f} s, lowered "
             f"{total('lower_seconds'):.3f} s ("
             + ", ".join(f"{r['program']} in {r['phase']} {r['count']} in "
                         f"{r['seconds']:.3f} s"
                         + (f", {r['misses']} missed" if r["misses"] else "")
+                        + (f", {r['kept']} kept" if r["kept"] else "")
                         + f", traced {r['trace_seconds']:.3f} s, lowered "
                         f"{r['lower_seconds']:.3f} s"
                         for r in rows) + ")")
@@ -422,4 +450,5 @@ __all__ = ["OTHER", "UNCACHED", "phase", "current_phase", "install",
            "OPTIMIZER_ROWS", "ACCUMULATION", "GATHER_PIECES",
            "PROJECTION_COLUMNS",
            "FIRST_DISPATCH_SECONDS", "DEVICE_BYTES", "PROGRAM_BYTES",
-           "KERNEL_OPERAND_BYTES", "TIME_REVERSALS", "KERNEL_EDGE_PASSES"]
+           "KERNEL_OPERAND_BYTES", "TIME_REVERSALS", "KERNEL_EDGE_PASSES",
+           "KEPT_EXECUTABLES", "KEPT", "compilations_of", "count_kept_load"]

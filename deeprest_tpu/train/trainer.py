@@ -55,6 +55,7 @@ from deeprest_tpu.parallel.sharding import (
     carried_rows_split, gather_pieces, shard_params, state_sharding,
 )
 from deeprest_tpu.train.data import DatasetBundle, eval_window_indices
+from deeprest_tpu.train.kept import KeptJit
 from deeprest_tpu.train.metrics import Throughput, mae_report
 
 
@@ -673,7 +674,18 @@ class Trainer:
 
         self._train_step = jax.jit(train_step, donate_argnums=0)
         self._train_step_indexed = jax.jit(train_step_indexed, donate_argnums=0)
-        self._superstep = jax.jit(train_superstep, donate_argnums=0)
+        # The superstep's executable is kept from one process to the next
+        # (train/kept.py: a later process of the same key loads it and
+        # traces nothing).  Its key takes the WHOLE config, so that a new
+        # field enters by itself, but for the seed: that makes the state
+        # and the host's shuffles, both arguments of the program.
+        self._superstep = KeptJit(
+            train_superstep, self.mesh,
+            (dataclasses.replace(
+                self.config,
+                train=dataclasses.replace(self.config.train, seed=0)),
+             self.model_config),
+            donate_argnums=0)
         self._stale_rows = jax.jit(stale_rows)
         self._eval_step = jax.jit(eval_step)
         self._eval_step_indexed = jax.jit(eval_step_indexed)

@@ -29,6 +29,7 @@ import numpy as np
 import pytest
 
 from deeprest_tpu.data.schema import Bucket, MetricSample, Span
+from deeprest_tpu.train import kept
 
 
 # Folding G window batches into the rows of one dot changes nothing in the
@@ -51,6 +52,29 @@ def assert_fold_equal(actual, desired):
     atol = FOLD_ULPS * np.finfo(np.float32).eps * float(
         np.max(np.abs(desired)))
     np.testing.assert_allclose(actual, desired, rtol=0.0, atol=atol)
+
+
+# The superstep's executable is kept beside the compilation cache under a
+# key made of the program's FILES (deeprest_tpu/train/kept.py), which cannot
+# see a function a test replaced at run time; and the suite shares one cache
+# directory on purpose.  So every test gets a store of its own, empty when it
+# starts: it traces what it patched, the suite does not depend on its order,
+# and nothing is left under `.jax_cache/deeprest-kept/`.  Outside every test
+# (a fixture of a wider scope that dispatches) there is no store at all.
+STORE_DIR_OF_THE_PROGRAM = kept.store_dir       # beside the compile cache
+
+
+@pytest.fixture(scope="session", autouse=True)
+def _no_kept_store_outside_a_test():
+    kept.store_dir = lambda: None
+    yield
+    kept.store_dir = STORE_DIR_OF_THE_PROGRAM
+
+
+@pytest.fixture(autouse=True)
+def _kept_store_of_its_own(monkeypatch, tmp_path):
+    monkeypatch.setattr(kept, "store_dir",
+                        lambda: str(tmp_path / kept.SUBDIR))
 
 
 @pytest.fixture

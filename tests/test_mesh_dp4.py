@@ -827,7 +827,9 @@ LISTED_IN_EVERY_TRAIN_CELL = (
     "gru_kernel_vmem_pct.train", "dropout_draws_per_step.train",
     "time_reversals_per_step.train", "kernel_edge_passes_per_step.train",
     # ISSUE 50: set-up's seconds in jax's pipeline, by stage
-    "trace_s.train", "lower_s.train", "superstep_build_s.train")
+    "trace_s.train", "lower_s.train", "superstep_build_s.train",
+    # ISSUE 51: whether the process loaded the kept superstep
+    "superstep_loaded.train")
 
 
 @pytest.mark.parametrize("name", sorted(MESH_CELLS))

@@ -904,19 +904,67 @@ def test_setup_table_rows_carry_the_three_stages(by_hand):
     table = obs_setup.setup_table()
     assert table["compilations"] == [
         {"program": "train_superstep", "phase": "first_dispatch", "count": 1,
-         "misses": 1, "seconds": 0.61, "trace_seconds": 7.9,
+         "misses": 1, "kept": 0, "seconds": 0.61, "trace_seconds": 7.9,
          "lower_seconds": 1.5},
         {"program": "train_superstep", "phase": "epoch", "count": 0,
-         "misses": 0, "seconds": 0.0, "trace_seconds": 9.0,
+         "misses": 0, "kept": 0, "seconds": 0.0, "trace_seconds": 9.0,
          "lower_seconds": 0.0},
         {"program": "other", "phase": "other", "count": 1, "misses": 1,
-         "seconds": 0.25, "trace_seconds": 0.0, "lower_seconds": 0.0}]
+         "kept": 0, "seconds": 0.25, "trace_seconds": 0.0,
+         "lower_seconds": 0.0}]
     line = obs_setup.format_setup(table)
     assert ("train_superstep in first_dispatch 1 in 0.610 s, 1 missed, "
             "traced 7.900 s, lowered 1.500 s") in line
     assert "train_superstep in epoch 0 in 0.000 s, traced 9.000 s" in line
     assert ("2 compilations in 0.860 s, 2 not from the cache, traced "
             "16.900 s, lowered 1.500 s (") in line
+
+
+def test_setup_table_says_kept_where_the_superstep_was_loaded(by_hand):
+    """ISSUE 51: an executable train/kept.py loaded is counted where the
+    cache's load was (one compilation, its seconds, the phase open on the
+    thread) with ``cache="kept"``: no miss, and nothing traced or lowered
+    beside it."""
+    feed, registry = by_hand
+    with obs_setup.phase("first_dispatch"):
+        obs_setup.count_kept_load("train_superstep", 0.33)
+        feed([(_COMPILE, "jit(stale_rows)"), (_COMPILE, "jit(stale_rows)", 0.1)])
+    assert registry.get(obs_setup.COMPILATIONS).series() == {
+        ("train_superstep", "first_dispatch", "kept"): 1.0,
+        ("other", "first_dispatch", "uncached"): 1.0}
+    assert obs_setup.compilations_of("train_superstep") == {"kept": 1}
+    assert obs_setup.compilations_of("pin_state") == {}
+    table = obs_setup.setup_table()
+    assert table["compilations"][0] == {
+        "program": "train_superstep", "phase": "first_dispatch", "count": 1,
+        "misses": 0, "kept": 1, "seconds": 0.33, "trace_seconds": 0.0,
+        "lower_seconds": 0.0}
+    line = obs_setup.format_setup(table)
+    assert ("train_superstep in first_dispatch 1 in 0.330 s, 1 kept, "
+            "traced 0.000 s, lowered 0.000 s") in line
+    assert ("2 compilations in 0.430 s, 1 not from the cache, 1 kept, "
+            "traced 0.000 s") in line
+
+
+def test_the_kept_reader(monkeypatch):
+    """chipbench/readers/kept.py: the superstep's ``loaded`` count; 0 from a
+    process that traced; nothing (not an error) from a program without the
+    counter: the parent's."""
+    from chipbench.readers import kept
+    from deeprest_tpu.obs import metrics
+
+    registry = metrics.MetricsRegistry()
+    monkeypatch.setattr(metrics, "REGISTRY", registry)
+    assert kept.superstep_loaded({}) is None
+    counter = registry.counter(obs_setup.KEPT_EXECUTABLES,
+                               labelnames=("program", "result"))
+    assert kept.superstep_loaded({}) is None
+    counter.inc(program="train_superstep", result="miss")
+    counter.inc(program="train_superstep", result="stored")
+    assert kept.superstep_loaded({}) == 0
+    counter.inc(program="train_superstep", result="loaded")
+    counter.inc(program="another_program", result="loaded")
+    assert kept.superstep_loaded({}) == 1
 
 
 _STAGE_SERIES = {
@@ -1193,6 +1241,10 @@ _BENCHMARK_SERIES = [
     ("deeprest_lower_seconds_total", ("program", "phase"),
      [{"program": "train_superstep", "phase": "first_dispatch"},
       {"program": "other", "phase": "init_state"}]),
+    # ISSUE 51: what the store of kept executables did for the superstep
+    # (`loaded`, the value the reader asks for, takes a second process)
+    ("deeprest_train_kept_executables_total", ("program", "result"),
+     [{"program": "train_superstep"}]),
     ("deeprest_train_collective_bytes", (), []),
     ("deeprest_train_device_bytes", ("at", "kind"), []),
     ("deeprest_train_dropout_draws", (), [{}]),
