@@ -12,13 +12,18 @@ import numpy as np
 
 
 def phase(ctx, name: str, since: float) -> float:
-    """Log a phase's seconds with the device memory in use and its peak so
-    far; returns now."""
+    """Log a phase's seconds with the device memory in use, its peak so far
+    and what the runtime reserves for the loaded programs; a context that
+    watches memory (``run.Context``) takes a sample here; returns now."""
     now = time.perf_counter()
+    watch = getattr(ctx, "memory", None)
+    if watch is not None:
+        watch.sample(name)
     stats = ctx.device.memory_stats() or {}
     ctx.log(f"phase {name}: {now - since:.3f} s (memory in use "
             f"{stats.get('bytes_in_use', 0) / 1e9:.3f} GB, peak so far "
-            f"{stats.get('peak_bytes_in_use', 0) / 1e9:.3f} GB)")
+            f"{stats.get('peak_bytes_in_use', 0) / 1e9:.3f} GB, reserved for "
+            f"loaded programs {stats.get('bytes_reserved', 0) / 1e9:.3f} GB)")
     return now
 
 
@@ -26,9 +31,10 @@ def phase(ctx, name: str, since: float) -> float:
 def harness_only(ctx, what: str):
     """Around what the harness itself puts on the device beside the
     program's state.  The caller waits for the program's work before it
-    enters and for its own results before it leaves; if the peak of device
-    memory rose in between, the peak is no longer the program's and the run
-    fails.  (A backend that reports no peak is not judged.)"""
+    enters and for its own results before it leaves; if the most bytes a
+    chip held at one time (``ctx.memory_peak_bytes()``) rose in between, the
+    peak is no longer the program's and the run fails.  (A backend that
+    reports no memory is not judged.)"""
     before = ctx.memory_peak_bytes()
     yield
     after = ctx.memory_peak_bytes()

@@ -81,12 +81,63 @@ class Compiles:
             self.count += 1
 
 
+class MemoryWatch:
+    """The most bytes a chip held AT ONE TIME, the allocator's and the loaded
+    programs' together, to the resolution of its samples.
+
+    On this backend ``peak_bytes_in_use`` does not count what the runtime
+    reserves for a loaded program's temporaries (``bytes_reserved``, real HBM:
+    PERF.md section 6, PR 53), and the two peaks the backend keeps may lie
+    apart in time, so their sum is bytes that were never held together.
+    Between two samples of one chip the allocator's part is
+    ``peak_bytes_in_use`` if it rose in the interval, else the larger of the
+    two ``bytes_in_use``; the programs' part is the larger of the two
+    ``bytes_reserved``.  The interval holds the sum of the two parts, the
+    chip the largest interval's, the cell the fullest chip's.  What it
+    cannot see: a peak of the allocator inside an interval that does not
+    pass its own high-water mark, and a reservation made and dropped inside
+    one interval.  A backend that reports no memory reads 0, an absent key 0
+    for its part."""
+
+    KEYS = ("bytes_in_use", "peak_bytes_in_use", "bytes_reserved",
+            "peak_bytes_reserved")
+
+    def __init__(self, devices):
+        self._devices = devices         # () -> the devices to watch
+        self._last = {}                 # device id -> its last sample
+        self._high = {}                 # device id -> most bytes at one time
+        self.samples = []               # [{"at", "devices": {id: sample}}]
+
+    def sample(self, at: str) -> int:
+        """Read every chip, close the interval since its last sample;
+        returns the fullest chip's high-water mark."""
+        now = {}
+        for d in self._devices():
+            stats = d.memory_stats() or {}
+            cur = now[d.id] = {k: int(stats.get(k, 0)) for k in self.KEYS}
+            old = self._last.get(d.id, dict.fromkeys(self.KEYS, 0))
+            allocator = (cur["peak_bytes_in_use"]
+                         if cur["peak_bytes_in_use"] > old["peak_bytes_in_use"]
+                         else max(cur["bytes_in_use"], old["bytes_in_use"]))
+            programs = max(cur["bytes_reserved"], old["bytes_reserved"])
+            self._high[d.id] = max(self._high.get(d.id, 0),
+                                   allocator + programs)
+        self._last = now
+        self.samples.append({"at": at, "devices": now})
+        return self.high()
+
+    def high(self) -> int:
+        return max(self._high.values(), default=0)
+
+
 class Context:
     """What a runner gets: the cell's data, the seed, the clock's origin and
     the device."""
 
     def __init__(self, loaded: dict, seed: int, seconds: float, trace: bool,
                  device, peaks: dict | None, compiles: Compiles):
+        import jax
+
         self.cell = loaded["cell"]
         self.config = loaded["config"]
         self.mix = loaded["mix"]
@@ -99,17 +150,16 @@ class Context:
         self.peaks = peaks
         self.compiles = compiles
         self.log = log
+        self.memory = MemoryWatch(jax.devices)
 
     def generator(self):
         return importlib.import_module(
             f"chipbench.generators.{self.mix['generator']}")
 
     def memory_peak_bytes(self) -> int:
-        """Peak bytes in use on the fullest chip, as the backend reports."""
-        import jax
-
-        return int(max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-                       for d in jax.devices()))
+        """The most bytes the fullest chip has held at one time so far
+        (``MemoryWatch``: the one place the rule lives); takes a sample."""
+        return self.memory.sample("memory_peak_bytes")
 
     def key_seed(self) -> int:
         """The seed folded into what a 32-bit PRNG key takes."""
@@ -159,13 +209,15 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     record["memory_peak_bytes"] = int(out["memory_peak_bytes"])
     log("memory", json.dumps({k: v for k, v in
                               (dev.memory_stats() or {}).items()
-                              if k in ("peak_bytes_in_use", "bytes_limit")}))
+                              if k in ("peak_bytes_in_use", "bytes_limit",
+                                       "peak_bytes_reserved")}))
 
     bench, name = loaded["bench"], loaded["cell"]["name"]
     metrics, result = {}, {}
     if trace:
         evidence = out["evidence"]
-        evidence.update(config=loaded["config"], peaks=peaks, log=log)
+        evidence.update(config=loaded["config"], peaks=peaks, log=log,
+                        memory_samples=ctx.memory.samples)
         for m in bench["per_layer"]:
             with open(os.path.join(HERE, "layer_metrics",
                                    m["name"] + ".json")) as fh:
