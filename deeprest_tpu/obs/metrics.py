@@ -123,6 +123,12 @@ class Gauge(_Metric):
     def dec(self, amount: float = 1.0, **labels) -> None:
         self.inc(-amount, **labels)
 
+    def clear(self) -> None:
+        """Drop every series: for a gauge whose label values describe one
+        thing at a time, before another thing's are set."""
+        with self._lock:
+            self._series.clear()
+
     def set_max(self, value: float, **labels) -> None:
         """High-water-mark update (batcher max_batch_windows style)."""
         key = self._key(labels)

@@ -26,7 +26,9 @@ This module holds the three pieces that are not one trainer's:
   of that span, entered when the compilation begins: it lies on the
   profiler's clock like every other (outside every span it is counted and
   no span: it would be a trace of its own in the plane's exports).  A compilation in phase ``epoch``
-  after the first epoch is a recompile, with its name.
+  after the first epoch is a recompile, with its name: a program compiled
+  twice.  (The superstep an epoch meets after a restage onto a NEW staged
+  program is compiled in ``first_dispatch``, like the trainer's first.)
   The two stages before a compilation are heard the same way and kept
   beside it: ``deeprest_trace_seconds_total{program,phase}`` (Python
   tracing the function to a jaxpr) and
@@ -73,6 +75,9 @@ ACCUMULATION = "deeprest_train_accumulation"
 GATHER_PIECES = "deeprest_train_projection_gather_pieces"
 PROJECTION_COLUMNS = "deeprest_train_projection_columns"
 FIRST_DISPATCH_SECONDS = "deeprest_train_first_dispatch_seconds"
+SUPERSTEP_PROGRAMS = "deeprest_train_superstep_programs_total"
+SUPERSTEP_FIRST_DISPATCH_SECONDS = (
+    "deeprest_train_superstep_first_dispatch_seconds")
 DEVICE_BYTES = "deeprest_train_device_bytes"
 PROGRAM_BYTES = "deeprest_train_program_bytes"
 KERNEL_OPERAND_BYTES = "deeprest_train_kernel_operand_bytes"
@@ -302,7 +307,10 @@ def setup_table() -> dict:
     of the last ``stage_dataset`` and, for a sparse corpus, the form its
     rule chose (``sparse_feed``: ``form``, and the columns ``live``,
     ``padded``, ``bound``, ``contracted``, ``total``), which staging of the
-    process that was (``nth``), of each first dispatch; what the last
+    process that was (``nth``), of each first dispatch; the supersteps a
+    life dispatched for the first time, in order, each with its staged
+    program and the seconds of that first dispatch (``programs``: more
+    than one where a restage changed the table's width or the form); what the last
     epoch's off-table pass found and did (``off_table``: the ``stale``
     rows, the ``bound`` up to which they are visited row by row, the
     ``trips`` of a dispatch); under gradient accumulation the
@@ -350,6 +358,11 @@ def setup_table() -> dict:
     if columns.get("total"):            # a sparse corpus was staged
         compact = columns["contracted"] < columns["total"]
         feed = {"form": "compact" if compact else "dense", **columns}
+    programs = sorted(
+        ({"nth": int(s["nth"]), "form": s["form"], "width": int(s["width"]),
+          "seconds": v}
+         for s, v in _series(SUPERSTEP_FIRST_DISPATCH_SECONDS)),
+        key=lambda found: found["nth"])
     table = {
         "init_state_seconds": _by(INIT_STATE_SECONDS, "phase"),
         "stage_seconds": stage[0][1] if stage else None,
@@ -360,6 +373,7 @@ def setup_table() -> dict:
         "accumulation": (accumulation
                          if accumulation.get("microbatches", 1) > 1 else None),
         "first_dispatch_seconds": _by(FIRST_DISPATCH_SECONDS, "program"),
+        "programs": programs,
         "compilations": sorted(
             compilations.values(),
             key=lambda r: -(r["seconds"] + r["trace_seconds"]
@@ -407,6 +421,14 @@ def format_setup(table: dict) -> str:
     if "first_dispatch_seconds" in table:
         parts.append("first dispatch "
                      + seconds(table["first_dispatch_seconds"]) + " s")
+    found = table.get("programs", ())
+    if len(found) > 1:      # one says nothing the line does not say already
+        parts.append(
+            f"programs {len(found)} ("
+            + ", ".join(f"{p['form']} {p['width']}" if p["form"] == "compact"
+                        else p["form"] for p in found)
+            + "), first dispatched in "
+            + ", ".join(f"{p['seconds']:.3f}" for p in found) + " s")
     rows = table.get("compilations", ())
     if rows:
         def total(key: str):
@@ -449,6 +471,7 @@ __all__ = ["OTHER", "UNCACHED", "phase", "current_phase", "install",
            "INIT_STATE_SECONDS", "STAGE_SECONDS", "STAGINGS",
            "OPTIMIZER_ROWS", "ACCUMULATION", "GATHER_PIECES",
            "PROJECTION_COLUMNS",
-           "FIRST_DISPATCH_SECONDS", "DEVICE_BYTES", "PROGRAM_BYTES",
+           "FIRST_DISPATCH_SECONDS", "SUPERSTEP_PROGRAMS",
+           "SUPERSTEP_FIRST_DISPATCH_SECONDS", "DEVICE_BYTES", "PROGRAM_BYTES",
            "KERNEL_OPERAND_BYTES", "TIME_REVERSALS", "KERNEL_EDGE_PASSES",
            "KEPT_EXECUTABLES", "KEPT", "compilations_of", "count_kept_load"]

@@ -696,6 +696,12 @@ class StreamingTrainer:
         # is W× less transfer than shipping overlapping windows even for
         # a single epoch (re-staged each refresh — the series grew).
         staged = self.trainer.stage_dataset(bundle)
+        stage = self.trainer.last_stage
+        if stage.get("restage") and stage.get("program") == "new":
+            print(f"stream: refresh {self._refresh_count} staged a new "
+                  f"program ({stage.get('form', 'base')} "
+                  f"{stage['width']}): its first dispatch traces, "
+                  "compiles or loads the superstep")
         # The stream joins the trainer's elastic fault barrier
         # (TrainConfig.elastic): a device loss mid-fine-tune remeshes,
         # restores the newest durable checkpoint (a mid-refresh snapshot

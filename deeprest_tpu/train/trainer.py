@@ -254,7 +254,6 @@ class Trainer:
         self.tx = optax.adam(config.train.learning_rate)
         self.mesh = mesh if mesh is not None else make_mesh(config.mesh)
         self.throughput = Throughput()
-        self._warmed = False       # first-ever step (jit compile) excluded
         self._global_step = 0      # host-side mirror of state.step for logging
         # Per-step losses of the most recent train_epoch (np [K], one host
         # readback per epoch/superstep) — the superstep-vs-per-step parity
@@ -267,10 +266,21 @@ class Trainer:
         # How often stage_dataset has run, its last call's compact table
         # (host copy; None for a feed without one) and what the rule of the
         # compact form decided there (empty for a feed that is not sparse):
-        # the stage span's tags.
+        # the stage span's tags.  THE PROGRAM OF THIS STAGING is what that
+        # call decided of the programs that read the staged base: the form
+        # (`compact` or `dense` for a sparse corpus, `base` for a dense
+        # staged one, None where nothing was staged) and the width they
+        # contract over.  (What a mesh splits of the rows follows from the
+        # width and the mesh, and a new mesh builds the programs anew.)  A
+        # life that restages may meet several; the first-dispatch books and
+        # the gauges of _publish_program are kept by it (_build_programs).
         self._stagings = 0
         self._staged_table: np.ndarray | None = None
         self._staged_form: dict = {}
+        self._staged_program: tuple = (None, 0)
+        # The tags of the last stage_dataset call's span, for a caller that
+        # logs them with the recorder off (train/stream.py).
+        self.last_stage: dict = {}
         # Whether a train_epoch has finished (device memory is read once,
         # after the first).
         self._epoch_finished = False
@@ -321,12 +331,15 @@ class Trainer:
         """
         self.model = QuantileGRU(config=self.model_config, mesh=self.mesh)
         quantiles = self.model_config.quantiles
-        # the epoch span's tag; whether this mesh's superstep has been
-        # read into the gauges of _publish_program; the programs built
-        # here that have been dispatched once (_first_dispatch)
+        # the epoch span's tag; the staged program whose superstep the
+        # gauges of _publish_program describe (None: none yet); the
+        # programs built here that have been dispatched once, by name and
+        # by the staged program they ran on (_first_dispatch), and of them
+        # the supersteps in the order this build met them
         self._mesh_tag = "x".join(str(self.mesh.shape[a]) for a in AXES)
-        self._program_published = False
-        self._dispatched_once: set[str] = set()
+        self._published_program: tuple | None = None
+        self._dispatched_once: set[tuple] = set()
+        self._superstep_programs: list[tuple] = []
 
         def pin_state(state: TrainState,
                       carried_rows: bool = False) -> TrainState:
@@ -787,6 +800,19 @@ class Trainer:
             "superstep's and the per-step programs' with the wait for "
             "that first dispatch)",
             labelnames=("program",))
+        self._m_superstep_programs = obs_metrics.REGISTRY.counter(
+            obs_setup.SUPERSTEP_PROGRAMS,
+            "supersteps a trainer dispatched for the first time, by the "
+            "program of the staging they ran on: the form of the staged "
+            "base and the width they contract over (a life whose live set "
+            "outgrows a table, or the compact form, meets more than one)",
+            labelnames=("form", "width"))
+        self._m_superstep_first_dispatch = obs_metrics.REGISTRY.gauge(
+            obs_setup.SUPERSTEP_FIRST_DISPATCH_SECONDS,
+            "host seconds of the first dispatch of the nth superstep the "
+            "programs of this build met (trace, lower, compile or load, "
+            "enqueue, the wait for it), by its staged program",
+            labelnames=("nth", "form", "width"))
         self._m_device_bytes = obs_metrics.REGISTRY.gauge(
             obs_setup.DEVICE_BYTES,
             "device memory of the mesh's fullest device (in_use, peak) as "
@@ -852,25 +878,59 @@ class Trainer:
                 sizes.append(int(probe()))
         return sum(sizes) if sizes else None
 
+    def _dispatch_key(self, program) -> tuple:
+        """(the jitted ``program``'s name, the staged program it runs on):
+        ``pin_state`` reads no staged base and is one program a build."""
+        on = None if program is self._pin_state else self._staged_program
+        return program.__name__, on
+
+    def _dispatched_before(self, program) -> bool:
+        """Whether the jitted ``program`` has run on the program of this
+        staging since the programs were built."""
+        return self._dispatch_key(program) in self._dispatched_once
+
     @contextlib.contextmanager
     def _first_dispatch(self, program):
-        """Round the first call of the jitted ``program`` since the
-        programs were built: a span ``train.first_dispatch`` tagged with
-        its name, the set-up phase ``first_dispatch`` (so what it compiles
-        or loads is counted there), its seconds in
-        ``deeprest_train_first_dispatch_seconds{program}``.  Nothing round
-        a later call."""
-        name = program.__name__
-        if name in self._dispatched_once:
+        """Round the first call of the jitted ``program`` ONCE A PROGRAM:
+        the first since the programs were built on the program of this
+        staging (``_staged_program``; a restage that keeps it changes
+        nothing, one that comes back to a program this build has
+        dispatched is no first dispatch).  A span ``train.first_dispatch``
+        tagged with its name, the set-up phase ``first_dispatch`` (so what
+        it traces, lowers, compiles or loads is counted there), its
+        seconds in ``deeprest_train_first_dispatch_seconds{program}``.
+        The superstep's is also tagged ``nth_program`` (which superstep of
+        this build, from 1), ``form`` and ``width``, adds one to
+        ``deeprest_train_superstep_programs_total{form,width}`` and keeps
+        its seconds in
+        ``deeprest_train_superstep_first_dispatch_seconds{nth,form,width}``.
+        Nothing round a later call."""
+        if self._dispatched_before(program):
             yield
             return
+        name = program.__name__
+        tags = {"program": name}
+        labels = None
+        if program is self._superstep:
+            form, width = self._staged_program
+            labels = {"form": str(form), "width": width}
+            tags.update(nth_program=len(self._superstep_programs) + 1,
+                        **labels)
         clock = obs_metrics.Stopwatch()
         with obs_spans.RECORDER.span("train.first_dispatch",
-                                     "deeprest-trainer", {"program": name}), \
+                                     "deeprest-trainer", tags), \
                 obs_setup.phase("first_dispatch"):
             yield
         self._m_first_dispatch.set(clock.elapsed(), program=name)
-        self._dispatched_once.add(name)
+        self._dispatched_once.add(self._dispatch_key(program))
+        if labels is not None:
+            if not self._superstep_programs:
+                # the gauge lists ONE build's programs: the newest's
+                self._m_superstep_first_dispatch.clear()
+            self._superstep_programs.append(self._staged_program)
+            self._m_superstep_programs.inc(**labels)
+            self._m_superstep_first_dispatch.set(
+                clock.elapsed(), nth=tags["nth_program"], **labels)
 
     def _publish_device_bytes(self, at: str) -> None:
         """``deeprest_train_device_bytes{at}``: what the fullest of this
@@ -889,7 +949,11 @@ class Trainer:
     def _publish_program(self, state, steps: int = 1, x_base=None) -> None:
         """What the compiler made of the superstep this epoch dispatched
         (``steps``: the trips of its scan, updates under accumulation),
-        once for each build of the programs; first, from the shapes alone,
+        ONCE A PROGRAM: the epoch driver calls it whenever the program of
+        this staging is not the one the gauges describe (the first epoch
+        of a build of the programs, and the first after a restage that
+        changed the program, the way back included), so every gauge set
+        here describes the superstep being dispatched; first, from the shapes alone,
         ``deeprest_train_accumulation{kind}`` (:meth:`_accum_carry_bytes`
         on the base ``x_base`` the dispatch read); then, from the executable
         the dispatch left in the jit's cache (:meth:`_dispatched_executable`:
@@ -915,7 +979,12 @@ class Trainer:
         VJP spans the bias add and the join)."""
         from deeprest_tpu.obs import profiler
 
-        self._program_published = True
+        # another program's kernels, collectives and draws say nothing of
+        # this one's
+        for gauge in (self._m_kernel_operand_bytes, self._m_collective_bytes,
+                      self._m_dropout_draws):
+            gauge.clear()
+        self._published_program = self._staged_program
         self._m_accumulation.set(self.config.train.grad_accum_windows,
                                  kind="microbatches")
         self._m_accumulation.set(self._accum_carry_bytes(state, x_base),
@@ -966,7 +1035,8 @@ class Trainer:
 
     def _publish_optimizer_rows(self, x_base, stale=None, steps=0) -> None:
         """The epoch's ``deeprest_train_optimizer_rows``, for a staged
-        sparse corpus.  ``stale`` is :func:`stale_rows` of the state the
+        sparse corpus, once an epoch and so of the program that epoch
+        dispatched.  ``stale`` is :func:`stale_rows` of the state the
         epoch began with on the base's table (a device scalar, read here,
         after the epoch; the superstep keeps it as it finds it), ``steps``
         the optimizer updates of a dispatch (its steps, or its groups of
@@ -979,7 +1049,10 @@ class Trainer:
         row by row up to :func:`off_table_bound` (``bound``).  ``stale``
         None where no table was consulted (the per-step path, a base in
         its dense form): every step ran over and wrote
-        all F rows, and ``stale``, ``bound`` and ``trips`` are left as
+        all F rows; on a base in its dense form, which HAS no table,
+        ``stale``, ``bound`` and ``trips`` are set to 0 (a life that
+        outgrew the compact form would else keep its last table's), on
+        the per-step path over a compact base they are left as
         they were.  ``per_chip``: the rows whose Adam one chip ran a step,
         the carried rows' share of the table under a ``data`` axis
         (parallel/sharding.py), else ``updated``; beside it, where they are
@@ -1002,6 +1075,9 @@ class Trainer:
                                        kind="bound")
             self._m_optimizer_rows.set(off_table_trips(*shapes, stale),
                                        kind="trips")
+        elif live_cols_of(x_base) is None:
+            for kind in ("stale", "bound", "trips"):
+                self._m_optimizer_rows.set(0, kind=kind)
         self._m_optimizer_rows.set(updated, kind="updated")
         self._m_optimizer_rows.set(
             x_base.width // split if split > 1 else updated, kind="per_chip")
@@ -1420,10 +1496,17 @@ class Trainer:
         rule of the compact form decided (``form`` ``compact`` or
         ``dense``, the ``live`` set's size, the ``padded`` width it
         weighed and the ``bound`` it held it to, ``model_axis`` where the
-        mesh's ``model`` axis decided) and, from one table to the
-        next, the rows that ``left`` and ``entered`` it — the rows that
+        mesh's ``model`` axis decided) and, from one sparse staging to the
+        next, the rows that ``left`` and ``entered`` the table (across a
+        change of form too: against the dense form, which contracts over
+        every column, nothing leaves) — the rows that
         left are the ones the carried moments make stale
-        (:func:`stale_rows`); its host seconds are the gauge
+        (:func:`stale_rows`); ``program`` says what the call decided of the
+        programs that read the staged base (``_staged_program``: the form
+        and the width), ONCE A PROGRAM: ``new`` (this build of the
+        programs has not dispatched on it: the next dispatch is a first
+        dispatch, :meth:`_first_dispatch`), ``same`` (the last staging's)
+        or ``back`` (an earlier one's: no first dispatch); its host seconds are the gauge
         ``deeprest_train_last_stage_seconds``, and device memory as it
         returns ``deeprest_train_device_bytes{at="stage"}``; what it
         compiles is counted in the set-up phase ``stage``.
@@ -1433,18 +1516,33 @@ class Trainer:
                                      "deeprest-trainer") as span, \
                 obs_setup.phase("stage"):
             before, self._staged_table = self._staged_table, None
+            was_sparse, was = bool(self._staged_form), self._staged_program
             self._staged_form = {}
             staged = self._stage(bundle)
             table = self._staged_table
+            width = (len(table) if table is not None else
+                     0 if staged is None else bundle.feature_dim)
+            self._staged_program = (
+                self._staged_form.get("form", None if staged is None
+                                      else "base"), width)
             tags = {"restage": self._stagings > 0,
-                    "nth": self._stagings + 1,
-                    "width": (len(table) if table is not None else
-                              0 if staged is None else bundle.feature_dim),
-                    **self._staged_form}
-            if before is not None and table is not None:
-                tags["left"] = int(np.setdiff1d(before, table).size)
-                tags["entered"] = int(np.setdiff1d(table, before).size)
+                    "nth": self._stagings + 1, "width": width,
+                    **self._staged_form,
+                    "program": (
+                        "same" if self._stagings and was == self._staged_program
+                        else "back" if any(on == self._staged_program
+                                           for _, on in self._dispatched_once)
+                        else "new")}
+            if was_sparse and self._staged_form:
+                # a staging in the dense form holds every column
+                every = np.arange(int(bundle.sparse_capacity
+                                      or bundle.feature_dim))
+                held, holds = (every if t is None else t
+                               for t in (before, table))
+                tags["left"] = int(np.setdiff1d(held, holds).size)
+                tags["entered"] = int(np.setdiff1d(holds, held).size)
             span.tag(**tags)
+        self.last_stage = tags
         self._stagings += 1
         self._m_stagings.inc()
         self._m_stage_seconds.set(clock.elapsed())
@@ -1566,7 +1664,9 @@ class Trainer:
         ``DxExM`` and ``accum`` (the microbatches an optimizer update,
         ``grad_accum_windows``), with a child per phase when the recorder
         is on, the phase-seconds counters always.  What compiles in it is counted in
-        the set-up phase ``epoch`` (after the first epoch: a recompile);
+        the set-up phase ``epoch`` (after the first epoch: a recompile; the
+        first dispatch on a new staged program opens ``first_dispatch``
+        inside it);
         device memory after the first one to finish is
         ``deeprest_train_device_bytes{at="first_epoch"}``."""
         with obs_setup.phase("epoch"), \
@@ -1617,9 +1717,6 @@ class Trainer:
         log_every = self.config.train.log_every_steps
         losses = []
         steps = 0
-        measuring = self._warmed
-        if measuring:
-            self.throughput.start()
         if staged is None:
             def host_batches():
                 # feed_global_batch (inside prefetch): sharded device_put on
@@ -1658,11 +1755,17 @@ class Trainer:
                                          depth=self.config.train.prefetch_depth)
             program, fixed = self._train_step_indexed, (x_base, y_base)
 
+        # The program's first step on this staging's program pays jit
+        # trace+compile: it is kept out of the throughput window, so
+        # steps/sec reflects steady state.
+        measuring = self._dispatched_before(program)
+        if measuring:
+            self.throughput.start()
         # This driver feeds as it dispatches (the prefetch generator
         # builds and ships each batch), so the whole loop is `dispatch`
         # and `plan_build`/`plan_h2d` stay 0.
         with phase("dispatch"), contextlib.ExitStack() as first:
-            if not self._warmed:
+            if not measuring:
                 first.enter_context(self._first_dispatch(program))
             for batch in batches:
                 state, loss = program(state, *fixed, *batch)
@@ -1673,13 +1776,9 @@ class Trainer:
                 self._fault_check(1)
                 losses.append(loss)
                 self._global_step += 1
-                if not self._warmed:
-                    # The first step ever pays jit trace+compile; keep it
-                    # out of the throughput window so steps/sec reflects
-                    # steady state.
+                if not measuring:
                     jax.block_until_ready(loss)
                     first.close()
-                    self._warmed = True
                     self.throughput.start()
                     measuring = True
                 else:
@@ -1759,14 +1858,18 @@ class Trainer:
         if live_cols_of(x_base) is not None and w_ih_leaves(state.params):
             with self._first_dispatch(self._stale_rows):
                 stale = self._stale_rows(state.opt_state, x_base.live)
-        measuring = self._warmed
+        # A superstep's first dispatch on this staging's program pays the
+        # scan's trace+compile (or its load): kept out of the throughput
+        # window, in an epoch that restaged onto a new program as in the
+        # trainer's first.
+        measuring = self._dispatched_before(superstep)
         if measuring:
             self.throughput.start()
         chunk_losses = []
         steps = updates = 0
         accum = cfg.grad_accum_windows
         with phase("dispatch"), contextlib.ExitStack() as first:
-            if not self._warmed:
+            if not measuring:
                 first.enter_context(self._first_dispatch(superstep))
             for c in range(skip_chunks, starts.shape[0]):
                 real = min(s, num_steps - c * s)
@@ -1780,11 +1883,9 @@ class Trainer:
                 # barrier rolls back.
                 self._fault_check(real)
                 chunk_losses.append(losses_c)
-                if not self._warmed:
-                    # First-ever superstep pays the scan's trace+compile.
+                if not measuring:
                     jax.block_until_ready(losses_c)
                     first.close()
-                    self._warmed = True
                     self.throughput.start()
                     measuring = True
                 else:
@@ -1810,7 +1911,7 @@ class Trainer:
         # what this epoch dispatched, for profile_epoch to lower again
         self._dispatched = (superstep,
                             (x_base, y_base, starts_d, weights_d, 0))
-        if not self._program_published:
+        if self._published_program != self._staged_program:
             # the host reads the executable while the device runs the
             # epoch's last chunk
             self._publish_program(state, s // accum, x_base)
@@ -1836,7 +1937,7 @@ class Trainer:
         the dispatch left in the jit's caches, and ``compile`` the
         executable that ran: nothing is compiled again
         (tests/test_mesh_dp4.py counts the backend's compiles).  Called by
-        :meth:`profile_epoch`, and once for each build of the programs by
+        :meth:`profile_epoch`, and once a program by
         :meth:`_publish_program`."""
         program, args = self._dispatched
         return program.lower(state, *args).compile()

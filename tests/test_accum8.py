@@ -246,7 +246,8 @@ def test_the_cells_files_say_what_the_runner_reads():
                                                  sparse["params"])
     assert mix["runner"] == "train_accum"
     bench = load("BENCHMARK.json")
-    cell = bench["workloads"][-1]
+    # ISSUE 54's cell was appended behind it
+    cell = bench["workloads"][-2]
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
         "tenk-train-accum8", "endpoints-10k-accum8", "week-sparse-accum8", 1)
     listed = {m["name"]: m["workloads"]

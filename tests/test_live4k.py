@@ -484,8 +484,8 @@ def test_the_all_live_cell_is_endpoints_10k_on_a_mix_with_no_dead_column():
     `endpoints-10k` x a mix that is `week-sparse`'s in everything but the
     live set, through the `train` runner and the `corpus` generator as they
     stand; the rule sends it to the dense form; the cell is in the lists of
-    the metrics it reports, and the benchmark holds eight cells, two of them
-    on four chips."""
+    the metrics it reports, and the benchmark holds ten cells (ISSUE 54's
+    the tenth), two of them on four chips."""
     bench = _load("BENCHMARK.json")
     cell = {c["name"]: c for c in bench["workloads"]}["tenk-train-alllive"]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
@@ -508,15 +508,16 @@ def test_the_all_live_cell_is_endpoints_10k_on_a_mix_with_no_dead_column():
     listed = ("train_steps_per_s",
               "hbm_peak_gb") + LISTED_IN_EVERY_TRAIN_CELL
     for name in listed:
-        # ISSUE 44's two cells, in the order appended, then ISSUE 48's
-        assert metrics[name]["workloads"][-3:] == [
+        # ISSUE 44's two cells, in the order appended, then ISSUE 48's and
+        # ISSUE 54's (whose window runs this cell's dense form)
+        assert metrics[name]["workloads"][-4:] == [
             "tenk-train-live4k-dp4", "tenk-train-alllive",
-            "tenk-train-accum8"], name
+            "tenk-train-accum8", "tenk-retrain-growing"], name
     for name, m in metrics.items():
         # (ISSUE 48's `updates_per_epoch.train` lists every cell)
         if name not in listed + ("updates_per_epoch.train",):
             assert "tenk-train-alllive" not in m.get("workloads", ()), name
-    assert len(bench["workloads"]) == 9
+    assert len(bench["workloads"]) == 10
     assert sum(c["chips"] == 4 for c in bench["workloads"]) == 2
     limits = _load("chipbench", "limits", "tenk-train-alllive.json")
     assert limits["cell"] == "tenk-train-alllive"

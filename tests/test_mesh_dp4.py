@@ -211,7 +211,7 @@ def test_the_widest_table_under_data4_is_recorded_as_it_ran(widest,
                   "width": F // 2}
     gauge = REGISTRY.get("deeprest_train_collective_bytes")
     gauge._series.clear()
-    trainer._program_published = False
+    trainer._published_program = None
     out = []
     spans = _recorded(lambda: out.append(trainer.train_epoch(
         state, bundle, np.random.default_rng(0), staged=staged[0])))
@@ -359,12 +359,12 @@ def test_reading_the_collectives_compiles_nothing(runs):
     try:
         jax.jit(lambda x: x + 1)(np.float32(len(runs)))   # the listener hears
         heard = len(compiles)
-        trainer._program_published = False
+        trainer._published_program = None
         trainer._publish_program(state)
     finally:
         monitoring.unregister_event_duration_listener(listen)
     assert heard and len(compiles) == heard
-    assert trainer._program_published
+    assert trainer._published_program == trainer._staged_program
     # the same read gave the executable's bytes by kind
     found = REGISTRY.get("deeprest_train_program_bytes").series()
     assert {k[0] for k in found} == {"arguments", "outputs", "aliased",
@@ -892,9 +892,9 @@ def test_the_cells_files_exist_and_say_what_the_issue_says(name):
     assert len(unlisted) == 7
     assert all(_load("chipbench", "layer_metrics", name + ".json")["runners"]
                == ["train"] for name in unlisted)
-    # the quota: of nine cells (ISSUE 48's is a one-chip cell) two may ask
-    # for four chips, and these do
-    assert len(bench["workloads"]) == 9 and len(bench["configs"]) == 8
+    # the quota: of ten cells (ISSUE 48's and ISSUE 54's are one-chip
+    # cells) two may ask for four chips, and these do
+    assert len(bench["workloads"]) == 10 and len(bench["configs"]) == 9
     assert sorted(c["name"] for c in bench["workloads"]
                   if c["chips"] == 4) == sorted(MESH_CELLS)
 

@@ -1265,6 +1265,13 @@ _BENCHMARK_SERIES = [
                             "bound")]),
     ("deeprest_train_readbacks_total", (), [{"sink": "epoch_losses"}]),
     ("deeprest_train_superstep_dispatches_total", (), [{}]),
+    # ISSUE 54: the supersteps a life dispatched for the first time, and
+    # the seconds of each (`chipbench/readers/programs.py` sums the one
+    # and indexes the other by `nth`)
+    ("deeprest_train_superstep_programs_total", ("form", "width"),
+     [{"form": "compact"}]),
+    ("deeprest_train_superstep_first_dispatch_seconds", ("nth",),
+     [{"nth": "1", "form": "compact"}]),
     ("deeprest_train_time_reversals", (), [{}]),
 ]
 
