@@ -154,6 +154,7 @@ _CALLED = re.compile(
     r"=%?([\w.\-]+)|\bbranch_computations=\{([^}]*)\}")
 _ARRAY = re.compile(
     r"\b(?:pred|[a-z]+?(\d+)\w*)\[([\d,]*)\](?:\{([^}]*)\})?")
+_F32_ARRAY = re.compile(r"\bf32\[([\d,]*)\]")
 _SPACE = re.compile(r"S\((\d+)\)")
 _OPERAND = re.compile(r"%?([\w.\-]+)\s*(?:,|$)")
 # the memory spaces a TPU layout names; an array with no `S(n)` is in HBM
@@ -192,9 +193,13 @@ def _scope_of(op_name: str, names) -> tuple[str, str]:
     return known[-1], "bwd" if "transpose(" in op_name else "fwd"
 
 
-def _instruction_lines(hlo_text: str):
+def instruction_lines(hlo_text):
     """({computation: [(the match of its instruction, the line)]}, the
-    ENTRY computation's name) of an HLO module's text."""
+    ENTRY computation's name) of an HLO module's text.  Every reader of a
+    module's text below takes this in the text's place too, so a caller
+    with several questions (``Trainer._publish_program``) parses once."""
+    if not isinstance(hlo_text, str):
+        return hlo_text
     computations, entry, current = {}, None, None
     for line in hlo_text.splitlines():
         if not line.startswith(" "):
@@ -216,7 +221,7 @@ def _parse_hlo(hlo_text: str):
     instructions whose computation holds a collective (XLA:TPU's wrappers:
     a reduce-scatter, the start and the done of an asynchronous chain)."""
     computations, inner, custom = {}, set(), {}
-    for comp, lines in _instruction_lines(hlo_text)[0].items():
+    for comp, lines in instruction_lines(hlo_text)[0].items():
         current = computations[comp] = []
         for m, line in lines:
             op_name = _OP_NAME.search(line)
@@ -334,7 +339,7 @@ def collective_bytes(hlo_text: str, steps: int = 1) -> dict[str, int]:
     chips moves 2 S (n - 1) / n a chip, an all-gather of a result of S
     moves S (n - 1) / n, a reduce-scatter of a result of S moves
     S (n - 1)."""
-    bodies, entry = _instruction_lines(hlo_text)
+    bodies, entry = instruction_lines(hlo_text)
     chains = set()
 
     def held(comp: str) -> collections.Counter:
@@ -399,7 +404,7 @@ def kernel_operand_spaces(hlo_text: str, names) -> dict[str, dict[str, int]]:
     A kernel the text does not hold is left out."""
     names = frozenset(names)
     out: dict[str, collections.Counter] = {}
-    for lines in _instruction_lines(hlo_text)[0].values():
+    for lines in instruction_lines(hlo_text)[0].values():
         types = None
         for m, line in lines:
             base, _, number = m["name"].rpartition(".")
@@ -444,7 +449,7 @@ def threefry_draws(hlo_text: str, scope: str) -> list[str]:
     (models/qrnn.py); a loop's body is counted once however often it runs,
     and XLA:CPU rolls the rounds into a loop of their own, so there the
     count says little."""
-    computations = _instruction_lines(hlo_text)[0]
+    computations = instruction_lines(hlo_text)[0]
     holders = {comp for comp, lines in computations.items()
                if any(_array_op_under(m, line, "xor", scope)
                       for m, line in lines)}
@@ -468,7 +473,7 @@ def time_reversals(hlo_text: str, scope: str) -> list[str]:
     (ops/pallas_gru.py), so a compiled step that holds one under
     ``recurrence`` has grown a flip again."""
     return [m["name"]
-            for lines in _instruction_lines(hlo_text)[0].values()
+            for lines in instruction_lines(hlo_text)[0].values()
             for m, line in lines
             if _array_op_under(m, line, "reverse", scope)]
 
@@ -494,7 +499,7 @@ def kernel_edge_passes(hlo_text: str, scope: str, kernel: str) -> list[str]:
     Instructions inside a fusion are its fusion's; a loop's body is counted
     once however often it runs, so for a superstep the count is a train
     step's."""
-    computations = _instruction_lines(hlo_text)[0]
+    computations = instruction_lines(hlo_text)[0]
     fused = {}                  # a fusion's computation -> the opcodes in it
     for lines in computations.values():
         for m, line in lines:
@@ -528,6 +533,50 @@ def kernel_edge_passes(hlo_text: str, scope: str, kernel: str) -> list[str]:
                     and parts[-1] == "split"):
                 found.append(m["name"])
     return found
+
+
+def _dims(text: str) -> tuple[int, ...]:
+    return tuple(int(d) for d in text.split(",") if d)
+
+
+def bare_weight_grad_dots(hlo_text: str, scope: str, leaves) -> list[str]:
+    """The fusions of an optimized HLO module's text that compute a weight
+    gradient under ``scope`` only to hand it over, by name: a fusion whose
+    computation holds a ``convolution`` (what XLA:TPU makes of a dot) with
+    ``scope`` among the components of its ``op_name`` under a
+    ``transpose(`` (the backward pass), whose result is as large as one of
+    ``leaves`` (the shapes of the weights differentiated there: its
+    dimensions are theirs in the dot's own order), and which returns no
+    float32 array of that leaf's shape.  A fusion that holds such a dot and
+    returns the leaf, ``mu`` and ``nu`` has consumed the gradient where it
+    was made: the dot's MXU time hides under the optimizer's HBM traffic
+    and no gradient is written.  One that returns the gradient itself (in
+    the compute dtype) runs the MXU with HBM idle, and the gradient is
+    written once and read once by whoever holds the optimizer
+    (train/trainer.py's ``apply_gradients`` orders the update so that none
+    does).  A loop's body is counted once however often it runs, so for a
+    superstep the count is a train step's."""
+    leaves = {tuple(dims) for dims in leaves}
+    sizes = {tuple(sorted(d for d in dims if d > 1)) for dims in leaves}
+    computations = instruction_lines(hlo_text)[0]
+
+    def weight_gradient(m, line: str) -> bool:
+        op_name = m["opcode"] == "convolution" and _OP_NAME.search(line)
+        if not op_name or _scope_of(op_name[1], {scope}) != (scope, "bwd"):
+            return False
+        made = _dims(_ARRAY.search(_result_type(m, line))[2])
+        return tuple(sorted(d for d in made if d > 1)) in sizes
+
+    holders = {comp for comp, lines in computations.items()
+               if any(weight_gradient(m, line) for m, line in lines)}
+    if not holders:
+        return []
+    return [m["name"]
+            for lines in computations.values() for m, line in lines
+            if m["opcode"] == "fusion"
+            and _CALLS.search(line)[1] in holders
+            and not leaves & {_dims(dims) for dims in _F32_ARRAY.findall(
+                _result_type(m, line))}]
 
 
 def _operands(m, line: str) -> list[str]:
@@ -753,7 +802,8 @@ def format_table(table: dict) -> str:
 __all__ = ["OTHER", "UNATTRIBUTED", "COLLECTIVE", "COLLECTIVE_KINDS",
            "ASYNC_COLLECTIVE", "collective_kind", "collective_bytes",
            "kernel_operand_spaces", "threefry_draws", "time_reversals",
-           "kernel_edge_passes", "PROGRAM_SPAN_PREFIXES", "ProfilerBusy", "capture",
+           "kernel_edge_passes", "bare_weight_grad_dots", "instruction_lines",
+           "PROGRAM_SPAN_PREFIXES", "ProfilerBusy", "capture",
            "trace_window", "scope_table", "fused_scopes", "module_name",
            "layer_table", "layer_table_of", "read_planes", "find_xplane",
            "format_table"]

@@ -83,6 +83,7 @@ PROGRAM_BYTES = "deeprest_train_program_bytes"
 KERNEL_OPERAND_BYTES = "deeprest_train_kernel_operand_bytes"
 TIME_REVERSALS = "deeprest_train_time_reversals"
 KERNEL_EDGE_PASSES = "deeprest_train_kernel_edge_passes"
+BARE_WEIGHT_GRAD_DOTS = "deeprest_train_bare_weight_grad_dots"
 KEPT_EXECUTABLES = "deeprest_train_kept_executables_total"
 KEPT = "kept"           # the `cache` of an executable train/kept.py loaded
 
@@ -325,7 +326,9 @@ def setup_table() -> dict:
     three moments; the superstep executable's bytes, where its kernels'
     operands live, how many arrays a step reverses in time round them
     (``time_reversals``) and how many passes it makes over a kernel's
-    operand or result only to cut or to sum it (``kernel_edge_passes``).
+    operand or result only to cut or to sum it (``kernel_edge_passes``) and
+    how many layer-0 weight-gradient dots it runs only to hand the gradient
+    over (``bare_weight_grad_dots``).
     What was never set is left out."""
     compilations: dict = {}
 
@@ -351,6 +354,7 @@ def setup_table() -> dict:
     stagings = _series(STAGINGS)
     reversals = _series(TIME_REVERSALS)
     edge_passes = _series(KERNEL_EDGE_PASSES)
+    bare_dots = _series(BARE_WEIGHT_GRAD_DOTS)
     rows = _by(OPTIMIZER_ROWS, "kind")
     accumulation = {k: int(v) for k, v in _by(ACCUMULATION, "kind").items()}
     columns = {k: int(v) for k, v in _by(PROJECTION_COLUMNS, "kind").items()}
@@ -384,6 +388,8 @@ def setup_table() -> dict:
         "time_reversals": int(reversals[0][1]) if reversals else None,
         "kernel_edge_passes": (int(edge_passes[0][1]) if edge_passes
                                else None),
+        "bare_weight_grad_dots": (int(bare_dots[0][1]) if bare_dots
+                                  else None),
     }
     return {k: v for k, v in table.items() if v not in (None, {}, [])}
 
@@ -462,6 +468,9 @@ def format_setup(table: dict) -> str:
     if "kernel_edge_passes" in table:
         parts.append(f"{table['kernel_edge_passes']} passes at the kernels' "
                      "edge a step")
+    if "bare_weight_grad_dots" in table:
+        parts.append(f"{table['bare_weight_grad_dots']} bare weight-gradient "
+                     "dots a step")
     return "set-up: " + "; ".join(parts)
 
 
@@ -474,4 +483,5 @@ __all__ = ["OTHER", "UNCACHED", "phase", "current_phase", "install",
            "FIRST_DISPATCH_SECONDS", "SUPERSTEP_PROGRAMS",
            "SUPERSTEP_FIRST_DISPATCH_SECONDS", "DEVICE_BYTES", "PROGRAM_BYTES",
            "KERNEL_OPERAND_BYTES", "TIME_REVERSALS", "KERNEL_EDGE_PASSES",
+           "BARE_WEIGHT_GRAD_DOTS",
            "KEPT_EXECUTABLES", "KEPT", "compilations_of", "count_kept_load"]

@@ -277,14 +277,28 @@ class KeptJit:
             return loaded(*args)
         self._run[signature] = self._jit
         name = self.__name__
+        # On the TPU the file is written BESIDE the first dispatch (the call
+        # left the trace, the lowering and the executable in the jit's
+        # caches).  Anywhere else the executable is compiled and written
+        # AHEAD of its first run: XLA:CPU cannot serialise an executable
+        # whose sort has run once (``UNIMPLEMENTED: `LessThan` is not
+        # serializable``; the superstep sorts its stale rows), and beside
+        # the dispatch that was a race a loaded machine lost.  Not ahead on
+        # the TPU too: there the call after an ahead-of-time compile traces
+        # the program a second time (2.0 s of 15 in `tenk-retrain-drift`;
+        # PERF.md section 6, PRs 55 and 56).
+        ahead = self._mesh.devices.flat[0].platform != "tpu"
         before = obs_setup.compilations_of(name)
-        out = self._jit(*args)
-        # the dispatch is on its way; what follows runs beside it
+        if ahead:
+            compiled = self._jit.lower(*args).compile()
+        else:
+            compiled, out = None, self._jit(*args)
         made = collections.Counter(obs_setup.compilations_of(name))
         made.subtract(before)
         compiled_here = made["miss"] + made[obs_setup.UNCACHED] > 0
-        self._keep(path, key, args, fresh=compiled_here and not made["hit"])
-        return out
+        self._keep(path, key, args, compiled_here and not made["hit"],
+                   compiled)
+        return self._jit(*args) if ahead else out
 
     def _where(self, args):
         """(the file, the live key) for these arguments, or None where
@@ -348,8 +362,11 @@ class KeptJit:
             obs_setup.count_kept_load(self.__name__, clock.elapsed())
         return loaded
 
-    def _keep(self, path: str, key: dict, args, fresh: bool) -> None:
-        """Write the executable the jit just dispatched, if it is whole."""
+    def _keep(self, path: str, key: dict, args, fresh: bool,
+              compiled=None) -> None:
+        """Write the executable the jit dispatches for ``args``
+        (``compiled``, where the caller compiled it ahead), if it is
+        whole."""
         from jax.experimental.serialize_executable import serialize
 
         platform = self._mesh.devices.flat[0].platform
@@ -357,9 +374,10 @@ class KeptJit:
             self._count("unsupported")
             return
         try:
-            # the call left the trace, the lowering and the executable in
+            # a call left the trace, the lowering and the executable in
             # the jit's caches (the donated arguments keep their types)
-            compiled = self._jit.lower(*args).compile()
+            if compiled is None:
+                compiled = self._jit.lower(*args).compile()
             payload, _, out_tree = serialize(compiled)
             _write(path, (key, out_tree, payload))
         except (NotImplementedError, ValueError, OSError):

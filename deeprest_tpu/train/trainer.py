@@ -118,13 +118,26 @@ def put_w_ih(rows, tree, live_cols, mesh=None):
                               if _is_w_ih(path) else new), rows, tree)
 
 
-def only_w_ih(tree):
-    """``tree`` with only its w_ih leaves: every other leaf of params, or
-    of a mirror of it, is None, which a pytree does not count; what is no
-    mirror of a parameter (Adam's ``count``) stays."""
+def only_leaves(tree, keep):
+    """``tree`` with only the leaves of params, or of a mirror of them,
+    whose name ``keep`` holds: every other such leaf is None, which a
+    pytree does not count; what is no mirror of a parameter (Adam's
+    ``count``) stays."""
     return jax.tree_util.tree_map_with_path(
-        lambda path, a: a if (not _names_param(path) or _is_w_ih(path))
+        lambda path, a: a if (not _names_param(path) or keep(path[-1].key))
         else None, tree)
+
+
+def only_w_ih(tree):
+    """:func:`only_leaves` of ``tree``'s w_ih leaves."""
+    return only_leaves(tree, MASKED_PARAM_NAMES.__contains__)
+
+
+def _overlaid(under, over):
+    """``under`` with every leaf that ``over`` holds in its place (two
+    trees of one structure once None counts as a leaf)."""
+    return jax.tree.map(lambda a, b: a if b is None else b, under, over,
+                        is_leaf=lambda a: a is None)
 
 
 def _moments_off_table(opt_state, live_cols) -> jax.Array:
@@ -374,10 +387,46 @@ class Trainer:
             superstep's scan are split over the mesh's ``data`` axis."""
             return carried_rows_split(self.mesh, live_cols.shape[0]) > 1
 
+        def adam(params, grads, opt_state):
+            updates, opt_state = self.tx.update(grads, opt_state)
+            return optax.apply_updates(params, updates), opt_state
+
         @jax.named_scope(scopes.OPTIMIZER)
-        def apply_gradients(state: TrainState, grads):
-            updates, opt_state = self.tx.update(grads, state.opt_state)
-            return optax.apply_updates(state.params, updates), opt_state
+        def apply_gradients(state: TrainState, grads, rows_split=False):
+            """The one ``tx.update`` of a step, ORDERED BY LEAF where the
+            layer has two w_ih leaves whose gradients are whole dots: the
+            first one's update on its own sub-tree, one
+            ``optimization_barrier`` over Adam's ``count`` and that leaf's
+            new params, ``mu`` and ``nu``, then every other leaf's with
+            the ``count`` that came through it.  The arithmetic is
+            ``tx.update``'s, operation for operation (Adam of a leaf reads
+            that leaf's gradient and the shared ``count`` alone), and the
+            state keeps its tree.  Why: XLA:TPU fuses the two leaves' Adam
+            loops as siblings and a fusion takes one convolution, so the
+            OTHER direction's weight-gradient dot ran bare on the MXU with
+            HBM idle and its bf16 ``[E, F, 3H]`` result was written once
+            and read once (1.3–4.1 ms a step in the wide cells: PERF.md
+            section 6, PRs 55 and 56).  Behind the barrier the second
+            leaf's Adam can share no fusion with the first's: each
+            direction's dot, fold backward and Adam are one fusion that
+            writes no gradient.  The barrier holds only what the step
+            returns anyway; a GRADIENT must never go behind it (it would
+            be materialised, which is what this removes).  ``rows_split``:
+            the carried rows are split over a `data` axis, where
+            `sharding.project_split_rows` owns the backward and its chunk
+            dots carry no Adam: the plain update, as for a tree with one
+            w_ih leaf or none."""
+            w_ih = [k for k in MASKED_PARAM_NAMES if k in grads]
+            if len(w_ih) < 2 or rows_split:
+                return adam(state.params, grads, state.opt_state)
+            trees = state.params, grads, state.opt_state
+            first = adam(*(only_leaves(t, w_ih[0].__eq__) for t in trees))
+            counts, first = jax.lax.optimization_barrier(
+                (only_leaves(state.opt_state, lambda _: False), first))
+            params, grads, opt_state = (
+                only_leaves(t, w_ih[0].__ne__) for t in trees)
+            return _overlaid(
+                first, adam(params, grads, _overlaid(opt_state, counts)))
 
         @jax.named_scope(scopes.DROPOUT)
         def dropout_key(state: TrainState):
@@ -411,11 +460,12 @@ class Trainer:
             w_ih = full_w_ih or {}
             loss, grads = jax.value_and_grad(microbatch_loss)(
                 state.params, dropout_key(state), xb, yb, wb, live_cols, w_ih)
-            params, opt_state = apply_gradients(state, grads)
+            carried = bool(w_ih) and split_rows(live_cols)
+            params, opt_state = apply_gradients(state, grads, carried)
             return (
                 pin_state(TrainState(step=state.step + 1, params=params,
                                      opt_state=opt_state, rng=state.rng),
-                          carried_rows=bool(w_ih) and split_rows(live_cols)),
+                          carried_rows=carried),
                 loss,
             )
 
@@ -511,7 +561,7 @@ class Trainer:
             grads, losses = jax.lax.scan(
                 micro, zeros, (jnp.arange(accum_g), starts, wb, share),
                 unroll=accum_g)
-            params, opt_state = apply_gradients(state, grads)
+            params, opt_state = apply_gradients(state, grads, carried)
             n_real = jnp.sum((per_micro > 0).astype(jnp.int32))
             return (
                 pin_state(TrainState(step=state.step + n_real, params=params,
@@ -648,9 +698,8 @@ class Trainer:
             @jax.named_scope(scopes.OFF_TABLE)
             def off_table_step(_, leaves):
                 params, opt_state = leaves
-                updates, opt_state = self.tx.update(
-                    jax.tree.map(jnp.zeros_like, params), opt_state)
-                return optax.apply_updates(params, updates), opt_state
+                return adam(params, jax.tree.map(jnp.zeros_like, params),
+                            opt_state)
 
             @jax.named_scope(scopes.OFF_TABLE)
             def off_table_chunk(i, leaves):
@@ -853,6 +902,12 @@ class Trainer:
             "kernel call (a sum over dproj for the input bias, the split "
             "of the joined cotangent): 0 where the backward kernels do "
             "both themselves")
+        self._m_bare_weight_grad_dots = obs_metrics.REGISTRY.gauge(
+            obs_setup.BARE_WEIGHT_GRAD_DOTS,
+            "fusions of a step of the compiled superstep that compute a "
+            "layer-0 input weight's gradient and return it instead of the "
+            "updated weight and its moments: 0 where each direction's dot "
+            "carries its own leaf's fold backward and Adam")
         # Elastic-remeshing legs (detect -> rebuild -> restore -> resume),
         # one increment per event — never on the step path.
         self._m_device_losses = obs_metrics.REGISTRY.counter(
@@ -976,7 +1031,10 @@ class Trainer:
         the reverse direction themselves) or passes over a kernel's operand
         or result only to cut or to sum it
         (``deeprest_train_kernel_edge_passes``: none since the recurrence's
-        VJP spans the bias add and the join)."""
+        VJP spans the bias add and the join) or runs a layer-0
+        weight-gradient dot only to hand the gradient over
+        (``deeprest_train_bare_weight_grad_dots``: none where
+        ``apply_gradients`` orders the update by leaf)."""
         from deeprest_tpu.obs import profiler
 
         # another program's kernels, collectives and draws say nothing of
@@ -1003,7 +1061,7 @@ class Trainer:
                             ("temporaries", mem.temp_size_in_bytes),
                             ("code", mem.generated_code_size_in_bytes)):
                 self._m_program_bytes.set(n, kind=kind)
-        text = compiled.as_text()
+        text = profiler.instruction_lines(compiled.as_text())
         for kernel, spaces in profiler.kernel_operand_spaces(
                 text, scopes.KERNELS).items():
             for space, n in spaces.items():
@@ -1018,6 +1076,13 @@ class Trainer:
             len(profiler.time_reversals(text, scopes.RECURRENCE)))
         self._m_kernel_edge_passes.set(len(profiler.kernel_edge_passes(
             text, scopes.RECURRENCE, scopes.GRU_KERNEL_BWD)))
+        # what a step differentiates with respect to: the w_ih leaves, on
+        # a compact base the table's rows of them
+        live = live_cols_of(x_base)
+        self._m_bare_weight_grad_dots.set(len(profiler.bare_weight_grad_dots(
+            text, scopes.IN_PROJ,
+            [a.shape if live is None else (a.shape[0], *live.shape, a.shape[2])
+             for a in w_ih_leaves(state.params)])))
 
     def _accum_carry_bytes(self, state, x_base) -> int:
         """The bytes of the gradient accumulator an update of the superstep

@@ -265,12 +265,9 @@ def test_fused_serve_program_10k_sparse(one_chip):
 # ---------------------------------------------------------------------------
 
 
-def _train_step_lowered(mesh, feature_dim, sparse, accum=1,
-                        superstep=False, batch=B, experts=E, table=U_LIVE):
-    """``superstep``: the G=1 superstep (a 3 x 50 plan, as the 10k cell's
-    126-step epoch gives; under a mesh of several chips the 1 x 32 plan of
-    `tenk-train-dp4`'s 32-step epoch, split over ``data``) instead of the
-    per-step program.  ``table``: the width of the compact form's table."""
+def _trainer_and_state(mesh, feature_dim, accum=1, batch=B, experts=E):
+    """A `Trainer` at the flagship geometry and the shapes of its state,
+    placed on ``mesh`` as `state_sharding` places them."""
     cfg = Config(model=_model_config(feature_dim=feature_dim,
                                      experts=experts),
                  train=TrainConfig(batch_size=batch, window_size=W,
@@ -286,9 +283,19 @@ def _train_step_lowered(mesh, feature_dim, sparse, accum=1,
                           opt_state=trainer.tx.init(params), rng=rng)
 
     shapes = jax.eval_shape(state)
-    state_sds = jax.tree.map(
+    return trainer, jax.tree.map(
         lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
         shapes, state_sharding(mesh, shapes))
+
+
+def _train_step_lowered(mesh, feature_dim, sparse, accum=1,
+                        superstep=False, batch=B, experts=E, table=U_LIVE):
+    """``superstep``: the G=1 superstep (a 3 x 50 plan, as the 10k cell's
+    126-step epoch gives; under a mesh of several chips the 1 x 32 plan of
+    `tenk-train-dp4`'s 32-step epoch, split over ``data``) instead of the
+    per-step program.  ``table``: the width of the compact form's table."""
+    trainer, state_sds = _trainer_and_state(mesh, feature_dim, accum, batch,
+                                            experts)
     t_len = 4096
     sds = jax.ShapeDtypeStruct
     if sparse:
@@ -518,7 +525,17 @@ def test_dense_superstep_e200_draws_the_dropout_mask_once(
     assert _need(mem) < 9.25e9, mem
 
 
-def test_dense_form_10k_superstep_is_the_all_live_program(one_chip):
+@pytest.fixture(scope="module")
+def dense_form_10k_superstep(one_chip):
+    """`tenk-train-alllive`'s program (the sparse base in its dense form at
+    F = 10,240, a 3 x 50 plan), compiled once for the tests that read
+    it."""
+    return _train_step_lowered(one_chip, F_10K, True,
+                               superstep=True).compile()
+
+
+def test_dense_form_10k_superstep_is_the_all_live_program(
+        dense_form_10k_superstep):
     """The sparse base in its DENSE form at the 10k width (ISSUE 38; since
     ISSUE 39 moved the rule's bound to F // 2 the program of a live set
     over 4,096 paths, the all-live corpus among them, and of a mesh whose
@@ -528,8 +545,7 @@ def test_dense_form_10k_superstep_is_the_all_live_program(one_chip):
     gradients are whole leaves; with the 4.46 GB of state it needs less than
     `init_state` leaves at its peak (8.924 GB: the 10k cells'
     `hbm_peak_gb`), so the peak stays `init_state`'s."""
-    compiled = _train_step_lowered(one_chip, F_10K, True,
-                                   superstep=True).compile()
+    compiled = dense_form_10k_superstep
     text = compiled.as_text()
     mem = compiled.memory_analysis()
     print(f"dense-form 10k superstep for a described v5e: temporaries "
@@ -564,10 +580,12 @@ def test_compact_superstep_at_the_widest_table_is_the_live4k_cells_program(
     3 x 50 plan).  Windows and folded weights are the table's width and
     never F wide, the six `[40,4096,384]` arrays of rows ride the scan as
     the 256-wide ones do (ten `while`s, no whole-leaf copy), and with the
-    4.46 GB of state it needs 6.48 GB (temporaries 2.016; 1.922 until
-    ISSUE 42 took the split and the two sums from round the backward
-    kernels: three operations fewer, and the compiler's buffer assignment
-    came out 94 MB wider): 2.4 GB under what `init_state` leaves at its
+    4.46 GB of state it needs 6.42 GB (temporaries 1.953 since ISSUE 56
+    ordered the update by leaf: the second direction's bf16
+    `[40,384,4096]` gradient, 126 MB, is no longer written; 2.016 from
+    ISSUE 42, which took the split and the two sums from round the backward
+    kernels and left the buffer assignment 94 MB wider than the 1.922
+    before it): 2.5 GB under what `init_state` leaves at its
     peak (8.924 GB, the cell's `hbm_peak_gb`), so the peak stays
     `init_state`'s.  (A table of 8,192,
     which the rule does not admit, compiles to 3.554 GB of temporaries and
@@ -588,9 +606,137 @@ def test_compact_superstep_at_the_widest_table_is_the_live4k_cells_program(
     assert f"bf16[{E},{F_10K},{3 * H}]" not in text
     assert len(re.findall(r" while[(]", text)) == 10
     assert _whole_leaf_copies(text) == 0
-    assert mem.temp_size_in_bytes == pytest.approx(2.016e9, rel=0.03), mem
+    assert mem.temp_size_in_bytes == pytest.approx(1.953e9, rel=0.03), mem
     assert _need(mem) < 6.5e9, mem
     assert mem.generated_code_size_in_bytes <= 20e6, mem
+
+
+# the four one-chip programs at one microbatch an update, and the shape of
+# the w_ih leaves a step of each differentiates with respect to
+_W_IH_LEAF = {
+    "dense_superstep_e200": (200, 2048, 3 * H),        # `tt-train-dense`
+    "dense_form_10k_superstep": (E, F_10K, 3 * H),     # `tenk-train-alllive`
+    "compact_superstep_table4k": (E, TABLE_4K, 3 * H),  # the live4k cells
+    "compact_superstep": (E, U_LIVE, 3 * H),           # `tenk-train-sparse`
+}
+_W_IH_BWD = re.compile(
+    r"^\s+%(?P<name>[\w.\-]+) = (?P<result>.*?) fusion\(.*"
+    r"transpose\(jvp\(QuantileGRU\)\)/in_proj/[^\"]*dot_general\"", re.M)
+
+
+def _bare_weight_grad_dots(text: str, leaf) -> list[str]:
+    from deeprest_tpu.obs import profiler
+    from deeprest_tpu.ops import scopes
+
+    return profiler.bare_weight_grad_dots(text, scopes.IN_PROJ, [leaf])
+
+
+@pytest.mark.parametrize("cell", list(_W_IH_LEAF))
+def test_each_weight_gradient_dot_carries_its_own_leafs_adam(request, cell):
+    """ISSUE 56: in the four one-chip programs every layer-0
+    weight-gradient convolution fusion (one a direction) returns its OWN
+    leaf's three float32 arrays (the leaf, `mu`, `nu`) and the one
+    `[E, F]` partial of the mask's gradient, and nothing else: no fusion
+    holds Adam for both leaves, so none of the two dots runs bare and no
+    `[E, 3H, F]` gradient is written, which is what
+    ``deeprest_train_bare_weight_grad_dots`` reads as 0 on the chip.  (The
+    parent's texts hold ONE fusion of eight outputs and a bare dot that
+    returns the bf16 gradient: the next test.)"""
+    text = request.getfixturevalue(cell).as_text()
+    e, f, g = leaf = _W_IH_LEAF[cell]
+    found = {m["name"]: re.findall(r"\b(\w+)\[([\d,]*)\]", m["result"])
+             for m in _W_IH_BWD.finditer(text)}
+    assert len(found) == 2, found
+    for name, arrays in found.items():
+        assert sorted(arrays) == sorted(
+            3 * [("f32", f"{e},{f},{g}")] + [("f32", f"{e},{f}")]), name
+    assert _bare_weight_grad_dots(text, leaf) == []
+
+
+# The two backward fusions of the parent's programs (ISSUE 56's table), as
+# the compiler for the described v5e wrote them (layouts, operands' bodies
+# and backend configuration left out): Adam of BOTH leaves round one
+# direction's dot, and the other direction's dot alone.
+_PARENT_DOTS = """HloModule jit_train_superstep
+
+%fused_computation.23 (p0: bf16[{e},384,32,60], p1: bf16[32,60,{f}]) -> (f32[{e},{f},384], f32[{e},{f},384], f32[{e},{f},384], f32[{e},{f}], f32[{e},{f}], f32[{e},{f},384], f32[{e},{f},384], f32[{e},{f},384]) {{
+  %p0 = bf16[{e},384,32,60]{{1,2,3,0}} parameter(0)
+  %p1 = bf16[32,60,{f}]{{2,0,1}} parameter(1)
+  %convolution.14 = bf16[{e},384,{f},1]{{2,1,3,0}} convolution(%p0, %p1), window={{size=1x60}}, dim_labels=0bf1_i1o0->0bf1, metadata={{op_name="{step}/in_proj/btf,efg->etbg/dot_general"}}
+  %leaf = f32[{e},{f},384]{{1,2,0}} convert(%convolution.14), metadata={{op_name="jit(train_superstep)/while/body/closed_call/cond/branch_1_fun/optimizer/add"}}
+  %partial = f32[{e},{f}]{{1,0}} reduce(%leaf), dimensions={{2}}
+  ROOT %tuple.9 = (f32[{e},{f},384]{{1,2,0}}, f32[{e},{f},384]{{1,2,0}}, f32[{e},{f},384]{{1,2,0}}, f32[{e},{f}]{{1,0}}, f32[{e},{f}]{{1,0}}, f32[{e},{f},384]{{1,2,0}}, f32[{e},{f},384]{{1,2,0}}, f32[{e},{f},384]{{1,2,0}}) tuple(%leaf, %leaf, %leaf, %partial, %partial, %leaf, %leaf, %leaf)
+}}
+
+%fused_computation.70 (p0: bf16[{e},384,32,60], p1: bf16[32,60,{f}]) -> bf16[{e},384,{f},1] {{
+  %p0 = bf16[{e},384,32,60]{{1,2,3,0}} parameter(0)
+  %p1 = bf16[32,60,{f}]{{2,0,1}} parameter(1)
+  ROOT %convolution.15 = bf16[{e},384,{f},1]{{2,1,3,0}} convolution(%p0, %p1), window={{size=1x60}}, dim_labels=0bf1_i1o0->0bf1, metadata={{op_name="{step}/in_proj/btf,efg->etbg/dot_general"}}
+}}
+
+%fused_computation.75 (p0: bf16[32,60,{f}], p1: bf16[{e},{f},384]) -> bf16[{e},60,32,384] {{
+  %p0 = bf16[32,60,{f}]{{2,0,1}} parameter(0)
+  %p1 = bf16[{e},{f},384]{{1,2,0}} parameter(1)
+  ROOT %convolution.9 = bf16[{e},60,32,384]{{3,2,1,0}} convolution(%p0, %p1), dim_labels=0fb1_o1i0->0bf1, metadata={{op_name="jit(train_superstep)/while/body/closed_call/cond/branch_1_fun/jvp(QuantileGRU)/in_proj/btf,efg->etbg/dot_general"}}
+}}
+
+%body (d0: bf16[{e},384,32,60], d1: bf16[{e},384,32,60], x: bf16[32,60,{f}], w: bf16[{e},{f},384]) -> bf16[{e},60,32,384] {{
+  %d0 = bf16[{e},384,32,60]{{1,2,3,0}} parameter(0)
+  %d1 = bf16[{e},384,32,60]{{1,2,3,0}} parameter(1)
+  %x = bf16[32,60,{f}]{{2,0,1}} parameter(2)
+  %w = bf16[{e},{f},384]{{1,2,0}} parameter(3)
+  %{bare} = bf16[{e},384,{f},1]{{2,1,3,0:T(8,128)(2,1)}} fusion(%d1, %x), kind=kOutput, calls=%fused_computation.70, metadata={{op_name="{step}/in_proj/btf,efg->etbg/dot_general"}}
+  %{both} = (f32[{e},{f},384]{{1,2,0:T(8,128)}}, f32[{e},{f},384]{{1,2,0:T(8,128)}}, f32[{e},{f},384]{{1,2,0:T(8,128)}}, f32[{e},{f}]{{1,0:T(8,128)S(1)}}, f32[{e},{f}]{{1,0:T(8,128)S(1)}}, /*index=5*/f32[{e},{f},384]{{1,2,0:T(8,128)}}, f32[{e},{f},384]{{1,2,0:T(8,128)}}, f32[{e},{f},384]{{1,2,0:T(8,128)}}) fusion(%d0, %x), kind=kOutput, calls=%fused_computation.23, metadata={{op_name="{step}/in_proj/btf,efg->etbg/dot_general"}}
+  ROOT %fusion.53 = bf16[{e},60,32,384]{{3,2,1,0}} fusion(%x, %w), kind=kOutput, calls=%fused_computation.75, metadata={{op_name="jit(train_superstep)/while/body/closed_call/cond/branch_1_fun/jvp(QuantileGRU)/in_proj/btf,efg->etbg/dot_general"}}
+}}
+"""
+
+
+@pytest.mark.parametrize("e,f,both,bare", [
+    (200, 2048, "fusion.17", "fusion.56"),       # the dense feed, E=200
+    (E, F_10K, "fusion.17", "fusion.62"),        # the dense form
+    (E, TABLE_4K, "fusion.42", "fusion.87"),     # a table of 4,096
+    (E, U_LIVE, "fusion.83", "fusion.97"),       # a table of 256
+])
+def test_parents_four_programs_hold_one_bare_weight_gradient_dot(
+        e, f, both, bare):
+    """What the counter reads on the four programs of ISSUE 56's parent
+    (their two backward fusions and a forward one each kept above, not the
+    whole texts): the fusion that returns the bf16 gradient, and neither
+    the one that holds Adam for both leaves nor the forward's dot; with
+    another leaf's shape nothing is a weight gradient."""
+    text = _PARENT_DOTS.format(step=_PARENT_STEP, e=e, f=f, both=both,
+                               bare=bare)
+    assert _bare_weight_grad_dots(text, (e, f, 3 * H)) == [bare]
+    assert _bare_weight_grad_dots(text, (e, f // 2, 3 * H)) == []
+
+
+def _plain_step_lowered(mesh, feature_dim):
+    """The per-step program of the dense feed with the PLAIN update written
+    out (tests/test_sparse_adam.py's `plain_step`: one ``tx.update`` over
+    the whole gradient tree, which is what `apply_gradients` was before
+    ISSUE 56 ordered it by leaf)."""
+    from test_sparse_adam import plain_step
+
+    trainer, state_sds = _trainer_and_state(mesh, feature_dim)
+    sds = jax.ShapeDtypeStruct
+    args = (sds((4096, feature_dim), jnp.bfloat16),
+            sds((4096, E), jnp.float32),
+            sds((B,), jnp.int32), sds((B,), jnp.float32))
+    return jax.jit(plain_step(trainer, W), donate_argnums=0).lower(
+        state_sds, *_on(mesh, args))
+
+
+def test_the_plain_update_compiles_to_one_bare_weight_gradient_dot(one_chip):
+    """The same counter on the parent's STRUCTURE, compiled here: a train
+    step with the plain update holds one bare weight-gradient dot (the
+    compiler gives both leaves' Adam to one direction's dot), the trainer's
+    own per-step program at the same shapes none."""
+    leaf = (E, 2048, 3 * H)
+    plain = _plain_step_lowered(one_chip, 2048).compile().as_text()
+    assert len(_bare_weight_grad_dots(plain, leaf)) == 1
+    ordered = _train_step_lowered(one_chip, 2048, False).compile().as_text()
+    assert _bare_weight_grad_dots(ordered, leaf) == []
 
 
 def test_compact_superstep_updates_the_leaves_in_place(compact_superstep):

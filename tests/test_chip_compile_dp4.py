@@ -121,7 +121,7 @@ def _schedule(text: str) -> list:
     """The loop's instructions in the order the scheduler left them (the
     module is scheduled: a computation's lines are its sequence)."""
     assert "is_scheduled=true" in text.splitlines()[0]
-    computations, _ = profiler._instruction_lines(text)
+    computations, _ = profiler.instruction_lines(text)
     (body,) = [lines for lines in computations.values()
                if any(m["opcode"] == "collective-permute-start"
                       for m, _ in lines)]
@@ -181,7 +181,7 @@ def _gather_chains(text: str, body: list) -> dict:
     schedule, instruction name), ...]}`` of the fusions whose computation
     holds a piece of the chain (XLA:TPU's start, the fusion the transfer
     runs beside, the done)."""
-    computations, _ = profiler._instruction_lines(text)
+    computations, _ = profiler.instruction_lines(text)
     chain_of = {}
     for comp, lines in computations.items():
         for m, line in lines:
@@ -348,7 +348,7 @@ def test_wide_table_under_data4_names_its_collectives_for_the_readers(
     assert kinds[("all-gather", "")] == 1 + 6     # a step's first, the six
     # a collective instruction anywhere is in the row itself or inside a
     # fusion: a wrapper of the row, or the one that hides the chain
-    computations, _ = profiler._instruction_lines(text)
+    computations, _ = profiler.instruction_lines(text)
     holders = {comp for comp, lines in computations.items()
                if any(profiler.collective_kind(m["opcode"])
                       for m, _ in lines)}

@@ -374,6 +374,34 @@ def test_another_table_width_is_a_miss_and_traced(store, kept_once,
     assert os.path.exists(store)                # the other key's file stays
 
 
+def test_the_executable_is_written_before_it_first_runs(
+        store, kept_once, backend_compiles, monkeypatch):
+    """XLA:CPU cannot serialise an executable whose sort has run once
+    (``UNIMPLEMENTED: `LessThan` is not serializable``; the superstep sorts
+    its stale rows), so off the TPU the write comes BEFORE the first
+    dispatch and not beside it, where a loaded machine let the dispatch win
+    (ISSUE 55's tier-1 run lost this file's seventeen cases to it, ISSUE 56's one): when
+    `_keep` runs, the jit has dispatched nothing, and the call after it
+    compiles nothing again.  (On the TPU the write stays beside the
+    dispatch: ahead costs the call a second trace there.)"""
+    os.remove(store)
+    dispatched = []
+    keep = kept.KeptJit._keep
+
+    def watched(self, *args, **kwargs):
+        dispatched.append(self._jit._cache_size())
+        return keep(self, *args, **kwargs)
+
+    monkeypatch.setattr(kept.KeptJit, "_keep", watched)
+    trainer = _trainer()
+    before = _results()
+    state, _ = trainer._superstep(*_arguments(trainer, kept_once["bundle"]))
+    assert dispatched == [0]
+    assert _since(before) == {"miss": 1, "stored": 1}
+    assert os.path.exists(store) and int(state.step) > 0
+    assert trainer._superstep._cache_size() == 1
+
+
 def test_another_mesh_is_a_miss(store, kept_once):
     trainer = _trainer(mesh=make_mesh(MeshConfig(data=2)))
     args = _arguments(trainer, kept_once["bundle"])

@@ -829,7 +829,9 @@ LISTED_IN_EVERY_TRAIN_CELL = (
     # ISSUE 50: set-up's seconds in jax's pipeline, by stage
     "trace_s.train", "lower_s.train", "superstep_build_s.train",
     # ISSUE 51: whether the process loaded the kept superstep
-    "superstep_loaded.train")
+    "superstep_loaded.train",
+    # ISSUE 56: the weight-gradient dots a step runs only to hand over
+    "bare_weight_grad_dots_per_step.train")
 
 
 @pytest.mark.parametrize("name", sorted(MESH_CELLS))

@@ -20,6 +20,7 @@ from conftest import make_series_buckets
 from deeprest_tpu import obs
 from deeprest_tpu.config import Config, FeaturizeConfig, ModelConfig, TrainConfig
 from deeprest_tpu.data.featurize import featurize_buckets
+from deeprest_tpu.models.qrnn import MASKED_PARAM_NAMES
 from deeprest_tpu.obs import profiler
 from deeprest_tpu.obs import setup as obs_setup
 from deeprest_tpu.obs.metrics import REGISTRY
@@ -1045,11 +1046,17 @@ _KERNEL_BYTES = {("gru_kernel_fwd", "vmem"): 1.0, ("gru_kernel_fwd", "hbm"): 2.0
      {(): 0.0}, 0.0),
     ("kernel_edge_passes:passes_per_step", obs_setup.KERNEL_EDGE_PASSES, (),
      {(): 3.0}, 3.0),
+    # ISSUE 56: the layer-0 weight-gradient dots a compiled step runs only
+    # to hand the gradient over; 0 is a reading too
+    ("bare_weight_grad_dots:dots_per_step", obs_setup.BARE_WEIGHT_GRAD_DOTS,
+     (), {(): 0.0}, 0.0),
+    ("bare_weight_grad_dots:dots_per_step", obs_setup.BARE_WEIGHT_GRAD_DOTS,
+     (), {(): 1.0}, 1.0),
 ])
 def test_the_setup_readers(monkeypatch, reader, metric, labels, series,
                            expected):
-    """chipbench/readers/setup.py, dropout_draws.py, time_reversals.py and
-    kernel_edge_passes.py: nothing (not an error) from a program without the gauge or with the
+    """chipbench/readers/setup.py, dropout_draws.py, time_reversals.py,
+    kernel_edge_passes.py and bare_weight_grad_dots.py: nothing (not an error) from a program without the gauge or with the
     gauge never set, the value with it set."""
     import importlib
 
@@ -1219,6 +1226,28 @@ def test_first_epoch_sets_the_kernel_edge_passes_gauge(tiny):
         in obs_setup.format_setup(table)
 
 
+def test_first_epoch_sets_the_bare_weight_grad_dots_gauge(tiny):
+    """``deeprest_train_bare_weight_grad_dots`` is what
+    ``bare_weight_grad_dots`` finds in the text of the executable the epoch
+    dispatched for the shapes of the w_ih leaves the step differentiates,
+    and a count of 0 is SET (XLA:CPU keeps a dot a ``dot``: the count is
+    the chip's to make); ``profile_epoch``'s ``setup`` and the ``set-up:``
+    line carry it (ISSUE 56)."""
+    _epoch(tiny)
+    trainer, state = tiny["trainer"], tiny["state"]
+    leaves = [a.shape for name, a in state.params.items()
+              if name in MASKED_PARAM_NAMES]
+    assert len(leaves) == 2
+    counted = profiler.bare_weight_grad_dots(
+        trainer._dispatched_program_text(state), scopes.IN_PROJ, leaves)
+    gauge = REGISTRY.get(obs_setup.BARE_WEIGHT_GRAD_DOTS)
+    assert gauge.series() == {(): float(len(counted))}
+    table = obs_setup.setup_table()
+    assert table["bare_weight_grad_dots"] == len(counted)
+    assert f"{len(counted)} bare weight-gradient dots a step" \
+        in obs_setup.format_setup(table)
+
+
 # -- the seam to the benchmark ----------------------------------------------
 
 # The program series that `chipbench/readers/*.py` (and the runners beside
@@ -1245,6 +1274,7 @@ _BENCHMARK_SERIES = [
     # (`loaded`, the value the reader asks for, takes a second process)
     ("deeprest_train_kept_executables_total", ("program", "result"),
      [{"program": "train_superstep"}]),
+    ("deeprest_train_bare_weight_grad_dots", (), [{}]),
     ("deeprest_train_collective_bytes", (), []),
     ("deeprest_train_device_bytes", ("at", "kind"), []),
     ("deeprest_train_dropout_draws", (), [{}]),

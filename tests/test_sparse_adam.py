@@ -655,11 +655,15 @@ def _sparse_dense_form():
 # Before it the two feeds without a table had stood since a159714 (ISSUE
 # 27's parent: 0d7001e2..., 088b19fa...) and the compact base (``_setup``: a
 # table, the rows riding the scan, the off-table pass by chunks of stale
-# rows) as ISSUE 34 left it (f4785d4d...).
+# rows) as ISSUE 34 left it (f4785d4d...).  ISSUE 56 moved all three again
+# (from c6b63920..., 026af2c7..., 4a167ee4...): the update is ordered by
+# leaf, so each lowered step holds a second ``optimization_barrier`` and two
+# ``tx.update``s over disjoint leaves where it held one, the same arithmetic
+# (section (g) below and tests/test_ordered_update.py hold the states equal).
 PARENT_SHA1 = {
-    "dense-feed": "c6b639204636401d23ad0f2b4df7ad634c3b7a40",
-    "sparse-dense-form": "026af2c7d7306eedc0db79f3c18f193db013f40e",
-    "sparse-compact": "4a167ee44d1a9e9066f2a8b3a115142fb82b59c6",
+    "dense-feed": "b533b5505a245ad94bf44fb1baccd57d12bc7029",
+    "sparse-dense-form": "788dff3dac637d787b6b28eeac8322fd9d437ef0",
+    "sparse-compact": "9057f599c5bdb9b4805e717dc59e0114adf2672f",
 }
 BUILDERS = dict(zip(PARENT_SHA1,
                     (_dense_feed, _sparse_dense_form, _setup)))
@@ -694,6 +698,90 @@ def test_feeds_without_a_table_lower_to_the_parents_superstep(
                         staged=staged)
     assert _gauge() == ({"updated": 0, "total": 0} if build is _dense_feed
                         else {"updated": F, "total": F})
+
+
+# -- (g): the update ordered by leaf IS tx.update (ISSUE 56) ------------------
+
+
+def plain_step(trainer, window: int = W):
+    """A train step written out with the PLAIN update (one ``tx.update``
+    over the whole gradient tree, then ``apply_updates``), differentiating
+    with respect to the whole leaves: what `apply_gradients` was before it
+    ordered the update by leaf.  The model, the loss and ``tx`` are the
+    trainer's own; tests/test_chip_compile.py compiles it for the chip."""
+    import optax
+    from deeprest_tpu.ops.densify import (
+        SparseBase, gather_densify_normalize,
+    )
+    from deeprest_tpu.ops.quantile import pinball_loss
+
+    def step(state, x_base, y_base, starts, wb):
+        idx = starts[:, None] + jnp.arange(window)[None, :]
+        sparse = isinstance(x_base, SparseBase)
+        xb = gather_densify_normalize(x_base, idx) if sparse else x_base[idx]
+
+        def loss_of(params):
+            preds = trainer.model.apply(
+                {"params": params}, xb, deterministic=False,
+                rngs={"dropout": jax.random.fold_in(state.rng, state.step)},
+                live_cols=x_base.live if sparse else None)
+            return pinball_loss(preds, y_base[idx],
+                                trainer.model_config.quantiles,
+                                sample_weight=wb)
+
+        loss, grads = jax.value_and_grad(loss_of)(state.params)
+        updates, opt_state = trainer.tx.update(grads, state.opt_state)
+        return state.replace(
+            step=state.step + 1, opt_state=opt_state,
+            params=optax.apply_updates(state.params, updates)), loss
+
+    return step
+
+
+def _plain_steps(trainer, state, staged, plan):
+    """The plan's real steps through :func:`plain_step`: the state and the
+    steps' losses."""
+    step = jax.jit(plain_step(trainer))
+    starts, weights, _ = plan
+    losses = []
+    for s, w in zip(starts.reshape(-1, B), weights.reshape(-1, B)):
+        if w.any():
+            state, loss = step(state, *staged, jnp.asarray(s), jnp.asarray(w))
+            losses.append(float(loss))
+    return state, np.asarray(losses, np.float32)
+
+
+def ordered_update_is_the_plain_update(form):
+    """The dense feed, the sparse feed's dense form and the compact form:
+    three steps of the superstep (whose update runs the first w_ih leaf's
+    Adam, a barrier, then every other leaf's) and three of the per-step
+    program (the same `apply_gradients` on whole leaves) leave the params,
+    ``mu``, ``nu``, ``count`` and the losses of three plain ``tx.update``s,
+    and the optimizer state's tree with its keys in the order ``tx.init``
+    gives them (a checkpoint of the parent restores).  The cases are
+    tests/test_ordered_update.py's (a file of its own: this one is near the
+    330 s a file may take), in-process and, to the bit, in its SSE4.2
+    subprocess."""
+    trainer, bundle, staged = BUILDERS[form]()
+    plan = _plan(trainer, bundle, 3)
+
+    def fresh_state():
+        return trainer.init_state(trainer.sample_input(bundle), seed=1)
+
+    want, want_losses = _plain_steps(trainer, fresh_state(), staged, plan)
+    for through in (_through_superstep, _through_per_step):
+        got, losses = through(trainer, fresh_state(), staged, plan)
+        assert int(got.opt_state[0].count) == 3
+        _assert_states_equal(got, want, ULPS)
+        np.testing.assert_allclose(
+            losses[:3], want_losses, rtol=0.0, err_msg=through.__name__,
+            atol=ULPS * float(np.spacing(np.float32(1.0))))
+    fresh = trainer.tx.init(got.params)
+    assert (jax.tree.structure(got.opt_state)
+            == jax.tree.structure(fresh))
+    for mirror in ("mu", "nu"):
+        assert (list(getattr(got.opt_state[0], mirror))
+                == list(getattr(fresh[0], mirror)) == list(got.params))
 
 
 # -- (h): without an FMA to contract into, every bit ---------------------------
